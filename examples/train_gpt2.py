@@ -1,12 +1,15 @@
 """Train GPT-2 124M data-parallel with JaxTrainer.
 
 Run:  python examples/train_gpt2.py [--workers 2] [--steps 20]
+      python examples/train_gpt2.py --tpu --full    (one worker, one chip)
 
 Each worker joins one jax.distributed process group (the TPU-native
 analogue of the reference's NCCL process-group bootstrap); the train step
 is one jitted XLA program (fwd, bwd, adamw) with bf16 compute and the
-Pallas flash-attention kernel.  On CPU test machines the workers get
-virtual XLA host devices.
+Pallas flash-attention kernel.  Without ``--tpu`` the workers run on the
+CPU with two virtual XLA host devices each; with it, one worker is granted
+one TPU chip and the trainer refuses to start unless it holds it.  This
+process never touches jax either way: a chip belongs to one process.
 """
 
 import os
@@ -49,20 +52,33 @@ def main():
     parser.add_argument("--full", action="store_true",
                         help="train GPT-2 124M (default: the tiny config, "
                         "sized for CPU smoke runs)")
+    parser.add_argument("--tpu", action="store_true",
+                        help="one worker on one TPU chip instead of CPU "
+                        "workers")
     args = parser.parse_args()
     args.tiny = not args.full
 
     import ray_tpu
     from ray_tpu.train import JaxConfig, JaxTrainer, RunConfig, ScalingConfig
 
-    # provision a logical CPU per worker regardless of host core count
-    ray_tpu.init(num_cpus=args.workers + 1)
+    if args.tpu:
+        # one process per chip; several chips of a host go to ONE worker
+        # (resources_per_worker={"TPU": n}) and a ShardingConfig inside it
+        args.workers = 1
+        ray_tpu.init(num_cpus=2, num_tpus=1)
+        jax_config = JaxConfig()
+        scaling = ScalingConfig(num_workers=1, use_tpu=True)
+    else:
+        # provision a logical CPU per worker regardless of host core count
+        ray_tpu.init(num_cpus=args.workers + 1)
+        jax_config = JaxConfig(platform="cpu", devices_per_worker=2)
+        scaling = ScalingConfig(num_workers=args.workers,
+                                resources_per_worker={"CPU": 1})
     trainer = JaxTrainer(
         train_loop,
         train_loop_config={"steps": args.steps, "tiny": args.tiny},
-        jax_config=JaxConfig(platform="cpu", devices_per_worker=2),
-        scaling_config=ScalingConfig(num_workers=args.workers,
-                                     resources_per_worker={"CPU": 1}),
+        jax_config=jax_config,
+        scaling_config=scaling,
         run_config=RunConfig(name="gpt2_example"),
     )
     result = trainer.fit()

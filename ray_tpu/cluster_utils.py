@@ -51,7 +51,9 @@ def _read_tagged_line(proc: subprocess.Popen, tag: str, timeout: float = 30.0):
 
 def make_cluster_env(extra: Optional[Dict[str, str]] = None) -> Dict[str, str]:
     """Environment for spawned GCS/raylet processes: driver import path,
-    fast failure detection for tests, CPU-only jax."""
+    fast failure detection for tests.  Nothing here pins jax: the GCS and
+    raylets never import it, and a raylet sets each worker's platform from
+    its profile (cpu workers to the CPU, tpu workers to their chips)."""
     env = dict(os.environ)
     # Subprocesses must resolve ray_tpu (and the user's modules) no
     # matter their cwd — propagate the driver's import path, the same
@@ -65,9 +67,6 @@ def make_cluster_env(extra: Optional[Dict[str, str]] = None) -> Dict[str, str]:
     # Fast failure detection for tests (prod tunes these up).
     env.setdefault("RAY_TPU_GCS_HEARTBEAT_INTERVAL_S", "0.1")
     env.setdefault("RAY_TPU_GCS_NODE_TIMEOUT_S", "1.5")
-    # Cluster workers are control-plane only in tests: never let them
-    # grab the TPU chip or spend seconds importing jax eagerly.
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env.update(extra or {})
     return env
 

@@ -326,7 +326,7 @@ config.define("actor_restarts", int, 0,
               live=True)
 config.define("num_chips", int, 0,
               "TPU chip count to advertise as this node's TPU resource "
-              "(overrides jax device discovery).", live=True)
+              "(overrides the count read from the PCI bus).", live=True)
 config.define("gcs_address", str, "",
               "GCS host:port for autoscaler-provisioned nodes: the "
               "instance startup script exports it and hands it to "

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import pickle
 import struct
+import sys
 
 import numpy as np
 from typing import Any, List
@@ -85,13 +86,10 @@ def _aligned(n: int) -> int:
 
 
 def _device_to_host(obj: Any) -> Any:
-    # Imported lazily: the core runtime must not require jax.
-    try:
-        import jax
-        import numpy as np
-    except ImportError:
-        return obj
-    if isinstance(obj, jax.Array):
+    # A process that never imported jax holds no jax.Array — and must not
+    # import it here: the driver stays off jax so that workers get the chip.
+    jax = sys.modules.get("jax")
+    if jax is not None and isinstance(obj, jax.Array):
         return np.asarray(obj)
     return obj
 

@@ -16,6 +16,7 @@ larger values go through the shm object store with zero-copy reads.
 
 from __future__ import annotations
 
+import glob
 import hashlib
 import os
 import time
@@ -679,6 +680,33 @@ def _gc_stale_stores(shm_dir: str):
         pass
 
 
+# PCI ids of Google's TPU chips (v3, v4, v5p, v5e, v6e, 7x)
+_TPU_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = {"0x0027", "0x005e", "0x0062", "0x0063", "0x006f",
+                    "0x0076"}
+
+
+def count_local_tpu_chips() -> int:
+    """TPU chips this host lets a process open, from sysfs and /dev.
+
+    Counting them must not open the device: a chip belongs to one process
+    at a time, and the driver is not the process that trains — once it has
+    asked jax for its devices, no worker can.  The PCI bus can list chips
+    the machine does not hand out (a one-chip slice of a four-chip host
+    shows four), so the count is bounded by the device nodes: /dev/accel<N>
+    up to v4, one /dev/vfio/<group> a chip from v5e on."""
+    on_bus = 0
+    for vendor in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        with open(vendor) as f:
+            if f.read().strip() != _TPU_PCI_VENDOR:
+                continue
+        with open(os.path.join(os.path.dirname(vendor), "device")) as f:
+            on_bus += f.read().strip() in _TPU_PCI_DEVICES
+    nodes = (glob.glob("/dev/accel[0-9]*")
+             or glob.glob("/dev/vfio/[0-9]*"))
+    return min(on_bus, len(nodes))
+
+
 class DriverWorker(Worker):
     def __init__(self, num_cpus=None, num_tpus=None, resources=None,
                  object_store_memory=None, namespace: str = ""):
@@ -692,16 +720,7 @@ class DriverWorker(Worker):
 
         total = {"CPU": float(num_cpus if num_cpus is not None else os.cpu_count())}
         if num_tpus is None:
-            num_tpus = config.num_chips
-            if num_tpus == 0 and "jax" in __import__("sys").modules:
-                try:
-                    import jax
-
-                    num_tpus = sum(
-                        1 for d in jax.devices() if d.platform != "cpu"
-                    )
-                except Exception:  # noqa: BLE001
-                    num_tpus = 0
+            num_tpus = config.num_chips or count_local_tpu_chips()
         if num_tpus:
             total["TPU"] = float(num_tpus)
         total.update(resources or {})

@@ -1066,6 +1066,12 @@ def main():
     tracing.maybe_enable_from_env()
     profiling.ensure_profiler("worker")
 
+    if config.worker_profile.startswith("tpu"):
+        # this process will compile for the chip
+        from ray_tpu.util.compile_cache import ensure_compile_cache
+
+        ensure_compile_cache()
+
     sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
     sock.connect(args.socket)
     worker = RemoteWorker(sock)

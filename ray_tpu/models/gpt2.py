@@ -146,8 +146,19 @@ def _attention(x, p, cfg: GPT2Config, mesh=None):
             v.transpose(0, 2, 1, 3), D ** -0.5, True)
         o = o.astype(x.dtype).transpose(0, 2, 1, 3)
     else:
-        # layout-native kernel: no (B,S,H,D) <-> (B,H,S,D) transposes
-        o = flash_attention_bshd(q, k, v, True)
+        # layout-native kernel: no (B,S,H,D) <-> (B,H,S,D) transposes;
+        # under a bound mesh each device runs it on its batch/head slice
+        from ray_tpu.parallel.context import get_mesh
+
+        mesh = get_mesh()
+        if mesh is None or mesh.size == 1:
+            o = flash_attention_bshd(q, k, v, True)
+        else:
+            from ray_tpu.parallel.ring_attention import (
+                flash_attention_sharded,
+            )
+
+            o = flash_attention_sharded(q, k, v, mesh, causal=True)
     o = o.reshape(B, S, E)
     return o @ p["c_proj"]["kernel"].astype(x.dtype) + p["c_proj"]["bias"].astype(x.dtype)
 

@@ -1,9 +1,12 @@
 """Lazy build of the native components.
 
 Shared libraries are compiled on first use (and cached next to the
-sources).  We deliberately avoid setuptools here: the native runtime has no
-Python-API dependency (pure ``extern "C"`` + ctypes), so a single g++
-invocation per library suffices and works in hermetic environments.
+sources, with the hash of the source they were built from: a copy of the
+tree resets mtimes, so only content says whether a binary lying there is
+the committed source's).  We deliberately avoid setuptools here: the native
+runtime has no Python-API dependency (pure ``extern "C"`` + ctypes), so a
+single g++ invocation per library suffices and works in hermetic
+environments.
 
 Two callers with different failure policies share this module:
 
@@ -16,6 +19,7 @@ Two callers with different failure policies share this module:
 
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
 import sys
@@ -37,10 +41,25 @@ class NativeBuildError(RuntimeError):
     pass
 
 
+def _source_hash(src: str) -> str:
+    with open(src, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _built_from(lib: str) -> str:
+    """Hash of the source ``lib`` was built from ('' when unrecorded)."""
+    try:
+        with open(lib + ".src-sha256") as f:
+            return f.read().strip()
+    except OSError:
+        return ""
+
+
 def _build(src: str, lib: str):
     # Per-pid temp name: two processes racing to build must not scribble
     # over each other's half-written .so (os.replace keeps the swap atomic).
     tmp = f"{lib}.tmp{os.getpid()}"
+    digest = _source_hash(src)
     cmd = [
         "g++", "-std=c++17", "-O3", "-fPIC", "-shared", "-pthread",
         "-o", tmp, src,
@@ -58,6 +77,9 @@ def _build(src: str, lib: str):
             f"native build failed: {' '.join(cmd)}\n{proc.stderr}"
         )
     os.replace(tmp, lib)
+    with open(tmp, "w") as f:
+        f.write(digest + "\n")
+    os.replace(tmp, lib + ".src-sha256")
 
 
 def lib_path(name: str = "store") -> str:
@@ -73,10 +95,8 @@ def lib_path(name: str = "store") -> str:
     src = os.path.join(_DIR, "src", src_name)
     lib = os.path.join(_DIR, lib_name)
     with _lock:
-        if (
-            not os.path.exists(lib)
-            or os.path.getmtime(lib) < os.path.getmtime(src)
-        ):
+        if (not os.path.exists(lib)
+                or _built_from(lib) != _source_hash(src)):
             _build(src, lib)
     return lib
 

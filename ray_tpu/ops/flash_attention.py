@@ -37,19 +37,13 @@ On non-TPU backends the same kernels run in interpret mode for tiny shapes
 from __future__ import annotations
 
 import functools
+import warnings
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is absent on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634  # 1/ln(2)
@@ -57,20 +51,9 @@ _LANE = 128  # minor-dim block width Pallas TPU requires
 
 # Both grid dims are embarrassingly parallel (batch*heads, and q/k blocks
 # within a head); telling Mosaic so lets it pipeline block prologues across
-# steps instead of treating the grid as a dependent loop nest.  The params
-# class moved across jax releases (TPUCompilerParams -> CompilerParams);
-# resolve whichever this install has, and degrade to None (valid for
-# pallas_call) when neither exists — interpret-mode tests don't need it.
-_COMPILER_PARAMS = None
-if _HAS_PLTPU:
-    _params_cls = (getattr(pltpu, "CompilerParams", None)
-                   or getattr(pltpu, "TPUCompilerParams", None))
-    if _params_cls is not None:
-        try:
-            _COMPILER_PARAMS = _params_cls(
-                dimension_semantics=("parallel", "parallel"))
-        except TypeError:  # pragma: no cover — surface drift
-            _COMPILER_PARAMS = None
+# steps instead of treating the grid as a dependent loop nest.
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel"))
 
 
 # ---------------------------------------------------------------------------
@@ -580,23 +563,69 @@ def _reference_attention(q, k, v, sm_scale, causal):
     return o.astype(q.dtype), lse
 
 
-def _use_pallas(q, S, block_q, block_k) -> Optional[bool]:
-    """None = no pallas at all; True = compiled; False = interpret mode."""
-    if not _HAS_PLTPU:
-        return None
+def _reference_backward(q, k, v, lse, do, delta, sm_scale, causal):
+    qf = q.astype(jnp.float32)
+    kf = k.astype(jnp.float32)
+    vf = v.astype(jnp.float32)
+    dof = do.astype(jnp.float32)
+    s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * sm_scale
+    if causal:
+        S = q.shape[2]
+        mask = jnp.tril(jnp.ones((S, S), bool))
+        s = jnp.where(mask, s, _NEG_INF)
+    p = jnp.exp(s - lse[..., None])
+    dv = jnp.einsum("bhqk,bhqd->bhkd", p, dof)
+    dp = jnp.einsum("bhqd,bhkd->bhqk", dof, vf)
+    ds = p * (dp - delta[..., None]) * sm_scale
+    dq = jnp.einsum("bhqk,bhkd->bhqd", ds, kf)
+    dk = jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+class AttentionFallbackWarning(UserWarning):
+    """A shape the flash kernels cannot tile ran the O(S^2) reference —
+    on every platform, the TPU included."""
+
+
+def _tiling_problem(S, block_q, block_k) -> Optional[str]:
+    """Why the Pallas kernels cannot tile S with these blocks, or None."""
     if S % block_q or S % block_k:
-        return None
-    # Degenerate blocks (odd/prime S drives _auto_block toward 1): the
-    # dense path beats a grid of sub-tile steps, and sub-8-sublane blocks
-    # risk Mosaic compile errors.  Whole-sequence blocks (bq == S) stay
-    # allowed for short-sequence/decode shapes.
+        return "the blocks do not divide the sequence"
+    # Degenerate blocks (odd/prime S drives _auto_block toward 1): a grid of
+    # sub-tile steps loses to the dense path, and sub-8-sublane blocks risk
+    # Mosaic compile errors.  Whole-sequence blocks (bq == S) stay allowed
+    # for short-sequence/decode shapes.
     if (block_q < 128 and block_q != S) or (block_k < 128 and block_k != S):
-        return None
-    platform = jax.devices()[0].platform
-    if platform == "cpu":
-        # interpret mode is only worth it for test-sized shapes
-        return False if q.size <= (1 << 16) else None
-    return True
+        return "the blocks are narrower than one 128-row tile"
+    return None
+
+
+def _warn_reference(shape, block_q, block_k, reason):
+    warnings.warn(
+        f"flash attention on shape {tuple(shape)} with blocks "
+        f"({block_q}, {block_k}) runs the O(S^2) reference: {reason}",
+        AttentionFallbackWarning, stacklevel=4)
+
+
+# q elements up to which a non-TPU backend interprets the kernels (the
+# tests' sizes); beyond it interpretation is too slow to be worth it.
+_INTERPRET_MAX_ELEMS = 1 << 16
+
+
+def _by_platform(kernel, reference, q, *rest):
+    """``kernel(q, *rest, interpret=False)`` — the compiled Mosaic kernel —
+    wherever the computation is lowered for a TPU; on any other platform
+    the same kernel interpreted at test sizes and ``reference(q, *rest)``
+    beyond them.  The choice is made per lowering platform, not from the
+    devices of the tracing process, so an export for a TPU from a CPU host
+    carries the kernel and nothing on a TPU is ever interpreted."""
+    if q.size <= _INTERPRET_MAX_ELEMS:
+        other = functools.partial(kernel, interpret=True)
+    else:
+        other = reference
+    return jax.lax.platform_dependent(
+        q, *rest, tpu=functools.partial(kernel, interpret=False),
+        default=other)
 
 
 def _auto_block(S: int, cap: int) -> int:
@@ -607,6 +636,13 @@ def _auto_block(S: int, cap: int) -> int:
     while b > 1 and S % b:
         b //= 2
     return max(b, 1)
+
+
+def _resolve(q, S, sm_scale, block_q, block_k):
+    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
+    bq = min(block_q, S) if block_q else _auto_block(S, 1024)
+    bk = min(block_k, S) if block_k else _auto_block(S, 1024)
+    return scale, bq, bk
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -624,47 +660,45 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
-    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     S = q.shape[2]
-    bq = min(block_q, S) if block_q else _auto_block(S, 1024)
-    bk = min(block_k, S) if block_k else _auto_block(S, 1024)
-    mode = _use_pallas(q, S, bq, bk)
-    if mode is None:
-        o, lse = _reference_attention(q, k, v, scale, causal)
+    scale, bq, bk = _resolve(q, S, sm_scale, block_q, block_k)
+    reference = functools.partial(_reference_attention, sm_scale=scale,
+                                  causal=causal)
+    problem = _tiling_problem(S, bq, bk)
+    if problem:
+        _warn_reference(q.shape, bq, bk, problem)
+        o, lse = reference(q, k, v)
     else:
-        o, lse = _pallas_forward(q, k, v, scale, causal, bq, bk,
-                                 interpret=not mode)
+        o, lse = _by_platform(
+            functools.partial(_pallas_forward, sm_scale=scale, causal=causal,
+                              block_q=bq, block_k=bk),
+            reference, q, k, v)
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, res, do, delta=None):
     q, k, v, o, lse = res
-    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     S = q.shape[2]
-    bq = min(block_q, S) if block_q else _auto_block(S, 1024)
-    bk = min(block_k, S) if block_k else _auto_block(S, 1024)
-    mode = _use_pallas(q, S, bq, bk)
-    if mode is not None:
-        return _pallas_backward(q, k, v, o, lse, do, scale, causal, bq, bk,
-                                interpret=not mode, delta=delta)
-    qf = q.astype(jnp.float32)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    dof = do.astype(jnp.float32)
-    s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * scale
-    if causal:
-        S = q.shape[2]
-        mask = jnp.tril(jnp.ones((S, S), bool))
-        s = jnp.where(mask, s, _NEG_INF)
-    p = jnp.exp(s - lse[..., None])
-    dv = jnp.einsum("bhqk,bhqd->bhkd", p, dof)
-    dp = jnp.einsum("bhqd,bhkd->bhqk", dof, vf)
+    scale, bq, bk = _resolve(q, S, sm_scale, block_q, block_k)
+    # delta = rowsum(do * o): cheap elementwise+reduce, XLA fuses it.
+    # Callers looping over K/V chunks (ring attention) pass it precomputed
+    # — it only depends on the q side, so per-chunk recompute is waste.
     if delta is None:
-        delta = jnp.sum(dof * o.astype(jnp.float32), axis=-1)
-    ds = p * (dp - delta[..., None]) * scale
-    dq = jnp.einsum("bhqk,bhkd->bhqd", ds, kf)
-    dk = jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+        delta = jnp.sum(
+            do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    problem = _tiling_problem(S, bq, bk)
+    if problem:
+        _warn_reference(q.shape, bq, bk, problem)
+        return _reference_backward(q, k, v, lse, do, delta, scale, causal)
+
+    def kernel(q, k, v, o, lse, do, delta, interpret):
+        return _pallas_backward(q, k, v, o, lse, do, scale, causal, bq, bk,
+                                interpret, delta=delta)
+
+    def reference(q, k, v, o, lse, do, delta):
+        return _reference_backward(q, k, v, lse, do, delta, scale, causal)
+
+    return _by_platform(kernel, reference, q, k, v, o, lse, do, delta)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
@@ -687,6 +721,10 @@ def _bshd_lanes_bwd_ok(q, S):
     return _bshd_lanes_ok(q, S, S, S) and S <= _LANES_MAX_SEQ
 
 
+def _tr(x):
+    return x.transpose(0, 2, 1, 3)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention_bshd(q, k, v, causal=False, sm_scale=None,
                          block_q=None, block_k=None):
@@ -703,35 +741,43 @@ def flash_attention_bshd(q, k, v, causal=False, sm_scale=None,
 
 
 def _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k):
-    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     S = q.shape[1]
-    bq = min(block_q, S) if block_q else _auto_block(S, 1024)
-    bk = min(block_k, S) if block_k else _auto_block(S, 1024)
-    mode = _use_pallas(q, S, bq, bk)
-    if mode is not None and _bshd_lanes_ok(q, S, bq, bk):
-        o, lse = _pallas_forward_bshd(q, k, v, scale, causal, bq, bk,
-                                      interpret=not mode)
+    scale, bq, bk = _resolve(q, S, sm_scale, block_q, block_k)
+    if _tiling_problem(S, bq, bk) is None and _bshd_lanes_ok(q, S, bq, bk):
+        def reference(q, k, v):
+            o, lse = _reference_attention(_tr(q), _tr(k), _tr(v), scale,
+                                          causal)
+            return _tr(o), lse
+
+        o, lse = _by_platform(
+            functools.partial(_pallas_forward_bshd, sm_scale=scale,
+                              causal=causal, block_q=bq, block_k=bk),
+            reference, q, k, v)
         return o, (q, k, v, o, lse)
-    tr = lambda x: x.transpose(0, 2, 1, 3)
-    o, (_, _, _, ot, lse) = _flash_fwd(tr(q), tr(k), tr(v), causal, sm_scale,
-                                       block_q, block_k)
-    return tr(o), (q, k, v, tr(ot), lse)
+    o, (_, _, _, ot, lse) = _flash_fwd(_tr(q), _tr(k), _tr(v), causal,
+                                       sm_scale, block_q, block_k)
+    return _tr(o), (q, k, v, _tr(ot), lse)
 
 
 def _flash_bwd_bshd(causal, sm_scale, block_q, block_k, res, do):
     q, k, v, o, lse = res
-    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     S = q.shape[1]
-    bq = min(block_q, S) if block_q else _auto_block(S, 1024)
-    bk = min(block_k, S) if block_k else _auto_block(S, 1024)
-    mode = _use_pallas(q, S, bq, bk)
-    if mode is not None and _bshd_lanes_bwd_ok(q, S):
-        return _pallas_backward_bshd(q, k, v, o, lse, do, scale, causal,
-                                     interpret=not mode)
-    tr = lambda x: x.transpose(0, 2, 1, 3)
+    scale, bq, bk = _resolve(q, S, sm_scale, block_q, block_k)
+    if _tiling_problem(S, bq, bk) is None and _bshd_lanes_bwd_ok(q, S):
+        def reference(q, k, v, o, lse, do):
+            qt, kt, vt, ot, dot = map(_tr, (q, k, v, o, do))
+            delta = jnp.sum(
+                dot.astype(jnp.float32) * ot.astype(jnp.float32), axis=-1)
+            return tuple(map(_tr, _reference_backward(
+                qt, kt, vt, lse, dot, delta, scale, causal)))
+
+        return _by_platform(
+            functools.partial(_pallas_backward_bshd, sm_scale=scale,
+                              causal=causal),
+            reference, q, k, v, o, lse, do)
     dq, dk, dv = _flash_bwd(causal, sm_scale, block_q, block_k,
-                            (tr(q), tr(k), tr(v), tr(o), lse), tr(do))
-    return tr(dq), tr(dk), tr(dv)
+                            (_tr(q), _tr(k), _tr(v), _tr(o), lse), _tr(do))
+    return _tr(dq), _tr(dk), _tr(dv)
 
 
 flash_attention_bshd.defvjp(_flash_fwd_bshd, _flash_bwd_bshd)

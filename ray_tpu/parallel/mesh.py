@@ -24,6 +24,7 @@ from typing import Dict, Optional, Sequence
 
 import jax
 import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
 AXIS_ORDER = ("dp", "fsdp", "pp", "sp", "ep", "tp")
@@ -60,15 +61,13 @@ def create_mesh(
     extra = [a for a in sizes if a not in AXIS_ORDER]
     names += extra
     shape = [sizes[a] for a in names]
-    try:
-        from jax.experimental import mesh_utils
-
-        dev_array = mesh_utils.create_device_mesh(
-            shape, devices=devices,
-            allow_split_physical_axes=allow_split_physical_axes,
-        )
-    except Exception:  # noqa: BLE001 - fallback: row-major reshape
-        dev_array = np.asarray(devices).reshape(shape)
+    # Topology-aware on a TPU (a plain reshape elsewhere).  An error here is
+    # a layout the physical torus cannot carry; a row-major guess in its
+    # place would hide that, so it propagates.
+    dev_array = mesh_utils.create_device_mesh(
+        shape, devices=devices,
+        allow_split_physical_axes=allow_split_physical_axes,
+    )
     return Mesh(dev_array, tuple(names))
 
 

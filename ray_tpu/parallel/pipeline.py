@@ -49,8 +49,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-import ray_tpu.parallel._shard_map_compat  # noqa: F401 — jax.shard_map shim
-
 
 def stack_layer_params(layer_params: list):
     """[per-layer pytree] -> single pytree with leading layer dim (the

@@ -48,12 +48,11 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.parallel._shard_map_compat import shard_map
-
 from ray_tpu.ops.flash_attention import (
     _flash_bwd,
     _flash_fwd,
     flash_attention,
+    flash_attention_bshd,
 )
 
 _NEG_INF = -1e30
@@ -225,8 +224,41 @@ def ring_attention_sharded(q, k, v, mesh: Mesh, causal: bool = True,
     inner = ring_attention if variant == "ring" else ulysses_attention
     fn = functools.partial(inner, axis_name=seq_axis, causal=causal,
                            sm_scale=sm_scale)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
+
+
+def _dividing_axes(mesh: Mesh, names, size: int):
+    """PartitionSpec entry sharding a dimension of ``size`` over the mesh
+    axes among ``names``; an axis that does not divide what is left of the
+    dimension is dropped, and the dimension is gathered over it."""
+    picked = []
+    for a in names:
+        n = mesh.shape.get(a, 1)
+        if n > 1 and size % n == 0:
+            picked.append(a)
+            size //= n
+    if not picked:
+        return None
+    return tuple(picked) if len(picked) > 1 else picked[0]
+
+
+def flash_attention_sharded(q, k, v, mesh: Mesh, causal: bool = True,
+                            sm_scale: Optional[float] = None,
+                            batch_axes=("dp", "fsdp"), head_axis="tp"):
+    """shard_map wrapper for the layout-native kernel: q/k/v are (batch,
+    seq, heads, head_dim) global arrays, batch sharded on dp/fsdp and heads
+    on `tp`, and each device runs the kernel on its own slice.  A
+    pallas_call is an opaque custom call to the SPMD partitioner: under a
+    mesh of several devices jax refuses to lower one that is not inside a
+    shard_map."""
+    spec = P(_dividing_axes(mesh, batch_axes, q.shape[0]), None,
+             _dividing_axes(mesh, (head_axis,), q.shape[2]), None)
+    return jax.shard_map(
+        lambda q, k, v: flash_attention_bshd(q, k, v, causal, sm_scale),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)
 

@@ -19,6 +19,7 @@ the single-machine analogue of the reference's fake multi-node cluster.
 
 from __future__ import annotations
 
+import math
 import socket
 from dataclasses import dataclass
 from typing import Dict, Optional
@@ -59,8 +60,8 @@ class JaxConfig(BackendConfig):
     ``distributed=False`` skips ``jax.distributed.initialize`` (single-worker
     training or externally-initialized runtimes).  ``platform`` pins
     JAX_PLATFORMS in the workers ("cpu" for the virtual-device test path;
-    None = whatever the worker env provides, i.e. the TPU chips visible to
-    the process on real hardware).  ``devices_per_worker`` sets
+    None = what the raylet sets from the worker's grant: the CPU, or the
+    TPU chips it was granted).  ``devices_per_worker`` sets
     ``--xla_force_host_platform_device_count`` (CPU testing only).
     """
 
@@ -111,10 +112,7 @@ def _init_jax_distributed(coordinator: str, world_size: int, rank: int,
     if platform == "cpu" or (platform is None and env_platform == "cpu"):
         # Cross-process CPU collectives need gloo (the CPU analogue of the
         # ICI/DCN data plane).
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # noqa: BLE001 - older jax: flag absent
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=world_size,
@@ -136,12 +134,36 @@ def _shutdown_jax_distributed():
         pass
 
 
+def _check_granted_chips(granted: int):
+    """Runs inside a training worker that was granted TPU chips: it must
+    see platform ``tpu`` and exactly the chips of its grant, or the group
+    does not start — a worker that was promised a chip and has none would
+    otherwise train on the CPU and report success."""
+    import os
+
+    import jax
+
+    devices = jax.local_devices()
+    platform = devices[0].platform
+    if platform != "tpu" or len(devices) != granted:
+        raise RuntimeError(
+            f"training worker was granted TPU: {granted} but jax sees "
+            f"platform {platform!r} with {len(devices)} local device(s) "
+            f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}, "
+            f"TPU_VISIBLE_CHIPS={os.environ.get('TPU_VISIBLE_CHIPS')!r})")
+
+
 class JaxBackend(Backend):
     def on_start(self, worker_group: WorkerGroup, backend_config: JaxConfig):
-        if not backend_config.distributed or len(worker_group) == 1:
-            # Single process: nothing to bootstrap; jax picks up the local
-            # devices on first use.
-            return
+        if backend_config.distributed and len(worker_group) > 1:
+            self._bootstrap_distributed(worker_group, backend_config)
+        # after the bootstrap: looking at the devices creates the backend
+        granted = math.ceil(worker_group.resources_per_worker.get("TPU", 0))
+        if granted:
+            worker_group.execute(_check_granted_chips, granted)
+
+    def _bootstrap_distributed(self, worker_group: WorkerGroup,
+                               backend_config: JaxConfig):
         # Elect rank 0's host as coordinator (reference broadcasts rank-0's
         # address the same way, `train/torch/config.py:102-136`).
         host, port = worker_group.execute_single(

@@ -62,7 +62,13 @@ class BackendExecutor:
         self.worker_group = WorkerGroup(
             self._num_workers, self._resources_per_worker, env_vars=env_vars
         )
-        self._backend.on_start(self.worker_group, self._backend_config)
+        try:
+            self._backend.on_start(self.worker_group, self._backend_config)
+        except BaseException:
+            # a group the backend refused must not keep its workers (and
+            # the chips they opened)
+            self.shutdown(graceful=False)
+            raise
 
     def start_training(self, train_fn: Callable, config: Optional[dict],
                        checkpoint: Optional[Checkpoint] = None,

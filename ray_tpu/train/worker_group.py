@@ -74,6 +74,7 @@ class WorkerGroup:
                  env_vars: Optional[Dict[str, str]] = None,
                  placement_strategy: str = "PACK"):
         self.num_workers = num_workers
+        self.resources_per_worker = dict(resources_per_worker)
         runtime_env = {"env_vars": dict(env_vars)} if env_vars else None
         opts = dict(resources_per_worker)
         # The actor's request must equal its PG bundle exactly (a bundle

@@ -17,14 +17,6 @@ from ray_tpu.parallel.sharding import ShardingConfig, shard_params
 
 TOL = 2e-2  # CPU backend matmuls are low-precision by default
 
-# Pipeline parallelism relies on the newer manual-sharding surface
-# (jax.lax.pcast / partial-auto shard_map); skip — not fail — on jax
-# releases that predate it (same policy as the pallas-surface guard).
-requires_pipeline_surface = pytest.mark.skipif(
-    not hasattr(jax.lax, "pcast"),
-    reason="pipeline parallelism needs jax.lax.pcast (newer jax)")
-
-
 def _qkv(B=2, H=4, S=128, D=32, dtype=jnp.float32):
     key = jax.random.PRNGKey(0)
     return tuple(
@@ -251,7 +243,6 @@ def test_moe_ep_sharded_matches_single_device():
     assert abs(got - ref) < 1e-3, (got, ref)
 
 
-@requires_pipeline_surface
 def test_pipeline_matches_sequential():
     """pp=2 pipelined blocks produce the same loss as the sequential
     single-device model (the GPipe schedule only reorders work)."""
@@ -278,7 +269,6 @@ def test_pipeline_matches_sequential():
     assert abs(got - ref) < 1e-3, (got, ref)
 
 
-@requires_pipeline_surface
 def test_pipeline_moe_train_step_learns():
     """Full fwd+bwd+adamw on a pp x ep x tp mesh: grads flow through the
     ppermute schedule and the expert dispatch; loss decreases."""
@@ -347,6 +337,45 @@ def test_flash_attention_bshd_lane_path(H, D, causal):
                                    err_msg=f"{name} causal={causal} D={D}")
 
 
+# (B, S, H, D) -> Mosaic kernels in forward + backward: the lane kernels
+# with the fused backward, the transposing bhsd kernels where heads do not
+# pair up into 128 lanes (25, 3), and past _LANES_MAX_SEQ the dq and dk/dv
+# kernels of the two-kernel backward
+@pytest.mark.parametrize("shape,kernels", [
+    ((16, 1024, 12, 64), 2), ((8, 1024, 16, 64), 2), ((4, 1024, 25, 64), 2),
+    ((16, 1024, 3, 64), 2), ((2, 2048, 32, 128), 3)])
+def test_flash_attention_lowers_to_mosaic_for_tpu(shape, kernels):
+    """Exported for a TPU from this CPU host, forward + backward are Mosaic
+    custom calls and nothing else: no interpreted kernel body and no O(S^2)
+    reference (either would show up as dots in the module).  Catches a
+    change that breaks Mosaic lowering before it costs chip time."""
+    from ray_tpu.ops.flash_attention import flash_attention_bshd
+
+    def loss(q, k, v):
+        o = flash_attention_bshd(q, k, v, True)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    module = jax.export.export(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+        platforms=["tpu"])(x, x, x).mlir_module()
+    assert module.count("stablehlo.custom_call @tpu_custom_call") == kernels
+    assert "stablehlo.dot_general" not in module
+    assert "stablehlo.while" not in module
+
+
+def test_flash_attention_reference_fallback_warns():
+    """A sequence the kernels cannot tile runs the O(S^2) reference on every
+    platform — visibly."""
+    from ray_tpu.ops.flash_attention import AttentionFallbackWarning
+
+    q, k, v = _qkv(B=1, H=1, S=1032, D=8)  # 1032 = 8 * 129: blocks fall to 8
+    with pytest.warns(AttentionFallbackWarning, match=r"\(1, 1, 1032, 8\)"):
+        o = flash_attention(q, k, v, True)
+    ref, _ = _reference_attention(q, k, v, 8 ** -0.5, True)
+    np.testing.assert_allclose(o, ref, atol=TOL)
+
+
 def test_flash_attention_fused_bwd_mixed_dtypes():
     """dk/dv must come back in k/v's dtype on the fused single-block paths
     (regression: out_shape used q.dtype for all three)."""
@@ -364,7 +393,6 @@ def test_flash_attention_fused_bwd_mixed_dtypes():
     assert dv.dtype == jnp.bfloat16
 
 
-@requires_pipeline_surface
 def test_pipeline_moe_aux_collected_under_pp():
     """The MoE load-balancing aux must ride the pp stage handoff: the
     pp-pipelined loss equals the sequential loss WITH its aux term (to the
@@ -402,7 +430,6 @@ def test_pipeline_moe_aux_collected_under_pp():
     assert got > ref_no_aux + 1e-4
 
 
-@requires_pipeline_surface
 def test_pipeline_schedule_utilization():
     """The fill-drain schedule runs M+S-1 stage-body ticks per device with
     M useful — the best any non-interleaved schedule (GPipe or 1F1B)

@@ -186,6 +186,31 @@ def test_native_build_graceful_fallback(monkeypatch, capsys):
     assert build.try_lib_path("codec") is None
 
 
+def test_native_build_is_stale_by_content_not_mtime(tmp_path, monkeypatch):
+    """A binary is rebuilt when the source it was built from differs from
+    the source now, whatever the mtimes say: a copy of the tree resets
+    them, and the copy must not run whatever binary was lying there."""
+    from ray_tpu.native import build
+
+    src = tmp_path / "src" / "one.cc"
+    src.parent.mkdir()
+    src.write_text('extern "C" int answer() { return 1; }\n')
+    monkeypatch.setattr(build, "_DIR", str(tmp_path))
+    monkeypatch.setattr(build, "_LIBS", {"one": ("one.cc", "libone.so")})
+
+    lib = build.lib_path("one")
+    built_from = build._built_from(lib)
+    assert built_from == build._source_hash(str(src))
+    src.write_text('extern "C" int answer() { return 2; }\n')
+    os.utime(src, (0, 0))  # the source now looks OLDER than the binary
+    assert build.lib_path("one") == lib
+    assert build._built_from(lib) == build._source_hash(str(src))
+    assert build._built_from(lib) != built_from
+    import ctypes
+
+    assert ctypes.CDLL(lib).answer() == 2
+
+
 def test_fallback_runtime_end_to_end():
     """Dedicated fallback-viability run: a representative workload (tasks,
     actor calls, store round trip, error propagation) in a subprocess with

@@ -308,3 +308,60 @@ def test_user_error_propagates(ray_train, tmp_path):
     )
     with pytest.raises(Exception, match="boom in train loop"):
         trainer.fit()
+
+
+# ---------------------------------------------------------------------------
+# compile-cache placement
+
+
+def _compile_cache_seen_by_a_process(cwd, cache_env):
+    """(what ensure_compile_cache returns, what jax then uses) in a fresh
+    process started from ``cwd``."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(cache_env, PYTHONPATH=repo)
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from ray_tpu.util.compile_cache import ensure_compile_cache\n"
+         "import jax\n"
+         "print(ensure_compile_cache())\n"
+         "print(jax.config.jax_compilation_cache_dir)\n"],
+        env=env, cwd=cwd, capture_output=True, text=True, timeout=120,
+        check=True).stdout.split()
+    return out[0], out[1]
+
+
+def test_compile_cache_is_one_fixed_directory_in_the_checkout(tmp_path):
+    from ray_tpu.util.compile_cache import CHECKOUT_CACHE_DIR
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert CHECKOUT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    other = tmp_path / "elsewhere"
+    other.mkdir()
+    # two processes, two cwds, one directory: a cache that moves never hits
+    for cwd in (tmp_path, other):
+        assert _compile_cache_seen_by_a_process(cwd, {}) == (
+            CHECKOUT_CACHE_DIR, CHECKOUT_CACHE_DIR)
+
+
+def test_compile_cache_placed_from_outside_is_left_alone(
+        ray_train, tmp_path, monkeypatch):
+    placed = str(tmp_path / "placed")
+    # jax reads the variable itself; the helper sets nothing on top of it
+    assert _compile_cache_seen_by_a_process(
+        tmp_path, {"JAX_COMPILATION_CACHE_DIR": placed}) == (placed, placed)
+
+    # ... and a worker spawned from here on inherits it through its raylet
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+
+    @ray_train.remote
+    def seen():
+        return os.environ.get("JAX_COMPILATION_CACHE_DIR")
+
+    fresh_worker = {"env_vars": {"CACHE_PLACEMENT_TEST": "1"}}
+    assert ray_train.get(
+        seen.options(runtime_env=fresh_worker).remote(), timeout=60) == placed
