@@ -26,7 +26,7 @@ from ray_tpu.core.task_spec import (
     TaskSpec,
 )
 from ray_tpu.core.worker import global_worker
-from ray_tpu.util.tracing import submit_with_span
+from ray_tpu.util.tracing import submit_with_span, timeline_ctx
 
 
 class ActorMethod:
@@ -223,6 +223,9 @@ class ActorClass:
             runtime_env=_prepare_env(worker, opts.get("runtime_env")),
             placement=placement or None,
         )
+        # a Train job's timeline follows its actors' creation (the raylet
+        # names the worker spawn it causes after it)
+        spec.trace_ctx = timeline_ctx()
         worker.submit_spec(spec)
         return ActorHandle(actor_id, self.__name__, method_groups)
 

@@ -545,6 +545,9 @@ class Raylet:
         self._chip_counts_refused: set = set()  # said so on stderr once
         self._procs: List[subprocess.Popen] = []
         self._unregistered: List[Tuple[subprocess.Popen, str]] = []
+        # pid -> (Popen time, trace ctx of the task whose demand caused the
+        # spawn, chips): closed as a `raylet.worker_spawn` hop at registration
+        self._spawn_started: Dict[int, tuple] = {}
         self._health_timer_armed = False
         self._ready_queue: deque = deque()  # TaskSpecs with deps satisfied
         self._waiting: Dict[TaskID, Tuple[TaskSpec, set]] = {}
@@ -1113,7 +1116,7 @@ class Raylet:
                         pass
         return None
 
-    def _spawn_worker(self, profile: str):
+    def _spawn_worker(self, profile: str, trace_ctx: Optional[dict] = None):
         base = profile.split("|", 1)[0]
         chips = None
         if base != "cpu":
@@ -1189,8 +1192,11 @@ class Raylet:
             if not self._log_timer_armed:
                 self._log_timer_armed = True
                 self.add_timer(0.3, self._pump_worker_logs)
+        spawn_t0 = time.time()
         proc = subprocess.Popen(cmd, env=env, cwd=os.getcwd(),
                                 stdout=stdout, stderr=stderr)
+        self._spawn_started[proc.pid] = (spawn_t0, trace_ctx,
+                                         len(chips or ()))
         for c in chips or ():
             self._chip_procs[c] = proc
         if stdout is not None:
@@ -1420,6 +1426,7 @@ class Raylet:
         for proc, profile in self._unregistered:
             if proc.poll() is not None:
                 self._spawning[profile] = max(0, self._spawning.get(profile, 0) - 1)
+                self._spawn_started.pop(proc.pid, None)
                 sys.stderr.write(
                     f"[ray_tpu] worker (profile={profile}) exited with code "
                     f"{proc.returncode} before registering — check worker "
@@ -1607,6 +1614,16 @@ class Raylet:
             self._unregistered = [
                 (p, prof) for p, prof in self._unregistered if p.pid != conn.pid
             ]
+            started = self._spawn_started.pop(conn.pid, None)
+            if started is not None:
+                # Popen -> registered: process start, imports, socket.
+                # Always recorded (a handful a job): a Train job's
+                # timeline joins it by the worker's pid
+                _tracing.timeline_hop(
+                    "raylet.worker_spawn", started[1], started[0],
+                    time.time(), proc="raylet", always_export=True,
+                    profile=conn.profile, pid=conn.pid, chips=started[2])
+                self._arm_trace_flush()
             self._return_worker(conn)
             self._schedule()
         elif t == "requeue":
@@ -4437,6 +4454,7 @@ class Raylet:
                 return
         deferred = deque()
         spawn_demand: Dict[str, int] = {}
+        spawn_ctx: Dict[str, Optional[dict]] = {}  # first demander's trace
         pg_orphans = []  # tasks whose PG no longer exists — fail after drain
         # Bounded scan: once NO_PROGRESS_WINDOW consecutive specs deferred
         # without a single dispatch, stop — freed capacity this pass is
@@ -4637,6 +4655,7 @@ class Raylet:
             conn = self._get_idle_worker(profile)
             if conn is None:
                 spawn_demand[profile] = spawn_demand.get(profile, 0) + 1
+                spawn_ctx.setdefault(profile, spec.trace_ctx)
                 if shape_key is not None:
                     # same-shape tasks would also find no idle worker; the
                     # skip is per-pass only (any env-profile mismatch just
@@ -4740,7 +4759,7 @@ class Raylet:
             # for life and are excluded — resource accounting gates them.
             want = min(depth, cap - poolable.get(profile, 0)) - pending
             for _ in range(max(0, want)):
-                self._spawn_worker(profile)
+                self._spawn_worker(profile, spawn_ctx.get(profile))
 
     def _locality_preferred_node(self, spec: TaskSpec) -> Optional[str]:
         """Node holding strictly more bytes of this task's arguments than

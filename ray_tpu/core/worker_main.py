@@ -718,7 +718,13 @@ class _run_span:
         self._sp = None
         self._err_ctx = None
         ctx = spec.trace_ctx
-        if ctx is None or not tracing.tracing_enabled():
+        if ctx is None:
+            return
+        if not tracing.tracing_enabled():
+            if ctx.get("timeline"):
+                # a Train job's timeline spans parent under the caller
+                # with the master switch off: context only, no span
+                self._sp = tracing.adopt(ctx)
             return
         if ctx.get("sampled", True):
             self._sp = tracing.span(

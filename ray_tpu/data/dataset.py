@@ -27,6 +27,7 @@ import numpy as np
 
 import ray_tpu
 from ray_tpu.data.block import Block, BlockAccessor, BlockMetadata, VALUE_COL
+from ray_tpu.util import tracing
 
 
 # --------------------------------------------------------------------------
@@ -473,6 +474,28 @@ def _stream_blocks(sources: List[_Source], ops: List[_OpSpec],
         yield pending.popleft()
 
 
+def _get_block(ref, index: int) -> Block:
+    """The consumer's ``get`` of its next block.  In a Train job's
+    timeline it is the span ``data.block_wait`` (how long the consumer
+    was blocked on the object store, or on a read task that had not
+    finished) and the counters ``data.blocks`` / ``data.block_bytes`` /
+    ``data.blocks_ready``: ``ready`` says the ref was local and sealed
+    when asked, so the prefetch had done its work."""
+    if tracing.timeline_ctx() is None:
+        return ray_tpu.get(ref)
+    ready = bool(ray_tpu.wait([ref], timeout=0)[0])
+    with tracing.timeline_span("data.block_wait", block=index,
+                               ready=ready) as sp:
+        block = ray_tpu.get(ref)
+        size = BlockAccessor.for_block(block).size_bytes()
+        sp.set_attrs(bytes=size)
+    tracing.count("data.blocks")
+    tracing.count("data.block_bytes", size)
+    if ready:
+        tracing.count("data.blocks_ready")
+    return block
+
+
 class _ExecutedBlock:
     __slots__ = ("ref", "meta_ref", "_meta")
 
@@ -597,7 +620,8 @@ class Dataset:
                      ) -> Iterator[Any]:
         """Stream batches; at most ``prefetch_blocks`` map tasks in flight."""
         return _batches_from_block_iter(
-            (ray_tpu.get(eb.ref) for eb in self._stream(prefetch_blocks)),
+            (_get_block(eb.ref, i)
+             for i, eb in enumerate(self._stream(prefetch_blocks))),
             batch_size=batch_size, batch_format=batch_format,
             drop_last=drop_last,
             local_shuffle_buffer_size=local_shuffle_buffer_size,

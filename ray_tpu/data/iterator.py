@@ -11,6 +11,8 @@ from __future__ import annotations
 
 from typing import Any, Iterator, Optional
 
+from ray_tpu.util import tracing
+
 
 class DataIterator:
     def __init__(self, dataset):
@@ -38,13 +40,16 @@ class DataIterator:
 
         for batch in self.iter_batches(batch_size=batch_size,
                                        drop_last=drop_last):
-            if isinstance(batch, dict):
-                out = {k: jnp.asarray(v, dtype=dtype) if v.dtype.kind in "fiub"
-                       else v for k, v in batch.items()}
-            else:
-                out = jnp.asarray(batch, dtype=dtype)
-            if device is not None:
-                out = jax.device_put(out, device)
+            with tracing.timeline_span("data.to_device"):
+                if isinstance(batch, dict):
+                    out = {k: jnp.asarray(v, dtype=dtype)
+                           if v.dtype.kind in "fiub" else v
+                           for k, v in batch.items()}
+                else:
+                    out = jnp.asarray(batch, dtype=dtype)
+                if device is not None:
+                    out = jax.device_put(out, device)
+            tracing.count("data.batches")
             yield out
 
     def materialize(self):
@@ -86,18 +91,20 @@ class StreamSplitDataIterator(DataIterator):
         def claim():
             return ray_tpu.get(self._coord.claim.remote(epoch), timeout=120)
 
+        from ray_tpu.data.dataset import _get_block
+
         pending = []
         for _ in range(2):
             i = claim()
             if i is None:
                 break
-            pending.append(self._dataset._execute_block(i))
+            pending.append((i, self._dataset._execute_block(i)))
         while pending:
-            ref = pending.pop(0)
+            index, ref = pending.pop(0)
             i = claim()
             if i is not None:
-                pending.append(self._dataset._execute_block(i))
-            yield ray_tpu.get(ref)
+                pending.append((i, self._dataset._execute_block(i)))
+            yield _get_block(ref, index)
 
     def iter_batches(self, *, batch_size: int = 256,
                      batch_format: str = "numpy", drop_last: bool = False,

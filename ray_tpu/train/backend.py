@@ -19,12 +19,17 @@ the single-machine analogue of the reference's fake multi-node cluster.
 
 from __future__ import annotations
 
+import logging
 import math
 import socket
+import time
 from dataclasses import dataclass
 from typing import Dict, Optional
 
 from ray_tpu.train.worker_group import WorkerGroup
+from ray_tpu.util import tracing
+
+logger = logging.getLogger(__name__)
 
 
 class BackendConfig:
@@ -101,23 +106,27 @@ def _get_host_and_port(port: Optional[int]):
 def _init_jax_distributed(coordinator: str, world_size: int, rank: int,
                           platform: Optional[str]):
     """Runs inside each training worker process."""
-    import os
+    # the span takes in the import: a worker that holds no chip has not
+    # imported jax before this
+    with tracing.timeline_span("train.jax_distributed_init", rank=rank,
+                               world_size=world_size):
+        import os
 
-    import jax
+        import jax
 
-    # NOTE: must not touch jax.devices()/default_backend() before
-    # distributed.initialize — that would create the backend early and the
-    # process would never see the global mesh.
-    env_platform = os.environ.get("JAX_PLATFORMS", "").split(",")[0]
-    if platform == "cpu" or (platform is None and env_platform == "cpu"):
-        # Cross-process CPU collectives need gloo (the CPU analogue of the
-        # ICI/DCN data plane).
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    jax.distributed.initialize(
-        coordinator_address=coordinator,
-        num_processes=world_size,
-        process_id=rank,
-    )
+        # NOTE: must not touch jax.devices()/default_backend() before
+        # distributed.initialize — that would create the backend early and
+        # the process would never see the global mesh.
+        env_platform = os.environ.get("JAX_PLATFORMS", "").split(",")[0]
+        if platform == "cpu" or (platform is None and env_platform == "cpu"):
+            # Cross-process CPU collectives need gloo (the CPU analogue of
+            # the ICI/DCN data plane).
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
+        jax.distributed.initialize(
+            coordinator_address=coordinator,
+            num_processes=world_size,
+            process_id=rank,
+        )
     return {
         "process_index": jax.process_index(),
         "global_devices": jax.device_count(),
@@ -140,17 +149,94 @@ def _check_granted_chips(granted: int):
     does not start — a worker that was promised a chip and has none would
     otherwise train on the CPU and report success."""
     import os
+    import sys
 
-    import jax
+    # paid here unless the worker imported jax when it started (it does
+    # where `ensure_compile_cache` has to place the cache itself)
+    with tracing.timeline_span("train.jax_import",
+                               imported="jax" in sys.modules):
+        import jax
 
-    devices = jax.local_devices()
-    platform = devices[0].platform
+    # the first look at the devices: libtpu opens the chips here
+    with tracing.timeline_span("train.chip_claim", granted=granted) as sp:
+        devices = jax.local_devices()
+        platform = devices[0].platform
+        sp.set_attrs(platform=platform, devices=len(devices))
     if platform != "tpu" or len(devices) != granted:
         raise RuntimeError(
             f"training worker was granted TPU: {granted} but jax sees "
             f"platform {platform!r} with {len(devices)} local device(s) "
             f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}, "
             f"TPU_VISIBLE_CHIPS={os.environ.get('TPU_VISIBLE_CHIPS')!r})")
+
+
+# jax.monitoring's durations, by event, as spans of the job timeline
+_JAX_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax.cache_read",
+}
+_JAX_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": "jax.cache_hits",
+    "/jax/compilation_cache/cache_misses": "jax.cache_misses",
+}
+# jax reports every function traced inside another's trace, thousands a
+# step: a trace shorter than this is counted (`jax.traces`), not spanned
+_SHORT_TRACE_S = 0.005
+_jax_listening = False
+_compiles: Dict[str, int] = {}     # backend compiles, by trace id + function
+_recompile_warned = set()
+
+
+def _listen_to_jax():
+    """Runs inside each training worker, once a process: what this process
+    pays JAX's tracer, its lowering (Mosaic included), its compiler and
+    its compile cache becomes spans and counters of the job whose context
+    is active when jax reports it (end = now, start = now - the seconds
+    reported; a cache read lies inside its `jax.backend_compile`, a
+    function traced inside another's trace inside that one's span).  A
+    function that compiles a second time after the job's first
+    `train.report` is named in one WARNING line: the step recompiled."""
+    global _jax_listening
+    if _jax_listening:
+        return
+    _jax_listening = True
+    import jax
+
+    def on_duration(event, seconds, **kwargs):
+        name = _JAX_SPANS.get(event)
+        ctx = name and tracing.timeline_ctx()
+        if not ctx:
+            return
+        if name == "jax.trace":
+            tracing.count("jax.traces")
+            if seconds < _SHORT_TRACE_S:
+                return
+        now = time.time()
+        fun_name = str(kwargs.get("fun_name", ""))
+        tracing.timeline_hop(name, ctx, now - seconds, now,
+                             **({"fun_name": fun_name} if fun_name else {}))
+        if name != "jax.backend_compile":
+            return
+        tracing.count("jax.compiles")
+        key = ctx["trace_id"] + fun_name
+        seen = _compiles[key] = _compiles.get(key, 0) + 1
+        if (fun_name and seen > 1 and key not in _recompile_warned
+                and tracing.counter("train.reports")):
+            _recompile_warned.add(key)
+            logger.warning(
+                "%s compiled again after the job's first train.report: "
+                "a shape, dtype or static argument of it changes between "
+                "steps", fun_name)
+
+    def on_event(event, **_):
+        name = _JAX_COUNTERS.get(event)
+        if name:
+            tracing.count(name)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
 
 
 class JaxBackend(Backend):
@@ -187,6 +273,10 @@ class JaxBackend(Backend):
                     f"worker {rank} sees {r['global_devices']} global devices"
                     f", rank 0 sees {expect}"
                 )
+
+    def on_training_start(self, worker_group: WorkerGroup,
+                          backend_config: JaxConfig):
+        worker_group.execute(_listen_to_jax)
 
     def on_shutdown(self, worker_group: WorkerGroup,
                     backend_config: JaxConfig):

@@ -22,6 +22,7 @@ from ray_tpu.train import session as session_mod
 from ray_tpu.train.backend import BackendConfig, JaxConfig
 from ray_tpu.train.session import TrainContext
 from ray_tpu.train.worker_group import WorkerGroup
+from ray_tpu.util import tracing
 
 
 class TrainingWorkerError(RuntimeError):
@@ -52,6 +53,10 @@ class BackendExecutor:
         self._train_fn: Optional[Callable] = None
         self._train_config: Optional[dict] = None
         self._dataset_splitter: Optional[Callable] = None
+        # the job timeline's parts: what every worker of every group this
+        # executor ran handed back, and the pids the raylet's hops join by
+        self.timelines: List[Dict[str, Any]] = []
+        self.worker_pids: List[int] = []
 
     # ------------------------------------------------------------------
 
@@ -62,6 +67,7 @@ class BackendExecutor:
         self.worker_group = WorkerGroup(
             self._num_workers, self._resources_per_worker, env_vars=env_vars
         )
+        self.worker_pids += self.worker_group.pids
         try:
             self._backend.on_start(self.worker_group, self._backend_config)
         except BaseException:
@@ -79,6 +85,13 @@ class BackendExecutor:
         self._train_fn = train_fn
         self._train_config = config
         self._dataset_splitter = dataset_splitter
+        # shipping loop, config and shards; the session threads up
+        with tracing.timeline_span("train.start_session"):
+            self._start_sessions(train_fn, config, checkpoint,
+                                 dataset_splitter)
+
+    def _start_sessions(self, train_fn, config, checkpoint,
+                        dataset_splitter):
         self._backend.on_training_start(self.worker_group,
                                         self._backend_config)
         shards_per_rank: List[Optional[Dict[str, Any]]] = [None] * len(
@@ -141,19 +154,33 @@ class BackendExecutor:
         self.shutdown(graceful=False)
         self.start()
 
-    def finish_sessions(self):
-        if self.worker_group is not None:
+    def finish_sessions(self, timeout: float = 30):
+        """End every session that still answers within ``timeout`` and keep
+        the part of the job timeline each hands back."""
+        if self.worker_group is None:
+            return
+        try:
+            futures = [w.end_session.remote()
+                       for w in self.worker_group.workers]
+            ready, _ = ray_tpu.wait(futures, num_returns=len(futures),
+                                    timeout=timeout)
+        except Exception:  # noqa: BLE001
+            return
+        for future in ready:
             try:
-                ray_tpu.get([w.end_session.remote()
-                             for w in self.worker_group.workers], timeout=30)
-            except Exception:  # noqa: BLE001
-                pass
+                part = ray_tpu.get(future)
+            except Exception:  # noqa: BLE001 - a dead worker's part is lost
+                continue
+            if part:
+                self.timelines.append(part)
 
     def shutdown(self, graceful: bool = True):
         if self.worker_group is None:
             return
+        # a failed group is asked too, briefly: the workers that still
+        # answer hand back their part of the job timeline
+        self.finish_sessions(timeout=30 if graceful else 3)
         if graceful:
-            self.finish_sessions()
             try:
                 self._backend.on_shutdown(self.worker_group,
                                           self._backend_config)

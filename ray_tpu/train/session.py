@@ -16,6 +16,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional
 
 from ray_tpu.air.checkpoint import Checkpoint
+from ray_tpu.util import tracing
 
 REPORT = "report"
 FINISHED = "finished"
@@ -42,6 +43,10 @@ class _TrainSession:
         self._result_q: "queue.Queue" = queue.Queue(maxsize=1)
         self._consumed = threading.Event()
         self._dataset_shards: Dict[str, Any] = {}
+        # the session is made inside the trainer's `start_session` call:
+        # the loop's thread parents its spans under that call's context
+        self._trace_ctx = tracing.timeline_ctx()
+        self._reports = 0
         self._thread = threading.Thread(
             target=self._run, args=(train_fn, config),
             name=f"train-session-rank{context.world_rank}", daemon=True,
@@ -62,10 +67,12 @@ class _TrainSession:
                     train_fn).parameters) >= 1
             except (TypeError, ValueError):
                 pass
-            if takes_config:
-                train_fn(config if config is not None else {})
-            else:
-                train_fn()
+            with tracing.timeline_span("train.loop", parent=self._trace_ctx,
+                                       rank=self.context.world_rank):
+                if takes_config:
+                    train_fn(config if config is not None else {})
+                else:
+                    train_fn()
         except BaseException as e:  # noqa: BLE001
             self._result_q.put((ERROR, (e, traceback.format_exc())))
             return
@@ -75,11 +82,16 @@ class _TrainSession:
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None):
-        self._consumed.clear()
-        self._result_q.put((REPORT, (metrics, checkpoint)))
-        # Lockstep: wait until the driver drained this round before
-        # producing the next (reference blocks on a bounded queue too).
-        self._consumed.wait()
+        # put -> consumed: the time the loop is blocked on the trainer
+        with tracing.timeline_span("train.report", n=self._reports,
+                                   checkpoint=checkpoint is not None):
+            self._consumed.clear()
+            self._result_q.put((REPORT, (metrics, checkpoint)))
+            # Lockstep: wait until the driver drained this round before
+            # producing the next (reference blocks on a bounded queue too).
+            self._consumed.wait()
+        self._reports += 1
+        tracing.count("train.reports")
 
     # ---------------------------------------------------------------- driver side
 

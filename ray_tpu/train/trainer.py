@@ -12,6 +12,8 @@ points the other way, which keeps the stack usable without Tune).
 
 from __future__ import annotations
 
+import json
+import os
 import time
 from typing import Any, Callable, Dict, Optional
 
@@ -24,10 +26,39 @@ from ray_tpu.train.backend_executor import (
     BackendExecutor,
     TrainingWorkerError,
 )
+from ray_tpu.util import tracing
 
 
 class TrainingFailedError(RuntimeError):
     pass
+
+
+def _write_timeline(exp_dir: str, trace_id: str, executor: BackendExecutor):
+    """``<exp_dir>/timeline.json``: ``{"spans", "counters", "dropped"}``,
+    the spans as the tracing layer records them
+    (``trace_analysis.to_chrome_trace`` turns them into a Perfetto file).
+    This process's part (the driver's spans and, in single-node mode, the
+    raylet's worker spawns, joined by pid), the parts the workers handed
+    back, and what a raylet in a process of its own put into the GCS trace
+    table under the job's trace.  Never fails the job."""
+    try:
+        parts = [tracing.timeline_take(trace_id, executor.worker_pids)]
+        parts += executor.timelines
+        try:
+            from ray_tpu.util import state
+
+            parts.append({"spans": [
+                sp for sp in state.get_trace(trace_id)["spans"]
+                if sp["name"] == "raylet.worker_spawn"]})
+        except Exception:  # noqa: BLE001 - no runtime, no table
+            pass
+        os.makedirs(exp_dir, exist_ok=True)
+        tmp = os.path.join(exp_dir, f".timeline.{os.getpid()}.tmp")
+        with open(tmp, "w") as f:
+            json.dump(tracing.timeline_merge(parts), f)
+        os.replace(tmp, os.path.join(exp_dir, "timeline.json"))
+    except Exception:  # noqa: BLE001
+        pass
 
 
 class DataParallelTrainer:
@@ -109,10 +140,14 @@ class DataParallelTrainer:
         return split
 
     def fit(self) -> Result:
+        """Runs the job, and leaves its timeline in the run directory
+        (``<Result.path>/timeline.json``) whatever ``RAY_TPU_TRACE`` says
+        and however the job ends: the root span ``train.fit`` and, beneath
+        it, what the driver, the raylet and the workers spent the wall
+        time on (README, "Tracing")."""
         sc = self.scaling_config
         rc = self.run_config
         exp_dir = rc.resolved_storage_path()
-        ckpt_mgr = CheckpointManager(exp_dir, rc.checkpoint_config)
 
         if isinstance(self._backend_config, JaxConfig) and \
                 sc.devices_per_worker and \
@@ -125,6 +160,24 @@ class DataParallelTrainer:
             resources_per_worker=sc._resources_per_worker_not_none,
             experiment_name=rc.name or "",
         )
+        job = tracing.timeline_span("train.fit", root=True,
+                                    experiment=rc.name or "",
+                                    workers=sc.num_workers)
+        try:
+            with job:
+                result = self._run(executor, exp_dir)
+                if result.error is not None:
+                    job.set_error(repr(result.error))
+        finally:
+            _write_timeline(exp_dir, job.trace_id, executor)
+        if result.error is not None and \
+                not isinstance(result.error, TrainingFailedError):
+            raise result.error
+        return result
+
+    def _run(self, executor: BackendExecutor, exp_dir: str) -> Result:
+        rc = self.run_config
+        ckpt_mgr = CheckpointManager(exp_dir, rc.checkpoint_config)
         max_failures = rc.failure_config.max_failures
         failures = 0
         latest_checkpoint: Optional[Checkpoint] = self._resume_from_checkpoint
@@ -144,7 +197,9 @@ class DataParallelTrainer:
                             dataset_splitter=self._dataset_splitter(),
                         )
                         started = True
-                    round_results = executor.get_next_results()
+                    # the driver's side of one lockstep round
+                    with tracing.timeline_span("train.round"):
+                        round_results = executor.get_next_results()
                 except TrainingWorkerError as e:
                     failures += 1
                     if max_failures >= 0 and failures > max_failures:
@@ -158,7 +213,10 @@ class DataParallelTrainer:
                     latest_checkpoint = (ckpt_mgr.latest.checkpoint
                                          if ckpt_mgr.latest
                                          else latest_checkpoint)
-                    executor.restart()
+                    with tracing.timeline_span("train.restart",
+                                               failures=failures,
+                                               cause=str(e)[:200]):
+                        executor.restart()
                     started = False
                     continue
                 if round_results is None:
@@ -170,14 +228,16 @@ class DataParallelTrainer:
                 ckpt = next((r["checkpoint"] for r in round_results
                              if r["checkpoint"] is not None), None)
                 if ckpt is not None:
-                    tracked = ckpt_mgr.register(ckpt, last_metrics)
+                    # bookkeeping that delays the next round
+                    with tracing.timeline_span("train.checkpoint_register"):
+                        tracked = ckpt_mgr.register(ckpt, last_metrics)
                     latest_checkpoint = tracked.checkpoint
         except BaseException as e:  # noqa: BLE001 - user loop error
             error = e
         finally:
             executor.shutdown(graceful=error is None)
 
-        result = Result(
+        return Result(
             metrics=last_metrics,
             checkpoint=ckpt_mgr.latest.checkpoint if ckpt_mgr.latest
             else latest_checkpoint,
@@ -185,9 +245,6 @@ class DataParallelTrainer:
             metrics_history=metrics_history,
             path=exp_dir,
         )
-        if error is not None and not isinstance(error, TrainingFailedError):
-            raise error
-        return result
 
     # Tune integration: a trainer is convertible to a trainable function.
     def as_trainable(self) -> Callable:

@@ -19,6 +19,7 @@ from ray_tpu.train.session import (
     _init_session,
     _shutdown_session,
 )
+from ray_tpu.util import tracing
 
 
 class RayTrainWorker:
@@ -58,12 +59,18 @@ class RayTrainWorker:
         return self._session.get_next()
 
     def end_session(self):
+        """Ends the session; returns this process's part of the job's
+        timeline (``tracing.timeline_take``: spans, counters, drops)."""
         s = self._session
         self._session = None
         _shutdown_session()
         if s is not None:
             s.finish()
-        return True
+        # the group is killed next: what the master switch exports must
+        # not wait for the flusher's next tick
+        tracing.flush_spans()
+        ctx = tracing.timeline_ctx()
+        return tracing.timeline_take(ctx["trace_id"]) if ctx else None
 
 
 class WorkerGroup:
@@ -89,27 +96,32 @@ class WorkerGroup:
             PlacementGroupSchedulingStrategy,
         )
 
-        self._pg = placement_group(
-            [dict(resources_per_worker) for _ in range(num_workers)],
-            strategy=placement_strategy,
-        )
-        ray_tpu.get(self._pg.ready(), timeout=120)
+        with tracing.timeline_span("train.placement", bundles=num_workers):
+            self._pg = placement_group(
+                [dict(resources_per_worker) for _ in range(num_workers)],
+                strategy=placement_strategy,
+            )
+            ray_tpu.get(self._pg.ready(), timeout=120)
         actor_cls = ray_tpu.remote(RayTrainWorker)
-        self.workers = [
-            actor_cls.options(
-                num_cpus=num_cpus,
-                num_tpus=num_tpus,
-                resources=opts or None,
-                runtime_env=runtime_env,
-                scheduling_strategy=PlacementGroupSchedulingStrategy(
-                    placement_group=self._pg,
-                    placement_group_bundle_index=rank,
-                ),
-            ).remote()
-            for rank in range(num_workers)
-        ]
-        # Fail fast if any worker can't come up.
-        ray_tpu.get([w.node_info.remote() for w in self.workers], timeout=120)
+        with tracing.timeline_span("train.workers_up", workers=num_workers):
+            self.workers = [
+                actor_cls.options(
+                    num_cpus=num_cpus,
+                    num_tpus=num_tpus,
+                    resources=opts or None,
+                    runtime_env=runtime_env,
+                    scheduling_strategy=PlacementGroupSchedulingStrategy(
+                        placement_group=self._pg,
+                        placement_group_bundle_index=rank,
+                    ),
+                ).remote()
+                for rank in range(num_workers)
+            ]
+            # Fail fast if any worker can't come up.
+            info = ray_tpu.get([w.node_info.remote() for w in self.workers],
+                               timeout=120)
+        # the raylet's `raylet.worker_spawn` hops join the job by these
+        self.pids = [i["pid"] for i in info]
 
     def __len__(self):
         return self.num_workers

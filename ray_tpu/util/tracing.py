@@ -41,6 +41,16 @@ cadence and post to the GCS.  The legacy per-process JSONL export under
 ``RAY_TPU_TRACE_DIR`` is kept for offline use, now with size-bounded
 rotation.  Span ids use the 128/64-bit hex format so exported spans
 correlate with any surrounding OpenTelemetry spans.
+
+Two things hold whatever ``RAY_TPU_TRACE`` says.  A span that is open in a
+process that has imported jax is also a ``jax.profiler.TraceAnnotation`` of
+the same name, so in a profiler session it sits on the profiler's clock
+beside the device's operations (this module never imports jax itself).
+And a Train job records its own timeline: ``timeline_span`` /
+``timeline_hop`` / ``count`` beneath the root that ``fit()`` opens land in
+a bounded per-process buffer which ``timeline_take`` hands back, and
+``fit()`` writes them to ``<run dir>/timeline.json`` (see "job timeline"
+below).
 """
 
 from __future__ import annotations
@@ -48,8 +58,10 @@ from __future__ import annotations
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
+from collections import deque
 
 from ray_tpu.core.config import config
 from ray_tpu.util.locks import make_lock
@@ -91,7 +103,9 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 __all__ = ["enable_tracing", "tracing_enabled", "span", "maybe_span",
            "current_trace_ctx", "trace_sampled", "emit_span", "hop",
-           "read_spans", "drain_pending", "flush_spans", "set_flush_target"]
+           "read_spans", "drain_pending", "flush_spans", "set_flush_target",
+           "timeline_span", "timeline_hop", "timeline_ctx", "adopt", "count",
+           "counter", "timeline_take", "timeline_merge"]
 
 _ENV = "RAY_TPU_TRACE_DIR"
 
@@ -193,6 +207,19 @@ def set_process_label(label: str):
     _proc_label = label
 
 
+_node = ""
+
+
+def _node_label() -> str:
+    """The hosting node's id as span records carry it.  Set once in a
+    spawned worker's environment, so kept once read there: a registry read
+    costs ~2us and per-step spans pay it every step."""
+    global _node
+    if not _node:
+        _node = config.node_id[:12]
+    return _node
+
+
 def current_trace_ctx() -> Optional[Dict[str, Any]]:
     """The active span's context, for propagation into a TaskSpec."""
     return _current.get()
@@ -253,9 +280,10 @@ def _write_file(line: str):
         _file_bytes += len(line)
 
 
-def _emit(record: dict):
-    """Route one finished span record to the enabled exporters."""
-    if not tracing_enabled():
+def _emit(record: dict, force: bool = False):
+    """Route one finished span record to the enabled exporters (``force``:
+    a job-timeline hop rides the export path with the master switch off)."""
+    if not force and not tracing_enabled():
         return
     if _trace_dir is not None:
         try:
@@ -349,13 +377,36 @@ def _new_span_id() -> str:
     return f"{_rand.getrandbits(64):016x}"
 
 
+_TraceAnnotation = None
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` of ``name`` where this process
+    has already imported jax, else None.  Outside a profiler session an
+    annotation costs an atomic load; inside one the span sits on the
+    profiler's clock under the same name."""
+    global _TraceAnnotation
+    cls = _TraceAnnotation
+    if cls is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        try:
+            cls = _TraceAnnotation = jax.profiler.TraceAnnotation
+        except AttributeError:  # jax is still being imported
+            return None
+    return cls(name)
+
+
 class span:
     """Context manager recording one span; nests via contextvars and
     parents across processes via an explicit ``parent`` ctx dict.  The
     root span makes the head-sampling decision; children inherit it.
     Unsampled spans still mint ids and propagate context (so a later
     ERROR anywhere in the trace exports with real ids) but are not
-    exported unless they fail."""
+    exported unless they fail.  A context that belongs to a job timeline
+    carries ``"timeline": True`` down to every child; only spans made by
+    ``timeline_span`` are recorded there."""
 
     def __init__(self, name: str, parent: Optional[Dict[str, Any]] = None,
                  **attributes: Any):
@@ -366,39 +417,53 @@ class span:
             self.trace_id = explicit["trace_id"]
             self.parent_id = explicit.get("span_id")
             self.sampled = bool(explicit.get("sampled", True))
+            self.in_job = bool(explicit.get("timeline"))
         else:
             self.trace_id = _new_trace_id()
             self.parent_id = None
             self.sampled = trace_sampled(self.trace_id)
+            self.in_job = False
         self.span_id = _new_span_id()
+        self.timeline = False  # record into the job timeline on exit
         self._token = None
         self._t0 = 0.0
 
     @property
     def ctx(self) -> Dict[str, Any]:
-        return {"trace_id": self.trace_id, "span_id": self.span_id,
-                "sampled": self.sampled}
+        ctx = {"trace_id": self.trace_id, "span_id": self.span_id,
+               "sampled": self.sampled}
+        if self.in_job:
+            ctx["timeline"] = True
+        return ctx
 
     def set_error(self, message: str):
         """Mark the span failed without an exception crossing the with
         block (e.g. a task error converted into an error reply)."""
         self._error = message
 
+    def set_attrs(self, **attributes: Any):
+        """Attributes known only once the spanned work is under way."""
+        self.attributes.update(attributes)
+
     def __enter__(self) -> "span":
         self._t0 = time.time()
         self._error: Optional[str] = None
         self._token = _current.set(self.ctx)
+        self._ann = _annotation(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         _current.reset(self._token)
         failed = exc_type is not None or self._error is not None
-        if not self.sampled and not failed:
+        export = (self.sampled or failed) and tracing_enabled()
+        if not export and not self.timeline:
             return False  # head-sampled out; errors always export
-        if not tracing_enabled():
-            return False
         end = time.time()
-        _emit({
+        record = {
             "name": self.name,
             "trace_id": self.trace_id,
             "span_id": self.span_id,
@@ -406,14 +471,18 @@ class span:
             "start_us": int(self._t0 * 1e6),
             "duration_us": int((end - self._t0) * 1e6),
             "pid": os.getpid(),
-            "node": config.node_id[:12],
+            "node": _node_label(),
             "proc": _proc_label,
             "job": _job,
             "status": "ERROR" if failed else "OK",
             **({"error": repr(exc) if exc is not None else self._error}
                if failed else {}),
             "attributes": self.attributes,
-        })
+        }
+        if self.timeline:
+            _timeline_add(record)
+        if export:
+            _emit(record)
         return False
 
 
@@ -422,6 +491,9 @@ class _NullSpan:
         return self
 
     def set_error(self, message: str):
+        pass
+
+    def set_attrs(self, **attributes):
         pass
 
     def __exit__(self, *exc):
@@ -449,7 +521,14 @@ def emit_span(name: str, trace_id: str, parent_id: Optional[str],
     thread, where contextvar nesting is meaningless).  Returns the new
     span id."""
     span_id = _new_span_id()
-    _emit({
+    _emit(_measured(name, trace_id, span_id, parent_id, start, end, status,
+                    error, proc, attributes))
+    return span_id
+
+
+def _measured(name, trace_id, span_id, parent_id, start, end, status, error,
+              proc, attributes) -> dict:
+    return {
         "name": name,
         "trace_id": trace_id,
         "span_id": span_id,
@@ -457,14 +536,13 @@ def emit_span(name: str, trace_id: str, parent_id: Optional[str],
         "start_us": int(start * 1e6),
         "duration_us": max(0, int((end - start) * 1e6)),
         "pid": os.getpid(),
-        "node": config.node_id[:12],
+        "node": _node_label(),
         "proc": proc or _proc_label,
         "job": _job,
         "status": status,
         **({"error": error} if error else {}),
         "attributes": attributes,
-    })
-    return span_id
+    }
 
 
 def hop(name: str, parent: Optional[Dict[str, Any]], start: float,
@@ -489,6 +567,172 @@ def hop(name: str, parent: Optional[Dict[str, Any]], start: float,
                      error=error, proc=proc, **attributes)
 
 
+# ------------------------------------------------------------ job timeline
+#
+# A Train job's own timeline, on in every run: `fit()` opens the root with
+# `timeline_span(..., root=True)`, its context (marked "timeline") rides
+# TaskSpec.trace_ctx to every process that works for the job, and what those
+# processes record with timeline_span / timeline_hop / count waits in the
+# buffers below until `timeline_take` hands it to whoever writes the file.
+# Two bounded buffers a process: lifecycle spans (placement, worker start,
+# chip claim, compiles, restarts: kept oldest-first, a few dozen a start) and
+# a ring of the newest per-step spans; whatever either sheds is counted.
+
+_STEP_SPANS = ("train.report", "train.round", "data.")
+TIMELINE_LIFECYCLE_CAP = 4096
+TIMELINE_STEP_CAP = 4096
+_tl_lock = make_lock("tracing.timeline")
+_tl_lifecycle: deque = deque()  # guard: _tl_lock
+_tl_steps: deque = deque()      # guard: _tl_lock
+_tl_counters: Dict[str, Dict[str, float]] = {}  # guard: _tl_lock; by trace
+_tl_dropped = 0                 # guard: _tl_lock
+
+
+def _timeline_add(record: dict):
+    global _tl_dropped
+    step = record["name"].startswith(_STEP_SPANS)
+    with _tl_lock:
+        buf, cap = ((_tl_steps, TIMELINE_STEP_CAP) if step
+                    else (_tl_lifecycle, TIMELINE_LIFECYCLE_CAP))
+        buf.append(record)
+        if len(buf) > cap:
+            buf.popleft()
+            _tl_dropped += 1
+
+
+def timeline_ctx() -> Optional[Dict[str, Any]]:
+    """The active context if it belongs to a job timeline, else None."""
+    ctx = _current.get()
+    return ctx if ctx is not None and ctx.get("timeline") else None
+
+
+def timeline_span(name: str, parent: Optional[Dict[str, Any]] = None,
+                  root: bool = False, **attributes: Any):
+    """A span of the job timeline: recorded whatever the master switch
+    says (and exported as any span when it is on).  ``root`` opens a job:
+    sampled, under the active context if there is one.  Outside a job it
+    is a ``maybe_span``."""
+    ctx = parent or _current.get()
+    if root:
+        sp = span(name, parent=ctx, **attributes)
+        sp.sampled = sp.in_job = True
+    elif ctx is not None and ctx.get("timeline"):
+        sp = span(name, parent=ctx, **attributes)
+    else:
+        return maybe_span(name, **attributes)
+    sp.timeline = True
+    return sp
+
+
+def timeline_hop(name: str, parent: Optional[Dict[str, Any]], start: float,
+                 end: float, proc: Optional[str] = None,
+                 always_export: bool = False, **attributes: Any) -> str:
+    """A measured span of the job timeline, recorded whatever the master
+    switch says.  Under ``parent`` where the job is known; else a root
+    that stays in this process's buffer, where the job's writer joins it
+    by what the attributes name.  ``always_export`` also puts a job's hop
+    on the export path with the switch off (the raylet's worker spawns, a
+    handful a job: a raylet in a process of its own reaches the job's
+    writer through the GCS trace table)."""
+    in_job = bool(parent and parent.get("timeline"))
+    span_id = _new_span_id()
+    record = _measured(
+        name, parent["trace_id"] if in_job else _new_trace_id(), span_id,
+        parent.get("span_id") if in_job else None, start, end, "OK", None,
+        proc, attributes)
+    _timeline_add(record)
+    if in_job:
+        _emit(record, force=always_export)
+    return span_id
+
+
+class adopt:
+    """Make a propagated context current without opening a span: how a
+    worker that runs with the master switch off still parents its
+    timeline spans under the job."""
+
+    def __init__(self, ctx: Dict[str, Any]):
+        self._ctx = ctx
+
+    def __enter__(self):
+        self._token = _current.set(self._ctx)
+        return self
+
+    def set_error(self, message: str):
+        pass
+
+    def __exit__(self, *exc):
+        _current.reset(self._token)
+        return False
+
+
+def count(name: str, n: float = 1):
+    """Add to a counter of the job whose context is active (no job: a
+    contextvar read and nothing else)."""
+    ctx = _current.get()
+    if ctx is None or not ctx.get("timeline"):
+        return
+    with _tl_lock:
+        counters = _tl_counters.setdefault(ctx["trace_id"], {})
+        counters[name] = counters.get(name, 0) + n
+
+
+def counter(name: str) -> float:
+    """What ``count`` has added under the active job in this process."""
+    ctx = _current.get()
+    if ctx is None:
+        return 0
+    with _tl_lock:
+        return _tl_counters.get(ctx["trace_id"], {}).get(name, 0)
+
+
+def timeline_take(trace_id: str, pids=()) -> Dict[str, Any]:
+    """Remove and return what this process holds of one job:
+    ``{"spans", "counters", "dropped"}``.  Hops that were recorded without
+    the job's context join it by the ``pid`` their attributes name."""
+    global _tl_dropped
+
+    def ours(record):
+        return (record["trace_id"] == trace_id
+                or record["attributes"].get("pid") in pids)
+
+    with _tl_lock:
+        spans = []
+        for buf in (_tl_lifecycle, _tl_steps):
+            held = list(buf)
+            buf.clear()
+            for record in held:
+                (spans if ours(record) else buf).append(record)
+        dropped, _tl_dropped = _tl_dropped, 0
+        return {"spans": spans,
+                "counters": _tl_counters.pop(trace_id, {}),
+                "dropped": dropped}
+
+
+def timeline_merge(parts: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """One job timeline from the parts its processes handed back: spans
+    by start time, once each; per-step spans cut to the newest
+    ``TIMELINE_STEP_CAP``; counters summed; drops counted."""
+    seen, lifecycle, steps = set(), [], []
+    counters: Dict[str, float] = {}
+    dropped = 0
+    for part in parts:
+        dropped += part.get("dropped", 0)
+        for name, value in part.get("counters", {}).items():
+            counters[name] = counters.get(name, 0) + value
+        for record in part.get("spans", ()):
+            if record["span_id"] in seen:
+                continue
+            seen.add(record["span_id"])
+            (steps if record["name"].startswith(_STEP_SPANS)
+             else lifecycle).append(record)
+    steps.sort(key=lambda r: r["start_us"])
+    dropped += max(0, len(steps) - TIMELINE_STEP_CAP)
+    spans = lifecycle + steps[-TIMELINE_STEP_CAP:]
+    spans.sort(key=lambda r: r["start_us"])
+    return {"spans": spans, "counters": counters, "dropped": dropped}
+
+
 # ------------------------------------------------------------- submission
 
 
@@ -504,6 +748,11 @@ def submit_with_span(worker, spec, **attrs):
     export-buffer traffic happens — at RAY_TPU_TRACE_SAMPLE=0.01 the
     other 99% of submits pay only the id mint and this dict."""
     if not tracing_enabled():
+        # a job timeline's context still rides the spec: the worker's
+        # timeline spans parent under it with the master switch off
+        ctx = timeline_ctx()
+        if ctx is not None:
+            spec.trace_ctx = ctx
         return worker.submit_spec(spec)
     parent = _current.get()
     if parent is not None:
@@ -519,8 +768,8 @@ def submit_with_span(worker, spec, **attrs):
                           "sampled": False}
         return worker.submit_spec(spec)
     with span(f"task.submit {spec.name}",
-              parent={"trace_id": trace_id, "span_id": parent_id,
-                      "sampled": True},
+              parent=parent or {"trace_id": trace_id, "span_id": None,
+                                "sampled": True},
               task_id=spec.task_id.hex(), **attrs) as sp:
         spec.trace_ctx = sp.ctx
         refs = worker.submit_spec(spec)

@@ -96,6 +96,151 @@ def _hops(tr):
     return {str(s.get("name", "")).split(" ")[0] for s in tr["spans"]}
 
 
+# ------------------------------------------- spans on the profiler's clock
+
+
+def test_span_is_a_trace_annotation_on_the_profilers_clock(tmp_path):
+    """A span open during a `jax.profiler` session sits, under its own
+    name, on `/host:CPU` of the `.xplane.pb` the session writes."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing.timeline_span("train.fit", root=True) as job:
+            with tracing.timeline_span("train.report", n=0):
+                jnp.ones(8).sum().block_until_ready()
+        with tracing.span("plain.span"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    tracing.timeline_take(job.trace_id)
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    with open(path, "rb") as f:
+        space = jax.profiler.ProfileData.from_serialized_xspace(f.read())
+    host = {e.name for plane in space.planes
+            if plane.name.startswith("/host:CPU")
+            for line in plane.lines for e in line.events}
+    assert {"train.fit", "train.report", "plain.span"} <= host
+
+
+def test_tracing_never_imports_jax():
+    """The benchmark's parent and the raylet must stay off jax: a process
+    that imports the tracing layer and opens every kind of span ends
+    without it."""
+    code = (
+        "import sys\n"
+        "from ray_tpu.util import tracing\n"
+        "tracing.enable_tracing()\n"
+        "with tracing.span('a') as sp:\n"
+        "    with tracing.maybe_span('b'):\n"
+        "        pass\n"
+        "with tracing.timeline_span('train.fit', root=True) as job:\n"
+        "    with tracing.timeline_span('train.report', n=0):\n"
+        "        tracing.count('train.reports')\n"
+        "    tracing.timeline_hop('raylet.worker_spawn', job.ctx, 0, 1)\n"
+        "part = tracing.timeline_take(job.trace_id)\n"
+        "assert len(part['spans']) == 3, part\n"
+        "assert 'jax' not in sys.modules\n"
+        "print('NOJAX')\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=root,
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "NOJAX" in proc.stdout
+
+
+def _many_ops(x):
+    import jax.numpy as jnp
+
+    for _ in range(400):        # long enough to trace to be spanned
+        x = jnp.sin(x) + 1.0
+    return x
+
+
+def test_compile_listener_spans_a_first_call_and_not_a_second(caplog):
+    """What a process pays JAX's tracer, lowering and compiler becomes
+    `jax.trace` / `jax.lower` / `jax.backend_compile` spans of the job; a
+    call served from jit's own cache leaves none; a function that
+    compiles again after the first `train.report` is named once."""
+    import logging
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.train import backend
+
+    backend._listen_to_jax()
+    backend._listen_to_jax()              # once a process
+    # the inputs' own little programs compile outside any job
+    x, x16, x32 = jnp.ones(8), jnp.ones(16), jnp.ones(32)
+    step = jax.jit(_many_ops)
+    with tracing.timeline_span("train.fit", root=True) as job:
+        step(x).block_until_ready()
+        first = tracing.timeline_take(job.trace_id)
+        step(x).block_until_ready()
+        second = tracing.timeline_take(job.trace_id)
+        tracing.count("train.reports")
+        with caplog.at_level(logging.WARNING, logger=backend.__name__):
+            step(x16).block_until_ready()      # a new shape: compiles
+            step(x32).block_until_ready()
+        third = tracing.timeline_take(job.trace_id)
+    tracing.timeline_take(job.trace_id)
+    names = [r["name"] for r in first["spans"]]
+    assert {"jax.trace", "jax.lower", "jax.backend_compile"} <= set(names)
+    assert names.count("jax.backend_compile") == 1
+    compile_span = [r for r in first["spans"]
+                    if r["name"] == "jax.backend_compile"][0]
+    assert "_many_ops" in compile_span["attributes"]["fun_name"]
+    assert compile_span["trace_id"] == job.trace_id
+    assert compile_span["parent_id"] == job.span_id
+    assert compile_span["duration_us"] > 0
+    assert first["counters"]["jax.compiles"] == 1
+    assert first["counters"]["jax.traces"] >= 1
+    assert second == {"spans": [], "counters": {}, "dropped": 0}
+    assert third["counters"]["jax.compiles"] == 2
+    warned = [r for r in caplog.records if "compiled again" in r.message]
+    assert len(warned) == 1 and "_many_ops" in warned[0].getMessage()
+    # outside a job the listener records nothing
+    jax.jit(_many_ops)(jnp.ones(4)).block_until_ready()
+    assert tracing.timeline_take(job.trace_id)["spans"] == []
+
+
+def test_job_timeline_with_the_master_switch_on(traced_gcs, tmp_path):
+    """With `RAY_TPU_TRACE=1` the generic RPC spans parent under the job's
+    trace in the GCS trace table (`ray_tpu trace export` shows the whole
+    job), and the file still holds the job's own spans only."""
+    from ray_tpu.train import JaxConfig, JaxTrainer, RunConfig, ScalingConfig
+
+    def loop(config):
+        from ray_tpu.train import session
+
+        session.report({"step": 0})
+
+    result = JaxTrainer(
+        loop, train_loop_config={},
+        jax_config=JaxConfig(platform="cpu", devices_per_worker=2),
+        scaling_config=ScalingConfig(num_workers=1),
+        run_config=RunConfig(name="switched_on",
+                             storage_path=str(tmp_path))).fit()
+    with open(os.path.join(result.path, "timeline.json")) as f:
+        doc = json.load(f)
+    in_file = {s["name"] for s in doc["spans"]}
+    assert {"train.fit", "train.loop", "train.report",
+            "raylet.worker_spawn"} <= in_file
+    assert not any(n.startswith(("task.", "worker.")) for n in in_file)
+    trace_id = [s for s in doc["spans"]
+                if s["name"] == "train.fit"][0]["trace_id"]
+    want = {"task.submit", "worker.exec", "train.fit", "train.loop",
+            "train.report", "raylet.worker_spawn"}
+    tr = _wait_trace(trace_id, lambda tr: want <= _hops(tr))
+    assert want <= _hops(tr), _hops(tr)
+    assert len({s["span_id"] for s in tr["spans"]}) == len(tr["spans"])
+
+
 # ------------------------------------------------------- legacy two-span
 
 

@@ -1,0 +1,308 @@
+"""The Train job's own timeline: every `fit()` leaves `timeline.json` in
+its run directory, whatever `RAY_TPU_TRACE` says: `train.fit` at the root
+and, beneath it through parent ids across the driver, the raylet and the
+workers, what the job spent its wall time on (README, "Tracing")."""
+
+import functools
+import json
+import os
+
+import numpy as np
+import pytest
+
+from ray_tpu.util import trace_analysis, tracing
+
+SETUP_SPANS = ("train.placement", "train.workers_up", "raylet.worker_spawn",
+               "train.jax_distributed_init", "train.jax_import",
+               "train.chip_claim", "train.start_session")
+
+
+@pytest.fixture(scope="module")
+def ray_train():
+    import ray_tpu
+
+    ray_tpu.init(num_cpus=8)
+    yield ray_tpu
+    ray_tpu.shutdown()
+
+
+def _block(index):
+    return {"x": np.full((8, 4), index, np.int32)}
+
+
+def _two_report_loop(config):
+    """Draws every batch of its shard; reports after the second batch
+    (with a checkpoint) and after the last."""
+    import jax
+
+    from ray_tpu.train import session
+
+    double = jax.jit(lambda x: (x * 2).sum())
+    shard = session.get_dataset_shard("train")
+    total, batches = 0.0, 0
+    for batch in shard.iter_jax_batches(batch_size=4):
+        total += float(double(batch["x"]))
+        batches += 1
+        if batches == 2:
+            session.report(
+                {"batches": batches},
+                checkpoint=session.Checkpoint.from_dict({"batches": 2}))
+    session.report({"batches": batches, "total": total})
+
+
+def _fit(tmp_path, loop, name, workers=1, config=None, max_failures=0,
+         datasets=True):
+    from ray_tpu.data.dataset import Dataset
+    from ray_tpu.train import (
+        FailureConfig,
+        JaxConfig,
+        JaxTrainer,
+        RunConfig,
+        ScalingConfig,
+    )
+
+    ds = Dataset.from_read_fns(
+        [functools.partial(_block, i) for i in range(4)])
+    return JaxTrainer(
+        loop, train_loop_config=config or {},
+        jax_config=JaxConfig(platform="cpu", devices_per_worker=2),
+        scaling_config=ScalingConfig(num_workers=workers),
+        datasets={"train": ds} if datasets else None,
+        run_config=RunConfig(
+            name=name, storage_path=str(tmp_path),
+            failure_config=FailureConfig(max_failures=max_failures)))
+
+
+def _timeline(path):
+    with open(os.path.join(path, "timeline.json")) as f:
+        doc = json.load(f)
+    by_name = {}
+    for record in doc["spans"]:
+        by_name.setdefault(record["name"], []).append(record)
+    return doc, by_name
+
+
+@pytest.fixture(scope="module")
+def two_report_job(ray_train, tmp_path_factory):
+    """One two-worker, two-report fit with a small `from_read_fns` shard
+    each; the tests below read the timeline it left."""
+    result = _fit(tmp_path_factory.mktemp("job"), _two_report_loop,
+                  "two_reports", workers=2).fit()
+    assert result.error is None
+    return result, *_timeline(result.path)
+
+
+def test_fit_leaves_a_timeline_with_every_span_of_the_table(two_report_job):
+    result, doc, by_name = two_report_job
+    assert set(doc) == {"spans", "counters", "dropped"}
+    # every span of ISSUE 25's table that can occur without a chip
+    assert set(by_name) >= {
+        "train.fit", "train.placement", "train.workers_up",
+        "raylet.worker_spawn", "train.jax_distributed_init",
+        "train.start_session", "train.loop", "jax.lower",
+        "jax.backend_compile", "train.report", "train.round",
+        "train.checkpoint_register", "data.block_wait", "data.to_device"}
+    assert "train.chip_claim" not in by_name      # no chip was granted
+    assert len(by_name["train.fit"]) == 1
+    assert len(by_name["train.loop"]) == 2        # one a rank
+    assert len(by_name["train.jax_distributed_init"]) == 2
+    # records as the tracing layer makes them
+    assert set(by_name["train.report"][0]) >= {
+        "name", "trace_id", "span_id", "parent_id", "start_us",
+        "duration_us", "pid", "proc", "status", "attributes"}
+    assert by_name["train.report"][0]["attributes"].keys() == {
+        "n", "checkpoint"}
+    assert {r["attributes"]["ready"] for r in by_name["data.block_wait"]} \
+        <= {True, False}
+    assert all(r["attributes"]["bytes"] == 128
+               for r in by_name["data.block_wait"])
+
+
+def test_timeline_is_one_tree_across_driver_raylet_and_workers(
+        two_report_job):
+    result, doc, by_name = two_report_job
+    fit = by_name["train.fit"][0]
+    by_id = {r["span_id"]: r for r in doc["spans"]}
+    assert {r["trace_id"] for r in doc["spans"]} == {fit["trace_id"]}
+    for record in doc["spans"]:
+        hops = 0
+        while record["parent_id"] is not None:
+            record = by_id[record["parent_id"]]
+            hops += 1
+            assert hops < 16
+        assert record is fit
+    procs = {r["proc"] for r in doc["spans"]}
+    assert procs == {"driver", "raylet", "worker"}
+    worker_pids = {r["pid"] for r in by_name["train.loop"]}
+    assert len(worker_pids) == 2
+    # the raylet's hop names the worker it started, and lies in the
+    # driver's wait for the workers
+    spawned = {r["attributes"]["pid"] for r in by_name["raylet.worker_spawn"]}
+    assert worker_pids <= spawned
+    up = by_name["train.workers_up"][0]
+    for spawn in by_name["raylet.worker_spawn"]:
+        if spawn["attributes"]["pid"] in worker_pids:
+            assert spawn["parent_id"] == up["span_id"]
+    # the loop's thread parents under the call that started its session
+    start = by_name["train.start_session"][0]
+    assert {r["parent_id"] for r in by_name["train.loop"]} == {
+        start["span_id"]}
+    loops = {r["span_id"] for r in by_name["train.loop"]}
+    assert {r["parent_id"] for r in by_name["train.report"]} <= loops
+    assert {r["parent_id"] for r in by_name["data.block_wait"]} <= loops
+
+
+def test_named_spans_cover_fit_to_loop(two_report_job):
+    """fit() called -> the loop entered: at least 90 % of it lies under a
+    named span, so what `spawn_s` times from outside is accounted for."""
+    result, doc, by_name = two_report_job
+    t0 = by_name["train.fit"][0]["start_us"]
+    t1 = min(r["start_us"] for r in by_name["train.loop"])
+    covered, reach = 0, t0
+    for record in sorted((r for name in SETUP_SPANS
+                          for r in by_name.get(name, ())),
+                         key=lambda r: r["start_us"]):
+        start = max(record["start_us"], reach)
+        end = min(record["start_us"] + record["duration_us"], t1)
+        if end > start:
+            covered += end - start
+            reach = end
+    assert covered >= 0.9 * (t1 - t0), (covered, t1 - t0)
+
+
+def test_counters_equal_the_calls_made(two_report_job):
+    result, doc, by_name = two_report_job
+    counters = doc["counters"]
+    # two reports a rank, seen by the trainer as two rounds of reports
+    assert counters["train.reports"] == 4
+    assert len(by_name["train.report"]) == 4
+    assert len(result.metrics_history) == 2
+    assert len(by_name["train.round"]) == 3          # and the finish
+    # the two shards cover the four blocks once, two batches a block
+    assert counters["data.blocks"] == len(by_name["data.block_wait"]) == 4
+    assert 0 <= counters["data.blocks_ready"] <= counters["data.blocks"]
+    assert counters["data.blocks_ready"] == sum(
+        r["attributes"]["ready"] for r in by_name["data.block_wait"])
+    assert counters["data.block_bytes"] == 4 * 128
+    assert counters["data.batches"] == len(by_name["data.to_device"]) == 8
+    assert counters["jax.compiles"] == len(by_name["jax.backend_compile"])
+    assert doc["dropped"] == 0
+
+
+def test_timeline_opens_as_a_perfetto_file_unchanged(two_report_job):
+    result, doc, by_name = two_report_job
+    chrome = trace_analysis.to_chrome_trace(doc["spans"])
+    events = [e for e in chrome["traceEvents"] if e["ph"] == "X"]
+    assert len(events) == len(doc["spans"])
+    lanes = {e["args"]["name"] for e in chrome["traceEvents"]
+             if e["ph"] == "M"}
+    assert any(l.startswith("driver") for l in lanes)
+    assert sum(l.startswith("worker") for l in lanes) == 2
+    json.dumps(chrome)
+    tree = trace_analysis.build_tree(doc["spans"])
+    assert len(tree) == 1 and tree[0]["name"] == "train.fit"
+
+
+def _raising_loop(config):
+    from ray_tpu.train import session
+
+    session.report({"step": 0})
+    raise ValueError("boom in the timeline's loop")
+
+
+def test_a_loop_that_raises_still_leaves_the_file(ray_train, tmp_path):
+    trainer = _fit(tmp_path, _raising_loop, "raises", datasets=False)
+    with pytest.raises(Exception, match="boom in the timeline's loop"):
+        trainer.fit()
+    doc, by_name = _timeline(os.path.join(str(tmp_path), "raises"))
+    fit, loop = by_name["train.fit"][0], by_name["train.loop"][0]
+    assert fit["status"] == "ERROR" and "boom" in fit["error"]
+    # the worker still answered `end_session`: its part is there
+    assert loop["status"] == "ERROR" and "boom" in loop["error"]
+    assert doc["counters"]["train.reports"] == 1
+    assert len(by_name["train.report"]) == 1
+
+
+def _crash_once_loop(config):
+    import os
+
+    from ray_tpu.train import session
+
+    session.report({"step": 0})
+    if not os.path.exists(config["marker"]):
+        open(config["marker"], "w").close()
+        os._exit(1)                     # the worker dies: a group restart
+    session.report({"step": 1})
+
+
+def test_a_forced_restart_shows_one_train_restart(ray_train, tmp_path):
+    marker = str(tmp_path / "crashed")
+    result = _fit(tmp_path, _crash_once_loop, "restarts", datasets=False,
+                  config={"marker": marker}, max_failures=1).fit()
+    assert result.error is None and os.path.exists(marker)
+    doc, by_name = _timeline(result.path)
+    assert len(by_name["train.restart"]) == 1
+    restart = by_name["train.restart"][0]
+    assert restart["attributes"]["failures"] == 1
+    assert restart["attributes"]["cause"]
+    assert restart["parent_id"] == by_name["train.fit"][0]["span_id"]
+    # both groups' starts are kept; the dead worker's own part is lost
+    assert len(by_name["train.workers_up"]) == 2
+    assert len(by_name["train.start_session"]) == 2
+    assert len(by_name["raylet.worker_spawn"]) == 2
+    assert len(by_name["train.loop"]) == 1
+    # the second group's start lies inside the restart
+    second = by_name["train.workers_up"][1]
+    assert second["parent_id"] == restart["span_id"]
+
+
+def test_buffers_are_bounded_and_count_what_they_drop(monkeypatch):
+    monkeypatch.setattr(tracing, "TIMELINE_STEP_CAP", 8)
+    monkeypatch.setattr(tracing, "TIMELINE_LIFECYCLE_CAP", 4)
+    with tracing._tl_lock:      # what earlier jobs of this process left
+        tracing._tl_lifecycle.clear()
+        tracing._tl_steps.clear()
+        tracing._tl_dropped = 0
+    with tracing.timeline_span("train.fit", root=True) as job:
+        for i in range(20):
+            with tracing.timeline_span("train.report", n=i):
+                pass
+        for i in range(6):
+            with tracing.timeline_span("train.restart", failures=i):
+                pass
+        tracing.count("train.reports", 20)
+    part = tracing.timeline_take(job.trace_id)
+    names = [r["name"] for r in part["spans"]]
+    # the newest per-step spans, and the lifecycle list at its cap
+    assert [r["attributes"]["n"] for r in part["spans"]
+            if r["name"] == "train.report"] == list(range(12, 20))
+    assert names.count("train.restart") + names.count("train.fit") == 4
+    assert part["dropped"] == 12 + 3
+    assert part["counters"] == {"train.reports": 20}
+    # taken means gone
+    assert tracing.timeline_take(job.trace_id) == {
+        "spans": [], "counters": {}, "dropped": 0}
+    # the merge of the parts cuts the per-step spans again, and counts
+    merged = tracing.timeline_merge([part, part, {"spans": [dict(
+        part["spans"][-1], span_id=f"s{i}", name="train.round",
+        start_us=i) for i in range(10)], "dropped": 2}])
+    assert len([r for r in merged["spans"]
+                if r["name"].startswith(("train.report", "train.round"))]) \
+        == 8
+    assert merged["dropped"] == 2 * 15 + 2 + 10
+    assert merged["counters"] == {"train.reports": 40}
+
+
+def test_outside_a_job_nothing_is_recorded(ray_train):
+    """Data and Train code paths run outside `fit()` too: no job, no
+    record, and `timeline_span` is a no-op span."""
+    from ray_tpu.data.dataset import Dataset
+
+    before = len(tracing._tl_steps) + len(tracing._tl_lifecycle)
+    ds = Dataset.from_read_fns(
+        [functools.partial(_block, i) for i in range(2)])
+    assert sum(len(b["x"]) for b in ds.iter_batches(batch_size=4)) == 16
+    assert tracing.timeline_span("data.block_wait") is tracing._NULL_SPAN
+    tracing.count("data.blocks")
+    assert len(tracing._tl_steps) + len(tracing._tl_lifecycle) == before
+    assert tracing._tl_counters == {}
