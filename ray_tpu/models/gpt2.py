@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.flash_attention import flash_attention_bshd
+from ray_tpu.ops.moe import moe_dispatch
 
 
 @dataclass(frozen=True)
@@ -40,11 +41,10 @@ class GPT2Config:
     remat: bool = False      # jax.checkpoint each block (trade FLOPs for HBM)
     # MoE (expert parallelism, SURVEY §2.6 row "EP"): >0 swaps every
     # block's dense FFN for a top-k routed mixture; expert weights carry a
-    # leading "expert" dim that ShardingConfig places on the ep axis (XLA
-    # SPMD emits the all_to_all dispatch).
+    # leading "expert" dim that ShardingConfig places on the ep axis; the
+    # dispatch is dropless (`ops/moe.py`): no capacity, no token dropped.
     moe_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity_factor: float = 1.5
     moe_aux_weight: float = 0.01
 
     @property
@@ -170,48 +170,32 @@ def _mlp(x, p):
 
 
 def _moe_mlp(x, p, cfg: GPT2Config):
-    """Top-k routed mixture-of-experts FFN (GShard/Switch-style capacity
-    dispatch; SURVEY §2.6 row "EP").  Expert weights carry a leading
-    expert dim; sharded on the ep mesh axis the dispatch/combine einsums
-    lower to all_to_all under the XLA SPMD partitioner.  The dense
-    (T, n_exp, C) dispatch tensors are fine at the capacities used here;
-    a sort-based dispatch is the optimization path for very long
-    sequences.  Returns (y, aux_load_balancing_loss)."""
+    """Top-k routed mixture-of-experts FFN (SURVEY §2.6 row "EP"), dropless
+    (`ops/moe.py:moe_dispatch`): the (token, expert) rows sorted by expert,
+    two grouped matmuls with a GELU between, the rows summed back with
+    their renormalised gate values.  Expert weights carry a leading expert
+    dim that `ShardingConfig` places on the ep axis.  Returns
+    (y, aux_load_balancing_loss)."""
     B, S, E = x.shape
-    T = B * S
-    k = cfg.moe_top_k
-    n_exp = cfg.moe_experts
-    xt = x.reshape(T, E)
+    xt = x.reshape(B * S, E)
     router_logits = (xt @ p["router"]["kernel"].astype(x.dtype)
                      ).astype(jnp.float32)                      # (T, n_exp)
     probs = jax.nn.softmax(router_logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)               # (T, k)
+    gate_vals, gate_idx = jax.lax.top_k(probs, cfg.moe_top_k)   # (T, k)
     gate_vals = gate_vals / (jnp.sum(gate_vals, -1, keepdims=True) + 1e-9)
-    capacity = max(k, int(cfg.moe_capacity_factor * T * k / n_exp))
-    mask = jax.nn.one_hot(gate_idx, n_exp, dtype=jnp.float32)   # (T, k, n)
-    # slot positions: earlier tokens and lower-k choices win capacity
-    positions = []
-    counts = jnp.zeros((n_exp,), jnp.float32)
-    for j in range(k):
-        mj = mask[:, j]                                         # (T, n)
-        positions.append(jnp.cumsum(mj, axis=0) - 1 + counts)
-        counts = counts + jnp.sum(mj, axis=0)
-    pos = jnp.stack(positions, axis=1)                          # (T, k, n)
-    keep = mask * (pos < capacity)
-    slot = jax.nn.one_hot(pos.astype(jnp.int32), capacity,
-                          dtype=jnp.float32)                    # (T,k,n,C)
-    dispatch = jnp.einsum("tkn,tknc->tnc", keep, slot)
-    combine = jnp.einsum("tk,tkn,tknc->tnc", gate_vals, keep, slot)
-    expert_in = jnp.einsum("te,tnc->nce", xt,
-                           dispatch.astype(x.dtype))            # (n, C, E)
-    h = jax.nn.gelu(jnp.einsum("nce,neh->nch", expert_in,
-                               p["wi"].astype(x.dtype)))
-    expert_out = jnp.einsum("nch,nhe->nce", h, p["wo"].astype(x.dtype))
-    y = jnp.einsum("nce,tnc->te", expert_out, combine.astype(x.dtype))
+    wi, wo = p["wi"].astype(x.dtype), p["wo"].astype(x.dtype)
+
+    def gelu_experts(xs, group_sizes):
+        h = jax.nn.gelu(jax.lax.ragged_dot(xs, wi, group_sizes))
+        return jax.lax.ragged_dot(h, wo, group_sizes)
+
+    y, _ = moe_dispatch(xt, gate_vals, gate_idx, cfg.moe_experts,
+                        gelu_experts)
     # load-balancing aux (Switch eq. 4): fraction routed x router prob
-    frac = jnp.mean(mask[:, 0], axis=0)
+    frac = jnp.mean(jax.nn.one_hot(gate_idx[:, 0], cfg.moe_experts,
+                                   dtype=jnp.float32), axis=0)
     importance = jnp.mean(probs, axis=0)
-    aux = n_exp * jnp.sum(frac * importance)
+    aux = cfg.moe_experts * jnp.sum(frac * importance)
     return y.reshape(B, S, E), aux
 
 

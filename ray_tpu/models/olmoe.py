@@ -1,0 +1,263 @@
+"""OLMoE — a routed mixture-of-experts decoder for the Train path.
+
+Muennighoff et al., "OLMoE: Open Mixture-of-Experts Language Models"
+(arXiv:2409.02060); layer equations as `allenai/OLMoE-1B-7B-0125`'s public
+`modeling_olmoe.py`:
+
+  h = x + Attn(RMSNorm(x));  y = h + MoE(RMSNorm(h));  final RMSNorm; an
+  untied head.  Attention: q, k, v without bias, RMSNorm over the whole q
+  and k projections (before the split into heads), rotate-half RoPE on all
+  of a head's dimensions, causal softmax at head_dim^-1/2.  MoE: a softmax
+  router over all experts in float32, the top k of it as weights (not
+  renormalised), every expert a SiLU-gated feed-forward, no shared expert
+  and no token dropped.  Trained on cross-entropy + a load-balancing loss
+  + a router z-loss.
+
+It is the first model here that needs both halves of what the repo had:
+`llama.py`'s RMSNorm and RoPE, and `gpt2.py`'s training surface (f32 master
+parameters cast once, the chunked loss, the flash kernels under a mesh,
+logical dimensions for `parallel/sharding.py`).  They are imported, not
+copied.  The experts run dropless (`ops/moe.py:moe_dispatch`): the (token,
+expert) rows are sorted by expert and each group is multiplied by its
+expert with `jax.lax.ragged_dot`, XLA:TPU's grouped-matmul kernel.
+
+`jax.named_scope`s name the parts: attention, route, dispatch, experts,
+combine, head_and_loss, optimizer_update.
+"""
+
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.gpt2 import _cast_weights, _chunked_xent, num_params  # noqa: F401
+from ray_tpu.models.llama import _rms_norm, _rope
+from ray_tpu.ops.flash_attention import flash_attention_bshd
+from ray_tpu.ops.moe import moe_dispatch
+
+
+@dataclass(frozen=True)
+class OlmoeConfig:
+    vocab_size: int = 50304
+    max_seq: int = 4096
+    n_layer: int = 16
+    n_head: int = 16
+    n_embd: int = 2048
+    expert_width: int = 1024
+    n_experts: int = 64
+    top_k: int = 8
+    rope_theta: float = 10000.0
+    rms_eps: float = 1e-5
+    aux_weight: float = 0.01      # load balancing (the paper's alpha)
+    z_weight: float = 0.001       # router z-loss (the paper's beta)
+    compute_dtype: Any = jnp.bfloat16
+    remat: bool = False           # jax.checkpoint each layer
+    # rows of the head's logits alive at once (`gpt2._chunked_xent`): at
+    # 16,384 x 50,304 the whole of them and their gradient are 6.6 GB
+    loss_chunk_rows: int = 2048
+
+    @property
+    def head_dim(self) -> int:
+        return self.n_embd // self.n_head
+
+
+OLMOE_1B_7B = OlmoeConfig()
+OLMOE_TINY = OlmoeConfig(vocab_size=512, max_seq=64, n_layer=2, n_head=4,
+                         n_embd=64, expert_width=32, n_experts=8, top_k=2,
+                         loss_chunk_rows=32)
+
+
+def init_params(rng, cfg: OlmoeConfig) -> Dict[str, Any]:
+    """Normal(0, 0.02) matrices (`initializer_range` of the published
+    config), unit norm scales.  Names are those `parallel/sharding.py:
+    infer_param_logical_dims` lays out: the experts' stacks are
+    ("expert", "embed", "mlp") / ("expert", "mlp", "embed")."""
+    std = 0.02
+    E, W, N = cfg.n_embd, cfg.expert_width, cfg.n_experts
+    keys = jax.random.split(rng, 2 + cfg.n_layer)
+
+    def normal(key, shape):
+        return jax.random.normal(key, shape, jnp.float32) * std
+
+    def scale():
+        return {"scale": jnp.ones((E,), jnp.float32)}
+
+    params = {
+        "embed_tokens": {"embedding": normal(keys[0], (cfg.vocab_size, E))},
+        "norm_f": scale(),
+        "lm_head": {"kernel": normal(keys[1], (E, cfg.vocab_size))},
+    }
+    for i in range(cfg.n_layer):
+        ks = jax.random.split(keys[2 + i], 8)
+        params[f"layer_{i}"] = {
+            "input_norm": scale(),
+            "attn": {
+                "q_proj": {"kernel": normal(ks[0], (E, E))},
+                "k_proj": {"kernel": normal(ks[1], (E, E))},
+                "v_proj": {"kernel": normal(ks[2], (E, E))},
+                "o_proj": {"kernel": normal(ks[3], (E, E))},
+                "q_norm": scale(),
+                "k_norm": scale(),
+            },
+            "post_norm": scale(),
+            "moe": {
+                "router": {"kernel": normal(ks[4], (E, N))},
+                "wi_gate": normal(ks[5], (N, E, W)),
+                "wi_up": normal(ks[6], (N, E, W)),
+                "wo": normal(ks[7], (N, W, E)),
+            },
+        }
+    return params
+
+
+def _attention(x, p, cfg: OlmoeConfig):
+    B, S, E = x.shape
+    H, D = cfg.n_head, cfg.head_dim
+    kernel = lambda name: p[name]["kernel"].astype(x.dtype)
+    q = _rms_norm(x @ kernel("q_proj"), p["q_norm"], cfg.rms_eps)
+    k = _rms_norm(x @ kernel("k_proj"), p["k_norm"], cfg.rms_eps)
+    v = x @ kernel("v_proj")
+    positions = jnp.arange(S)
+    q = _rope(q.reshape(B, S, H, D), positions, cfg.rope_theta)
+    k = _rope(k.reshape(B, S, H, D), positions, cfg.rope_theta)
+    v = v.reshape(B, S, H, D)
+    # as gpt2._attention: under a bound mesh each device runs the kernel
+    # on its batch/head slice
+    from ray_tpu.parallel.context import get_mesh
+
+    mesh = get_mesh()
+    if mesh is None or mesh.size == 1:
+        o = flash_attention_bshd(q, k, v, True)
+    else:
+        from ray_tpu.parallel.ring_attention import flash_attention_sharded
+
+        o = flash_attention_sharded(q, k, v, mesh, causal=True)
+    return o.reshape(B, S, E) @ kernel("o_proj")
+
+
+def _gated_experts(wi_gate, wi_up, wo):
+    """The experts' SiLU-gated feed-forward over rows sorted by expert:
+    three grouped matmuls over the ragged groups."""
+    def run(xs, group_sizes):
+        gate = jax.lax.ragged_dot(xs, wi_gate, group_sizes)
+        up = jax.lax.ragged_dot(xs, wi_up, group_sizes)
+        return jax.lax.ragged_dot(jax.nn.silu(gate) * up, wo, group_sizes)
+    return run
+
+
+def _moe(x, p, cfg: OlmoeConfig):
+    """-> (y, {load-balancing loss, router z-loss, most rows an expert
+    got}) for x of shape (B, S, E)."""
+    B, S, E = x.shape
+    xt = x.reshape(B * S, E)
+    with jax.named_scope("route"):
+        logits = (xt @ p["router"]["kernel"].astype(x.dtype)
+                  ).astype(jnp.float32)                       # (T, N)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, cfg.top_k)    # (T, k)
+    y, group_sizes = moe_dispatch(
+        xt, weights, experts, cfg.n_experts,
+        _gated_experts(p["wi_gate"], p["wi_up"], p["wo"]))
+    with jax.named_scope("route"):
+        # f: each expert's share of the T*k assignments (a count: no
+        # gradient); P: its mean router probability
+        share = group_sizes.astype(jnp.float32) / (B * S * cfg.top_k)
+        balance = cfg.n_experts * jnp.sum(share * jnp.mean(probs, axis=0))
+        z = jnp.mean(jnp.square(jax.scipy.special.logsumexp(logits, -1)))
+    return y.reshape(B, S, E), {"aux_loss": balance, "z_loss": z,
+                                "max_expert_rows": jnp.max(group_sizes)}
+
+
+def _layer(x, p, cfg: OlmoeConfig):
+    with jax.named_scope("attention"):
+        x = x + _attention(_rms_norm(x, p["input_norm"], cfg.rms_eps),
+                           p["attn"], cfg)
+    with jax.named_scope("moe"):
+        y, stats = _moe(_rms_norm(x, p["post_norm"], cfg.rms_eps),
+                        p["moe"], cfg)
+    return x + y, stats
+
+
+def _trunk(params, tokens, cfg: OlmoeConfig):
+    """-> ((B, S, E) after the final norm, the routers' statistics: the
+    auxiliary losses averaged over the layers, the fullest expert of
+    any)."""
+    x = params["embed_tokens"]["embedding"][tokens].astype(cfg.compute_dtype)
+    layer = jax.checkpoint(_layer, static_argnums=(2,)) if cfg.remat \
+        else _layer
+    stats = []
+    for i in range(cfg.n_layer):
+        x, s = layer(x, params[f"layer_{i}"], cfg)
+        stats.append(s)
+    mean = lambda key: sum(s[key] for s in stats) / len(stats)
+    return _rms_norm(x, params["norm_f"], cfg.rms_eps), {
+        "aux_loss": mean("aux_loss"), "z_loss": mean("z_loss"),
+        "max_expert_rows": functools.reduce(
+            jnp.maximum, [s["max_expert_rows"] for s in stats])}
+
+
+def forward(params, tokens, cfg: OlmoeConfig):
+    """tokens (B, S) int32 -> (logits (B, S, vocab) f32, routers'
+    statistics)."""
+    x, stats = _trunk(params, tokens, cfg)
+    head = params["lm_head"]["kernel"].astype(cfg.compute_dtype)
+    return jnp.matmul(x, head, preferred_element_type=jnp.float32), stats
+
+
+def loss_fn(params, batch, cfg: OlmoeConfig):
+    """batch {"tokens": (B, S+1)} -> (the objective that is
+    differentiated, its parts).  The objective is next-token cross-entropy
+    + aux_weight x load balancing + z_weight x router z-loss; `parts`
+    holds the cross-entropy as "loss", the two auxiliary losses and the
+    fullest expert's rows.  The head's logits are made
+    `cfg.loss_chunk_rows` rows at a time and never all held."""
+    tokens = batch["tokens"]
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    x, stats = _trunk(params, inputs, cfg)
+    B, S, E = x.shape
+    with jax.named_scope("head_and_loss"):
+        head = params["lm_head"]["kernel"].astype(cfg.compute_dtype)
+        total = _chunked_xent(x.reshape(B * S, E), head.T,
+                              targets.reshape(B * S),
+                              -(-B * S // cfg.loss_chunk_rows))
+        xent = total / (B * S)
+    objective = (xent + cfg.aux_weight * stats["aux_loss"]
+                 + cfg.z_weight * stats["z_loss"])
+    return objective, dict(stats, loss=xent)
+
+
+def make_train_step(cfg: OlmoeConfig, optimizer):
+    """train_step(params, opt_state, batch) -> (params, opt_state, out),
+    to be jitted with its shardings and `donate_argnums=(0, 1)` as
+    `gpt2.make_train_step`'s.  `out["loss"]` is the cross-entropy alone;
+    `out` also carries "aux_loss", "z_loss" and "max_expert_rows", device
+    scalars that cost nothing unless fetched.  Mixed precision as GPT-2's:
+    f32 master parameters, cast once to `cfg.compute_dtype`."""
+
+    def train_step(params, opt_state, batch):
+        def objective(p):
+            return loss_fn(_cast_weights(p, cfg.compute_dtype), batch, cfg)
+
+        (_, out), grads = jax.value_and_grad(objective, has_aux=True)(params)
+        with jax.named_scope("optimizer_update"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = jax.tree.map(lambda p, u: p + u, params, updates)
+        return params, opt_state, out
+
+    return train_step
+
+
+def count_flops_per_token(cfg: OlmoeConfig, seq_len: int) -> float:
+    """Training (forward + backward) operations per token: 6 N + 12 L E S
+    as `gpt2.count_flops_per_token`, N the parameters a token multiplies:
+    the head, and per layer the four attention matrices, the router and
+    the token's top_k experts (three matrices each), not all of them."""
+    E = cfg.n_embd
+    per_layer = (4 * E * E + E * cfg.n_experts
+                 + cfg.top_k * 3 * E * cfg.expert_width)
+    n = cfg.vocab_size * E + cfg.n_layer * per_layer
+    return 6 * n + 12 * cfg.n_layer * E * seq_len

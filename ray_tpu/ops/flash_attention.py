@@ -676,10 +676,20 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     return o, (q, k, v, o, lse)
 
 
+# Largest tile of the two-kernel backward (blocks that are not the whole
+# sequence); the fused whole-sequence backward is not held to it.
+_SPLIT_BWD_MAX_BLOCK = 512
+
+
 def _flash_bwd(causal, sm_scale, block_q, block_k, res, do, delta=None):
     q, k, v, o, lse = res
     S = q.shape[2]
     scale, bq, bk = _resolve(q, S, sm_scale, block_q, block_k)
+    if not bq == bk == S:
+        # the two-kernel split holds s, p, dp and ds of one (bq, bk) tile
+        # at once: at 1024 x 1024 they pass the 16 MB of scoped VMEM
+        # (Mosaic refuses the dk/dv kernel at S = 4096, D = 128)
+        bq, bk = min(bq, _SPLIT_BWD_MAX_BLOCK), min(bk, _SPLIT_BWD_MAX_BLOCK)
     # delta = rowsum(do * o): cheap elementwise+reduce, XLA fuses it.
     # Callers looping over K/V chunks (ring attention) pass it precomputed
     # — it only depends on the q side, so per-chunk recompute is waste.
