@@ -12,6 +12,11 @@ pretraining").  Design choices for the MXU/HBM:
     `ray_tpu/parallel/sharding.py` so `ShardingConfig` can place every leaf
     (wte → (vocab, embed), c_attn → (embed, heads), mlp c_proj →
     (mlp, embed), ...).
+  * activations state their logical dims (`constrain`): the residual
+    stream and LayerNorm outputs ("batch", "seq", None), qkv on "heads",
+    the MLP's hidden on "mlp", the logits on "vocab".  Under a bound mesh
+    the same rules pin them, so the batch stays cut and `fsdp` gathers
+    each weight at its use; with no mesh, or one device, nothing is added.
   * static shapes everywhere; the whole train step jits to one XLA program.
 """
 
@@ -26,6 +31,7 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.flash_attention import flash_attention_bshd
 from ray_tpu.ops.moe import moe_dispatch
+from ray_tpu.parallel.sharding import constrain
 
 
 @dataclass(frozen=True)
@@ -124,6 +130,7 @@ def _attention(x, p, cfg: GPT2Config, mesh=None):
     B, S, E = x.shape
     H, D = cfg.n_head, cfg.head_dim
     qkv = x @ p["c_attn"]["kernel"].astype(x.dtype) + p["c_attn"]["bias"].astype(x.dtype)
+    qkv = constrain(qkv, "batch", "seq", "heads")
     q, k, v = jnp.split(qkv, 3, axis=-1)
     q = q.reshape(B, S, H, D)
     k = k.reshape(B, S, H, D)
@@ -165,7 +172,7 @@ def _attention(x, p, cfg: GPT2Config, mesh=None):
 
 def _mlp(x, p):
     h = x @ p["c_fc"]["kernel"].astype(x.dtype) + p["c_fc"]["bias"].astype(x.dtype)
-    h = jax.nn.gelu(h)
+    h = jax.nn.gelu(constrain(h, "batch", "seq", "mlp"))
     return h @ p["c_proj"]["kernel"].astype(x.dtype) + p["c_proj"]["bias"].astype(x.dtype)
 
 
@@ -199,16 +206,26 @@ def _moe_mlp(x, p, cfg: GPT2Config):
     return y.reshape(B, S, E), aux
 
 
+def _residual(x):
+    """The residual stream and what LayerNorm makes of it: cut by batch
+    and sequence, whole in the width."""
+    return constrain(x, "batch", "seq", None)
+
+
 def _block(x, p, cfg: GPT2Config, aux_acc=None):
-    x = x + _attention(_layer_norm(x, p["ln_1"]), p["attn"], cfg)
+    # stated at the block's entry (and not at its exit), so that a
+    # `jax.checkpoint` of it recomputes the forward pass under the same pins
+    x = _residual(x)
+    x = _residual(x + _attention(_residual(_layer_norm(x, p["ln_1"])),
+                                 p["attn"], cfg))
+    h = _residual(_layer_norm(x, p["ln_2"]))
     if "moe" in p:
-        y, aux = _moe_mlp(_layer_norm(x, p["ln_2"]), p["moe"], cfg)
+        y, aux = _moe_mlp(h, p["moe"], cfg)
         if aux_acc is not None:
             aux_acc.append(aux)
-        x = x + y
     else:
-        x = x + _mlp(_layer_norm(x, p["ln_2"]), p["mlp"])
-    return x
+        y = _mlp(h, p["mlp"])
+    return x + y
 
 
 def to_pipeline_params(params, cfg: GPT2Config):
@@ -234,7 +251,8 @@ def _trunk(params, tokens, cfg: GPT2Config, aux_acc=None,
     S = tokens.shape[1]
     x = (params["wte"]["embedding"][tokens]
          + params["wpe"]["embedding"][:S][None])
-    x = x.astype(cfg.compute_dtype)
+    x = _residual(x.astype(cfg.compute_dtype))
+
     def block_with_aux(h, p):
         acc: list = []
         h2 = _block(h, p, cfg, acc)
@@ -263,8 +281,8 @@ def _trunk(params, tokens, cfg: GPT2Config, aux_acc=None,
     else:
         for i in range(cfg.n_layer):
             x = _block(x, params[f"h_{i}"], cfg, aux_acc)
-    x = _layer_norm(x.astype(jnp.float32), params["ln_f"])
-    return x.astype(cfg.compute_dtype)
+    x = _layer_norm(_residual(x.astype(jnp.float32)), params["ln_f"])
+    return _residual(x.astype(cfg.compute_dtype))
 
 
 def forward(params, tokens, cfg: GPT2Config, aux_acc=None,
@@ -275,7 +293,12 @@ def forward(params, tokens, cfg: GPT2Config, aux_acc=None,
     # model FLOPs at the slow f32 MXU rate) with an f32 accumulate/output
     # so the softmax sees full-precision logits.
     wte = params["wte"]["embedding"].astype(cfg.compute_dtype)
-    return jnp.matmul(x, wte.T, preferred_element_type=jnp.float32)
+    return _logits(x, wte)
+
+
+def _logits(x, wte):
+    return constrain(jnp.matmul(x, wte.T, preferred_element_type=jnp.float32),
+                     "batch", "seq", "vocab")
 
 
 def _chunked_xent(x, wte, targets, n_chunks: int):
@@ -338,7 +361,7 @@ def loss_fn(params, batch, cfg: GPT2Config, pp_microbatches: int = 2,
         loss = total / (B * S)
     else:
         # dense path: materialize logits (faster when HBM is not tight)
-        logits = jnp.matmul(x, wte.T, preferred_element_type=jnp.float32)
+        logits = _logits(x, wte)
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
         tgt = jnp.take_along_axis(logits, targets[..., None],
                                   axis=-1)[..., 0]

@@ -7,6 +7,20 @@ the reference lacks natively (TP/PP/SP/EP; SURVEY.md §2.6): here they are
 first-class axis sizes, and "wrapping a model" becomes assigning
 `NamedSharding`s to a pytree of params by logical-dimension rules.
 
+Parameters are placed by their names (`infer_param_logical_dims`).
+Activations are placed where the model says what their dims are:
+`constrain(x, "batch", "seq", "mlp")` inside the traced step pins `x` by the
+same rules, under the mesh `use_mesh` bound.  `models/gpt2.py` states the
+residual stream and both LayerNorm outputs ("batch", "seq", None), qkv
+("batch", "seq", "heads"), the MLP's hidden ("batch", "seq", "mlp") and the
+logits ("batch", "seq", "vocab").  Earlier dims win a mesh axis, so under
+`fsdp=4` all of these are `P("fsdp", None, None)`: the batch stays cut, and
+the partitioner has to gather each `embed → fsdp` weight at its use (FSDP).
+A model that does not state them leaves the activations' layout to the
+partitioner, which may as well keep the weights' cut on the contraction
+dim and all-reduce full-batch partial products (GPT-2 XL before PR 29:
+210 MB a layer, three times).
+
 Logical dims used by the bundled models (ray_tpu/models/*):
   "batch"   → (dp, fsdp)     activations' leading dim
   "seq"     → sp             sequence dim of activations
@@ -21,13 +35,16 @@ Logical dims used by the bundled models (ray_tpu/models/*):
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_tpu.parallel.context import get_mesh
 from ray_tpu.parallel.mesh import create_mesh
+from ray_tpu.util import tracing
 
 DEFAULT_RULES: Dict[str, Any] = {
     "batch": ("dp", "fsdp"),
@@ -65,35 +82,8 @@ class ShardingConfig:
 
     # ------------------------------------------------------------------
 
-    def _resolve(self, logical: Optional[str], mesh: Mesh):
-        axis = self.rules.get(logical, None)
-        if axis is None:
-            return None
-        if isinstance(axis, (tuple, list)):
-            present = tuple(a for a in axis if a in mesh.shape and mesh.shape[a] > 1)
-            if not present:
-                return None
-            return present if len(present) > 1 else present[0]
-        if axis in mesh.shape and mesh.shape[axis] > 1:
-            return axis
-        return None
-
     def spec(self, mesh: Mesh, *logical_dims: Optional[str]) -> P:
-        # A mesh axis may appear only once in a PartitionSpec; earlier dims
-        # win (so "batch" on (dp, fsdp) suppresses "embed" on fsdp for
-        # activations — params without a batch dim still shard on fsdp).
-        used: set = set()
-        parts = []
-        for d in logical_dims:
-            axis = self._resolve(d, mesh)
-            if axis is None:
-                parts.append(None)
-                continue
-            axes = axis if isinstance(axis, tuple) else (axis,)
-            axes = tuple(a for a in axes if a not in used)
-            used.update(axes)
-            parts.append(axes if len(axes) > 1 else (axes[0] if axes else None))
-        return P(*parts)
+        return logical_spec(mesh, logical_dims, self.rules)
 
     def named_sharding(self, mesh: Mesh, *logical_dims) -> NamedSharding:
         return NamedSharding(mesh, self.spec(mesh, *logical_dims))
@@ -106,11 +96,50 @@ class ShardingConfig:
             is_leaf=lambda x: isinstance(x, tuple),
         )
 
-    def constraint(self, x, mesh: Mesh, *logical_dims):
-        """with_sharding_constraint by logical dims (inside jit)."""
-        return jax.lax.with_sharding_constraint(
-            x, self.named_sharding(mesh, *logical_dims)
-        )
+
+def _axes(mesh, logical_dims, rules):
+    """Per logical dim, the mesh axes (those larger than 1) its rule cuts it
+    on.  A mesh axis may appear only once in a PartitionSpec; earlier dims
+    win (so "batch" on (dp, fsdp) suppresses "embed" on fsdp for
+    activations — params without a batch dim still shard on fsdp)."""
+    used: set = set()
+    out = []
+    for d in logical_dims:
+        axis = rules.get(d)
+        axes = axis if isinstance(axis, (tuple, list)) else (axis,)
+        axes = tuple(a for a in axes
+                     if mesh.shape.get(a, 1) > 1 and a not in used)
+        used.update(axes)
+        out.append(axes)
+    return out
+
+
+def logical_spec(mesh, logical_dims, rules=DEFAULT_RULES) -> P:
+    """PartitionSpec of logical dims on a mesh."""
+    return P(*(axes if len(axes) > 1 else (axes[0] if axes else None)
+               for axes in _axes(mesh, logical_dims, rules)))
+
+
+def constrain(x, *logical_dims):
+    """Pin an activation to where `DEFAULT_RULES` put its logical dims on
+    the mesh `use_mesh` bound: `with_sharding_constraint` inside the traced
+    step.  `x` comes back untouched, with nothing added to the jaxpr, when
+    no mesh is bound or it has one device, when some dim's size does not
+    divide by its axes, and inside a `shard_map` (the pipeline's stages: a
+    full-mesh constraint cannot name the axis that is manual there, so the
+    stage's inside stays with the partitioner).  What it does depends on
+    the mesh alone; each pin counts once, at trace time, on the job's
+    timeline (`parallel.constraints`)."""
+    mesh = get_mesh()
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return x
+    for size, axes in zip(x.shape, _axes(mesh, logical_dims, DEFAULT_RULES)):
+        if size % math.prod(mesh.shape[a] for a in axes):
+            return x
+    tracing.count("parallel.constraints")
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, logical_spec(mesh, logical_dims)))
 
 
 def infer_param_logical_dims(path: Tuple[str, ...], shape: Tuple[int, ...]):

@@ -1,0 +1,166 @@
+"""A benchmark configuration's train step, compiled for the v5e without a
+chip: what each chip holds and which collectives the partitioner put in.
+
+    python tools/aot_collectives.py gpt2-xl-fsdp4 [--layers 2]
+
+Reads `benchmark/configs/<name>.json`, binds the configuration's family to
+the described devices of `v5e:2x2` (a one-chip configuration: the first of
+them), gives `lower_step` the state's shapes with the shardings `init_state` would
+give the arrays, and compiles.  Prints arguments and scratch space per
+chip, then every collective once per `channel_id` (XLA prints an
+asynchronous one several times) grouped by kind and result shape, with the
+bytes of one and of all.  `--layers` compiles a shallower model: the
+collectives of one layer are those of every layer, in a tenth of the time
+(all 48 of XL take four minutes here).  Nothing runs: no time, no result.
+"""
+
+from __future__ import annotations
+
+import argparse
+import collections
+import json
+import math
+import os
+import re
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+KINDS = ("all-reduce", "all-gather", "all-to-all", "reduce-scatter",
+         "collective-permute")
+_WIDTH = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
+          "u8": 1, "pred": 1, "f64": 8, "s64": 8, "u64": 8}
+_SHAPE = re.compile(r"\b([a-z]+\d*)\[([\d,]*)\]")
+_OP = re.compile(r"=\s*(\(?[^=]*?)\s(" + "|".join(KINDS) + r")(-start)?\(")
+_COMMENT = re.compile(r"/\*.*?\*/")
+# groups of one member each, in either notation: nothing crosses a wire
+_ALONE = re.compile(r"replica_groups=(\{\{\d+\}(,\{\d+\})*\}|\[\d+,1\]<=)")
+
+
+def collectives(hlo_text: str) -> dict:
+    """{(kind, "dtype[shape], ..."): (count, bytes of one)} over the distinct
+    `channel_id`s of a compiled module's text (one whose groups have one
+    member each is left out: `shard_map`'s `psum` over an axis of size 1
+    on the CPU).  The shape is the
+    instruction's result; an asynchronous gather or permute carries its
+    operands beside its results (and two `u32[]` of context), so only the
+    second half of what is left of its tuple counts."""
+    seen, found = set(), {}
+    for line in hlo_text.splitlines():
+        line = _COMMENT.sub("", line)
+        op = _OP.search(line)
+        channel = re.search(r"channel_id=(\d+)", line)
+        if (not op or not channel or channel.group(1) in seen
+                or _ALONE.search(line)):
+            continue
+        seen.add(channel.group(1))
+        shapes = [s for s in _SHAPE.findall(op.group(1)) if s != ("u32", "")]
+        if op.group(3) and op.group(2) != "all-reduce" and len(shapes) > 1:
+            shapes = shapes[len(shapes) // 2:]
+        size = sum(_WIDTH.get(t, 4) * math.prod(int(n) for n in d.split(",")
+                                                 if n) for t, d in shapes)
+        # a combined collective's tuple repeats one shape many times over
+        key = (op.group(2), ", ".join(
+            f"{t}[{d}]" + (f"*{n}" if n > 1 else "")
+            for (t, d), n in collections.Counter(shapes).items()))
+        count, _ = found.get(key, (0, size))
+        found[key] = (count + 1, size)
+    return found
+
+
+def state_shapes(family):
+    """(params, opt_state) as ShapeDtypeStructs placed as
+    `Family.init_state` places the arrays: parameters by the layout's
+    rules, each optimizer leaf beside the parameter whose path ends its
+    own, whatever else (the step count) everywhere."""
+    import jax
+
+    from ray_tpu.parallel.sharding import param_shardings
+
+    key = jax.random.PRNGKey(0)
+    shapes = jax.eval_shape(family._init, key)
+    placed = param_shardings(shapes, family.layout, family.mesh)
+    by_path = {path: s for path, s in
+               jax.tree_util.tree_flatten_with_path(placed)[0]}
+    everywhere = family.layout.named_sharding(family.mesh)
+
+    def beside(path):
+        for start in range(len(path)):
+            if path[start:] in by_path:
+                return by_path[path[start:]]
+        return everywhere
+
+    def struct(leaf, sharding):
+        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sharding)
+
+    params = jax.tree.map(struct, shapes, placed)
+    opt = jax.eval_shape(family.optimizer().init, shapes)
+    opt = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: struct(leaf, beside(path)), opt)
+    return params, opt
+
+
+def compile_step(config: dict, traffic: dict):
+    """The configuration's step compiled for the described chips."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+
+    from benchmark.harness import registry
+
+    chips = config["chips"]
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    family = registry.family(config)
+    family.bind(topo.devices[:chips])
+    params, opt = state_shapes(family)
+    tokens = jax.ShapeDtypeStruct(
+        (traffic["batch"], traffic["seq"] + 1), jnp.int32,
+        sharding=family.layout.named_sharding(family.mesh, "batch", None))
+    return family.lower_step(params, opt, {"tokens": tokens}).compile()
+
+
+def main():
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("config", help="a name under benchmark/configs/")
+    parser.add_argument("--traffic", default=None,
+                        help="a name under benchmark/traffic/ (default: "
+                             "that of the configuration's first cell)")
+    parser.add_argument("--layers", type=int, default=None)
+    parser.add_argument("--hlo", default=None,
+                        help="write the compiled module's text here")
+    args = parser.parse_args()
+
+    from benchmark.harness import registry
+
+    config = registry.config(args.config)
+    traffic = args.traffic or next(
+        w["traffic"] for w in registry.benchmark()["workloads"]
+        if w["config"] == args.config)
+    if args.layers:
+        depth = "n_layer" if "n_layer" in config else "num_hidden_layers"
+        config[depth] = args.layers
+    compiled = compile_step(config, registry.traffic(traffic))
+    text = compiled.as_text()
+    if args.hlo:
+        with open(args.hlo, "w") as f:
+            f.write(text)
+    memory = compiled.memory_analysis()
+    gib = 2.0 ** 30
+    print(f"{args.config} x {traffic}, {config['chips']} chip(s), per chip: "
+          f"arguments {memory.argument_size_in_bytes / gib:.3f} GiB, scratch "
+          f"{memory.temp_size_in_bytes / gib:.3f} GiB, output "
+          f"{memory.output_size_in_bytes / gib:.3f} GiB (aliased "
+          f"{memory.alias_size_in_bytes / gib:.3f})")
+    found = collectives(text)
+    for (kind, shape), (count, size) in sorted(
+            found.items(), key=lambda kv: -kv[1][0] * kv[1][1]):
+        print(f"{count:5d} x {kind:18s} {shape:60s} {size / 1e6:10.2f} MB "
+              f"each {count * size / 1e6:10.1f} MB")
+    print(json.dumps({"collectives": sum(c for c, _ in found.values()),
+                      "bytes": sum(c * s for c, s in found.values())}))
+
+
+if __name__ == "__main__":
+    main()
