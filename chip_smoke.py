@@ -48,6 +48,7 @@ def train_loop(config):
     from ray_tpu import train
     from ray_tpu.core import protocol
     from ray_tpu.models import gpt2
+    from ray_tpu.models.layers import cast_weights
     from ray_tpu.ops import flash_attention as fa
     from ray_tpu.parallel.context import use_mesh
     from ray_tpu.parallel.sharding import ShardingConfig, param_shardings
@@ -108,7 +109,7 @@ def train_loop(config):
     # shard_map (taken now: the first step donates the parameters)
     dense = replace(cfg, attention="dense")
     report["reference_loss0"] = float(jax.jit(
-        lambda p, b: gpt2.loss_fn(gpt2._cast_weights(p, cfg.compute_dtype),
+        lambda p, b: gpt2.loss_fn(cast_weights(p, cfg.compute_dtype),
                                   b, dense))(params, data))
 
     with use_mesh(mesh):

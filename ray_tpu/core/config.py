@@ -281,11 +281,6 @@ config.define("alerts_default_rules", bool, True,
               "shed-ratio burn rate, telemetry drop counters).  0 leaves "
               "only RAY_TPU_ALERTS_RULES rules active.")
 
-# --- tensor plane -----------------------------------------------------------
-config.define("mesh_default_axes", str, "dp,tp", "")
-config.define("enable_pallas", bool, True,
-              "Use Pallas kernels on TPU when shapes allow.")
-
 # --- process identity (live: set by a parent in the child's environment) ----
 config.define("address", str, "",
               "Cluster address auto-attached by ray_tpu.init() when no "

@@ -5,9 +5,9 @@ pretraining").  Design choices for the MXU/HBM:
 
   * params stay f32 (optimizer quality), activations/matmuls run bf16
     (`compute_dtype`) — MXU native.
-  * attention goes through the Pallas flash kernel
-    (`ray_tpu/ops/flash_attention.py`); sequence-parallel configs swap in
-    ring attention (`ray_tpu/parallel/ring_attention.py`) under shard_map.
+  * attention goes through `ray_tpu/parallel/attention.py`: the Pallas
+    flash kernel (`ray_tpu/ops/flash_attention.py`), under a bound mesh
+    inside a shard_map; sequence-parallel configs take ring attention.
   * param names follow the logical-dim heuristics in
     `ray_tpu/parallel/sharding.py` so `ShardingConfig` can place every leaf
     (wte → (vocab, embed), c_attn → (embed, heads), mlp c_proj →
@@ -29,8 +29,9 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.flash_attention import flash_attention_bshd
+from ray_tpu.models.layers import layer_norm, train_step
 from ray_tpu.ops.moe import moe_dispatch
+from ray_tpu.parallel.attention import attention
 from ray_tpu.parallel.sharding import constrain
 
 
@@ -41,7 +42,6 @@ class GPT2Config:
     n_layer: int = 12
     n_head: int = 12
     n_embd: int = 768
-    dropout: float = 0.0
     compute_dtype: Any = jnp.bfloat16
     attention: str = "flash"  # flash | ring | ulysses | dense
     remat: bool = False      # jax.checkpoint each block (trade FLOPs for HBM)
@@ -115,57 +115,14 @@ def init_params(rng, cfg: GPT2Config) -> Dict[str, Any]:
     return params
 
 
-def _layer_norm(x, p, eps=1e-5):
-    """Stats in f32 for stability; output CAST BACK to the input dtype —
-    the f32 scale/bias would otherwise silently promote the residual
-    stream (and every downstream matmul) to the MXU's slow f32 path."""
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
-    y = (xf - mu) * jax.lax.rsqrt(var + eps)
-    return (y * p["scale"] + p["bias"]).astype(x.dtype)
-
-
-def _attention(x, p, cfg: GPT2Config, mesh=None):
+def _attention(x, p, cfg: GPT2Config):
     B, S, E = x.shape
     H, D = cfg.n_head, cfg.head_dim
     qkv = x @ p["c_attn"]["kernel"].astype(x.dtype) + p["c_attn"]["bias"].astype(x.dtype)
     qkv = constrain(qkv, "batch", "seq", "heads")
     q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(B, S, H, D)
-    k = k.reshape(B, S, H, D)
-    v = v.reshape(B, S, H, D)
-    if cfg.attention in ("ring", "ulysses"):
-        # sequence parallelism: shard_map over the bound mesh's sp axis
-        # (head-major layout — the ring rotates (B, H, Sq, D) chunks)
-        from ray_tpu.parallel.context import require_mesh
-        from ray_tpu.parallel.ring_attention import ring_attention_sharded
-
-        o = ring_attention_sharded(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), require_mesh(), causal=True,
-            variant=cfg.attention).transpose(0, 2, 1, 3)
-    elif cfg.attention == "dense":
-        from ray_tpu.ops.flash_attention import _reference_attention
-
-        o, _ = _reference_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), D ** -0.5, True)
-        o = o.astype(x.dtype).transpose(0, 2, 1, 3)
-    else:
-        # layout-native kernel: no (B,S,H,D) <-> (B,H,S,D) transposes;
-        # under a bound mesh each device runs it on its batch/head slice
-        from ray_tpu.parallel.context import get_mesh
-
-        mesh = get_mesh()
-        if mesh is None or mesh.size == 1:
-            o = flash_attention_bshd(q, k, v, True)
-        else:
-            from ray_tpu.parallel.ring_attention import (
-                flash_attention_sharded,
-            )
-
-            o = flash_attention_sharded(q, k, v, mesh, causal=True)
+    o = attention(q.reshape(B, S, H, D), k.reshape(B, S, H, D),
+                  v.reshape(B, S, H, D), variant=cfg.attention)
     o = o.reshape(B, S, E)
     return o @ p["c_proj"]["kernel"].astype(x.dtype) + p["c_proj"]["bias"].astype(x.dtype)
 
@@ -212,20 +169,19 @@ def _residual(x):
     return constrain(x, "batch", "seq", None)
 
 
-def _block(x, p, cfg: GPT2Config, aux_acc=None):
+def _block(x, p, cfg: GPT2Config):
+    """-> (x, the mixture's load-balancing loss; zero for a dense block)."""
     # stated at the block's entry (and not at its exit), so that a
     # `jax.checkpoint` of it recomputes the forward pass under the same pins
     x = _residual(x)
-    x = _residual(x + _attention(_residual(_layer_norm(x, p["ln_1"])),
+    x = _residual(x + _attention(_residual(layer_norm(x, p["ln_1"])),
                                  p["attn"], cfg))
-    h = _residual(_layer_norm(x, p["ln_2"]))
+    h = _residual(layer_norm(x, p["ln_2"]))
     if "moe" in p:
         y, aux = _moe_mlp(h, p["moe"], cfg)
-        if aux_acc is not None:
-            aux_acc.append(aux)
     else:
-        y = _mlp(h, p["mlp"])
-    return x + y
+        y, aux = _mlp(h, p["mlp"]), jnp.zeros((), jnp.float32)
+    return x + y, aux
 
 
 def to_pipeline_params(params, cfg: GPT2Config):
@@ -241,57 +197,48 @@ def to_pipeline_params(params, cfg: GPT2Config):
     return out
 
 
-def _trunk(params, tokens, cfg: GPT2Config, aux_acc=None,
-           pp_microbatches: int = 2):
-    """Embedding + transformer blocks + final LN -> (B, S, E) in
-    compute_dtype (the LN itself runs f32 for stability).  With stacked
-    ``blocks`` params (see to_pipeline_params) the block stack runs as a
-    pipeline over the mesh pp axis; MoE aux loss rides the stage handoff
-    as a scalar carry lane (averaged over microbatches)."""
+def _trunk(params, tokens, cfg: GPT2Config, pp_microbatches: int = 2):
+    """Embedding + transformer blocks + final LN -> ((B, S, E) in
+    compute_dtype (the LN itself runs f32 for stability), the blocks'
+    auxiliary loss averaged over the layers).  With stacked ``blocks``
+    params (see to_pipeline_params) the block stack runs as a pipeline over
+    the mesh pp axis; MoE aux loss rides the stage handoff as a scalar
+    carry lane (averaged over microbatches)."""
     S = tokens.shape[1]
     x = (params["wte"]["embedding"][tokens]
          + params["wpe"]["embedding"][:S][None])
     x = _residual(x.astype(cfg.compute_dtype))
 
-    def block_with_aux(h, p):
-        acc: list = []
-        h2 = _block(h, p, cfg, acc)
-        aux = acc[0] if acc else jnp.zeros((), jnp.float32)
-        return h2, aux
+    def block(h, p):
+        return _block(h, p, cfg)
 
     if "blocks" in params:
         from ray_tpu.parallel.context import require_mesh
         from ray_tpu.parallel.pipeline import pipeline_apply
 
-        # MoE aux rides the stage handoff as a scalar carry lane; the
-        # pipeline returns sum-over-layers of the per-microbatch-mean aux,
-        # so dividing by n_layer matches the sequential path's
-        # sum(aux_acc)/len(aux_acc).
-        x, pp_aux = pipeline_apply(
-            lambda p, h: block_with_aux(h, p),
+        # the pipeline returns sum-over-layers of the per-microbatch-mean
+        # aux, so dividing by n_layer matches the sequential path's mean
+        x, aux = pipeline_apply(
+            lambda p, h: block(h, p),
             params["blocks"], x, require_mesh(), pp_microbatches)
-        if aux_acc is not None and cfg.moe_experts > 0:
-            aux_acc.append(pp_aux / cfg.n_layer)
-    elif cfg.remat:
-        rblock = jax.checkpoint(block_with_aux)
-        for i in range(cfg.n_layer):
-            x, aux = rblock(x, params[f"h_{i}"])
-            if aux_acc is not None and cfg.moe_experts > 0:
-                aux_acc.append(aux)
+        aux = aux / cfg.n_layer
     else:
+        layer = jax.checkpoint(block) if cfg.remat else block
+        auxes = []
         for i in range(cfg.n_layer):
-            x = _block(x, params[f"h_{i}"], cfg, aux_acc)
-    x = _layer_norm(_residual(x.astype(jnp.float32)), params["ln_f"])
-    return _residual(x.astype(cfg.compute_dtype))
+            x, aux = layer(x, params[f"h_{i}"])
+            auxes.append(aux)
+        aux = sum(auxes) / len(auxes)
+    x = layer_norm(_residual(x.astype(jnp.float32)), params["ln_f"])
+    return _residual(x.astype(cfg.compute_dtype)), aux
 
 
-def forward(params, tokens, cfg: GPT2Config, aux_acc=None,
-            pp_microbatches: int = 2):
+def forward(params, tokens, cfg: GPT2Config, pp_microbatches: int = 2):
     """tokens (B, S) int32 -> logits (B, S, vocab) f32."""
-    x = _trunk(params, tokens, cfg, aux_acc, pp_microbatches)
-    # Tied lm head: bf16 operands on the MXU (an f32 head costs ~30% of
-    # model FLOPs at the slow f32 MXU rate) with an f32 accumulate/output
-    # so the softmax sees full-precision logits.
+    x, _ = _trunk(params, tokens, cfg, pp_microbatches)
+    # Tied lm head: bf16 operands on the MXU (an f32 head would run the
+    # model's largest matmul at the slow f32 MXU rate) with an f32
+    # accumulate/output so the softmax sees full-precision logits.
     wte = params["wte"]["embedding"].astype(cfg.compute_dtype)
     return _logits(x, wte)
 
@@ -301,117 +248,33 @@ def _logits(x, wte):
                      "batch", "seq", "vocab")
 
 
-def _chunked_xent(x, wte, targets, n_chunks: int):
-    """Fused linear + softmax cross-entropy, chunked over tokens.
-
-    The naive path materializes (B*S, V) f32 logits in HBM twice (forward
-    residual + backward read) — ~3.3 GB at B=16, S=1024, V=50257, which
-    dominates step time for a 124M model.  Instead: scan over token chunks,
-    each chunk computing logits -> (lse, target-logit) under
-    ``jax.checkpoint`` so the backward pass RECOMPUTES the chunk's logits
-    and immediately contracts d_logits into (dx, dwte) — the full logits
-    tensor never exists in HBM in either pass.  (Same idea as fused
-    linear-cross-entropy kernels; here XLA fuses the chunk, no Pallas
-    needed.)
-
-    x: (N, E) compute-dtype; wte: (V, E); targets: (N,) int32.
-    Returns summed loss (f32).
-    """
-    N, E = x.shape
-    n_chunks = max(1, min(n_chunks, N))
-    while N % n_chunks:
-        n_chunks -= 1
-    xc = x.reshape(n_chunks, N // n_chunks, E)
-    tc = targets.reshape(n_chunks, N // n_chunks)
-
-    @jax.checkpoint
-    def chunk(carry, xt):
-        xi, ti = xt
-        logits = jnp.matmul(xi, wte.T,
-                            preferred_element_type=jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, ti[:, None], axis=-1)[:, 0]
-        return carry + jnp.sum(lse - tgt), None
-
-    total, _ = jax.lax.scan(chunk, jnp.zeros((), jnp.float32), (xc, tc))
-    return total
-
-
-def loss_fn(params, batch, cfg: GPT2Config, pp_microbatches: int = 2,
-            xent_chunks: int = 0):
+def loss_fn(params, batch, cfg: GPT2Config, pp_microbatches: int = 2):
     """batch: {"tokens": (B, S+1)} — next-token cross entropy (+ MoE
-    load-balancing aux when the model is a mixture).
-
-    ``xent_chunks=0`` (default) materializes logits densely — measured
-    FASTER on v5e at the 124M/seq-1024 bench shape, where HBM is not
-    tight.  ``xent_chunks>0`` switches to the chunked rematerialized
-    fused head (``_chunked_xent``) that never materializes (B, S, V)
-    logits — for long-sequence / big-batch configs where the ~3 GB+
-    logits tensor would evict everything else (it wins at B=32 already).
-    """
+    load-balancing aux when the model is a mixture)."""
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    aux_acc: list = []
-    x = _trunk(params, inputs, cfg, aux_acc, pp_microbatches)
-    B, S, E = x.shape
+    x, aux = _trunk(params, inputs, cfg, pp_microbatches)
     wte = params["wte"]["embedding"].astype(cfg.compute_dtype)
-    if xent_chunks > 0:
-        total = _chunked_xent(x.reshape(B * S, E), wte,
-                              targets.reshape(B * S), xent_chunks)
-        loss = total / (B * S)
-    else:
-        # dense path: materialize logits (faster when HBM is not tight)
-        logits = _logits(x, wte)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, targets[..., None],
-                                  axis=-1)[..., 0]
-        loss = jnp.mean(lse - tgt)
-    if aux_acc:
-        loss = loss + cfg.moe_aux_weight * sum(aux_acc) / len(aux_acc)
+    logits = _logits(x, wte)
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    loss = jnp.mean(lse - tgt)
+    if cfg.moe_experts > 0:
+        loss = loss + cfg.moe_aux_weight * aux
     return loss
 
 
-def _cast_weights(params, dtype):
-    """One whole-tree cast of the matmul weights (ndim >= 2) to the compute
-    dtype.  Doing this ONCE up front instead of per-use matters on TPU:
-    XLA fuses a single-consumer f32->bf16 cast INTO the consuming matmul,
-    and a matmul with a fused operand conversion runs at ~0.4x the MXU
-    rate (measured 137 -> 57 TFLOP/s on v5e).  A shared pre-cast
-    materializes each bf16 weight once and every matmul runs full speed.
-    1-D leaves (biases, LN scale) stay f32 — they only feed VPU ops."""
-    return jax.tree.map(
-        lambda x: x.astype(dtype)
-        if x.dtype == jnp.float32 and x.ndim >= 2 else x, params)
-
-
-def make_train_step(cfg: GPT2Config, optimizer, pp_microbatches: int = 2,
-                    xent_chunks: int = 0):
+def make_train_step(cfg: GPT2Config, optimizer, pp_microbatches: int = 2):
     """Returns train_step(params, opt_state, batch) -> (params, opt_state,
     metrics) — jit it with the appropriate shardings.  Works for dense,
-    MoE, and pipeline-stacked params alike.
+    MoE, and pipeline-stacked params alike.  Mixed precision as
+    `layers.train_step` says."""
 
-    Mixed precision: f32 master params; the loss closure casts the weight
-    tree to ``cfg.compute_dtype`` once (see _cast_weights), autodiff flows
-    back through the cast, so grads and the adamw update stay f32.
+    def objective(params, batch):
+        loss = loss_fn(params, batch, cfg, pp_microbatches)
+        return loss, {"loss": loss}
 
-    ``xent_chunks>0`` enables the chunked fused lm-head cross-entropy for
-    HBM-tight configs (see loss_fn)."""
-
-    def train_step(params, opt_state, batch):
-        def loss_cast(p):
-            return loss_fn(_cast_weights(p, cfg.compute_dtype), batch, cfg,
-                           pp_microbatches, xent_chunks)
-
-        loss, grads = jax.value_and_grad(loss_cast)(params)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = jax.tree.map(lambda p, u: p + u, params, updates)
-        return params, opt_state, {"loss": loss}
-
-    return train_step
-
-
-def num_params(params) -> int:
-    return sum(x.size for x in jax.tree.leaves(params))
+    return train_step(objective, optimizer, cfg.compute_dtype)
 
 
 def count_flops_per_token(cfg: GPT2Config, seq_len: int) -> float:

@@ -1,0 +1,115 @@
+"""What the Train-path models share: the norms, rotary positions, the
+chunked loss and the mixed-precision step.  A model file imports these and
+`ray_tpu.parallel.attention`; it imports no other model file.
+
+Imports jax and nothing of the runtime: a worker pays nothing for it before
+its first step.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+
+def layer_norm(x, p, eps=1e-5):
+    """Stats in f32 for stability; output CAST BACK to the input dtype —
+    the f32 scale/bias would otherwise silently promote the residual
+    stream (and every downstream matmul) to the MXU's slow f32 path."""
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.var(xf, axis=-1, keepdims=True)
+    y = (xf - mu) * jax.lax.rsqrt(var + eps)
+    return (y * p["scale"] + p["bias"]).astype(x.dtype)
+
+
+def rms_norm(x, p, eps=1e-5):
+    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
+    return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * p["scale"].astype(x.dtype)
+
+
+def rope(x, positions, theta):
+    """x: (B, S, H, D); positions: (B, S) or (S,)."""
+    D = x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) / (D // 2))
+    angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
+    cos = jnp.cos(angles)[..., None, :].astype(x.dtype)
+    sin = jnp.sin(angles)[..., None, :].astype(x.dtype)
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+
+
+def chunked_xent(x, wte, targets, n_chunks: int):
+    """Fused linear + softmax cross-entropy, chunked over tokens.
+
+    The naive path materializes (B*S, V) f32 logits in HBM twice (forward
+    residual + backward read) — ~3.3 GB at B=16, S=1024, V=50257.
+    Instead: scan over token chunks, each chunk computing logits ->
+    (lse, target-logit) under ``jax.checkpoint`` so the backward pass
+    RECOMPUTES the chunk's logits and immediately contracts d_logits into
+    (dx, dwte) — the full logits tensor never exists in HBM in either pass.
+    (Same idea as fused linear-cross-entropy kernels; here XLA fuses the
+    chunk, no Pallas needed.)
+
+    x: (N, E) compute-dtype; wte: (V, E); targets: (N,) int32.
+    Returns summed loss (f32).
+    """
+    N, E = x.shape
+    n_chunks = max(1, min(n_chunks, N))
+    while N % n_chunks:
+        n_chunks -= 1
+    xc = x.reshape(n_chunks, N // n_chunks, E)
+    tc = targets.reshape(n_chunks, N // n_chunks)
+
+    @jax.checkpoint
+    def chunk(carry, xt):
+        xi, ti = xt
+        logits = jnp.matmul(xi, wte.T,
+                            preferred_element_type=jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        tgt = jnp.take_along_axis(logits, ti[:, None], axis=-1)[:, 0]
+        return carry + jnp.sum(lse - tgt), None
+
+    total, _ = jax.lax.scan(chunk, jnp.zeros((), jnp.float32), (xc, tc))
+    return total
+
+
+def cast_weights(params, dtype):
+    """One whole-tree cast of the matmul weights (ndim >= 2) to the compute
+    dtype.  Made ONCE up front and not per use: XLA fuses a single-consumer
+    f32->bf16 cast INTO the consuming matmul, and a matmul with a fused
+    operand conversion leaves the MXU's fast path.  A shared pre-cast
+    materializes each bf16 weight once and every matmul takes bf16
+    operands.  1-D leaves (biases, norm scales) stay f32 — they only feed
+    VPU ops."""
+    return jax.tree.map(
+        lambda x: x.astype(dtype)
+        if x.dtype == jnp.float32 and x.ndim >= 2 else x, params)
+
+
+def train_step(objective, optimizer, compute_dtype):
+    """train_step(params, opt_state, batch) -> (params, opt_state, out) for
+    ``objective(cast_params, batch) -> (scalar, out)`` — jit it with the
+    appropriate shardings and ``donate_argnums=(0, 1)``.
+
+    Mixed precision: f32 master params; the objective sees the weight tree
+    cast to ``compute_dtype`` once (see cast_weights), autodiff flows back
+    through the cast, so grads and the optimizer's update stay f32."""
+
+    # its name is the compiled module's (`jit_train_step`) in every trace
+    def train_step(params, opt_state, batch):
+        def cast_objective(p):
+            return objective(cast_weights(p, compute_dtype), batch)
+
+        (_, out), grads = jax.value_and_grad(cast_objective,
+                                             has_aux=True)(params)
+        with jax.named_scope("optimizer_update"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = jax.tree.map(lambda p, u: p + u, params, updates)
+        return params, opt_state, out
+
+    return train_step
+
+
+def num_params(params) -> int:
+    return sum(x.size for x in jax.tree.leaves(params))

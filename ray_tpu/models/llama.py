@@ -15,6 +15,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.layers import rms_norm, rope
 from ray_tpu.ops.flash_attention import flash_attention_bshd
 
 
@@ -73,22 +74,6 @@ def init_params(rng, cfg: LlamaConfig) -> Dict[str, Any]:
     return params
 
 
-def _rms_norm(x, p, eps=1e-5):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * p["scale"].astype(x.dtype)
-
-
-def _rope(x, positions, theta):
-    """x: (B, S, H, D); positions: (B, S) or (S,)."""
-    D = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) / (D // 2))
-    angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
-    cos = jnp.cos(angles)[..., None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[..., None, :].astype(x.dtype)
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-
-
 def _repeat_kv(x, n_rep: int):
     if n_rep == 1:
         return x
@@ -103,8 +88,8 @@ def _attn_block(x, p, cfg: LlamaConfig, positions, cache=None,
     q = (x @ p["q_proj"]["kernel"].astype(x.dtype)).reshape(B, S, H, D)
     k = (x @ p["k_proj"]["kernel"].astype(x.dtype)).reshape(B, S, Hk, D)
     v = (x @ p["v_proj"]["kernel"].astype(x.dtype)).reshape(B, S, Hk, D)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    q = rope(q, positions, cfg.rope_theta)
+    k = rope(k, positions, cfg.rope_theta)
     new_cache = None
     if cache is not None:
         ck, cv = cache  # (B, max_seq, Hk, D)
@@ -153,14 +138,14 @@ def forward(params, tokens, cfg: LlamaConfig, caches=None, cache_index=None,
     new_caches = []
     for i in range(cfg.n_layer):
         p = params[f"layer_{i}"]
-        h, nc = _attn_block(_rms_norm(x, p["input_norm"]), p["attn"], cfg,
+        h, nc = _attn_block(rms_norm(x, p["input_norm"]), p["attn"], cfg,
                             positions,
                             None if caches is None else caches[i],
                             cache_index)
         x = x + h
-        x = x + _mlp_block(_rms_norm(x, p["post_norm"]), p["mlp"])
+        x = x + _mlp_block(rms_norm(x, p["post_norm"]), p["mlp"])
         new_caches.append(nc)
-    x = _rms_norm(x, params["norm_f"]).astype(jnp.float32)
+    x = rms_norm(x, params["norm_f"]).astype(jnp.float32)
     logits = x @ params["lm_head"]["kernel"]
     return logits, (new_caches if caches is not None else None)
 

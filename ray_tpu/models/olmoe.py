@@ -13,11 +13,11 @@ Muennighoff et al., "OLMoE: Open Mixture-of-Experts Language Models"
   and no token dropped.  Trained on cross-entropy + a load-balancing loss
   + a router z-loss.
 
-It is the first model here that needs both halves of what the repo had:
-`llama.py`'s RMSNorm and RoPE, and `gpt2.py`'s training surface (f32 master
-parameters cast once, the chunked loss, the flash kernels under a mesh,
-logical dimensions for `parallel/sharding.py`).  They are imported, not
-copied.  The experts run dropless (`ops/moe.py:moe_dispatch`): the (token,
+What it shares with the other models it takes from `models/layers.py`
+(RMSNorm, RoPE, the chunked loss, f32 master parameters cast once in the
+mixed-precision step) and `parallel/attention.py` (the flash kernels under
+a mesh); its names are those `parallel/sharding.py` lays out.  The experts
+run dropless (`ops/moe.py:moe_dispatch`): the (token,
 expert) rows are sorted by expert and each group is multiplied by its
 expert with `jax.lax.ragged_dot`, XLA:TPU's grouped-matmul kernel.
 
@@ -34,10 +34,15 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.gpt2 import _cast_weights, _chunked_xent, num_params  # noqa: F401
-from ray_tpu.models.llama import _rms_norm, _rope
-from ray_tpu.ops.flash_attention import flash_attention_bshd
+from ray_tpu.models.layers import (
+    chunked_xent,
+    num_params,  # noqa: F401  (`olmoe.num_params` is public)
+    rms_norm,
+    rope,
+    train_step,
+)
 from ray_tpu.ops.moe import moe_dispatch
+from ray_tpu.parallel.attention import attention
 
 
 @dataclass(frozen=True)
@@ -56,7 +61,7 @@ class OlmoeConfig:
     z_weight: float = 0.001       # router z-loss (the paper's beta)
     compute_dtype: Any = jnp.bfloat16
     remat: bool = False           # jax.checkpoint each layer
-    # rows of the head's logits alive at once (`gpt2._chunked_xent`): at
+    # rows of the head's logits alive at once (`layers.chunked_xent`): at
     # 16,384 x 50,304 the whole of them and their gradient are 6.6 GB
     loss_chunk_rows: int = 2048
 
@@ -118,24 +123,14 @@ def _attention(x, p, cfg: OlmoeConfig):
     B, S, E = x.shape
     H, D = cfg.n_head, cfg.head_dim
     kernel = lambda name: p[name]["kernel"].astype(x.dtype)
-    q = _rms_norm(x @ kernel("q_proj"), p["q_norm"], cfg.rms_eps)
-    k = _rms_norm(x @ kernel("k_proj"), p["k_norm"], cfg.rms_eps)
+    q = rms_norm(x @ kernel("q_proj"), p["q_norm"], cfg.rms_eps)
+    k = rms_norm(x @ kernel("k_proj"), p["k_norm"], cfg.rms_eps)
     v = x @ kernel("v_proj")
     positions = jnp.arange(S)
-    q = _rope(q.reshape(B, S, H, D), positions, cfg.rope_theta)
-    k = _rope(k.reshape(B, S, H, D), positions, cfg.rope_theta)
+    q = rope(q.reshape(B, S, H, D), positions, cfg.rope_theta)
+    k = rope(k.reshape(B, S, H, D), positions, cfg.rope_theta)
     v = v.reshape(B, S, H, D)
-    # as gpt2._attention: under a bound mesh each device runs the kernel
-    # on its batch/head slice
-    from ray_tpu.parallel.context import get_mesh
-
-    mesh = get_mesh()
-    if mesh is None or mesh.size == 1:
-        o = flash_attention_bshd(q, k, v, True)
-    else:
-        from ray_tpu.parallel.ring_attention import flash_attention_sharded
-
-        o = flash_attention_sharded(q, k, v, mesh, causal=True)
+    o = attention(q, k, v)
     return o.reshape(B, S, E) @ kernel("o_proj")
 
 
@@ -174,10 +169,10 @@ def _moe(x, p, cfg: OlmoeConfig):
 
 def _layer(x, p, cfg: OlmoeConfig):
     with jax.named_scope("attention"):
-        x = x + _attention(_rms_norm(x, p["input_norm"], cfg.rms_eps),
+        x = x + _attention(rms_norm(x, p["input_norm"], cfg.rms_eps),
                            p["attn"], cfg)
     with jax.named_scope("moe"):
-        y, stats = _moe(_rms_norm(x, p["post_norm"], cfg.rms_eps),
+        y, stats = _moe(rms_norm(x, p["post_norm"], cfg.rms_eps),
                         p["moe"], cfg)
     return x + y, stats
 
@@ -194,7 +189,7 @@ def _trunk(params, tokens, cfg: OlmoeConfig):
         x, s = layer(x, params[f"layer_{i}"], cfg)
         stats.append(s)
     mean = lambda key: sum(s[key] for s in stats) / len(stats)
-    return _rms_norm(x, params["norm_f"], cfg.rms_eps), {
+    return rms_norm(x, params["norm_f"], cfg.rms_eps), {
         "aux_loss": mean("aux_loss"), "z_loss": mean("z_loss"),
         "max_expert_rows": functools.reduce(
             jnp.maximum, [s["max_expert_rows"] for s in stats])}
@@ -221,7 +216,7 @@ def loss_fn(params, batch, cfg: OlmoeConfig):
     B, S, E = x.shape
     with jax.named_scope("head_and_loss"):
         head = params["lm_head"]["kernel"].astype(cfg.compute_dtype)
-        total = _chunked_xent(x.reshape(B * S, E), head.T,
+        total = chunked_xent(x.reshape(B * S, E), head.T,
                               targets.reshape(B * S),
                               -(-B * S // cfg.loss_chunk_rows))
         xent = total / (B * S)
@@ -235,20 +230,10 @@ def make_train_step(cfg: OlmoeConfig, optimizer):
     to be jitted with its shardings and `donate_argnums=(0, 1)` as
     `gpt2.make_train_step`'s.  `out["loss"]` is the cross-entropy alone;
     `out` also carries "aux_loss", "z_loss" and "max_expert_rows", device
-    scalars that cost nothing unless fetched.  Mixed precision as GPT-2's:
-    f32 master parameters, cast once to `cfg.compute_dtype`."""
-
-    def train_step(params, opt_state, batch):
-        def objective(p):
-            return loss_fn(_cast_weights(p, cfg.compute_dtype), batch, cfg)
-
-        (_, out), grads = jax.value_and_grad(objective, has_aux=True)(params)
-        with jax.named_scope("optimizer_update"):
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = jax.tree.map(lambda p, u: p + u, params, updates)
-        return params, opt_state, out
-
-    return train_step
+    scalars that cost nothing unless fetched.  Mixed precision as
+    `layers.train_step` says."""
+    return train_step(lambda params, batch: loss_fn(params, batch, cfg),
+                      optimizer, cfg.compute_dtype)
 
 
 def count_flops_per_token(cfg: OlmoeConfig, seq_len: int) -> float:

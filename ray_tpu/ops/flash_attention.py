@@ -18,14 +18,14 @@ Two entry points / layouts:
   Pallas TPU requires minor block dims of 128), slicing each head out of
   the lanes in-kernel.  No (B,S,H,D) <-> (B,H,S,D) transpose ever
   materializes — the bhsd route costs four such transposes per transformer
-  layer fwd (plus their mirrors in bwd), ~400 MB of HBM round trips per
-  GPT-2-small layer per step.
+  layer fwd (plus their mirrors in bwd), each a round trip of the whole
+  array through HBM.
 
 Backward: when a whole (b, h) slice fits one block (block == S — the
 transformer bench regime), ONE fused kernel computes dq/dk/dv per grid
 step, sharing the recomputed s and dp tiles (5 (S,S)-operand dots instead
-of the 7 a two-kernel FlashAttention-2 split pays; measured ~6% end-to-end
-on the GPT-2 bench).  Otherwise the classic two-kernel split runs: a dq
+of the 7 a two-kernel FlashAttention-2 split pays; the GPT-2 cells of the
+benchmark run it).  Otherwise the classic two-kernel split runs: a dq
 kernel blocked over q rows and a dk/dv kernel blocked over k columns, both
 recomputing probabilities tile-by-tile from the saved logsumexp.  The S×S
 matrix never exists in HBM in any pass.
@@ -550,7 +550,7 @@ def _pallas_backward_bshd(q, k, v, o, lse, do, sm_scale, causal, interpret):
 # reference path + public API
 # ---------------------------------------------------------------------------
 
-def _reference_attention(q, k, v, sm_scale, causal):
+def reference_attention(q, k, v, sm_scale, causal):
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * sm_scale
     if causal:
@@ -650,11 +650,11 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
                     block_q=None, block_k=None):
     """Multi-head attention over (batch, heads, seq, head_dim) tensors.
 
-    Default blocks are large ((1024, 1024)-capped) and the grid dims are
-    marked parallel for Mosaic: the kernel is bound by (S, S)-operand dot
-    throughput, not VMEM, at transformer head dims, so fewer/bigger grid
-    steps win — and whole-S blocks additionally enable the fused one-pass
-    backward (5 big dots instead of 7)."""
+    Default blocks are large ((1024, 1024)-capped: `_auto_block`) and the
+    grid dims are marked parallel for Mosaic.  A block that is the whole
+    sequence is what enables the fused one-pass backward (5 big dots
+    instead of 7); the two-kernel backward caps its own tiles
+    (`_SPLIT_BWD_MAX_BLOCK`)."""
     o, _ = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k)
     return o
 
@@ -662,7 +662,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     S = q.shape[2]
     scale, bq, bk = _resolve(q, S, sm_scale, block_q, block_k)
-    reference = functools.partial(_reference_attention, sm_scale=scale,
+    reference = functools.partial(reference_attention, sm_scale=scale,
                                   causal=causal)
     problem = _tiling_problem(S, bq, bk)
     if problem:
@@ -755,7 +755,7 @@ def _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k):
     scale, bq, bk = _resolve(q, S, sm_scale, block_q, block_k)
     if _tiling_problem(S, bq, bk) is None and _bshd_lanes_ok(q, S, bq, bk):
         def reference(q, k, v):
-            o, lse = _reference_attention(_tr(q), _tr(k), _tr(v), scale,
+            o, lse = reference_attention(_tr(q), _tr(k), _tr(v), scale,
                                           causal)
             return _tr(o), lse
 
@@ -791,11 +791,3 @@ def _flash_bwd_bshd(causal, sm_scale, block_q, block_k, res, do):
 
 
 flash_attention_bshd.defvjp(_flash_fwd_bshd, _flash_bwd_bshd)
-
-
-def mha(q, k, v, causal=False, sm_scale=None):
-    """Attention over (batch, seq, heads, head_dim) layout (model-friendly).
-
-    Alias for :func:`flash_attention_bshd` — kept for callers that predate
-    the layout-native kernels."""
-    return flash_attention_bshd(q, k, v, causal, sm_scale)

@@ -1,0 +1,77 @@
+"""Attention under a mesh: the one entry the models call.
+
+`attention(q, k, v)` reads the mesh `use_mesh` bound (the only place that
+does so for attention) and picks how the kernels of `ray_tpu/ops/` run on
+it.  Which mesh axes cut the batch, the heads and the sequence is not said
+here: `parallel/sharding.py`'s rules say it, for the activations' pins and
+for the `shard_map`s below alike.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+
+from ray_tpu.ops.flash_attention import (
+    flash_attention_bshd,
+    reference_attention,
+)
+from ray_tpu.parallel.context import get_mesh, require_mesh
+from ray_tpu.parallel.ring_attention import ring_attention, ulysses_attention
+from ray_tpu.parallel.sharding import dividing_spec
+
+
+def _tr(x):
+    return x.transpose(0, 2, 1, 3)
+
+
+def attention(q, k, v, *, causal: bool = True, variant: str = "flash"):
+    """Multi-head attention over (batch, seq, heads, head_dim) arrays, at
+    head_dim^-1/2.
+
+    ``"flash"``: the layout-native kernel (no (B,S,H,D) <-> (B,H,S,D)
+    transposes); under a bound mesh of several devices each runs it on its
+    batch/head slice.  ``"ring"`` / ``"ulysses"``: sequence parallelism
+    over the bound mesh's sequence axis.  ``"dense"``: the O(S^2)
+    reference, left to the partitioner."""
+    if variant in ("ring", "ulysses"):
+        return _sequence_parallel(q, k, v, require_mesh(), causal, variant)
+    if variant == "dense":
+        o, _ = reference_attention(_tr(q), _tr(k), _tr(v),
+                                   q.shape[-1] ** -0.5, causal)
+        return _tr(o)
+    mesh = get_mesh()
+    if mesh is None or mesh.size == 1:
+        return flash_attention_bshd(q, k, v, causal)
+    return _flash_sharded(q, k, v, mesh, causal)
+
+
+def _flash_sharded(q, k, v, mesh, causal):
+    """shard_map of the layout-native kernel: batch and heads cut as the
+    rules say, and each device runs the kernel on its own slice.  A
+    pallas_call is an opaque custom call to the SPMD partitioner: under a
+    mesh of several devices jax refuses to lower one that is not inside a
+    shard_map."""
+    spec = dividing_spec(mesh, ("batch", None, "heads", None), q.shape)
+    return jax.shard_map(
+        lambda q, k, v: flash_attention_bshd(q, k, v, causal),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
+
+
+def _sequence_parallel(q, k, v, mesh, causal, variant):
+    """shard_map of the ring (or Ulysses) over the mesh's sequence axis, in
+    the head-major layout its chunks rotate in; with no such axis, as
+    ``"flash"``."""
+    B, S, H, D = q.shape
+    spec = dividing_spec(mesh, ("batch", "heads", "seq", None), (B, H, S, D))
+    if spec[2] is None:
+        return attention(q, k, v, causal=causal)
+    inner = ring_attention if variant == "ring" else ulysses_attention
+    return _tr(jax.shard_map(
+        functools.partial(inner, axis_name=spec[2], causal=causal),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(_tr(q), _tr(k), _tr(v)))

@@ -46,13 +46,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops.flash_attention import (
     _flash_bwd,
     _flash_fwd,
     flash_attention,
-    flash_attention_bshd,
 )
 
 _NEG_INF = -1e30
@@ -78,7 +76,7 @@ def _chunk_bwd(q, k, v, o, lse, do, scale, causal_step, delta):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def ring_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
+def ring_attention(q, k, v, axis_name: str, causal: bool = True,
                    sm_scale: Optional[float] = None):
     """Attention over sequence-sharded q/k/v — call INSIDE shard_map.
 
@@ -205,65 +203,7 @@ def _ring_bwd(axis_name, causal, sm_scale, res, do):
 ring_attention.defvjp(_ring_fwd, _ring_bwd)
 
 
-def ring_attention_sharded(q, k, v, mesh: Mesh, causal: bool = True,
-                           sm_scale: Optional[float] = None,
-                           batch_axes=("dp", "fsdp"), seq_axis="sp",
-                           head_axis="tp", variant: str = "ring"):
-    """shard_map wrapper: q/k/v are (batch, heads, seq, head_dim) global
-    arrays; seq sharded on `sp`, heads on `tp`, batch on dp/fsdp."""
-    batch = tuple(a for a in batch_axes if a in mesh.shape and mesh.shape[a] > 1)
-    bspec = batch if len(batch) > 1 else (batch[0] if batch else None)
-    hspec = head_axis if head_axis in mesh.shape and mesh.shape[head_axis] > 1 else None
-    sspec = seq_axis if seq_axis in mesh.shape and mesh.shape[seq_axis] > 1 else None
-    spec = P(bspec, hspec, sspec, None)
-
-    if sspec is None:
-        # no sequence sharding: plain flash attention
-        return flash_attention(q, k, v, causal, sm_scale)
-
-    inner = ring_attention if variant == "ring" else ulysses_attention
-    fn = functools.partial(inner, axis_name=seq_axis, causal=causal,
-                           sm_scale=sm_scale)
-    return jax.shard_map(
-        fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False,
-    )(q, k, v)
-
-
-def _dividing_axes(mesh: Mesh, names, size: int):
-    """PartitionSpec entry sharding a dimension of ``size`` over the mesh
-    axes among ``names``; an axis that does not divide what is left of the
-    dimension is dropped, and the dimension is gathered over it."""
-    picked = []
-    for a in names:
-        n = mesh.shape.get(a, 1)
-        if n > 1 and size % n == 0:
-            picked.append(a)
-            size //= n
-    if not picked:
-        return None
-    return tuple(picked) if len(picked) > 1 else picked[0]
-
-
-def flash_attention_sharded(q, k, v, mesh: Mesh, causal: bool = True,
-                            sm_scale: Optional[float] = None,
-                            batch_axes=("dp", "fsdp"), head_axis="tp"):
-    """shard_map wrapper for the layout-native kernel: q/k/v are (batch,
-    seq, heads, head_dim) global arrays, batch sharded on dp/fsdp and heads
-    on `tp`, and each device runs the kernel on its own slice.  A
-    pallas_call is an opaque custom call to the SPMD partitioner: under a
-    mesh of several devices jax refuses to lower one that is not inside a
-    shard_map."""
-    spec = P(_dividing_axes(mesh, batch_axes, q.shape[0]), None,
-             _dividing_axes(mesh, (head_axis,), q.shape[2]), None)
-    return jax.shard_map(
-        lambda q, k, v: flash_attention_bshd(q, k, v, causal, sm_scale),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False,
-    )(q, k, v)
-
-
-def ulysses_attention(q, k, v, axis_name: str = "sp", causal: bool = True,
+def ulysses_attention(q, k, v, axis_name: str, causal: bool = True,
                       sm_scale: Optional[float] = None):
     """All-to-all (DeepSpeed-Ulysses style) sequence parallelism — call
     inside shard_map.  Per device in: (B, H, S/n, D); internally reshards to

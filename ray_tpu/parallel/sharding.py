@@ -19,7 +19,9 @@ the partitioner has to gather each `embed → fsdp` weight at its use (FSDP).
 A model that does not state them leaves the activations' layout to the
 partitioner, which may as well keep the weights' cut on the contraction
 dim and all-reduce full-batch partial products (GPT-2 XL before PR 29:
-210 MB a layer, three times).
+210 MB a layer, three times).  The `shard_map`s the attention kernels run
+under (`parallel/attention.py`) take their specs from the same rules
+(`dividing_spec`): no other module names a mesh axis for a logical dim.
 
 Logical dims used by the bundled models (ray_tpu/models/*):
   "batch"   → (dp, fsdp)     activations' leading dim
@@ -36,7 +38,7 @@ Logical dims used by the bundled models (ray_tpu/models/*):
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -70,7 +72,6 @@ class ShardingConfig:
     sp: int = 1
     ep: int = 1
     tp: int = 1
-    rules: Dict[str, Any] = field(default_factory=lambda: dict(DEFAULT_RULES))
 
     def axes(self) -> Dict[str, int]:
         sizes = {"dp": self.dp, "fsdp": self.fsdp, "pp": self.pp,
@@ -83,7 +84,7 @@ class ShardingConfig:
     # ------------------------------------------------------------------
 
     def spec(self, mesh: Mesh, *logical_dims: Optional[str]) -> P:
-        return logical_spec(mesh, logical_dims, self.rules)
+        return logical_spec(mesh, logical_dims)
 
     def named_sharding(self, mesh: Mesh, *logical_dims) -> NamedSharding:
         return NamedSharding(mesh, self.spec(mesh, *logical_dims))
@@ -97,15 +98,15 @@ class ShardingConfig:
         )
 
 
-def _axes(mesh, logical_dims, rules):
-    """Per logical dim, the mesh axes (those larger than 1) its rule cuts it
-    on.  A mesh axis may appear only once in a PartitionSpec; earlier dims
-    win (so "batch" on (dp, fsdp) suppresses "embed" on fsdp for
-    activations — params without a batch dim still shard on fsdp)."""
+def _axes(mesh, logical_dims):
+    """Per logical dim, the mesh axes (those larger than 1) `DEFAULT_RULES`
+    cut it on.  A mesh axis may appear only once in a PartitionSpec;
+    earlier dims win (so "batch" on (dp, fsdp) suppresses "embed" on fsdp
+    for activations — params without a batch dim still shard on fsdp)."""
     used: set = set()
     out = []
     for d in logical_dims:
-        axis = rules.get(d)
+        axis = DEFAULT_RULES.get(d)
         axes = axis if isinstance(axis, (tuple, list)) else (axis,)
         axes = tuple(a for a in axes
                      if mesh.shape.get(a, 1) > 1 and a not in used)
@@ -114,10 +115,29 @@ def _axes(mesh, logical_dims, rules):
     return out
 
 
-def logical_spec(mesh, logical_dims, rules=DEFAULT_RULES) -> P:
+def _entry(axes):
+    return tuple(axes) if len(axes) > 1 else (axes[0] if axes else None)
+
+
+def logical_spec(mesh, logical_dims) -> P:
     """PartitionSpec of logical dims on a mesh."""
-    return P(*(axes if len(axes) > 1 else (axes[0] if axes else None)
-               for axes in _axes(mesh, logical_dims, rules)))
+    return P(*map(_entry, _axes(mesh, logical_dims)))
+
+
+def dividing_spec(mesh, logical_dims, shape) -> P:
+    """PartitionSpec of an array of ``shape`` stated as ``logical_dims``,
+    for a `shard_map` (which refuses a dimension its axes do not divide):
+    an axis that does not divide what is left of its dimension is dropped,
+    and the dimension is gathered over it."""
+    entries = []
+    for axes, size in zip(_axes(mesh, logical_dims), shape):
+        picked = []
+        for a in axes:
+            if size % mesh.shape[a] == 0:
+                picked.append(a)
+                size //= mesh.shape[a]
+        entries.append(_entry(picked))
+    return P(*entries)
 
 
 def constrain(x, *logical_dims):
@@ -134,7 +154,7 @@ def constrain(x, *logical_dims):
     if (mesh is None or mesh.size == 1
             or jax.sharding.get_abstract_mesh().manual_axes):
         return x
-    for size, axes in zip(x.shape, _axes(mesh, logical_dims, DEFAULT_RULES)):
+    for size, axes in zip(x.shape, _axes(mesh, logical_dims)):
         if size % math.prod(mesh.shape[a] for a in axes):
             return x
     tracing.count("parallel.constraints")

@@ -21,9 +21,9 @@ def ensure_compile_cache() -> str:
     """Place the compilation cache and return its directory.
 
     For every process that compiles for the chip (a ``tpu`` worker before
-    its first use of jax, ``bench.py``) and for nothing else.  Where
-    ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself — workers
-    inherit it through their raylet — and this sets nothing."""
+    its first use of jax, ``tools/chip_kernels.py``) and for nothing else.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself —
+    workers inherit it through their raylet — and this sets nothing."""
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env

@@ -14,6 +14,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import gpt2
 from ray_tpu.parallel import sharding
+from ray_tpu.parallel.attention import attention
 from ray_tpu.parallel.context import use_mesh
 from ray_tpu.parallel.sharding import ShardingConfig, constrain, shard_params
 from tools.aot_collectives import collectives
@@ -165,7 +166,7 @@ def test_nothing_is_pinned_where_nothing_can_be(case):
         jaxpr = _step_jaxpr(ShardingConfig().build_mesh(jax.devices()[:1]))
     else:
         # six sequences do not divide over fsdp=4: the batch dim's pins go,
-        # as `_dividing_axes` drops the axis for the attention kernel
+        # as `dividing_spec` drops the axis for the attention kernel
         mesh = ShardingConfig(fsdp=4).build_mesh(jax.devices()[:4])
         jaxpr = _step_jaxpr(mesh, batch_rows=6)
     assert "sharding_constraint" not in jaxpr
@@ -194,3 +195,47 @@ def test_constrain_resolves_logical_dims_by_the_default_rules(axes, dims,
         y = jax.jit(lambda x: constrain(x, *dims))(x)
     assert y.sharding.is_equivalent_to(NamedSharding(mesh, spec), x.ndim)
     assert sharding.logical_spec(mesh, dims) == spec
+
+
+def _pin_and_shard_map(mesh, shape):
+    """(the spec `constrain` pins q to, the in/out spec of the `shard_map`
+    the flash kernel runs under) for q, k, v of `shape` (B, S, H, D)."""
+    q = jax.ShapeDtypeStruct(shape, jnp.float32)
+    with use_mesh(mesh):
+        eqns = jax.make_jaxpr(lambda q: attention(
+            *[constrain(q, "batch", None, "heads", None)] * 3))(q).eqns
+    pins = [e.params["sharding"].spec for e in eqns
+            if e.primitive.name == "sharding_constraint"]
+    (wrapped,) = [e.params for e in eqns if e.primitive.name == "shard_map"]
+    (spec,) = set(wrapped["in_specs"] + wrapped["out_specs"])
+    return (pins[0] if pins else None), spec
+
+
+# what is left of the rules' spec where 6 sequences and 3 heads do not
+# divide by an axis
+UNEVEN = {"fsdp4": P(None, None, None, None),
+          "tp2_fsdp2": P("fsdp", None, None, None),
+          "sp2_fsdp2": P("fsdp", None, None, None),
+          "pp2": P("dp", None, None, None)}
+
+
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_the_flash_shard_map_takes_its_axes_from_the_rules(layout):
+    mesh = ShardingConfig(**LAYOUTS[layout][0]).build_mesh(jax.devices()[:4])
+    by_rules = sharding.logical_spec(mesh, ("batch", None, "heads", None))
+    assert _pin_and_shard_map(mesh, (BATCH, SEQ, 2, 32)) == (by_rules,
+                                                             by_rules)
+    # where a dim does not divide nothing is pinned, and the kernel's
+    # shard_map gathers that dim over the axis and keeps the others cut
+    uneven = UNEVEN[layout]
+    assert _pin_and_shard_map(mesh, (6, SEQ, 3, 32)) == (
+        uneven if uneven == by_rules else None, uneven)
+
+
+def test_a_rule_moves_the_pin_and_the_shard_map_together(monkeypatch):
+    mesh = ShardingConfig(tp=2, fsdp=2).build_mesh(jax.devices()[:4])
+    cut = P("fsdp", None, "tp", None)
+    assert _pin_and_shard_map(mesh, (BATCH, SEQ, 2, 32)) == (cut, cut)
+    monkeypatch.setitem(sharding.DEFAULT_RULES, "heads", None)
+    whole = P("fsdp", None, None, None)
+    assert _pin_and_shard_map(mesh, (BATCH, SEQ, 2, 32)) == (whole, whole)

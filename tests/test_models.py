@@ -1,6 +1,10 @@
 """Model tests: GPT-2 forward/train-step (sharded), MNIST learns, llama
 decode-with-cache matches full forward."""
 
+import ast
+import pathlib
+from dataclasses import replace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -65,8 +69,6 @@ def test_gpt2_ring_attention_matches_flash():
 
     dense = gpt2.forward(params, tokens, cfg)
 
-    from dataclasses import replace
-
     from ray_tpu.parallel.context import use_mesh
 
     ring_cfg = replace(cfg, attention="ring")
@@ -80,6 +82,59 @@ def test_gpt2_ring_attention_matches_flash():
             in_shardings=(None, spec_tok),
         )(params, jax.device_put(tokens, spec_tok))
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense), atol=5e-2)
+
+
+def test_gpt2_mixture_under_remat_is_the_same_step():
+    """The one loop over `jax.checkpoint(block)`: a mixture's loss, its
+    auxiliary term and its gradients are those of the loop that keeps
+    every activation."""
+    cfg = replace(gpt2.GPT2_TINY, moe_experts=4, attention="dense",
+                  compute_dtype=jnp.float32)
+    params = gpt2.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 33), 0,
+                                          cfg.vocab_size)}
+
+    def step(cfg):
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda p: gpt2.loss_fn(p, batch, cfg)))(params)
+        _, aux = jax.jit(lambda p: gpt2._trunk(
+            p, batch["tokens"][:, :-1], cfg))(params)
+        return loss, aux, grads
+
+    with jax.default_matmul_precision("highest"):
+        loss, aux, grads = step(cfg)
+        rloss, raux, rgrads = step(replace(cfg, remat=True))
+    assert float(aux) > 0.5          # a balanced router gives 1
+    np.testing.assert_allclose(float(rloss), float(loss), atol=1e-6, rtol=0)
+    np.testing.assert_allclose(float(raux), float(aux), atol=1e-6, rtol=0)
+    for (path, want), got in zip(
+            jax.tree_util.tree_flatten_with_path(grads)[0],
+            jax.tree.leaves(rgrads)):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=1e-6, rtol=0,
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_models_and_ops_reach_around_nothing():
+    """A model file takes what it shares from `models/layers.py`, never
+    another model's underscore names; and no file of `models/` or `ops/`
+    names a mesh axis: `parallel/sharding.py`'s rules do."""
+    from ray_tpu.parallel.mesh import AXIS_ORDER
+
+    package = pathlib.Path(gpt2.__file__).parents[1]
+    files = sorted([*(package / "models").glob("*.py"),
+                    *(package / "ops").glob("*.py")])
+    assert len(files) > 6
+    for path in files:
+        for node in ast.walk(ast.parse(path.read_text())):
+            if (isinstance(node, ast.ImportFrom)
+                    and (node.module or "").startswith("ray_tpu.models.")):
+                private = [a.name for a in node.names
+                           if a.name.startswith("_")]
+                assert not private, (path.name, node.module, private)
+            if isinstance(node, ast.Constant) and isinstance(node.value, str):
+                assert node.value not in AXIS_ORDER, (
+                    path.name, node.lineno, node.value)
 
 
 @pytest.mark.slow

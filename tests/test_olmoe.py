@@ -19,7 +19,7 @@ import pytest
 
 from benchmark.families.olmoe import to_reference
 from benchmark.reference import olmoe as reference
-from ray_tpu.models import llama, olmoe
+from ray_tpu.models import layers, olmoe
 from ray_tpu.parallel.sharding import infer_param_logical_dims
 
 F32 = dataclasses.replace(olmoe.OLMOE_TINY, compute_dtype=jnp.float32)
@@ -220,7 +220,7 @@ def test_bfloat16_compute_stays_close_and_routes_alike(seed):
     params, tokens = make_params(seed, one_layer), make_tokens(seed)
     inputs = tokens[:, :-1]
     logits, _ = jax.jit(lambda p: olmoe.forward(
-        olmoe._cast_weights(p, jnp.bfloat16), inputs, one_layer))(params)
+        layers.cast_weights(p, jnp.bfloat16), inputs, one_layer))(params)
     ref_params = to_reference(params)
     want = reference.logits(ref_params, inputs, SIZES)
     gaps, choices = jax.jit(reference_router_gaps)(ref_params, inputs)
@@ -345,7 +345,7 @@ def test_rope_is_the_rotation_of_its_formula():
     """Position m turns the pair (x_i, x_{i+D/2}) by m * theta^(-2i/D)."""
     b, s, h, d = 2, 16, 3, 8
     x = np.asarray(jax.random.normal(jax.random.PRNGKey(0), (b, s, h, d)))
-    got = np.asarray(llama._rope(jnp.asarray(x), jnp.arange(s), 10000.0))
+    got = np.asarray(layers.rope(jnp.asarray(x), jnp.arange(s), 10000.0))
     want = np.zeros_like(x)
     for m in range(s):
         for i in range(d // 2):
@@ -366,7 +366,7 @@ def test_qk_norm_is_over_the_whole_projection():
     before the split into heads: not a norm per head."""
     x = np.asarray(jax.random.normal(jax.random.PRNGKey(1), (2, 5, 64))) * 3
     gain = np.linspace(0.5, 1.5, 64).astype(np.float32)
-    got = np.asarray(llama._rms_norm(jnp.asarray(x),
+    got = np.asarray(layers.rms_norm(jnp.asarray(x),
                                      {"scale": jnp.asarray(gain)}, 1e-5))
     want = x / np.sqrt((x ** 2).mean(-1, keepdims=True) + 1e-5) * gain
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
@@ -389,9 +389,9 @@ def with_fault(monkeypatch, params, fault):
         skipped = {id(params[f"layer_{i}"]["attn"][name])
                    for i in range(F32.n_layer)
                    for name in ("q_norm", "k_norm")}
-        real_norm = olmoe._rms_norm
+        real_norm = olmoe.rms_norm
         monkeypatch.setattr(
-            olmoe, "_rms_norm", lambda x, p, eps: x if id(p) in skipped
+            olmoe, "rms_norm", lambda x, p, eps: x if id(p) in skipped
             else real_norm(x, p, eps))
     return F32
 
@@ -412,7 +412,7 @@ def test_lower_precision_than_stated_fails_the_bfloat16_tolerance():
     params, tokens = case()
     low = jnp.float8_e4m3fn
     logits, _ = jax.jit(lambda p, t: olmoe.forward(
-        olmoe._cast_weights(p, low), t,
+        layers.cast_weights(p, low), t,
         dataclasses.replace(F32, compute_dtype=low)))(params, tokens[:, :-1])
     # nan at that
     assert not max_diff(logits, results("reference")[0]) \

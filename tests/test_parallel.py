@@ -7,12 +7,11 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ray_tpu.ops.flash_attention import _reference_attention, flash_attention
+from ray_tpu.ops.flash_attention import flash_attention, reference_attention
+from ray_tpu.parallel.attention import attention
+from ray_tpu.parallel.context import use_mesh
 from ray_tpu.parallel.mesh import create_mesh
-from ray_tpu.parallel.ring_attention import (
-    ring_attention_sharded,
-    ulysses_attention,
-)
+from ray_tpu.parallel.ring_attention import ulysses_attention
 from ray_tpu.parallel.sharding import ShardingConfig, shard_params
 
 TOL = 2e-2  # CPU backend matmuls are low-precision by default
@@ -23,6 +22,14 @@ def _qkv(B=2, H=4, S=128, D=32, dtype=jnp.float32):
         jax.random.normal(jax.random.fold_in(key, i), (B, H, S, D), dtype)
         for i in range(3)
     )
+
+
+def _ring(q, k, v, mesh, causal):
+    """`attention(variant="ring")` under `mesh`, on head-major arrays."""
+    tr = lambda x: x.transpose(0, 2, 1, 3)
+    with use_mesh(mesh):
+        return tr(attention(tr(q), tr(k), tr(v), causal=causal,
+                            variant="ring"))
 
 
 def test_device_count():
@@ -40,7 +47,7 @@ def test_flash_attention_matches_reference():
     q, k, v = _qkv()
     for causal in (False, True):
         o = flash_attention(q, k, v, causal)
-        ref, _ = _reference_attention(q, k, v, q.shape[-1] ** -0.5, causal)
+        ref, _ = reference_attention(q, k, v, q.shape[-1] ** -0.5, causal)
         np.testing.assert_allclose(o, ref, atol=TOL)
 
 
@@ -53,7 +60,7 @@ def test_flash_attention_backward_matches_reference():
         return jnp.sum(flash_attention(q, k, v, causal, None, bq, bk) ** 2)
 
     def loss_ref(q, k, v, causal):
-        o, _ = _reference_attention(q, k, v, q.shape[-1] ** -0.5, causal)
+        o, _ = reference_attention(q, k, v, q.shape[-1] ** -0.5, causal)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
     # block 128 = single-block path; block 32 = 4x4 blocks, exercising the
@@ -88,13 +95,13 @@ def test_pallas_kernels_direct_multiblock(bq, bk, causal):
     scale = D ** -0.5
 
     o, lse = _pallas_forward(q, k, v, scale, causal, bq, bk, interpret=True)
-    ref_o, ref_lse = _reference_attention(q, k, v, scale, causal)
+    ref_o, ref_lse = reference_attention(q, k, v, scale, causal)
     np.testing.assert_allclose(np.asarray(o), np.asarray(ref_o), atol=TOL)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
                                atol=TOL)
 
     def loss_ref(q, k, v):
-        o, _ = _reference_attention(q, k, v, scale, causal)
+        o, _ = reference_attention(q, k, v, scale, causal)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -112,8 +119,8 @@ def test_ring_attention_matches_dense():
     q, k, v = _qkv(B, H, S, D)
     mesh = create_mesh({"sp": 8})
     for causal in (False, True):
-        out = ring_attention_sharded(q, k, v, mesh, causal=causal)
-        ref, _ = _reference_attention(q, k, v, D ** -0.5, causal)
+        out = _ring(q, k, v, mesh, causal)
+        ref, _ = reference_attention(q, k, v, D ** -0.5, causal)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=TOL)
 
 
@@ -123,10 +130,10 @@ def test_ring_attention_grad():
     mesh = create_mesh({"sp": 8})
 
     def loss_ring(q, k, v):
-        return (ring_attention_sharded(q, k, v, mesh, causal=True) ** 2).sum()
+        return (_ring(q, k, v, mesh, True) ** 2).sum()
 
     def loss_ref(q, k, v):
-        o, _ = _reference_attention(q, k, v, D ** -0.5, True)
+        o, _ = reference_attention(q, k, v, D ** -0.5, True)
         return (o ** 2).sum()
 
     # all three grads: dq exercises the local accumulation, dk/dv the
@@ -149,7 +156,7 @@ def test_ulysses_attention_matches_dense():
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)
-    ref, _ = _reference_attention(q, k, v, D ** -0.5, True)
+    ref, _ = reference_attention(q, k, v, D ** -0.5, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=TOL)
 
 
@@ -320,14 +327,14 @@ def test_flash_attention_bshd_lane_path(H, D, causal):
     tr = lambda x: x.transpose(0, 2, 1, 3)
 
     o = flash_attention_bshd(q, k, v, causal)
-    ref, _ = _reference_attention(tr(q), tr(k), tr(v), D ** -0.5, causal)
+    ref, _ = reference_attention(tr(q), tr(k), tr(v), D ** -0.5, causal)
     np.testing.assert_allclose(np.asarray(tr(o)), np.asarray(ref), atol=TOL)
 
     def loss_lane(q, k, v):
         return jnp.sum(flash_attention_bshd(q, k, v, causal) ** 2)
 
     def loss_ref(q, k, v):
-        o, _ = _reference_attention(tr(q), tr(k), tr(v), D ** -0.5, causal)
+        o, _ = reference_attention(tr(q), tr(k), tr(v), D ** -0.5, causal)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
     gl = jax.grad(loss_lane, argnums=(0, 1, 2))(q, k, v)
@@ -372,7 +379,7 @@ def test_flash_attention_reference_fallback_warns():
     q, k, v = _qkv(B=1, H=1, S=1032, D=8)  # 1032 = 8 * 129: blocks fall to 8
     with pytest.warns(AttentionFallbackWarning, match=r"\(1, 1, 1032, 8\)"):
         o = flash_attention(q, k, v, True)
-    ref, _ = _reference_attention(q, k, v, 8 ** -0.5, True)
+    ref, _ = reference_attention(q, k, v, 8 ** -0.5, True)
     np.testing.assert_allclose(o, ref, atol=TOL)
 
 

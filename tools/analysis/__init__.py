@@ -32,8 +32,7 @@ from tools.analysis.common import (SourceFile, Suppression, Violation,
                                    iter_py_files, load_files)
 
 #: files outside ray_tpu/ also swept by the env-var completeness scan
-EXTRA_SCAN = ("tests", "examples", "bench.py", "bench_core.py",
-              "bench_scale.py")
+EXTRA_SCAN = ("tests", "examples", "bench_core.py", "bench_scale.py")
 #: fixture snippets in here intentionally contain violations
 SCAN_EXCLUDE = ("tests/test_analysis.py",)
 

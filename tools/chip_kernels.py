@@ -8,7 +8,7 @@ kernels with the fused backward (GPT-2 124M's heads), the transposing bhsd
 kernels with the fused backward (25 heads: no lane tiling), and the
 two-kernel backward past ``_LANES_MAX_SEQ`` (S=2048).  This process holds
 the chip, so run it alone.  Exits non-zero unless every case ran as
-compiled Mosaic kernels on a TPU and agrees with ``_reference_attention``.
+compiled Mosaic kernels on a TPU and agrees with ``reference_attention``.
 """
 
 from __future__ import annotations
@@ -32,13 +32,14 @@ TOLERANCE = 0.05
 def compare_with_reference(shape, dtype):
     """Causal ``flash_attention_bshd`` at ``shape`` (B, S, H, D), forward and
     backward, on the default device: (largest error of o, dq, dk, dv
-    relative to ``_reference_attention``'s, Mosaic kernels in the compiled
+    relative to ``reference_attention``'s, Mosaic kernels in the compiled
     program — 0 where the kernels are interpreted)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from ray_tpu.ops import flash_attention as fa
+    from ray_tpu.parallel.attention import attention
 
     q, k, v = (jax.random.normal(jax.random.PRNGKey(i), shape, dtype)
                for i in range(3))
@@ -48,9 +49,8 @@ def compare_with_reference(shape, dtype):
         return jnp.sum(o.astype(jnp.float32) ** 2), o
 
     def reference(q, k, v):
-        o, _ = fa._reference_attention(fa._tr(q), fa._tr(k), fa._tr(v),
-                                       shape[-1] ** -0.5, True)
-        return jnp.sum(o.astype(jnp.float32) ** 2), fa._tr(o)
+        o = attention(q, k, v, variant="dense")
+        return jnp.sum(o.astype(jnp.float32) ** 2), o
 
     def grad(f):
         return jax.jit(jax.value_and_grad(f, (0, 1, 2), has_aux=True))
