@@ -8,9 +8,8 @@ train loops); here it is a framework op reused by models, ring attention
 
 Two entry points / layouts:
 
-* ``flash_attention`` — (batch, heads, seq, head_dim).  Forward: grid
-  (batch*heads, q_blocks), inner fori over k blocks with running
-  (max, sum, acc); causal variant skips blocks past the diagonal.
+* ``flash_attention`` — (batch, heads, seq, head_dim): one head per grid
+  step, online softmax with running (max, sum, acc).
 * ``flash_attention_bshd`` — (batch, seq, heads, head_dim), the layout
   models naturally produce from the fused qkv projection.  The arrays are
   viewed as (batch, seq, heads*head_dim) and the kernels take 128-wide
@@ -21,14 +20,29 @@ Two entry points / layouts:
   layer fwd (plus their mirrors in bwd), each a round trip of the whole
   array through HBM.
 
-Backward: when a whole (b, h) slice fits one block (block == S — the
-transformer bench regime), ONE fused kernel computes dq/dk/dv per grid
-step, sharing the recomputed s and dp tiles (5 (S,S)-operand dots instead
-of the 7 a two-kernel FlashAttention-2 split pays; the GPT-2 cells of the
-benchmark run it).  Otherwise the classic two-kernel split runs: a dq
-kernel blocked over q rows and a dk/dv kernel blocked over k columns, both
-recomputing probabilities tile-by-tile from the saved logsumexp.  The S×S
-matrix never exists in HBM in any pass.
+Causal calls compute only the tiles on and below the diagonal and mask
+only the tiles the diagonal crosses, forward and backward; `_auto_tiles`
+picks the tile from S and `causal` (`block_q` / `block_k` name one
+explicitly).  Non-causal calls have nothing to skip and take the whole
+sequence (1024-capped) as one tile.
+
+Up to S = `_WHOLE_SEQ_MAX` a grid step takes a whole (b, h) slice (a
+128-lane group in the lane layout) and every extent inside it is static,
+so nothing loops: the tiles of a row (forward) or column (backward) that
+lie wholly below the diagonal are merged into one unmasked span, the ones
+the diagonal crosses into one masked span (`_span`).  The forward walks
+its q tiles, an online softmax of two steps at most each.  The backward is
+ONE kernel for dq/dk/dv: per k tile, each span recomputes s and dp once
+and shares them between dq, dk and dv (5 dots instead of the 7 a
+two-kernel FlashAttention-2 split pays), and dq sums over k tiles in an
+f32 VMEM scratch.  Past `_WHOLE_SEQ_MAX` the grid walks the forward's q
+tiles, each looping over its k blocks, and the classic two-kernel split
+runs backward: a dq kernel blocked over q rows and a dk/dv kernel blocked
+over k columns, both recomputing probabilities tile-by-tile from the saved
+logsumexp.  The S×S matrix never exists in HBM in any pass.
+
+Each kernel adds its tiles to the job timeline as the step is traced
+(`attention.tiles`, `attention.tiles_skipped`: see `_count_tiles`).
 
 On non-TPU backends the same kernels run in interpret mode for tiny shapes
 (tests), and a pure-XLA reference path is used otherwise.
@@ -45,9 +59,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.util import tracing
+
 _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634  # 1/ln(2)
 _LANE = 128  # minor-dim block width Pallas TPU requires
+
+# Longest sequence a grid step takes whole — all q rows in the forward, the
+# one-kernel backward: the (b, h) slices of q, k, v, do and the three
+# gradients (double-buffered), the lane layout's padded lse/delta and the
+# f32 dq scratch stay within a few MB of VMEM up to here.  `_resolve` makes
+# the decision from it; `_auto_tiles` reads it for the tiles.
+_WHOLE_SEQ_MAX = 1024
 
 # Both grid dims are embarrassingly parallel (batch*heads, and q/k blocks
 # within a head); telling Mosaic so lets it pipeline block prologues across
@@ -60,43 +83,66 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
 # shared kernel cores (operate on squeezed (rows, d) tiles)
 # ---------------------------------------------------------------------------
 
-def _fwd_core(q, read_k, read_v, qi, *, causal, block_q, block_k, seq_len):
+def _span(first, last, block, body, carry):
+    """``body(start, rows, carry)`` over the tiles [first, last) of
+    ``block`` rows taken as ONE span (the bounds are Python ints): a tile's
+    fixed and per-row costs are paid once and nothing loops."""
+    if last > first:
+        carry = body(first * block, (last - first) * block, carry)
+    return carry
+
+
+def _tile_loop(first, last, block, body, carry):
+    """The same tiles one at a time; the bounds may be traced (the q tile
+    of a grid step)."""
+    return jax.lax.fori_loop(
+        first, last, lambda i, c: body(i * block, block, c), carry)
+
+
+def _causal_mask(s, q_start, k_start):
+    """Scores of rows q_start.. against keys k_start.., keys after the
+    row's own position at -inf."""
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+
+
+def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
+              seq_len):
     """Online-softmax forward over one q tile.
 
-    Attention at small head_dim is bound by (S, S)-operand dot throughput,
-    not FLOPs (a (1024,64)x(64,1024) dot runs at ~1/10 the rate of a square
-    one on v5e), so the body minimizes VPU ops per score element:
+    At small head_dim the two dots leave the matrix unit half full and the
+    vector work per score element (mask, max, exp2, sum) is what the body
+    can save:
 
       * dots are bf16-in / f32-accumulate — never cast operands to f32
         (that demotes the MXU to its multi-pass f32 path);
       * sm_scale*log2(e) is pre-folded into the q tile by the caller
         (d ops/row, not bk) and the whole softmax runs in base-2 units;
       * the causal mask (iota+compare+select) runs ONLY on blocks
-        intersecting the diagonal — interior blocks take the unmasked body;
+        intersecting the diagonal — interior blocks take the unmasked
+        body, blocks above it are not visited;
       * exp2 runs on bf16 lanes (2x VPU width; p is consumed as bf16 by
         the p@v dot anyway, and max-subtraction bounds the error).
 
-    q: (block_q, d) with scale folded, base-2 units.  read_k/read_v:
-    kj -> (block_k, d).  Returns (acc f32 (block_q, d), m, l)."""
+    q: (block_q, d) with scale folded, base-2 units; ``qi`` its tile
+    index.  ``over`` walks the k blocks (`_fwd_rows`): `_span` takes the
+    interior blocks as one span and the diagonal's as another, a pass of
+    two steps at most; `_tile_loop` loops over them.  read_k/read_v:
+    (start, rows) -> (rows, d).  Returns (acc f32 (block_q, d), m, l)."""
 
-    num_k_blocks = pl.cdiv(seq_len, block_k)
+    num_k_blocks = seq_len // block_k
 
-    def body(kj, carry, masked):
+    def body(start, rows, carry, masked):
         acc, m_prev, l_prev = carry
-        k = read_k(kj)
-        v = read_v(kj)
+        k = read_k(start, rows)
+        v = read_v(start, rows)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # (bq, bk) f32
+        )  # (bq, rows) f32
         if masked:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            s = _causal_mask(s, qi * block_q, start)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp2(m_prev - m_new)
         p = jnp.exp2((s - m_new).astype(v.dtype))  # bf16: 2x VPU lanes
@@ -109,25 +155,22 @@ def _fwd_core(q, read_k, read_v, qi, *, causal, block_q, block_k, seq_len):
         return acc, m_new, l_new
 
     d = q.shape[-1]
-    init = (
+    carry = (
         jnp.zeros((block_q, d), jnp.float32),
         jnp.full((block_q, 1), _NEG_INF, jnp.float32),
         jnp.zeros((block_q, 1), jnp.float32),
     )
+    first_diag = last = num_k_blocks
     if causal:
         # interior blocks (strictly below the diagonal): no mask.
-        # blocks intersecting the diagonal band: masked body.
+        # blocks intersecting the diagonal band: masked body.  The blocks
+        # divide the sequence, so `last` stays within num_k_blocks.
         first_diag = (qi * block_q) // block_k
-        last = jnp.minimum(num_k_blocks,
-                           pl.cdiv((qi + 1) * block_q, block_k))
-        carry = jax.lax.fori_loop(
-            0, first_diag, lambda kj, c: body(kj, c, False), init)
-        acc, m, l = jax.lax.fori_loop(
-            first_diag, last, lambda kj, c: body(kj, c, True), carry)
-    else:
-        acc, m, l = jax.lax.fori_loop(
-            0, num_k_blocks, lambda kj, c: body(kj, c, False), init)
-    return acc, m, l
+        last = pl.cdiv((qi + 1) * block_q, block_k)
+    carry = over(0, first_diag, block_k,
+                 functools.partial(body, masked=False), carry)
+    return over(first_diag, last, block_k,
+                functools.partial(body, masked=True), carry)
 
 
 def _finish_fwd(acc, m, l, out_dtype):
@@ -138,59 +181,118 @@ def _finish_fwd(acc, m, l, out_dtype):
     return o, lse
 
 
-def _bwd_fused_core(q, k, v, do, lse, delta, *, sm_scale, causal, seq_len):
-    """Whole-(b,h)-slice backward: recompute s and dp ONCE, contract into
-    dq, dk, dv — 5 (S,S)-operand dots vs the split's 7.  q arrives with
-    sm_scale*log2e folded (base-2 units); lse is base-2; delta f32 (S, 1).
-    Returns (dq, dk, dv) in q's dtype."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)   # (S, S) f32
-    if causal:
-        q_pos = jax.lax.broadcasted_iota(jnp.int32, (seq_len, seq_len), 0)
-        k_pos = jax.lax.broadcasted_iota(jnp.int32, (seq_len, seq_len), 1)
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-    p = jnp.exp2((s - lse).astype(k.dtype))   # (S, S) bf16; masked -> 0
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)   # (S, S) f32
-    ds = p * (dp - delta).astype(k.dtype)     # (S, S) bf16
-    dq = jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * sm_scale
-    # q carries sm_scale*log2e; rescale dk back by ln2.
-    dk = jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * (1.0 / _LOG2E)
-    dv = jax.lax.dot_general(
-        p, do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return (dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype))
+def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dq_ref, dk_ref, dv_ref, dq_acc, cols, stat, *,
+                    sm_scale, causal, block_q, block_k, seq_len):
+    """One head's whole backward: for each k tile, the q tiles the diagonal
+    crosses as one masked span and the q tiles below it as one unmasked
+    span (`_span`; q tiles above are not visited); each span recomputes s
+    and dp ONCE and contracts them into dq, dk and dv — 5 dots vs the
+    split's 7.  Every extent is static: the sequence is short.
+
+    The refs hold all S rows; ``cols`` picks the head's columns of
+    q/k/v/do/dq/dk/dv and ``stat`` its column of lse/delta (natural-log
+    lse, f32 delta).  ``dq_acc`` is an f32 (S, head_dim) scratch: dq sums
+    over k tiles there and is scaled and written once at the end."""
+    num_q, num_k = seq_len // block_q, seq_len // block_k
+    # sm_scale * log2(e) folded into the q rows: s is in base-2 units, q
+    # also serves the dk dot (rescaled by ln2 at the end), and ds's
+    # trailing *sm_scale is hoisted onto dq.
+    scale = jnp.asarray(sm_scale * _LOG2E, q_ref.dtype)
+    dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    for kj in range(num_k):
+        k_rows = pl.ds(kj * block_k, block_k)
+        k = k_ref[k_rows, cols]
+        v = v_ref[k_rows, cols]
+
+        def q_span(start, rows, carry, masked):
+            dk_acc, dv_acc = carry
+            q_rows = pl.ds(start, rows)
+            q = q_ref[q_rows, cols] * scale
+            do = do_ref[q_rows, cols]
+            lse = lse_ref[q_rows, stat] * _LOG2E      # (rows, 1), base 2
+            delta = delta_ref[q_rows, stat]           # (rows, 1)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)   # (rows, bk) f32
+            if masked:
+                s = _causal_mask(s, start, kj * block_k)
+            p = jnp.exp2((s - lse).astype(k.dtype))   # bf16; masked -> 0
+            dp = jax.lax.dot_general(
+                do, v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)   # (rows, bk) f32
+            ds = p * (dp - delta).astype(k.dtype)     # (rows, bk) bf16
+            dq_acc[q_rows, :] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dk_acc = dk_acc + jax.lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dv_acc = dv_acc + jax.lax.dot_general(
+                p, do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return dk_acc, dv_acc
+
+        d = dq_acc.shape[-1]
+        acc = (jnp.zeros((block_k, d), jnp.float32),
+               jnp.zeros((block_k, d), jnp.float32))
+        first = below = 0
+        if causal:
+            first = (kj * block_k) // block_q
+            below = min(num_q, pl.cdiv((kj + 1) * block_k, block_q))
+        acc = _span(first, below, block_q,
+                    functools.partial(q_span, masked=True), acc)
+        dk_acc, dv_acc = _span(
+            below, num_q, block_q,
+            functools.partial(q_span, masked=False), acc)
+        dk_ref[k_rows, cols] = (dk_acc * (1.0 / _LOG2E)).astype(dk_ref.dtype)
+        dv_ref[k_rows, cols] = dv_acc.astype(dv_ref.dtype)
+
+    dq_ref[:, cols] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+
+
+def _kernel_call(*form):
+    """`jax.jit` for a function that makes the `pallas_call`s of one pass.
+    A model calls attention once a layer with the same shapes and tiles: as
+    a jitted function each kernel is traced and lowered to Mosaic once a
+    step and called from every layer, instead of once a layer.  Everything
+    that shapes the kernels is a static argument (``form``: the names a
+    pass adds to the common ones), so the cache keys on it."""
+    return functools.partial(
+        jax.jit, static_argnames=("sm_scale", "causal", "block_q", "block_k",
+                                  "interpret") + form)
 
 
 # ---------------------------------------------------------------------------
 # bhsd layout: arrays viewed (B*H, S, D), one head per grid step
 # ---------------------------------------------------------------------------
 
+def _fwd_rows(whole, seq_len, block_q):
+    """[(q tile index, its rows in the q block, how it walks its k blocks)]
+    of one grid step, the two forms of the forward.  ``whole``: the step
+    holds the whole sequence and the kernel walks its q tiles, every extent
+    static (`_span`).  Else the grid has a step per q tile, which loops
+    over its k blocks (`_tile_loop`)."""
+    if not whole:
+        return [(pl.program_id(1), slice(None), _tile_loop)]
+    return [(i, pl.ds(i * block_q, block_q), _span)
+            for i in range(seq_len // block_q)]
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
-                block_q, block_k, seq_len):
-    qi = pl.program_id(1)
-    q = q_ref[...] * jnp.asarray(sm_scale * _LOG2E, q_ref.dtype)
-    acc, m, l = _fwd_core(
-        q, lambda kj: k_ref[pl.ds(kj * block_k, block_k), :],
-        lambda kj: v_ref[pl.ds(kj * block_k, block_k), :], qi,
-        causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len)
-    o_ref[...], lse_ref[...] = _finish_fwd(acc, m, l, o_ref.dtype)
+                block_q, block_k, seq_len, whole):
+    for qi, rows, over in _fwd_rows(whole, seq_len, block_q):
+        q = q_ref[rows, :] * jnp.asarray(sm_scale * _LOG2E, q_ref.dtype)
+        acc, m, l = _fwd_core(
+            q, lambda start, n: k_ref[pl.ds(start, n), :],
+            lambda start, n: v_ref[pl.ds(start, n), :], qi, over,
+            causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len)
+        o_ref[rows, :], lse_ref[rows, :] = _finish_fwd(acc, m, l, o_ref.dtype)
 
 
-def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, *, sm_scale, causal, seq_len):
-    q = q_ref[...] * jnp.asarray(sm_scale * _LOG2E, q_ref.dtype)
-    dq, dk, dv = _bwd_fused_core(
-        q, k_ref[...], v_ref[...], do_ref[...],
-        lse_ref[...] * _LOG2E, delta_ref[...],
-        sm_scale=sm_scale, causal=causal, seq_len=seq_len)
-    dq_ref[...], dk_ref[...], dv_ref[...] = dq, dk, dv
+def _bwd_fused_kernel(*refs, **tiling):
+    _bwd_fused_core(*refs, slice(None), pl.ds(0, 1), **tiling)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
@@ -317,24 +419,29 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[...] = dv_acc.astype(dv_ref.dtype)
 
 
-def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, interpret):
+@_kernel_call("whole")
+def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, whole,
+                    interpret):
+    """``whole``: a grid step takes the whole sequence and walks its q
+    tiles; else one q tile (the forward's two forms: `_fwd_rows`)."""
     B, H, S, D = q.shape
     qf = q.reshape(B * H, S, D)
     kf = k.reshape(B * H, S, D)
     vf = v.reshape(B * H, S, D)
-    grid = (B * H, S // block_q)
+    rows = S if whole else block_q
+    grid = (B * H, S // rows)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, seq_len=S,
+        block_q=block_q, block_k=block_k, seq_len=S, whole=whole,
     )
-    qspec = pl.BlockSpec((None, block_q, D), lambda g, i: (g, i, 0))
+    qspec = pl.BlockSpec((None, rows, D), lambda g, i: (g, i, 0))
     kvspec = pl.BlockSpec((None, S, D), lambda g, i: (g, 0, 0))
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[qspec, kvspec, kvspec],
         out_specs=[qspec,
-                   pl.BlockSpec((None, block_q, 1), lambda g, i: (g, i, 0))],
+                   pl.BlockSpec((None, rows, 1), lambda g, i: (g, i, 0))],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
             jax.ShapeDtypeStruct((B * H, S, 1), jnp.float32),
@@ -345,8 +452,12 @@ def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     return o.reshape(B, H, S, D), lse.reshape(B, H, S)
 
 
+@_kernel_call("whole")
 def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
-                     interpret, delta=None):
+                     whole, interpret, delta=None):
+    """``whole``: the one-kernel backward, a grid step taking the whole
+    sequence; else the two-kernel split, blocked over q rows (dq) and over
+    k columns (dk/dv)."""
     B, H, S, D = q.shape
     qf = q.reshape(B * H, S, D)
     kf = k.reshape(B * H, S, D)
@@ -361,19 +472,21 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
             do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = delta.reshape(B * H, S, 1)
 
-    if block_q == block_k == S:
-        # fused single-pass backward: shares s/dp across dq/dk/dv.
+    if whole:
+        # one kernel, tiled inside: shares s/dp across dq/dk/dv.
         spec = pl.BlockSpec((None, S, D), lambda g, i: (g, 0, 0))
         row = pl.BlockSpec((None, S, 1), lambda g, i: (g, 0, 0))
         dq, dk, dv = pl.pallas_call(
             functools.partial(_bwd_fused_kernel, sm_scale=sm_scale,
-                              causal=causal, seq_len=S),
+                              causal=causal, block_q=block_q,
+                              block_k=block_k, seq_len=S),
             grid=(B * H, 1),
             in_specs=[spec, spec, spec, spec, row, row],
             out_specs=[spec, spec, spec],
             out_shape=[jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
                        jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
                        jax.ShapeDtypeStruct((B * H, S, D), v.dtype)],
+            scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
             interpret=interpret,
             compiler_params=_COMPILER_PARAMS,
         )(qf, kf, vf, dof, lsef, delta)
@@ -425,37 +538,31 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
 
 def _fwd_kernel_lanes(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
                       causal, heads_per_block, head_dim, block_q, block_k,
-                      seq_len):
-    """Refs: q/o (block_q, hpb*head_dim), k/v (S, hpb*head_dim), lse
-    (block_q, hpb).  Each 128-lane block carries hpb heads side by side;
-    the per-head chains run sequentially so their (S, S) temporaries
+                      seq_len, whole):
+    """Refs: q/o (rows, hpb*head_dim), k/v (S, hpb*head_dim), lse
+    (rows, hpb).  Each 128-lane block carries hpb heads side by side;
+    the per-head chains run sequentially so their tiles' temporaries
     reuse the same VMEM."""
-    qi = pl.program_id(1)
     for h in range(heads_per_block):
         sl = pl.ds(h * head_dim, head_dim)
-        q = q_ref[:, sl] * jnp.asarray(sm_scale * _LOG2E, q_ref.dtype)
-        acc, m, l = _fwd_core(
-            q, lambda kj: k_ref[pl.ds(kj * block_k, block_k), sl],
-            lambda kj: v_ref[pl.ds(kj * block_k, block_k), sl], qi,
-            causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len)
-        o, lse = _finish_fwd(acc, m, l, o_ref.dtype)
-        o_ref[:, sl] = o
-        lse_ref[:, h] = lse[:, 0]
+        for qi, rows, over in _fwd_rows(whole, seq_len, block_q):
+            q = q_ref[rows, sl] * jnp.asarray(sm_scale * _LOG2E, q_ref.dtype)
+            acc, m, l = _fwd_core(
+                q, lambda start, n: k_ref[pl.ds(start, n), sl],
+                lambda start, n: v_ref[pl.ds(start, n), sl], qi, over,
+                causal=causal, block_q=block_q, block_k=block_k,
+                seq_len=seq_len)
+            o, lse = _finish_fwd(acc, m, l, o_ref.dtype)
+            o_ref[rows, sl] = o
+            lse_ref[rows, pl.ds(h, 1)] = lse
 
 
-def _bwd_fused_kernel_lanes(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                            dq_ref, dk_ref, dv_ref, *, sm_scale, causal,
-                            heads_per_block, head_dim, seq_len):
+def _bwd_fused_kernel_lanes(*refs, heads_per_block, head_dim, **tiling):
+    """The per-head chains run one after the other, so the tiles'
+    temporaries and the dq scratch are one head's."""
     for h in range(heads_per_block):
-        sl = pl.ds(h * head_dim, head_dim)
-        q = q_ref[:, sl] * jnp.asarray(sm_scale * _LOG2E, q_ref.dtype)
-        dq, dk, dv = _bwd_fused_core(
-            q, k_ref[:, sl], v_ref[:, sl], do_ref[:, sl],
-            lse_ref[:, h][:, None] * _LOG2E, delta_ref[:, h][:, None],
-            sm_scale=sm_scale, causal=causal, seq_len=seq_len)
-        dq_ref[:, sl] = dq
-        dk_ref[:, sl] = dk
-        dv_ref[:, sl] = dv
+        _bwd_fused_core(*refs, pl.ds(h * head_dim, head_dim), pl.ds(h, 1),
+                        **tiling)
 
 
 def _lanes_config(H, D):
@@ -473,8 +580,9 @@ def _lanes_config(H, D):
     return hpb
 
 
+@_kernel_call("whole")
 def _pallas_forward_bshd(q, k, v, sm_scale, causal, block_q, block_k,
-                         interpret):
+                         whole, interpret):
     B, S, H, D = q.shape
     hpb = _lanes_config(H, D)
     qf = q.reshape(B, S, H * D)
@@ -482,20 +590,21 @@ def _pallas_forward_bshd(q, k, v, sm_scale, causal, block_q, block_k,
     vf = v.reshape(B, S, H * D)
     G = H // hpb                      # lane-block groups per batch entry
     W = hpb * D                       # == _LANE
-    grid = (B * G, S // block_q)
+    rows = S if whole else block_q
+    grid = (B * G, S // rows)
     kernel = functools.partial(
         _fwd_kernel_lanes, sm_scale=sm_scale, causal=causal,
         heads_per_block=hpb, head_dim=D, block_q=block_q, block_k=block_k,
-        seq_len=S,
+        seq_len=S, whole=whole,
     )
-    qspec = pl.BlockSpec((None, block_q, W), lambda g, i: (g // G, i, g % G))
+    qspec = pl.BlockSpec((None, rows, W), lambda g, i: (g // G, i, g % G))
     kvspec = pl.BlockSpec((None, S, W), lambda g, i: (g // G, 0, g % G))
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[qspec, kvspec, kvspec],
         out_specs=[qspec,
-                   pl.BlockSpec((None, block_q, hpb),
+                   pl.BlockSpec((None, rows, hpb),
                                 lambda g, i: (g, i, 0))],
         out_shape=[
             jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
@@ -509,9 +618,11 @@ def _pallas_forward_bshd(q, k, v, sm_scale, causal, block_q, block_k,
     return o.reshape(B, S, H, D), lse
 
 
-def _pallas_backward_bshd(q, k, v, o, lse, do, sm_scale, causal, interpret):
-    """Fused whole-S backward in the lane layout (requires S as the only
-    block — callers gate on that)."""
+@_kernel_call()
+def _pallas_backward_bshd(q, k, v, o, lse, do, sm_scale, causal, block_q,
+                          block_k, interpret):
+    """The one-kernel backward in the lane layout: a grid step takes the
+    whole sequence (callers gate on `_resolve`'s ``whole``)."""
     B, S, H, D = q.shape
     hpb = _lanes_config(H, D)
     G = H // hpb
@@ -532,13 +643,14 @@ def _pallas_backward_bshd(q, k, v, o, lse, do, sm_scale, causal, interpret):
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_fused_kernel_lanes, sm_scale=sm_scale,
                           causal=causal, heads_per_block=hpb, head_dim=D,
-                          seq_len=S),
+                          block_q=block_q, block_k=block_k, seq_len=S),
         grid=(B * G, 1),
         in_specs=[spec, spec, spec, spec, row, row],
         out_specs=[spec, spec, spec],
         out_shape=[jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
                    jax.ShapeDtypeStruct((B, S, H * D), k.dtype),
                    jax.ShapeDtypeStruct((B, S, H * D), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
     )(qf, kf, vf, dof, lsef, delta)
@@ -638,11 +750,58 @@ def _auto_block(S: int, cap: int) -> int:
     return max(b, 1)
 
 
-def _resolve(q, S, sm_scale, block_q, block_k):
+# Largest tile of the two-kernel backward past _WHOLE_SEQ_MAX: it holds
+# s, p, dp and ds of one (bq, bk) tile at once, and at 1024 x 1024 they
+# pass the 16 MB of scoped VMEM (Mosaic refuses the dk/dv kernel at
+# S = 4096, D = 128).
+_SPLIT_BWD_MAX_BLOCK = 512
+
+
+def _auto_tiles(S: int, causal: bool):
+    """((block_q, block_k) of the forward, the same of the backward) for a
+    call that names no blocks: a function of what a call can see of itself.
+    Non-causal attention has no tile to skip and takes the largest block,
+    and so does a sequence past `_WHOLE_SEQ_MAX`, whose 1024-blocks skip
+    already.  A shorter causal sequence takes the tiles a sweep on a v5e
+    found fastest (`tools/chip_kernels.py --sweep`; PERF.md §6, PR 31):
+    smaller ones skip more of the square and pay more fixed cost.  Swept:
+    S = 1,024 at head_dim 64 and 128, and S = 512 at head_dim 64; the same
+    caps won at each, and other lengths take them unmeasured."""
+    whole = _auto_block(S, 1024)
+    if not causal or S > _WHOLE_SEQ_MAX:
+        return (whole, whole), (whole, whole)
+
+    def tile(cap):
+        b = _auto_block(S, cap)
+        return b if b >= 128 else whole  # under 128 rows is no tile
+
+    fwd, bwd = tile(512), tile(256)
+    return (fwd, fwd), (bwd, bwd)
+
+
+def _resolve(q, S, causal, sm_scale, block_q, block_k):
+    """(sm_scale, whole, forward tiles, backward tiles).  ``whole``: a grid
+    step takes the whole sequence, which picks the forward's form
+    (`_fwd_rows`) and the one-kernel backward; it is decided here, outside
+    the jitted kernel calls, and reaches them as a static argument.
+    Explicit blocks override `_auto_tiles` in both passes."""
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
-    bq = min(block_q, S) if block_q else _auto_block(S, 1024)
-    bk = min(block_k, S) if block_k else _auto_block(S, 1024)
-    return scale, bq, bk
+    return (scale, S <= _WHOLE_SEQ_MAX) + tuple(
+        (min(block_q, S) if block_q else bq, min(block_k, S) if block_k else bk)
+        for bq, bk in _auto_tiles(S, causal))
+
+
+def _count_tiles(S, block_q, block_k, causal, kernels=1):
+    """Add one kernel's tiles to the job timeline, as the step is traced:
+    `attention.tiles` the (block_q x block_k) tiles of the S x S score
+    square, `attention.tiles_skipped` those of them wholly above the
+    diagonal, which a causal kernel does not visit.  Once per kernel in the
+    traced program (not per head slice or grid step)."""
+    num_q, num_k = S // block_q, S // block_k
+    skipped = sum(num_k - min(num_k, pl.cdiv((i + 1) * block_q, block_k))
+                  for i in range(num_q)) if causal else 0
+    tracing.count("attention.tiles", kernels * num_q * num_k)
+    tracing.count("attention.tiles_skipped", kernels * skipped)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -650,10 +809,10 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
                     block_q=None, block_k=None):
     """Multi-head attention over (batch, heads, seq, head_dim) tensors.
 
-    Default blocks are large ((1024, 1024)-capped: `_auto_block`) and the
-    grid dims are marked parallel for Mosaic.  A block that is the whole
-    sequence is what enables the fused one-pass backward (5 big dots
-    instead of 7); the two-kernel backward caps its own tiles
+    Blocks the call does not name come from `_auto_tiles`; the grid dims
+    are marked parallel for Mosaic.  Up to `_WHOLE_SEQ_MAX` the
+    backward is one kernel tiled inside (5 dots a tile instead of 7); the
+    two-kernel backward past it caps its own tiles
     (`_SPLIT_BWD_MAX_BLOCK`)."""
     o, _ = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k)
     return o
@@ -661,7 +820,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     S = q.shape[2]
-    scale, bq, bk = _resolve(q, S, sm_scale, block_q, block_k)
+    scale, whole, (bq, bk), _ = _resolve(q, S, causal, sm_scale, block_q,
+                                         block_k)
     reference = functools.partial(reference_attention, sm_scale=scale,
                                   causal=causal)
     problem = _tiling_problem(S, bq, bk)
@@ -669,26 +829,20 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
         _warn_reference(q.shape, bq, bk, problem)
         o, lse = reference(q, k, v)
     else:
+        _count_tiles(S, bq, bk, causal)
         o, lse = _by_platform(
             functools.partial(_pallas_forward, sm_scale=scale, causal=causal,
-                              block_q=bq, block_k=bk),
+                              block_q=bq, block_k=bk, whole=whole),
             reference, q, k, v)
     return o, (q, k, v, o, lse)
-
-
-# Largest tile of the two-kernel backward (blocks that are not the whole
-# sequence); the fused whole-sequence backward is not held to it.
-_SPLIT_BWD_MAX_BLOCK = 512
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, res, do, delta=None):
     q, k, v, o, lse = res
     S = q.shape[2]
-    scale, bq, bk = _resolve(q, S, sm_scale, block_q, block_k)
-    if not bq == bk == S:
-        # the two-kernel split holds s, p, dp and ds of one (bq, bk) tile
-        # at once: at 1024 x 1024 they pass the 16 MB of scoped VMEM
-        # (Mosaic refuses the dk/dv kernel at S = 4096, D = 128)
+    scale, whole, _, (bq, bk) = _resolve(q, S, causal, sm_scale, block_q,
+                                         block_k)
+    if not whole:
         bq, bk = min(bq, _SPLIT_BWD_MAX_BLOCK), min(bk, _SPLIT_BWD_MAX_BLOCK)
     # delta = rowsum(do * o): cheap elementwise+reduce, XLA fuses it.
     # Callers looping over K/V chunks (ring attention) pass it precomputed
@@ -700,10 +854,11 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, do, delta=None):
     if problem:
         _warn_reference(q.shape, bq, bk, problem)
         return _reference_backward(q, k, v, lse, do, delta, scale, causal)
+    _count_tiles(S, bq, bk, causal, kernels=1 if whole else 2)
 
     def kernel(q, k, v, o, lse, do, delta, interpret):
         return _pallas_backward(q, k, v, o, lse, do, scale, causal, bq, bk,
-                                interpret, delta=delta)
+                                whole, interpret, delta=delta)
 
     def reference(q, k, v, o, lse, do, delta):
         return _reference_backward(q, k, v, lse, do, delta, scale, causal)
@@ -714,21 +869,10 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, do, delta=None):
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-# VMEM budget gate for the fused lane backward: its per-head temporaries
-# (s f32 + dp f32 + p/ds bf16 at (S, S)) must fit the ~16 MB scoped VMEM.
-_LANES_MAX_SEQ = 1024
-
-
 def _bshd_lanes_ok(q, S, bq, bk):
     B, _, H, D = q.shape
     return (_lanes_config(H, D) is not None and S % 128 == 0
-            and S % bq == 0 and S % bk == 0)
-
-
-def _bshd_lanes_bwd_ok(q, S):
-    # the fused lane backward always runs whole-S blocks (one grid step per
-    # lane group) — gate on the (S, S) temporaries fitting scoped VMEM.
-    return _bshd_lanes_ok(q, S, S, S) and S <= _LANES_MAX_SEQ
+            and _tiling_problem(S, bq, bk) is None)
 
 
 def _tr(x):
@@ -741,8 +885,9 @@ def flash_attention_bshd(q, k, v, causal=False, sm_scale=None,
     """Multi-head attention over (batch, seq, heads, head_dim) tensors —
     the layout models naturally produce from the fused qkv projection.
 
-    When the lane tiling applies (head_dim divides 128, whole-S blocks,
-    S <= 1024) the kernels index heads through 128-wide lane blocks and no
+    When the lane tiling applies (head_dim divides 128, heads fill whole
+    lane blocks; for the backward S <= `_WHOLE_SEQ_MAX`) the kernels
+    index heads through 128-wide lane blocks and no
     (B,S,H,D) <-> (B,H,S,D) transpose ever materializes; otherwise the
     call transposes to the bhsd kernels (still flash, just with the
     transpose cost the lane path avoids)."""
@@ -752,8 +897,11 @@ def flash_attention_bshd(q, k, v, causal=False, sm_scale=None,
 
 def _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k):
     S = q.shape[1]
-    scale, bq, bk = _resolve(q, S, sm_scale, block_q, block_k)
-    if _tiling_problem(S, bq, bk) is None and _bshd_lanes_ok(q, S, bq, bk):
+    scale, whole, (bq, bk), _ = _resolve(q, S, causal, sm_scale, block_q,
+                                         block_k)
+    if _bshd_lanes_ok(q, S, bq, bk):
+        _count_tiles(S, bq, bk, causal)
+
         def reference(q, k, v):
             o, lse = reference_attention(_tr(q), _tr(k), _tr(v), scale,
                                           causal)
@@ -761,7 +909,8 @@ def _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k):
 
         o, lse = _by_platform(
             functools.partial(_pallas_forward_bshd, sm_scale=scale,
-                              causal=causal, block_q=bq, block_k=bk),
+                              causal=causal, block_q=bq, block_k=bk,
+                              whole=whole),
             reference, q, k, v)
         return o, (q, k, v, o, lse)
     o, (_, _, _, ot, lse) = _flash_fwd(_tr(q), _tr(k), _tr(v), causal,
@@ -772,8 +921,11 @@ def _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k):
 def _flash_bwd_bshd(causal, sm_scale, block_q, block_k, res, do):
     q, k, v, o, lse = res
     S = q.shape[1]
-    scale, bq, bk = _resolve(q, S, sm_scale, block_q, block_k)
-    if _tiling_problem(S, bq, bk) is None and _bshd_lanes_bwd_ok(q, S):
+    scale, whole, _, (bq, bk) = _resolve(q, S, causal, sm_scale, block_q,
+                                         block_k)
+    if whole and _bshd_lanes_ok(q, S, bq, bk):
+        _count_tiles(S, bq, bk, causal)
+
         def reference(q, k, v, o, lse, do):
             qt, kt, vt, ot, dot = map(_tr, (q, k, v, o, do))
             delta = jnp.sum(
@@ -783,7 +935,7 @@ def _flash_bwd_bshd(causal, sm_scale, block_q, block_k, res, do):
 
         return _by_platform(
             functools.partial(_pallas_backward_bshd, sm_scale=scale,
-                              causal=causal),
+                              causal=causal, block_q=bq, block_k=bk),
             reference, q, k, v, o, lse, do)
     dq, dk, dv = _flash_bwd(causal, sm_scale, block_q, block_k,
                             (_tr(q), _tr(k), _tr(v), _tr(o), lse), _tr(do))

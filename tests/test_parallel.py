@@ -80,11 +80,15 @@ def test_flash_attention_backward_matches_reference():
 @pytest.mark.parametrize("bq,bk", [(128, 128), (128, 256), (256, 128),
                                    (256, 256)])
 @pytest.mark.parametrize("causal", [False, True])
-def test_pallas_kernels_direct_multiblock(bq, bk, causal):
+@pytest.mark.parametrize("whole", [False, True])
+def test_pallas_kernels_direct_multiblock(bq, bk, causal, whole):
     """Exercise _pallas_forward/_pallas_backward directly (interpret mode)
-    at S=256 with mixed block sizes — the production-shaped multi-block
-    causal split (first_diag/diag_end two-phase fori loops) that the
-    _use_pallas gate keeps out of the public-API path on CPU."""
+    at S=256 with mixed block sizes, in both forms a call can take.  Not
+    ``whole`` (what a sequence past `_WHOLE_SEQ_MAX` runs): the grid walks
+    the forward's q tiles, each with its first_diag/last two-phase fori
+    loops over k blocks, and the backward is the two-kernel split with the
+    same loops.  ``whole``: a grid step takes the sequence, the forward
+    walks its q tiles over merged spans and the backward is one kernel."""
     from ray_tpu.ops.flash_attention import (
         _pallas_backward,
         _pallas_forward,
@@ -94,7 +98,8 @@ def test_pallas_kernels_direct_multiblock(bq, bk, causal):
     q, k, v = _qkv(B, H, S, D)
     scale = D ** -0.5
 
-    o, lse = _pallas_forward(q, k, v, scale, causal, bq, bk, interpret=True)
+    o, lse = _pallas_forward(q, k, v, scale, causal, bq, bk, whole=whole,
+                             interpret=True)
     ref_o, ref_lse = reference_attention(q, k, v, scale, causal)
     np.testing.assert_allclose(np.asarray(o), np.asarray(ref_o), atol=TOL)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
@@ -107,11 +112,11 @@ def test_pallas_kernels_direct_multiblock(bq, bk, causal):
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     do = (2.0 * o).astype(q.dtype)  # d/do of sum(o^2)
     dq, dk, dv = _pallas_backward(q, k, v, o, lse, do, scale, causal,
-                                  bq, bk, interpret=True)
+                                  bq, bk, whole=whole, interpret=True)
     for a, b, name in zip((dq, dk, dv), gr, ("dq", "dk", "dv")):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=5e-2,
-            err_msg=f"{name} causal={causal} bq={bq} bk={bk}")
+            err_msg=f"{name} causal={causal} bq={bq} bk={bk} whole={whole}")
 
 
 def test_ring_attention_matches_dense():
@@ -345,13 +350,15 @@ def test_flash_attention_bshd_lane_path(H, D, causal):
 
 
 # (B, S, H, D) -> Mosaic kernels in forward + backward: the lane kernels
-# with the fused backward, the transposing bhsd kernels where heads do not
-# pair up into 128 lanes (25, 3), and past _LANES_MAX_SEQ the dq and dk/dv
-# kernels of the two-kernel backward
-@pytest.mark.parametrize("shape,kernels", [
-    ((16, 1024, 12, 64), 2), ((8, 1024, 16, 64), 2), ((4, 1024, 25, 64), 2),
-    ((16, 1024, 3, 64), 2), ((2, 2048, 32, 128), 3)])
-def test_flash_attention_lowers_to_mosaic_for_tpu(shape, kernels):
+# with the one-kernel backward, the transposing bhsd kernels where heads do
+# not pair up into 128 lanes (25, 3), and past _WHOLE_SEQ_MAX the dq and
+# dk/dv kernels of the two-kernel backward; last, the medium cell's own
+# shape with 256-tiles forced in both passes
+@pytest.mark.parametrize("shape,kernels,block", [
+    ((16, 1024, 12, 64), 2, None), ((8, 1024, 16, 64), 2, None),
+    ((4, 1024, 25, 64), 2, None), ((16, 1024, 3, 64), 2, None),
+    ((2, 2048, 32, 128), 3, None), ((16, 1024, 16, 64), 2, 256)])
+def test_flash_attention_lowers_to_mosaic_for_tpu(shape, kernels, block):
     """Exported for a TPU from this CPU host, forward + backward are Mosaic
     custom calls and nothing else: no interpreted kernel body and no O(S^2)
     reference (either would show up as dots in the module).  Catches a
@@ -359,7 +366,7 @@ def test_flash_attention_lowers_to_mosaic_for_tpu(shape, kernels):
     from ray_tpu.ops.flash_attention import flash_attention_bshd
 
     def loss(q, k, v):
-        o = flash_attention_bshd(q, k, v, True)
+        o = flash_attention_bshd(q, k, v, True, None, block, block)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
@@ -398,6 +405,159 @@ def test_flash_attention_fused_bwd_mixed_dtypes():
     assert dq.dtype == jnp.float32
     assert dk.dtype == jnp.bfloat16
     assert dv.dtype == jnp.bfloat16
+
+
+def _pallas_kernels(jaxpr, found=None):
+    """{kernel function's name: its body holds a loop} over the
+    `pallas_call`s of a jaxpr, nested ones (jit, the platform's branches,
+    custom_vjp) included."""
+    def subjaxprs(value):
+        if isinstance(value, jex_core.ClosedJaxpr):
+            yield value.jaxpr
+        elif isinstance(value, jex_core.Jaxpr):
+            yield value
+        elif isinstance(value, (tuple, list)):
+            for item in value:
+                yield from subjaxprs(item)
+
+    from jax.extend import core as jex_core
+
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["jaxpr"].debug_info.func_name
+            # fori_loop: `while` under traced bounds, `scan` under static
+            body = str(eqn.params["jaxpr"])
+            found[name] = (found.get(name, False) or "while[" in body
+                           or "scan[" in body)
+        for value in eqn.params.values():
+            for sub in subjaxprs(value):
+                _pallas_kernels(sub, found)
+    return found
+
+
+def _bshd_against_reference(q, k, v, causal, block_q, block_k, kernels):
+    """Forward and dq / dk / dv of `flash_attention_bshd` with these tiles
+    against the dense reference, at the tolerances of the tests above; and
+    that the kernels that ran were ``kernels`` (`_pallas_kernels`)."""
+    from ray_tpu.ops.flash_attention import flash_attention_bshd
+
+    D = q.shape[-1]
+    tr = lambda x: x.transpose(0, 2, 1, 3)
+
+    def loss_flash(q, k, v):
+        o = flash_attention_bshd(q, k, v, causal, None, block_q, block_k)
+        return jnp.sum(o.astype(jnp.float32) ** 2), o
+
+    def loss_ref(q, k, v):
+        o, _ = reference_attention(tr(q), tr(k), tr(v), D ** -0.5, causal)
+        return jnp.sum(o.astype(jnp.float32) ** 2), tr(o)
+
+    grad = lambda f: jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+    assert _pallas_kernels(
+        jax.make_jaxpr(grad(loss_flash))(q, k, v).jaxpr) == kernels
+    (_, o), gf = grad(loss_flash)(q, k, v)
+    (_, ref), gr = grad(loss_ref)(q, k, v)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(ref), atol=TOL)
+    for a, b, name in zip(gf, gr, ("dq", "dk", "dv")):
+        assert a.dtype == b.dtype, name
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), atol=5e-2,
+            err_msg=f"{name} causal={causal} tiles=({block_q}, {block_k})")
+
+
+# {kernel: its body loops} of a gradient in the two forms (`_pallas_kernels`)
+_WHOLE_LANES = {"_fwd_kernel_lanes": False, "_bwd_fused_kernel_lanes": False}
+_WHOLE_BHSD = {"_fwd_kernel": False, "_bwd_fused_kernel": False}
+_LOOPED_SPLIT = {"_bwd_dq_kernel": True, "_bwd_dkv_kernel": True}
+
+
+def _bshd_qkv(S, H, D, dtype=jnp.float32):
+    key = jax.random.PRNGKey(2)
+    return tuple(
+        jax.random.normal(jax.random.fold_in(key, i), (1, S, H, D), dtype)
+        * 0.5 for i in range(3))
+
+
+# heads x head_dim: two heads a lane block, one, and 3 heads of 64, which
+# fill no lane block and take the transposing head-major kernels
+@pytest.mark.parametrize("H,D", [(2, 64), (1, 128), (3, 64)])
+@pytest.mark.parametrize("bq,bk", [(128, 128), (128, 256), (256, 128)])
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention_tiled(H, D, bq, bk, causal):
+    """Several tiles inside the kernels that take a short sequence whole
+    (forward walking its q tiles, one-kernel backward): spans merged below
+    the diagonal, masked on it, nothing above it."""
+    from ray_tpu.ops.flash_attention import _bshd_lanes_ok
+
+    q, k, v = _bshd_qkv(256, H, D)
+    assert _bshd_lanes_ok(q, 256, bq, bk) == (H != 3)
+    _bshd_against_reference(q, k, v, causal, bq, bk,
+                            _WHOLE_BHSD if H == 3 else _WHOLE_LANES)
+
+
+@pytest.mark.parametrize("tiles", [2, 4])
+def test_flash_attention_tiled_diagonal_only_rows(tiles):
+    """`tiles` square tiles a side: the first row tile has only its masked
+    diagonal tile (no interior span), the last k tile only its diagonal q
+    tile (no span below it)."""
+    q, k, v = _bshd_qkv(128 * tiles, 2, 64)
+    _bshd_against_reference(q, k, v, True, 128, 128, _WHOLE_LANES)
+
+
+def test_flash_attention_tiled_mixed_dtypes():
+    """k/v in bf16 under an f32 q through the tiled one-kernel backward:
+    dk/dv come back in k/v's dtype (the regression of
+    test_flash_attention_fused_bwd_mixed_dtypes, several tiles)."""
+    q, k, v = _bshd_qkv(256, 2, 64)
+    _bshd_against_reference(q, k.astype(jnp.bfloat16),
+                            v.astype(jnp.bfloat16), True, 128, 128,
+                            _WHOLE_LANES)
+
+
+@pytest.mark.parametrize("H,D", [(2, 64), (3, 64)])
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention_long_sequence_forms(H, D, causal, monkeypatch):
+    """Past `_WHOLE_SEQ_MAX` (lowered here so that an interpretable size
+    passes it) the grid walks the forward's q tiles, each looping over its
+    k blocks, and the backward is the two-kernel split: three kernels with
+    loops, whatever an earlier test traced at these shapes (the form is a
+    static argument of the jitted kernel calls)."""
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_WHOLE_SEQ_MAX", 128)
+    q, k, v = _bshd_qkv(256, H, D)
+    forward = "_fwd_kernel" if H == 3 else "_fwd_kernel_lanes"
+    _bshd_against_reference(q, k, v, causal, 128, 128,
+                            {forward: True, **_LOOPED_SPLIT})
+
+
+def test_flash_attention_counts_its_tiles():
+    """`attention.tiles` / `attention.tiles_skipped` on the job timeline:
+    once per kernel as it is traced, not per head slice."""
+    from ray_tpu.ops.flash_attention import flash_attention_bshd
+    from ray_tpu.util import tracing
+
+    x = jax.ShapeDtypeStruct((2, 1024, 4, 64), jnp.bfloat16)
+
+    def traced(causal, block):
+        def loss(q, k, v):
+            o = flash_attention_bshd(q, k, v, causal, None, block, block)
+            return jnp.sum(o.astype(jnp.float32))
+
+        before = [tracing.counter(name) for name in names]
+        jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+        return [tracing.counter(name) - b for name, b in zip(names, before)]
+
+    names = ("attention.tiles", "attention.tiles_skipped")
+    assert traced(True, 256) == [0, 0]           # no job, no count
+    with tracing.timeline_span("train.fit", root=True) as job:
+        # forward 16 tiles of which 6 above the diagonal, backward the same
+        assert traced(True, 256) == [32, 12]
+        assert traced(False, 256) == [32, 0]
+        # what a call that names no tiles takes: 512 forward, 256 backward
+        assert traced(True, None) == [4 + 16, 1 + 6]
+    tracing.timeline_take(job.trace_id)
 
 
 def test_pipeline_moe_aux_collected_under_pp():
