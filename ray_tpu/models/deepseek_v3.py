@@ -1,0 +1,367 @@
+"""DeepSeek-V3-shaped decoders (`model_type` `deepseek_v3`) for the Train
+path: latent attention (MLA) and a sigmoid-scored, bias-corrected mixture
+of experts with shared experts, after leading dense layers.
+
+DeepSeek-AI, "DeepSeek-V3 Technical Report" (arXiv:2412.19437); layer
+equations as the public `modeling_deepseek_v3.py`, for a config without
+query compression (`q_lora_rank` null) and one routing group:
+
+  h = x + Attn(RMSNorm(x));  y = h + F(RMSNorm(h));  final RMSNorm; an
+  untied head.  F is a SwiGLU in the first `n_dense_layer` layers and the
+  mixture after.
+  Attn: q = u W_q, a head's [q_nope | q_rope];  [c | k_r] = u W_kv_a;
+  c = RMSNorm(c);  a head's [k_nope | v] = c W_kv_b;  RoPE on q_rope of
+  every head and on the ONE k_r all heads share;  k = [k_nope | k_r];
+  causal softmax of q k' at (nope + rope)^-1/2;  o = P v;  W_o.
+  Mixture: s = sigmoid(u W_g) in float32 over all experts; the top k of
+  s + b (b the routing bias `e_score_correction_bias`); weights s at the
+  chosen, over their sum + 1e-20, times `routed_scale`;
+  F(u) = sum w_i E_i(u) + Shared(u), every one a SwiGLU, no token dropped,
+  no auxiliary loss.
+  b (`noaux_tc`) is no optimizer leaf: after a step
+  b_e += speed * sign(mean(n) - n_e), n the rows each expert was sent.
+
+Departures from that description, none of which changes a score or a sum:
+
+- `rope_interleave`: the rotary dims are taken apart ([evens | odds]) and
+  rotated as halves, as the public modeling code does, so q_rope and k_r
+  hold the pairwise rotation in another order of their dims, the same on
+  both (`layers.rope`).
+- Training multiplies the latent out: a head's k and v exist.  The latent
+  cache and the absorbed weights are serving's and are not here.
+- ``held`` = (first, count): one chip's share of an expert-parallel layer.
+  The router, the shared experts, attention and the dense layers are
+  whole; only the held experts' matrices exist and only their part of the
+  sum is computed (`ops/moe.py:moe_dispatch`); what the absent experts
+  would add is another chip's, and the exchange that would bring it (and
+  sum n over the data-parallel group for the bias rule) is not written.
+- `vocab_size` is the rows of embedding and head held here (a slice of the
+  vocabulary is a smaller vocabulary).
+
+What it shares with the other models: `models/layers.py` (RMSNorm, RoPE,
+the SwiGLU, the chunked loss, the mixed-precision step and its place for
+state that moves by a rule), `parallel/attention.py` (the flash kernels,
+here with q/k and v of two widths) and `ops/moe.py`; the names are those
+`parallel/sharding.py` lays out.
+
+`jax.named_scope`s: attention/latent_down, attention/latent_up,
+attention/kernel, moe/route, moe/dispatch, moe/experts, moe/shared,
+moe/combine, dense_mlp, head_and_loss, optimizer_update,
+routing_bias_update.
+"""
+
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.layers import (
+    chunked_xent,
+    num_params,  # noqa: F401  (`deepseek_v3.num_params` is public)
+    rms_norm,
+    rope,
+    swiglu,
+    train_step,
+)
+from ray_tpu.ops.moe import moe_dispatch
+from ray_tpu.parallel.attention import attention
+
+ROUTING_BIAS = "e_score_correction_bias"
+
+
+@dataclass(frozen=True)
+class DeepseekV3Config:
+    vocab_size: int = 128256          # rows of embedding and head held here
+    n_layer: int = 48
+    n_dense_layer: int = 1            # `first_k_dense_replace`
+    n_head: int = 32
+    n_embd: int = 2048
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    dense_width: int = 6144
+    expert_width: int = 768
+    shared_width: int = 1536          # n_shared_experts x expert_width
+    n_experts: int = 128              # the router's width
+    held: Optional[Tuple[int, int]] = None   # (first, count); None: all
+    top_k: int = 6
+    routed_scale: float = 2.448
+    rope_theta: float = 1e6
+    rms_eps: float = 1e-6
+    bias_update_speed: float = 0.001  # gamma of arXiv:2412.19437
+    compute_dtype: Any = jnp.bfloat16
+    remat: bool = False               # jax.checkpoint each layer
+    loss_chunk_rows: int = 2048       # `layers.chunked_xent`
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1] if self.held else self.n_experts
+
+    @property
+    def moe_layers(self):
+        return range(self.n_dense_layer, self.n_layer)
+
+
+KANANA_2_30B_A3B = DeepseekV3Config()
+DEEPSEEK_V3_TINY = DeepseekV3Config(
+    vocab_size=512, n_layer=3, n_dense_layer=1, n_head=4, n_embd=64,
+    kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+    dense_width=96, expert_width=24, shared_width=48, n_experts=8, top_k=3,
+    loss_chunk_rows=32)
+
+
+def init_params(rng, cfg: DeepseekV3Config) -> Dict[str, Any]:
+    """Normal(0, 0.02) matrices, unit norm gains, routing biases 0.  Names
+    are those `parallel/sharding.py:infer_param_logical_dims` lays out; the
+    experts' stacks hold the `cfg.n_held` experts that live here."""
+    std = 0.02
+    E, H, R = cfg.n_embd, cfg.n_head, cfg.kv_lora_rank
+    keys = jax.random.split(rng, 2 + cfg.n_layer)
+
+    def kernel(key, *shape):
+        return {"kernel": jax.random.normal(key, shape, jnp.float32) * std}
+
+    def scale(width=E):
+        return {"scale": jnp.ones((width,), jnp.float32)}
+
+    def mlp(ks, width):
+        return {"gate_proj": kernel(ks[0], E, width),
+                "up_proj": kernel(ks[1], E, width),
+                "down_proj": kernel(ks[2], width, E)}
+
+    params = {
+        "embed_tokens": {
+            "embedding": kernel(keys[0], cfg.vocab_size, E)["kernel"]},
+        "norm_f": scale(),
+        "lm_head": kernel(keys[1], E, cfg.vocab_size),
+    }
+    for i in range(cfg.n_layer):
+        ks = jax.random.split(keys[2 + i], 11)
+        layer = {
+            "input_norm": scale(),
+            "attn": {
+                "q_proj": kernel(ks[0], E, H * cfg.qk_head_dim),
+                "kv_a_proj": kernel(ks[1], E, R + cfg.qk_rope_dim),
+                "kv_a_norm": scale(R),
+                "kv_b_proj": kernel(
+                    ks[2], R, H * (cfg.qk_nope_dim + cfg.v_head_dim)),
+                "o_proj": kernel(ks[3], H * cfg.v_head_dim, E),
+            },
+            "post_norm": scale(),
+        }
+        if i < cfg.n_dense_layer:
+            layer["mlp"] = mlp(ks[4:7], cfg.dense_width)
+        else:
+            n, W = cfg.n_held, cfg.expert_width
+            layer["moe"] = {
+                "router": {
+                    **kernel(ks[4], E, cfg.n_experts),
+                    ROUTING_BIAS: jnp.zeros((cfg.n_experts,), jnp.float32)},
+                "wi_gate": kernel(ks[5], n, E, W)["kernel"],
+                "wi_up": kernel(ks[6], n, E, W)["kernel"],
+                "wo": kernel(ks[7], n, W, E)["kernel"],
+                "shared": mlp(ks[8:11], cfg.shared_width),
+            }
+        params[f"layer_{i}"] = layer
+    return params
+
+
+def _mlp(x, p):
+    return swiglu(x, *(p[name]["kernel"].astype(x.dtype)
+                       for name in ("gate_proj", "up_proj", "down_proj")))
+
+
+def _attention(x, p, cfg: DeepseekV3Config):
+    B, S, _ = x.shape
+    H, R, nope = cfg.n_head, cfg.kv_lora_rank, cfg.qk_nope_dim
+    kernel = lambda name: p[name]["kernel"].astype(x.dtype)
+    positions = jnp.arange(S)
+    turn = functools.partial(rope, positions=positions,
+                             theta=cfg.rope_theta, interleaved=True)
+    with jax.named_scope("latent_down"):
+        q = (x @ kernel("q_proj")).reshape(B, S, H, cfg.qk_head_dim)
+        latent = x @ kernel("kv_a_proj")
+        c = rms_norm(latent[..., :R], p["kv_a_norm"], cfg.rms_eps)
+        k_rope = turn(latent[..., None, R:])            # (B, S, 1, rope)
+    with jax.named_scope("latent_up"):
+        kv = (c @ kernel("kv_b_proj")).reshape(
+            B, S, H, nope + cfg.v_head_dim)
+        q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
+        k = jnp.concatenate([
+            kv[..., :nope],
+            jnp.broadcast_to(k_rope, (B, S, H, cfg.qk_rope_dim))], axis=-1)
+        v = kv[..., nope:]
+    with jax.named_scope("kernel"):
+        o = attention(q, k, v)                          # (B, S, H, v_head_dim)
+    return o.reshape(B, S, H * cfg.v_head_dim) @ kernel("o_proj")
+
+
+def _route(xt, router, cfg: DeepseekV3Config):
+    """-> (weights (T, k) f32, experts (T, k) int32) over all experts."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        xt, router["kernel"].astype(xt.dtype),
+        preferred_element_type=jnp.float32))                  # (T, N)
+    # the bias picks and does not weigh; nothing differentiates through it
+    _, experts = jax.lax.top_k(
+        scores + jax.lax.stop_gradient(router[ROUTING_BIAS]), cfg.top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return weights * cfg.routed_scale, experts
+
+
+def _moe(x, p, cfg: DeepseekV3Config):
+    """-> (y, rows this chip's tokens sent to each of all the experts)."""
+    B, S, E = x.shape
+    xt = x.reshape(B * S, E)
+    with jax.named_scope("route"):
+        weights, experts = _route(xt, p["router"], cfg)
+
+    def run(xs, group_sizes):
+        grouped = lambda a, w: jax.lax.ragged_dot(a, w, group_sizes)
+        return swiglu(xs, p["wi_gate"], p["wi_up"], p["wo"], matmul=grouped)
+
+    y, rows = moe_dispatch(xt, weights, experts, cfg.n_experts, run,
+                           held=cfg.held)
+    with jax.named_scope("shared"):
+        y = y + _mlp(xt, p["shared"])
+    return y.reshape(B, S, E), rows
+
+
+def _layer(x, p, cfg: DeepseekV3Config):
+    """-> (x, the rows sent to each expert; None from a dense layer)."""
+    with jax.named_scope("attention"):
+        x = x + _attention(rms_norm(x, p["input_norm"], cfg.rms_eps),
+                           p["attn"], cfg)
+    u = rms_norm(x, p["post_norm"], cfg.rms_eps)
+    if "mlp" in p:
+        with jax.named_scope("dense_mlp"):
+            return x + _mlp(u, p["mlp"]), None
+    with jax.named_scope("moe"):
+        y, rows = _moe(u, p["moe"], cfg)
+    return x + y, rows
+
+
+def _trunk(params, tokens, cfg: DeepseekV3Config):
+    """-> ((B, S, E) after the final norm, the routers' statistics)."""
+    x = params["embed_tokens"]["embedding"][tokens].astype(cfg.compute_dtype)
+    layer = jax.checkpoint(_layer, static_argnums=(2,)) if cfg.remat \
+        else _layer
+    rows = []
+    for i in range(cfg.n_layer):
+        x, sent = layer(x, params[f"layer_{i}"], cfg)
+        if sent is not None:
+            rows.append(sent)
+    rows = jnp.stack(rows)                       # (routed layers, N)
+    first, count = cfg.held or (0, cfg.n_experts)
+    biases = jnp.stack([
+        params[f"layer_{i}"]["moe"]["router"][ROUTING_BIAS]
+        for i in cfg.moe_layers])
+    return rms_norm(x, params["norm_f"], cfg.rms_eps), {
+        "expert_rows": rows,
+        "rows_held": jnp.sum(rows[:, first:first + count]),
+        "max_expert_rows": jnp.max(rows),
+        "max_routing_bias": jnp.max(jnp.abs(biases)),
+    }
+
+
+def forward(params, tokens, cfg: DeepseekV3Config):
+    """tokens (B, S) int32 -> (logits (B, S, rows held) f32, routers'
+    statistics)."""
+    x, stats = _trunk(params, tokens, cfg)
+    head = params["lm_head"]["kernel"].astype(cfg.compute_dtype)
+    return jnp.matmul(x, head, preferred_element_type=jnp.float32), stats
+
+
+def loss_fn(params, batch, cfg: DeepseekV3Config):
+    """batch {"tokens": (B, S+1)} -> (next-token cross-entropy over the
+    rows of the vocabulary held here, its parts: "loss" the same, and the
+    routers' statistics).  There is no auxiliary loss.  The head's logits
+    are made `cfg.loss_chunk_rows` rows at a time and never all held."""
+    tokens = batch["tokens"]
+    inputs, targets = tokens[:, :-1], tokens[:, 1:]
+    x, stats = _trunk(params, inputs, cfg)
+    B, S, E = x.shape
+    with jax.named_scope("head_and_loss"):
+        head = params["lm_head"]["kernel"].astype(cfg.compute_dtype)
+        total = chunked_xent(x.reshape(B * S, E), head.T,
+                              targets.reshape(B * S),
+                              -(-B * S // cfg.loss_chunk_rows))
+        xent = total / (B * S)
+    return xent, dict(stats, loss=xent)
+
+
+def routing_bias_rule(cfg: DeepseekV3Config):
+    """rule(params, out) -> params for `layers.train_step`: each routed
+    layer's bias moves `bias_update_speed` towards the experts that were
+    sent fewer rows than the mean, by `out["expert_rows"]`."""
+    def rule(params, out):
+        with jax.named_scope("routing_bias_update"):
+            params = dict(params)
+            for j, i in enumerate(cfg.moe_layers):
+                n = out["expert_rows"][j].astype(jnp.float32)
+                layer = params[f"layer_{i}"]
+                router = layer["moe"]["router"]
+                bias = router[ROUTING_BIAS] + cfg.bias_update_speed \
+                    * jnp.sign(jnp.mean(n) - n)
+                params[f"layer_{i}"] = {**layer, "moe": {
+                    **layer["moe"], "router": {**router, ROUTING_BIAS: bias}}}
+            return params
+    return rule
+
+
+def trained_by(optimizer):
+    """``optimizer`` over every leaf but the routing biases, which it
+    neither moves nor decays and keeps no moments for."""
+    import optax
+
+    def labels(params):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: "rule" if path[-1].key == ROUTING_BIAS
+            else "optimizer", params)
+
+    return optax.multi_transform(
+        {"optimizer": optimizer, "rule": optax.set_to_zero()}, labels)
+
+
+def make_train_step(cfg: DeepseekV3Config, optimizer):
+    """train_step(params, opt_state, batch) -> (params, opt_state, out),
+    to be jitted with its shardings and `donate_argnums=(0, 1)` as
+    `gpt2.make_train_step`'s; ``optimizer`` comes through `trained_by`.
+    `out["loss"]` is the cross-entropy; `out` also carries "expert_rows"
+    (routed layers, experts), "rows_held" (rows the held experts computed,
+    over the layers), "max_expert_rows" and "max_routing_bias" (|b| as the
+    step used it), device values that cost nothing unless fetched."""
+    return train_step(lambda params, batch: loss_fn(params, batch, cfg),
+                      optimizer, cfg.compute_dtype,
+                      rule=routing_bias_rule(cfg))
+
+
+def count_flops_per_token(cfg: DeepseekV3Config, seq_len: int) -> float:
+    """Training (forward + backward) operations per token HERE: 6 x the
+    parameters a token multiplies on this chip (the head's rows held; per
+    layer W_q, W_kv_a, W_kv_b, W_o; in a routed layer the router, the
+    shared experts and the EXPECTED rows of held experts, top_k x held /
+    experts of three matrices each; in a dense layer its MLP) + the full
+    score squares, 6 L S heads (qk_head_dim + v_head_dim): QK' is
+    qk_head_dim deep and PV v_head_dim, forward once and backward twice."""
+    E, H = cfg.n_embd, cfg.n_head
+    attn = (E * H * cfg.qk_head_dim + E * (cfg.kv_lora_rank + cfg.qk_rope_dim)
+            + cfg.kv_lora_rank * H * (cfg.qk_nope_dim + cfg.v_head_dim)
+            + H * cfg.v_head_dim * E)
+    routed = (E * cfg.n_experts + 3 * E * cfg.shared_width
+              + cfg.top_k * cfg.n_held / cfg.n_experts
+              * 3 * E * cfg.expert_width)
+    n = (cfg.vocab_size * E + cfg.n_layer * attn
+         + cfg.n_dense_layer * 3 * E * cfg.dense_width
+         + len(cfg.moe_layers) * routed)
+    return 6 * n + 6 * cfg.n_layer * seq_len * H * (
+        cfg.qk_head_dim + cfg.v_head_dim)

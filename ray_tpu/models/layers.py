@@ -28,15 +28,33 @@ def rms_norm(x, p, eps=1e-5):
     return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * p["scale"].astype(x.dtype)
 
 
-def rope(x, positions, theta):
-    """x: (B, S, H, D); positions: (B, S) or (S,)."""
+def rope(x, positions, theta, interleaved=False):
+    """x: (B, S, H, D); positions: (B, S) or (S,).  Dim i turns with dim
+    i + D/2 by frequency i (rotate-half).  A caller that rotates a part of
+    a head passes that part; a key part all heads share comes with H = 1.
+
+    ``interleaved``: the pairs that turn together are the adjacent (2i,
+    2i+1).  They are taken apart first ([evens | odds]) and rotated as
+    halves, so the result is the pairwise rotation in that order of the
+    dims and not in x's: the same permutation on queries and keys, which
+    leaves every q . k as it was."""
     D = x.shape[-1]
+    if interleaved:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
     freqs = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) / (D // 2))
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
     cos = jnp.cos(angles)[..., None, :].astype(x.dtype)
     sin = jnp.sin(angles)[..., None, :].astype(x.dtype)
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+
+
+def swiglu(x, gate, up, down, matmul=jnp.matmul):
+    """down(silu(gate(x)) * up(x)), no bias: the gated feed-forward of a
+    dense layer, a shared expert (``matmul`` a plain product) and routed
+    experts (a grouped one over rows sorted by expert, the weights one
+    stack an expert)."""
+    return matmul(jax.nn.silu(matmul(x, gate)) * matmul(x, up), down)
 
 
 def chunked_xent(x, wte, targets, n_chunks: int):
@@ -87,14 +105,21 @@ def cast_weights(params, dtype):
         if x.dtype == jnp.float32 and x.ndim >= 2 else x, params)
 
 
-def train_step(objective, optimizer, compute_dtype):
+def train_step(objective, optimizer, compute_dtype, rule=None):
     """train_step(params, opt_state, batch) -> (params, opt_state, out) for
     ``objective(cast_params, batch) -> (scalar, out)`` — jit it with the
     appropriate shardings and ``donate_argnums=(0, 1)``.
 
     Mixed precision: f32 master params; the objective sees the weight tree
     cast to ``compute_dtype`` once (see cast_weights), autodiff flows back
-    through the cast, so grads and the optimizer's update stay f32."""
+    through the cast, so grads and the optimizer's update stay f32.
+
+    ``rule(params, out) -> params``: state among the parameters that moves
+    by a rule of its own after the optimizer's update (a router's
+    load-balancing bias, from the step's counts in ``out``).  Such leaves
+    get no gradient from the objective and ``optimizer`` is built to leave
+    them alone.  A model without such state passes none and its step is
+    what it was."""
 
     # its name is the compiled module's (`jit_train_step`) in every trace
     def train_step(params, opt_state, batch):
@@ -106,6 +131,8 @@ def train_step(objective, optimizer, compute_dtype):
         with jax.named_scope("optimizer_update"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = jax.tree.map(lambda p, u: p + u, params, updates)
+        if rule is not None:
+            params = rule(params, out)
         return params, opt_state, out
 
     return train_step

@@ -79,6 +79,33 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel"))
 
 
+# Mosaic's default limit of scoped VMEM on a v5e, and what one tile's
+# temporaries (s, p, dp, ds of 1024 x 1024 scores) may take of it.
+_SCOPED_VMEM = 16 << 20
+_TILE_VMEM = 10 << 20
+
+
+def _compiler_params(S, D, Dv, dtype):
+    """`_COMPILER_PARAMS`, with a higher limit of scoped VMEM where the
+    operands a head-major kernel holds for the whole sequence (k and v in
+    the forward and the dq kernel; q, do and the row statistics in the
+    dk/dv kernel; each double-buffered, a width padded to whole lanes)
+    leave a tile's temporaries no room under the default.  At S = 8,192
+    with q/k 192 wide and v 128 the forward wants 17.8 MB and the dq kernel
+    16.02 of the default 16 (compiled for a v5e without the chip: PERF.md
+    §6, PR 32); every shape that fitted before takes the default as
+    before (S = 4,096 at D = 128 holds 4 MB of k and v)."""
+    lanes = lambda d: -(-d // _LANE) * _LANE
+    resident = 2 * S * (lanes(D) + lanes(Dv)) * jnp.dtype(dtype).itemsize
+    if resident <= _SCOPED_VMEM - _TILE_VMEM:
+        return _COMPILER_PARAMS
+    # the dk/dv kernel's residents are the largest: q and do as wide as k
+    # and v, and two (S, 1) f32 statistics that fill a lane each
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel"),
+        vmem_limit_bytes=min(100 << 20, _SCOPED_VMEM + 4 * resident))
+
+
 # ---------------------------------------------------------------------------
 # shared kernel cores (operate on squeezed (rows, d) tiles)
 # ---------------------------------------------------------------------------
@@ -108,7 +135,7 @@ def _causal_mask(s, q_start, k_start):
 
 
 def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
-              seq_len):
+              seq_len, v_dim):
     """Online-softmax forward over one q tile.
 
     At small head_dim the two dots leave the matrix unit half full and the
@@ -129,7 +156,9 @@ def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
     index.  ``over`` walks the k blocks (`_fwd_rows`): `_span` takes the
     interior blocks as one span and the diagonal's as another, a pass of
     two steps at most; `_tile_loop` loops over them.  read_k/read_v:
-    (start, rows) -> (rows, d).  Returns (acc f32 (block_q, d), m, l)."""
+    (start, rows) -> (rows, d) of k and (rows, v_dim) of v, which may be
+    another width (latent attention: 192 and 128).  Returns (acc f32
+    (block_q, v_dim), m, l)."""
 
     num_k_blocks = seq_len // block_k
 
@@ -154,9 +183,8 @@ def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
         )
         return acc, m_new, l_new
 
-    d = q.shape[-1]
     carry = (
-        jnp.zeros((block_q, d), jnp.float32),
+        jnp.zeros((block_q, v_dim), jnp.float32),
         jnp.full((block_q, 1), _NEG_INF, jnp.float32),
         jnp.zeros((block_q, 1), jnp.float32),
     )
@@ -193,7 +221,8 @@ def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     The refs hold all S rows; ``cols`` picks the head's columns of
     q/k/v/do/dq/dk/dv and ``stat`` its column of lse/delta (natural-log
     lse, f32 delta).  ``dq_acc`` is an f32 (S, head_dim) scratch: dq sums
-    over k tiles there and is scaled and written once at the end."""
+    over k tiles there and is scaled and written once at the end.  q, k
+    and dq, dk have one width, v, do and dv may have another."""
     num_q, num_k = seq_len // block_q, seq_len // block_k
     # sm_scale * log2(e) folded into the q rows: s is in base-2 units, q
     # also serves the dk dot (rescaled by ln2 at the end), and ds's
@@ -234,9 +263,8 @@ def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 preferred_element_type=jnp.float32)
             return dk_acc, dv_acc
 
-        d = dq_acc.shape[-1]
-        acc = (jnp.zeros((block_k, d), jnp.float32),
-               jnp.zeros((block_k, d), jnp.float32))
+        acc = (jnp.zeros((block_k, k.shape[-1]), jnp.float32),
+               jnp.zeros((block_k, v.shape[-1]), jnp.float32))
         first = below = 0
         if causal:
             first = (kj * block_k) // block_q
@@ -287,7 +315,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
         acc, m, l = _fwd_core(
             q, lambda start, n: k_ref[pl.ds(start, n), :],
             lambda start, n: v_ref[pl.ds(start, n), :], qi, over,
-            causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len)
+            causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len,
+            v_dim=v_ref.shape[-1])
         o_ref[rows, :], lse_ref[rows, :] = _finish_fwd(acc, m, l, o_ref.dtype)
 
 
@@ -398,9 +427,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         )                                       # (bk, d) — q carries the scale
         return dk_acc, dv_acc
 
-    d = k_ref.shape[-1]
-    init = (jnp.zeros((block_k, d), jnp.float32),
-            jnp.zeros((block_k, d), jnp.float32))
+    init = (jnp.zeros((block_k, k_ref.shape[-1]), jnp.float32),
+            jnp.zeros((block_k, v_ref.shape[-1]), jnp.float32))
     if causal:
         # q blocks intersecting this k column's diagonal band need the
         # mask; q blocks strictly below it don't; ones above contribute
@@ -423,11 +451,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, whole,
                     interpret):
     """``whole``: a grid step takes the whole sequence and walks its q
-    tiles; else one q tile (the forward's two forms: `_fwd_rows`)."""
+    tiles; else one q tile (the forward's two forms: `_fwd_rows`).  q and
+    k are (B, H, S, D); v, and so o, may have another last dim."""
     B, H, S, D = q.shape
+    Dv = v.shape[-1]
     qf = q.reshape(B * H, S, D)
     kf = k.reshape(B * H, S, D)
-    vf = v.reshape(B * H, S, D)
+    vf = v.reshape(B * H, S, Dv)
     rows = S if whole else block_q
     grid = (B * H, S // rows)
     kernel = functools.partial(
@@ -435,21 +465,22 @@ def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, whole,
         block_q=block_q, block_k=block_k, seq_len=S, whole=whole,
     )
     qspec = pl.BlockSpec((None, rows, D), lambda g, i: (g, i, 0))
-    kvspec = pl.BlockSpec((None, S, D), lambda g, i: (g, 0, 0))
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[qspec, kvspec, kvspec],
-        out_specs=[qspec,
+        in_specs=[qspec,
+                  pl.BlockSpec((None, S, D), lambda g, i: (g, 0, 0)),
+                  pl.BlockSpec((None, S, Dv), lambda g, i: (g, 0, 0))],
+        out_specs=[pl.BlockSpec((None, rows, Dv), lambda g, i: (g, i, 0)),
                    pl.BlockSpec((None, rows, 1), lambda g, i: (g, i, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+            jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, S, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=_compiler_params(S, D, Dv, q.dtype),
     )(qf, kf, vf)
-    return o.reshape(B, H, S, D), lse.reshape(B, H, S)
+    return o.reshape(B, H, S, Dv), lse.reshape(B, H, S)
 
 
 @_kernel_call("whole")
@@ -457,12 +488,14 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
                      whole, interpret, delta=None):
     """``whole``: the one-kernel backward, a grid step taking the whole
     sequence; else the two-kernel split, blocked over q rows (dq) and over
-    k columns (dk/dv)."""
+    k columns (dk/dv).  v, o and do may have another last dim than q and
+    k."""
     B, H, S, D = q.shape
+    Dv = v.shape[-1]
     qf = q.reshape(B * H, S, D)
     kf = k.reshape(B * H, S, D)
-    vf = v.reshape(B * H, S, D)
-    dof = do.reshape(B * H, S, D)
+    vf = v.reshape(B * H, S, Dv)
+    dof = do.reshape(B * H, S, Dv)
     lsef = lse.reshape(B * H, S, 1)
     # delta = rowsum(do * o): cheap elementwise+reduce, XLA fuses it.
     # Callers looping over K/V chunks (ring attention) pass it precomputed
@@ -471,64 +504,64 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
         delta = jnp.sum(
             do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = delta.reshape(B * H, S, 1)
+    out_shape = [jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+                 jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
+                 jax.ShapeDtypeStruct((B * H, S, Dv), v.dtype)]
+    params = _compiler_params(S, D, Dv, q.dtype)
+
+    def spec(rows, width, index):
+        return pl.BlockSpec((None, rows, width), index)
+
+    whole_seq, tile = (lambda g, i: (g, 0, 0)), (lambda g, i: (g, i, 0))
 
     if whole:
         # one kernel, tiled inside: shares s/dp across dq/dk/dv.
-        spec = pl.BlockSpec((None, S, D), lambda g, i: (g, 0, 0))
-        row = pl.BlockSpec((None, S, 1), lambda g, i: (g, 0, 0))
+        qk, vo = spec(S, D, whole_seq), spec(S, Dv, whole_seq)
+        row = spec(S, 1, whole_seq)
         dq, dk, dv = pl.pallas_call(
             functools.partial(_bwd_fused_kernel, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
                               block_k=block_k, seq_len=S),
             grid=(B * H, 1),
-            in_specs=[spec, spec, spec, spec, row, row],
-            out_specs=[spec, spec, spec],
-            out_shape=[jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-                       jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
-                       jax.ShapeDtypeStruct((B * H, S, D), v.dtype)],
+            in_specs=[qk, qk, vo, vo, row, row],
+            out_specs=[qk, qk, vo],
+            out_shape=out_shape,
             scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
             interpret=interpret,
-            compiler_params=_COMPILER_PARAMS,
+            compiler_params=params,
         )(qf, kf, vf, dof, lsef, delta)
-        return (dq.reshape(B, H, S, D), dk.reshape(B, H, S, D),
-                dv.reshape(B, H, S, D))
-
-    qspec = pl.BlockSpec((None, block_q, D), lambda g, i: (g, i, 0))
-    qrow = pl.BlockSpec((None, block_q, 1), lambda g, i: (g, i, 0))
-    full = pl.BlockSpec((None, S, D), lambda g, i: (g, 0, 0))
-    fullrow = pl.BlockSpec((None, S, 1), lambda g, i: (g, 0, 0))
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, seq_len=S,
-        ),
-        grid=(B * H, S // block_q),
-        in_specs=[qspec, full, full, qspec, qrow, qrow],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-        interpret=interpret,
-        compiler_params=_COMPILER_PARAMS,
-    )(qf, kf, vf, dof, lsef, delta)
-
-    kspec = pl.BlockSpec((None, block_k, D), lambda g, i: (g, i, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, seq_len=S,
-        ),
-        grid=(B * H, S // block_k),
-        in_specs=[full, kspec, kspec, full, fullrow, fullrow],
-        out_specs=[kspec, kspec],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, S, D), v.dtype),
-        ],
-        interpret=interpret,
-        compiler_params=_COMPILER_PARAMS,
-    )(qf, kf, vf, dof, lsef, delta)
+    else:
+        dq = pl.pallas_call(
+            functools.partial(
+                _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
+                block_q=block_q, block_k=block_k, seq_len=S,
+            ),
+            grid=(B * H, S // block_q),
+            in_specs=[spec(block_q, D, tile), spec(S, D, whole_seq),
+                      spec(S, Dv, whole_seq), spec(block_q, Dv, tile),
+                      spec(block_q, 1, tile), spec(block_q, 1, tile)],
+            out_specs=spec(block_q, D, tile),
+            out_shape=out_shape[0],
+            interpret=interpret,
+            compiler_params=params,
+        )(qf, kf, vf, dof, lsef, delta)
+        dk, dv = pl.pallas_call(
+            functools.partial(
+                _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
+                block_q=block_q, block_k=block_k, seq_len=S,
+            ),
+            grid=(B * H, S // block_k),
+            in_specs=[spec(S, D, whole_seq), spec(block_k, D, tile),
+                      spec(block_k, Dv, tile), spec(S, Dv, whole_seq),
+                      spec(S, 1, whole_seq), spec(S, 1, whole_seq)],
+            out_specs=[spec(block_k, D, tile), spec(block_k, Dv, tile)],
+            out_shape=out_shape[1:],
+            interpret=interpret,
+            compiler_params=params,
+        )(qf, kf, vf, dof, lsef, delta)
 
     return (dq.reshape(B, H, S, D), dk.reshape(B, H, S, D),
-            dv.reshape(B, H, S, D))
+            dv.reshape(B, H, S, Dv))
 
 
 # ---------------------------------------------------------------------------
@@ -551,7 +584,7 @@ def _fwd_kernel_lanes(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
                 q, lambda start, n: k_ref[pl.ds(start, n), sl],
                 lambda start, n: v_ref[pl.ds(start, n), sl], qi, over,
                 causal=causal, block_q=block_q, block_k=block_k,
-                seq_len=seq_len)
+                seq_len=seq_len, v_dim=head_dim)
             o, lse = _finish_fwd(acc, m, l, o_ref.dtype)
             o_ref[rows, sl] = o
             lse_ref[rows, pl.ds(h, 1)] = lse
@@ -766,7 +799,12 @@ def _auto_tiles(S: int, causal: bool):
     found fastest (`tools/chip_kernels.py --sweep`; PERF.md §6, PR 31):
     smaller ones skip more of the square and pay more fixed cost.  Swept:
     S = 1,024 at head_dim 64 and 128, and S = 512 at head_dim 64; the same
-    caps won at each, and other lengths take them unmeasured."""
+    caps won at each, and other lengths take them unmeasured.  Past
+    `_WHOLE_SEQ_MAX`, swept at S = 4,096 (D = 128) and at S = 8,192 with
+    q/k 192 and v 128 wide (PERF.md §6, PR 32: forward 13.76 ms at 1,024,
+    13.09 at 512, 22.24 at 256; backward 42.64 at 512, its cap, 51.29 at
+    256): 512 forward is 5 to 9 % faster than the largest block at both
+    and is left, 0.7 % of a step."""
     whole = _auto_block(S, 1024)
     if not causal or S > _WHOLE_SEQ_MAX:
         return (whole, whole), (whole, whole)
@@ -899,7 +937,8 @@ def _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k):
     S = q.shape[1]
     scale, whole, (bq, bk), _ = _resolve(q, S, causal, sm_scale, block_q,
                                          block_k)
-    if _bshd_lanes_ok(q, S, bq, bk):
+    # the lane layout slices every operand's heads out of the same lanes
+    if v.shape == q.shape and _bshd_lanes_ok(q, S, bq, bk):
         _count_tiles(S, bq, bk, causal)
 
         def reference(q, k, v):
@@ -923,7 +962,7 @@ def _flash_bwd_bshd(causal, sm_scale, block_q, block_k, res, do):
     S = q.shape[1]
     scale, whole, _, (bq, bk) = _resolve(q, S, causal, sm_scale, block_q,
                                          block_k)
-    if whole and _bshd_lanes_ok(q, S, bq, bk):
+    if whole and v.shape == q.shape and _bshd_lanes_ok(q, S, bq, bk):
         _count_tiles(S, bq, bk, causal)
 
         def reference(q, k, v, o, lse, do):

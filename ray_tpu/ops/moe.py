@@ -15,6 +15,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.util import tracing
+
 
 @jax.custom_vjp
 def _permute_rows(x, perm, inverse):
@@ -29,27 +31,57 @@ _permute_rows.defvjp(
     lambda inverse, g: (g[inverse], None, None))
 
 
-def moe_dispatch(x, weights, experts, n_experts, run_experts):
-    """x (T, E); weights, experts (T, k): each token's k experts and what
-    each one's output is multiplied by.  `run_experts(rows, group_sizes)`
-    gets the T*k rows in expert order (R, E) with the rows of each expert
-    (n_experts,) and returns their outputs (R, E), row for row.  Whatever
-    the imbalance, every row is computed.  Returns (y (T, E), rows per
-    expert (n_experts,) int32).  Differentiable in x, weights and whatever
-    `run_experts` closes over."""
+def moe_dispatch(x, weights, experts, n_experts, run_experts, held=None):
+    """x (T, E); weights, experts (T, k): each token's k experts, of ALL
+    ``n_experts``, and what each one's output is multiplied by.
+    `run_experts(rows, group_sizes)` gets the T*k rows in expert order
+    (R, E) with the rows of each expert it runs and returns their outputs
+    (R, E), row for row.  Whatever the imbalance, every row is computed.
+    Returns (y (T, E), rows sent to each of all the experts (n_experts,)
+    int32).  Differentiable in x, weights and whatever `run_experts`
+    closes over.
+
+    ``held`` = (first, count): only that contiguous range of the experts
+    lives here (one chip's share of an expert-parallel layer).  Routing is
+    still over all of them; the rows sent to a held expert come first in
+    expert order and `run_experts` gets the group sizes of the held experts
+    alone (count,); a row sent to an absent expert is computed by nobody
+    and adds nothing to y (its part of the sum is another chip's), and no
+    gradient comes back through it.  The buffer stays T*k rows, the most
+    the held experts can be sent, so nothing is dropped under any
+    imbalance: `moe.rows_buffered` against `moe.rows_routed` in the job
+    timeline is what that costs.  None: all are held."""
     T, k = experts.shape
+    first, count = held or (0, n_experts)
+    tracing.count("moe.experts", n_experts)
+    tracing.count("moe.experts_held", count)
+    tracing.count("moe.rows_routed", T * k)
+    tracing.count("moe.rows_buffered", T * k)
     with jax.named_scope("dispatch"):
         flat = experts.reshape(T * k)
         rows = jnp.arange(T * k, dtype=jnp.int32)
+        keys = flat
+        if held:
+            # the absent experts' rows go last, behind every held group
+            here = (flat >= first) & (flat < first + count)
+            keys = jnp.where(here, flat, n_experts)
         # a stable sort keeps a token's rows in token order inside a group
-        _, order = jax.lax.sort((flat, rows), num_keys=1)
+        _, order = jax.lax.sort((keys, rows), num_keys=1)
         _, inverse = jax.lax.sort((order, rows), num_keys=1)
         group_sizes = jnp.sum(
             flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype)[None],
             axis=0, dtype=jnp.int32)
         xs = _permute_rows(jnp.repeat(x, k, axis=0), order, inverse)
     with jax.named_scope("experts"):
-        ys = run_experts(xs, group_sizes)
+        if held:
+            sizes = group_sizes[first:first + count]
+            # whatever a grouped matmul makes of the rows of no group, or
+            # its transposes of their cotangents, stays inside
+            grouped = (rows < jnp.sum(sizes))[:, None]
+            ys = jnp.where(grouped, run_experts(
+                jnp.where(grouped, xs, 0), sizes), 0)
+        else:
+            ys = run_experts(xs, group_sizes)
     with jax.named_scope("combine"):
         ys = _permute_rows(ys, inverse, order).reshape(T, k, -1)
         y = jnp.sum(ys.astype(jnp.float32) * weights[..., None], axis=1)

@@ -9,8 +9,9 @@ One case on each side of the gates in ``ops/flash_attention.py``: the lane
 kernels with the one-kernel backward (GPT-2 124M's heads), the transposing
 bhsd kernels with the one-kernel backward (25 heads: no lane tiling; XL's
 share of a batch on one chip), the two-kernel backward past
-``_WHOLE_SEQ_MAX`` (S=2048), and OLMoE's shape (S=4096, D=128).  This
-process holds the chip, so run it alone.  Exits non-zero unless every case
+``_WHOLE_SEQ_MAX`` (S=2048), OLMoE's shape (S=4096, D=128), and latent
+attention's two widths at S=8192 (a fifth number in a shape is v's width:
+q and k 192, v 128).  This process holds the chip, so run it alone.  Exits non-zero unless every case
 ran as compiled Mosaic kernels on a TPU and agrees with
 ``reference_attention``.  ``--sweep`` times forced square tiles instead
 (what ``_auto_tiles`` is set from) and compares nothing.
@@ -28,12 +29,13 @@ import warnings
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# (B, S, H, D) -> Mosaic kernels in forward + backward
+# (B, S, H, D[, Dv]) -> Mosaic kernels in forward + backward
 CASES = {
     "lanes_fused_bwd": ((16, 1024, 12, 64), 2),
     "bhsd_fused_bwd": ((4, 1024, 25, 64), 2),
     "two_kernel_bwd": ((2, 2048, 32, 128), 3),
     "olmoe_4k": ((4, 4096, 16, 128), 3),
+    "mla_8k": ((2, 8192, 32, 192, 128), 3),
 }
 # the benchmark's cells: medium's step, XL's on one chip of four, OLMoE's
 SWEEP = {
@@ -48,15 +50,25 @@ SWEEP = {
     "d128-1k": ((4, 1024, 16, 128), (
         (128, 128), (256, 256), (512, 512), (1024, 1024))),
     "s512-d64": ((32, 512, 16, 64), ((128, 128), (256, 256), (512, 512))),
+    # kanana-2-30b-a3b's latent attention, multiplied out: q, k 192, v 128
+    "mla-8k": ((2, 8192, 32, 192, 128), (
+        (256, 256), (512, 512), (1024, 1024))),
 }
 TOLERANCE = 0.05
 
 
+# elements of the reference's S x S scores alive at once: 1 GiB in float32
+REFERENCE_SCORES = 1 << 28
+
+
 def _qkv(shape, dtype):
+    """q, k of (B, S, H, D) and v of (B, S, H, Dv), Dv = D if not given."""
     import jax
 
-    return tuple(jax.random.normal(jax.random.PRNGKey(i), shape, dtype)
-                 for i in range(3))
+    *bsh, d = shape[:4]
+    widths = (d, d, shape[4] if len(shape) > 4 else d)
+    return tuple(jax.random.normal(jax.random.PRNGKey(i), (*bsh, w), dtype)
+                 for i, w in enumerate(widths))
 
 
 def kernel_ms(f, *args, calls=5):
@@ -102,13 +114,15 @@ def time_passes(shape, dtype, block_q=None, block_k=None):
 
 
 def compare_with_reference(shape, dtype):
-    """Causal ``flash_attention_bshd`` at ``shape`` (B, S, H, D), forward and
-    backward, on the default device: (largest error of o, dq, dk, dv
-    relative to ``reference_attention``'s, the worst of the batch's rows,
-    Mosaic kernels in the compiled program — 0 where the kernels are
-    interpreted).  The O(S^2) reference runs one batch row at a time
-    (OLMoE's whole batch would hold several 4 GB score arrays); the loss is
-    a sum over rows, so a row's gradients are the batch's."""
+    """Causal ``flash_attention_bshd`` at ``shape`` (B, S, H, D[, Dv]),
+    forward and backward, on the default device: (largest error of o, dq,
+    dk, dv relative to ``reference_attention``'s, the worst of the batch's
+    rows, Mosaic kernels in the compiled program — 0 where the kernels are
+    interpreted).  The O(S^2) reference runs one batch row at a time, and
+    of a row as many heads as keep its scores under `REFERENCE_SCORES`
+    (OLMoE's whole batch would hold several 4 GB score arrays, one row of
+    32 heads at S = 8,192 several of 8.6 GB); the loss is a sum over rows
+    and heads, so a slice's gradients are the batch's."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -133,13 +147,18 @@ def compare_with_reference(shape, dtype):
     (_, o_k), g_k = compiled(q, k, v)
     reference = grad(reference)
     errs = dict.fromkeys(("o", "dq", "dk", "dv"), 0.0)
-    for row in range(shape[0]):
-        rows = slice(row, row + 1)
-        (_, o_r), g_r = reference(q[rows], k[rows], v[rows])
-        for what, a, b in zip(errs, (o_k, *g_k), (o_r, *g_r)):
-            a, b = np.asarray(a[rows], np.float32), np.asarray(b, np.float32)
-            errs[what] = max(errs[what], round(
-                float(np.max(np.abs(a - b)) / np.max(np.abs(b))), 5))
+    B, S, H = shape[:3]
+    heads = max(1, min(H, REFERENCE_SCORES // (S * S)))
+    for row in range(B):
+        for first in range(0, H, heads):
+            part = (slice(row, row + 1), slice(None),
+                    slice(first, first + heads))
+            (_, o_r), g_r = reference(q[part], k[part], v[part])
+            for what, a, b in zip(errs, (o_k, *g_k), (o_r, *g_r)):
+                a = np.asarray(a[part], np.float32)
+                b = np.asarray(b, np.float32)
+                errs[what] = max(errs[what], round(
+                    float(np.max(np.abs(a - b)) / np.max(np.abs(b))), 5))
     return errs, compiled.as_text().count(
         'custom_call_target="tpu_custom_call"')
 
