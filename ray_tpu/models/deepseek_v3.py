@@ -67,7 +67,7 @@ from ray_tpu.models.layers import (
     swiglu,
     train_step,
 )
-from ray_tpu.ops.moe import moe_dispatch
+from ray_tpu.ops.moe import buffer_rows, moe_dispatch
 from ray_tpu.parallel.attention import attention
 
 ROUTING_BIAS = "e_score_correction_bias"
@@ -262,12 +262,15 @@ def _trunk(params, tokens, cfg: DeepseekV3Config):
             rows.append(sent)
     rows = jnp.stack(rows)                       # (routed layers, N)
     first, count = cfg.held or (0, cfg.n_experts)
+    held = jnp.sum(rows[:, first:first + count], axis=1)
+    buffer = buffer_rows(tokens.size * cfg.top_k, count, cfg.n_experts)
     biases = jnp.stack([
         params[f"layer_{i}"]["moe"]["router"][ROUTING_BIAS]
         for i in cfg.moe_layers])
     return rms_norm(x, params["norm_f"], cfg.rms_eps), {
         "expert_rows": rows,
-        "rows_held": jnp.sum(rows[:, first:first + count]),
+        "rows_held": jnp.sum(held),
+        "moe_overflow_layers": jnp.sum(held > buffer, dtype=jnp.int32),
         "max_expert_rows": jnp.max(rows),
         "max_routing_bias": jnp.max(jnp.abs(biases)),
     }
@@ -338,8 +341,11 @@ def make_train_step(cfg: DeepseekV3Config, optimizer):
     `gpt2.make_train_step`'s; ``optimizer`` comes through `trained_by`.
     `out["loss"]` is the cross-entropy; `out` also carries "expert_rows"
     (routed layers, experts), "rows_held" (rows the held experts computed,
-    over the layers), "max_expert_rows" and "max_routing_bias" (|b| as the
-    step used it), device values that cost nothing unless fetched."""
+    over the layers), "moe_overflow_layers" (routed layers whose held
+    experts were sent more than `ops/moe.py:buffer_rows` and ran over all
+    the routed rows instead: exact, and slower), "max_expert_rows" and
+    "max_routing_bias" (|b| as the step used it), device values that cost
+    nothing unless fetched."""
     return train_step(lambda params, batch: loss_fn(params, batch, cfg),
                       optimizer, cfg.compute_dtype,
                       rule=routing_bias_rule(cfg))

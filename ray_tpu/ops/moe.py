@@ -7,15 +7,43 @@ are one contiguous group of whatever size the router made it, the experts
 run over the ragged groups (`jax.lax.ragged_dot`, which XLA:TPU lowers to
 a grouped-matmul kernel), and the rows go back to their tokens and are
 summed with their weights.  Shared by `models/olmoe.py` (SiLU-gated
-experts) and `models/gpt2.py`'s mixture (GELU experts).
+experts), `models/gpt2.py`'s mixture (GELU experts) and
+`models/deepseek_v3.py` (a chip's share of the experts).
+
+Where all the experts live here, the buffer between dispatch and combine
+is the T*k rows.  Where a share of under half of them does (``held``), it
+is `buffer_rows` long, twice what a balanced router sends the share, and
+the rows of held experts alone are gathered into it and summed back out
+of it (`_take`, `_put`); a step whose router sends the share more than
+that takes the T*k path instead, inside the same compiled program
+(`lax.cond`), so the result is exact under any imbalance.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.util import tracing
+
+# A share's buffer over the rows a balanced router sends it.  Twice: a
+# router at its initialisation, or one a bias rule or an auxiliary loss
+# keeps balanced, sends a share its expected rows within a few per cent,
+# and a share that is sent double has lost its balance altogether; that
+# step is still exact (the T*k path), only slower.  Not an option: a caller
+# says which experts it holds, and nothing about buffers.
+_HEADROOM = 2
+
+
+def buffer_rows(rows: int, count: int, n_experts: int) -> int:
+    """Rows of the buffer between dispatch and combine when ``count`` of
+    ``n_experts`` are held and ``rows`` = T*k are routed: `_HEADROOM` times
+    the expected load, to a whole sublane tile of 8, and never more than
+    all the rows (half of the experts and more: T*k)."""
+    need = -(-_HEADROOM * rows * count // n_experts)
+    return min(rows, -(-need // 8) * 8)
 
 
 @jax.custom_vjp
@@ -31,49 +59,117 @@ _permute_rows.defvjp(
     lambda inverse, g: (g[inverse], None, None))
 
 
-def moe_dispatch(x, weights, experts, n_experts, run_experts, held=None):
-    """x (T, E); weights, experts (T, k): each token's k experts, of ALL
-    ``n_experts``, and what each one's output is multiplied by.
-    `run_experts(rows, group_sizes)` gets the T*k rows in expert order
-    (R, E) with the rows of each expert it runs and returns their outputs
-    (R, E), row for row.  Whatever the imbalance, every row is computed.
-    Returns (y (T, E), rows sent to each of all the experts (n_experts,)
-    int32).  Differentiable in x, weights and whatever `run_experts`
-    closes over.
+@jax.custom_vjp
+def _first_of_permuted(v, first, inverse):
+    """v[first] for ``first`` the leading C entries of a permutation whose
+    ``inverse`` is known (v a vector): the cotangent is a gather too."""
+    return v[first]
 
-    ``held`` = (first, count): only that contiguous range of the experts
-    lives here (one chip's share of an expert-parallel layer).  Routing is
-    still over all of them; the rows sent to a held expert come first in
-    expert order and `run_experts` gets the group sizes of the held experts
-    alone (count,); a row sent to an absent expert is computed by nobody
-    and adds nothing to y (its part of the sum is another chip's), and no
-    gradient comes back through it.  The buffer stays T*k rows, the most
-    the held experts can be sent, so nothing is dropped under any
-    imbalance: `moe.rows_buffered` against `moe.rows_routed` in the job
-    timeline is what that costs.  None: all are held."""
+
+def _first_of_permuted_bwd(inverse, g):
+    C = g.shape[0]
+    return (jnp.where(inverse < C, g[jnp.minimum(inverse, C - 1)], 0),
+            None, None)
+
+
+_first_of_permuted.defvjp(
+    lambda v, first, inverse: (v[first], inverse), _first_of_permuted_bwd)
+
+
+def _sum_into_tokens(rows, scale, where):
+    """(C, E) buffered rows -> (T, E): each token's rows, times ``scale``
+    (C,) if given, summed in float32 in the order of its choices.  A gather
+    of T rows for each of the k choices and one sum, which XLA:TPU fuses:
+    nothing with T*k rows exists (`tools/chip_kernels.py`, case
+    `moe_held_8k`, has the forms tried: a scatter-add is serial on the
+    chip, and the buffer sorted by token with its neighbours added takes
+    two more gathers)."""
+    slot = where[2]                         # (T, k); C: not in the buffer
+    C = rows.shape[0]
+    total = 0
+    for j in range(slot.shape[1]):
+        at = jnp.minimum(slot[:, j], C - 1)
+        row = rows[at].astype(jnp.float32)
+        if scale is not None:
+            row = row * scale[at][:, None]
+        total = total + jnp.where((slot[:, j] < C)[:, None], row, 0)
+    return total.astype(rows.dtype)
+
+
+@jax.custom_vjp
+def _take(x, where):
+    """(T, E) -> the (C, E) buffer: each buffered row's token's row, zero
+    for the rows of the buffer that belong to no group.  Its transpose is
+    `_put` without weights."""
+    tokens, valid = where[:2]
+    return jnp.where(valid[:, None], x[tokens], 0)
+
+
+_take.defvjp(lambda x, where: (_take(x, where), where),
+             lambda where, g: (_sum_into_tokens(g, None, where), None))
+
+
+@jax.custom_vjp
+def _put(rows, scale, where):
+    """The (C, E) buffer, each row times its ``scale``, summed into the
+    (T, E) tokens; the rows of no group add nothing.  Its transpose in
+    ``rows`` is `_take` times the scale."""
+    return _sum_into_tokens(rows, scale, where)
+
+
+def _put_bwd(res, g):
+    rows, scale, where = res
+    g = _take(g, where).astype(jnp.float32)
+    return ((g * scale[:, None]).astype(rows.dtype),
+            jnp.sum(g * rows.astype(jnp.float32), axis=1), None)
+
+
+_put.defvjp(lambda rows, scale, where: (
+    _sum_into_tokens(rows, scale, where), (rows, scale, where)), _put_bwd)
+
+
+def _sort_by_expert(experts, n_experts, held):
+    """-> ((0..T*k-1, the order that sorts the (token, choice) rows by
+    expert, its inverse), rows sent to each of all the experts)."""
     T, k = experts.shape
-    first, count = held or (0, n_experts)
-    tracing.count("moe.experts", n_experts)
-    tracing.count("moe.experts_held", count)
-    tracing.count("moe.rows_routed", T * k)
-    tracing.count("moe.rows_buffered", T * k)
+    flat = experts.reshape(T * k)
+    rows = jnp.arange(T * k, dtype=jnp.int32)
+    keys = flat
+    if held:
+        # the absent experts' rows go last, behind every held group
+        first, count = held
+        here = (flat >= first) & (flat < first + count)
+        keys = jnp.where(here, flat, n_experts)
+    # a stable sort keeps a token's rows in token order inside a group
+    _, order = jax.lax.sort((keys, rows), num_keys=1)
+    _, inverse = jax.lax.sort((order, rows), num_keys=1)
+    group_sizes = jnp.sum(
+        flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype)[None],
+        axis=0, dtype=jnp.int32)
+    return (rows, order, inverse), group_sizes
+
+
+def _buffer_index(C, k, by_expert, n_held):
+    """-> (the (token, choice) rows in the C-row buffer, in expert order;
+    `where` for `_take` and `_put`: each buffered row's token, whether it
+    is a held expert's, and each (token, choice)'s place in the buffer
+    (T, k), C for those not in it)."""
+    _, order, inverse = by_expert
+    rows = order[:C]
+    return rows, (rows // k, jnp.arange(C, dtype=jnp.int32) < n_held,
+                  jnp.where(inverse < n_held, inverse, C).reshape(-1, k))
+
+
+def _over_all_rows(x, weights, by_expert, group_sizes, held, run_experts):
+    """Dispatch, experts and combine over a buffer of all T*k rows;
+    ``by_expert`` = (0..T*k-1, the sort's order, its inverse)."""
+    T, k = weights.shape
+    rows, order, inverse = by_expert
     with jax.named_scope("dispatch"):
-        flat = experts.reshape(T * k)
-        rows = jnp.arange(T * k, dtype=jnp.int32)
-        keys = flat
-        if held:
-            # the absent experts' rows go last, behind every held group
-            here = (flat >= first) & (flat < first + count)
-            keys = jnp.where(here, flat, n_experts)
-        # a stable sort keeps a token's rows in token order inside a group
-        _, order = jax.lax.sort((keys, rows), num_keys=1)
-        _, inverse = jax.lax.sort((order, rows), num_keys=1)
-        group_sizes = jnp.sum(
-            flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype)[None],
-            axis=0, dtype=jnp.int32)
         xs = _permute_rows(jnp.repeat(x, k, axis=0), order, inverse)
     with jax.named_scope("experts"):
         if held:
+            first, count = held
             sizes = group_sizes[first:first + count]
             # whatever a grouped matmul makes of the rows of no group, or
             # its transposes of their cotangents, stays inside
@@ -85,4 +181,122 @@ def moe_dispatch(x, weights, experts, n_experts, run_experts, held=None):
     with jax.named_scope("combine"):
         ys = _permute_rows(ys, inverse, order).reshape(T, k, -1)
         y = jnp.sum(ys.astype(jnp.float32) * weights[..., None], axis=1)
-    return y.astype(x.dtype), group_sizes
+    return y.astype(x.dtype)
+
+
+def _over_held_rows(C, x, weights, by_expert, group_sizes, held,
+                    run_experts):
+    """The same over a buffer of the first C rows in expert order, which
+    must hold every row sent to a held expert.  Nothing here has T*k rows
+    and a width."""
+    T, k = weights.shape
+    first, count = held
+    sizes = group_sizes[first:first + count]
+    with jax.named_scope("dispatch"):
+        rows, where = _buffer_index(C, k, by_expert, jnp.sum(sizes))
+        xs = _take(x, where)
+    with jax.named_scope("experts"):
+        ys = jnp.where(where[1][:, None], run_experts(xs, sizes), 0)
+    with jax.named_scope("combine"):
+        scale = _first_of_permuted(weights.reshape(T * k), rows,
+                                   by_expert[2])
+        return _put(ys, scale, where)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _one_of(compact, full, fits, index, operands):
+    """`compact(index, *operands)` if ``fits`` else `full(...)`,
+    differentiable in ``operands``, with no residual but the arguments:
+    reverse mode of a plain `lax.cond` would have each branch write zeros
+    for the other's residuals, the T*k-row arrays among them; here the
+    backward pass chooses again and the branch taken differentiates
+    itself."""
+    return jax.lax.cond(fits, compact, full, index, *operands)
+
+
+def _one_of_bwd(compact, full, res, g):
+    def back(branch):
+        return lambda index, g, *operands: jax.vjp(
+            functools.partial(branch, index), *operands)[1](g)
+    fits, index, operands = res
+    return None, None, jax.lax.cond(fits, back(compact), back(full),
+                                    index, g, *operands)
+
+
+_one_of.defvjp(
+    lambda compact, full, fits, index, operands: (
+        _one_of(compact, full, fits, index, operands),
+        (fits, index, operands)),
+    _one_of_bwd)
+
+
+def _over_either(C, x, weights, by_expert, group_sizes, held, run_experts):
+    """`_over_held_rows` when the share was sent at most C rows, else
+    `_over_all_rows`, chosen on the device."""
+    (T, E), k = x.shape, weights.shape[1]
+    first, count = held
+    # what `run_experts` closes over has to cross the custom rule as
+    # arguments, at each of the two buffer lengths
+    runs, consts = [], {}
+    for n in (C, T * k):
+        run, closed = jax.closure_convert(
+            run_experts, jax.ShapeDtypeStruct((n, E), x.dtype),
+            jax.ShapeDtypeStruct((count,), group_sizes.dtype))
+        for c in closed:
+            consts.setdefault(id(c), c)
+        runs.append((run, [list(consts).index(id(c)) for c in closed]))
+
+    def branch(body, which):
+        run, at = runs[which]
+
+        def over(index, x, weights, *hoisted):
+            return body(x, weights, *index, held,
+                        lambda xs, sizes: run(xs, sizes,
+                                              *(hoisted[i] for i in at)))
+        return over
+
+    return _one_of(
+        branch(functools.partial(_over_held_rows, C), 0),
+        branch(_over_all_rows, 1),
+        jnp.sum(group_sizes[first:first + count]) <= C,
+        (by_expert, group_sizes), (x, weights, *consts.values()))
+
+
+def moe_dispatch(x, weights, experts, n_experts, run_experts, held=None):
+    """x (T, E); weights, experts (T, k): each token's k experts, of ALL
+    ``n_experts``, and what each one's output is multiplied by.
+    `run_experts(rows, group_sizes)` gets the buffered rows in expert
+    order (R, E) with the rows of each expert it runs and returns their
+    outputs (R, E), row for row.  Whatever the imbalance, every row is
+    computed.  Returns (y (T, E), rows sent to each of all the experts
+    (n_experts,) int32).  Differentiable in x, weights and whatever
+    `run_experts` closes over.
+
+    ``held`` = (first, count): only that contiguous range of the experts
+    lives here (one chip's share of an expert-parallel layer).  Routing is
+    still over all of them; the rows sent to a held expert come first in
+    expert order and `run_experts` gets the group sizes of the held experts
+    alone (count,); a row sent to an absent expert is computed by nobody
+    and adds nothing to y (its part of the sum is another chip's), and no
+    gradient comes back through it.  The buffer is R = `buffer_rows` long:
+    all T*k rows for a share of half the experts and more, twice the
+    share's expected rows for a smaller one, and then a step that sends it
+    more runs over all T*k rows instead (`run_experts` is traced at both
+    lengths, and called again by the backward pass).  Nothing is dropped
+    under any imbalance.  In the job timeline `moe.rows_buffered` against
+    `moe.rows_routed` is what the buffer costs, and `moe.overflow_passes`
+    how many passes (forward, recomputed, backward) took the long way.
+    None: all are held."""
+    T, k = experts.shape
+    first, count = held or (0, n_experts)
+    C = buffer_rows(T * k, count, n_experts)
+    tracing.count("moe.experts", n_experts)
+    tracing.count("moe.experts_held", count)
+    tracing.count("moe.rows_routed", T * k)
+    tracing.count("moe.rows_buffered", C)
+    with jax.named_scope("dispatch"):
+        by_expert, group_sizes = _sort_by_expert(experts, n_experts, held)
+    over = _over_all_rows if C == T * k \
+        else functools.partial(_over_either, C)
+    return over(x, weights, by_expert, group_sizes, held,
+                run_experts), group_sizes

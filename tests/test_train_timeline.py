@@ -309,8 +309,9 @@ def test_outside_a_job_nothing_is_recorded(ray_train):
 
 
 def _trace_a_mixture_step(config):
-    """Traces (never runs) a tiny `deepseek_v3` step that holds 4 of its 8
-    experts, as `fit()`'s loop would lower it, and reports."""
+    """Traces (never runs) a tiny `deepseek_v3` step that holds
+    ``config["held"]`` of its 8 experts, as `fit()`'s loop would lower it,
+    and reports."""
     import dataclasses
 
     import jax
@@ -320,7 +321,8 @@ def _trace_a_mixture_step(config):
     from ray_tpu.models import deepseek_v3 as model
     from ray_tpu.train import session
 
-    cfg = dataclasses.replace(model.DEEPSEEK_V3_TINY, held=(2, 4))
+    cfg = dataclasses.replace(model.DEEPSEEK_V3_TINY,
+                              held=tuple(config["held"]))
     optimizer = model.trained_by(optax.adamw(1e-3))
     params = jax.eval_shape(lambda k: model.init_params(k, cfg),
                             jax.random.PRNGKey(0))
@@ -331,16 +333,20 @@ def _trace_a_mixture_step(config):
     session.report({"done": 1})
 
 
-def test_a_fit_counts_the_mixture_in_its_timeline(ray_train, tmp_path):
+@pytest.mark.parametrize("held, buffered", [(4, 128 * 3), (2, 192)])
+def test_a_fit_counts_the_mixture_in_its_timeline(ray_train, tmp_path, held,
+                                                  buffered):
     """`moe.experts`, `moe.experts_held`, `moe.rows_routed` and
     `moe.rows_buffered` (`ops/moe.py:moe_dispatch`, as the step is traced):
     two routed layers, each traced once: 8 experts of which 4 are held, 2
-    x 64 tokens x 3 rows routed and a buffer of all of them."""
+    x 64 tokens x 3 rows routed and a buffer of all of them; of which 2
+    are held, a buffer of twice the 96 rows a balanced router sends them
+    (`ops/moe.py:buffer_rows`)."""
     result = _fit(tmp_path, _trace_a_mixture_step, "moe_counters",
-                  datasets=False).fit()
+                  config={"held": (2, held)}, datasets=False).fit()
     assert result.error is None
     counters = _timeline(result.path)[0]["counters"]
     assert counters["moe.experts"] == 2 * 8
-    assert counters["moe.experts_held"] == 2 * 4
+    assert counters["moe.experts_held"] == 2 * held
     assert counters["moe.rows_routed"] == 2 * 128 * 3
-    assert counters["moe.rows_buffered"] == 2 * 128 * 3
+    assert counters["moe.rows_buffered"] == 2 * buffered

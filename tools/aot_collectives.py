@@ -11,7 +11,11 @@ chip, then every collective once per `channel_id` (XLA prints an
 asynchronous one several times) grouped by kind and result shape, with the
 bytes of one and of all.  `--layers` compiles a shallower model: the
 collectives of one layer are those of every layer, in a tenth of the time
-(all 48 of XL take four minutes here).  Nothing runs: no time, no result.
+(all 48 of XL take four minutes here).  `--conditionals` lists every
+`conditional` with the largest arrays each of its branches makes (a share
+of the experts: `ops/moe.py` runs over its buffer in one branch and over
+all the routed rows in the other; XLA puts the false branch first).
+Nothing runs: no time, no result.
 """
 
 from __future__ import annotations
@@ -65,6 +69,65 @@ def collectives(hlo_text: str) -> dict:
             for (t, d), n in collections.Counter(shapes).items()))
         count, _ = found.get(key, (0, size))
         found[key] = (count + 1, size)
+    return found
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_CALLED = re.compile(r"(?:calls|to_apply|body|condition|true_computation|"
+                     r"false_computation)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+
+
+def conditional_branches(hlo_text: str, largest: int = 4) -> list:
+    """[(the conditional's name, [(branch, [(bytes, "dtype[shape]"), ...])])]
+    over a compiled module's text: for each branch, the ``largest`` distinct
+    result shapes of its instructions, those of the computations it calls
+    (fusions, loops, nested conditionals) included."""
+    bodies, name = {}, None
+    for line in hlo_text.splitlines():
+        start = _COMPUTATION.match(line)
+        if start:
+            name = start.group(1)
+            bodies[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            bodies[name].append(_COMMENT.sub("", line))
+
+    def called(line):
+        names = _CALLED.findall(line)
+        for group in _BRANCHES.findall(line):
+            names += [b.strip().lstrip("%") for b in group.split(",")]
+        return names
+
+    def reach(name, seen):
+        if name in bodies and name not in seen:
+            seen.add(name)
+            for line in bodies[name]:
+                for other in called(line):
+                    reach(other, seen)
+        return seen
+
+    found = []
+    for lines in bodies.values():
+        for line in lines:
+            if " conditional(" not in line:
+                continue
+            branches = []
+            for group in _BRANCHES.findall(line) or [",".join(
+                    _CALLED.findall(line))]:
+                for branch in group.split(","):
+                    branch = branch.strip().lstrip("%")
+                    shapes = {
+                        (_WIDTH.get(t, 4) * math.prod(
+                            int(n) for n in d.split(",") if n), f"{t}[{d}]")
+                        for body in reach(branch, set())
+                        for made in bodies[body] if "=" in made
+                        for t, d in _SHAPE.findall(
+                            made.split("=", 1)[1].split("(", 1)[0])}
+                    branches.append(
+                        (branch, sorted(shapes, reverse=True)[:largest]))
+            found.append((line.split("=")[0].strip(), branches))
     return found
 
 
@@ -128,6 +191,9 @@ def main():
                         help="a name under benchmark/traffic/ (default: "
                              "that of the configuration's first cell)")
     parser.add_argument("--layers", type=int, default=None)
+    parser.add_argument("--conditionals", action="store_true",
+                        help="list each conditional's branches with the "
+                             "largest arrays they make")
     parser.add_argument("--hlo", default=None,
                         help="write the compiled module's text here")
     args = parser.parse_args()
@@ -158,6 +224,12 @@ def main():
             found.items(), key=lambda kv: -kv[1][0] * kv[1][1]):
         print(f"{count:5d} x {kind:18s} {shape:60s} {size / 1e6:10.2f} MB "
               f"each {count * size / 1e6:10.1f} MB")
+    if args.conditionals:
+        for name, branches in conditional_branches(text):
+            print(name)
+            for branch, shapes in branches:
+                print(f"    {branch:28s}", ", ".join(
+                    f"{shape} {size / 1e6:.1f} MB" for size, shape in shapes))
     print(json.dumps({"collectives": sum(c for c, _ in found.values()),
                       "bytes": sum(c * s for c, s in found.values())}))
 

@@ -4,6 +4,7 @@ reference, with the device time of its forward and of its backward.
     python tools/chip_kernels.py            # the cases, default tiles
     python tools/chip_kernels.py --sweep    # tile -> ms at the cells' shapes
     python tools/chip_kernels.py --sweep s512-d64   # at the named shapes only
+    python tools/chip_kernels.py --cases moe_held_8k   # the named cases only
 
 One case on each side of the gates in ``ops/flash_attention.py``: the lane
 kernels with the one-kernel backward (GPT-2 124M's heads), the transposing
@@ -15,11 +16,18 @@ q and k 192, v 128).  This process holds the chip, so run it alone.  Exits non-z
 ran as compiled Mosaic kernels on a TPU and agrees with
 ``reference_attention``.  ``--sweep`` times forced square tiles instead
 (what ``_auto_tiles`` is set from) and compares nothing.
+
+``moe_held_8k`` is no attention case: one routed layer of kanana's share
+(`ops/moe.py`: dispatch, the held experts, combine) over all the routed
+rows, over the held rows' buffer, and as `moe_dispatch` chooses between
+them; the forms tried for summing the buffer into its tokens; and all of
+it against a float32 loop over the held experts.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import glob
 import json
 import os
@@ -36,6 +44,11 @@ CASES = {
     "two_kernel_bwd": ((2, 2048, 32, 128), 3),
     "olmoe_4k": ((4, 4096, 16, 128), 3),
     "mla_8k": ((2, 8192, 32, 192, 128), 3),
+}
+# (T, k, held, experts, E, W): tokens, choices a token, experts held of the
+# router's, hidden and expert widths
+MOE_CASES = {
+    "moe_held_8k": (16384, 6, 16, 128, 2048, 768),
 }
 # the benchmark's cells: medium's step, XL's on one chip of four, OLMoE's
 SWEEP = {
@@ -71,11 +84,9 @@ def _qkv(shape, dtype):
                  for i, w in enumerate(widths))
 
 
-def kernel_ms(f, *args, calls=5):
-    """Device ms of the Mosaic kernels in one call of jitted ``f``: a
-    profiler trace of ``calls`` calls, the durations of every
-    ``tpu_custom_call`` on the first chip's ``XLA Ops`` line summed and
-    divided by the calls."""
+def _device_events(f, args, calls):
+    """[(name, start ns, duration ns)] of the first chip's ``XLA Ops`` line
+    over ``calls`` calls of jitted ``f`` under the profiler."""
     import jax
 
     jax.block_until_ready(f(*args))
@@ -89,11 +100,180 @@ def kernel_ms(f, *args, calls=5):
         data = jax.profiler.ProfileData.from_file(found[0])
     for plane in data.planes:
         if plane.name == "/device:TPU:0":
-            ns = sum(e.duration_ns for line in plane.lines
-                     if line.name == "XLA Ops" for e in line.events
-                     if "tpu_custom_call" in e.name)
-            return round(ns / calls / 1e6, 4)
+            return [(e.name, e.start_ns, e.duration_ns)
+                    for line in plane.lines if line.name == "XLA Ops"
+                    for e in line.events]
     raise ValueError("the trace holds no /device:TPU:0 plane")
+
+
+def kernel_ms(f, *args, calls=5):
+    """Device ms of the Mosaic kernels in one call of jitted ``f``: the
+    durations of every ``tpu_custom_call`` summed and divided by the
+    calls."""
+    ns = sum(duration for name, _, duration in _device_events(f, args, calls)
+             if "tpu_custom_call" in name)
+    return round(ns / calls / 1e6, 4)
+
+
+def busy_ms(f, *args, calls=3):
+    """Device ms of one call of jitted ``f``, every operation counted: the
+    union of the events' intervals (a conditional's event covers its
+    branch's), divided by the calls."""
+    busy, until = 0.0, 0.0
+    for _, start, duration in sorted(
+            _device_events(f, args, calls), key=lambda e: e[1]):
+        busy += max(0.0, start + duration - max(start, until))
+        until = max(until, start + duration)
+    return round(busy / calls / 1e6, 4)
+
+
+def moe_case(name, dtype):
+    """One routed layer of a share of the experts at ``MOE_CASES[name]``:
+    yields a line for each form (forward ms; forward and backward ms of
+    one `jax.grad` in x, the weights and the three matrices; largest
+    error of y and of each gradient relative to a float32 loop over the
+    held experts), then a line for each way tried to sum the buffer into
+    its tokens (forward ms alone)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.layers import swiglu
+    from ray_tpu.ops import moe
+
+    T, k, count, n_experts, E, W = MOE_CASES[name]
+    held = (0, count)
+    C = moe.buffer_rows(T * k, count, n_experts)
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    x = jax.random.normal(ks[0], (T, E), dtype)
+    scores = jax.nn.sigmoid(jax.random.normal(ks[1], (T, n_experts)))
+    weights, experts = jax.lax.top_k(scores, k)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    gate, up, down = (
+        (0.02 * jax.random.normal(key, shape)).astype(dtype)
+        for key, shape in zip(ks[2:5], ((count, E, W), (count, E, W),
+                                        (count, W, E))))
+    seed = jax.random.normal(ks[5], (T, E), jnp.float32)   # d loss / d y
+
+    def routed(over):
+        """The layer with ``over`` between the sorts and the result."""
+        def y(experts, x, weights, gate, up, down):
+            def run(xs, sizes):
+                grouped = lambda a, w: jax.lax.ragged_dot(a, w, sizes)
+                return swiglu(xs, gate, up, down, matmul=grouped)
+            if over is None:
+                return moe.moe_dispatch(x, weights, experts, n_experts, run,
+                                        held=held)[0]
+            return over(x, weights,
+                        *moe._sort_by_expert(experts, n_experts, held),
+                        held, run)
+        return y
+
+    def reference(experts, x, weights, gate, up, down):
+        """Every held expert over every token in float32, weighted by the
+        token's choice of it (0 where it chose another)."""
+        y = jnp.zeros((T, E), jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            for e in range(count):
+                w = jnp.sum(jnp.where(experts == e, weights, 0), axis=1)
+                y = y + w[:, None] * swiglu(x, gate[e], up[e], down[e])
+        return y
+
+    def both(y):
+        forward = jax.jit(y)
+        grad = jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(y(*a).astype(jnp.float32) * seed),
+            (1, 2, 3, 4, 5)))
+        return forward, grad
+
+    def in_f32(args):
+        return (args[0], *(a.astype(jnp.float32) for a in args[1:]))
+
+    args = (experts, x, weights, gate, up, down)
+    exact = both(reference)
+    want = (exact[0](*in_f32(args)), *exact[1](*in_f32(args))[1])
+    forms = {"all_rows": moe._over_all_rows,
+             "held_rows": functools.partial(moe._over_held_rows, C),
+             "moe_dispatch": None,
+             # a router that sends every token to held experts, more than
+             # the buffer holds: `moe_dispatch` runs over all the rows
+             "moe_dispatch_overflowed": None}
+    for form, over in forms.items():
+        if form == "moe_dispatch_overflowed":
+            most, favoured = jax.lax.top_k(
+                scores + (jnp.arange(n_experts) < count), k)
+            args = (favoured, x,
+                    most / jnp.sum(most, axis=-1, keepdims=True), *args[3:])
+            want = (exact[0](*in_f32(args)), *exact[1](*in_f32(args))[1])
+        forward, grad = both(routed(over))
+        got = (forward(*args), *grad(*args)[1])
+        errs = {what: round(float(
+            np.max(np.abs(np.asarray(g, np.float32) - np.asarray(w)))
+            / np.max(np.abs(np.asarray(w)))), 5) for what, g, w in zip(
+                ("y", "dx", "dweights", "dgate", "dup", "ddown"), got, want)}
+        yield {"case": name, "form": form, "buffer_rows": C,
+               "rows_held": int(jnp.sum(args[0] < count)),
+               "fwd_ms": busy_ms(forward, *args),
+               "fwd_bwd_ms": busy_ms(grad, *args), "rel_err": errs}
+
+    # the sum of the buffer into its tokens, alone, each way tried
+    tokens, scale, where, by_token, same, start, some = jax.jit(
+        lambda: _buffer_of(experts, weights, held, n_experts, C))()
+    ys = jax.random.normal(ks[0], (C, E), dtype)
+
+    def sorted_neighbours(ys):
+        """The buffer in token order (a sort of C keys, a gather of C
+        rows), to each row the up to k-1 after it of the same token
+        added, each token's first row (or nothing) gathered."""
+        z = ys[by_token].astype(jnp.float32) * scale[by_token][:, None]
+        after = jnp.concatenate([z, jnp.zeros((k - 1, E), z.dtype)])
+        for d, same_token in enumerate(same, 1):
+            z = z + jnp.where(same_token[:, None], after[d:d + C], 0)
+        return jnp.where(some[:, None], z.astype(dtype)[start], 0)
+
+    puts = {
+        # kept (`ops/moe.py:_sum_into_tokens`): a gather of T rows for
+        # each of the k choices, summed
+        "gather_per_choice": lambda ys: moe._sum_into_tokens(
+            ys, scale, where),
+        "sorted_neighbours": sorted_neighbours,
+        "scatter_add": lambda ys: jnp.zeros((T, E), jnp.float32).at[
+            tokens].add(jnp.where(where[1][:, None], ys.astype(jnp.float32)
+                                  * scale[:, None], 0)).astype(dtype),
+    }
+    kept = None
+    for form, put in puts.items():
+        put = jax.jit(put)
+        out = np.asarray(put(ys), np.float32)
+        kept = out if kept is None else kept
+        yield {"case": name, "put": form, "fwd_ms": busy_ms(put, ys),
+               "max_abs_diff_to_kept": round(float(
+                   np.max(np.abs(out - kept))), 5)}
+
+
+def _buffer_of(experts, weights, held, n_experts, C):
+    """What `ops/moe.py:_over_held_rows` derives from a routing, and what
+    the other forms of the sum need: (each buffered row's token, its
+    weight, `where`; the buffer's rows in token order, whether each of the
+    k-1 rows after one is the same token's, each token's first row there,
+    whether it has one)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import moe
+
+    T, k = experts.shape
+    by_expert, sizes = moe._sort_by_expert(experts, n_experts, held)
+    n_held = jnp.sum(sizes[held[0]:held[0] + held[1]])
+    first, where = moe._buffer_index(C, k, by_expert, n_held)
+    in_order, by_token = jax.lax.sort(
+        (jnp.where(where[1], first, T * k), jnp.arange(C, dtype=jnp.int32)),
+        num_keys=1)
+    token = jnp.concatenate([in_order // k, jnp.full((k - 1,), -1, jnp.int32)])
+    same = [token[d:d + C] == token[:C] for d in range(1, k)]
+    mine = jnp.sum(where[2] < C, axis=1, dtype=jnp.int32)
+    return (where[0], weights.reshape(T * k)[first], where, by_token, same,
+            jnp.cumsum(mine) - mine, mine > 0)
 
 
 def time_passes(shape, dtype, block_q=None, block_k=None):
@@ -168,9 +348,15 @@ def main():
     parser.add_argument("--sweep", nargs="*", metavar="SHAPE",
                         help="time forced tiles at these of SWEEP's shapes "
                              f"({', '.join(SWEEP)}; none named: at all)")
+    parser.add_argument("--cases", nargs="+", metavar="CASE",
+                        default=[*CASES, *MOE_CASES],
+                        help=f"run these only ({', '.join(CASES)}, "
+                             f"{', '.join(MOE_CASES)}; default: all)")
     args = parser.parse_args()
     if args.sweep and set(args.sweep) - set(SWEEP):
         parser.error(f"--sweep: no such shape in {sorted(SWEEP)}")
+    if set(args.cases) - set(CASES) - set(MOE_CASES):
+        parser.error(f"--cases: no such case in {[*CASES, *MOE_CASES]}")
 
     import jax
     import jax.numpy as jnp
@@ -202,6 +388,8 @@ def main():
 
     failed = []
     for name, (shape, n_kernels) in CASES.items():
+        if name not in args.cases:
+            continue
         errs, found = compare_with_reference(shape, jnp.bfloat16)
         fwd_ms, bwd_ms = time_passes(shape, jnp.bfloat16)
         ok = found == n_kernels and max(errs.values()) < TOLERANCE
@@ -211,6 +399,14 @@ def main():
                           "mosaic_kernels": found, "rel_err": errs,
                           "fwd_ms": fwd_ms, "bwd_ms": bwd_ms,
                           "device_kind": dev.device_kind}), flush=True)
+    for name in MOE_CASES:
+        for line in moe_case(name, jnp.bfloat16) \
+                if name in args.cases else ():
+            ok = max(line.get("rel_err", {"": 0}).values()) < TOLERANCE
+            if not ok:
+                failed.append(f"{name}:{line['form']}")
+            print(json.dumps({**line, "ok": ok,
+                              "device_kind": dev.device_kind}), flush=True)
     if failed:
         sys.exit(f"failed: {failed}")
 
