@@ -67,10 +67,15 @@ from ray_tpu.models.layers import (
     swiglu,
     train_step,
 )
-from ray_tpu.ops.moe import buffer_rows, moe_dispatch
+from ray_tpu.ops.moe import (
+    ROUTING_BIAS,
+    buffer_rows,
+    moe_dispatch,
+    sigmoid_route,
+    trained_by,  # noqa: F401  (`deepseek_v3.trained_by` is public)
+)
+from ray_tpu.ops.moe import routing_bias_rule as _bias_rule_over
 from ray_tpu.parallel.attention import attention
-
-ROUTING_BIAS = "e_score_correction_bias"
 
 
 @dataclass(frozen=True)
@@ -207,15 +212,7 @@ def _attention(x, p, cfg: DeepseekV3Config):
 
 def _route(xt, router, cfg: DeepseekV3Config):
     """-> (weights (T, k) f32, experts (T, k) int32) over all experts."""
-    scores = jax.nn.sigmoid(jnp.matmul(
-        xt, router["kernel"].astype(xt.dtype),
-        preferred_element_type=jnp.float32))                  # (T, N)
-    # the bias picks and does not weigh; nothing differentiates through it
-    _, experts = jax.lax.top_k(
-        scores + jax.lax.stop_gradient(router[ROUTING_BIAS]), cfg.top_k)
-    weights = jnp.take_along_axis(scores, experts, axis=-1)
-    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
-    return weights * cfg.routed_scale, experts
+    return sigmoid_route(xt, router, cfg.top_k, 1e-20, cfg.routed_scale)
 
 
 def _moe(x, p, cfg: DeepseekV3Config):
@@ -303,36 +300,9 @@ def loss_fn(params, batch, cfg: DeepseekV3Config):
 
 
 def routing_bias_rule(cfg: DeepseekV3Config):
-    """rule(params, out) -> params for `layers.train_step`: each routed
-    layer's bias moves `bias_update_speed` towards the experts that were
-    sent fewer rows than the mean, by `out["expert_rows"]`."""
-    def rule(params, out):
-        with jax.named_scope("routing_bias_update"):
-            params = dict(params)
-            for j, i in enumerate(cfg.moe_layers):
-                n = out["expert_rows"][j].astype(jnp.float32)
-                layer = params[f"layer_{i}"]
-                router = layer["moe"]["router"]
-                bias = router[ROUTING_BIAS] + cfg.bias_update_speed \
-                    * jnp.sign(jnp.mean(n) - n)
-                params[f"layer_{i}"] = {**layer, "moe": {
-                    **layer["moe"], "router": {**router, ROUTING_BIAS: bias}}}
-            return params
-    return rule
-
-
-def trained_by(optimizer):
-    """``optimizer`` over every leaf but the routing biases, which it
-    neither moves nor decays and keeps no moments for."""
-    import optax
-
-    def labels(params):
-        return jax.tree_util.tree_map_with_path(
-            lambda path, _: "rule" if path[-1].key == ROUTING_BIAS
-            else "optimizer", params)
-
-    return optax.multi_transform(
-        {"optimizer": optimizer, "rule": optax.set_to_zero()}, labels)
+    """`ops/moe.py:routing_bias_rule` over this model's routed layers at
+    its `bias_update_speed`."""
+    return _bias_rule_over(cfg.moe_layers, cfg.bias_update_speed)
 
 
 def make_train_step(cfg: DeepseekV3Config, optimizer):

@@ -1,15 +1,19 @@
 """What the Train-path models share: the norms, rotary positions, the
-chunked loss and the mixed-precision step.  A model file imports these and
-`ray_tpu.parallel.attention`; it imports no other model file.
+gated feed-forward, the gated short convolution, the chunked loss and the
+mixed-precision step.  A model file imports these, `ray_tpu.parallel.attention`
+and `ray_tpu.ops`; it imports no other model file.
 
-Imports jax and nothing of the runtime: a worker pays nothing for it before
-its first step.
+Imports jax and, of the runtime, only the job timeline's counters
+(`util/tracing.py`, which imports nothing heavy): a worker pays nothing for
+it before its first step.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.util import tracing
 
 
 def layer_norm(x, p, eps=1e-5):
@@ -55,6 +59,96 @@ def swiglu(x, gate, up, down, matmul=jnp.matmul):
     experts (a grouped one over rows sorted by expert, the weights one
     stack an expert)."""
     return matmul(jax.nn.silu(matmul(x, gate)) * matmul(x, up), down)
+
+
+def _back(x, k):
+    """x (B, S, E) -> row t holds x_{t-k}, zeros before the sequence starts:
+    one `pad` with a negative high edge, which XLA:TPU fuses into the pass
+    that reads it (a slice and a concatenate it writes out first)."""
+    return x if k == 0 else jax.lax.pad(
+        x, jnp.zeros((), x.dtype), ((0, 0, 0), (k, -k, 0), (0, 0, 0)))
+
+
+def _ahead(x, k):
+    """row t holds x_{t+k}, zeros past the sequence's end."""
+    return x if k == 0 else jax.lax.pad(
+        x, jnp.zeros((), x.dtype), ((0, 0, 0), (-k, k, 0), (0, 0, 0)))
+
+
+def _thirds(bcz):
+    E = bcz.shape[-1] // 3
+    return (bcz[..., i * E:(i + 1) * E] for i in range(3))
+
+
+def _f32(x):
+    return x.astype(jnp.float32)
+
+
+def _taps(w, shifted):
+    """sum_j w_j * shifted(L-1-j) in float32, w (E, L)."""
+    L = w.shape[1]
+    return sum(w[:, j].astype(jnp.float32) * shifted(L - 1 - j)
+               for j in range(L))
+
+
+@jax.custom_vjp
+def _gate_taps(bcz, w):
+    """[b | c | z] (B, S, 3E) and taps w (E, L) -> c * conv(b * z), (B, S,
+    E): v_t = sum_j w_j * g_{t-(L-1)+j} over g = b * z, zero before the
+    sequence starts, one filter a channel.  L shifted multiply-adds in
+    float32 over the (B, S, E) layout, no transpose to channels-major, the
+    gate b * z taken again for each tap from shifted b and z: ONE pass that
+    reads 3E and writes E a token.  The backward is written out for the
+    same reason (two passes: the gradient of [b | c | z], and the taps');
+    autodiff of a first form that padded g made four with a float32
+    (B, S, E) array between them (`tools/chip_kernels.py --cases
+    shortconv_8k` has the forms tried; PERF.md §6, PR 34)."""
+    b, c, z = _thirds(bcz)
+    v = _taps(w, lambda k: _f32(_back(b, k)) * _f32(_back(z, k)))
+    return (_f32(c) * v).astype(bcz.dtype)
+
+
+def _gate_taps_bwd(res, dy):
+    """dv = dy * c goes back through the taps looking AHEAD (dg_t = sum_j
+    w_j dv_{t+(L-1)-j}); db = dg * z, dc = dy * v, dz = dg * b; the taps'
+    gradient sums dv_t * g_{t-(L-1)+j} over batch and sequence."""
+    bcz, w = res
+    b, c, z = _thirds(bcz)
+    L = w.shape[1]
+    g = lambda k: _f32(_back(b, k)) * _f32(_back(z, k))
+    dg = _taps(w, lambda k: _f32(_ahead(dy, k)) * _f32(_ahead(c, k)))
+    dbcz = jnp.concatenate(
+        [dg * _f32(z), _f32(dy) * _taps(w, g), dg * _f32(b)], axis=-1)
+    dv = _f32(dy) * _f32(c)
+    dw = jnp.stack([jnp.sum(dv * g(L - 1 - j), axis=(0, 1))
+                    for j in range(L)], axis=1)
+    return dbcz.astype(bcz.dtype), dw.astype(w.dtype)
+
+
+# the forward rule names the function itself, not the module's global: a
+# test that patches `_gate_taps` wraps the whole rule, forward and backward
+_gate_taps.defvjp(lambda bcz, w, _primal=_gate_taps: (_primal(bcz, w),
+                                                      (bcz, w)),
+                  _gate_taps_bwd)
+
+
+def short_conv(u, p):
+    """The gated short convolution of LFM2 (`Lfm2ShortConv`), u (B, S, E):
+    [b | c | z] = u W_in;  g = b * z;  v = the causal depthwise convolution
+    of g with the layer's L taps (position t sees t-L+1 .. t);
+    (c * v) W_out.  No bias, no activation function.  ``p``: {"in_proj":
+    {"kernel": (E, 3E)}, "conv": {"kernel": (E, L)}, "out_proj":
+    {"kernel": (E, E)}}.  Counts itself on the job timeline as the step is
+    traced (`shortconv.layers`, `shortconv.taps`)."""
+    taps = p["conv"]["kernel"]
+    tracing.count("shortconv.layers")
+    tracing.count("shortconv.taps", taps.shape[1])
+    with jax.named_scope("in_proj"):
+        bcz = u @ p["in_proj"]["kernel"].astype(u.dtype)
+    with jax.named_scope("gate_taps"):
+        y = _gate_taps(bcz, taps)
+    with jax.named_scope("out_proj"):
+        return y @ p["out_proj"]["kernel"].astype(u.dtype)
 
 
 def chunked_xent(x, wte, targets, n_chunks: int):

@@ -113,10 +113,11 @@ def _attn_block(x, p, cfg: LlamaConfig, positions, cache=None,
                        vv.astype(jnp.float32)).astype(x.dtype)
         o = o.transpose(0, 2, 1, 3).reshape(B, S, H * D)
     else:
-        k = _repeat_kv(k, H // Hk)
-        v = _repeat_kv(v, H // Hk)
-        # layout-native lane kernel (128-dim heads map 1:1 onto lane
-        # blocks): no (B,S,H,D) <-> (B,H,S,D) transposes
+        # prefill: the kernels read the Hk key/value heads as they are
+        # (query head h on head h // (H / Hk)).  With Hk == H the
+        # layout-native lane kernel runs (128-dim heads map 1:1 onto lane
+        # blocks: no (B,S,H,D) <-> (B,H,S,D) transposes); grouped queries
+        # take the head-major kernels
         o = flash_attention_bshd(q, k, v, True)
         o = o.reshape(B, S, H * D)
     return o @ p["o_proj"]["kernel"].astype(x.dtype), new_cache

@@ -41,8 +41,23 @@ runs backward: a dq kernel blocked over q rows and a dk/dv kernel blocked
 over k columns, both recomputing probabilities tile-by-tile from the saved
 logsumexp.  The S×S matrix never exists in HBM in any pass.
 
-Each kernel adds its tiles to the job timeline as the step is traced
-(`attention.tiles`, `attention.tiles_skipped`: see `_count_tiles`).
+Grouped-query attention: k and v may come with fewer heads than q
+(``H % H_kv == 0``), query head h reading key/value head h // (H / H_kv).
+The head-major kernels read that head through their `BlockSpec` index maps
+(`_kv_rows`), so no copy of k or v with H heads exists; dk and dv leave the
+backward kernels as one float32 partial a query head and are summed over
+each group in float32 (`_sum_groups`: at (2, 8192, 32 / 8, 64) on a v5e the
+split backward takes 28.68 ms of kernels and 31.44 with the sums and the
+transposes; a dk/dv kernel that summed a group itself, the group's heads
+its innermost grid axis, took 31.58 and 33.71, fetching a head's q, do and
+statistics again at every k block: PERF.md §6, PR 34).  The lane layout
+slices every operand's heads out of the same lanes and declines such a
+call, which then takes the head-major kernels.  With ``H_kv == H`` every
+kernel is the one it was.
+
+Each kernel adds its tiles and the heads it reads to the job timeline as
+the step is traced (`attention.tiles`, `attention.tiles_skipped`,
+`attention.q_heads`, `attention.kv_heads`: see `_count_tiles`).
 
 On non-TPU backends the same kernels run in interpret mode for tiny shapes
 (tests), and a pure-XLA reference path is used otherwise.
@@ -447,17 +462,38 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[...] = dv_acc.astype(dv_ref.dtype)
 
 
+def _kv_rows(group, tile=False):
+    """Index map of the k / v block that grid row g's query head reads,
+    the whole sequence or (``tile``) block i of it.  The rows of q are
+    (batch, head) flat and those of k (batch, key/value head), so query
+    head h's key/value head h // group is row g // group."""
+    if group == 1:
+        return (lambda g, i: (g, i, 0)) if tile else (lambda g, i: (g, 0, 0))
+    if tile:
+        return lambda g, i: (g // group, i, 0)
+    return lambda g, i: (g // group, 0, 0)
+
+
+def _sum_groups(partials, like):
+    """(B * H, S, D) float32 partials of dk or dv, one a query head ->
+    ``like``'s (B, H_kv, S, D): each group's summed in float32."""
+    B, Hkv, S, D = like.shape
+    return jnp.sum(partials.reshape(B, Hkv, -1, S, D), axis=2).astype(
+        like.dtype)
+
+
 @_kernel_call("whole")
 def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, whole,
                     interpret):
     """``whole``: a grid step takes the whole sequence and walks its q
-    tiles; else one q tile (the forward's two forms: `_fwd_rows`).  q and
-    k are (B, H, S, D); v, and so o, may have another last dim."""
+    tiles; else one q tile (the forward's two forms: `_fwd_rows`).  q is
+    (B, H, S, D) and k (B, H_kv, S, D); v, and so o, may have another last
+    dim."""
     B, H, S, D = q.shape
-    Dv = v.shape[-1]
+    Hkv, Dv = k.shape[1], v.shape[-1]
     qf = q.reshape(B * H, S, D)
-    kf = k.reshape(B * H, S, D)
-    vf = v.reshape(B * H, S, Dv)
+    kf = k.reshape(B * Hkv, S, D)
+    vf = v.reshape(B * Hkv, S, Dv)
     rows = S if whole else block_q
     grid = (B * H, S // rows)
     kernel = functools.partial(
@@ -469,8 +505,8 @@ def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, whole,
         kernel,
         grid=grid,
         in_specs=[qspec,
-                  pl.BlockSpec((None, S, D), lambda g, i: (g, 0, 0)),
-                  pl.BlockSpec((None, S, Dv), lambda g, i: (g, 0, 0))],
+                  pl.BlockSpec((None, S, D), _kv_rows(H // Hkv)),
+                  pl.BlockSpec((None, S, Dv), _kv_rows(H // Hkv))],
         out_specs=[pl.BlockSpec((None, rows, Dv), lambda g, i: (g, i, 0)),
                    pl.BlockSpec((None, rows, 1), lambda g, i: (g, i, 0))],
         out_shape=[
@@ -489,12 +525,15 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
     """``whole``: the one-kernel backward, a grid step taking the whole
     sequence; else the two-kernel split, blocked over q rows (dq) and over
     k columns (dk/dv).  v, o and do may have another last dim than q and
-    k."""
+    k; k and v may have fewer heads (a group of q's heads reads each):
+    every query head then writes its float32 part of dk and dv, and
+    `_sum_groups` adds a group's."""
     B, H, S, D = q.shape
-    Dv = v.shape[-1]
+    Hkv, Dv = k.shape[1], v.shape[-1]
+    group = H // Hkv
     qf = q.reshape(B * H, S, D)
-    kf = k.reshape(B * H, S, D)
-    vf = v.reshape(B * H, S, Dv)
+    kf = k.reshape(B * Hkv, S, D)
+    vf = v.reshape(B * Hkv, S, Dv)
     dof = do.reshape(B * H, S, Dv)
     lsef = lse.reshape(B * H, S, 1)
     # delta = rowsum(do * o): cheap elementwise+reduce, XLA fuses it.
@@ -504,15 +543,17 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
         delta = jnp.sum(
             do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = delta.reshape(B * H, S, 1)
+    part = (lambda x: x.dtype) if group == 1 else (lambda x: jnp.float32)
     out_shape = [jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-                 jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
-                 jax.ShapeDtypeStruct((B * H, S, Dv), v.dtype)]
+                 jax.ShapeDtypeStruct((B * H, S, D), part(k)),
+                 jax.ShapeDtypeStruct((B * H, S, Dv), part(v))]
     params = _compiler_params(S, D, Dv, q.dtype)
 
     def spec(rows, width, index):
         return pl.BlockSpec((None, rows, width), index)
 
     whole_seq, tile = (lambda g, i: (g, 0, 0)), (lambda g, i: (g, i, 0))
+    kv_seq, kv_tile = _kv_rows(group), _kv_rows(group, tile=True)
 
     if whole:
         # one kernel, tiled inside: shares s/dp across dq/dk/dv.
@@ -523,7 +564,8 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
                               causal=causal, block_q=block_q,
                               block_k=block_k, seq_len=S),
             grid=(B * H, 1),
-            in_specs=[qk, qk, vo, vo, row, row],
+            in_specs=[qk, spec(S, D, kv_seq), spec(S, Dv, kv_seq), vo, row,
+                      row],
             out_specs=[qk, qk, vo],
             out_shape=out_shape,
             scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
@@ -537,8 +579,8 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
                 block_q=block_q, block_k=block_k, seq_len=S,
             ),
             grid=(B * H, S // block_q),
-            in_specs=[spec(block_q, D, tile), spec(S, D, whole_seq),
-                      spec(S, Dv, whole_seq), spec(block_q, Dv, tile),
+            in_specs=[spec(block_q, D, tile), spec(S, D, kv_seq),
+                      spec(S, Dv, kv_seq), spec(block_q, Dv, tile),
                       spec(block_q, 1, tile), spec(block_q, 1, tile)],
             out_specs=spec(block_q, D, tile),
             out_shape=out_shape[0],
@@ -551,8 +593,8 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
                 block_q=block_q, block_k=block_k, seq_len=S,
             ),
             grid=(B * H, S // block_k),
-            in_specs=[spec(S, D, whole_seq), spec(block_k, D, tile),
-                      spec(block_k, Dv, tile), spec(S, Dv, whole_seq),
+            in_specs=[spec(S, D, whole_seq), spec(block_k, D, kv_tile),
+                      spec(block_k, Dv, kv_tile), spec(S, Dv, whole_seq),
                       spec(S, 1, whole_seq), spec(S, 1, whole_seq)],
             out_specs=[spec(block_k, D, tile), spec(block_k, Dv, tile)],
             out_shape=out_shape[1:],
@@ -560,8 +602,10 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
             compiler_params=params,
         )(qf, kf, vf, dof, lsef, delta)
 
-    return (dq.reshape(B, H, S, D), dk.reshape(B, H, S, D),
-            dv.reshape(B, H, S, Dv))
+    dq = dq.reshape(B, H, S, D)
+    if group == 1:
+        return dq, dk.reshape(B, H, S, D), dv.reshape(B, H, S, Dv)
+    return dq, _sum_groups(dk, k), _sum_groups(dv, v)
 
 
 # ---------------------------------------------------------------------------
@@ -644,7 +688,10 @@ def _pallas_forward_bshd(q, k, v, sm_scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((B * G, S, hpb), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_COMPILER_PARAMS,
+        # k and v of a lane block are held for the whole sequence: past
+        # `_WHOLE_SEQ_MAX` they can outgrow the default (S = 8,192 wants
+        # 18.3 of 16 MB); every shape a cell runs keeps the default
+        compiler_params=_compiler_params(S, W, W, q.dtype),
     )(qf, kf, vf)
     # lse (B*G, S, hpb) -> (B, H, S): group-major heads, tiny tensor.
     lse = lse.reshape(B, G, S, hpb).transpose(0, 1, 3, 2).reshape(B, H, S)
@@ -695,7 +742,17 @@ def _pallas_backward_bshd(q, k, v, o, lse, do, sm_scale, causal, block_q,
 # reference path + public API
 # ---------------------------------------------------------------------------
 
+def _repeat_groups(q, k, v):
+    """k and v with each head repeated for its group of q's heads (axis 1
+    is the heads'); as they are where the counts are equal."""
+    group = q.shape[1] // k.shape[1]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+
+
 def reference_attention(q, k, v, sm_scale, causal):
+    k, v = _repeat_groups(q, k, v)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * sm_scale
     if causal:
@@ -710,8 +767,7 @@ def reference_attention(q, k, v, sm_scale, causal):
 
 def _reference_backward(q, k, v, lse, do, delta, sm_scale, causal):
     qf = q.astype(jnp.float32)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
+    kf, vf = _repeat_groups(q, k.astype(jnp.float32), v.astype(jnp.float32))
     dof = do.astype(jnp.float32)
     s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * sm_scale
     if causal:
@@ -724,6 +780,8 @@ def _reference_backward(q, k, v, lse, do, delta, sm_scale, causal):
     ds = p * (dp - delta[..., None]) * sm_scale
     dq = jnp.einsum("bhqk,bhkd->bhqd", ds, kf)
     dk = jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
+    if k.shape[1] != q.shape[1]:
+        dk, dv = _sum_groups(dk, k), _sum_groups(dv, v)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -800,11 +858,14 @@ def _auto_tiles(S: int, causal: bool):
     smaller ones skip more of the square and pay more fixed cost.  Swept:
     S = 1,024 at head_dim 64 and 128, and S = 512 at head_dim 64; the same
     caps won at each, and other lengths take them unmeasured.  Past
-    `_WHOLE_SEQ_MAX`, swept at S = 4,096 (D = 128) and at S = 8,192 with
+    `_WHOLE_SEQ_MAX`, swept at S = 4,096 (D = 128), at S = 8,192 with
     q/k 192 and v 128 wide (PERF.md §6, PR 32: forward 13.76 ms at 1,024,
     13.09 at 512, 22.24 at 256; backward 42.64 at 512, its cap, 51.29 at
-    256): 512 forward is 5 to 9 % faster than the largest block at both
-    and is left, 0.7 % of a step."""
+    256) and at S = 8,192 with 32 query heads on 8 key/value heads of 64
+    (PERF.md §6, PR 34: forward 10.42 at 1,024, 9.94 at 512, 19.37 at 256;
+    backward 28.68 at 512, 55.19 at 256): 512 forward is 5 to 9 % faster
+    than the largest block at all three and is left, under 0.7 % of a
+    step."""
     whole = _auto_block(S, 1024)
     if not causal or S > _WHOLE_SEQ_MAX:
         return (whole, whole), (whole, whole)
@@ -829,12 +890,18 @@ def _resolve(q, S, causal, sm_scale, block_q, block_k):
         for bq, bk in _auto_tiles(S, causal))
 
 
-def _count_tiles(S, block_q, block_k, causal, kernels=1):
+def _count_tiles(S, block_q, block_k, causal, heads, kernels=1):
     """Add one kernel's tiles to the job timeline, as the step is traced:
     `attention.tiles` the (block_q x block_k) tiles of the S x S score
     square, `attention.tiles_skipped` those of them wholly above the
     diagonal, which a causal kernel does not visit.  Once per kernel in the
-    traced program (not per head slice or grid step)."""
+    traced program (not per head slice or grid step).  Beside them
+    ``heads``: those of the q the kernel is given and of the k it reads
+    from HBM (`attention.q_heads`, `attention.kv_heads`): a quarter where
+    four query heads share a key/value head, equal where a caller repeated
+    k and v first."""
+    tracing.count("attention.q_heads", kernels * heads[0])
+    tracing.count("attention.kv_heads", kernels * heads[1])
     num_q, num_k = S // block_q, S // block_k
     skipped = sum(num_k - min(num_k, pl.cdiv((i + 1) * block_q, block_k))
                   for i in range(num_q)) if causal else 0
@@ -845,7 +912,9 @@ def _count_tiles(S, block_q, block_k, causal, kernels=1):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal=False, sm_scale=None,
                     block_q=None, block_k=None):
-    """Multi-head attention over (batch, heads, seq, head_dim) tensors.
+    """Multi-head attention over (batch, heads, seq, head_dim) tensors; k
+    and v may have fewer heads than q, a divisor of its count (query head h
+    reads key/value head h // group).
 
     Blocks the call does not name come from `_auto_tiles`; the grid dims
     are marked parallel for Mosaic.  Up to `_WHOLE_SEQ_MAX` the
@@ -867,7 +936,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
         _warn_reference(q.shape, bq, bk, problem)
         o, lse = reference(q, k, v)
     else:
-        _count_tiles(S, bq, bk, causal)
+        _count_tiles(S, bq, bk, causal, (q.shape[1], k.shape[1]))
         o, lse = _by_platform(
             functools.partial(_pallas_forward, sm_scale=scale, causal=causal,
                               block_q=bq, block_k=bk, whole=whole),
@@ -892,7 +961,8 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, do, delta=None):
     if problem:
         _warn_reference(q.shape, bq, bk, problem)
         return _reference_backward(q, k, v, lse, do, delta, scale, causal)
-    _count_tiles(S, bq, bk, causal, kernels=1 if whole else 2)
+    _count_tiles(S, bq, bk, causal, (q.shape[1], k.shape[1]),
+                 kernels=1 if whole else 2)
 
     def kernel(q, k, v, o, lse, do, delta, interpret):
         return _pallas_backward(q, k, v, o, lse, do, scale, causal, bq, bk,
@@ -924,7 +994,8 @@ def flash_attention_bshd(q, k, v, causal=False, sm_scale=None,
     the layout models naturally produce from the fused qkv projection.
 
     When the lane tiling applies (head_dim divides 128, heads fill whole
-    lane blocks; for the backward S <= `_WHOLE_SEQ_MAX`) the kernels
+    lane blocks, k and v have q's heads; for the backward S <=
+    `_WHOLE_SEQ_MAX`) the kernels
     index heads through 128-wide lane blocks and no
     (B,S,H,D) <-> (B,H,S,D) transpose ever materializes; otherwise the
     call transposes to the bhsd kernels (still flash, just with the
@@ -938,8 +1009,8 @@ def _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k):
     scale, whole, (bq, bk), _ = _resolve(q, S, causal, sm_scale, block_q,
                                          block_k)
     # the lane layout slices every operand's heads out of the same lanes
-    if v.shape == q.shape and _bshd_lanes_ok(q, S, bq, bk):
-        _count_tiles(S, bq, bk, causal)
+    if k.shape == v.shape == q.shape and _bshd_lanes_ok(q, S, bq, bk):
+        _count_tiles(S, bq, bk, causal, (q.shape[2], k.shape[2]))
 
         def reference(q, k, v):
             o, lse = reference_attention(_tr(q), _tr(k), _tr(v), scale,
@@ -962,8 +1033,9 @@ def _flash_bwd_bshd(causal, sm_scale, block_q, block_k, res, do):
     S = q.shape[1]
     scale, whole, _, (bq, bk) = _resolve(q, S, causal, sm_scale, block_q,
                                          block_k)
-    if whole and v.shape == q.shape and _bshd_lanes_ok(q, S, bq, bk):
-        _count_tiles(S, bq, bk, causal)
+    if whole and k.shape == v.shape == q.shape \
+            and _bshd_lanes_ok(q, S, bq, bk):
+        _count_tiles(S, bq, bk, causal, (q.shape[2], k.shape[2]))
 
         def reference(q, k, v, o, lse, do):
             qt, kt, vt, ot, dot = map(_tr, (q, k, v, o, do))

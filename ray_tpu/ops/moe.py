@@ -7,8 +7,11 @@ are one contiguous group of whatever size the router made it, the experts
 run over the ragged groups (`jax.lax.ragged_dot`, which XLA:TPU lowers to
 a grouped-matmul kernel), and the rows go back to their tokens and are
 summed with their weights.  Shared by `models/olmoe.py` (SiLU-gated
-experts), `models/gpt2.py`'s mixture (GELU experts) and
-`models/deepseek_v3.py` (a chip's share of the experts).
+experts), `models/gpt2.py`'s mixture (GELU experts), and
+`models/deepseek_v3.py` and `models/lfm2_moe.py` (a chip's share of the
+experts), which also share the router at the end of this file: sigmoid
+scores, a routing bias that picks and does not weigh and moves by a rule
+of its own (`sigmoid_route`, `routing_bias_rule`, `trained_by`).
 
 Where all the experts live here, the buffer between dispatch and combine
 is the T*k rows.  Where a share of under half of them does (``held``), it
@@ -300,3 +303,63 @@ def moe_dispatch(x, weights, experts, n_experts, run_experts, held=None):
         else functools.partial(_over_either, C)
     return over(x, weights, by_expert, group_sizes, held,
                 run_experts), group_sizes
+
+
+# -- the sigmoid router with a routing bias (DeepSeek-V3's `noaux_tc`) ------
+
+ROUTING_BIAS = "e_score_correction_bias"
+
+
+def sigmoid_route(xt, router, top_k, eps, scale):
+    """xt (T, E) -> (weights (T, k) f32, experts (T, k) int32) over all the
+    experts ``router["kernel"]`` (E, N) scores: s = sigmoid(xt W) in
+    float32; the top k of s + b, b the leaf `ROUTING_BIAS` where the router
+    has one (it picks and does not weigh, and nothing differentiates
+    through it); weights s at the chosen, over their sum + ``eps`` unless
+    ``eps`` is None (weights not renormalised), times ``scale``."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        xt, router["kernel"].astype(xt.dtype),
+        preferred_element_type=jnp.float32))                  # (T, N)
+    picks = scores
+    if ROUTING_BIAS in router:
+        picks = scores + jax.lax.stop_gradient(router[ROUTING_BIAS])
+    _, experts = jax.lax.top_k(picks, top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if eps is not None:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
+    return weights * scale, experts
+
+
+def routing_bias_rule(routed_layers, speed):
+    """rule(params, out) -> params for `models/layers.py:train_step`: the
+    bias of each routed layer (``params[f"layer_{i}"]["moe"]["router"]``,
+    i in ``routed_layers``, in the order of `out["expert_rows"]`'s rows)
+    moves ``speed`` towards the experts that were sent fewer rows than the
+    mean: b_e += speed * sign(mean(n) - n_e) (arXiv:2412.19437)."""
+    def rule(params, out):
+        with jax.named_scope("routing_bias_update"):
+            params = dict(params)
+            for j, i in enumerate(routed_layers):
+                n = out["expert_rows"][j].astype(jnp.float32)
+                layer = params[f"layer_{i}"]
+                router = layer["moe"]["router"]
+                bias = router[ROUTING_BIAS] + speed \
+                    * jnp.sign(jnp.mean(n) - n)
+                params[f"layer_{i}"] = {**layer, "moe": {
+                    **layer["moe"], "router": {**router, ROUTING_BIAS: bias}}}
+            return params
+    return rule
+
+
+def trained_by(optimizer):
+    """``optimizer`` over every leaf but the routing biases, which it
+    neither moves nor decays and keeps no moments for."""
+    import optax
+
+    def labels(params):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: "rule" if path[-1].key == ROUTING_BIAS
+            else "optimizer", params)
+
+    return optax.multi_transform(
+        {"optimizer": optimizer, "rule": optax.set_to_zero()}, labels)

@@ -28,14 +28,27 @@ def _tr(x):
 
 def attention(q, k, v, *, causal: bool = True, variant: str = "flash"):
     """Multi-head attention over (batch, seq, heads, head_dim) arrays, at
-    head_dim^-1/2.
+    head_dim^-1/2.  k and v may have fewer heads than q, a divisor of its
+    count (grouped queries: head h reads key/value head h // group); the
+    kernels read them as they are and nothing repeats them.
 
     ``"flash"``: the layout-native kernel (no (B,S,H,D) <-> (B,H,S,D)
     transposes); under a bound mesh of several devices each runs it on its
     batch/head slice.  ``"ring"`` / ``"ulysses"``: sequence parallelism
     over the bound mesh's sequence axis.  ``"dense"``: the O(S^2)
     reference, left to the partitioner."""
+    if q.shape[2] % k.shape[2] or k.shape[:3] != v.shape[:3]:
+        raise ValueError(
+            f"attention: q has {q.shape[2]} heads and k, v "
+            f"{k.shape[2]}, {v.shape[2]}: the key/value heads must be "
+            f"alike and divide the query heads")
     if variant in ("ring", "ulysses"):
+        if k.shape[2] != q.shape[2]:
+            raise NotImplementedError(
+                f"attention(variant={variant!r}) takes k and v with q's "
+                f"{q.shape[2]} heads, not {k.shape[2]}: grouped queries "
+                f"are not written for the sequence-parallel kernels "
+                f"(repeat k and v, or use \"flash\")")
         return _sequence_parallel(q, k, v, require_mesh(), causal, variant)
     if variant == "dense":
         o, _ = reference_attention(_tr(q), _tr(k), _tr(v),
@@ -52,8 +65,10 @@ def _flash_sharded(q, k, v, mesh, causal):
     rules say, and each device runs the kernel on its own slice.  A
     pallas_call is an opaque custom call to the SPMD partitioner: under a
     mesh of several devices jax refuses to lower one that is not inside a
-    shard_map."""
-    spec = dividing_spec(mesh, ("batch", None, "heads", None), q.shape)
+    shard_map.  The heads' axes are those that divide the key/value heads
+    (and so q's: with grouped queries each device holds whole groups, its
+    query heads and the key/value heads they read)."""
+    spec = dividing_spec(mesh, ("batch", None, "heads", None), k.shape)
     return jax.shard_map(
         lambda q, k, v: flash_attention_bshd(q, k, v, causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

@@ -1,0 +1,276 @@
+"""Grouped-query attention inside the flash kernels: k and v with fewer
+heads than q (query head h on key/value head h // group), in every form a
+call can take, against `reference_attention` with k and v repeated; what
+a call with equal heads gets is what it got; and the entry under a mesh."""
+
+import hashlib
+import re
+import warnings
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import flash_attention as fa
+from ray_tpu.parallel.attention import _flash_sharded, attention
+from ray_tpu.parallel.mesh import create_mesh
+from ray_tpu.util import tracing
+
+H, D, S = 8, 16, 256
+
+
+def _tr(x):
+    return x.transpose(0, 2, 1, 3)
+
+
+def _qkv(kv_heads, dtype=jnp.float32, B=1, S=S, H=H, D=D):
+    """(B, S, H, D) q and (B, S, kv_heads, D) k, v."""
+    ks = jax.random.split(jax.random.PRNGKey(kv_heads), 3)
+    return (jax.random.normal(ks[0], (B, S, H, D), dtype),
+            jax.random.normal(ks[1], (B, S, kv_heads, D), dtype),
+            jax.random.normal(ks[2], (B, S, kv_heads, D), dtype))
+
+
+def _value_and_grads(f, q, k, v):
+    def loss(q, k, v):
+        o = f(q, k, v)
+        return jnp.sum(jnp.sin(o.astype(jnp.float32))), o
+    (_, o), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(q, k, v)
+    return (o, *grads)
+
+
+def _repeated_reference(causal):
+    """`reference_attention` given k and v with q's heads: each key/value
+    head repeated for its group, so autodiff sums a group's gradients."""
+    def reference(q, k, v):
+        group = q.shape[2] // k.shape[2]
+        o, _ = fa.reference_attention(
+            _tr(q), jnp.repeat(_tr(k), group, axis=1),
+            jnp.repeat(_tr(v), group, axis=1), q.shape[-1] ** -0.5, causal)
+        return _tr(o)
+    return reference
+
+
+def _kernels(f, *args):
+    """Names of the kernel functions of the `pallas_call`s in f's jaxpr."""
+    from jax.extend import core as jex_core
+
+    def walk(jaxpr, found):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.add(eqn.params["jaxpr"].debug_info.func_name)
+            for value in eqn.params.values():
+                for item in value if isinstance(value, (tuple, list)) \
+                        else (value,):
+                    if isinstance(item, jex_core.ClosedJaxpr):
+                        walk(item.jaxpr, found)
+                    elif isinstance(item, jex_core.Jaxpr):
+                        walk(item, found)
+        return found
+    return walk(jax.make_jaxpr(f)(*args).jaxpr, set())
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("layout", ["lane", "head_major"])
+@pytest.mark.parametrize("form", ["whole", "long"])
+@pytest.mark.parametrize("group", [1, 4, 8])
+def test_gqa_kernels_match_the_reference_with_kv_repeated(
+        group, form, layout, causal, monkeypatch):
+    """o, dq, dk, dv of the interpreted kernels, in the form a grid step
+    takes the whole sequence in and in the split one (`_WHOLE_SEQ_MAX`
+    lowered so that an interpretable size passes it: q tiles looping over
+    k blocks, the two-kernel backward), through the (B, S, H, D) entry (the
+    lane layout where the heads are equal, head-major where they are not)
+    and the (B, H, S, D) one, at H / H_kv of 1, 4 and 8."""
+    if form == "long":
+        monkeypatch.setattr(fa, "_WHOLE_SEQ_MAX", 128)
+    q, k, v = _qkv(H // group)
+    if layout == "lane":
+        def kernel(q, k, v):
+            return fa.flash_attention_bshd(q, k, v, causal, None, 128, 128)
+    else:
+        def kernel(q, k, v):
+            return _tr(fa.flash_attention(_tr(q), _tr(k), _tr(v), causal,
+                                          None, 128, 128))
+    with jax.default_matmul_precision("highest"):
+        got = _value_and_grads(kernel, q, k, v)
+        want = _value_and_grads(_repeated_reference(causal), q, k, v)
+    assert got[2].shape == k.shape and got[3].shape == v.shape
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert float(jnp.max(jnp.abs(g - w))) < 5e-5, name
+
+
+def test_the_reference_paths_take_grouped_queries_too():
+    """Beyond the interpreter's size off the TPU a call runs
+    `reference_attention` and `_reference_backward`: they repeat k and v
+    themselves and sum a group's gradients."""
+    q, k, v = _qkv(2)
+    with jax.default_matmul_precision("highest"):
+        def reference(q, k, v):
+            o, _ = fa.reference_attention(_tr(q), _tr(k), _tr(v),
+                                          D ** -0.5, True)
+            return _tr(o)
+        got = _value_and_grads(reference, q, k, v)
+        want = _value_and_grads(_repeated_reference(True), q, k, v)
+        o, lse = fa.reference_attention(_tr(q), _tr(k), _tr(v), D ** -0.5,
+                                        True)
+        do = jnp.cos(o)
+        delta = jnp.sum(do * o, axis=-1)
+        back = fa._reference_backward(_tr(q), _tr(k), _tr(v), lse, do, delta,
+                                      D ** -0.5, True)
+    for g, w in zip(got, want):
+        assert float(jnp.max(jnp.abs(g - w))) < 2e-5
+    for g, w in zip(back, want[1:]):
+        assert g.shape == _tr(w).shape
+        assert float(jnp.max(jnp.abs(g - _tr(w)))) < 2e-5
+
+
+def test_grouped_queries_take_the_head_major_kernels_and_no_fallback():
+    """The lane layout slices every operand's heads out of the same lanes
+    and declines the call; it goes head-major, never to the O(S^2)
+    reference; dk and dv leave the kernel in float32."""
+    q, k, v = _qkv(2, jnp.bfloat16)
+
+    def grad(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention_bshd(
+            q, k, v, True).astype(jnp.float32)), (0, 1, 2))(q, k, v)
+
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", fa.AttentionFallbackWarning)
+        assert _kernels(grad, q, k, v) == {"_fwd_kernel", "_bwd_fused_kernel"}
+        text = str(jax.make_jaxpr(grad)(q, k, v))
+    # the parts of dk and dv: one a query head, float32
+    assert f"f32[{H},{S},{D}]" in text
+    dq, dk, dv = grad(q, k, v)
+    assert dk.dtype == dv.dtype == jnp.bfloat16 and dk.shape == k.shape
+    # equal heads that fill lanes keep the lane kernels
+    q, k, v = _qkv(H, jnp.bfloat16)
+    assert _kernels(grad, q, k, v) == {"_fwd_kernel_lanes",
+                                       "_bwd_fused_kernel_lanes"}
+
+
+def _normalised_jaxpr(f, *args):
+    """The jaxpr's text without source paths and line numbers."""
+    return re.sub(r"(/[\w./-]+\.py|<[\w ]+>):\d+(:\d+)?", "",
+                  str(jax.make_jaxpr(f)(*args)))
+
+
+# sha256 (16 hex digits) of `_normalised_jaxpr` of the gradient of causal
+# attention at the accepted cells' shapes, taken on the parent of the PR
+# that brought grouped queries (ab23c31): (B, S, H, D), v's width, through
+# `parallel/attention.py:attention`.  A PR that changes the kernels on
+# purpose changes these with them.
+PARENT = {
+    "gpt2-medium": ((16, 1024, 16, 64), None, False, "58052d16e0f69720"),
+    "gpt2-xl a chip": ((4, 1024, 25, 64), None, False, "8eb6286277376bc2"),
+    "olmoe": ((4, 4096, 16, 128), None, False, "fe169d92c65f1afc"),
+    "kanana": ((2, 8192, 32, 192), 128, True, "a6f85e1c2df21be4"),
+    "lanes, long forward": ((2, 2048, 32, 64), None, False,
+                            "d427a24dd632e38b"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENT))
+def test_equal_heads_lower_to_the_jaxpr_they_lowered_to(name):
+    """`H_kv == H`: every `pallas_call`, index map and shape is the
+    parent's, so the five accepted cells run the parent's kernels."""
+    shape, v_dim, entry, want = PARENT[name]
+    B, S, heads, D = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((B, S, heads, v_dim or D), jnp.bfloat16)
+
+    def loss(q, k, v):
+        o = attention(q, k, v) if entry \
+            else fa.flash_attention_bshd(q, k, v, True, None, None, None)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    text = _normalised_jaxpr(jax.grad(loss, (0, 1, 2)), q, q, v)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+
+
+@pytest.mark.parametrize("shape,kv_heads,kernels", [
+    ((2, 8192, 32, 64), 8, 3), ((2, 1024, 32, 64), 8, 2)])
+def test_grouped_queries_lower_to_mosaic_for_tpu(shape, kv_heads, kernels):
+    """LFM2-24B-A2B's attention (32 query heads on 8 key/value heads of 64
+    at S = 8,192: the head-major forward by q tiles and the two-kernel
+    backward) exported for a TPU from this CPU host: Mosaic custom calls,
+    no interpreted kernel body and no O(S^2) reference."""
+    B, S, heads, D = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((B, S, kv_heads, D), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(attention(q, k, v).astype(jnp.float32) ** 2)
+
+    module = jax.export.export(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+        platforms=["tpu"])(q, k, k).mlir_module()
+    assert module.count("stablehlo.custom_call @tpu_custom_call") == kernels
+    assert "stablehlo.dot_general" not in module
+    assert "stablehlo.while" not in module
+
+
+@pytest.mark.parametrize("kv_heads", [8, 2])
+def test_flash_sharded_cuts_k_and_v_by_their_own_heads(kv_heads):
+    """Under a mesh of four devices along the heads' axis, 8 query heads on
+    8 or 2 key/value heads: with 8 each device gets 2 of each; 2 key/value
+    heads do not divide by 4, so the heads stay whole on every device (the
+    batch is still cut).  The result is the unsharded call's."""
+    mesh = create_mesh({"dp": 2, "tp": 4})
+    q, k, v = _qkv(kv_heads, B=2, S=128)
+    with jax.default_matmul_precision("highest"):
+        want = _value_and_grads(_repeated_reference(True), q, k, v)
+        got = _value_and_grads(
+            lambda q, k, v: _flash_sharded(q, k, v, mesh, True), q, k, v)
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert g.shape == w.shape
+        assert float(jnp.max(jnp.abs(g - w))) < 5e-5, name
+    text = str(jax.make_jaxpr(
+        lambda q, k, v: _flash_sharded(q, k, v, mesh, True))(q, k, v))
+    specs = re.search(r"in_specs=\((.*?)\)\)", text).group(1)
+    assert specs.count("'dp'") == 3
+    assert specs.count("'tp'") == (3 if kv_heads == 8 else 0)
+
+
+def test_the_entry_refuses_what_it_cannot_do():
+    q, k, v = _qkv(2, S=128)
+    mesh = create_mesh({"sp": 8})
+    from ray_tpu.parallel.context import use_mesh
+
+    for variant in ("ring", "ulysses"):
+        with use_mesh(mesh), pytest.raises(
+                NotImplementedError, match="grouped queries"):
+            attention(q, k, v, variant=variant)
+    with pytest.raises(ValueError, match="divide the query heads"):
+        attention(q, k[:, :, :1].repeat(3, axis=2), v[:, :, :1].repeat(
+            3, axis=2))
+    # the dense variant repeats k and v itself
+    with jax.default_matmul_precision("highest"):
+        o = attention(q, k, v, variant="dense")
+        want = _repeated_reference(True)(q, k, v)
+    assert float(jnp.max(jnp.abs(o - want))) < 2e-5
+
+
+def test_the_kernels_count_the_heads_they_read():
+    """`attention.q_heads` / `attention.kv_heads` on the job timeline, once
+    per kernel as it is traced: a quarter where four query heads share a
+    key/value head, equal where k and v were repeated before the call."""
+    names = ("attention.q_heads", "attention.kv_heads")
+
+    def traced(q_shape, kv_heads):
+        q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
+        k = jax.ShapeDtypeStruct(
+            (*q_shape[:2], kv_heads, q_shape[3]), jnp.bfloat16)
+        before = [tracing.counter(name) for name in names]
+        jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(
+            attention(q, k, v).astype(jnp.float32)), (0, 1, 2)), q, k, k)
+        return [tracing.counter(name) - b for name, b in zip(names, before)]
+
+    assert traced((2, 1024, 32, 64), 8) == [0, 0]          # no job, no count
+    with tracing.timeline_span("train.fit", root=True):
+        # whole sequence: a forward and a one-kernel backward
+        assert traced((2, 1024, 32, 64), 8) == [64, 16]
+        # past `_WHOLE_SEQ_MAX`: a forward and the two kernels of the split
+        assert traced((2, 2048, 32, 64), 8) == [96, 24]
+        assert traced((2, 2048, 32, 64), 32) == [96, 96]

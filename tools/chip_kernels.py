@@ -12,7 +12,9 @@ bhsd kernels with the one-kernel backward (25 heads: no lane tiling; XL's
 share of a batch on one chip), the two-kernel backward past
 ``_WHOLE_SEQ_MAX`` (S=2048), OLMoE's shape (S=4096, D=128), and latent
 attention's two widths at S=8192 (a fifth number in a shape is v's width:
-q and k 192, v 128).  This process holds the chip, so run it alone.  Exits non-zero unless every case
+q and k 192, v 128), and grouped queries at S=8192 (`gqa_8k`: 32 query
+heads on 8 key/value heads of 64, `KV_HEADS`; beside it the same call with
+k and v repeated to 32 heads first).  This process holds the chip, so run it alone.  Exits non-zero unless every case
 ran as compiled Mosaic kernels on a TPU and agrees with
 ``reference_attention``.  ``--sweep`` times forced square tiles instead
 (what ``_auto_tiles`` is set from) and compares nothing.
@@ -22,6 +24,12 @@ ran as compiled Mosaic kernels on a TPU and agrees with
 rows, over the held rows' buffer, and as `moe_dispatch` chooses between
 them; the forms tried for summing the buffer into its tokens; and all of
 it against a float32 loop over the held experts.
+
+``shortconv_8k`` is no attention case either: one gated short convolution
+(`models/layers.py:short_conv`) at (2, 8192, 2048) with 3 taps, the whole
+operator and its gates and taps alone, in each form tried for the taps,
+against a float32 sum over taps and against the least time of the gates'
+and taps' bytes.
 """
 
 from __future__ import annotations
@@ -44,6 +52,13 @@ CASES = {
     "two_kernel_bwd": ((2, 2048, 32, 128), 3),
     "olmoe_4k": ((4, 4096, 16, 128), 3),
     "mla_8k": ((2, 8192, 32, 192, 128), 3),
+    "gqa_8k": ((2, 8192, 32, 64), 3),
+}
+# key/value heads of the cases and sweeps whose k and v have fewer than q
+KV_HEADS = {"gqa_8k": 8, "gqa-8k": 8}
+# (B, S, E, taps) of one conv operator
+SHORTCONV_CASES = {
+    "shortconv_8k": (2, 8192, 2048, 3),
 }
 # (T, k, held, experts, E, W): tokens, choices a token, experts held of the
 # router's, hidden and expert widths
@@ -66,6 +81,9 @@ SWEEP = {
     # kanana-2-30b-a3b's latent attention, multiplied out: q, k 192, v 128
     "mla-8k": ((2, 8192, 32, 192, 128), (
         (256, 256), (512, 512), (1024, 1024))),
+    # LFM2-24B-A2B's attention layers: 32 query heads on 8 key/value heads
+    "gqa-8k": ((2, 8192, 32, 64), (
+        (256, 256), (512, 512), (1024, 1024))),
 }
 TOLERANCE = 0.05
 
@@ -74,14 +92,16 @@ TOLERANCE = 0.05
 REFERENCE_SCORES = 1 << 28
 
 
-def _qkv(shape, dtype):
-    """q, k of (B, S, H, D) and v of (B, S, H, Dv), Dv = D if not given."""
+def _qkv(shape, dtype, kv_heads=None):
+    """q, k of (B, S, H, D) and v of (B, S, H, Dv), Dv = D if not given; k
+    and v with ``kv_heads`` heads if given."""
     import jax
 
-    *bsh, d = shape[:4]
+    b, s, h, d = shape[:4]
     widths = (d, d, shape[4] if len(shape) > 4 else d)
-    return tuple(jax.random.normal(jax.random.PRNGKey(i), (*bsh, w), dtype)
-                 for i, w in enumerate(widths))
+    heads = (h, kv_heads or h, kv_heads or h)
+    return tuple(jax.random.normal(jax.random.PRNGKey(i), (b, s, n, w), dtype)
+                 for i, (n, w) in enumerate(zip(heads, widths)))
 
 
 def _device_events(f, args, calls):
@@ -276,24 +296,125 @@ def _buffer_of(experts, weights, held, n_experts, C):
             jnp.cumsum(mine) - mine, mine > 0)
 
 
-def time_passes(shape, dtype, block_q=None, block_k=None):
+def time_passes(shape, dtype, block_q=None, block_k=None, kv_heads=None,
+                repeated=False, every_op=False):
     """(forward ms, backward ms) on the device of the kernels of causal
     ``flash_attention_bshd`` at ``shape`` with these tiles (None:
-    ``_auto_tiles``)."""
+    ``_auto_tiles``); k and v with ``kv_heads`` heads, ``repeated`` to q's
+    before the call.  ``every_op``: of every operation of the two passes
+    (the transposes to the head-major kernels, the sums of a group's parts
+    of dk and dv), not of the kernels alone."""
     import jax
+    import jax.numpy as jnp
 
     from ray_tpu.ops import flash_attention as fa
 
-    q, k, v = _qkv(shape, dtype)
+    q, k, v = _qkv(shape, dtype, kv_heads)
+    if repeated:
+        k, v = (jnp.repeat(t, shape[2] // kv_heads, axis=2) for t in (k, v))
     forward = jax.jit(lambda q, k, v: fa._flash_fwd_bshd(
         q, k, v, True, None, block_q, block_k))
     backward = jax.jit(lambda res, do: fa._flash_bwd_bshd(
         True, None, block_q, block_k, res, do))
     o, res = forward(q, k, v)
-    return kernel_ms(forward, q, k, v), kernel_ms(backward, res, o)
+    ms = busy_ms if every_op else kernel_ms
+    return ms(forward, q, k, v), ms(backward, res, o)
 
 
-def compare_with_reference(shape, dtype):
+def shortconv_case(name, dtype):
+    """One conv operator at ``SHORTCONV_CASES[name]``: a line for each form
+    of the gates and taps (forward ms; forward and backward ms of one
+    `jax.grad` in [b c z] and the taps; largest error of the result and of
+    both gradients relative to a float32 sum over taps; the least time of
+    the bytes: forward reads 3E and writes E a token, backward reads 4E
+    and writes 3E), then a line for the whole operator with the form
+    `layers.short_conv` has."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import layers
+
+    B, S, E, L = SHORTCONV_CASES[name]
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    bcz = jax.random.normal(ks[0], (B, S, 3 * E), dtype)
+    taps = jax.random.uniform(ks[1], (E, L), jnp.float32, -L ** -0.5,
+                              L ** -0.5)
+    seed = jax.random.normal(ks[2], (B, S, E), jnp.float32)   # d loss / d y
+
+    def by_convolution(bcz, w):
+        """`lax.conv_general_dilated`, one group a channel, in the same
+        (B, S, E) layout; the gates around it left to XLA."""
+        b, c, z = (bcz[..., i * E:(i + 1) * E] for i in range(3))
+        v = jax.lax.conv_general_dilated(
+            b * z, w.T[:, None, :].astype(bcz.dtype), (1,), [(L - 1, 0)],
+            dimension_numbers=("NWC", "WIO", "NWC"), feature_group_count=E)
+        return c * v
+
+    def padded_float32(bcz, w):
+        """The first form `layers._gate_taps` had: g = b * z in float32,
+        padded once, three slices of it; its backward by autodiff."""
+        b, c, z = (bcz[..., i * E:(i + 1) * E] for i in range(3))
+        g = jnp.pad((b * z).astype(jnp.float32),
+                    ((0, 0), (L - 1, 0), (0, 0)))
+        v = sum(w[:, j].astype(jnp.float32) * g[:, j:j + S]
+                for j in range(L))
+        return (c.astype(jnp.float32) * v).astype(bcz.dtype)
+
+    def in_float32(bcz, w):
+        bcz = bcz.astype(jnp.float32)
+        b, c, z = (bcz[..., i * E:(i + 1) * E] for i in range(3))
+        g = b * z
+        v = jnp.zeros_like(g)
+        for j in range(L):
+            back = L - 1 - j
+            v = v + w[:, j] * jnp.concatenate(
+                [jnp.zeros((B, back, E)), g[:, :S - back]], axis=1)
+        return c * v
+
+    def both(y):
+        return jax.jit(y), jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(y(*a).astype(jnp.float32) * seed), (0, 1)))
+
+    rel = lambda g, w: round(float(
+        np.max(np.abs(np.asarray(g, np.float32) - np.asarray(w)))
+        / np.max(np.abs(np.asarray(w)))), 5)
+    exact = both(in_float32)
+    want = (exact[0](bcz, taps), *exact[1](bcz, taps)[1])
+    peak = 819e9                       # HBM bytes a second, TPU v5e
+    width = jnp.dtype(dtype).itemsize
+    least = {"fwd": 4 * E * B * S * width / peak * 1e3,
+             "bwd": 7 * E * B * S * width / peak * 1e3}
+    for form, y in (("one_pass_shifted_gates", layers._gate_taps),  # kept
+                    ("padded_float32", padded_float32),
+                    ("conv_general_dilated", by_convolution)):
+        forward, grad = both(y)
+        w = taps.astype(dtype)
+        got = (forward(bcz, w), *grad(bcz, w)[1])
+        yield {"case": name, "form": form,
+               "fwd_ms": busy_ms(forward, bcz, w),
+               "fwd_bwd_ms": busy_ms(grad, bcz, w),
+               "least_fwd_ms": round(least["fwd"], 4),
+               "least_fwd_bwd_ms": round(least["fwd"] + least["bwd"], 4),
+               "rel_err": {what: rel(g, t) for what, g, t in zip(
+                   ("y", "dbcz", "dtaps"), got, want)}}
+    # the whole operator as the model calls it
+    u = jax.random.normal(ks[3], (B, S, E), dtype)
+    p = {"in_proj": {"kernel": (0.02 * jax.random.normal(
+             ks[4], (E, 3 * E))).astype(dtype)},
+         "conv": {"kernel": taps.astype(dtype)},
+         "out_proj": {"kernel": (0.02 * jax.random.normal(
+             ks[5], (E, E))).astype(dtype)}}
+    forward, grad = both(layers.short_conv)
+    flops = 2 * B * S * 4 * E * E
+    yield {"case": name, "form": "short_conv",
+           "fwd_ms": busy_ms(forward, u, p),
+           "fwd_bwd_ms": busy_ms(grad, u, p),
+           "least_fwd_ms": round(flops / 197e12 * 1e3, 4),
+           "least_fwd_bwd_ms": round(4 * flops / 197e12 * 1e3, 4)}
+
+
+def compare_with_reference(shape, dtype, kv_heads=None):
     """Causal ``flash_attention_bshd`` at ``shape`` (B, S, H, D[, Dv]),
     forward and backward, on the default device: (largest error of o, dq,
     dk, dv relative to ``reference_attention``'s, the worst of the batch's
@@ -310,7 +431,8 @@ def compare_with_reference(shape, dtype):
     from ray_tpu.ops import flash_attention as fa
     from ray_tpu.parallel.attention import attention
 
-    q, k, v = _qkv(shape, dtype)
+    q, k, v = _qkv(shape, dtype, kv_heads)
+    group = shape[2] // (kv_heads or shape[2])
 
     def kernel(q, k, v):
         o = fa.flash_attention_bshd(q, k, v, True)
@@ -328,14 +450,18 @@ def compare_with_reference(shape, dtype):
     reference = grad(reference)
     errs = dict.fromkeys(("o", "dq", "dk", "dv"), 0.0)
     B, S, H = shape[:3]
-    heads = max(1, min(H, REFERENCE_SCORES // (S * S)))
+    # whole groups of query heads, with the key/value heads they read
+    heads = max(group, min(H, REFERENCE_SCORES // (S * S)) // group * group)
     for row in range(B):
         for first in range(0, H, heads):
             part = (slice(row, row + 1), slice(None),
                     slice(first, first + heads))
-            (_, o_r), g_r = reference(q[part], k[part], v[part])
+            kv_part = (*part[:2], slice(first // group,
+                                        (first + heads) // group))
+            (_, o_r), g_r = reference(q[part], k[kv_part], v[kv_part])
             for what, a, b in zip(errs, (o_k, *g_k), (o_r, *g_r)):
-                a = np.asarray(a[part], np.float32)
+                a = np.asarray(a[part if what in ("o", "dq") else kv_part],
+                               np.float32)
                 b = np.asarray(b, np.float32)
                 errs[what] = max(errs[what], round(
                     float(np.max(np.abs(a - b)) / np.max(np.abs(b))), 5))
@@ -349,14 +475,16 @@ def main():
                         help="time forced tiles at these of SWEEP's shapes "
                              f"({', '.join(SWEEP)}; none named: at all)")
     parser.add_argument("--cases", nargs="+", metavar="CASE",
-                        default=[*CASES, *MOE_CASES],
+                        default=[*CASES, *MOE_CASES, *SHORTCONV_CASES],
                         help=f"run these only ({', '.join(CASES)}, "
-                             f"{', '.join(MOE_CASES)}; default: all)")
+                             f"{', '.join(MOE_CASES)}, "
+                             f"{', '.join(SHORTCONV_CASES)}; default: all)")
     args = parser.parse_args()
     if args.sweep and set(args.sweep) - set(SWEEP):
         parser.error(f"--sweep: no such shape in {sorted(SWEEP)}")
-    if set(args.cases) - set(CASES) - set(MOE_CASES):
-        parser.error(f"--cases: no such case in {[*CASES, *MOE_CASES]}")
+    known = [*CASES, *MOE_CASES, *SHORTCONV_CASES]
+    if set(args.cases) - set(known):
+        parser.error(f"--cases: no such case in {known}")
 
     import jax
     import jax.numpy as jnp
@@ -377,7 +505,8 @@ def main():
         for name in args.sweep or SWEEP:
             shape, blocks = SWEEP[name]
             for block in ((None, None),) + blocks:
-                fwd_ms, bwd_ms = time_passes(shape, jnp.bfloat16, *block)
+                fwd_ms, bwd_ms = time_passes(shape, jnp.bfloat16, *block,
+                                             kv_heads=KV_HEADS.get(name))
                 print(json.dumps({
                     "sweep": name, "shape": shape,
                     "tile": block if block[0] else _auto_tiles(
@@ -390,17 +519,38 @@ def main():
     for name, (shape, n_kernels) in CASES.items():
         if name not in args.cases:
             continue
-        errs, found = compare_with_reference(shape, jnp.bfloat16)
-        fwd_ms, bwd_ms = time_passes(shape, jnp.bfloat16)
+        kv_heads = KV_HEADS.get(name)
+        errs, found = compare_with_reference(shape, jnp.bfloat16, kv_heads)
+        fwd_ms, bwd_ms = time_passes(shape, jnp.bfloat16, kv_heads=kv_heads)
         ok = found == n_kernels and max(errs.values()) < TOLERANCE
         if not ok:
             failed.append(name)
-        print(json.dumps({"case": name, "shape": shape, "ok": ok,
-                          "mosaic_kernels": found, "rel_err": errs,
-                          "fwd_ms": fwd_ms, "bwd_ms": bwd_ms,
-                          "device_kind": dev.device_kind}), flush=True)
+        line = {"case": name, "shape": shape, "ok": ok,
+                "mosaic_kernels": found, "rel_err": errs,
+                "fwd_ms": fwd_ms, "bwd_ms": bwd_ms,
+                "device_kind": dev.device_kind}
+        if kv_heads:
+            # every operation of the passes, beside the same call with k
+            # and v repeated to q's heads first (the repeat not timed)
+            line["kv_heads"] = kv_heads
+            line["every_op_ms"] = time_passes(
+                shape, jnp.bfloat16, kv_heads=kv_heads, every_op=True)
+            line["repeated_kernel_ms"] = time_passes(
+                shape, jnp.bfloat16, kv_heads=kv_heads, repeated=True)
+            line["repeated_every_op_ms"] = time_passes(
+                shape, jnp.bfloat16, kv_heads=kv_heads, repeated=True,
+                every_op=True)
+        print(json.dumps(line), flush=True)
     for name in MOE_CASES:
         for line in moe_case(name, jnp.bfloat16) \
+                if name in args.cases else ():
+            ok = max(line.get("rel_err", {"": 0}).values()) < TOLERANCE
+            if not ok:
+                failed.append(f"{name}:{line['form']}")
+            print(json.dumps({**line, "ok": ok,
+                              "device_kind": dev.device_kind}), flush=True)
+    for name in SHORTCONV_CASES:
+        for line in shortconv_case(name, jnp.bfloat16) \
                 if name in args.cases else ():
             ok = max(line.get("rel_err", {"": 0}).values()) < TOLERANCE
             if not ok:
