@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.layers import (
+    checkpoint_layer,
     chunked_xent,
     num_params,  # noqa: F401  (`deepseek_v3.num_params` is public)
     rms_norm,
@@ -100,7 +101,9 @@ class DeepseekV3Config:
     rms_eps: float = 1e-6
     bias_update_speed: float = 0.001  # gamma of arXiv:2412.19437
     compute_dtype: Any = jnp.bfloat16
-    remat: bool = False               # jax.checkpoint each layer
+    # jax.checkpoint each layer, its attention kernel's output and row
+    # statistics kept (`layers.checkpoint_layer`)
+    remat: bool = False
     loss_chunk_rows: int = 2048       # `layers.chunked_xent`
 
     @property
@@ -250,7 +253,7 @@ def _layer(x, p, cfg: DeepseekV3Config):
 def _trunk(params, tokens, cfg: DeepseekV3Config):
     """-> ((B, S, E) after the final norm, the routers' statistics)."""
     x = params["embed_tokens"]["embedding"][tokens].astype(cfg.compute_dtype)
-    layer = jax.checkpoint(_layer, static_argnums=(2,)) if cfg.remat \
+    layer = checkpoint_layer(_layer, static_argnums=(2,)) if cfg.remat \
         else _layer
     rows = []
     for i in range(cfg.n_layer):

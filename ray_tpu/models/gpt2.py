@@ -29,7 +29,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.layers import layer_norm, train_step
+from ray_tpu.models.layers import checkpoint_layer, layer_norm, train_step
 from ray_tpu.ops.moe import moe_dispatch
 from ray_tpu.parallel.attention import attention
 from ray_tpu.parallel.sharding import constrain
@@ -44,7 +44,9 @@ class GPT2Config:
     n_embd: int = 768
     compute_dtype: Any = jnp.bfloat16
     attention: str = "flash"  # flash | ring | ulysses | dense
-    remat: bool = False      # jax.checkpoint each block (trade FLOPs for HBM)
+    # jax.checkpoint each block, its attention kernel's output and row
+    # statistics kept (`layers.checkpoint_layer`: trade FLOPs for HBM)
+    remat: bool = False
     # MoE (expert parallelism, SURVEY §2.6 row "EP"): >0 swaps every
     # block's dense FFN for a top-k routed mixture; expert weights carry a
     # leading "expert" dim that ShardingConfig places on the ep axis; the
@@ -223,7 +225,7 @@ def _trunk(params, tokens, cfg: GPT2Config, pp_microbatches: int = 2):
             params["blocks"], x, require_mesh(), pp_microbatches)
         aux = aux / cfg.n_layer
     else:
-        layer = jax.checkpoint(block) if cfg.remat else block
+        layer = checkpoint_layer(block) if cfg.remat else block
         auxes = []
         for i in range(cfg.n_layer):
             x, aux = layer(x, params[f"h_{i}"])

@@ -3,9 +3,11 @@ gated feed-forward, the gated short convolution, the chunked loss and the
 mixed-precision step.  A model file imports these, `ray_tpu.parallel.attention`
 and `ray_tpu.ops`; it imports no other model file.
 
-Imports jax and, of the runtime, only the job timeline's counters
-(`util/tracing.py`, which imports nothing heavy): a worker pays nothing for
-it before its first step.
+Imports jax, the names of the flash kernels' residuals
+(`ops/flash_attention.py`, which every model imports through
+`parallel/attention.py` anyway) and, of the runtime, only the job
+timeline's counters (`util/tracing.py`, which imports nothing heavy): a
+worker pays nothing for it before its first step.
 """
 
 from __future__ import annotations
@@ -13,6 +15,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.flash_attention import KEPT_RESIDUALS
 from ray_tpu.util import tracing
 
 
@@ -149,6 +152,29 @@ def short_conv(u, p):
         y = _gate_taps(bcz, taps)
     with jax.named_scope("out_proj"):
         return y @ p["out_proj"]["kernel"].astype(u.dtype)
+
+
+_save_named = jax.checkpoint_policies.save_only_these_names(*KEPT_RESIDUALS)
+
+
+def _keep_attention_residuals(prim, *avals, **params):
+    """The policy of `checkpoint_layer`: keep a value the flash kernels'
+    forward rules named, recompute everything else.  Counts each value it
+    keeps on the job timeline (`remat.residuals_kept`)."""
+    keep = _save_named(prim, *avals, **params)
+    if keep:
+        tracing.count("remat.residuals_kept")
+    return keep
+
+
+def checkpoint_layer(fn, **kw):
+    """`jax.checkpoint(fn, **kw)` for a model's layer, and the one owner of
+    what a recomputed layer keeps: its attention kernel's output and row
+    statistics (`ops/flash_attention.py:KEPT_RESIDUALS`, what the kernel's
+    backward reads besides q, k and v), so the backward pass recomputes the
+    layer's forward but for the kernel.  A layer with no attention call
+    finds nothing to keep and is a bare `jax.checkpoint`."""
+    return jax.checkpoint(fn, policy=_keep_attention_residuals, **kw)
 
 
 def chunked_xent(x, wte, targets, n_chunks: int):

@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.layers import (
+    checkpoint_layer,
     chunked_xent,
     num_params,  # noqa: F401  (`lfm2_moe.num_params` is public)
     rms_norm,
@@ -107,7 +108,9 @@ class Lfm2MoeConfig:
     rms_eps: float = 1e-5
     bias_update_speed: float = 0.001  # assumed: arXiv:2412.19437's gamma
     compute_dtype: Any = jnp.bfloat16
-    remat: bool = False               # jax.checkpoint each layer
+    # jax.checkpoint each layer, its attention kernel's output and row
+    # statistics kept (`layers.checkpoint_layer`)
+    remat: bool = False
     loss_chunk_rows: int = 2048       # `layers.chunked_xent`
 
     @property
@@ -259,7 +262,7 @@ def _layer(x, p, cfg: Lfm2MoeConfig):
 def _trunk(params, tokens, cfg: Lfm2MoeConfig):
     """-> ((B, S, E) after the final norm, the routers' statistics)."""
     x = params["embed_tokens"]["embedding"][tokens].astype(cfg.compute_dtype)
-    layer = jax.checkpoint(_layer, static_argnums=(2,)) if cfg.remat \
+    layer = checkpoint_layer(_layer, static_argnums=(2,)) if cfg.remat \
         else _layer
     rows = []
     for i in range(cfg.n_layer):

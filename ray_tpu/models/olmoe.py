@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.layers import (
+    checkpoint_layer,
     chunked_xent,
     num_params,  # noqa: F401  (`olmoe.num_params` is public)
     rms_norm,
@@ -60,7 +61,9 @@ class OlmoeConfig:
     aux_weight: float = 0.01      # load balancing (the paper's alpha)
     z_weight: float = 0.001       # router z-loss (the paper's beta)
     compute_dtype: Any = jnp.bfloat16
-    remat: bool = False           # jax.checkpoint each layer
+    # jax.checkpoint each layer, its attention kernel's output and row
+    # statistics kept (`layers.checkpoint_layer`)
+    remat: bool = False
     # rows of the head's logits alive at once (`layers.chunked_xent`): at
     # 16,384 x 50,304 the whole of them and their gradient are 6.6 GB
     loss_chunk_rows: int = 2048
@@ -182,7 +185,7 @@ def _trunk(params, tokens, cfg: OlmoeConfig):
     auxiliary losses averaged over the layers, the fullest expert of
     any)."""
     x = params["embed_tokens"]["embedding"][tokens].astype(cfg.compute_dtype)
-    layer = jax.checkpoint(_layer, static_argnums=(2,)) if cfg.remat \
+    layer = checkpoint_layer(_layer, static_argnums=(2,)) if cfg.remat \
         else _layer
     stats = []
     for i in range(cfg.n_layer):

@@ -55,6 +55,12 @@ slices every operand's heads out of the same lanes and declines such a
 call, which then takes the head-major kernels.  With ``H_kv == H`` every
 kernel is the one it was.
 
+The public forward rules name the two residuals the backward kernels read
+besides q, k and v, the output and the row statistics (`KEPT_RESIDUALS`):
+a checkpointed layer whose policy keeps them
+(`models/layers.py:checkpoint_layer`) recomputes its forward pass without
+running the forward kernel a second time.
+
 Each kernel adds its tiles and the heads it reads to the job timeline as
 the step is traced (`attention.tiles`, `attention.tiles_skipped`,
 `attention.q_heads`, `attention.kv_heads`: see `_count_tiles`).
@@ -71,6 +77,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -92,6 +99,15 @@ _WHOLE_SEQ_MAX = 1024
 # steps instead of treating the grid as a dependent loop nest.
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel"))
+
+
+# The two residuals the backward kernels read besides their recomputed
+# inputs, as the public forward rules name them (`_named_residuals`): the
+# kernel's output and its (B, H, S) row statistics.  A `jax.checkpoint`
+# whose policy keeps these names (`models/layers.py:checkpoint_layer`) does
+# not run the forward kernel again in the backward pass; anywhere else a
+# name is nothing.
+KEPT_RESIDUALS = ("flash_attention.o", "flash_attention.lse")
 
 
 # Mosaic's default limit of scoped VMEM on a v5e, and what one tile's
@@ -925,6 +941,45 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
     return o
 
 
+def _named_residuals(q, k, v, o, lse, rows=False):
+    """(o, the residuals) of a public forward rule, o and lse under
+    `KEPT_RESIDUALS`.  The rule's result IS the named o: a checkpointed
+    layer that keeps the names then needs nothing else of the kernel, and
+    its replay drops the call.  q, k and v stay unnamed (recomputed).
+
+    ``rows``: name lse as the head-major kernels write and read it,
+    (B*H, S, 1), whose rows fill a lane each on the chip: 128 times the
+    bytes of (B, H, S), and no pass over them to pack after the forward
+    kernel and unpack before the backward.  On XL (S = 1,024, 48 layers, 52
+    MB a layer in rows) those passes took back the 12.6 ms a step that the
+    kept forward saved: 37,558 tokens/s packed for the parent's 37,607,
+    38,648 in rows; at S = 8,192 they are a twentieth of a forward kernel
+    and the rows 268 MB a layer (PERF.md §6, PR 35)."""
+    o = checkpoint_name(o, KEPT_RESIDUALS[0])
+    if rows:
+        B, H, S = lse.shape
+        lse = checkpoint_name(lse.reshape(B * H, S, 1),
+                              KEPT_RESIDUALS[1]).reshape(B, H, S)
+    else:
+        lse = checkpoint_name(lse, KEPT_RESIDUALS[1])
+    return o, (q, k, v, o, lse)
+
+
+def _rows_kept(S):
+    """Whether a head-major call's lse is kept in the kernels' own layout
+    (`_named_residuals`): up to `_WHOLE_SEQ_MAX`, where it is at most half
+    a megabyte a (b, h) slice and the kernel too short to hide a pass."""
+    return S <= _WHOLE_SEQ_MAX
+
+
+def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
+    """`flash_attention`'s forward rule.  The names go on here and not in
+    `_flash_fwd`, which the ring calls once per rotating chunk: its
+    partials are no residuals of anything."""
+    _, res = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k)
+    return _named_residuals(*res, rows=_rows_kept(q.shape[2]))
+
+
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     S = q.shape[2]
     scale, whole, (bq, bk), _ = _resolve(q, S, causal, sm_scale, block_q,
@@ -974,7 +1029,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, do, delta=None):
     return _by_platform(kernel, reference, q, k, v, o, lse, do, delta)
 
 
-flash_attention.defvjp(_flash_fwd, _flash_bwd)
+flash_attention.defvjp(_flash_fwd_rule, _flash_bwd)
 
 
 def _bshd_lanes_ok(q, S, bq, bk):
@@ -1022,10 +1077,13 @@ def _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k):
                               causal=causal, block_q=bq, block_k=bk,
                               whole=whole),
             reference, q, k, v)
-        return o, (q, k, v, o, lse)
-    o, (_, _, _, ot, lse) = _flash_fwd(_tr(q), _tr(k), _tr(v), causal,
+        return _named_residuals(q, k, v, o, lse)
+    # of o's two layouts the one named is the caller's (B, S, H, D), which
+    # is also the rule's result; the backward transposes it to head-major
+    # as it always did
+    ot, (_, _, _, _, lse) = _flash_fwd(_tr(q), _tr(k), _tr(v), causal,
                                        sm_scale, block_q, block_k)
-    return _tr(o), (q, k, v, _tr(ot), lse)
+    return _named_residuals(q, k, v, _tr(ot), lse, rows=_rows_kept(S))
 
 
 def _flash_bwd_bshd(causal, sm_scale, block_q, block_k, res, do):

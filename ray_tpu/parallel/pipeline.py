@@ -35,9 +35,11 @@ single-program autodiff world the non-interleaved 1F1B schedule buys
 nothing over this: its bubble fraction is identical ((S-1)/(M+S-1) ticks
 each way — 1F1B's advantage over GPipe is PEAK MEMORY, bounding in-flight
 microbatches at S instead of M), and here the memory bound comes from the
-remat policy instead: ``jax.checkpoint`` around the stage body keeps the
-residual set to one activation per tick, so peak live activations per
-stage are O(M + S) microbatch-slices either way.  See
+remat policy instead: ``jax.checkpoint`` around the stage body
+(`models/layers.py:checkpoint_layer`) keeps the residual set to one
+activation per tick and a flash kernel's output and row statistics, so
+peak live activations per stage are O(M + S) microbatch-slices either way.
+See
 ``schedule_info()`` for the tick/bubble accounting the tests assert.
 """
 
@@ -48,6 +50,8 @@ from typing import Any, Callable, Dict
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
+
+from ray_tpu.models.layers import checkpoint_layer
 
 
 def stack_layer_params(layer_params: list):
@@ -110,7 +114,7 @@ def pipeline_apply(
 
         body = layer_step
         if remat:
-            body = jax.checkpoint(layer_step)
+            body = checkpoint_layer(layer_step)
         # the aux carry is pp-varying from the first layer (params differ
         # per stage) — mark the init accordingly
         aux0 = jax.lax.pcast(jnp.zeros((), jnp.float32), (axis,),

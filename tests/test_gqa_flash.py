@@ -160,21 +160,28 @@ def _normalised_jaxpr(f, *args):
 # attention at the accepted cells' shapes, taken on the parent of the PR
 # that brought grouped queries (ab23c31): (B, S, H, D), v's width, through
 # `parallel/attention.py:attention`.  A PR that changes the kernels on
-# purpose changes these with them.
+# purpose changes these with them.  The names the forward rules give o and
+# lse (`KEPT_RESIDUALS`, two identity equations a call) are taken out first.
+# Since PR 35 the transposing route (XL, kanana) transposes the kernel's o to
+# (B, S, H, D) once, for the result and the residual alike, where the parent
+# wrote the same transpose twice (XLA merged them): one equation fewer; and
+# up to `_WHOLE_SEQ_MAX` (XL) lse is named in the kernels' own (B*H, S, 1),
+# two reshapes that cancel.
 PARENT = {
     "gpt2-medium": ((16, 1024, 16, 64), None, False, "58052d16e0f69720"),
-    "gpt2-xl a chip": ((4, 1024, 25, 64), None, False, "8eb6286277376bc2"),
+    "gpt2-xl a chip": ((4, 1024, 25, 64), None, False, "46a839a5b15db2c7"),
     "olmoe": ((4, 4096, 16, 128), None, False, "fe169d92c65f1afc"),
-    "kanana": ((2, 8192, 32, 192), 128, True, "a6f85e1c2df21be4"),
+    "kanana": ((2, 8192, 32, 192), 128, True, "0309beacccb11756"),
     "lanes, long forward": ((2, 2048, 32, 64), None, False,
                             "d427a24dd632e38b"),
 }
 
 
 @pytest.mark.parametrize("name", sorted(PARENT))
-def test_equal_heads_lower_to_the_jaxpr_they_lowered_to(name):
+def test_equal_heads_lower_to_the_jaxpr_they_lowered_to(name, monkeypatch):
     """`H_kv == H`: every `pallas_call`, index map and shape is the
     parent's, so the five accepted cells run the parent's kernels."""
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
     shape, v_dim, entry, want = PARENT[name]
     B, S, heads, D = shape
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
