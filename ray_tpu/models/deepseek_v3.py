@@ -44,10 +44,10 @@ state that moves by a rule), `parallel/attention.py` (the flash kernels,
 here with q/k and v of two widths) and `ops/moe.py`; the names are those
 `parallel/sharding.py` lays out.
 
-`jax.named_scope`s: attention/latent_down, attention/latent_up,
-attention/kernel, moe/route, moe/dispatch, moe/experts, moe/shared,
-moe/combine, dense_mlp, head_and_loss, optimizer_update,
-routing_bias_update.
+`jax.named_scope`s (`models/layers.py:SCOPES`): embed, norm,
+attention/{latent_down,latent_up,kernel,out}, ffn/dense,
+ffn/moe/{route,dispatch,experts,combine,shared}, head_and_loss,
+optimizer_update, routing_bias_update.
 """
 
 from __future__ import annotations
@@ -210,7 +210,8 @@ def _attention(x, p, cfg: DeepseekV3Config):
         v = kv[..., nope:]
     with jax.named_scope("kernel"):
         o = attention(q, k, v)                          # (B, S, H, v_head_dim)
-    return o.reshape(B, S, H * cfg.v_head_dim) @ kernel("o_proj")
+    with jax.named_scope("out"):
+        return o.reshape(B, S, H * cfg.v_head_dim) @ kernel("o_proj")
 
 
 def _route(xt, router, cfg: DeepseekV3Config):
@@ -238,21 +239,24 @@ def _moe(x, p, cfg: DeepseekV3Config):
 
 def _layer(x, p, cfg: DeepseekV3Config):
     """-> (x, the rows sent to each expert; None from a dense layer)."""
+    u = rms_norm(x, p["input_norm"], cfg.rms_eps)
     with jax.named_scope("attention"):
-        x = x + _attention(rms_norm(x, p["input_norm"], cfg.rms_eps),
-                           p["attn"], cfg)
+        x = x + _attention(u, p["attn"], cfg)
     u = rms_norm(x, p["post_norm"], cfg.rms_eps)
-    if "mlp" in p:
-        with jax.named_scope("dense_mlp"):
-            return x + _mlp(u, p["mlp"]), None
-    with jax.named_scope("moe"):
-        y, rows = _moe(u, p["moe"], cfg)
+    with jax.named_scope("ffn"):
+        if "mlp" in p:
+            with jax.named_scope("dense"):
+                return x + _mlp(u, p["mlp"]), None
+        with jax.named_scope("moe"):
+            y, rows = _moe(u, p["moe"], cfg)
     return x + y, rows
 
 
 def _trunk(params, tokens, cfg: DeepseekV3Config):
     """-> ((B, S, E) after the final norm, the routers' statistics)."""
-    x = params["embed_tokens"]["embedding"][tokens].astype(cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        x = params["embed_tokens"]["embedding"][tokens].astype(
+            cfg.compute_dtype)
     layer = checkpoint_layer(_layer, static_argnums=(2,)) if cfg.remat \
         else _layer
     rows = []

@@ -18,6 +18,10 @@ pretraining").  Design choices for the MXU/HBM:
     the same rules pin them, so the batch stays cut and `fsdp` gathers
     each weight at its use; with no mesh, or one device, nothing is added.
   * static shapes everywhere; the whole train step jits to one XLA program.
+
+`jax.named_scope`s (`models/layers.py:SCOPES`): embed, norm,
+attention/{qkv,kernel,out}, ffn/dense, ffn/moe/{route,dispatch,experts,
+combine}, head_and_loss, optimizer_update.
 """
 
 from __future__ import annotations
@@ -120,13 +124,18 @@ def init_params(rng, cfg: GPT2Config) -> Dict[str, Any]:
 def _attention(x, p, cfg: GPT2Config):
     B, S, E = x.shape
     H, D = cfg.n_head, cfg.head_dim
-    qkv = x @ p["c_attn"]["kernel"].astype(x.dtype) + p["c_attn"]["bias"].astype(x.dtype)
-    qkv = constrain(qkv, "batch", "seq", "heads")
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    o = attention(q.reshape(B, S, H, D), k.reshape(B, S, H, D),
-                  v.reshape(B, S, H, D), variant=cfg.attention)
-    o = o.reshape(B, S, E)
-    return o @ p["c_proj"]["kernel"].astype(x.dtype) + p["c_proj"]["bias"].astype(x.dtype)
+    with jax.named_scope("qkv"):
+        qkv = (x @ p["c_attn"]["kernel"].astype(x.dtype)
+               + p["c_attn"]["bias"].astype(x.dtype))
+        qkv = constrain(qkv, "batch", "seq", "heads")
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+    with jax.named_scope("kernel"):
+        o = attention(q.reshape(B, S, H, D), k.reshape(B, S, H, D),
+                      v.reshape(B, S, H, D), variant=cfg.attention)
+    with jax.named_scope("out"):
+        o = o.reshape(B, S, E)
+        return (o @ p["c_proj"]["kernel"].astype(x.dtype)
+                + p["c_proj"]["bias"].astype(x.dtype))
 
 
 def _mlp(x, p):
@@ -144,11 +153,13 @@ def _moe_mlp(x, p, cfg: GPT2Config):
     (y, aux_load_balancing_loss)."""
     B, S, E = x.shape
     xt = x.reshape(B * S, E)
-    router_logits = (xt @ p["router"]["kernel"].astype(x.dtype)
-                     ).astype(jnp.float32)                      # (T, n_exp)
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, cfg.moe_top_k)   # (T, k)
-    gate_vals = gate_vals / (jnp.sum(gate_vals, -1, keepdims=True) + 1e-9)
+    with jax.named_scope("route"):
+        router_logits = (xt @ p["router"]["kernel"].astype(x.dtype)
+                         ).astype(jnp.float32)                  # (T, n_exp)
+        probs = jax.nn.softmax(router_logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, cfg.moe_top_k)  # (T, k)
+        gate_vals = gate_vals / (jnp.sum(gate_vals, -1, keepdims=True)
+                                 + 1e-9)
     wi, wo = p["wi"].astype(x.dtype), p["wo"].astype(x.dtype)
 
     def gelu_experts(xs, group_sizes):
@@ -157,11 +168,12 @@ def _moe_mlp(x, p, cfg: GPT2Config):
 
     y, _ = moe_dispatch(xt, gate_vals, gate_idx, cfg.moe_experts,
                         gelu_experts)
-    # load-balancing aux (Switch eq. 4): fraction routed x router prob
-    frac = jnp.mean(jax.nn.one_hot(gate_idx[:, 0], cfg.moe_experts,
-                                   dtype=jnp.float32), axis=0)
-    importance = jnp.mean(probs, axis=0)
-    aux = cfg.moe_experts * jnp.sum(frac * importance)
+    with jax.named_scope("route"):
+        # load-balancing aux (Switch eq. 4): fraction routed x router prob
+        frac = jnp.mean(jax.nn.one_hot(gate_idx[:, 0], cfg.moe_experts,
+                                       dtype=jnp.float32), axis=0)
+        importance = jnp.mean(probs, axis=0)
+        aux = cfg.moe_experts * jnp.sum(frac * importance)
     return y.reshape(B, S, E), aux
 
 
@@ -176,14 +188,18 @@ def _block(x, p, cfg: GPT2Config):
     # stated at the block's entry (and not at its exit), so that a
     # `jax.checkpoint` of it recomputes the forward pass under the same pins
     x = _residual(x)
-    x = _residual(x + _attention(_residual(layer_norm(x, p["ln_1"])),
-                                 p["attn"], cfg))
+    h = _residual(layer_norm(x, p["ln_1"]))
+    with jax.named_scope("attention"):
+        x = _residual(x + _attention(h, p["attn"], cfg))
     h = _residual(layer_norm(x, p["ln_2"]))
-    if "moe" in p:
-        y, aux = _moe_mlp(h, p["moe"], cfg)
-    else:
-        y, aux = _mlp(h, p["mlp"]), jnp.zeros((), jnp.float32)
-    return x + y, aux
+    with jax.named_scope("ffn"):
+        if "moe" in p:
+            with jax.named_scope("moe"):
+                y, aux = _moe_mlp(h, p["moe"], cfg)
+        else:
+            with jax.named_scope("dense"):
+                y, aux = _mlp(h, p["mlp"]), jnp.zeros((), jnp.float32)
+        return x + y, aux
 
 
 def to_pipeline_params(params, cfg: GPT2Config):
@@ -207,9 +223,10 @@ def _trunk(params, tokens, cfg: GPT2Config, pp_microbatches: int = 2):
     the mesh pp axis; MoE aux loss rides the stage handoff as a scalar
     carry lane (averaged over microbatches)."""
     S = tokens.shape[1]
-    x = (params["wte"]["embedding"][tokens]
-         + params["wpe"]["embedding"][:S][None])
-    x = _residual(x.astype(cfg.compute_dtype))
+    with jax.named_scope("embed"):
+        x = (params["wte"]["embedding"][tokens]
+             + params["wpe"]["embedding"][:S][None])
+        x = _residual(x.astype(cfg.compute_dtype))
 
     def block(h, p):
         return _block(h, p, cfg)
@@ -256,11 +273,13 @@ def loss_fn(params, batch, cfg: GPT2Config, pp_microbatches: int = 2):
     tokens = batch["tokens"]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     x, aux = _trunk(params, inputs, cfg, pp_microbatches)
-    wte = params["wte"]["embedding"].astype(cfg.compute_dtype)
-    logits = _logits(x, wte)
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    loss = jnp.mean(lse - tgt)
+    with jax.named_scope("head_and_loss"):
+        wte = params["wte"]["embedding"].astype(cfg.compute_dtype)
+        logits = _logits(x, wte)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        tgt = jnp.take_along_axis(logits, targets[..., None],
+                                  axis=-1)[..., 0]
+        loss = jnp.mean(lse - tgt)
     if cfg.moe_experts > 0:
         loss = loss + cfg.moe_aux_weight * aux
     return loss

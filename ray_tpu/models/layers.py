@@ -3,11 +3,25 @@ gated feed-forward, the gated short convolution, the chunked loss and the
 mixed-precision step.  A model file imports these, `ray_tpu.parallel.attention`
 and `ray_tpu.ops`; it imports no other model file.
 
-Imports jax, the names of the flash kernels' residuals
+Imports jax, the names of the flash kernels' residuals and forms
 (`ops/flash_attention.py`, which every model imports through
 `parallel/attention.py` anyway) and, of the runtime, only the job
 timeline's counters (`util/tracing.py`, which imports nothing heavy): a
 worker pays nothing for it before its first step.
+
+The scopes.  A scope is the device's span: a `jax.named_scope` puts its
+name into the `op_name` of every operation traced under it, the chip's
+profiler carries that into the trace (`tf_op`), and a reader
+(`benchmark/harness/scope_trace.py`) sums device time by it.  `SCOPES` is
+every path a Train-path model may use, and the one place a reader or a test
+takes them from.  A model writes plain `with jax.named_scope("attention"):`
+around the part; what the models share names itself (`layer_norm` and
+`rms_norm`: `norm`; `short_conv`'s three parts; `ops/moe.py`'s `dispatch`,
+`experts`, `combine` and `routing_bias_update`, which rely on the caller
+standing in `ffn/moe`; the flash kernels' forms; `train_step`'s
+`optimizer_update`).  `norm` is a layer's norm on the residual stream: one
+inside an operator (a norm over a head, the latent's) stands in that
+operator's scope and counts there.
 """
 
 from __future__ import annotations
@@ -15,24 +29,64 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.flash_attention import KEPT_RESIDUALS
+from ray_tpu.ops.flash_attention import KEPT_RESIDUALS, KERNEL_FORMS
 from ray_tpu.util import tracing
+
+SCOPES = (
+    "embed",
+    "norm",
+    "attention",
+    "attention/qkv",
+    "attention/latent_down",
+    "attention/latent_up",
+    "attention/kernel",
+    *(f"attention/kernel/{form}" for form in KERNEL_FORMS),
+    "attention/out",
+    "short_conv",
+    "short_conv/in_proj",
+    "short_conv/gate_taps",
+    "short_conv/out_proj",
+    "ffn",
+    "ffn/dense",
+    "ffn/moe",
+    "ffn/moe/route",
+    "ffn/moe/dispatch",
+    "ffn/moe/experts",
+    "ffn/moe/combine",
+    "ffn/moe/shared",
+    "head_and_loss",
+    "optimizer_update",
+    "routing_bias_update",
+)
+
+# What the compiler names itself: XLA:TPU replaces `jax.lax.ragged_dot` by
+# its own grouped-matmul kernel, whose `op_name` is the compiler's
+# (`ragged-dot-none`, and `ragged-dot-metadata` for the kernel that lays out
+# the groups) and no longer the caller's.  Every `ragged_dot` of the
+# Train-path models is the routed experts' (`tests/test_scopes.py` holds them
+# to it), so a reader puts an operation whose name starts so under that
+# scope; whether it ran forward or backward the name does not say.
+COMPILER_NAMED = (("ragged-dot", "ffn/moe/experts"),)
 
 
 def layer_norm(x, p, eps=1e-5):
     """Stats in f32 for stability; output CAST BACK to the input dtype —
     the f32 scale/bias would otherwise silently promote the residual
     stream (and every downstream matmul) to the MXU's slow f32 path."""
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
-    y = (xf - mu) * jax.lax.rsqrt(var + eps)
-    return (y * p["scale"] + p["bias"]).astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        mu = jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.var(xf, axis=-1, keepdims=True)
+        y = (xf - mu) * jax.lax.rsqrt(var + eps)
+        return (y * p["scale"] + p["bias"]).astype(x.dtype)
 
 
 def rms_norm(x, p, eps=1e-5):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) * p["scale"].astype(x.dtype)
+    with jax.named_scope("norm"):
+        var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
+                       keepdims=True)
+        return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) \
+            * p["scale"].astype(x.dtype)
 
 
 def rope(x, positions, theta, interleaved=False):

@@ -47,9 +47,10 @@ kernels, here with fewer key/value heads than query heads) and `ops/moe.py`
 (dispatch over a share of the experts, the sigmoid router and its bias
 rule); the names are those `parallel/sharding.py` lays out.
 
-`jax.named_scope`s: operator/short_conv/{in_proj,gate_taps,out_proj},
-operator/attention/{qkv,kernel,out}, ffn/dense, ffn/moe/{route,dispatch,
-experts,combine}, head_and_loss, optimizer_update, routing_bias_update.
+`jax.named_scope`s (`models/layers.py:SCOPES`): embed, norm,
+short_conv/{in_proj,gate_taps,out_proj}, attention/{qkv,kernel,out},
+ffn/dense, ffn/moe/{route,dispatch,experts,combine}, head_and_loss,
+optimizer_update, routing_bias_update.
 """
 
 from __future__ import annotations
@@ -240,13 +241,12 @@ def _moe(x, p, cfg: Lfm2MoeConfig):
 def _layer(x, p, cfg: Lfm2MoeConfig):
     """-> (x, the rows sent to each expert; None from a dense layer)."""
     u = rms_norm(x, p["operator_norm"], cfg.rms_eps)
-    with jax.named_scope("operator"):
-        if "short_conv" in p:
-            with jax.named_scope("short_conv"):
-                x = x + short_conv(u, p["short_conv"])
-        else:
-            with jax.named_scope("attention"):
-                x = x + _attention(u, p["attn"], cfg)
+    if "short_conv" in p:
+        with jax.named_scope("short_conv"):
+            x = x + short_conv(u, p["short_conv"])
+    else:
+        with jax.named_scope("attention"):
+            x = x + _attention(u, p["attn"], cfg)
     u = rms_norm(x, p["ffn_norm"], cfg.rms_eps)
     with jax.named_scope("ffn"):
         if "mlp" in p:
@@ -261,7 +261,9 @@ def _layer(x, p, cfg: Lfm2MoeConfig):
 
 def _trunk(params, tokens, cfg: Lfm2MoeConfig):
     """-> ((B, S, E) after the final norm, the routers' statistics)."""
-    x = params["embed_tokens"]["embedding"][tokens].astype(cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        x = params["embed_tokens"]["embedding"][tokens].astype(
+            cfg.compute_dtype)
     layer = checkpoint_layer(_layer, static_argnums=(2,)) if cfg.remat \
         else _layer
     rows = []

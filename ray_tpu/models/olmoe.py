@@ -21,8 +21,9 @@ run dropless (`ops/moe.py:moe_dispatch`): the (token,
 expert) rows are sorted by expert and each group is multiplied by its
 expert with `jax.lax.ragged_dot`, XLA:TPU's grouped-matmul kernel.
 
-`jax.named_scope`s name the parts: attention, route, dispatch, experts,
-combine, head_and_loss, optimizer_update.
+`jax.named_scope`s (`models/layers.py:SCOPES`): embed, norm,
+attention/{qkv,kernel,out}, ffn/moe/{route,dispatch,experts,combine},
+head_and_loss, optimizer_update.
 """
 
 from __future__ import annotations
@@ -126,15 +127,18 @@ def _attention(x, p, cfg: OlmoeConfig):
     B, S, E = x.shape
     H, D = cfg.n_head, cfg.head_dim
     kernel = lambda name: p[name]["kernel"].astype(x.dtype)
-    q = rms_norm(x @ kernel("q_proj"), p["q_norm"], cfg.rms_eps)
-    k = rms_norm(x @ kernel("k_proj"), p["k_norm"], cfg.rms_eps)
-    v = x @ kernel("v_proj")
-    positions = jnp.arange(S)
-    q = rope(q.reshape(B, S, H, D), positions, cfg.rope_theta)
-    k = rope(k.reshape(B, S, H, D), positions, cfg.rope_theta)
-    v = v.reshape(B, S, H, D)
-    o = attention(q, k, v)
-    return o.reshape(B, S, E) @ kernel("o_proj")
+    with jax.named_scope("qkv"):
+        q = rms_norm(x @ kernel("q_proj"), p["q_norm"], cfg.rms_eps)
+        k = rms_norm(x @ kernel("k_proj"), p["k_norm"], cfg.rms_eps)
+        v = x @ kernel("v_proj")
+        positions = jnp.arange(S)
+        q = rope(q.reshape(B, S, H, D), positions, cfg.rope_theta)
+        k = rope(k.reshape(B, S, H, D), positions, cfg.rope_theta)
+        v = v.reshape(B, S, H, D)
+    with jax.named_scope("kernel"):
+        o = attention(q, k, v)
+    with jax.named_scope("out"):
+        return o.reshape(B, S, E) @ kernel("o_proj")
 
 
 def _gated_experts(wi_gate, wi_up, wo):
@@ -171,12 +175,12 @@ def _moe(x, p, cfg: OlmoeConfig):
 
 
 def _layer(x, p, cfg: OlmoeConfig):
+    u = rms_norm(x, p["input_norm"], cfg.rms_eps)
     with jax.named_scope("attention"):
-        x = x + _attention(rms_norm(x, p["input_norm"], cfg.rms_eps),
-                           p["attn"], cfg)
-    with jax.named_scope("moe"):
-        y, stats = _moe(rms_norm(x, p["post_norm"], cfg.rms_eps),
-                        p["moe"], cfg)
+        x = x + _attention(u, p["attn"], cfg)
+    u = rms_norm(x, p["post_norm"], cfg.rms_eps)
+    with jax.named_scope("ffn"), jax.named_scope("moe"):
+        y, stats = _moe(u, p["moe"], cfg)
     return x + y, stats
 
 
@@ -184,7 +188,9 @@ def _trunk(params, tokens, cfg: OlmoeConfig):
     """-> ((B, S, E) after the final norm, the routers' statistics: the
     auxiliary losses averaged over the layers, the fullest expert of
     any)."""
-    x = params["embed_tokens"]["embedding"][tokens].astype(cfg.compute_dtype)
+    with jax.named_scope("embed"):
+        x = params["embed_tokens"]["embedding"][tokens].astype(
+            cfg.compute_dtype)
     layer = checkpoint_layer(_layer, static_argnums=(2,)) if cfg.remat \
         else _layer
     stats = []

@@ -109,6 +109,14 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
 # name is nothing.
 KEPT_RESIDUALS = ("flash_attention.o", "flash_attention.lse")
 
+# The `jax.named_scope` each of the six `pallas_call`s is made under: the
+# form of kernel, which an operation's `op_name` (and the chip's trace, as
+# `tf_op`) then says besides forward / backward.  The head-major forward (a
+# grid step a q tile, or the whole sequence) and the lane layout's; the
+# one-kernel backward of each layout; the split backward's two.
+KERNEL_FORMS = ("fwd_rows", "fwd_lanes", "bwd_fused", "bwd_fused_lanes",
+                "bwd_dq", "bwd_dkv")
+
 
 # Mosaic's default limit of scoped VMEM on a v5e, and what one tile's
 # temporaries (s, p, dp, ds of 1024 x 1024 scores) may take of it.
@@ -517,7 +525,7 @@ def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, whole,
         block_q=block_q, block_k=block_k, seq_len=S, whole=whole,
     )
     qspec = pl.BlockSpec((None, rows, D), lambda g, i: (g, i, 0))
-    o, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[qspec,
@@ -531,7 +539,9 @@ def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, whole,
         ],
         interpret=interpret,
         compiler_params=_compiler_params(S, D, Dv, q.dtype),
-    )(qf, kf, vf)
+    )
+    with jax.named_scope("fwd_rows"):
+        o, lse = call(qf, kf, vf)
     return o.reshape(B, H, S, Dv), lse.reshape(B, H, S)
 
 
@@ -575,7 +585,7 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
         # one kernel, tiled inside: shares s/dp across dq/dk/dv.
         qk, vo = spec(S, D, whole_seq), spec(S, Dv, whole_seq)
         row = spec(S, 1, whole_seq)
-        dq, dk, dv = pl.pallas_call(
+        call = pl.pallas_call(
             functools.partial(_bwd_fused_kernel, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
                               block_k=block_k, seq_len=S),
@@ -587,9 +597,11 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
             scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
             interpret=interpret,
             compiler_params=params,
-        )(qf, kf, vf, dof, lsef, delta)
+        )
+        with jax.named_scope("bwd_fused"):
+            dq, dk, dv = call(qf, kf, vf, dof, lsef, delta)
     else:
-        dq = pl.pallas_call(
+        call = pl.pallas_call(
             functools.partial(
                 _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                 block_q=block_q, block_k=block_k, seq_len=S,
@@ -602,8 +614,10 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
             out_shape=out_shape[0],
             interpret=interpret,
             compiler_params=params,
-        )(qf, kf, vf, dof, lsef, delta)
-        dk, dv = pl.pallas_call(
+        )
+        with jax.named_scope("bwd_dq"):
+            dq = call(qf, kf, vf, dof, lsef, delta)
+        call = pl.pallas_call(
             functools.partial(
                 _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                 block_q=block_q, block_k=block_k, seq_len=S,
@@ -616,7 +630,9 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
             out_shape=out_shape[1:],
             interpret=interpret,
             compiler_params=params,
-        )(qf, kf, vf, dof, lsef, delta)
+        )
+        with jax.named_scope("bwd_dkv"):
+            dk, dv = call(qf, kf, vf, dof, lsef, delta)
 
     dq = dq.reshape(B, H, S, D)
     if group == 1:
@@ -692,7 +708,7 @@ def _pallas_forward_bshd(q, k, v, sm_scale, causal, block_q, block_k,
     )
     qspec = pl.BlockSpec((None, rows, W), lambda g, i: (g // G, i, g % G))
     kvspec = pl.BlockSpec((None, S, W), lambda g, i: (g // G, 0, g % G))
-    o, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[qspec, kvspec, kvspec],
@@ -708,7 +724,9 @@ def _pallas_forward_bshd(q, k, v, sm_scale, causal, block_q, block_k,
         # `_WHOLE_SEQ_MAX` they can outgrow the default (S = 8,192 wants
         # 18.3 of 16 MB); every shape a cell runs keeps the default
         compiler_params=_compiler_params(S, W, W, q.dtype),
-    )(qf, kf, vf)
+    )
+    with jax.named_scope("fwd_lanes"):
+        o, lse = call(qf, kf, vf)
     # lse (B*G, S, hpb) -> (B, H, S): group-major heads, tiny tensor.
     lse = lse.reshape(B, G, S, hpb).transpose(0, 1, 3, 2).reshape(B, H, S)
     return o.reshape(B, S, H, D), lse
@@ -736,7 +754,7 @@ def _pallas_backward_bshd(q, k, v, o, lse, do, sm_scale, causal, block_q,
 
     spec = pl.BlockSpec((None, S, W), lambda g, i: (g // G, 0, g % G))
     row = pl.BlockSpec((None, S, hpb), lambda g, i: (g, 0, 0))
-    dq, dk, dv = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_bwd_fused_kernel_lanes, sm_scale=sm_scale,
                           causal=causal, heads_per_block=hpb, head_dim=D,
                           block_q=block_q, block_k=block_k, seq_len=S),
@@ -749,7 +767,9 @@ def _pallas_backward_bshd(q, k, v, o, lse, do, sm_scale, causal, block_q,
         scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
-    )(qf, kf, vf, dof, lsef, delta)
+    )
+    with jax.named_scope("bwd_fused_lanes"):
+        dq, dk, dv = call(qf, kf, vf, dof, lsef, delta)
     return (dq.reshape(B, S, H, D), dk.reshape(B, S, H, D),
             dv.reshape(B, S, H, D))
 

@@ -1,0 +1,221 @@
+"""The scope vocabulary (`models/layers.py:SCOPES`) on every Train-path
+model: in the lowered step's text with debug info, every matmul, grouped
+matmul, Mosaic kernel and convolution has an `op_name` under a scope of the
+vocabulary, no other scope appears, `rematted_computation` appears exactly
+when `remat` is on, and the flash kernels' six forms are told apart.
+
+The step is lowered for the platform `tpu` (no chip needed: the Mosaic
+kernels become `tpu_custom_call`s at lowering time), so the text holds what
+the chip's program holds.  jax lowers a jitted function called inside the
+step (`_pallas_forward`, the loss's chunk) once, as a private function
+whose operations carry names relative to it; XLA's inliner puts the call's
+name in front, and `full_names` here does the same.  Names are read as the
+benchmark's reader reads a trace's `tf_op` (`scope_trace.scope_of`).
+"""
+
+import dataclasses
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from benchmark.harness import scope_trace
+from ray_tpu.models import deepseek_v3, gpt2, layers, lfm2_moe, olmoe
+from ray_tpu.ops import flash_attention as fa
+from ray_tpu.ops.moe import trained_by
+
+MODELS = {
+    "gpt2": (gpt2, gpt2.GPT2_TINY),
+    "gpt2_moe": (gpt2, dataclasses.replace(gpt2.GPT2_TINY, moe_experts=4)),
+    "olmoe": (olmoe, olmoe.OLMOE_TINY),
+    "deepseek_v3": (deepseek_v3, deepseek_v3.DEEPSEEK_V3_TINY),
+    "lfm2_moe": (lfm2_moe, lfm2_moe.LFM2_MOE_TINY),
+}
+CASES = [(name, remat) for name in MODELS for remat in (False, True)]
+# what every model's step must have a matmul under
+EXPECTED = {
+    "gpt2": {"attention/qkv", "attention/out", "ffn/dense",
+             "head_and_loss", "attention/kernel/fwd_rows",
+             "attention/kernel/bwd_fused"},
+    "gpt2_moe": {"attention/qkv", "attention/out", "ffn/moe/route",
+                 "ffn/moe/experts", "head_and_loss"},
+    "olmoe": {"attention/qkv", "attention/out", "ffn/moe/route",
+              "ffn/moe/experts", "head_and_loss"},
+    "deepseek_v3": {"attention/latent_down", "attention/latent_up",
+                    "attention/out", "ffn/dense", "ffn/moe/route",
+                    "ffn/moe/experts", "ffn/moe/shared", "head_and_loss"},
+    "lfm2_moe": {"short_conv/in_proj", "short_conv/out_proj",
+                 "attention/qkv", "attention/out", "ffn/dense",
+                 "ffn/moe/route", "ffn/moe/experts", "head_and_loss"},
+}
+# components of an `op_name` that jax puts there itself
+JAX_WRAPPERS = re.compile(
+    r"^(checkpoint|rematted_computation|shard_map|cond|branch_\d+_fun|"
+    r"while|body|closed_call|custom_vjp_call|custom_jvp_call|pjit)$")
+OPS = re.compile(r"stablehlo\.dot_general|chlo\.ragged_dot|"
+                 r"stablehlo\.convolution|"
+                 r"stablehlo\.custom_call @tpu_custom_call")
+LOC_DEF = re.compile(r'^(#loc\d+) = loc\("([^"]*)"\(', re.M)
+LOC_USE = re.compile(r"loc\((#loc\d+)\)\s*$")
+FUNC = re.compile(r"^\s*func\.func (?:public|private) @([\w.]+)\(")
+CALL = re.compile(r"\bcall @([\w.]+)\(")
+
+
+@functools.lru_cache(maxsize=None)
+def lowered_text(name: str, remat: bool) -> str:
+    module, cfg = MODELS[name]
+    cfg = dataclasses.replace(cfg, remat=remat)
+    optimizer = optax.adamw(1e-4)
+    if module in (deepseek_v3, lfm2_moe):
+        optimizer = trained_by(optimizer)
+    step = module.make_train_step(cfg, optimizer)
+    params = jax.eval_shape(lambda key: module.init_params(key, cfg),
+                            jax.random.PRNGKey(0))
+    opt_state = jax.eval_shape(optimizer.init, params)
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 65), jnp.int32)}
+    traced = jax.jit(step).trace(params, opt_state, batch)
+    return traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+def full_names(text: str, wanted=OPS) -> list:
+    """[(what matched ``wanted``, the operation's `op_name` as XLA's
+    inliner will make it: every chain of call sites that reaches its
+    function, in front of its own)]."""
+    names = dict(LOC_DEF.findall(text))
+    calls, found, function = {}, [], None      # callee -> [(caller, name)]
+    for line in text.splitlines():
+        start = FUNC.match(line)
+        if start:
+            function = start[1]
+            continue
+        used = LOC_USE.search(line)
+        name = names.get(used[1]) if used else None
+        called = CALL.search(line)
+        if called:
+            calls.setdefault(called[1], []).append((function, name))
+        op = wanted.search(line)
+        if op:
+            found.append((op[0], function, name))
+
+    def prefixes(function, seen=()):
+        if function == "main":
+            return [""]
+        return [f"{front}/{site}" if front else site
+                for caller, site in calls.get(function, ())
+                if caller not in seen and site is not None
+                for front in prefixes(caller, seen + (function,))]
+
+    return [(op, f"{front}/{name}" if front else name)
+            for op, function, name in found
+            for front in (prefixes(function) if name is not None else [])]
+
+
+def scope(name: str) -> str:
+    return scope_trace.scope_of(name, tuple(layers.SCOPES))
+
+
+def test_the_vocabulary_is_one_tuple_of_paths():
+    assert len(set(layers.SCOPES)) == len(layers.SCOPES)
+    for path in layers.SCOPES:
+        # a scope's parent is a scope
+        parent = path.rpartition("/")[0]
+        assert not parent or parent in layers.SCOPES, path
+    for form in fa.KERNEL_FORMS:
+        assert f"attention/kernel/{form}" in layers.SCOPES
+
+
+@pytest.mark.parametrize("name,remat", CASES)
+def test_every_matmul_and_kernel_is_under_a_scope(name, remat):
+    found = full_names(lowered_text(name, remat))
+    assert found, "the lowered text holds no matmul?"
+    kinds = {op for op, _ in found}
+    assert "stablehlo.dot_general" in kinds
+    assert "stablehlo.custom_call @tpu_custom_call" in kinds
+    bare = [(op, full) for op, full in found if not scope(full)]
+    assert not bare, bare[:5]
+    scopes = {scope(full) for _, full in found}
+    assert EXPECTED[name] <= scopes, EXPECTED[name] - scopes
+    # XLA:TPU renames the grouped matmuls, and `COMPILER_NAMED` says they
+    # are the experts': so every one of them must be
+    (renamed, experts), = layers.COMPILER_NAMED
+    assert {scope(full) for op, full in found
+            if op == "chlo.ragged_dot"} <= {experts}
+    assert scope_trace.scope_of(f"{renamed}-none:", tuple(layers.SCOPES),
+                                layers.COMPILER_NAMED) == experts
+    # the optimizer's and the norms' operations are named too
+    every = {scope(full) for _, full in full_names(
+        lowered_text(name, remat), re.compile(r"stablehlo\.\w+"))}
+    assert {"optimizer_update", "norm", "embed"} <= every
+    if name in ("deepseek_v3", "lfm2_moe"):
+        assert "routing_bias_update" in every
+    if name == "lfm2_moe":
+        assert "short_conv/gate_taps" in every
+
+
+@pytest.mark.parametrize("name,remat", CASES)
+def test_no_scope_outside_the_vocabulary(name, remat):
+    parts = {part for path in layers.SCOPES for part in path.split("/")}
+    text = lowered_text(name, remat)
+    for _, full in full_names(text, re.compile(r"stablehlo\.\w+|chlo\.\w+")):
+        inside = []
+        for part in scope_trace.components(full):
+            if JAX_WRAPPERS.match(part):
+                continue
+            assert part in parts, (part, full)
+            inside.append(part)
+        # and in the vocabulary's order: what is left IS a scope's path
+        # (a norm inside an operator stands in the operator's scope)
+        if len(inside) > 1 and inside[-1] == "norm":
+            inside.pop()
+        assert "/".join(inside) in layers.SCOPES + ("",), full
+
+
+@pytest.mark.parametrize("name,remat", CASES)
+def test_rematted_computation_exactly_under_remat(name, remat):
+    """A recomputed layer's operations say so, which is what the reader's
+    `remat_fwd` phase goes by; the chunked loss recomputes its logits
+    whatever `remat` says, inside `head_and_loss`."""
+    found = full_names(lowered_text(name, remat),
+                       re.compile(r"stablehlo\.dot_general"))
+    replayed = {scope(full) for _, full in found
+                if scope_trace.phase_of(full) == "remat_fwd"}
+    layer_scopes = {s for s in replayed if s != "head_and_loss"}
+    if remat:
+        assert layer_scopes >= {"attention/out"}, replayed
+    else:
+        assert not layer_scopes, replayed
+    phases = {scope_trace.phase_of(full) for _, full in found}
+    assert {"fwd", "bwd"} <= phases
+
+
+def _kernel_names(fn, *shapes, dtype=jnp.bfloat16):
+    args = [jax.ShapeDtypeStruct(shape, dtype) for shape in shapes]
+
+    def loss(*xs):
+        with jax.named_scope("attention"), jax.named_scope("kernel"):
+            return jnp.sum(fn(*xs).astype(jnp.float32))
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    text = grad.trace(*args).lower(lowering_platforms=("tpu",)).as_text(
+        debug_info=True)
+    return sorted(scope(full) for _, full in full_names(
+        text, re.compile(r"@tpu_custom_call")))
+
+
+@pytest.mark.parametrize("fn,shape,forms", [
+    # head-major, a grid step the whole sequence: the one-kernel backward
+    (fa.flash_attention, (1, 2, 256, 64), ("fwd_rows", "bwd_fused")),
+    # head-major past `_WHOLE_SEQ_MAX`: the split backward
+    (fa.flash_attention, (1, 2, 2048, 64),
+     ("fwd_rows", "bwd_dq", "bwd_dkv")),
+    # (B, S, H, D) with two heads to a lane block: the lane layout
+    (fa.flash_attention_bshd, (1, 256, 4, 64),
+     ("fwd_lanes", "bwd_fused_lanes")),
+])
+def test_the_six_kernel_forms_are_told_apart(fn, shape, forms):
+    causal = functools.partial(fn, causal=True)
+    assert _kernel_names(causal, shape, shape, shape) == sorted(
+        f"attention/kernel/{form}" for form in forms)
