@@ -62,6 +62,7 @@ import jax.numpy as jnp
 from ray_tpu.models.layers import (
     checkpoint_layer,
     chunked_xent,
+    named,
     num_params,  # noqa: F401  (`deepseek_v3.num_params` is public)
     rms_norm,
     rope,
@@ -101,8 +102,11 @@ class DeepseekV3Config:
     rms_eps: float = 1e-6
     bias_update_speed: float = 0.001  # gamma of arXiv:2412.19437
     compute_dtype: Any = jnp.bfloat16
-    # jax.checkpoint each layer, its attention kernel's output and row
-    # statistics kept (`layers.checkpoint_layer`)
+    # jax.checkpoint each layer, keeping its attention kernel's output and
+    # row statistics and, of `layers.KEPT_NAMES` (here the routers' products,
+    # choices and sorted order, the latent, W_o's result, q, the gates and
+    # ups of the dense and shared feed-forwards, k and v), those the chip
+    # has room for over all layers (`layers.checkpoint_layer`)
     remat: bool = False
     loss_chunk_rows: int = 2048       # `layers.chunked_xent`
 
@@ -197,21 +201,23 @@ def _attention(x, p, cfg: DeepseekV3Config):
                              theta=cfg.rope_theta, interleaved=True)
     with jax.named_scope("latent_down"):
         q = (x @ kernel("q_proj")).reshape(B, S, H, cfg.qk_head_dim)
-        latent = x @ kernel("kv_a_proj")
+        latent = named(x @ kernel("kv_a_proj"), "attention/latent_down")
         c = rms_norm(latent[..., :R], p["kv_a_norm"], cfg.rms_eps)
         k_rope = turn(latent[..., None, R:])            # (B, S, 1, rope)
     with jax.named_scope("latent_up"):
         kv = (c @ kernel("kv_b_proj")).reshape(
             B, S, H, nope + cfg.v_head_dim)
-        q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
-        k = jnp.concatenate([
+        q = named(jnp.concatenate(
+            [q[..., :nope], turn(q[..., nope:])], axis=-1), "attention/qkv")
+        k, v = named((jnp.concatenate([
             kv[..., :nope],
-            jnp.broadcast_to(k_rope, (B, S, H, cfg.qk_rope_dim))], axis=-1)
-        v = kv[..., nope:]
+            jnp.broadcast_to(k_rope, (B, S, H, cfg.qk_rope_dim))], axis=-1),
+            kv[..., nope:]), "attention/latent_up")
     with jax.named_scope("kernel"):
         o = attention(q, k, v)                          # (B, S, H, v_head_dim)
     with jax.named_scope("out"):
-        return o.reshape(B, S, H * cfg.v_head_dim) @ kernel("o_proj")
+        return named(o.reshape(B, S, H * cfg.v_head_dim) @ kernel("o_proj"),
+                     "attention/out")
 
 
 def _route(xt, router, cfg: DeepseekV3Config):
@@ -257,11 +263,14 @@ def _trunk(params, tokens, cfg: DeepseekV3Config):
     with jax.named_scope("embed"):
         x = params["embed_tokens"]["embedding"][tokens].astype(
             cfg.compute_dtype)
-    layer = checkpoint_layer(_layer, static_argnums=(2,)) if cfg.remat \
-        else _layer
+    layers = [params[f"layer_{i}"] for i in range(cfg.n_layer)]
+    layer = checkpoint_layer(
+        _layer, stack=[(x, p, cfg) for p in layers], static_argnums=(2,),
+        behind=jax.ShapeDtypeStruct((cfg.loss_chunk_rows, cfg.vocab_size),
+                                    jnp.float32)) if cfg.remat else _layer
     rows = []
-    for i in range(cfg.n_layer):
-        x, sent = layer(x, params[f"layer_{i}"], cfg)
+    for p in layers:
+        x, sent = layer(x, p, cfg)
         if sent is not None:
             rows.append(sent)
     rows = jnp.stack(rows)                       # (routed layers, N)

@@ -33,8 +33,13 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.layers import checkpoint_layer, layer_norm, train_step
-from ray_tpu.ops.moe import moe_dispatch
+from ray_tpu.models.layers import (
+    checkpoint_layer,
+    layer_norm,
+    named,
+    train_step,
+)
+from ray_tpu.ops.moe import ROUTE_NAME, moe_dispatch
 from ray_tpu.parallel.attention import attention
 from ray_tpu.parallel.sharding import constrain
 
@@ -48,8 +53,10 @@ class GPT2Config:
     n_embd: int = 768
     compute_dtype: Any = jnp.bfloat16
     attention: str = "flash"  # flash | ring | ulysses | dense
-    # jax.checkpoint each block, its attention kernel's output and row
-    # statistics kept (`layers.checkpoint_layer`: trade FLOPs for HBM)
+    # jax.checkpoint each block (trade FLOPs for HBM), keeping its attention
+    # kernel's output and row statistics and, of `layers.KEPT_NAMES` (here
+    # c_attn's, the attention c_proj's and c_fc's results), those the chip
+    # has room for over all blocks (`layers.checkpoint_layer`)
     remat: bool = False
     # MoE (expert parallelism, SURVEY §2.6 row "EP"): >0 swaps every
     # block's dense FFN for a top-k routed mixture; expert weights carry a
@@ -127,20 +134,20 @@ def _attention(x, p, cfg: GPT2Config):
     with jax.named_scope("qkv"):
         qkv = (x @ p["c_attn"]["kernel"].astype(x.dtype)
                + p["c_attn"]["bias"].astype(x.dtype))
-        qkv = constrain(qkv, "batch", "seq", "heads")
+        qkv = named(constrain(qkv, "batch", "seq", "heads"), "attention/qkv")
         q, k, v = jnp.split(qkv, 3, axis=-1)
     with jax.named_scope("kernel"):
         o = attention(q.reshape(B, S, H, D), k.reshape(B, S, H, D),
                       v.reshape(B, S, H, D), variant=cfg.attention)
     with jax.named_scope("out"):
         o = o.reshape(B, S, E)
-        return (o @ p["c_proj"]["kernel"].astype(x.dtype)
-                + p["c_proj"]["bias"].astype(x.dtype))
+        return named(o @ p["c_proj"]["kernel"].astype(x.dtype)
+                     + p["c_proj"]["bias"].astype(x.dtype), "attention/out")
 
 
 def _mlp(x, p):
     h = x @ p["c_fc"]["kernel"].astype(x.dtype) + p["c_fc"]["bias"].astype(x.dtype)
-    h = jax.nn.gelu(constrain(h, "batch", "seq", "mlp"))
+    h = jax.nn.gelu(named(constrain(h, "batch", "seq", "mlp"), "ffn/hidden"))
     return h @ p["c_proj"]["kernel"].astype(x.dtype) + p["c_proj"]["bias"].astype(x.dtype)
 
 
@@ -154,8 +161,10 @@ def _moe_mlp(x, p, cfg: GPT2Config):
     B, S, E = x.shape
     xt = x.reshape(B * S, E)
     with jax.named_scope("route"):
-        router_logits = (xt @ p["router"]["kernel"].astype(x.dtype)
-                         ).astype(jnp.float32)                  # (T, n_exp)
+        # the logits: a softmax's backward reads its own result
+        router_logits = named(
+            (xt @ p["router"]["kernel"].astype(x.dtype)
+             ).astype(jnp.float32), ROUTE_NAME)                 # (T, n_exp)
         probs = jax.nn.softmax(router_logits, axis=-1)
         gate_vals, gate_idx = jax.lax.top_k(probs, cfg.moe_top_k)  # (T, k)
         gate_vals = gate_vals / (jnp.sum(gate_vals, -1, keepdims=True)
@@ -242,10 +251,14 @@ def _trunk(params, tokens, cfg: GPT2Config, pp_microbatches: int = 2):
             params["blocks"], x, require_mesh(), pp_microbatches)
         aux = aux / cfg.n_layer
     else:
-        layer = checkpoint_layer(block) if cfg.remat else block
+        blocks = [params[f"h_{i}"] for i in range(cfg.n_layer)]
+        layer = checkpoint_layer(
+            block, stack=[(x, p) for p in blocks],
+            behind=jax.ShapeDtypeStruct((*tokens.shape, cfg.vocab_size),
+                                        jnp.float32)) if cfg.remat else block
         auxes = []
-        for i in range(cfg.n_layer):
-            x, aux = layer(x, params[f"h_{i}"])
+        for p in blocks:
+            x, aux = layer(x, p)
             auxes.append(aux)
         aux = sum(auxes) / len(auxes)
     x = layer_norm(_residual(x.astype(jnp.float32)), params["ln_f"])

@@ -26,10 +26,18 @@ operator's scope and counts there.
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import threading
+
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.flash_attention import KEPT_RESIDUALS, KERNEL_FORMS
+from ray_tpu.ops.moe import ROUTE_NAME
+from ray_tpu.parallel.context import get_mesh
+from ray_tpu.parallel.sharding import chip_bytes, param_logical_dims
 from ray_tpu.util import tracing
 
 SCOPES = (
@@ -114,8 +122,10 @@ def swiglu(x, gate, up, down, matmul=jnp.matmul):
     """down(silu(gate(x)) * up(x)), no bias: the gated feed-forward of a
     dense layer, a shared expert (``matmul`` a plain product) and routed
     experts (a grouped one over rows sorted by expert, the weights one
-    stack an expert)."""
-    return matmul(jax.nn.silu(matmul(x, gate)) * matmul(x, up), down)
+    stack an expert).  The gate's and up's results are marked for a
+    recomputed layer (`KEPT_NAMES`)."""
+    gate, up = named((matmul(x, gate), matmul(x, up)), "ffn/hidden")
+    return matmul(jax.nn.silu(gate) * up, down)
 
 
 def _back(x, k):
@@ -201,34 +211,259 @@ def short_conv(u, p):
     tracing.count("shortconv.layers")
     tracing.count("shortconv.taps", taps.shape[1])
     with jax.named_scope("in_proj"):
-        bcz = u @ p["in_proj"]["kernel"].astype(u.dtype)
+        bcz = named(u @ p["in_proj"]["kernel"].astype(u.dtype),
+                    "short_conv/in_proj")
     with jax.named_scope("gate_taps"):
-        y = _gate_taps(bcz, taps)
+        y = named(_gate_taps(bcz, taps), "short_conv/gate_taps")
     with jax.named_scope("out_proj"):
-        return y @ p["out_proj"]["kernel"].astype(u.dtype)
+        return named(y @ p["out_proj"]["kernel"].astype(u.dtype),
+                     "short_conv/out_proj")
 
 
-_save_named = jax.checkpoint_policies.save_only_these_names(*KEPT_RESIDUALS)
+# What a recomputed layer may keep besides its attention kernel's residuals:
+# results of plain matmuls that the backward pass reads, each marked where it
+# is made (`named`).  The mark goes on the product itself where an operation
+# behind it reads its own result in the backward pass (a norm, a sigmoid, a
+# softmax): kept, that result would still be made again.  The words are
+# `SCOPES`' where one fits, so a trace and a kept name read alike.  The ORDER
+# is the policy's and the same for every model: by the milliseconds a replay
+# spends remaking a value per byte it takes to hold, as the chip's traces
+# gave them (PERF.md §6, PR 37).
+KEPT_NAMES = (
+    ROUTE_NAME,                 # a router's product (T, N), the experts it
+                                # chose (T, k) and the rows' order by expert
+    "attention/latent_down",    # DeepSeek-V3's [c | k_r], W_kv_a's result
+    "attention/out",            # W_o's result, as wide as the stream
+    "short_conv/out_proj",      # W_out's result, the same
+    "short_conv/gate_taps",     # c * conv(b * z), the same
+    "attention/qkv",            # GPT-2's fused qkv; W_q's, W_k's and W_v's
+                                # results; DeepSeek-V3's q with its RoPE part
+    "short_conv/in_proj",       # [b c z], W_in's result, 3E wide
+    "ffn/hidden",               # c_fc's result (4E); a SwiGLU's gate and up
+    "attention/latent_up",      # k and v multiplied out of the latent: the
+                                # widest and the cheapest to remake
+)
+
+# Of the device's memory limit, the share the budget never spends: the
+# program's own code, the batch, what the allocator loses between buffers.
+_HEADROOM = 0.05
+# One layer's live backward pass, in units of that layer's input and marked
+# values (the matmul results and kernel residuals, which are what a replay
+# writes out whole; the glue between them the compiler fuses away): the
+# replay's values, their head-major and float32 copies and the cotangents in
+# flight beside them.  And what runs behind the stack with every kept value
+# alive (the head's logits), in units of its bytes: the value and its
+# gradient.  Sized with the no-chip compile (`tools/aot_collectives.py`;
+# PERF.md §6, PR 37), to err towards keeping less.
+_LIVE_LAYERS = 2.5
+_LIVE_BEHIND = 2.0
+
+_told = threading.local()
 
 
-def _keep_attention_residuals(prim, *avals, **params):
-    """The policy of `checkpoint_layer`: keep a value the flash kernels'
-    forward rules named, recompute everything else.  Counts each value it
-    keeps on the job timeline (`remat.residuals_kept`)."""
-    keep = _save_named(prim, *avals, **params)
-    if keep:
-        tracing.count("remat.residuals_kept")
-    return keep
+@contextlib.contextmanager
+def _telling(**what):
+    """Bind what a caller further out knows and a stack of layers cannot
+    see: ``state_bytes`` (`train_step`), ``memory_limit``
+    (`assume_memory_limit`)."""
+    before = dict(vars(_told))
+    vars(_told).update(what)
+    try:
+        yield
+    finally:
+        vars(_told).clear()
+        vars(_told).update(before)
 
 
-def checkpoint_layer(fn, **kw):
+def assume_memory_limit(limit, plans=None):
+    """Context manager: take ``limit`` bytes for the device's memory limit
+    whatever the device states (a described device of the no-chip compile
+    states none: `tools/aot_collectives.py`; the CPU of a test neither).
+    ``plans``: a list that gets the `keep_plan` of each stack traced
+    inside."""
+    return _telling(memory_limit=limit, plans=plans)
+
+
+def _memory_limit():
+    """`memory_stats()["bytes_limit"]` of the first device the step is
+    traced for; None where the device states none."""
+    told = getattr(_told, "memory_limit", None)
+    if told is not None:
+        return told
+    mesh = get_mesh()
+    device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    try:
+        return (device.memory_stats() or {}).get("bytes_limit")
+    except jax.errors.JaxRuntimeError:  # a described device has no runtime
+        return None
+
+
+def named(x, name):
+    """``x`` (an array or a pytree of them) marked as a value a recomputed
+    layer may keep, under ``name`` of `KEPT_NAMES`.  Outside a
+    `checkpoint_layer` the mark is nothing: no instruction, no copy."""
+    if name not in KEPT_NAMES:
+        raise ValueError(f"{name!r} is no name of KEPT_NAMES")
+    return jax.tree.map(lambda v: checkpoint_name(v, name), x)
+
+
+def _activation_bytes(tree, tiled=False):
+    """Bytes one chip holds of a layer's values (arrays or avals): their
+    leading dim is the batch's (or batch x sequence, flat), cut as
+    `parallel/sharding.py` cuts a batch, the rest whole; ``tiled`` as
+    `chip_bytes` says.  A value inside a `shard_map` (a kernel's under a
+    mesh: `parallel/attention.py`) is one chip's share already."""
+    def one(leaf):
+        mesh = getattr(getattr(leaf, "sharding", None), "mesh", None)
+        dims = () if getattr(mesh, "manual_axes", ()) else ("batch",)
+        return chip_bytes(leaf.shape, leaf.dtype, *dims, tiled=tiled)
+    return sum(one(leaf) for leaf in jax.tree.leaves(tree)
+               if hasattr(leaf, "shape"))
+
+
+def state_bytes(params, opt_state, compute_dtype) -> int:
+    """Bytes of the training state on one chip while a step runs: the
+    parameters cut as `parallel/sharding.py` lays them out by their names,
+    their gradients beside them, the optimizer's state cut as the
+    parameters are on average, and the matrices' copy in the compute type
+    (`cast_weights`)."""
+    whole = held = cast = 0
+    for leaf, dims in param_logical_dims(params)[1]:
+        here = chip_bytes(leaf.shape, leaf.dtype, *dims)
+        whole += chip_bytes(leaf.shape, leaf.dtype)
+        held += here
+        if leaf.ndim >= 2 and leaf.dtype == jnp.float32:
+            cast += here * jnp.dtype(compute_dtype).itemsize // 4
+    moments = sum(chip_bytes(leaf.shape, leaf.dtype)
+                  for leaf in jax.tree.leaves(opt_state))
+    return 2 * held + cast + moments * held // max(whole, 1)
+
+
+# true of `checkpoint_name`'s primitive alone: the public way to tell it
+_is_name = jax.checkpoint_policies.save_any_names_but_these()
+
+
+def _layer_marks(fn, args, static_argnums):
+    """{name: bytes one chip holds of the values ONE call `fn(*args)` marks
+    with it}, `KEPT_RESIDUALS` among them, from an abstract trace of the
+    layer under the gradient; nothing runs and nothing counts on the job
+    timeline."""
+    static = {i: args[i] for i in static_argnums}
+    avals = [jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                          a) for i, a in enumerate(args) if i not in static]
+
+    def call(*dynamic):     # a function of its own: no trace cache shared
+        dynamic = iter(dynamic)
+        return fn(*(static[i] if i in static else next(dynamic)
+                    for i in range(len(args))))
+
+    marked = collections.Counter()
+
+    def record(prim, *in_avals, **params):
+        if _is_name(prim, *in_avals, **params):
+            # a kernel's result keeps the kernel's layout
+            marked[params["name"]] += _activation_bytes(
+                in_avals[0], tiled=params["name"] in KEPT_RESIDUALS)
+        return False
+
+    with tracing.outside_job():
+        jax.eval_shape(lambda *a: jax.vjp(
+            jax.checkpoint(call, policy=record), *a)[0], *avals)
+    return marked
+
+
+def keep_plan(fn, calls, static_argnums=(), behind=(), room=None):
+    """What a stack of recomputed layers keeps.  ``calls``: the arguments
+    of each call of ``fn``, the first argument the residual stream;
+    ``behind``: what the caller makes right behind the stack.
+    -> {"names": the names of `KEPT_NAMES` kept, in its order;
+    "bytes_kept": what they hold on one chip over the whole stack;
+    "declined": the marked names that did not fit; "room": the budget;
+    "already": what the stack keeps whatever the budget (each layer's
+    input, `KEPT_RESIDUALS`); "state": the training state as `train_step`
+    told it; "reserve"; "marked": {name: bytes over the stack}}.
+
+    Greedy over `KEPT_NAMES`: a name is kept when all its values, every
+    layer counted, fit what is left of the room; one that does not is
+    skipped whole and the next is tried.  The room (``room`` given: that)
+    is the device's memory limit less `_HEADROOM`, the training state on
+    one chip, what the stack keeps already and a reserve: `_LIVE_LAYERS`
+    times the heaviest layer's input and marked values, or `_LIVE_BEHIND`
+    times ``behind`` if that is more.  A device that states no limit,
+    or a step whose state nobody told (`train_step` does), has no room:
+    the stack keeps `KEPT_RESIDUALS` alone."""
+    marked, already, heaviest = collections.Counter(), 0, 0
+    marks = {}
+    for args in calls:
+        dynamic = [a for i, a in enumerate(args) if i not in static_argnums]
+        key = (tuple(args[i] for i in static_argnums),
+               jax.tree.structure(dynamic),
+               tuple((x.shape, x.dtype) for x in jax.tree.leaves(dynamic)))
+        if key not in marks:
+            marks[key] = _layer_marks(fn, args, static_argnums)
+        marked.update(marks[key])
+        stream = _activation_bytes(args[0])
+        already += stream
+        heaviest = max(heaviest, stream + sum(marks[key].values()))
+    already += sum(marked.pop(name, 0) for name in KEPT_RESIDUALS)
+    reserve = int(max(_LIVE_LAYERS * heaviest,
+                      _LIVE_BEHIND * _activation_bytes(behind)))
+    state = getattr(_told, "state_bytes", None)
+    if room is None:
+        limit = _memory_limit()
+        room = 0 if limit is None or state is None else \
+            int(limit * (1 - _HEADROOM)) - state - already - reserve
+    plan = {"names": (), "bytes_kept": 0, "declined": (), "room": room,
+            "state": state, "already": already, "reserve": reserve,
+            "marked": dict(marked)}
+    for name in KEPT_NAMES:
+        need = marked.get(name, 0)
+        if need and plan["bytes_kept"] + need <= room:
+            plan["names"] += (name,)
+            plan["bytes_kept"] += need
+        elif need:
+            plan["declined"] += (name,)
+    return plan
+
+
+def _keep(names):
+    """The policy of `checkpoint_layer`: keep a value marked with one of
+    ``names``, recompute everything else.  Counts each value it keeps on
+    the job timeline (`remat.residuals_kept`), once per traced layer."""
+    save = jax.checkpoint_policies.save_only_these_names(*names)
+
+    def policy(prim, *avals, **params):
+        keep = save(prim, *avals, **params)
+        if keep:
+            tracing.count("remat.residuals_kept")
+        return keep
+    return policy
+
+
+def checkpoint_layer(fn, stack=None, behind=(), **kw):
     """`jax.checkpoint(fn, **kw)` for a model's layer, and the one owner of
-    what a recomputed layer keeps: its attention kernel's output and row
-    statistics (`ops/flash_attention.py:KEPT_RESIDUALS`, what the kernel's
-    backward reads besides q, k and v), so the backward pass recomputes the
-    layer's forward but for the kernel.  A layer with no attention call
-    finds nothing to keep and is a bare `jax.checkpoint`."""
-    return jax.checkpoint(fn, policy=_keep_attention_residuals, **kw)
+    what a recomputed layer keeps.  Always its attention kernel's output
+    and row statistics (`ops/flash_attention.py:KEPT_RESIDUALS`, what the
+    kernel's backward reads besides q, k and v).  With ``stack``, the
+    arguments of every call the caller is about to make (one tuple a
+    layer), also the values the layers mark with `KEPT_NAMES`, most
+    valuable first, as far as the chip has room for them over the whole
+    stack (`keep_plan`): the backward pass then recomputes the cheap glue
+    between kept matmul results and no more.  ``behind``: arrays or avals
+    of what the caller makes right behind the stack, with every kept value
+    alive (the head's logits, or a chunk of them).  The plan is made here, once
+    per traced stack, and counted on the job timeline: `remat.bytes_kept`
+    (one chip, all layers) and `remat.names_declined`.  A layer that marks
+    nothing is a bare `jax.checkpoint`."""
+    names = KEPT_RESIDUALS
+    if stack is not None:
+        plan = keep_plan(fn, stack, kw.get("static_argnums", ()), behind)
+        names += plan["names"]
+        tracing.count("remat.bytes_kept", plan["bytes_kept"])
+        tracing.count("remat.names_declined", len(plan["declined"]))
+        if getattr(_told, "plans", None) is not None:
+            _told.plans.append(plan)
+    return jax.checkpoint(fn, policy=_keep(names), **kw)
 
 
 def chunked_xent(x, wte, targets, n_chunks: int):
@@ -300,8 +535,11 @@ def train_step(objective, optimizer, compute_dtype, rule=None):
         def cast_objective(p):
             return objective(cast_weights(p, compute_dtype), batch)
 
-        (_, out), grads = jax.value_and_grad(cast_objective,
-                                             has_aux=True)(params)
+        # what a recomputed stack inside cannot see and its budget needs
+        with _telling(state_bytes=state_bytes(params, opt_state,
+                                              compute_dtype)):
+            (_, out), grads = jax.value_and_grad(cast_objective,
+                                                 has_aux=True)(params)
         with jax.named_scope("optimizer_update"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = jax.tree.map(lambda p, u: p + u, params, updates)

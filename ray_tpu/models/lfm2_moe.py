@@ -64,6 +64,7 @@ import jax.numpy as jnp
 from ray_tpu.models.layers import (
     checkpoint_layer,
     chunked_xent,
+    named,
     num_params,  # noqa: F401  (`lfm2_moe.num_params` is public)
     rms_norm,
     rope,
@@ -109,8 +110,12 @@ class Lfm2MoeConfig:
     rms_eps: float = 1e-5
     bias_update_speed: float = 0.001  # assumed: arXiv:2412.19437's gamma
     compute_dtype: Any = jnp.bfloat16
-    # jax.checkpoint each layer, its attention kernel's output and row
-    # statistics kept (`layers.checkpoint_layer`)
+    # jax.checkpoint each layer, keeping its attention kernel's output and
+    # row statistics and, of `layers.KEPT_NAMES` (here the routers' products,
+    # choices and sorted order, a convolution's [b c z], gates-and-taps and
+    # W_out results, W_q's, W_k's, W_v's and W_o's results, the dense gate
+    # and up), those the chip has room for over all layers
+    # (`layers.checkpoint_layer`)
     remat: bool = False
     loss_chunk_rows: int = 2048       # `layers.chunked_xent`
 
@@ -206,9 +211,11 @@ def _attention(x, p, cfg: Lfm2MoeConfig):
     H, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     kernel = lambda name: p[name]["kernel"].astype(x.dtype)
     with jax.named_scope("qkv"):
-        q = (x @ kernel("q_proj")).reshape(B, S, H, D)
-        k = (x @ kernel("k_proj")).reshape(B, S, Hkv, D)
-        v = (x @ kernel("v_proj")).reshape(B, S, Hkv, D)
+        # the products, before the norms: a norm's backward reads them
+        q, k, v = named(((x @ kernel("q_proj")).reshape(B, S, H, D),
+                         (x @ kernel("k_proj")).reshape(B, S, Hkv, D),
+                         (x @ kernel("v_proj")).reshape(B, S, Hkv, D)),
+                        "attention/qkv")
         positions = jnp.arange(S)
         q = rope(rms_norm(q, p["q_norm"], cfg.rms_eps), positions,
                  cfg.rope_theta)
@@ -217,7 +224,8 @@ def _attention(x, p, cfg: Lfm2MoeConfig):
     with jax.named_scope("kernel"):
         o = attention(q, k, v)        # 8 key/value heads go in as they are
     with jax.named_scope("out"):
-        return o.reshape(B, S, H * D) @ kernel("o_proj")
+        return named(o.reshape(B, S, H * D) @ kernel("o_proj"),
+                     "attention/out")
 
 
 def _moe(x, p, cfg: Lfm2MoeConfig):
@@ -264,11 +272,14 @@ def _trunk(params, tokens, cfg: Lfm2MoeConfig):
     with jax.named_scope("embed"):
         x = params["embed_tokens"]["embedding"][tokens].astype(
             cfg.compute_dtype)
-    layer = checkpoint_layer(_layer, static_argnums=(2,)) if cfg.remat \
-        else _layer
+    layers = [params[f"layer_{i}"] for i in range(cfg.n_layer)]
+    layer = checkpoint_layer(
+        _layer, stack=[(x, p, cfg) for p in layers], static_argnums=(2,),
+        behind=jax.ShapeDtypeStruct((cfg.loss_chunk_rows, cfg.vocab_size),
+                                    jnp.float32)) if cfg.remat else _layer
     rows = []
-    for i in range(cfg.n_layer):
-        x, sent = layer(x, params[f"layer_{i}"], cfg)
+    for p in layers:
+        x, sent = layer(x, p, cfg)
         if sent is not None:
             rows.append(sent)
     rows = jnp.stack(rows)                       # (routed layers, N)

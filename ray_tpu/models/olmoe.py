@@ -38,12 +38,13 @@ import jax.numpy as jnp
 from ray_tpu.models.layers import (
     checkpoint_layer,
     chunked_xent,
+    named,
     num_params,  # noqa: F401  (`olmoe.num_params` is public)
     rms_norm,
     rope,
     train_step,
 )
-from ray_tpu.ops.moe import moe_dispatch
+from ray_tpu.ops.moe import ROUTE_NAME, moe_dispatch
 from ray_tpu.parallel.attention import attention
 
 
@@ -62,8 +63,11 @@ class OlmoeConfig:
     aux_weight: float = 0.01      # load balancing (the paper's alpha)
     z_weight: float = 0.001       # router z-loss (the paper's beta)
     compute_dtype: Any = jnp.bfloat16
-    # jax.checkpoint each layer, its attention kernel's output and row
-    # statistics kept (`layers.checkpoint_layer`)
+    # jax.checkpoint each layer, keeping its attention kernel's output and
+    # row statistics and, of `layers.KEPT_NAMES` (here the router's logits
+    # and the rows' order by expert, W_q's, W_k's, W_v's and W_o's results,
+    # the experts' gate and up), those the chip has room for over all layers
+    # (`layers.checkpoint_layer`)
     remat: bool = False
     # rows of the head's logits alive at once (`layers.chunked_xent`): at
     # 16,384 x 50,304 the whole of them and their gradient are 6.6 GB
@@ -128,9 +132,11 @@ def _attention(x, p, cfg: OlmoeConfig):
     H, D = cfg.n_head, cfg.head_dim
     kernel = lambda name: p[name]["kernel"].astype(x.dtype)
     with jax.named_scope("qkv"):
-        q = rms_norm(x @ kernel("q_proj"), p["q_norm"], cfg.rms_eps)
-        k = rms_norm(x @ kernel("k_proj"), p["k_norm"], cfg.rms_eps)
-        v = x @ kernel("v_proj")
+        # the products, before the norms: a norm's backward reads them
+        q, k, v = named((x @ kernel("q_proj"), x @ kernel("k_proj"),
+                         x @ kernel("v_proj")), "attention/qkv")
+        q = rms_norm(q, p["q_norm"], cfg.rms_eps)
+        k = rms_norm(k, p["k_norm"], cfg.rms_eps)
         positions = jnp.arange(S)
         q = rope(q.reshape(B, S, H, D), positions, cfg.rope_theta)
         k = rope(k.reshape(B, S, H, D), positions, cfg.rope_theta)
@@ -138,15 +144,16 @@ def _attention(x, p, cfg: OlmoeConfig):
     with jax.named_scope("kernel"):
         o = attention(q, k, v)
     with jax.named_scope("out"):
-        return o.reshape(B, S, E) @ kernel("o_proj")
+        return named(o.reshape(B, S, E) @ kernel("o_proj"), "attention/out")
 
 
 def _gated_experts(wi_gate, wi_up, wo):
     """The experts' SiLU-gated feed-forward over rows sorted by expert:
     three grouped matmuls over the ragged groups."""
     def run(xs, group_sizes):
-        gate = jax.lax.ragged_dot(xs, wi_gate, group_sizes)
-        up = jax.lax.ragged_dot(xs, wi_up, group_sizes)
+        gate, up = named((jax.lax.ragged_dot(xs, wi_gate, group_sizes),
+                          jax.lax.ragged_dot(xs, wi_up, group_sizes)),
+                         "ffn/hidden")
         return jax.lax.ragged_dot(jax.nn.silu(gate) * up, wo, group_sizes)
     return run
 
@@ -157,8 +164,10 @@ def _moe(x, p, cfg: OlmoeConfig):
     B, S, E = x.shape
     xt = x.reshape(B * S, E)
     with jax.named_scope("route"):
-        logits = (xt @ p["router"]["kernel"].astype(x.dtype)
-                  ).astype(jnp.float32)                       # (T, N)
+        # the logits: the z-loss reads them, and a softmax's and a top-k's
+        # backward read their own results, which a replay makes from these
+        logits = named((xt @ p["router"]["kernel"].astype(x.dtype)
+                        ).astype(jnp.float32), ROUTE_NAME)    # (T, N)
         probs = jax.nn.softmax(logits, axis=-1)
         weights, experts = jax.lax.top_k(probs, cfg.top_k)    # (T, k)
     y, group_sizes = moe_dispatch(
@@ -191,11 +200,14 @@ def _trunk(params, tokens, cfg: OlmoeConfig):
     with jax.named_scope("embed"):
         x = params["embed_tokens"]["embedding"][tokens].astype(
             cfg.compute_dtype)
-    layer = checkpoint_layer(_layer, static_argnums=(2,)) if cfg.remat \
-        else _layer
+    layers = [params[f"layer_{i}"] for i in range(cfg.n_layer)]
+    layer = checkpoint_layer(
+        _layer, stack=[(x, p, cfg) for p in layers], static_argnums=(2,),
+        behind=jax.ShapeDtypeStruct((cfg.loss_chunk_rows, cfg.vocab_size),
+                                    jnp.float32)) if cfg.remat else _layer
     stats = []
-    for i in range(cfg.n_layer):
-        x, s = layer(x, params[f"layer_{i}"], cfg)
+    for p in layers:
+        x, s = layer(x, p, cfg)
         stats.append(s)
     mean = lambda key: sum(s[key] for s in stats) / len(stats)
     return rms_norm(x, params["norm_f"], cfg.rms_eps), {

@@ -28,8 +28,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.util import tracing
+
+# The name a router's product, its choices and their order by expert carry
+# for a recomputed layer (`models/layers.py:KEPT_NAMES`, the first of them):
+# kilobytes a token, and with them kept a replay runs no router and no sort.
+# A softmax router in a model file marks its logits with the same word.
+ROUTE_NAME = "ffn/moe/route"
 
 # A share's buffer over the rows a balanced router sends it.  Twice: a
 # router at its initialisation, or one a bias rule or an auxiliary loss
@@ -298,7 +305,11 @@ def moe_dispatch(x, weights, experts, n_experts, run_experts, held=None):
     tracing.count("moe.rows_routed", T * k)
     tracing.count("moe.rows_buffered", C)
     with jax.named_scope("dispatch"):
-        by_expert, group_sizes = _sort_by_expert(experts, n_experts, held)
+        # the sorts' results are the router's choices in another order:
+        # kept with them, a replay sorts nothing
+        by_expert, group_sizes = jax.tree.map(
+            lambda v: checkpoint_name(v, ROUTE_NAME),
+            _sort_by_expert(experts, n_experts, held))
     over = _over_all_rows if C == T * k \
         else functools.partial(_over_either, C)
     return over(x, weights, by_expert, group_sizes, held,
@@ -317,14 +328,18 @@ def sigmoid_route(xt, router, top_k, eps, scale):
     has one (it picks and does not weigh, and nothing differentiates
     through it); weights s at the chosen, over their sum + ``eps`` unless
     ``eps`` is None (weights not renormalised), times ``scale``."""
-    scores = jax.nn.sigmoid(jnp.matmul(
+    # the product and not its sigmoid, whose backward reads its own result
+    scores = jax.nn.sigmoid(checkpoint_name(jnp.matmul(
         xt, router["kernel"].astype(xt.dtype),
-        preferred_element_type=jnp.float32))                  # (T, N)
+        preferred_element_type=jnp.float32), ROUTE_NAME))     # (T, N)
     picks = scores
     if ROUTING_BIAS in router:
         picks = scores + jax.lax.stop_gradient(router[ROUTING_BIAS])
     _, experts = jax.lax.top_k(picks, top_k)
-    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    experts = checkpoint_name(experts, ROUTE_NAME)
+    # and the scores at the chosen: (T, k), and a gather a replay would run
+    weights = checkpoint_name(
+        jnp.take_along_axis(scores, experts, axis=-1), ROUTE_NAME)
     if eps is not None:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return weights * scale, experts

@@ -140,6 +140,33 @@ def dividing_spec(mesh, logical_dims, shape) -> P:
     return P(*entries)
 
 
+def chip_bytes(shape, dtype, *logical_dims, mesh=None, tiled=False) -> int:
+    """Bytes ONE chip holds of an array of ``shape`` stated as
+    ``logical_dims`` (fewer than its dims: the rest are whole) on ``mesh``
+    (None: the one `use_mesh` bound; none bound: one chip holds it all):
+    each dim over the axes `dividing_spec` cuts it on.  ``tiled``: laid
+    out in the order of its dims as the chip tiles an array, the last dim
+    in whole lanes of 128 and the one before it in whole sublanes (8 rows
+    of 32 bits, 16 of 16): what a Mosaic kernel's operand or result takes
+    (a (rows, 1) float32 column 128 times its data: PERF.md §6, PR 35).
+    An array the compiler makes itself it lays out in whatever order of
+    dims pads least, and that counts as its data."""
+    mesh = mesh or get_mesh()
+    shape = list(shape)
+    if mesh is not None and mesh.size > 1:
+        dims = tuple(logical_dims) + (None,) * (len(shape) - len(logical_dims))
+        for i, axes in enumerate(dividing_spec(mesh, dims, shape)):
+            axes = axes if isinstance(axes, tuple) else (axes,)
+            shape[i] //= math.prod(mesh.shape[a] for a in axes if a)
+    width = jax.numpy.dtype(dtype).itemsize
+    if tiled and shape:
+        shape[-1] = -(-shape[-1] // 128) * 128
+    if tiled and len(shape) > 1:
+        sublanes = 8 * max(1, 4 // width)
+        shape[-2] = -(-shape[-2] // sublanes) * sublanes
+    return math.prod(shape) * width
+
+
 def constrain(x, *logical_dims):
     """Pin an activation to where `DEFAULT_RULES` put its logical dims on
     the mesh `use_mesh` bound: `with_sharding_constraint` inside the traced
@@ -213,25 +240,26 @@ def infer_param_logical_dims(path: Tuple[str, ...], shape: Tuple[int, ...]):
     return tuple([None] * nd)
 
 
-def shard_params(params, config: ShardingConfig, mesh: Mesh):
-    """Device-put a param pytree according to inferred logical dims."""
+def param_logical_dims(params):
+    """(the treedef of a param pytree, [(leaf, its logical dims by its
+    path's names)])."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
-    out = []
-    for path, leaf in flat:
-        keys = tuple(getattr(k, "key", getattr(k, "idx", str(k))) for k in path)
-        dims = infer_param_logical_dims(keys, getattr(leaf, "shape", ()))
-        sh = config.named_sharding(mesh, *dims) if dims else NamedSharding(mesh, P())
-        out.append(jax.device_put(leaf, sh))
-    return jax.tree_util.tree_unflatten(treedef, out)
+    return treedef, [
+        (leaf, infer_param_logical_dims(
+            tuple(getattr(k, "key", getattr(k, "idx", str(k))) for k in path),
+            getattr(leaf, "shape", ())))
+        for path, leaf in flat]
 
 
 def param_shardings(params, config: ShardingConfig, mesh: Mesh):
     """NamedSharding pytree (for jit in_shardings/out_shardings)."""
-    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
-    out = []
-    for path, leaf in flat:
-        keys = tuple(getattr(k, "key", getattr(k, "idx", str(k))) for k in path)
-        dims = infer_param_logical_dims(keys, getattr(leaf, "shape", ()))
-        out.append(config.named_sharding(mesh, *dims) if dims
-                   else NamedSharding(mesh, P()))
-    return jax.tree_util.tree_unflatten(treedef, out)
+    treedef, leaves = param_logical_dims(params)
+    return jax.tree_util.tree_unflatten(treedef, [
+        config.named_sharding(mesh, *dims) if dims
+        else NamedSharding(mesh, P()) for _, dims in leaves])
+
+
+def shard_params(params, config: ShardingConfig, mesh: Mesh):
+    """Device-put a param pytree according to inferred logical dims."""
+    return jax.tree.map(jax.device_put, params,
+                        param_shardings(params, config, mesh))

@@ -55,6 +55,7 @@ below).
 
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import json
 import os
@@ -664,6 +665,18 @@ class adopt:
     def __exit__(self, *exc):
         _current.reset(self._token)
         return False
+
+
+@contextlib.contextmanager
+def outside_job():
+    """Nothing inside belongs to the active job: its `count`s add nothing
+    and its spans are no timeline spans (an abstract trace of code that
+    counts itself as the real trace will)."""
+    token = _current.set(None)
+    try:
+        yield
+    finally:
+        _current.reset(token)
 
 
 def count(name: str, n: float = 1):

@@ -163,8 +163,14 @@ def state_shapes(family):
     return params, opt
 
 
-def compile_step(config: dict, traffic: dict):
-    """The configuration's step compiled for the described chips."""
+# what a v5e states as `memory_stats()["bytes_limit"]`: 15.75 GiB
+V5E_LIMIT_GIB = 15.75
+
+
+def compile_step(config: dict, traffic: dict, limit_gib=V5E_LIMIT_GIB):
+    """(the configuration's step compiled for the described chips, the
+    `keep_plan` of each recomputed stack it traced).  A described device
+    states no memory limit, so ``limit_gib`` stands for it."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
     import jax
     import jax.numpy as jnp
@@ -181,7 +187,12 @@ def compile_step(config: dict, traffic: dict):
     tokens = jax.ShapeDtypeStruct(
         (traffic["batch"], traffic["seq"] + 1), jnp.int32,
         sharding=family.layout.named_sharding(family.mesh, "batch", None))
-    return family.lower_step(params, opt, {"tokens": tokens}).compile()
+    from ray_tpu.models.layers import assume_memory_limit
+
+    plans = []
+    with assume_memory_limit(int(limit_gib * 2 ** 30), plans):
+        lowered = family.lower_step(params, opt, {"tokens": tokens})
+    return lowered.compile(), plans
 
 
 def main():
@@ -196,6 +207,11 @@ def main():
                              "largest arrays they make")
     parser.add_argument("--hlo", default=None,
                         help="write the compiled module's text here")
+    parser.add_argument("--memory-limit", type=float, default=V5E_LIMIT_GIB,
+                        help="the device's memory limit in GiB, which a "
+                             "recomputed stack's budget starts from "
+                             "(default: the v5e's; 0: a device that states "
+                             "none)")
     args = parser.parse_args()
 
     from benchmark.harness import registry
@@ -207,7 +223,8 @@ def main():
     if args.layers:
         depth = "n_layer" if "n_layer" in config else "num_hidden_layers"
         config[depth] = args.layers
-    compiled = compile_step(config, registry.traffic(traffic))
+    compiled, plans = compile_step(config, registry.traffic(traffic),
+                                   args.memory_limit)
     text = compiled.as_text()
     if args.hlo:
         with open(args.hlo, "w") as f:
@@ -219,6 +236,16 @@ def main():
           f"{memory.temp_size_in_bytes / gib:.3f} GiB, output "
           f"{memory.output_size_in_bytes / gib:.3f} GiB (aliased "
           f"{memory.alias_size_in_bytes / gib:.3f})")
+    for plan in plans:
+        size = lambda name: f"{name} {plan['marked'][name] / gib:.3f}"
+        print(f"recomputed stack, per chip: keeps "
+              f"{plan['bytes_kept'] / gib:.3f} GiB of names "
+              f"({', '.join(map(size, plan['names'])) or 'none'}); declined "
+              f"{', '.join(map(size, plan['declined'])) or 'none'}; room "
+              f"{plan['room'] / gib:.3f} GiB after {plan['already'] / gib:.3f}"
+              f" kept whatever the room, {plan['reserve'] / gib:.3f} of "
+              f"reserve and {(plan['state'] or 0) / gib:.3f} of training "
+              f"state")
     found = collectives(text)
     for (kind, shape), (count, size) in sorted(
             found.items(), key=lambda kv: -kv[1][0] * kv[1][1]):
