@@ -1,6 +1,7 @@
 """What the Train-path models share: the norms, rotary positions, the
-gated feed-forward, the gated short convolution, the chunked loss and the
-mixed-precision step.  A model file imports these, `ray_tpu.parallel.attention`
+gated feed-forward, the gated short convolution, the causal convolution and
+gated norm of a state-space mixer, the chunked loss and the mixed-precision
+step.  A model file imports these, `ray_tpu.parallel.attention`
 and `ray_tpu.ops`; it imports no other model file.
 
 Imports jax, the names of the flash kernels' residuals and forms
@@ -54,6 +55,12 @@ SCOPES = (
     "short_conv/in_proj",
     "short_conv/gate_taps",
     "short_conv/out_proj",
+    "ssm",
+    "ssm/in_proj",
+    "ssm/conv",
+    "ssm/scan",
+    "ssm/gate_norm",
+    "ssm/out_proj",
     "ffn",
     "ffn/dense",
     "ffn/moe",
@@ -220,6 +227,31 @@ def short_conv(u, p):
                      "short_conv/out_proj")
 
 
+def causal_conv(v, p, activation=None):
+    """A causal depthwise convolution with a bias, v (B, S, C) in the
+    (B, S, C) layout: out_t = activation(sum_j w_j * v_{t-(K-1)+j} + b), w
+    ``p["kernel"]`` (C, K), one filter a channel, b ``p["bias"]`` (C,),
+    zeros before the sequence starts.  K shifted multiply-adds in float32,
+    `short_conv`'s taps without its gates; jax differentiates it."""
+    out = _taps(p["kernel"], lambda k: _f32(_back(v, k))) \
+        + p["bias"].astype(jnp.float32)
+    if activation is not None:
+        out = activation(out)
+    return out.astype(v.dtype)
+
+
+def gated_rms_norm(y, z, p, groups, eps=1e-5):
+    """Mamba-2's `MambaRMSNormGated`, y and z (..., C): the gate first,
+    y * silu(z), THEN an RMSNorm over each of the ``groups`` runs of
+    C / groups channels, times the gain ``p["scale"]`` (C,).  Statistics
+    in float32; the result in y's type."""
+    g = _f32(y) * jax.nn.silu(_f32(z))
+    parts = g.reshape(*g.shape[:-1], groups, g.shape[-1] // groups)
+    parts = parts * jax.lax.rsqrt(
+        jnp.mean(jnp.square(parts), axis=-1, keepdims=True) + eps)
+    return (parts.reshape(g.shape) * p["scale"]).astype(y.dtype)
+
+
 # What a recomputed layer may keep besides its attention kernel's residuals:
 # results of plain matmuls that the backward pass reads, each marked where it
 # is made (`named`).  The mark goes on the product itself where an operation
@@ -239,7 +271,12 @@ KEPT_NAMES = (
     "attention/qkv",            # GPT-2's fused qkv; W_q's, W_k's and W_v's
                                 # results; DeepSeek-V3's q with its RoPE part
     "short_conv/in_proj",       # [b c z], W_in's result, 3E wide
-    "ffn/hidden",               # c_fc's result (4E); a SwiGLU's gate and up
+    "ssm/in_proj",              # [z | xBC | dt], W_in's result, 3.8E wide
+    "ffn/hidden",               # c_fc's result (4E); a SwiGLU's gate and up;
+                                # an ungated expert's up
+    "ssm/scan",                 # the scan's y: kept, a replay runs the two
+                                # products its backward reads and not the two
+                                # that only make y (PERF.md §6, PR 38)
     "attention/latent_up",      # k and v multiplied out of the latent: the
                                 # widest and the cheapest to remake
 )

@@ -205,8 +205,9 @@ def infer_param_logical_dims(path: Tuple[str, ...], shape: Tuple[int, ...]):
         return ()
     if "router" in name:
         return ("embed", None)[:nd]
-    if "short_conv/conv" in name:
-        # a depthwise filter a channel, (E, taps): the taps are never cut
+    if "short_conv/conv" in name or "mamba/conv" in name:
+        # a depthwise filter a channel, (E, taps), and a state-space
+        # mixer's with its bias: the taps are never cut
         return ("embed", None)[:nd]
     if "moe" in name and "/wi" in name:
         return ("expert", "embed", "mlp")[:nd]

@@ -101,6 +101,26 @@ def test_gqa_kernels_match_the_reference_with_kv_repeated(
         assert float(jnp.max(jnp.abs(g - w))) < 5e-5, name
 
 
+@pytest.mark.parametrize("form", ["whole", "long"])
+def test_a_group_of_sixteen_heads_of_128(form, monkeypatch):
+    """Nemotron-H's attention: 32 query heads on 2 key/value heads of 128
+    (H / H_kv = 16), through the head-major kernels in both forms, against
+    `reference_attention` with k and v repeated."""
+    if form == "long":
+        monkeypatch.setattr(fa, "_WHOLE_SEQ_MAX", 128)
+    q, k, v = _qkv(2, H=32, D=128)
+    kernel = lambda q, k, v: fa.flash_attention_bshd(
+        q, k, v, True, None, 128, 128)
+    assert _kernels(kernel, q, k, v), "no kernel ran"
+    with jax.default_matmul_precision("highest"):
+        got = _value_and_grads(kernel, q, k, v)
+        want = _value_and_grads(_repeated_reference(True), q, k, v)
+    assert got[2].shape == k.shape == (1, S, 2, 128)
+    for name, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert float(jnp.max(jnp.abs(g - w))) < 2e-4 * max(
+            1.0, float(jnp.max(jnp.abs(w)))), name
+
+
 def test_the_reference_paths_take_grouped_queries_too():
     """Beyond the interpreter's size off the TPU a call runs
     `reference_attention` and `_reference_backward`: they repeat k and v

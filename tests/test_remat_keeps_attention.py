@@ -24,7 +24,14 @@ import numpy as np
 import pytest
 from jax.ad_checkpoint import checkpoint_name, print_saved_residuals
 
-from ray_tpu.models import deepseek_v3, gpt2, layers, lfm2_moe, olmoe
+from ray_tpu.models import (
+    deepseek_v3,
+    gpt2,
+    layers,
+    lfm2_moe,
+    nemotron_h,
+    olmoe,
+)
 from ray_tpu.models.layers import KEPT_NAMES, checkpoint_layer, named
 from ray_tpu.ops.flash_attention import KEPT_RESIDUALS, flash_attention
 from ray_tpu.parallel import pipeline
@@ -509,12 +516,15 @@ def test_the_names_live_in_one_tuple(monkeypatch):
     `checkpoint_name` (`layers.named` does, once, and refuses a word that
     is not the tuple's), and every name of the tuple is marked by some
     model's layer."""
-    for module in (gpt2, deepseek_v3, lfm2_moe, olmoe, pipeline):
+    for module in (gpt2, deepseek_v3, lfm2_moe, nemotron_h, olmoe, pipeline):
         assert "checkpoint_name(" not in inspect.getsource(module), \
             module.__name__
     assert inspect.getsource(layers).count("checkpoint_name(") == 1
     plans = with_room(monkeypatch, ROOMY)
-    for module, cfg, _ in FOUR.values():
+    # the four, and the model whose layers mark the state-space names
+    for module, cfg in [v[:2] for v in FOUR.values()] + [
+            (nemotron_h, dataclasses.replace(nemotron_h.NEMOTRON_H_TINY,
+                                             **F32))]:
         backward_jaxpr(module, cfg, remat=True)
     assert {n for plan in plans for n in plan["marked"]} == set(KEPT_NAMES)
     assert not set(KEPT_NAMES) & set(KEPT_RESIDUALS)

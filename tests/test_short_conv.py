@@ -134,3 +134,104 @@ def test_it_counts_its_layers_and_taps_as_it_is_traced():
     assert traced() == [0, 0]                    # no job, no count
     with tracing.timeline_span("train.fit", root=True):
         assert traced() == [1, 3]
+
+
+# -- `causal_conv` and `gated_rms_norm`, a state-space mixer's glue ----------
+
+def make_conv(seed=0, taps=4, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (B, S, E), dtype),
+            {"kernel": jax.random.uniform(ks[1], (E, taps), jnp.float32,
+                                          -1.0, 1.0),
+             "bias": jax.random.normal(ks[2], (E,))})
+
+
+def conv_by_positions(v, p, activation):
+    v, w, b = (np.asarray(a, np.float64)
+               for a in (v, p["kernel"], p["bias"]))
+    taps = w.shape[1]
+    out = np.zeros_like(v)
+    for n in range(v.shape[0]):
+        for t in range(v.shape[1]):
+            total = b.copy()
+            for j in range(taps):
+                at = t - (taps - 1) + j
+                if at >= 0:                  # zero before the sequence
+                    total += w[:, j] * v[n, at]
+            out[n, t] = activation(total)
+    return out
+
+
+@pytest.mark.parametrize("taps", [1, 3, 4])
+def test_causal_conv_is_the_loop_over_positions(taps):
+    v, p = make_conv(taps=taps)
+    silu = lambda x: x / (1 + np.exp(-x))
+    got = layers.causal_conv(v, p, jax.nn.silu)
+    assert got.shape == v.shape and got.dtype == v.dtype
+    np.testing.assert_allclose(got, conv_by_positions(v, p, silu), atol=2e-5)
+    np.testing.assert_allclose(layers.causal_conv(v, p),
+                               conv_by_positions(v, p, lambda x: x),
+                               atol=2e-5)
+
+
+def test_causal_conv_is_causal_at_the_first_three_positions():
+    v, p = make_conv()
+    whole = layers.causal_conv(v, p)
+    w, b = p["kernel"], p["bias"]
+    # position 0 sees itself alone (the last tap), 1 the last two, 2 three
+    np.testing.assert_allclose(whole[:, 0], w[:, 3] * v[:, 0] + b, atol=1e-5)
+    np.testing.assert_allclose(
+        whole[:, 1], w[:, 3] * v[:, 1] + w[:, 2] * v[:, 0] + b, atol=1e-5)
+    np.testing.assert_allclose(
+        whole[:, 2], w[:, 3] * v[:, 2] + w[:, 2] * v[:, 1]
+        + w[:, 1] * v[:, 0] + b, atol=1e-5)
+    for t in (0, 1, 2, 6):
+        moved = layers.causal_conv(v.at[:, t].add(1.0), p)
+        changed = np.abs(np.asarray(moved - whole)).max(axis=(0, 2)) > 1e-6
+        assert not changed[:t].any() and not changed[t + 4:].any()
+        assert changed[t:t + 4].all()
+    # bfloat16 keeps its type
+    half = layers.causal_conv(v.astype(jnp.bfloat16), p, jax.nn.silu)
+    assert half.dtype == jnp.bfloat16
+
+
+@pytest.mark.parametrize("groups", [1, 4])
+def test_gated_rms_norm_is_the_gate_then_a_norm_a_group(groups):
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    y, z = (jax.random.normal(k, (B, S, 16)) for k in ks[:2])
+    gain = 1 + 0.3 * jax.random.normal(ks[2], (16,))
+    g = np.asarray(y, np.float64) * (np.asarray(z, np.float64)
+                                     / (1 + np.exp(-np.asarray(z, np.float64))))
+    parts = g.reshape(B, S, groups, 16 // groups)
+    parts = parts / np.sqrt((parts ** 2).mean(-1, keepdims=True) + 1e-5)
+    want = parts.reshape(B, S, 16) * np.asarray(gain)
+    got = layers.gated_rms_norm(y, z, {"scale": gain}, groups, 1e-5)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    # the norm before the gate is another function
+    other = np.asarray(layers.gated_rms_norm(
+        y, jnp.ones_like(z) * 30.0, {"scale": gain}, groups, 1e-5)) \
+        * np.asarray(jax.nn.silu(z)) / 30.0
+    assert np.abs(other - want).max() > 0.1
+
+
+def test_short_conv_is_bit_for_bit_what_it_was():
+    """`causal_conv` shares `_back` and `_taps` with `short_conv`, whose
+    gates and taps and their written-out backward are the parent's: the
+    formulas of PR 34, written out here, give the same bits."""
+    u, p = make(seed=7, dtype=jnp.bfloat16)
+    p = jax.tree.map(lambda x: x.astype(jnp.bfloat16), p)
+    f32 = lambda x: x.astype(jnp.float32)
+    back = lambda x, k: x if k == 0 else jnp.pad(
+        x, ((0, 0), (k, 0), (0, 0)))[:, :x.shape[1]]
+
+    def parent(u, p):
+        bcz = u @ p["in_proj"]["kernel"]
+        b, c, z = (bcz[..., i * E:(i + 1) * E] for i in range(3))
+        w = p["conv"]["kernel"]
+        v = sum(f32(w[:, j]) * (f32(back(b, L - 1 - j))
+                                * f32(back(z, L - 1 - j))) for j in range(L))
+        return (f32(c) * v).astype(u.dtype) @ p["out_proj"]["kernel"]
+
+    got, want = jax.jit(layers.short_conv)(u, p), jax.jit(parent)(u, p)
+    assert got.dtype == want.dtype == jnp.bfloat16
+    assert (np.asarray(got, np.float32) == np.asarray(want, np.float32)).all()

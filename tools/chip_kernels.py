@@ -14,7 +14,8 @@ share of a batch on one chip), the two-kernel backward past
 attention's two widths at S=8192 (a fifth number in a shape is v's width:
 q and k 192, v 128), and grouped queries at S=8192 (`gqa_8k`: 32 query
 heads on 8 key/value heads of 64, `KV_HEADS`; beside it the same call with
-k and v repeated to 32 heads first).  This process holds the chip, so run it alone.  Exits non-zero unless every case
+k and v repeated to 32 heads first; `gqa16_8k`: 32 query heads on 2
+key/value heads of 128, a group of 16).  This process holds the chip, so run it alone.  Exits non-zero unless every case
 ran as compiled Mosaic kernels on a TPU and agrees with
 ``reference_attention``.  ``--sweep`` times forced square tiles instead
 (what ``_auto_tiles`` is set from) and compares nothing.
@@ -30,6 +31,15 @@ it against a float32 loop over the held experts.
 operator and its gates and taps alone, in each form tried for the taps,
 against a float32 sum over taps and against the least time of the gates'
 and taps' bytes.
+
+``ssd_8k`` is the chunked state-space scan alone (`ops/ssd.py:ssd_scan`) at
+(2, 8192, 64 heads of 64) with 8 groups and a state of 128: each form of the
+carry over the chunks against the recurrence run position by position in
+float32 (`benchmark/reference/nemotron_h.py:recurrence`), device ms forward
+and forward + backward, beside the least time of the scan's operations and
+bytes (`families/nemotron_h.py:ssd_cost`'s count for one layer).  ``--sweep
+ssd-chunk`` times it at chunks of 64, 128 and 256 (the published 128 is what
+the timed path runs) and compares nothing.
 """
 
 from __future__ import annotations
@@ -53,9 +63,18 @@ CASES = {
     "olmoe_4k": ((4, 4096, 16, 128), 3),
     "mla_8k": ((2, 8192, 32, 192, 128), 3),
     "gqa_8k": ((2, 8192, 32, 64), 3),
+    "gqa16_8k": ((2, 8192, 32, 128), 3),
 }
 # key/value heads of the cases and sweeps whose k and v have fewer than q
-KV_HEADS = {"gqa_8k": 8, "gqa-8k": 8}
+KV_HEADS = {"gqa_8k": 8, "gqa-8k": 8, "gqa16_8k": 2}
+# (B, S, H, P, G, N, chunk) of one state-space scan
+SSD_CASES = {
+    "ssd_8k": (2, 8192, 64, 64, 8, 128, 128),
+}
+# the chunks `--sweep ssd-chunk` times a case of SSD_CASES at
+SSD_SWEEP = {
+    "ssd-chunk": ("ssd_8k", (64, 128, 256)),
+}
 # (B, S, E, taps) of one conv operator
 SHORTCONV_CASES = {
     "shortconv_8k": (2, 8192, 2048, 3),
@@ -414,6 +433,85 @@ def shortconv_case(name, dtype):
            "least_fwd_bwd_ms": round(4 * flops / 197e12 * 1e3, 4)}
 
 
+def ssd_case(name, dtype, chunk=None, compare=True):
+    """One state-space scan at ``SSD_CASES[name]`` (``chunk`` given: at
+    that chunk): a line for each form of the carry over the chunks
+    (`ops/ssd.py`): forward ms, forward and backward ms of one `jax.grad`
+    in x, dt, B and C, the least time of the scan's operations and bytes,
+    and (``compare``) the largest error of y and of the four gradients
+    relative to the float32 recurrence run position by position."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.reference import nemotron_h as reference
+    from ray_tpu.ops import ssd
+
+    B, S, H, P, G, N, Q = SSD_CASES[name]
+    Q = chunk or Q
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    x = jax.random.normal(ks[0], (B, S, H, P), dtype)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)) - 4.0)
+    A = -jnp.arange(1, H + 1, dtype=jnp.float32)
+    Bm = jax.random.normal(ks[2], (B, S, G, N), dtype) * N ** -0.5
+    Cm = jax.random.normal(ks[3], (B, S, G, N), dtype) * N ** -0.5
+    D = jnp.ones((H,), jnp.float32)
+    seed = jax.random.normal(ks[4], (B, S, H, P), jnp.float32)
+
+    def both(y):
+        return jax.jit(y), jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(y(*a).astype(jnp.float32) * seed),
+            (0, 1, 2, 3)))
+
+    def by_positions(x, dt, Bm, Cm):
+        f32 = lambda v: v.astype(jnp.float32)
+        rep = lambda v: jnp.repeat(f32(v), H // G, axis=1)
+        return jax.lax.map(lambda a: reference.recurrence(
+            f32(a[0]), a[1], A, rep(a[2]), rep(a[3]), D, 64),
+            (x, dt, Bm, Cm))
+
+    rel = lambda g, w: round(float(
+        np.max(np.abs(np.asarray(g, np.float32) - np.asarray(w)))
+        / np.max(np.abs(np.asarray(w)))), 5)
+    if compare:
+        exact = both(by_positions)
+        want = (exact[0](x, dt, Bm, Cm), *exact[1](x, dt, Bm, Cm)[1])
+    tokens, width = B * S, jnp.dtype(dtype).itemsize
+    flops = tokens * (Q * N * G + Q * P * H + 4 * N * P * H)
+    read = (H * P + 2 * G * N) * width + 4 * H
+    least = lambda f, b: max(f / 197e12, tokens * b / 819e9) * 1e3
+    def carry_by_scan(states, total):
+        """The form `ops/ssd.py` does not keep: a `lax.scan` over the
+        chunks, h_c = exp(total_c) h_{c-1} + S_c."""
+        def step(h, chunk):
+            own, decay = chunk
+            return jnp.exp(decay)[..., None, None] * h + own, h
+
+        _, before = jax.lax.scan(
+            step, jnp.zeros_like(states[:, 0]),
+            (jnp.moveaxis(states, 1, 0), jnp.moveaxis(total, 1, 0)))
+        return jnp.moveaxis(before, 0, 1)
+
+    kept = ssd._carry
+    for form, carry in (("carry_by_scan", carry_by_scan),
+                        ("carry_by_product", kept)):      # kept: the second
+        ssd._carry = carry
+        forward, grad = both(
+            lambda x, dt, Bm, Cm: ssd.ssd_scan(x, dt, A, Bm, Cm, D, Q))
+        line = {"case": name, "form": form, "chunk": Q,
+                "fwd_ms": busy_ms(forward, x, dt, Bm, Cm),
+                "fwd_bwd_ms": busy_ms(grad, x, dt, Bm, Cm),
+                "least_fwd_ms": round(least(flops, read + H * P * width), 4),
+                "least_fwd_bwd_ms": round(least(
+                    3 * flops, 3 * read + 2 * H * P * width), 4)}
+        if compare:
+            got = (forward(x, dt, Bm, Cm), *grad(x, dt, Bm, Cm)[1])
+            line["rel_err"] = {what: rel(g, t) for what, g, t in zip(
+                ("y", "dx", "ddt", "dB", "dC"), got, want)}
+        yield line
+    ssd._carry = kept
+
+
 def compare_with_reference(shape, dtype, kv_heads=None):
     """Causal ``flash_attention_bshd`` at ``shape`` (B, S, H, D[, Dv]),
     forward and backward, on the default device: (largest error of o, dq,
@@ -475,14 +573,17 @@ def main():
                         help="time forced tiles at these of SWEEP's shapes "
                              f"({', '.join(SWEEP)}; none named: at all)")
     parser.add_argument("--cases", nargs="+", metavar="CASE",
-                        default=[*CASES, *MOE_CASES, *SHORTCONV_CASES],
+                        default=[*CASES, *MOE_CASES, *SHORTCONV_CASES,
+                                 *SSD_CASES],
                         help=f"run these only ({', '.join(CASES)}, "
                              f"{', '.join(MOE_CASES)}, "
-                             f"{', '.join(SHORTCONV_CASES)}; default: all)")
+                             f"{', '.join(SHORTCONV_CASES)}, "
+                             f"{', '.join(SSD_CASES)}; default: all)")
     args = parser.parse_args()
-    if args.sweep and set(args.sweep) - set(SWEEP):
-        parser.error(f"--sweep: no such shape in {sorted(SWEEP)}")
-    known = [*CASES, *MOE_CASES, *SHORTCONV_CASES]
+    if args.sweep and set(args.sweep) - set(SWEEP) - set(SSD_SWEEP):
+        parser.error(f"--sweep: no such shape in "
+                     f"{sorted([*SWEEP, *SSD_SWEEP])}")
+    known = [*CASES, *MOE_CASES, *SHORTCONV_CASES, *SSD_CASES]
     if set(args.cases) - set(known):
         parser.error(f"--cases: no such case in {known}")
 
@@ -502,7 +603,15 @@ def main():
         sys.exit(f"no TPU: jax found {dev.platform!r}")
 
     if args.sweep is not None:
-        for name in args.sweep or SWEEP:
+        for name in args.sweep or [*SWEEP, *SSD_SWEEP]:
+            if name in SSD_SWEEP:
+                case, chunks = SSD_SWEEP[name]
+                for chunk in chunks:
+                    for line in ssd_case(case, jnp.bfloat16, chunk, False):
+                        print(json.dumps({
+                            "sweep": name, **line,
+                            "device_kind": dev.device_kind}), flush=True)
+                continue
             shape, blocks = SWEEP[name]
             for block in ((None, None),) + blocks:
                 fwd_ms, bwd_ms = time_passes(shape, jnp.bfloat16, *block,
@@ -549,8 +658,9 @@ def main():
                 failed.append(f"{name}:{line['form']}")
             print(json.dumps({**line, "ok": ok,
                               "device_kind": dev.device_kind}), flush=True)
-    for name in SHORTCONV_CASES:
-        for line in shortconv_case(name, jnp.bfloat16) \
+    for name, case in [(n, shortconv_case) for n in SHORTCONV_CASES] \
+            + [(n, ssd_case) for n in SSD_CASES]:
+        for line in case(name, jnp.bfloat16) \
                 if name in args.cases else ():
             ok = max(line.get("rel_err", {"": 0}).values()) < TOLERANCE
             if not ok:
