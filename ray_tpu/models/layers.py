@@ -274,9 +274,11 @@ KEPT_NAMES = (
     "ssm/in_proj",              # [z | xBC | dt], W_in's result, 3.8E wide
     "ffn/hidden",               # c_fc's result (4E); a SwiGLU's gate and up;
                                 # an ungated expert's up
-    "ssm/scan",                 # the scan's y: kept, a replay runs the two
-                                # products its backward reads and not the two
-                                # that only make y (PERF.md §6, PR 38)
+    "ssm/scan",                 # the scan's y: kept, a replay drops the scan
+                                # kernel altogether (its backward reads the
+                                # scan's inputs alone, `ops/ssd.py`): 4.4 ms
+                                # a step for 0.5 GiB on nemotron, 8.8 ms a
+                                # GiB (PERF.md §6, PR 39)
     "attention/latent_up",      # k and v multiplied out of the latent: the
                                 # widest and the cheapest to remake
 )

@@ -682,3 +682,78 @@ def test_state_bytes_cut_as_the_parameters_are():
     moments = 2 * (kernel + bias) + 4
     assert cut == 2 * held + kernel // 8 \
         + moments * held // (kernel + bias)
+
+
+# -- the state-space scan's kernels (PR 39) ----------------------------------
+
+def _scan_layer():
+    """A layer whose only marked value is a scan's y, at a size the scan's
+    kernels take (`ops/ssd.py`), and its one call's arguments."""
+    from ray_tpu.ops import ssd
+
+    H, P, N, S = 2, 64, 128, 16
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    args = (jax.random.normal(ks[0], (BATCH, S, H, P)),
+            jax.nn.softplus(jax.random.normal(ks[1], (BATCH, S, H))),
+            -jnp.ones((H,)),
+            jax.random.normal(ks[2], (BATCH, S, 1, N)),
+            jax.random.normal(ks[3], (BATCH, S, 1, N)), jnp.ones((H,)))
+
+    def layer(x, dt, A, Bm, Cm, D):
+        # the conv's place: the scan's operands are made from the layer's
+        y = named(ssd.ssd_scan(jnp.tanh(x), dt, A, Bm, Cm, D, 8), "ssm/scan")
+        return jnp.sum(jnp.tanh(y) * y)         # its backward reads y
+
+    return layer, args
+
+
+def _scan_kernels(jaxpr, replayed):
+    """The compiled-form `pallas_call`s of a jaxpr by kind (the y kernel's
+    one 3-D result, the state pass's 5-D, the backward's six), those of a
+    replay (``replayed``: under `rematted_computation`, which a
+    `platform_dependent`'s `cond` carries for its branches) or of the other
+    passes."""
+    kinds = {(1, 3): "forward", (1, 5): "states", (6, 3): "backward"}
+    found = collections.Counter()
+
+    def walk(jaxpr, inside):
+        for e in jaxpr.eqns:
+            here = inside or "rematted_computation" in str(
+                e.source_info.name_stack)
+            if e.primitive.name == "pallas_call":
+                if not e.params["interpret"] and here == replayed:
+                    found[kinds[len(e.outvars), e.outvars[0].aval.ndim]] += 1
+                continue
+            for value in e.params.values():
+                for sub in value if isinstance(value, (tuple, list)) \
+                        else (value,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub, here)
+
+    walk(jaxpr, False)
+    return found
+
+
+@pytest.mark.parametrize("room,replay", [(ROOMY, {}), (0, {"forward": 1})])
+def test_a_kept_scan_leaves_no_kernel_in_the_replay(room, replay,
+                                                    monkeypatch):
+    """With `ssm/scan` kept the backward pass of a recomputed layer holds
+    the scan's backward kernels (the state pass and the walk back) and no
+    forward kernel: its residuals are the scan's inputs alone.  Declined,
+    the replay runs the forward kernel and nothing else of the scan."""
+    plans = with_room(monkeypatch, room)
+    layer, args = _scan_layer()
+    grad = jax.grad(lambda *a: checkpoint_layer(layer, stack=[a])(*a),
+                    argnums=tuple(range(6)))
+    jaxpr = jax.make_jaxpr(grad)(*args).jaxpr
+    assert plans[0]["names"] == (("ssm/scan",) if room else ())
+    assert _scan_kernels(jaxpr, replayed=True) == replay
+    assert _scan_kernels(jaxpr, replayed=False) == {
+        "forward": 1, "states": 1, "backward": 1}
+    # and the gradients are the bare checkpoint's
+    want = jax.grad(lambda *a: jax.checkpoint(layer)(*a),
+                    argnums=tuple(range(6)))(*args)
+    for got_leaf, want_leaf in zip(grad(*args), want):
+        np.testing.assert_allclose(np.asarray(got_leaf),
+                                   np.asarray(want_leaf), atol=1e-5, rtol=0)

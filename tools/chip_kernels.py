@@ -33,13 +33,15 @@ against a float32 sum over taps and against the least time of the gates'
 and taps' bytes.
 
 ``ssd_8k`` is the chunked state-space scan alone (`ops/ssd.py:ssd_scan`) at
-(2, 8192, 64 heads of 64) with 8 groups and a state of 128: each form of the
-carry over the chunks against the recurrence run position by position in
-float32 (`benchmark/reference/nemotron_h.py:recurrence`), device ms forward
-and forward + backward, beside the least time of the scan's operations and
-bytes (`families/nemotron_h.py:ssd_cost`'s count for one layer).  ``--sweep
-ssd-chunk`` times it at chunks of 64, 128 and 256 (the published 128 is what
-the timed path runs) and compares nothing.
+(2, 8192, 64 heads of 64) with 8 groups and a state of 128, in bfloat16 and
+in float32: the Pallas kernels as `ssd_scan` calls them, and the `einsum`
+form with each form of the carry over the chunks, all against the
+recurrence run position by position in float32
+(`benchmark/reference/nemotron_h.py:recurrence`), y and the six gradients,
+device ms forward and forward + backward, beside the least time of the
+scan's operations and bytes (`families/nemotron_h.py:ssd_cost`'s count for
+one layer).  ``--sweep ssd-chunk`` times all three at chunks of 64, 128 and
+256 (the published 128 is what the timed path runs) and compares nothing.
 """
 
 from __future__ import annotations
@@ -435,11 +437,13 @@ def shortconv_case(name, dtype):
 
 def ssd_case(name, dtype, chunk=None, compare=True):
     """One state-space scan at ``SSD_CASES[name]`` (``chunk`` given: at
-    that chunk): a line for each form of the carry over the chunks
-    (`ops/ssd.py`): forward ms, forward and backward ms of one `jax.grad`
-    in x, dt, B and C, the least time of the scan's operations and bytes,
-    and (``compare``) the largest error of y and of the four gradients
-    relative to the float32 recurrence run position by position."""
+    that chunk): a line for each form of it (`ops/ssd.py`) -- the `einsum`
+    form with either carry over the chunks, and the Pallas kernels as
+    `ssd_scan` calls them: forward ms, forward and backward ms of one
+    `jax.grad` in all six operands, the least time of the scan's operations
+    and bytes, and (``compare``) the largest error of y and of the six
+    gradients relative to the float32 recurrence run position by
+    position."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -450,20 +454,28 @@ def ssd_case(name, dtype, chunk=None, compare=True):
     B, S, H, P, G, N, Q = SSD_CASES[name]
     Q = chunk or Q
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
-    x = jax.random.normal(ks[0], (B, S, H, P), dtype)
+    # x, B and C flat over heads and groups, as `_mamba` splits them off the
+    # conv's result: a 4-D array on the chip is laid out by its last two
+    # dimensions, and a jit that took one would time re-laying it
+    x = jax.random.normal(ks[0], (B, S, H * P), dtype)
     dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)) - 4.0)
     A = -jnp.arange(1, H + 1, dtype=jnp.float32)
-    Bm = jax.random.normal(ks[2], (B, S, G, N), dtype) * N ** -0.5
-    Cm = jax.random.normal(ks[3], (B, S, G, N), dtype) * N ** -0.5
+    Bm = jax.random.normal(ks[2], (B, S, G * N), dtype) * N ** -0.5
+    Cm = jax.random.normal(ks[3], (B, S, G * N), dtype) * N ** -0.5
     D = jnp.ones((H,), jnp.float32)
-    seed = jax.random.normal(ks[4], (B, S, H, P), jnp.float32)
+    seed = jax.random.normal(ks[4], (B, S, H * P), jnp.float32)
+    args = (x, dt, A, Bm, Cm, D)
 
-    def both(y):
+    def both(scan):
+        def y(x, dt, A, Bm, Cm, D):
+            return scan(x.reshape(B, S, H, P), dt, A, Bm.reshape(B, S, G, N),
+                        Cm.reshape(B, S, G, N), D).reshape(B, S, H * P)
+
         return jax.jit(y), jax.jit(jax.value_and_grad(
             lambda *a: jnp.sum(y(*a).astype(jnp.float32) * seed),
-            (0, 1, 2, 3)))
+            tuple(range(6))))
 
-    def by_positions(x, dt, Bm, Cm):
+    def by_positions(x, dt, A, Bm, Cm, D):
         f32 = lambda v: v.astype(jnp.float32)
         rep = lambda v: jnp.repeat(f32(v), H // G, axis=1)
         return jax.lax.map(lambda a: reference.recurrence(
@@ -475,7 +487,7 @@ def ssd_case(name, dtype, chunk=None, compare=True):
         / np.max(np.abs(np.asarray(w)))), 5)
     if compare:
         exact = both(by_positions)
-        want = (exact[0](x, dt, Bm, Cm), *exact[1](x, dt, Bm, Cm)[1])
+        want = (exact[0](*args), *exact[1](*args)[1])
     tokens, width = B * S, jnp.dtype(dtype).itemsize
     flops = tokens * (Q * N * G + Q * P * H + 4 * N * P * H)
     read = (H * P + 2 * G * N) * width + 4 * H
@@ -493,21 +505,27 @@ def ssd_case(name, dtype, chunk=None, compare=True):
         return jnp.moveaxis(before, 0, 1)
 
     kept = ssd._carry
-    for form, carry in (("carry_by_scan", carry_by_scan),
-                        ("carry_by_product", kept)):      # kept: the second
+    einsums = lambda *a: ssd._ssd_einsum(*a, Q)
+    for form, carry, scan in (
+            ("einsum_carry_by_scan", carry_by_scan, einsums),
+            ("einsum_carry_by_product", kept, einsums),
+            ("kernels", kept, lambda *a: ssd.ssd_scan(*a, Q))):   # what runs
         ssd._carry = carry
-        forward, grad = both(
-            lambda x, dt, Bm, Cm: ssd.ssd_scan(x, dt, A, Bm, Cm, D, Q))
+        forward, grad = both(scan)
         line = {"case": name, "form": form, "chunk": Q,
-                "fwd_ms": busy_ms(forward, x, dt, Bm, Cm),
-                "fwd_bwd_ms": busy_ms(grad, x, dt, Bm, Cm),
+                "dtype": jnp.dtype(dtype).name,
+                "fwd_ms": busy_ms(forward, *args),
+                "fwd_bwd_ms": busy_ms(grad, *args),
                 "least_fwd_ms": round(least(flops, read + H * P * width), 4),
                 "least_fwd_bwd_ms": round(least(
                     3 * flops, 3 * read + 2 * H * P * width), 4)}
+        if form == "kernels":
+            line["mosaic_kernels"] = grad.lower(*args).compile().as_text(
+                ).count('custom_call_target="tpu_custom_call"')
         if compare:
-            got = (forward(x, dt, Bm, Cm), *grad(x, dt, Bm, Cm)[1])
+            got = (forward(*args), *grad(*args)[1])
             line["rel_err"] = {what: rel(g, t) for what, g, t in zip(
-                ("y", "dx", "ddt", "dB", "dC"), got, want)}
+                ("y", "dx", "ddt", "dA", "dB", "dC", "dD"), got, want)}
         yield line
     ssd._carry = kept
 
@@ -594,10 +612,12 @@ def main():
         AttentionFallbackWarning,
         _auto_tiles,
     )
+    from ray_tpu.ops.ssd import SsdFallbackWarning
     from ray_tpu.util.compile_cache import ensure_compile_cache
 
     ensure_compile_cache()
     warnings.simplefilter("error", AttentionFallbackWarning)
+    warnings.simplefilter("error", SsdFallbackWarning)
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         sys.exit(f"no TPU: jax found {dev.platform!r}")
@@ -658,11 +678,15 @@ def main():
                 failed.append(f"{name}:{line['form']}")
             print(json.dumps({**line, "ok": ok,
                               "device_kind": dev.device_kind}), flush=True)
-    for name, case in [(n, shortconv_case) for n in SHORTCONV_CASES] \
-            + [(n, ssd_case) for n in SSD_CASES]:
-        for line in case(name, jnp.bfloat16) \
+    for name, case, dtype in [
+            (n, shortconv_case, jnp.bfloat16) for n in SHORTCONV_CASES] + [
+            (n, ssd_case, t) for n in SSD_CASES
+            for t in (jnp.bfloat16, jnp.float32)]:
+        for line in case(name, dtype) \
                 if name in args.cases else ():
-            ok = max(line.get("rel_err", {"": 0}).values()) < TOLERANCE
+            # the scan's forward kernel, and its backward's two
+            ok = max(line.get("rel_err", {"": 0}).values()) < TOLERANCE \
+                and line.get("mosaic_kernels", 3) == 3
             if not ok:
                 failed.append(f"{name}:{line['form']}")
             print(json.dumps({**line, "ok": ok,
