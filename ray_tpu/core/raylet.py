@@ -422,6 +422,13 @@ class _PlacementGroup:
 # host -> libtpu's TPU_CHIPS_PER_PROCESS_BOUNDS for that many
 _CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}
 
+# `Raylet.shutdown`: how long a worker has to exit on SIGTERM before
+# SIGKILL, and how long it then waits for those that opened chips to be
+# gone (four chips' mappings took 14 s to come back on a v5e host, one
+# idle chip's 3: PERF.md §6, PR 45).
+_WORKER_EXIT_GRACE_S = 2.0
+_CHIP_RELEASE_LIMIT_S = 60.0
+
 
 def _fits(avail: Dict[str, float], need: Dict[str, float]) -> bool:
     return all(avail.get(k, 0.0) + 1e-9 >= v for k, v in need.items())
@@ -6643,6 +6650,14 @@ class Raylet:
     # --------------------------------------------------------------- shutdown
 
     def shutdown(self):
+        """Stop the node.  Returns only when every worker process that
+        opened TPU chips has exited: a chip belongs to its process until
+        the kernel has taken back the mappings, seconds after SIGKILL on a
+        four-chip host, and the next job on the host (the caller's own next
+        `init`, or another program started as this one ends) finds
+        `/dev/vfio/<group>` busy until then.  Every worker has
+        `_WORKER_EXIT_GRACE_S` to exit on SIGTERM; one without chips that is
+        still there is killed and not waited for, as before."""
         try:
             self.gcs.unregister_node(self.node_id)
         except Exception:  # noqa: BLE001
@@ -6658,9 +6673,19 @@ class Raylet:
         for p in self._procs:
             try:
                 p.terminate()
-                p.wait(timeout=2)
+                p.wait(timeout=_WORKER_EXIT_GRACE_S)
             except (OSError, subprocess.TimeoutExpired):
                 try:
                     p.kill()
                 except OSError:
                     pass
+        holders = {p.pid: p for p in self._chip_procs.values()}
+        deadline = time.monotonic() + _CHIP_RELEASE_LIMIT_S
+        for p in holders.values():
+            try:
+                p.wait(timeout=max(0.0, deadline - time.monotonic()))
+            except subprocess.TimeoutExpired:
+                sys.stderr.write(
+                    f"[ray_tpu] worker {p.pid} still holds its TPU chips "
+                    f"{_CHIP_RELEASE_LIMIT_S:.0f} s after SIGKILL: shutdown "
+                    "returns without them\n")

@@ -24,22 +24,27 @@ Causal calls compute only the tiles on and below the diagonal and mask
 only the tiles the diagonal crosses, forward and backward; `_auto_tiles`
 picks the tile from S and `causal` (`block_q` / `block_k` name one
 explicitly).  Non-causal calls have nothing to skip and take the whole
-sequence (1024-capped) as one tile.
+sequence (1024-capped) as one tile; the backward past `_WHOLE_SEQ_MAX`
+takes 512-tiles either way.
 
 Up to S = `_WHOLE_SEQ_MAX` a grid step takes a whole (b, h) slice (a
 128-lane group in the lane layout) and every extent inside it is static,
 so nothing loops: the tiles of a row (forward) or column (backward) that
 lie wholly below the diagonal are merged into one unmasked span, the ones
 the diagonal crosses into one masked span (`_span`).  The forward walks
-its q tiles, an online softmax of two steps at most each.  The backward is
-ONE kernel for dq/dk/dv: per k tile, each span recomputes s and dp once
-and shares them between dq, dk and dv (5 dots instead of the 7 a
-two-kernel FlashAttention-2 split pays), and dq sums over k tiles in an
-f32 VMEM scratch.  Past `_WHOLE_SEQ_MAX` the grid walks the forward's q
-tiles, each looping over its k blocks, and the classic two-kernel split
-runs backward: a dq kernel blocked over q rows and a dk/dv kernel blocked
-over k columns, both recomputing probabilities tile-by-tile from the saved
-logsumexp.  The S×S matrix never exists in HBM in any pass.
+its q tiles, an online softmax of two steps at most each.  Past
+`_WHOLE_SEQ_MAX` the grid walks the tiles: the forward's q tiles, each
+looping over its k blocks, and the backward's k tiles, each looping over
+its q blocks (`_tile_loop`).
+
+The backward is ONE kernel for dq/dk/dv at every length, with one body
+(`_bwd_fused_core`): per k tile it recomputes s and dp once and shares them
+between dq, dk and dv (5 dots instead of the 7 a two-kernel
+FlashAttention-2 split pays, and a tile's mask, `exp2` and ds made once),
+and dq sums over k tiles in an f32 VMEM scratch.  Past `_WHOLE_SEQ_MAX` a
+(b, h) slice's q, do and row statistics stay in VMEM while its k tiles
+pass, last to first, under a limit of scoped VMEM that `_compiler_params`
+reckons from the shapes.  The S×S matrix never exists in HBM in any pass.
 
 Grouped-query attention: k and v may come with fewer heads than q
 (``H % H_kv == 0``), query head h reading key/value head h // (H / H_kv).
@@ -47,13 +52,13 @@ The head-major kernels read that head through their `BlockSpec` index maps
 (`_kv_rows`), so no copy of k or v with H heads exists; dk and dv leave the
 backward kernels as one float32 partial a query head and are summed over
 each group in float32 (`_sum_groups`: at (2, 8192, 32 / 8, 64) on a v5e the
-split backward takes 28.68 ms of kernels and 31.44 with the sums and the
-transposes; a dk/dv kernel that summed a group itself, the group's heads
-its innermost grid axis, took 31.58 and 33.71, fetching a head's q, do and
-statistics again at every k block: PERF.md §6, PR 34).  The lane layout
-slices every operand's heads out of the same lanes and declines such a
-call, which then takes the head-major kernels.  With ``H_kv == H`` every
-kernel is the one it was.
+split backward of PR 34 took 28.68 ms of kernels and 31.44 with the sums
+and the transposes; a dk/dv kernel that summed a group itself, the group's
+heads its innermost grid axis, took 31.58 and 33.71, fetching a head's q,
+do and statistics again at every k block: PERF.md §6, PR 34; the one
+kernel keeps the parts).  The lane layout slices every operand's heads out
+of the same lanes and declines such a call, which then takes the
+head-major kernels.
 
 The public forward rules name the two residuals the backward kernels read
 besides q, k and v, the output and the row statistics (`KEPT_RESIDUALS`):
@@ -87,11 +92,14 @@ _NEG_INF = -1e30
 _LOG2E = 1.4426950408889634  # 1/ln(2)
 _LANE = 128  # minor-dim block width Pallas TPU requires
 
-# Longest sequence a grid step takes whole — all q rows in the forward, the
-# one-kernel backward: the (b, h) slices of q, k, v, do and the three
+# Longest sequence a grid step takes whole — all q rows in the forward, all
+# k rows in the backward: the (b, h) slices of q, k, v, do and the three
 # gradients (double-buffered), the lane layout's padded lse/delta and the
-# f32 dq scratch stay within a few MB of VMEM up to here.  `_resolve` makes
-# the decision from it; `_auto_tiles` reads it for the tiles.
+# f32 dq scratch stay within a few MB of VMEM up to here, and a merged span
+# scores at most this many rows at once.  Past it the grid walks the tiles
+# (and the lane layout's backward goes head-major).  `_resolve` makes the
+# decision from it; `_auto_tiles` reads it for the tiles, `_rows_kept` for
+# the layout of a kept lse.
 _WHOLE_SEQ_MAX = 1024
 
 # Both grid dims are embarrassingly parallel (batch*heads, and q/k blocks
@@ -109,40 +117,73 @@ _COMPILER_PARAMS = pltpu.CompilerParams(
 # name is nothing.
 KEPT_RESIDUALS = ("flash_attention.o", "flash_attention.lse")
 
-# The `jax.named_scope` each of the six `pallas_call`s is made under: the
+# The `jax.named_scope` each of the four `pallas_call`s is made under: the
 # form of kernel, which an operation's `op_name` (and the chip's trace, as
 # `tf_op`) then says besides forward / backward.  The head-major forward (a
 # grid step a q tile, or the whole sequence) and the lane layout's; the
-# one-kernel backward of each layout; the split backward's two.
-KERNEL_FORMS = ("fwd_rows", "fwd_lanes", "bwd_fused", "bwd_fused_lanes",
-                "bwd_dq", "bwd_dkv")
+# one-kernel backward of each layout (head-major: a grid step the whole
+# sequence, or a k tile of it).
+KERNEL_FORMS = ("fwd_rows", "fwd_lanes", "bwd_fused", "bwd_fused_lanes")
 
 
-# Mosaic's default limit of scoped VMEM on a v5e, and what one tile's
-# temporaries (s, p, dp, ds of 1024 x 1024 scores) may take of it.
+# Mosaic's default limit of scoped VMEM on a v5e, what one tile's
+# temporaries (s, p, dp, ds of 1024 x 1024 scores) may take of it, and the
+# most a kernel asks of the chip's 128 MiB.
 _SCOPED_VMEM = 16 << 20
 _TILE_VMEM = 10 << 20
+_VMEM_MAX = 100 << 20
 
 
-def _compiler_params(S, D, Dv, dtype):
+def _lanes(d):
+    """A width padded to whole lanes, as VMEM holds it."""
+    return -(-d // _LANE) * _LANE
+
+
+def _bwd_held_bytes(S, D, Dv, dtype):
+    """What the backward past `_WHOLE_SEQ_MAX` holds in VMEM while a (b, h)
+    slice's k tiles pass: the slice's q and do (double-buffered), two
+    (S, 1) f32 statistics that fill a lane a row, dq's block and the f32
+    dq scratch.  5.5 KB a row at 192 / 128 in bfloat16 (q and k take 256
+    lanes), 46 MB at S = 8,192; 4 KB a row at D = 128 or 64, 16 MB at
+    S = 4,096, and 128 MiB at S = 32,768, half of it the statistics."""
+    width = jnp.dtype(dtype).itemsize
+    return S * (2 * (_lanes(D) + _lanes(Dv)) * width    # q and do
+                + 2 * 2 * _LANE * 4                     # lse and delta
+                + 2 * _lanes(D) * width                 # dq's block
+                + _lanes(D) * 4)                        # the scratch
+
+
+def _compiler_params(S, D, Dv, dtype, bwd_steps=1):
     """`_COMPILER_PARAMS`, with a higher limit of scoped VMEM where the
-    operands a head-major kernel holds for the whole sequence (k and v in
-    the forward and the dq kernel; q, do and the row statistics in the
-    dk/dv kernel; each double-buffered, a width padded to whole lanes)
-    leave a tile's temporaries no room under the default.  At S = 8,192
-    with q/k 192 wide and v 128 the forward wants 17.8 MB and the dq kernel
-    16.02 of the default 16 (compiled for a v5e without the chip: PERF.md
-    §6, PR 32); every shape that fitted before takes the default as
-    before (S = 4,096 at D = 128 holds 4 MB of k and v)."""
-    lanes = lambda d: -(-d // _LANE) * _LANE
-    resident = 2 * S * (lanes(D) + lanes(Dv)) * jnp.dtype(dtype).itemsize
+    operands a head-major kernel holds for the whole sequence (a width
+    padded to whole lanes, every block double-buffered) leave a tile's
+    temporaries no room under the default.
+
+    The forward holds k and v: at S = 8,192 with q/k 192 wide and v 128 it
+    wants 17.8 MB of the default 16 (compiled for a v5e without the chip:
+    PERF.md §6, PR 32) and is given 16 MB + 4 x the 12 of k and v; every
+    shape that fits takes the default (S = 4,096 at D = 128 holds 4 MB of k
+    and v), and so does a backward of one grid step a (b, h) slice
+    (``bwd_steps`` = 1: S <= `_WHOLE_SEQ_MAX`, a megabyte of each).
+
+    ``bwd_steps`` > 1: the backward with a slice's k tiles on the grid.
+    They run one after the other (dq sums over them in scratch: that grid
+    axis is "arbitrary") while `_bwd_held_bytes` stay in VMEM.  The limit
+    is that plus the default's 16 MB for the k, v, dk, dv tiles and a
+    tile's temporaries, `_VMEM_MAX` at most: S = 16,384 fits at every width
+    a cell has; a slice that leaves a tile no room (S = 32,768) never gets
+    here (`_tiling_problem`)."""
+    if bwd_steps > 1:
+        return pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=min(
+                _VMEM_MAX, _SCOPED_VMEM + _bwd_held_bytes(S, D, Dv, dtype)))
+    resident = 2 * S * (_lanes(D) + _lanes(Dv)) * jnp.dtype(dtype).itemsize
     if resident <= _SCOPED_VMEM - _TILE_VMEM:
         return _COMPILER_PARAMS
-    # the dk/dv kernel's residents are the largest: q and do as wide as k
-    # and v, and two (S, 1) f32 statistics that fill a lane each
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel"),
-        vmem_limit_bytes=min(100 << 20, _SCOPED_VMEM + 4 * resident))
+        vmem_limit_bytes=min(_VMEM_MAX, _SCOPED_VMEM + 4 * resident))
 
 
 # ---------------------------------------------------------------------------
@@ -159,8 +200,8 @@ def _span(first, last, block, body, carry):
 
 
 def _tile_loop(first, last, block, body, carry):
-    """The same tiles one at a time; the bounds may be traced (the q tile
-    of a grid step)."""
+    """The same tiles one at a time; the bounds may be traced (from the q
+    tile of the forward's grid step, the k tile of the backward's)."""
     return jax.lax.fori_loop(
         first, last, lambda i, c: body(i * block, block, c), carry)
 
@@ -248,29 +289,56 @@ def _finish_fwd(acc, m, l, out_dtype):
     return o, lse
 
 
+def _when(cond):
+    """`pl.when`; a condition that is a Python bool (a grid of one step) is
+    decided as the kernel is traced and leaves nothing in it."""
+    if isinstance(cond, bool):
+        return lambda f: f() if cond else None
+    return pl.when(cond)
+
+
 def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dq_ref, dk_ref, dv_ref, dq_acc, cols, stat, *,
                     sm_scale, causal, block_q, block_k, seq_len):
-    """One head's whole backward: for each k tile, the q tiles the diagonal
-    crosses as one masked span and the q tiles below it as one unmasked
-    span (`_span`; q tiles above are not visited); each span recomputes s
-    and dp ONCE and contracts them into dq, dk and dv — 5 dots vs the
-    split's 7.  Every extent is static: the sequence is short.
+    """One head's backward, at every length: for each k tile, the q tiles
+    the diagonal crosses masked and the q tiles below it unmasked (q tiles
+    above are not visited); each recomputes s and dp ONCE and contracts
+    them into dq, dk and dv: 5 dots, where a FlashAttention-2 split (a dq
+    kernel over q rows, a dk/dv kernel over k columns) pays 7 and does a
+    tile's mask, `exp2` and ds twice.
 
-    The refs hold all S rows; ``cols`` picks the head's columns of
-    q/k/v/do/dq/dk/dv and ``stat`` its column of lse/delta (natural-log
-    lse, f32 delta).  ``dq_acc`` is an f32 (S, head_dim) scratch: dq sums
-    over k tiles there and is scaled and written once at the end.  q, k
-    and dq, dk have one width, v, do and dv may have another."""
-    num_q, num_k = seq_len // block_q, seq_len // block_k
+    The q-side refs (q, do, lse, delta, dq) hold all S rows; the k-side
+    refs (k, v, dk, dv) hold what the grid step takes of them, and the
+    kernel reads its form off their rows.  All S (up to `_WHOLE_SEQ_MAX`):
+    one step, the k tiles unrolled and every extent static, so each kind of
+    q tile is merged into one span (`_span`, S rows at most).  One tile
+    (past it): the slice's k tiles are the grid's second axis and each
+    loops over its q tiles (`_tile_loop`); they pass from the LAST to the
+    first (`_kv_rows`), because a causal slice's last k tile has one q tile
+    to visit and its first all of them, and the next slice's q, do and
+    statistics (14 MB at S = 8,192, 192 / 128 wide) are fetched during a
+    slice's last step: the longest step hides the fetch, not the shortest.
+
+    ``cols`` picks the head's columns of q/k/v/do/dq/dk/dv and ``stat``
+    its column of lse/delta (natural-log lse, f32 delta).  ``dq_acc`` is an
+    f32 (S, head_dim) scratch: dq sums over k tiles there, zeroed at the
+    slice's first step and scaled and written at its last.  q, k and dq, dk
+    have one width, v, do and dv may have another."""
+    k_rows_held = k_ref.shape[0]
+    steps, tiles = seq_len // k_rows_held, k_rows_held // block_k
+    step, over = (0, _span) if steps == 1 else (pl.program_id(1), _tile_loop)
     # sm_scale * log2(e) folded into the q rows: s is in base-2 units, q
     # also serves the dk dot (rescaled by ln2 at the end), and ds's
     # trailing *sm_scale is hoisted onto dq.
     scale = jnp.asarray(sm_scale * _LOG2E, q_ref.dtype)
-    dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    for kj in range(num_k):
-        k_rows = pl.ds(kj * block_k, block_k)
+    @_when(step == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    for tile in range(tiles):
+        kj = (steps - 1 - step) * tiles + tile
+        k_rows = pl.ds(tile * block_k, block_k)
         k = k_ref[k_rows, cols]
         v = v_ref[k_rows, cols]
 
@@ -306,17 +374,21 @@ def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                jnp.zeros((block_k, v.shape[-1]), jnp.float32))
         first = below = 0
         if causal:
+            # the blocks divide the sequence, so `below` stays within the
+            # q tiles
             first = (kj * block_k) // block_q
-            below = min(num_q, pl.cdiv((kj + 1) * block_k, block_q))
-        acc = _span(first, below, block_q,
-                    functools.partial(q_span, masked=True), acc)
-        dk_acc, dv_acc = _span(
-            below, num_q, block_q,
+            below = pl.cdiv((kj + 1) * block_k, block_q)
+        acc = over(first, below, block_q,
+                   functools.partial(q_span, masked=True), acc)
+        dk_acc, dv_acc = over(
+            below, seq_len // block_q, block_q,
             functools.partial(q_span, masked=False), acc)
         dk_ref[k_rows, cols] = (dk_acc * (1.0 / _LOG2E)).astype(dk_ref.dtype)
         dv_ref[k_rows, cols] = dv_acc.astype(dv_ref.dtype)
 
-    dq_ref[:, cols] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+    @_when(step == steps - 1)
+    def _():
+        dq_ref[:, cols] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
 def _kernel_call(*form):
@@ -363,139 +435,17 @@ def _bwd_fused_kernel(*refs, **tiling):
     _bwd_fused_core(*refs, slice(None), pl.ds(0, 1), **tiling)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-                   sm_scale, causal, block_q, block_k, seq_len):
-    qi = pl.program_id(1)
-    # sm_scale * log2(e) folded into the q tile: s = (q*sc*log2e)@k is in
-    # base-2 units so p = exp2(s - lse*log2e); the trailing *sc of ds is
-    # hoisted onto the dq tile at the end (d ops/row, not bk).
-    q = q_ref[...] * jnp.asarray(sm_scale * _LOG2E, q_ref.dtype)  # (bq, d)
-    do = do_ref[...]                          # (bq, d) bf16
-    lse = lse_ref[...] * _LOG2E               # (bq, 1) f32, base-2 units
-    delta = delta_ref[...]                    # (bq, 1) f32
-
-    num_k_blocks = pl.cdiv(seq_len, block_k)
-
-    def body(kj, acc, masked):
-        k = k_ref[pl.ds(kj * block_k, block_k), :]
-        v = v_ref[pl.ds(kj * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                       # (bq, bk) f32
-        if masked:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp2((s - lse).astype(k.dtype))  # bf16; masked lanes -> 0
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                       # (bq, bk) f32
-        ds = (p * (dp - delta).astype(k.dtype))
-        return acc + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    d = q_ref.shape[-1]
-    init = jnp.zeros((block_q, d), jnp.float32)
-    if causal:
-        first_diag = (qi * block_q) // block_k
-        last = jnp.minimum(num_k_blocks,
-                           pl.cdiv((qi + 1) * block_q, block_k))
-        acc = jax.lax.fori_loop(0, first_diag,
-                                lambda kj, a: body(kj, a, False), init)
-        acc = jax.lax.fori_loop(first_diag, last,
-                                lambda kj, a: body(kj, a, True), acc)
-    else:
-        acc = jax.lax.fori_loop(0, num_k_blocks,
-                                lambda kj, a: body(kj, a, False), init)
-    dq_ref[...] = (acc * sm_scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, sm_scale, causal, block_q, block_k,
-                    seq_len):
-    kj = pl.program_id(1)
-    k = k_ref[...]                            # (bk, d) bf16
-    v = v_ref[...]                            # (bk, d) bf16
-    # q carries sm_scale*log2e (base-2 units for exp2); it also serves as
-    # the dk contraction operand, so dk is rescaled by 1/log2e at the end.
-    scale = jnp.asarray(sm_scale * _LOG2E, k.dtype)
-
-    num_q_blocks = pl.cdiv(seq_len, block_q)
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-
-    def body(qi, carry, masked):
-        dk_acc, dv_acc = carry
-        # scale folded into the q tile (serves both the s recompute and
-        # the dk dot, absorbing ds's trailing *sm_scale)
-        q = q_ref[pl.ds(qi * block_q, block_q), :] * scale
-        do = do_ref[pl.ds(qi * block_q, block_q), :]
-        lse = lse_ref[pl.ds(qi * block_q, block_q), :] * _LOG2E
-        delta = delta_ref[pl.ds(qi * block_q, block_q), :]  # (bq, 1)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                       # (bq, bk) f32
-        if masked:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp2((s - lse).astype(k.dtype))  # bf16
-        dv_acc = dv_acc + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                       # (bk, d)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                       # (bq, bk) f32
-        ds = p * (dp - delta).astype(k.dtype)
-        dk_acc = dk_acc + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                       # (bk, d) — q carries the scale
-        return dk_acc, dv_acc
-
-    init = (jnp.zeros((block_k, k_ref.shape[-1]), jnp.float32),
-            jnp.zeros((block_k, v_ref.shape[-1]), jnp.float32))
-    if causal:
-        # q blocks intersecting this k column's diagonal band need the
-        # mask; q blocks strictly below it don't; ones above contribute
-        # nothing and are skipped.
-        start = (kj * block_k) // block_q
-        diag_end = jnp.minimum(num_q_blocks,
-                               pl.cdiv((kj + 1) * block_k, block_q))
-        carry = jax.lax.fori_loop(start, diag_end,
-                                  lambda qi, c: body(qi, c, True), init)
-        dk_acc, dv_acc = jax.lax.fori_loop(
-            diag_end, num_q_blocks, lambda qi, c: body(qi, c, False), carry)
-    else:
-        dk_acc, dv_acc = jax.lax.fori_loop(
-            0, num_q_blocks, lambda qi, c: body(qi, c, False), init)
-    dk_ref[...] = (dk_acc * (1.0 / _LOG2E)).astype(dk_ref.dtype)
-    dv_ref[...] = dv_acc.astype(dv_ref.dtype)
-
-
-def _kv_rows(group, tile=False):
-    """Index map of the k / v block that grid row g's query head reads,
-    the whole sequence or (``tile``) block i of it.  The rows of q are
+def _kv_rows(group, last=0):
+    """Index map of the k / v / dk / dv block of grid step (g, i): the rows
+    of k tile ``last - i`` (the backward's k tiles pass from the last to
+    the first; ``last`` = 0: the step holds the whole sequence) of the
+    key/value head that grid row g's query head reads.  The rows of q are
     (batch, head) flat and those of k (batch, key/value head), so query
     head h's key/value head h // group is row g // group."""
-    if group == 1:
-        return (lambda g, i: (g, i, 0)) if tile else (lambda g, i: (g, 0, 0))
-    if tile:
-        return lambda g, i: (g // group, i, 0)
-    return lambda g, i: (g // group, 0, 0)
+    row = (lambda g: g) if group == 1 else (lambda g: g // group)
+    if last == 0:
+        return lambda g, i: (row(g), 0, 0)
+    return lambda g, i: (row(g), last - i, 0)
 
 
 def _sum_groups(partials, like):
@@ -545,14 +495,14 @@ def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, whole,
     return o.reshape(B, H, S, Dv), lse.reshape(B, H, S)
 
 
-@_kernel_call("whole")
+@_kernel_call("k_rows")
 def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
-                     whole, interpret, delta=None):
-    """``whole``: the one-kernel backward, a grid step taking the whole
-    sequence; else the two-kernel split, blocked over q rows (dq) and over
-    k columns (dk/dv).  v, o and do may have another last dim than q and
-    k; k and v may have fewer heads (a group of q's heads reads each):
-    every query head then writes its float32 part of dk and dv, and
+                     k_rows, interpret, delta=None):
+    """The one-kernel backward.  ``k_rows``: the rows of k a grid step
+    takes, the whole sequence or one k tile of it, a (b, h) slice's tiles
+    in order (`_bwd_fused_core`).  v, o and do may have another last dim
+    than q and k; k and v may have fewer heads (a group of q's heads reads
+    each): every query head then writes its float32 part of dk and dv, and
     `_sum_groups` adds a group's."""
     B, H, S, D = q.shape
     Hkv, Dv = k.shape[1], v.shape[-1]
@@ -570,69 +520,30 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
             do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     delta = delta.reshape(B * H, S, 1)
     part = (lambda x: x.dtype) if group == 1 else (lambda x: jnp.float32)
-    out_shape = [jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-                 jax.ShapeDtypeStruct((B * H, S, D), part(k)),
-                 jax.ShapeDtypeStruct((B * H, S, Dv), part(v))]
-    params = _compiler_params(S, D, Dv, q.dtype)
 
     def spec(rows, width, index):
         return pl.BlockSpec((None, rows, width), index)
 
-    whole_seq, tile = (lambda g, i: (g, 0, 0)), (lambda g, i: (g, i, 0))
-    kv_seq, kv_tile = _kv_rows(group), _kv_rows(group, tile=True)
-
-    if whole:
-        # one kernel, tiled inside: shares s/dp across dq/dk/dv.
-        qk, vo = spec(S, D, whole_seq), spec(S, Dv, whole_seq)
-        row = spec(S, 1, whole_seq)
-        call = pl.pallas_call(
-            functools.partial(_bwd_fused_kernel, sm_scale=sm_scale,
-                              causal=causal, block_q=block_q,
-                              block_k=block_k, seq_len=S),
-            grid=(B * H, 1),
-            in_specs=[qk, spec(S, D, kv_seq), spec(S, Dv, kv_seq), vo, row,
-                      row],
-            out_specs=[qk, qk, vo],
-            out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
-            interpret=interpret,
-            compiler_params=params,
-        )
-        with jax.named_scope("bwd_fused"):
-            dq, dk, dv = call(qf, kf, vf, dof, lsef, delta)
-    else:
-        call = pl.pallas_call(
-            functools.partial(
-                _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                block_q=block_q, block_k=block_k, seq_len=S,
-            ),
-            grid=(B * H, S // block_q),
-            in_specs=[spec(block_q, D, tile), spec(S, D, kv_seq),
-                      spec(S, Dv, kv_seq), spec(block_q, Dv, tile),
-                      spec(block_q, 1, tile), spec(block_q, 1, tile)],
-            out_specs=spec(block_q, D, tile),
-            out_shape=out_shape[0],
-            interpret=interpret,
-            compiler_params=params,
-        )
-        with jax.named_scope("bwd_dq"):
-            dq = call(qf, kf, vf, dof, lsef, delta)
-        call = pl.pallas_call(
-            functools.partial(
-                _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                block_q=block_q, block_k=block_k, seq_len=S,
-            ),
-            grid=(B * H, S // block_k),
-            in_specs=[spec(S, D, whole_seq), spec(block_k, D, kv_tile),
-                      spec(block_k, Dv, kv_tile), spec(S, Dv, whole_seq),
-                      spec(S, 1, whole_seq), spec(S, 1, whole_seq)],
-            out_specs=[spec(block_k, D, tile), spec(block_k, Dv, tile)],
-            out_shape=out_shape[1:],
-            interpret=interpret,
-            compiler_params=params,
-        )
-        with jax.named_scope("bwd_dkv"):
-            dk, dv = call(qf, kf, vf, dof, lsef, delta)
+    steps = S // k_rows
+    k_read, k_write = _kv_rows(group, steps - 1), _kv_rows(1, steps - 1)
+    qk, vo, row = (spec(S, width, _kv_rows(1)) for width in (D, Dv, 1))
+    call = pl.pallas_call(
+        functools.partial(_bwd_fused_kernel, sm_scale=sm_scale,
+                          causal=causal, block_q=block_q, block_k=block_k,
+                          seq_len=S),
+        grid=(B * H, steps),
+        in_specs=[qk, spec(k_rows, D, k_read), spec(k_rows, Dv, k_read), vo,
+                  row, row],
+        out_specs=[qk, spec(k_rows, D, k_write), spec(k_rows, Dv, k_write)],
+        out_shape=[jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+                   jax.ShapeDtypeStruct((B * H, S, D), part(k)),
+                   jax.ShapeDtypeStruct((B * H, S, Dv), part(v))],
+        scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
+        interpret=interpret,
+        compiler_params=_compiler_params(S, D, Dv, q.dtype, bwd_steps=steps),
+    )
+    with jax.named_scope("bwd_fused"):
+        dq, dk, dv = call(qf, kf, vf, dof, lsef, delta)
 
     dq = dq.reshape(B, H, S, D)
     if group == 1:
@@ -826,8 +737,14 @@ class AttentionFallbackWarning(UserWarning):
     on every platform, the TPU included."""
 
 
-def _tiling_problem(S, block_q, block_k) -> Optional[str]:
-    """Why the Pallas kernels cannot tile S with these blocks, or None."""
+def _tiling_problem(S, block_q, block_k, held=0) -> Optional[str]:
+    """Why the Pallas kernels cannot tile S with these blocks, or None.
+    ``held``: the bytes a kernel keeps in VMEM for all S rows beside its
+    tiles (`_bwd_held_bytes`)."""
+    if held + _TILE_VMEM > _VMEM_MAX:
+        # Mosaic would refuse it: "Ran out of memory in memory space vmem"
+        return ("a (batch, head) slice's q, do, statistics and dq leave a "
+                "tile no room in VMEM")
     if S % block_q or S % block_k:
         return "the blocks do not divide the sequence"
     # Degenerate blocks (odd/prime S drives _auto_block toward 1): a grid of
@@ -877,13 +794,6 @@ def _auto_block(S: int, cap: int) -> int:
     return max(b, 1)
 
 
-# Largest tile of the two-kernel backward past _WHOLE_SEQ_MAX: it holds
-# s, p, dp and ds of one (bq, bk) tile at once, and at 1024 x 1024 they
-# pass the 16 MB of scoped VMEM (Mosaic refuses the dk/dv kernel at
-# S = 4096, D = 128).
-_SPLIT_BWD_MAX_BLOCK = 512
-
-
 def _auto_tiles(S: int, causal: bool):
     """((block_q, block_k) of the forward, the same of the backward) for a
     call that names no blocks: a function of what a call can see of itself.
@@ -895,15 +805,23 @@ def _auto_tiles(S: int, causal: bool):
     S = 1,024 at head_dim 64 and 128, and S = 512 at head_dim 64; the same
     caps won at each, and other lengths take them unmeasured.  Past
     `_WHOLE_SEQ_MAX`, swept at S = 4,096 (D = 128), at S = 8,192 with
-    q/k 192 and v 128 wide (PERF.md §6, PR 32: forward 13.76 ms at 1,024,
-    13.09 at 512, 22.24 at 256; backward 42.64 at 512, its cap, 51.29 at
-    256) and at S = 8,192 with 32 query heads on 8 key/value heads of 64
-    (PERF.md §6, PR 34: forward 10.42 at 1,024, 9.94 at 512, 19.37 at 256;
-    backward 28.68 at 512, 55.19 at 256): 512 forward is 5 to 9 % faster
-    than the largest block at all three and is left, under 0.7 % of a
-    step."""
+    q/k 192 and v 128 wide and at S = 8,192 with 32 query heads on 8
+    key/value heads of 64 and on 2 of 128 (ms a layer, tiles of 256 / 512 /
+    1,024; PERF.md §6, PR 45: the sweep is PR 44's, whose kernels these
+    are).  The one-kernel backward: 7.10 / 4.83 / 5.07,
+    34.53 / 28.12 / 27.99, 27.07 / 17.62 / 17.59 and 26.78 / 17.56 / 17.57
+    (the two-kernel split it replaced: 8.04, 42.64, 28.68 and 28.59 at
+    512); a q tile of another size than the k tile gains nothing (1,024 on
+    512 within 0.3 % of square 512; 256 on 512 slower by a tenth, 512 on
+    1,024 by a twentieth).  So 512: the fastest at 4,096 and within 0.5 %
+    of 1,024 at 8,192.  The forward: 5.27 / 2.95 / 3.25, 22.24 / 13.09 /
+    13.76, 19.37 / 9.94 / 10.42: 512 would be 5 to 9 % faster than the
+    largest block, which it takes, under 0.7 % of a step and left."""
     whole = _auto_block(S, 1024)
-    if not causal or S > _WHOLE_SEQ_MAX:
+    if S > _WHOLE_SEQ_MAX:
+        bwd = _auto_block(S, 512)
+        return (whole, whole), (bwd, bwd)
+    if not causal:
         return (whole, whole), (whole, whole)
 
     def tile(cap):
@@ -917,16 +835,17 @@ def _auto_tiles(S: int, causal: bool):
 def _resolve(q, S, causal, sm_scale, block_q, block_k):
     """(sm_scale, whole, forward tiles, backward tiles).  ``whole``: a grid
     step takes the whole sequence, which picks the forward's form
-    (`_fwd_rows`) and the one-kernel backward; it is decided here, outside
-    the jitted kernel calls, and reaches them as a static argument.
-    Explicit blocks override `_auto_tiles` in both passes."""
+    (`_fwd_rows`), the rows of k a step of the backward takes (all, or a k
+    tile) and the lane layout's backward; it is decided here, outside the
+    jitted kernel calls, and reaches them as a static argument.  Explicit
+    blocks override `_auto_tiles` in both passes."""
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     return (scale, S <= _WHOLE_SEQ_MAX) + tuple(
         (min(block_q, S) if block_q else bq, min(block_k, S) if block_k else bk)
         for bq, bk in _auto_tiles(S, causal))
 
 
-def _count_tiles(S, block_q, block_k, causal, heads, kernels=1):
+def _count_tiles(S, block_q, block_k, causal, heads):
     """Add one kernel's tiles to the job timeline, as the step is traced:
     `attention.tiles` the (block_q x block_k) tiles of the S x S score
     square, `attention.tiles_skipped` those of them wholly above the
@@ -936,13 +855,13 @@ def _count_tiles(S, block_q, block_k, causal, heads, kernels=1):
     from HBM (`attention.q_heads`, `attention.kv_heads`): a quarter where
     four query heads share a key/value head, equal where a caller repeated
     k and v first."""
-    tracing.count("attention.q_heads", kernels * heads[0])
-    tracing.count("attention.kv_heads", kernels * heads[1])
+    tracing.count("attention.q_heads", heads[0])
+    tracing.count("attention.kv_heads", heads[1])
     num_q, num_k = S // block_q, S // block_k
     skipped = sum(num_k - min(num_k, pl.cdiv((i + 1) * block_q, block_k))
                   for i in range(num_q)) if causal else 0
-    tracing.count("attention.tiles", kernels * num_q * num_k)
-    tracing.count("attention.tiles_skipped", kernels * skipped)
+    tracing.count("attention.tiles", num_q * num_k)
+    tracing.count("attention.tiles_skipped", skipped)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -952,11 +871,15 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
     and v may have fewer heads than q, a divisor of its count (query head h
     reads key/value head h // group).
 
-    Blocks the call does not name come from `_auto_tiles`; the grid dims
-    are marked parallel for Mosaic.  Up to `_WHOLE_SEQ_MAX` the
-    backward is one kernel tiled inside (5 dots a tile instead of 7); the
-    two-kernel backward past it caps its own tiles
-    (`_SPLIT_BWD_MAX_BLOCK`)."""
+    Blocks the call does not name come from `_auto_tiles`.  The backward
+    is one kernel (5 dots a tile instead of a split's 7): up to
+    `_WHOLE_SEQ_MAX` a grid step takes a (b, h) slice whole, tiled inside;
+    past it a grid step takes one k tile and dq sums over a slice's tiles
+    in VMEM, which holds the slice's q, do, statistics and dq meanwhile
+    (`_bwd_held_bytes`: 4 to 5.5 KB a row, so S = 16,384 fits at every
+    width a cell has; S = 32,768 does not, where the split compiled, and
+    its backward takes the reference under an `AttentionFallbackWarning`:
+    `_tiling_problem`)."""
     o, _ = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k)
     return o
 
@@ -1024,24 +947,23 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, do, delta=None):
     S = q.shape[2]
     scale, whole, _, (bq, bk) = _resolve(q, S, causal, sm_scale, block_q,
                                          block_k)
-    if not whole:
-        bq, bk = min(bq, _SPLIT_BWD_MAX_BLOCK), min(bk, _SPLIT_BWD_MAX_BLOCK)
     # delta = rowsum(do * o): cheap elementwise+reduce, XLA fuses it.
     # Callers looping over K/V chunks (ring attention) pass it precomputed
     # — it only depends on the q side, so per-chunk recompute is waste.
     if delta is None:
         delta = jnp.sum(
             do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    problem = _tiling_problem(S, bq, bk)
+    held = 0 if whole else _bwd_held_bytes(S, q.shape[-1], v.shape[-1],
+                                           q.dtype)
+    problem = _tiling_problem(S, bq, bk, held)
     if problem:
         _warn_reference(q.shape, bq, bk, problem)
         return _reference_backward(q, k, v, lse, do, delta, scale, causal)
-    _count_tiles(S, bq, bk, causal, (q.shape[1], k.shape[1]),
-                 kernels=1 if whole else 2)
+    _count_tiles(S, bq, bk, causal, (q.shape[1], k.shape[1]))
 
     def kernel(q, k, v, o, lse, do, delta, interpret):
         return _pallas_backward(q, k, v, o, lse, do, scale, causal, bq, bk,
-                                whole, interpret, delta=delta)
+                                S if whole else bk, interpret, delta=delta)
 
     def reference(q, k, v, o, lse, do, delta):
         return _reference_backward(q, k, v, lse, do, delta, scale, causal)
@@ -1070,7 +992,7 @@ def flash_attention_bshd(q, k, v, causal=False, sm_scale=None,
 
     When the lane tiling applies (head_dim divides 128, heads fill whole
     lane blocks, k and v have q's heads; for the backward S <=
-    `_WHOLE_SEQ_MAX`) the kernels
+    `_WHOLE_SEQ_MAX`: the long backward is head-major only) the kernels
     index heads through 128-wide lane blocks and no
     (B,S,H,D) <-> (B,H,S,D) transpose ever materializes; otherwise the
     call transposes to the bhsd kernels (still flash, just with the

@@ -19,9 +19,13 @@ the single-machine analogue of the reference's fake multi-node cluster.
 
 from __future__ import annotations
 
+import errno
+import glob
 import logging
 import math
+import os
 import socket
+import sys
 import time
 from dataclasses import dataclass
 from typing import Dict, Optional
@@ -110,8 +114,6 @@ def _init_jax_distributed(coordinator: str, world_size: int, rank: int,
     # imported jax before this
     with tracing.timeline_span("train.jax_distributed_init", rank=rank,
                                world_size=world_size):
-        import os
-
         import jax
 
         # NOTE: must not touch jax.devices()/default_backend() before
@@ -143,20 +145,88 @@ def _shutdown_jax_distributed():
         pass
 
 
+# One device node a chip from v5e on (`core/worker.py:count_local_tpu_chips`
+# counts the same ones), and how long a granted worker waits for one that
+# another process is still giving back.
+_VFIO_NODES = "/dev/vfio/[0-9]*"
+_CHIP_BUSY_LIMIT_S = 60.0
+_CHIP_BUSY_POLL_S = 0.25
+
+
+def _granted_chip_nodes():
+    """The device nodes this worker's libtpu is about to open: those of
+    `TPU_VISIBLE_CHIPS` where the raylet granted part of the host (chip i
+    is the i-th node in numeric order), else every one.  None on a host
+    whose chips are `/dev/accel<N>` (up to v4) or that has no TPU."""
+    if glob.glob("/dev/accel[0-9]*"):
+        return []
+    nodes = sorted((n for n in glob.glob(_VFIO_NODES)
+                    if os.path.basename(n).isdigit()),
+                   key=lambda n: int(os.path.basename(n)))
+    visible = os.environ.get("TPU_VISIBLE_CHIPS", "")
+    try:
+        return [nodes[int(i)] for i in visible.split(",")] if visible \
+            else nodes
+    except (ValueError, IndexError):
+        return nodes
+
+
+def _chip_node_busy(node: str) -> bool:
+    """Whether another process holds the node: it is opened and closed at
+    once.  Any other refusal is libtpu's to report."""
+    try:
+        os.close(os.open(node, os.O_RDWR))
+    except OSError as e:
+        return e.errno == errno.EBUSY
+    return False
+
+
+def _wait_for_chips(granted: int):
+    """Make sure the grant's device nodes can be opened before libtpu
+    tries: a node opens for one process at a time, and the worker of a job
+    that has just ended (SIGKILLed, or of a program that did not wait for
+    it) holds its nodes for seconds more while the kernel takes back the
+    chips' mappings.  libtpu fails on the first busy node and jax's backend
+    does not start a second time in one process, so each node is tried
+    here first and a busy one waited for."""
+    nodes = _granted_chip_nodes()
+    if not nodes:
+        return
+    start = time.monotonic()
+    tries = 0
+    with tracing.timeline_span("train.chip_wait", granted=granted,
+                               nodes=len(nodes)) as sp:
+        for node in nodes:
+            while _chip_node_busy(node):
+                waited = time.monotonic() - start
+                if waited >= _CHIP_BUSY_LIMIT_S:
+                    sp.set_attrs(tries=tries, waited_s=waited)
+                    raise RuntimeError(
+                        f"training worker was granted TPU: {granted} but "
+                        f"open({node}) still answers 'Device or resource "
+                        f"busy' after {waited:.0f} s: another process holds "
+                        "the chip")
+                tries += 1
+                time.sleep(_CHIP_BUSY_POLL_S)
+        sp.set_attrs(tries=tries, waited_s=time.monotonic() - start)
+    tracing.count("train.chip_busy_retries", tries)
+
+
 def _check_granted_chips(granted: int):
     """Runs inside a training worker that was granted TPU chips: it must
     see platform ``tpu`` and exactly the chips of its grant, or the group
     does not start — a worker that was promised a chip and has none would
-    otherwise train on the CPU and report success."""
-    import os
-    import sys
-
+    otherwise train on the CPU and report success.  A chip that the worker
+    before it is still letting go is waited for (`_wait_for_chips`), not
+    died on."""
     # paid here unless the worker imported jax when it started (it does
     # where `ensure_compile_cache` has to place the cache itself)
     with tracing.timeline_span("train.jax_import",
                                imported="jax" in sys.modules):
         import jax
 
+    # after the import, which gives a leaving worker seconds more
+    _wait_for_chips(granted)
     # the first look at the devices: libtpu opens the chips here
     with tracing.timeline_span("train.chip_claim", granted=granted) as sp:
         devices = jax.local_devices()

@@ -78,9 +78,10 @@ def _kernels(f, *args):
 def test_gqa_kernels_match_the_reference_with_kv_repeated(
         group, form, layout, causal, monkeypatch):
     """o, dq, dk, dv of the interpreted kernels, in the form a grid step
-    takes the whole sequence in and in the split one (`_WHOLE_SEQ_MAX`
+    takes the whole sequence in and in the long one (`_WHOLE_SEQ_MAX`
     lowered so that an interpretable size passes it: q tiles looping over
-    k blocks, the two-kernel backward), through the (B, S, H, D) entry (the
+    k blocks forward, k tiles looping over q blocks in the one backward
+    kernel), through the (B, S, H, D) entry (the
     lane layout where the heads are equal, head-major where they are not)
     and the (B, H, S, D) one, at H / H_kv of 1, 4 and 8."""
     if form == "long":
@@ -186,23 +187,31 @@ def _normalised_jaxpr(f, *args):
 # (B, S, H, D) once, for the result and the residual alike, where the parent
 # wrote the same transpose twice (XLA merged them): one equation fewer; and
 # up to `_WHOLE_SEQ_MAX` (XL) lse is named in the kernels' own (B*H, S, 1),
-# two reshapes that cancel.
+# two reshapes that cancel.  PR 45 made the backward past `_WHOLE_SEQ_MAX`
+# one kernel: the three long shapes' gradients are that PR's, and the last
+# hash is of their FORWARD alone, which is still the parent's (taken on
+# fbcf05f).
 PARENT = {
-    "gpt2-medium": ((16, 1024, 16, 64), None, False, "58052d16e0f69720"),
-    "gpt2-xl a chip": ((4, 1024, 25, 64), None, False, "46a839a5b15db2c7"),
-    "olmoe": ((4, 4096, 16, 128), None, False, "fe169d92c65f1afc"),
-    "kanana": ((2, 8192, 32, 192), 128, True, "0309beacccb11756"),
+    "gpt2-medium": ((16, 1024, 16, 64), None, False, "58052d16e0f69720",
+                    "ba812a7dd7dc3012"),
+    "gpt2-xl a chip": ((4, 1024, 25, 64), None, False, "46a839a5b15db2c7",
+                       "6fc25eb3b821d90b"),
+    "olmoe": ((4, 4096, 16, 128), None, False, "e89b7c629b743aaf",
+              "08228a281a8c16ae"),
+    "kanana": ((2, 8192, 32, 192), 128, True, "57e8b2a48718df99",
+               "46beb62f7c8cade5"),
     "lanes, long forward": ((2, 2048, 32, 64), None, False,
-                            "d427a24dd632e38b"),
+                            "7889c1a6dc334d4e", "8c506a32eee08969"),
 }
 
 
 @pytest.mark.parametrize("name", sorted(PARENT))
 def test_equal_heads_lower_to_the_jaxpr_they_lowered_to(name, monkeypatch):
     """`H_kv == H`: every `pallas_call`, index map and shape is the
-    parent's, so the five accepted cells run the parent's kernels."""
+    parent's, so the accepted cells run the parent's kernels: both passes
+    up to `_WHOLE_SEQ_MAX`, the forward past it."""
     monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
-    shape, v_dim, entry, want = PARENT[name]
+    shape, v_dim, entry, want, want_forward = PARENT[name]
     B, S, heads, D = shape
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     v = jax.ShapeDtypeStruct((B, S, heads, v_dim or D), jnp.bfloat16)
@@ -212,17 +221,19 @@ def test_equal_heads_lower_to_the_jaxpr_they_lowered_to(name, monkeypatch):
             else fa.flash_attention_bshd(q, k, v, True, None, None, None)
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
-    text = _normalised_jaxpr(jax.grad(loss, (0, 1, 2)), q, q, v)
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+    for f, digest in ((jax.grad(loss, (0, 1, 2)), want),
+                      (loss, want_forward)):
+        text = _normalised_jaxpr(f, q, q, v)
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
 
 
 @pytest.mark.parametrize("shape,kv_heads,kernels", [
-    ((2, 8192, 32, 64), 8, 3), ((2, 1024, 32, 64), 8, 2)])
+    ((2, 8192, 32, 64), 8, 2), ((2, 1024, 32, 64), 8, 2)])
 def test_grouped_queries_lower_to_mosaic_for_tpu(shape, kv_heads, kernels):
     """LFM2-24B-A2B's attention (32 query heads on 8 key/value heads of 64
-    at S = 8,192: the head-major forward by q tiles and the two-kernel
-    backward) exported for a TPU from this CPU host: Mosaic custom calls,
-    no interpreted kernel body and no O(S^2) reference."""
+    at S = 8,192: the head-major forward by q tiles and the one backward
+    kernel by k tiles) exported for a TPU from this CPU host: Mosaic custom
+    calls, no interpreted kernel body and no O(S^2) reference."""
     B, S, heads, D = shape
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     k = jax.ShapeDtypeStruct((B, S, kv_heads, D), jnp.bfloat16)
@@ -298,6 +309,95 @@ def test_the_kernels_count_the_heads_they_read():
     with tracing.timeline_span("train.fit", root=True):
         # whole sequence: a forward and a one-kernel backward
         assert traced((2, 1024, 32, 64), 8) == [64, 16]
-        # past `_WHOLE_SEQ_MAX`: a forward and the two kernels of the split
-        assert traced((2, 2048, 32, 64), 8) == [96, 24]
-        assert traced((2, 2048, 32, 64), 32) == [96, 96]
+        # past `_WHOLE_SEQ_MAX` the same: a forward and ONE backward kernel
+        assert traced((2, 2048, 32, 64), 8) == [64, 16]
+        assert traced((2, 2048, 32, 64), 32) == [64, 64]
+
+
+# (query heads, key/value heads, q/k width, v width, S, tile): every shape
+# stays within what a non-TPU backend interprets (`_INTERPRET_MAX_ELEMS`)
+LONG_SHAPES = {
+    "heads of 64": (2, 2, 64, 64, 256, 128),
+    "heads of 128": (2, 2, 128, 128, 256, 128),
+    "q, k 192 and v 128": (1, 1, 192, 128, 256, 128),
+    "a group of 4": (4, 1, 64, 64, 256, 128),
+    "a group of 16": (16, 1, 16, 16, 256, 128),
+    # 512 does not divide 640: `_auto_tiles` falls to the 128 that does
+    "a length 512 does not divide": (2, 2, 32, 32, 640, None),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("name", sorted(LONG_SHAPES))
+def test_the_long_backward_is_the_reference_s_and_the_whole_form_s(
+        name, causal, dtype, monkeypatch):
+    """dq, dk and dv of the one backward kernel with a slice's k tiles on
+    the grid (`_WHOLE_SEQ_MAX` lowered to 128) against `_reference_backward`
+    on the same residuals, and against the same kernel with a grid step the
+    whole slice (`_WHOLE_SEQ_MAX` as it is): one body, two walks."""
+    heads, kv_heads, D, Dv, S, tile = LONG_SHAPES[name]
+    ks = jax.random.split(jax.random.PRNGKey(S + heads), 4)
+    q = jax.random.normal(ks[0], (1, heads, S, D), dtype)
+    k = jax.random.normal(ks[1], (1, kv_heads, S, D), dtype)
+    v = jax.random.normal(ks[2], (1, kv_heads, S, Dv), dtype)
+    do = jax.random.normal(ks[3], (1, heads, S, Dv), dtype)
+    scale = D ** -0.5
+    with jax.default_matmul_precision("highest"):
+        _, res = fa._flash_fwd(q, k, v, causal, scale, tile, tile)
+        o, lse = res[3:]
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+        want = fa._reference_backward(q, k, v, lse, do, delta, scale, causal)
+        whole = fa._flash_bwd(causal, scale, tile, tile, res, do)
+        monkeypatch.setattr(fa, "_WHOLE_SEQ_MAX", 128)
+        steps = S // (tile or fa._auto_tiles(S, causal)[1][1])
+        assert steps == (2 if tile else 5)
+        with warnings.catch_warnings():
+            warnings.simplefilter("error", fa.AttentionFallbackWarning)
+            long = fa._flash_bwd(causal, scale, tile, tile, res, do)
+    tol = 2e-5 if dtype == jnp.float32 else 6e-2
+    for what, g, w, other in zip(("dq", "dk", "dv"), long, want, whole):
+        assert g.shape == w.shape and g.dtype == w.dtype == dtype, what
+        for against in (w, other):
+            err = jnp.max(jnp.abs(g.astype(jnp.float32)
+                                  - against.astype(jnp.float32)))
+            assert float(err) < tol * max(1.0, float(jnp.max(jnp.abs(
+                w.astype(jnp.float32))))), what
+
+
+def test_a_slice_that_does_not_fit_vmem_falls_back(monkeypatch):
+    """The long backward holds a (b, h) slice's q, do, statistics and dq in
+    VMEM while its k tiles pass (`_bwd_held_bytes`): S = 16,384 fits at the
+    widest heads a cell has, S = 32,768 at none, and Mosaic would refuse it
+    ("Ran out of memory in memory space vmem").  `_tiling_problem` names
+    it first and the call takes the reference with the warning every
+    untileable shape gets, with the reference's gradients."""
+    bf16 = jnp.bfloat16
+    assert fa._bwd_held_bytes(8192, 192, 128, bf16) == 8192 * 5632
+    assert fa._bwd_held_bytes(4096, 128, 128, bf16) == 4096 * 4096
+    for S, D, Dv, fits in ((16384, 192, 128, True), (16384, 64, 64, True),
+                           (32768, 64, 64, False), (32768, 128, 128, False)):
+        problem = fa._tiling_problem(S, 512, 512,
+                                     fa._bwd_held_bytes(S, D, Dv, bf16))
+        assert (problem is None) == fits, (S, D)
+        assert fits or "VMEM" in problem
+    # at an interpretable size: the chip's room lowered to under the slice's
+    monkeypatch.setattr(fa, "_WHOLE_SEQ_MAX", 128)
+    monkeypatch.setattr(fa, "_VMEM_MAX", fa._TILE_VMEM + (1 << 20))
+    q, k, v = (_tr(x) for x in _qkv(2, H=4, D=32))
+    assert fa._bwd_held_bytes(S, 32, 32, q.dtype) > 1 << 20
+
+    def grads(attend):
+        return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(attend(q, k, v))),
+                        (0, 1, 2))(q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        with pytest.warns(fa.AttentionFallbackWarning, match="VMEM") as seen:
+            got = grads(lambda q, k, v: fa.flash_attention(
+                q, k, v, True, None, 128, 128))
+        # the forward holds no slice and keeps its kernel
+        assert len(seen) == 1
+        want = grads(lambda q, k, v: fa.reference_attention(
+            q, k, v, 32 ** -0.5, True)[0])
+    for g, w in zip(got, want):
+        assert float(jnp.max(jnp.abs(g - w))) < 2e-5

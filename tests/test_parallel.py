@@ -86,9 +86,11 @@ def test_pallas_kernels_direct_multiblock(bq, bk, causal, whole):
     at S=256 with mixed block sizes, in both forms a call can take.  Not
     ``whole`` (what a sequence past `_WHOLE_SEQ_MAX` runs): the grid walks
     the forward's q tiles, each with its first_diag/last two-phase fori
-    loops over k blocks, and the backward is the two-kernel split with the
-    same loops.  ``whole``: a grid step takes the sequence, the forward
-    walks its q tiles over merged spans and the backward is one kernel."""
+    loops over k blocks, and the backward's k tiles (a grid step takes
+    ``bk`` rows of k), each with the same loops over q blocks, dq summed
+    over the k tiles in scratch.  ``whole``: a grid step takes the
+    sequence, the forward walks its q tiles and the backward its k tiles
+    over merged spans.  One backward kernel either way."""
     from ray_tpu.ops.flash_attention import (
         _pallas_backward,
         _pallas_forward,
@@ -112,7 +114,8 @@ def test_pallas_kernels_direct_multiblock(bq, bk, causal, whole):
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     do = (2.0 * o).astype(q.dtype)  # d/do of sum(o^2)
     dq, dk, dv = _pallas_backward(q, k, v, o, lse, do, scale, causal,
-                                  bq, bk, whole=whole, interpret=True)
+                                  bq, bk, k_rows=S if whole else bk,
+                                  interpret=True)
     for a, b, name in zip((dq, dk, dv), gr, ("dq", "dk", "dv")):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=5e-2,
@@ -351,13 +354,13 @@ def test_flash_attention_bshd_lane_path(H, D, causal):
 
 # (B, S, H, D) -> Mosaic kernels in forward + backward: the lane kernels
 # with the one-kernel backward, the transposing bhsd kernels where heads do
-# not pair up into 128 lanes (25, 3), and past _WHOLE_SEQ_MAX the dq and
-# dk/dv kernels of the two-kernel backward; last, the medium cell's own
-# shape with 256-tiles forced in both passes
+# not pair up into 128 lanes (25, 3), and past _WHOLE_SEQ_MAX the forward
+# over q tiles and the one backward kernel over k tiles; last, the medium
+# cell's own shape with 256-tiles forced in both passes
 @pytest.mark.parametrize("shape,kernels,block", [
     ((16, 1024, 12, 64), 2, None), ((8, 1024, 16, 64), 2, None),
     ((4, 1024, 25, 64), 2, None), ((16, 1024, 3, 64), 2, None),
-    ((2, 2048, 32, 128), 3, None), ((16, 1024, 16, 64), 2, 256)])
+    ((2, 2048, 32, 128), 2, None), ((16, 1024, 16, 64), 2, 256)])
 def test_flash_attention_lowers_to_mosaic_for_tpu(shape, kernels, block):
     """Exported for a TPU from this CPU host, forward + backward are Mosaic
     custom calls and nothing else: no interpreted kernel body and no O(S^2)
@@ -407,10 +410,11 @@ def test_flash_attention_fused_bwd_mixed_dtypes():
     assert dv.dtype == jnp.bfloat16
 
 
-def _pallas_kernels(jaxpr, found=None):
-    """{kernel function's name: its body holds a loop} over the
-    `pallas_call`s of a jaxpr, nested ones (jit, the platform's branches,
-    custom_vjp) included."""
+def _pallas_calls(jaxpr):
+    """The `pallas_call` equations of a jaxpr, nested ones (jit, the
+    platform's branches, custom_vjp) included."""
+    from jax.extend import core as jex_core
+
     def subjaxprs(value):
         if isinstance(value, jex_core.ClosedJaxpr):
             yield value.jaxpr
@@ -420,19 +424,24 @@ def _pallas_kernels(jaxpr, found=None):
             for item in value:
                 yield from subjaxprs(item)
 
-    from jax.extend import core as jex_core
-
-    found = {} if found is None else found
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            name = eqn.params["jaxpr"].debug_info.func_name
-            # fori_loop: `while` under traced bounds, `scan` under static
-            body = str(eqn.params["jaxpr"])
-            found[name] = (found.get(name, False) or "while[" in body
-                           or "scan[" in body)
+            yield eqn
         for value in eqn.params.values():
             for sub in subjaxprs(value):
-                _pallas_kernels(sub, found)
+                yield from _pallas_calls(sub)
+
+
+def _pallas_kernels(jaxpr):
+    """{kernel function's name: its body holds a loop} over the
+    `pallas_call`s of a jaxpr (`_pallas_calls`)."""
+    found = {}
+    for eqn in _pallas_calls(jaxpr):
+        name = eqn.params["jaxpr"].debug_info.func_name
+        # fori_loop: `while` under traced bounds, `scan` under static
+        body = str(eqn.params["jaxpr"])
+        found[name] = (found.get(name, False) or "while[" in body
+                       or "scan[" in body)
     return found
 
 
@@ -469,7 +478,8 @@ def _bshd_against_reference(q, k, v, causal, block_q, block_k, kernels):
 # {kernel: its body loops} of a gradient in the two forms (`_pallas_kernels`)
 _WHOLE_LANES = {"_fwd_kernel_lanes": False, "_bwd_fused_kernel_lanes": False}
 _WHOLE_BHSD = {"_fwd_kernel": False, "_bwd_fused_kernel": False}
-_LOOPED_SPLIT = {"_bwd_dq_kernel": True, "_bwd_dkv_kernel": True}
+# past `_WHOLE_SEQ_MAX`: the same head-major kernel, a k tile a grid step
+_LOOPED_FUSED = {"_bwd_fused_kernel": True}
 
 
 def _bshd_qkv(S, H, D, dtype=jnp.float32):
@@ -520,16 +530,239 @@ def test_flash_attention_tiled_mixed_dtypes():
 def test_flash_attention_long_sequence_forms(H, D, causal, monkeypatch):
     """Past `_WHOLE_SEQ_MAX` (lowered here so that an interpretable size
     passes it) the grid walks the forward's q tiles, each looping over its
-    k blocks, and the backward is the two-kernel split: three kernels with
-    loops, whatever an earlier test traced at these shapes (the form is a
-    static argument of the jitted kernel calls)."""
+    k blocks, and the backward's k tiles, each looping over its q blocks:
+    two kernels with loops (the lane layout declines the long backward,
+    which goes head-major), whatever an earlier test traced at these
+    shapes (what a grid step takes is a static argument of the jitted
+    kernel calls)."""
     from ray_tpu.ops import flash_attention as fa
 
     monkeypatch.setattr(fa, "_WHOLE_SEQ_MAX", 128)
     q, k, v = _bshd_qkv(256, H, D)
     forward = "_fwd_kernel" if H == 3 else "_fwd_kernel_lanes"
     _bshd_against_reference(q, k, v, causal, 128, 128,
-                            {forward: True, **_LOOPED_SPLIT})
+                            {forward: True, **_LOOPED_FUSED})
+
+
+@pytest.mark.parametrize("bq,bk", [(128, 128), (256, 128), (128, 256)])
+@pytest.mark.parametrize("causal", [True, False])
+def test_long_backward_sums_dq_over_its_k_tiles(bq, bk, causal, monkeypatch):
+    """S = 512 past a lowered `_WHOLE_SEQ_MAX`: a (b, h) slice's backward
+    is four grid steps (two at 256), each adding its k tile's part of dq
+    into the f32 scratch, which is written once at the last.  dq, dk and dv
+    in float32 against the reference's, tightly: a part dropped, doubled or
+    written early would be a whole tile's worth off."""
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_WHOLE_SEQ_MAX", 128)
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in _bshd_qkv(512, 3, 32))
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(q, k, v) ** 2)
+
+    flash = loss(lambda q, k, v: flash_attention(q, k, v, causal, None,
+                                                 bq, bk))
+    dense = loss(lambda q, k, v: reference_attention(
+        q, k, v, 32 ** -0.5, causal)[0])
+    assert _pallas_kernels(jax.make_jaxpr(jax.grad(flash, (0, 1, 2)))(
+        q, k, v).jaxpr) == {"_fwd_kernel": True, **_LOOPED_FUSED}
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(flash, (0, 1, 2))(q, k, v)
+        want = jax.grad(dense, (0, 1, 2))(q, k, v)
+    for name, g, w in zip(("dq", "dk", "dv"), got, want):
+        assert float(jnp.max(jnp.abs(g - w))) < 2e-5, name
+
+
+@pytest.mark.parametrize("group", [1, 2])
+def test_long_backward_takes_the_ring_s_delta(group, monkeypatch):
+    """What `ring_attention` does with a rotating chunk: the backward of
+    one chunk of k and v under the lse and delta of the WHOLE row
+    (`_flash_bwd(..., delta=)`: o here is not this chunk's output, so the
+    kernel may not make delta from it), a causal chunk and a non-causal
+    one, in the long form.  dq summed over the chunks and dk, dv side by
+    side are the gradients of attention over both."""
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_WHOLE_SEQ_MAX", 128)
+    S, H, D = 256, 2, 32
+    q, _, _ = (x.transpose(0, 2, 1, 3) for x in _bshd_qkv(S, H, D))
+    k, v = (x.transpose(0, 2, 1, 3)[:, :H // group]
+            for x in _bshd_qkv(2 * S, H, D)[1:])
+    scale = D ** -0.5
+
+    def whole_row(q, k, v):
+        # the second half of a causal sequence's rows: every key of the
+        # first chunk, the keys up to its own position of the second
+        kr, vr = (jnp.repeat(x, group, axis=1) for x in (k, v))
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, kr) * scale
+        mask = jnp.arange(S)[:, None] + S >= jnp.arange(2 * S)[None, :]
+        s = jnp.where(mask, s, -1e30)
+        lse = jax.scipy.special.logsumexp(s, axis=-1)
+        return jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]),
+                          vr), lse
+
+    with jax.default_matmul_precision("highest"):
+        (o, lse), vjp = jax.vjp(whole_row, q, k, v)
+        do = 2.0 * o
+        want = vjp((do, jnp.zeros_like(lse)))
+        delta = jnp.sum(do * o, axis=-1)
+        parts = [fa._flash_bwd(causal, scale, 128, 128,
+                               (q, k[:, :, rows], v[:, :, rows], o, lse), do,
+                               delta=delta)
+                 for causal, rows in ((False, slice(0, S)),
+                                      (True, slice(S, 2 * S)))]
+    got = (parts[0][0] + parts[1][0],
+           jnp.concatenate([parts[0][1], parts[1][1]], axis=2),
+           jnp.concatenate([parts[0][2], parts[1][2]], axis=2))
+    for name, g, w in zip(("dq", "dk", "dv"), got, want):
+        assert g.shape == w.shape, name
+        assert float(jnp.max(jnp.abs(g - w))) < 2e-5, name
+
+
+def _backward_call(S, causal, block=128):
+    """The `pallas_call` equation of `flash_attention`'s backward at
+    (1, 2, S, 32) with square tiles of ``block``."""
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal, None, block, block))
+
+    x = jax.ShapeDtypeStruct((1, 2, S, 32), jnp.float32)
+    found = [eqn for eqn in _pallas_calls(jax.make_jaxpr(
+        jax.grad(loss, (0, 1, 2)))(x, x, x).jaxpr)
+        if eqn.params["jaxpr"].debug_info.func_name == "_bwd_fused_kernel"]
+    # the platform's two branches (compiled, interpreted) hold the same call
+    assert len({str(eqn.params["jaxpr"]) for eqn in found}) == 1
+    return found[0]
+
+
+def _score_rows(call):
+    """The rows of every (rows, block_k) score tile a backward kernel's
+    body makes: the first operand's of each product contracted over the
+    head's width."""
+    import re
+
+    return sorted({int(rows) for rows in re.findall(
+        r"= dot_general\[\s*dimension_numbers=\(\(\[1\], \[1\]\)"
+        r".*?f32\[(\d+),32\]", str(call.params["jaxpr"]), re.S)})
+
+
+@pytest.mark.parametrize("S,steps", [(256, 1), (512, 4)])
+@pytest.mark.parametrize("causal", [True, False])
+def test_the_backward_s_k_tiles_walk_on_either_side_of_the_gate(
+        S, steps, causal, monkeypatch):
+    """The two sides of `_WHOLE_SEQ_MAX` (lowered to 256) in the ONE
+    backward kernel.  Up to it a grid step holds the (b, h) slice's k, its
+    tiles are unrolled and nothing loops or branches, and the q tiles of a
+    kind are merged into one span: S rows at most, which is the spans' cap
+    (a non-causal k tile scores all 256 rows at once; a causal one its
+    diagonal tile's 128, and the first also the 128 below).  Past it the k
+    tiles are the grid's second axis, last to first, each loops over q
+    tiles of 128 rows, never a span of more, and dq is zeroed and written
+    under `pl.when`."""
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_WHOLE_SEQ_MAX", 256)
+    call = _backward_call(S, causal)
+    body = str(call.params["jaxpr"])
+    grid = call.params["grid_mapping"].grid
+    assert tuple(grid) == (2, steps)
+    looped = "while[" in body or "scan[" in body
+    assert looped == (steps > 1)
+    assert ("cond[" in body) == (steps > 1)
+    assert ("program_id" in body) == (steps > 1)
+    if steps == 1:
+        assert _score_rows(call) == ([128] if causal else [256])
+    else:
+        assert _score_rows(call) == [128]
+    # k, v, dk and dv blocks: the whole sequence, or one tile of it
+    held = [int(getattr(spec.block_shape[1], "block_size",
+                        spec.block_shape[1]))
+            for spec in call.params["grid_mapping"].block_mappings]
+    k_rows = S if steps == 1 else 128
+    #               q  k       v       do lse delta dq dk      dv
+    assert held == [S, k_rows, k_rows, S, S, S, S, k_rows, k_rows]
+
+
+def test_the_long_backward_s_k_tiles_pass_last_to_first():
+    """`_kv_rows`: grid step i of a slice takes k tile ``last - i`` of the
+    key/value head its query head reads (a causal slice's longest step, its
+    first k tile's, comes last and hides the next slice's fetch); with the
+    whole sequence a step the index maps are the ones they were."""
+    from ray_tpu.ops import flash_attention as fa
+
+    assert [fa._kv_rows(1, 3)(5, i) for i in range(4)] == [
+        (5, 3, 0), (5, 2, 0), (5, 1, 0), (5, 0, 0)]
+    assert [fa._kv_rows(4, 3)(5, i) for i in (0, 3)] == [(1, 3, 0), (1, 0, 0)]
+    assert fa._kv_rows(1)(5, 0) == (5, 0, 0)
+    assert fa._kv_rows(4)(5, 0) == (1, 0, 0)
+
+
+@pytest.mark.parametrize("S,steps,limit", [
+    # a step a slice: the default, as the kernels up to 1,024 always had
+    (1024, 1, None),
+    # k tiles on the grid: 16 MB + what the slice holds meanwhile, 4 KB a
+    # row at D = 128 and 5.5 at 192 / 128 (q, k and dq take 256 lanes)
+    (4096, 8, (16 << 20) + 4096 * 4096),
+    (8192, 16, (16 << 20) + 8192 * 4096),
+    (16384, 32, (16 << 20) + 16384 * 4096),
+    # past the v5e's room: the limit stops at 100 MB; Mosaic would refuse
+    # it, and `_tiling_problem` hands such a call to the reference first
+    (32768, 64, 100 << 20),
+])
+def test_the_backward_s_vmem_budget_on_either_side_of_its_gates(
+        S, steps, limit):
+    from ray_tpu.ops import flash_attention as fa
+
+    params = fa._compiler_params(S, 128, 128, jnp.bfloat16, bwd_steps=steps)
+    if limit is None:
+        assert params is fa._COMPILER_PARAMS
+        return
+    assert params.vmem_limit_bytes == limit
+    assert params.dimension_semantics == ("parallel", "arbitrary")
+    wide = fa._compiler_params(S, 192, 128, jnp.bfloat16, bwd_steps=steps)
+    assert wide.vmem_limit_bytes == min(100 << 20, (16 << 20) + S * 5632)
+
+
+def test_xl_s_own_backward_under_a_checkpointed_layer(monkeypatch):
+    """The XL cell's form of the kernels at an interpretable size: 25 heads
+    of 64 (no lane block: the head-major kernels through the (B, S, H, D)
+    entry's transposes), a grid step a (b, h) slice with two tiles a side,
+    under `checkpoint_layer`, which keeps o and the row statistics in the
+    kernels' own (B*H, S, 1) so that the replay runs no forward kernel.
+    Gradients against the dense reference's."""
+    from ray_tpu.models.layers import checkpoint_layer
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_INTERPRET_MAX_ELEMS", 1 << 20)
+    B, S, H, D = 1, 256, 25, 64
+    q, k, v = (x.astype(jnp.bfloat16) for x in _bshd_qkv(S, H, D))
+    tr = lambda x: x.transpose(0, 2, 1, 3)
+
+    def flash_layer(q, k, v):
+        o = fa.flash_attention_bshd(q, k, v, True, None, 128, 128)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    def dense_layer(q, k, v):
+        o, _ = reference_attention(tr(q), tr(k), tr(v), D ** -0.5, True)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    layer = checkpoint_layer(flash_layer)
+    jaxpr = jax.make_jaxpr(jax.grad(layer, (0, 1, 2)))(q, k, v)
+    assert _pallas_kernels(jaxpr.jaxpr) == _WHOLE_BHSD
+    # what the layer keeps besides its arguments: o as the caller has it,
+    # and lse a row a position, as the kernels write and read it
+    from jax._src.ad_checkpoint import saved_residuals
+
+    kept = sorted(tuple(x.shape) for x, where in
+                  saved_residuals(layer, q, k, v)
+                  if "from the argument" not in where)
+    assert kept == [(B, S, H, D), (B * H, S, 1)]
+    got = jax.jit(jax.grad(layer, (0, 1, 2)))(q, k, v)
+    want = jax.grad(dense_layer, (0, 1, 2))(q, k, v)
+    for name, g, w in zip(("dq", "dk", "dv"), got, want):
+        assert g.dtype == w.dtype == jnp.bfloat16, name
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(w, np.float32), atol=5e-2,
+            err_msg=name)
 
 
 def test_flash_attention_counts_its_tiles():

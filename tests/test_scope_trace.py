@@ -16,7 +16,10 @@ DATA = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark", "tests", "data")
 RECORDED = ("tpu1", "tpu4", "tpu1_olmoe", "tpu1_deepseek_v3",
             "tpu1_lfm2_moe")
-VOCABULARY = tuple(layers.SCOPES)
+# the program's scopes, and the two of the split backward that the recorded
+# programs (PR 36's) still had: the reader is tested on their names too
+VOCABULARY = (*layers.SCOPES, "attention/kernel/bwd_dq",
+              "attention/kernel/bwd_dkv")
 
 
 def recorded(name: str) -> str:
@@ -255,10 +258,10 @@ def hand_made() -> bytes:
         operation(1, "%fusion.1 = bf16[8,8]{1,0} fusion(%p), kind=kOutput",
                   "jit(train_step)/jvp(ffn)/dense/dot_general:")
         + operation(2, "%conditional.1 = bf16[8,8]{1,0} conditional(%p)")
-        + operation(3, "%bwd_dq.1 = bf16[8,8]{1,0} custom-call(%p), "
+        + operation(3, "%bwd_fused.1 = bf16[8,8]{1,0} custom-call(%p), "
                        'custom_call_target="tpu_custom_call"',
                     "jit(train_step)/transpose(jvp(attention))/kernel/"
-                    "jit(_pallas_backward)/bwd_dq/pallas_call:")
+                    "jit(_pallas_backward)/bwd_fused/pallas_call:")
         + operation(4, "%copy.1 = bf16[8,8]{1,0} copy(%p)")
         + operation(5, "%fusion.2 = f32[8]{0} fusion(%p), kind=kLoop",
                     ref=8))
@@ -283,7 +286,7 @@ def test_hand_made_xspace(tmp_path):
         ("jit(train_step)/jvp(ffn)/dense/dot_general:", 1000.0, 1100.0),
         ("", 1100.0, 1400.0),
         ("jit(train_step)/transpose(jvp(attention))/kernel/"
-         "jit(_pallas_backward)/bwd_dq/pallas_call:", 1150.0, 1350.0),
+         "jit(_pallas_backward)/bwd_fused/pallas_call:", 1150.0, 1350.0),
         ("", 1400.0, 1450.0),
         ("jit(train_step)/optimizer_update/add:", 1500.0, 1600.0)]
     assert line[0][0][0].startswith("%fusion.1 = bf16[8,8]")
@@ -301,10 +304,10 @@ def test_hand_made_xspace(tmp_path):
         "unnamed": 150 * ns})
     assert found["scopes"] == pytest.approx({
         "ffn": 100 * ns, "ffn/dense": 100 * ns, "attention": 200 * ns,
-        "attention/kernel": 200 * ns, "attention/kernel/bwd_dq": 200 * ns,
+        "attention/kernel": 200 * ns, "attention/kernel/bwd_fused": 200 * ns,
         "optimizer_update": 100 * ns})
     assert found["named_s"] == pytest.approx(400 * ns)
-    assert found["in_scope"]["attention/kernel/bwd_dq"] == pytest.approx(
+    assert found["in_scope"]["attention/kernel/bwd_fused"] == pytest.approx(
         {"bwd": 200 * ns})
     assert [op for op, _ in found["unnamed_ops"]] == [
         "conditional_bf16_8_8_", "copy_bf16_8_8_"]

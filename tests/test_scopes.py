@@ -2,7 +2,7 @@
 model: in the lowered step's text with debug info, every matmul, grouped
 matmul, Mosaic kernel and convolution has an `op_name` under a scope of the
 vocabulary, no other scope appears, `rematted_computation` appears exactly
-when `remat` is on, and the flash kernels' six forms are told apart.
+when `remat` is on, and the flash kernels' four forms are told apart.
 
 The step is lowered for the platform `tpu` (no chip needed: the Mosaic
 kernels become `tpu_custom_call`s at lowering time), so the text holds what
@@ -226,14 +226,32 @@ def _kernel_names(fn, *shapes, dtype=jnp.bfloat16):
 @pytest.mark.parametrize("fn,shape,forms", [
     # head-major, a grid step the whole sequence: the one-kernel backward
     (fa.flash_attention, (1, 2, 256, 64), ("fwd_rows", "bwd_fused")),
-    # head-major past `_WHOLE_SEQ_MAX`: the split backward
-    (fa.flash_attention, (1, 2, 2048, 64),
-     ("fwd_rows", "bwd_dq", "bwd_dkv")),
+    # head-major past `_WHOLE_SEQ_MAX`: the same two, a tile a grid step
+    (fa.flash_attention, (1, 2, 2048, 64), ("fwd_rows", "bwd_fused")),
     # (B, S, H, D) with two heads to a lane block: the lane layout
     (fa.flash_attention_bshd, (1, 256, 4, 64),
      ("fwd_lanes", "bwd_fused_lanes")),
+    # the lane layout past `_WHOLE_SEQ_MAX`: its forward, and the
+    # head-major backward it hands the long sequence to
+    (fa.flash_attention_bshd, (1, 2048, 4, 64), ("fwd_lanes", "bwd_fused")),
 ])
-def test_the_six_kernel_forms_are_told_apart(fn, shape, forms):
+def test_the_four_kernel_forms_are_told_apart(fn, shape, forms):
+    """One `pallas_call` a pass on either side of `_WHOLE_SEQ_MAX`, in
+    both layouts: the backward is one kernel at every length."""
     causal = functools.partial(fn, causal=True)
     assert _kernel_names(causal, shape, shape, shape) == sorted(
         f"attention/kernel/{form}" for form in forms)
+
+
+def test_no_split_backward_remains():
+    """PR 45 deleted the dq and dk/dv kernels of the long backward: no
+    form, scope, kernel body or gate of theirs is left in the program."""
+    import inspect
+
+    assert fa.KERNEL_FORMS == ("fwd_rows", "fwd_lanes", "bwd_fused",
+                               "bwd_fused_lanes")
+    assert [s for s in layers.SCOPES if s.startswith("attention/kernel/")] \
+        == [f"attention/kernel/{form}" for form in fa.KERNEL_FORMS]
+    source = inspect.getsource(fa) + inspect.getsource(layers)
+    for gone in ("bwd_dq", "bwd_dkv", "_SPLIT_BWD_MAX_BLOCK"):
+        assert gone not in source, gone

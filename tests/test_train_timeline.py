@@ -14,7 +14,7 @@ from ray_tpu.util import trace_analysis, tracing
 
 SETUP_SPANS = ("train.placement", "train.workers_up", "raylet.worker_spawn",
                "train.jax_distributed_init", "train.jax_import",
-               "train.chip_claim", "train.start_session")
+               "train.chip_wait", "train.chip_claim", "train.start_session")
 
 
 @pytest.fixture(scope="module")

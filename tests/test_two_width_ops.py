@@ -74,7 +74,7 @@ def test_attention_with_two_widths_matches_the_reference(
     width), in the form a grid step takes the whole sequence in (S <=
     `_WHOLE_SEQ_MAX`) and in the long one (lowered here so that an
     interpretable size passes it: q tiles looping over k blocks, the
-    two-kernel backward)."""
+    one backward kernel's k tiles looping over q blocks)."""
     D, Dv = dims
     if form == "long":
         monkeypatch.setattr(fa, "_WHOLE_SEQ_MAX", 128)
@@ -126,9 +126,16 @@ def test_equal_widths_get_what_they_got():
     assert raised.vmem_limit_bytes == (16 + 4 * 12) << 20
     assert raised.dimension_semantics == \
         fa._COMPILER_PARAMS.dimension_semantics
+    # the backward past `_WHOLE_SEQ_MAX` holds a slice's q, do, statistics,
+    # dq and its scratch while the k tiles pass, in order: 5.5 KB a row
+    long = fa._compiler_params(8192, 192, 128, jnp.bfloat16,
+                               bwd_steps=16)
+    assert long.vmem_limit_bytes == (16 << 20) + 8192 * 5632
+    assert long.dimension_semantics == ("parallel", "arbitrary")
     # the tiles are a function of S and causal alone, as before
     assert fa._auto_tiles(1024, True) == ((512, 512), (256, 256))
-    assert fa._auto_tiles(4096, True) == ((1024, 1024), (1024, 1024))
+    assert fa._auto_tiles(4096, True) == ((1024, 1024), (512, 512))
+    assert fa._auto_tiles(8192, False) == ((1024, 1024), (512, 512))
 
 
 def _experts(n=8, e=16, w=8):

@@ -9,16 +9,22 @@ reference, with the device time of its forward and of its backward.
 One case on each side of the gates in ``ops/flash_attention.py``: the lane
 kernels with the one-kernel backward (GPT-2 124M's heads), the transposing
 bhsd kernels with the one-kernel backward (25 heads: no lane tiling; XL's
-share of a batch on one chip), the two-kernel backward past
-``_WHOLE_SEQ_MAX`` (S=2048), OLMoE's shape (S=4096, D=128), and latent
+share of a batch on one chip), the long form past ``_WHOLE_SEQ_MAX``
+(S=2048: a forward over q tiles and the one-kernel backward over k tiles),
+OLMoE's shape (S=4096, D=128), and latent
 attention's two widths at S=8192 (a fifth number in a shape is v's width:
 q and k 192, v 128), and grouped queries at S=8192 (`gqa_8k`: 32 query
 heads on 8 key/value heads of 64, `KV_HEADS`; beside it the same call with
 k and v repeated to 32 heads first; `gqa16_8k`: 32 query heads on 2
 key/value heads of 128, a group of 16).  This process holds the chip, so run it alone.  Exits non-zero unless every case
 ran as compiled Mosaic kernels on a TPU and agrees with
-``reference_attention``.  ``--sweep`` times forced square tiles instead
-(what ``_auto_tiles`` is set from) and compares nothing.
+``reference_attention``.  ``--sweep`` times forced tiles instead (what
+``_auto_tiles`` is set from) and compares nothing: square ones, and past
+``_WHOLE_SEQ_MAX`` also a q tile of another size than the backward's k tile
+(``LONG_TILES``; on a v5e at PR 44 (the kernels of PR 45) the one-kernel backward read, ms a layer
+at 256 / 512 / 1,024: OLMoE's shape 7.10 / 4.83 / 5.07, ``mla-8k`` 34.53 /
+28.12 / 27.99, ``gqa-8k`` 27.07 / 17.62 / 17.59, ``gqa16-8k`` 26.78 / 17.56
+/ 17.57, and no mixed pair beat square 512 by more than 0.3 %).
 
 ``moe_held_8k`` is no attention case: one routed layer of kanana's share
 (`ops/moe.py`: dispatch, the held experts, combine) over all the routed
@@ -61,14 +67,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CASES = {
     "lanes_fused_bwd": ((16, 1024, 12, 64), 2),
     "bhsd_fused_bwd": ((4, 1024, 25, 64), 2),
-    "two_kernel_bwd": ((2, 2048, 32, 128), 3),
-    "olmoe_4k": ((4, 4096, 16, 128), 3),
-    "mla_8k": ((2, 8192, 32, 192, 128), 3),
-    "gqa_8k": ((2, 8192, 32, 64), 3),
-    "gqa16_8k": ((2, 8192, 32, 128), 3),
+    "long_fused_bwd": ((2, 2048, 32, 128), 2),
+    "olmoe_4k": ((4, 4096, 16, 128), 2),
+    "mla_8k": ((2, 8192, 32, 192, 128), 2),
+    "gqa_8k": ((2, 8192, 32, 64), 2),
+    "gqa16_8k": ((2, 8192, 32, 128), 2),
 }
 # key/value heads of the cases and sweeps whose k and v have fewer than q
-KV_HEADS = {"gqa_8k": 8, "gqa-8k": 8, "gqa16_8k": 2}
+KV_HEADS = {"gqa_8k": 8, "gqa-8k": 8, "gqa16_8k": 2, "gqa16-8k": 2}
 # (B, S, H, P, G, N, chunk) of one state-space scan
 SSD_CASES = {
     "ssd_8k": (2, 8192, 64, 64, 8, 128, 128),
@@ -86,25 +92,28 @@ SHORTCONV_CASES = {
 MOE_CASES = {
     "moe_held_8k": (16384, 6, 16, 128, 2048, 768),
 }
+# the tiles of a sequence past `_WHOLE_SEQ_MAX`: square, and the backward's
+# k tile (a grid step) beside another q tile (its loop's step)
+LONG_TILES = ((256, 256), (512, 512), (1024, 1024), (256, 512), (512, 1024),
+              (1024, 512))
 # the benchmark's cells: medium's step, XL's on one chip of four, OLMoE's
 SWEEP = {
     "gpt2-medium": ((16, 1024, 16, 64), (
         (128, 128), (256, 256), (512, 512), (1024, 1024))),
     "gpt2-xl-fsdp4": ((4, 1024, 25, 64), (
         (256, 256), (512, 512), (1024, 1024))),
-    "olmoe-1b-7b": ((4, 4096, 16, 128), (
-        (256, 256), (512, 512), (1024, 1024))),
+    "olmoe-1b-7b": ((4, 4096, 16, 128), LONG_TILES),
     # no cell: heads of 128 on a short sequence (llama's prefill), and
     # medium's tokens a step at half the sequence
     "d128-1k": ((4, 1024, 16, 128), (
         (128, 128), (256, 256), (512, 512), (1024, 1024))),
     "s512-d64": ((32, 512, 16, 64), ((128, 128), (256, 256), (512, 512))),
     # kanana-2-30b-a3b's latent attention, multiplied out: q, k 192, v 128
-    "mla-8k": ((2, 8192, 32, 192, 128), (
-        (256, 256), (512, 512), (1024, 1024))),
+    "mla-8k": ((2, 8192, 32, 192, 128), LONG_TILES),
     # LFM2-24B-A2B's attention layers: 32 query heads on 8 key/value heads
-    "gqa-8k": ((2, 8192, 32, 64), (
-        (256, 256), (512, 512), (1024, 1024))),
+    "gqa-8k": ((2, 8192, 32, 64), LONG_TILES),
+    # Nemotron-3-Nano's: 32 query heads on 2 key/value heads of 128
+    "gqa16-8k": ((2, 8192, 32, 128), LONG_TILES),
 }
 TOLERANCE = 0.05
 
