@@ -39,10 +39,11 @@ Departures from that description, none of which changes a score or a sum:
   vocabulary is a smaller vocabulary).
 
 What it shares with the other models: `models/layers.py` (RMSNorm, RoPE,
-the SwiGLU, the chunked loss, the mixed-precision step and its place for
-state that moves by a rule), `parallel/attention.py` (the flash kernels,
-here with q/k and v of two widths) and `ops/moe.py`; the names are those
-`parallel/sharding.py` lays out.
+the SwiGLU, the routed layer, the walk over the layers, the head and its
+chunked loss, the mixed-precision step and its place for state that moves
+by a rule), `parallel/attention.py` (the flash kernels, here with q/k and v
+of two widths) and `ops/moe.py` (the sigmoid router, its account and its
+bias rule); the names are those `parallel/sharding.py` lays out.
 
 `jax.named_scope`s (`models/layers.py:SCOPES`): embed, norm,
 attention/{latent_down,latent_up,kernel,out}, ffn/dense,
@@ -60,19 +61,20 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.layers import (
-    checkpoint_layer,
-    chunked_xent,
+    dense_ffn,
+    head_and_loss,
     named,
     num_params,  # noqa: F401  (`deepseek_v3.num_params` is public)
     rms_norm,
     rope,
+    routed_layer,
     swiglu,
     train_step,
+    trunk,
 )
 from ray_tpu.ops.moe import (
     ROUTING_BIAS,
-    buffer_rows,
-    moe_dispatch,
+    routing_account,
     sigmoid_route,
     trained_by,  # noqa: F401  (`deepseek_v3.trained_by` is public)
 )
@@ -187,11 +189,6 @@ def init_params(rng, cfg: DeepseekV3Config) -> Dict[str, Any]:
     return params
 
 
-def _mlp(x, p):
-    return swiglu(x, *(p[name]["kernel"].astype(x.dtype)
-                       for name in ("gate_proj", "up_proj", "down_proj")))
-
-
 def _attention(x, p, cfg: DeepseekV3Config):
     B, S, _ = x.shape
     H, R, nope = cfg.n_head, cfg.kv_lora_rank, cfg.qk_nope_dim
@@ -220,27 +217,11 @@ def _attention(x, p, cfg: DeepseekV3Config):
                      "attention/out")
 
 
-def _route(xt, router, cfg: DeepseekV3Config):
-    """-> (weights (T, k) f32, experts (T, k) int32) over all experts."""
-    return sigmoid_route(xt, router, cfg.top_k, 1e-20, cfg.routed_scale)
-
-
-def _moe(x, p, cfg: DeepseekV3Config):
-    """-> (y, rows this chip's tokens sent to each of all the experts)."""
-    B, S, E = x.shape
-    xt = x.reshape(B * S, E)
-    with jax.named_scope("route"):
-        weights, experts = _route(xt, p["router"], cfg)
-
-    def run(xs, group_sizes):
-        grouped = lambda a, w: jax.lax.ragged_dot(a, w, group_sizes)
-        return swiglu(xs, p["wi_gate"], p["wi_up"], p["wo"], matmul=grouped)
-
-    y, rows = moe_dispatch(xt, weights, experts, cfg.n_experts, run,
-                           held=cfg.held)
-    with jax.named_scope("shared"):
-        y = y + _mlp(xt, p["shared"])
-    return y.reshape(B, S, E), rows
+def _route(cfg: DeepseekV3Config):
+    """-> route(xt, router) -> (weights (T, k) f32, experts (T, k) int32)
+    over all experts."""
+    return functools.partial(sigmoid_route, top_k=cfg.top_k, eps=1e-20,
+                             scale=cfg.routed_scale)
 
 
 def _layer(x, p, cfg: DeepseekV3Config):
@@ -252,47 +233,24 @@ def _layer(x, p, cfg: DeepseekV3Config):
     with jax.named_scope("ffn"):
         if "mlp" in p:
             with jax.named_scope("dense"):
-                return x + _mlp(u, p["mlp"]), None
+                return x + dense_ffn(u, p["mlp"], swiglu), None
         with jax.named_scope("moe"):
-            y, rows = _moe(u, p["moe"], cfg)
+            y, rows = routed_layer(u, p["moe"], _route(cfg), cfg.n_experts,
+                                   cfg.held, swiglu)
     return x + y, rows
 
 
-def _trunk(params, tokens, cfg: DeepseekV3Config):
+def _hidden(params, tokens, cfg: DeepseekV3Config):
     """-> ((B, S, E) after the final norm, the routers' statistics)."""
-    with jax.named_scope("embed"):
-        x = params["embed_tokens"]["embedding"][tokens].astype(
-            cfg.compute_dtype)
-    layers = [params[f"layer_{i}"] for i in range(cfg.n_layer)]
-    layer = checkpoint_layer(
-        _layer, stack=[(x, p, cfg) for p in layers], static_argnums=(2,),
-        behind=jax.ShapeDtypeStruct((cfg.loss_chunk_rows, cfg.vocab_size),
-                                    jnp.float32)) if cfg.remat else _layer
-    rows = []
-    for p in layers:
-        x, sent = layer(x, p, cfg)
-        if sent is not None:
-            rows.append(sent)
-    rows = jnp.stack(rows)                       # (routed layers, N)
-    first, count = cfg.held or (0, cfg.n_experts)
-    held = jnp.sum(rows[:, first:first + count], axis=1)
-    buffer = buffer_rows(tokens.size * cfg.top_k, count, cfg.n_experts)
-    biases = jnp.stack([
-        params[f"layer_{i}"]["moe"]["router"][ROUTING_BIAS]
-        for i in cfg.moe_layers])
-    return rms_norm(x, params["norm_f"], cfg.rms_eps), {
-        "expert_rows": rows,
-        "rows_held": jnp.sum(held),
-        "moe_overflow_layers": jnp.sum(held > buffer, dtype=jnp.int32),
-        "max_expert_rows": jnp.max(rows),
-        "max_routing_bias": jnp.max(jnp.abs(biases)),
-    }
+    x, rows = trunk(params, tokens, _layer, cfg)
+    return x, routing_account(params, cfg.moe_layers, rows,
+                              tokens.size * cfg.top_k, cfg.held)
 
 
 def forward(params, tokens, cfg: DeepseekV3Config):
     """tokens (B, S) int32 -> (logits (B, S, rows held) f32, routers'
     statistics)."""
-    x, stats = _trunk(params, tokens, cfg)
+    x, stats = _hidden(params, tokens, cfg)
     head = params["lm_head"]["kernel"].astype(cfg.compute_dtype)
     return jnp.matmul(x, head, preferred_element_type=jnp.float32), stats
 
@@ -303,15 +261,9 @@ def loss_fn(params, batch, cfg: DeepseekV3Config):
     routers' statistics).  There is no auxiliary loss.  The head's logits
     are made `cfg.loss_chunk_rows` rows at a time and never all held."""
     tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    x, stats = _trunk(params, inputs, cfg)
-    B, S, E = x.shape
-    with jax.named_scope("head_and_loss"):
-        head = params["lm_head"]["kernel"].astype(cfg.compute_dtype)
-        total = chunked_xent(x.reshape(B * S, E), head.T,
-                              targets.reshape(B * S),
-                              -(-B * S // cfg.loss_chunk_rows))
-        xent = total / (B * S)
+    x, stats = _hidden(params, tokens[:, :-1], cfg)
+    xent = head_and_loss(x, params["lm_head"], tokens[:, 1:],
+                         cfg.loss_chunk_rows)
     return xent, dict(stats, loss=xent)
 
 
@@ -325,13 +277,10 @@ def make_train_step(cfg: DeepseekV3Config, optimizer):
     """train_step(params, opt_state, batch) -> (params, opt_state, out),
     to be jitted with its shardings and `donate_argnums=(0, 1)` as
     `gpt2.make_train_step`'s; ``optimizer`` comes through `trained_by`.
-    `out["loss"]` is the cross-entropy; `out` also carries "expert_rows"
-    (routed layers, experts), "rows_held" (rows the held experts computed,
-    over the layers), "moe_overflow_layers" (routed layers whose held
-    experts were sent more than `ops/moe.py:buffer_rows` and ran over all
-    the routed rows instead: exact, and slower), "max_expert_rows" and
-    "max_routing_bias" (|b| as the step used it), device values that cost
-    nothing unless fetched."""
+    `out["loss"]` is the cross-entropy; `out` also carries the routers'
+    account (`ops/moe.py:routing_account`: "expert_rows", "rows_held",
+    "moe_overflow_layers", "max_expert_rows", "max_routing_bias"), device
+    values that cost nothing unless fetched."""
     return train_step(lambda params, batch: loss_fn(params, batch, cfg),
                       optimizer, cfg.compute_dtype,
                       rule=routing_bias_rule(cfg))

@@ -1,8 +1,11 @@
 """What the Train-path models share: the norms, rotary positions, the
-gated feed-forward, the gated short convolution, the causal convolution and
-gated norm of a state-space mixer, the chunked loss and the mixed-precision
-step.  A model file imports these, `ray_tpu.parallel.attention`
-and `ray_tpu.ops`; it imports no other model file.
+feed-forwards (gated and not), the routed layer over them, the gated short
+convolution, the causal convolution and gated norm of a state-space mixer,
+the walk over a decoder's layers, the head and its chunked loss and the
+mixed-precision step.  A model file imports these,
+`ray_tpu.parallel.attention` and `ray_tpu.ops`; it imports no other model
+file: it is its configuration, `init_params`, its mixers and a `_layer` that
+says which mixer and which feed-forward a layer has.
 
 Imports jax, the names of the flash kernels' residuals and forms
 (`ops/flash_attention.py`, which every model imports through
@@ -17,10 +20,11 @@ profiler carries that into the trace (`tf_op`), and a reader
 every path a Train-path model may use, and the one place a reader or a test
 takes them from.  A model writes plain `with jax.named_scope("attention"):`
 around the part; what the models share names itself (`layer_norm` and
-`rms_norm`: `norm`; `short_conv`'s three parts; `ops/moe.py`'s `dispatch`,
+`rms_norm`: `norm`; `short_conv`'s three parts; `trunk`'s `embed`;
+`routed_layer`'s `route` and `shared` and `ops/moe.py`'s `dispatch`,
 `experts`, `combine` and `routing_bias_update`, which rely on the caller
-standing in `ffn/moe`; the flash kernels' forms; `train_step`'s
-`optimizer_update`).  `norm` is a layer's norm on the residual stream: one
+standing in `ffn/moe`; `head_and_loss`; the flash kernels' forms;
+`train_step`'s `optimizer_update`).  `norm` is a layer's norm on the residual stream: one
 inside an operator (a norm over a head, the latent's) stands in that
 operator's scope and counts there.
 """
@@ -36,7 +40,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.flash_attention import KEPT_RESIDUALS, KERNEL_FORMS
-from ray_tpu.ops.moe import ROUTE_NAME
+from ray_tpu.ops.moe import ROUTE_NAME, moe_dispatch
 from ray_tpu.parallel.context import get_mesh
 from ray_tpu.parallel.sharding import chip_bytes, param_logical_dims
 from ray_tpu.util import tracing
@@ -133,6 +137,81 @@ def swiglu(x, gate, up, down, matmul=jnp.matmul):
     recomputed layer (`KEPT_NAMES`)."""
     gate, up = named((matmul(x, gate), matmul(x, up)), "ffn/hidden")
     return matmul(jax.nn.silu(gate) * up, down)
+
+
+def relu2(x, up, down, matmul=jnp.matmul):
+    """down(relu(up(x))^2): a feed-forward that is not gated, two matrices
+    (Nemotron-H's experts, routed and shared); ``matmul`` as `swiglu`'s.
+    The up's result is marked for a recomputed layer."""
+    hidden = named(matmul(x, up), "ffn/hidden")
+    return matmul(jnp.square(jax.nn.relu(hidden)), down)
+
+
+def dense_ffn(x, p, ffn):
+    """``ffn`` (`swiglu` or `relu2`) over plain products with ``p``'s
+    matrices in the compute type: {"gate_proj" where ``ffn`` is gated,
+    "up_proj", "down_proj"}, each {"kernel": ...}: a dense layer's
+    feed-forward, a shared expert."""
+    return ffn(x, *(p[name]["kernel"].astype(x.dtype) for name in
+                    ("gate_proj", "up_proj", "down_proj") if name in p))
+
+
+# XLA:TPU's grouped-matmul kernel (`ragged_dot`) runs an expert width that is
+# no multiple of this at under half its speed: 8 groups of 768 rows, 2,688
+# wide, forward / forward + backward ms, at 1,856 wide 4.49 / 14.48, at 1,920
+# the same, at 2,048 1.83 / 6.81 (PERF.md §6, PR 38)
+_GROUPED_WIDTH = 256
+
+
+def _widened(w, axis):
+    """A stack of expert matrices with zeros up to a whole `_GROUPED_WIDTH`
+    along ``axis``, the experts' hidden width: silu(0) * 0 and relu(0)^2 are
+    0, times rows of zeros they add nothing, and no gradient comes back to
+    the zeros.  A width that is a multiple already is left as it is."""
+    extra = -w.shape[axis] % _GROUPED_WIDTH
+    if not extra:
+        return w
+    pad = [(0, 0)] * w.ndim
+    pad[axis] = (0, extra)
+    return jnp.pad(w, pad)
+
+
+def grouped_ffn(p, ffn):
+    """-> `run_experts(rows, group_sizes)` for `ops/moe.py:moe_dispatch`:
+    ``ffn`` (`swiglu` or `relu2`) over rows sorted by expert, every product
+    a grouped matmul (`jax.lax.ragged_dot`) with one of ``p``'s stacks, an
+    expert a matrix: "wi_gate" where the experts are gated, "wi_up" (n, E,
+    W) and "wo" (n, W, E), each widened to XLA:TPU's grouped width
+    (`_widened`) once, outside the run."""
+    stacks = [_widened(p[name], axis) for name, axis in
+              (("wi_gate", 2), ("wi_up", 2), ("wo", 1)) if name in p]
+
+    def run(xs, group_sizes):
+        grouped = lambda a, w: jax.lax.ragged_dot(a, w, group_sizes)
+        return ffn(xs, *stacks, matmul=grouped)
+    return run
+
+
+def routed_layer(x, p, route, n_experts, held, ffn):
+    """One routed feed-forward, x (B, S, E) -> (y (B, S, E), the rows this
+    chip's tokens sent to each of ALL the experts (n_experts,) int32); the
+    caller stands in the scope `ffn/moe`.  ``route(xt (T, E), p["router"])
+    -> (weights (T, k) f32, experts (T, k) int32)`` is the model's own
+    router, run under `route`; the experts are ``ffn`` over ``p``'s stacks
+    (`grouped_ffn`), dropless over the ``held`` = (first, count) of them
+    that live here, None: all (`ops/moe.py:moe_dispatch`); a shared expert
+    is there if ``p`` has "shared", the same ``ffn`` over every token
+    (`dense_ffn`) under `shared`."""
+    B, S, E = x.shape
+    xt = x.reshape(B * S, E)
+    with jax.named_scope("route"):
+        weights, experts = route(xt, p["router"])
+    y, rows = moe_dispatch(xt, weights, experts, n_experts,
+                           grouped_ffn(p, ffn), held=held)
+    if "shared" in p:
+        with jax.named_scope("shared"):
+            y = y + dense_ffn(xt, p["shared"], ffn)
+    return y.reshape(B, S, E), rows
 
 
 def _back(x, k):
@@ -505,6 +584,34 @@ def checkpoint_layer(fn, stack=None, behind=(), **kw):
     return jax.checkpoint(fn, policy=_keep(names), **kw)
 
 
+def trunk(params, tokens, layer, cfg):
+    """A decoder's walk, tokens (B, S) int32 -> ((B, S, E) after the final
+    norm, the layers' second results in order, a None left out): the
+    embedding ``params["embed_tokens"]`` under `embed`, in the compute
+    type; ``layer(x, params[f"layer_{i}"], cfg) -> (x, anything)`` for the
+    `cfg.n_layer` layers in order, each recomputed by the backward pass
+    when `cfg.remat`, keeping what fits the chip with a chunk of the head's
+    logits (`cfg.loss_chunk_rows` of `cfg.vocab_size`) behind the stack
+    (`checkpoint_layer`); RMSNorm by ``params["norm_f"]`` at
+    `cfg.rms_eps`.  `models/gpt2.py` walks by itself: positional
+    embeddings, pipeline stages, a mesh's pins."""
+    with jax.named_scope("embed"):
+        x = params["embed_tokens"]["embedding"][tokens].astype(
+            cfg.compute_dtype)
+    layers = [params[f"layer_{i}"] for i in range(cfg.n_layer)]
+    if cfg.remat:
+        layer = checkpoint_layer(
+            layer, stack=[(x, p, cfg) for p in layers], static_argnums=(2,),
+            behind=jax.ShapeDtypeStruct(
+                (cfg.loss_chunk_rows, cfg.vocab_size), jnp.float32))
+    seconds = []
+    for p in layers:
+        x, second = layer(x, p, cfg)
+        if second is not None:
+            seconds.append(second)
+    return rms_norm(x, params["norm_f"], cfg.rms_eps), seconds
+
+
 def chunked_xent(x, wte, targets, n_chunks: int):
     """Fused linear + softmax cross-entropy, chunked over tokens.
 
@@ -538,6 +645,21 @@ def chunked_xent(x, wte, targets, n_chunks: int):
 
     total, _ = jax.lax.scan(chunk, jnp.zeros((), jnp.float32), (xc, tc))
     return total
+
+
+def head_and_loss(x, head, targets, chunk_rows):
+    """x (B, S, E), targets (B, S) int32 -> the mean next-token
+    cross-entropy, under `head_and_loss`.  ``head``: an untied head's
+    parameters {"kernel": (E, V)}, or those of the embedding a tied one is
+    {"embedding": (V, E)}.  The logits are made ``chunk_rows`` rows at a
+    time and never all held (`chunked_xent`)."""
+    B, S, E = x.shape
+    with jax.named_scope("head_and_loss"):
+        rows = head["embedding"].astype(x.dtype) if "embedding" in head \
+            else head["kernel"].astype(x.dtype).T
+        total = chunked_xent(x.reshape(B * S, E), rows,
+                             targets.reshape(B * S), -(-B * S // chunk_rows))
+        return total / (B * S)
 
 
 def cast_weights(params, dtype):

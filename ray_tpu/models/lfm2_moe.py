@@ -41,11 +41,12 @@ the embedding held here (a slice of the vocabulary is a smaller
 vocabulary).
 
 What it shares with the other models: `models/layers.py` (RMSNorm, RoPE,
-the SwiGLU, `short_conv`, the chunked loss, the mixed-precision step and its
-place for state that moves by a rule), `parallel/attention.py` (the flash
-kernels, here with fewer key/value heads than query heads) and `ops/moe.py`
-(dispatch over a share of the experts, the sigmoid router and its bias
-rule); the names are those `parallel/sharding.py` lays out.
+the SwiGLU, `short_conv`, the routed layer, the walk over the layers, the
+head and its chunked loss, the mixed-precision step and its place for state
+that moves by a rule), `parallel/attention.py` (the flash kernels, here with
+fewer key/value heads than query heads) and `ops/moe.py` (dispatch over a
+share of the experts, the sigmoid router, its account and its bias rule);
+the names are those `parallel/sharding.py` lays out.
 
 `jax.named_scope`s (`models/layers.py:SCOPES`): embed, norm,
 short_conv/{in_proj,gate_taps,out_proj}, attention/{qkv,kernel,out},
@@ -55,6 +56,7 @@ optimizer_update, routing_bias_update.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -62,20 +64,21 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.layers import (
-    checkpoint_layer,
-    chunked_xent,
+    dense_ffn,
+    head_and_loss,
     named,
     num_params,  # noqa: F401  (`lfm2_moe.num_params` is public)
     rms_norm,
     rope,
+    routed_layer,
     short_conv,
     swiglu,
     train_step,
+    trunk,
 )
 from ray_tpu.ops.moe import (
     ROUTING_BIAS,
-    buffer_rows,
-    moe_dispatch,
+    routing_account,
     routing_bias_rule,
     sigmoid_route,
     trained_by,  # noqa: F401  (`lfm2_moe.trained_by` is public)
@@ -228,22 +231,12 @@ def _attention(x, p, cfg: Lfm2MoeConfig):
                      "attention/out")
 
 
-def _moe(x, p, cfg: Lfm2MoeConfig):
-    """-> (y, rows this chip's tokens sent to each of all the experts)."""
-    B, S, E = x.shape
-    xt = x.reshape(B * S, E)
-    with jax.named_scope("route"):
-        weights, experts = sigmoid_route(
-            xt, p["router"], cfg.top_k,
-            1e-6 if cfg.norm_topk_prob else None, cfg.routed_scale)
-
-    def run(xs, group_sizes):
-        grouped = lambda a, w: jax.lax.ragged_dot(a, w, group_sizes)
-        return swiglu(xs, p["wi_gate"], p["wi_up"], p["wo"], matmul=grouped)
-
-    y, rows = moe_dispatch(xt, weights, experts, cfg.n_experts, run,
-                           held=cfg.held)
-    return y.reshape(B, S, E), rows
+def _route(cfg: Lfm2MoeConfig):
+    """-> route(xt, router) -> (weights (T, k) f32, experts (T, k) int32)
+    over all experts."""
+    return functools.partial(
+        sigmoid_route, top_k=cfg.top_k,
+        eps=1e-6 if cfg.norm_topk_prob else None, scale=cfg.routed_scale)
 
 
 def _layer(x, p, cfg: Lfm2MoeConfig):
@@ -259,48 +252,24 @@ def _layer(x, p, cfg: Lfm2MoeConfig):
     with jax.named_scope("ffn"):
         if "mlp" in p:
             with jax.named_scope("dense"):
-                return x + swiglu(u, *(
-                    p["mlp"][name]["kernel"].astype(u.dtype)
-                    for name in ("gate_proj", "up_proj", "down_proj"))), None
+                return x + dense_ffn(u, p["mlp"], swiglu), None
         with jax.named_scope("moe"):
-            y, rows = _moe(u, p["moe"], cfg)
+            y, rows = routed_layer(u, p["moe"], _route(cfg), cfg.n_experts,
+                                   cfg.held, swiglu)
     return x + y, rows
 
 
-def _trunk(params, tokens, cfg: Lfm2MoeConfig):
+def _hidden(params, tokens, cfg: Lfm2MoeConfig):
     """-> ((B, S, E) after the final norm, the routers' statistics)."""
-    with jax.named_scope("embed"):
-        x = params["embed_tokens"]["embedding"][tokens].astype(
-            cfg.compute_dtype)
-    layers = [params[f"layer_{i}"] for i in range(cfg.n_layer)]
-    layer = checkpoint_layer(
-        _layer, stack=[(x, p, cfg) for p in layers], static_argnums=(2,),
-        behind=jax.ShapeDtypeStruct((cfg.loss_chunk_rows, cfg.vocab_size),
-                                    jnp.float32)) if cfg.remat else _layer
-    rows = []
-    for p in layers:
-        x, sent = layer(x, p, cfg)
-        if sent is not None:
-            rows.append(sent)
-    rows = jnp.stack(rows)                       # (routed layers, N)
-    first, count = cfg.held or (0, cfg.n_experts)
-    held = jnp.sum(rows[:, first:first + count], axis=1)
-    buffer = buffer_rows(tokens.size * cfg.top_k, count, cfg.n_experts)
-    biases = [params[f"layer_{i}"]["moe"]["router"][ROUTING_BIAS]
-              for i in cfg.moe_layers] if cfg.use_expert_bias else [0.0]
-    return rms_norm(x, params["norm_f"], cfg.rms_eps), {
-        "expert_rows": rows,
-        "rows_held": jnp.sum(held),
-        "moe_overflow_layers": jnp.sum(held > buffer, dtype=jnp.int32),
-        "max_expert_rows": jnp.max(rows),
-        "max_routing_bias": jnp.max(jnp.abs(jnp.stack(biases))),
-    }
+    x, rows = trunk(params, tokens, _layer, cfg)
+    return x, routing_account(params, cfg.moe_layers, rows,
+                              tokens.size * cfg.top_k, cfg.held)
 
 
 def forward(params, tokens, cfg: Lfm2MoeConfig):
     """tokens (B, S) int32 -> (logits (B, S, rows held) f32, routers'
     statistics)."""
-    x, stats = _trunk(params, tokens, cfg)
+    x, stats = _hidden(params, tokens, cfg)
     head = params["embed_tokens"]["embedding"].astype(cfg.compute_dtype)
     return jnp.matmul(x, head.T, preferred_element_type=jnp.float32), stats
 
@@ -311,15 +280,9 @@ def loss_fn(params, batch, cfg: Lfm2MoeConfig):
     routers' statistics).  There is no auxiliary loss.  The head's logits
     are made `cfg.loss_chunk_rows` rows at a time and never all held."""
     tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    x, stats = _trunk(params, inputs, cfg)
-    B, S, E = x.shape
-    with jax.named_scope("head_and_loss"):
-        head = params["embed_tokens"]["embedding"].astype(cfg.compute_dtype)
-        total = chunked_xent(x.reshape(B * S, E), head,
-                              targets.reshape(B * S),
-                              -(-B * S // cfg.loss_chunk_rows))
-        xent = total / (B * S)
+    x, stats = _hidden(params, tokens[:, :-1], cfg)
+    xent = head_and_loss(x, params["embed_tokens"], tokens[:, 1:],
+                         cfg.loss_chunk_rows)
     return xent, dict(stats, loss=xent)
 
 
@@ -327,10 +290,9 @@ def make_train_step(cfg: Lfm2MoeConfig, optimizer):
     """train_step(params, opt_state, batch) -> (params, opt_state, out),
     to be jitted with its shardings and `donate_argnums=(0, 1)` as
     `gpt2.make_train_step`'s; ``optimizer`` comes through `trained_by`.
-    `out` carries what `deepseek_v3.make_train_step`'s does: "loss",
-    "expert_rows" (routed layers, experts), "rows_held",
-    "moe_overflow_layers", "max_expert_rows" and "max_routing_bias", device
-    values that cost nothing unless fetched."""
+    `out` carries "loss" and the routers' account
+    (`ops/moe.py:routing_account`), device values that cost nothing unless
+    fetched."""
     rule = routing_bias_rule(cfg.moe_layers, cfg.bias_update_speed) \
         if cfg.use_expert_bias else None
     return train_step(lambda params, batch: loss_fn(params, batch, cfg),

@@ -58,12 +58,13 @@ of the sum is computed (`ops/moe.py:moe_dispatch`).  `vocab_size` is the
 rows of the embedding and of the head held here.
 
 What it shares with the other models: `models/layers.py` (RMSNorm,
-`causal_conv`, `gated_rms_norm`, the chunked loss, the mixed-precision step
-and its place for state that moves by a rule), `parallel/attention.py` (the
-flash kernels, 16 query heads on each key/value head), `ops/moe.py`
-(dispatch over a share of the experts, the sigmoid router and its bias
-rule) and `ops/ssd.py`; the names are those `parallel/sharding.py` lays
-out.
+`causal_conv`, `gated_rms_norm`, the ungated feed-forward `relu2`, the
+routed layer, the walk over the layers, the head and its chunked loss, the
+mixed-precision step and its place for state that moves by a rule),
+`parallel/attention.py` (the flash kernels, 16 query heads on each
+key/value head), `ops/moe.py` (dispatch over a share of the experts, the
+sigmoid router, its account and its bias rule) and `ops/ssd.py`; the names
+are those `parallel/sharding.py` lays out.
 
 `jax.named_scope`s (`models/layers.py:SCOPES`): embed, norm,
 ssm/{in_proj,conv,scan,gate_norm,out_proj}, attention/{qkv,kernel,out},
@@ -73,6 +74,7 @@ optimizer_update, routing_bias_update: the mixture stands under `ffn`.
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
@@ -82,18 +84,19 @@ import jax.numpy as jnp
 
 from ray_tpu.models.layers import (
     causal_conv,
-    checkpoint_layer,
-    chunked_xent,
     gated_rms_norm,
+    head_and_loss,
     named,
     num_params,  # noqa: F401  (`nemotron_h.num_params` is public)
+    relu2,
     rms_norm,
+    routed_layer,
     train_step,
+    trunk,
 )
 from ray_tpu.ops.moe import (
     ROUTING_BIAS,
-    buffer_rows,
-    moe_dispatch,
+    routing_account,
     routing_bias_rule,
     sigmoid_route,
     trained_by,  # noqa: F401  (`nemotron_h.trained_by` is public)
@@ -291,52 +294,12 @@ def _attention(u, p, cfg: NemotronHConfig):
         return o.reshape(B, S, H * D) @ kernel("o_proj")    # as W_out's
 
 
-def _relu2(x, up, down, matmul=jnp.matmul):
-    """down(relu(up(x))^2): an expert that is not gated, two matrices;
-    ``matmul`` as `layers.swiglu`'s.  The up's result is marked for a
-    recomputed layer."""
-    hidden = named(matmul(x, up), "ffn/hidden")
-    return matmul(jnp.square(jax.nn.relu(hidden)), down)
-
-
-# XLA:TPU's grouped-matmul kernel (`ragged_dot`) runs an expert width that is
-# no multiple of this at under half its speed: 8 groups of 768 rows, 2,688
-# wide, forward / forward + backward ms, at 1,856 wide 4.49 / 14.48, at 1,920
-# the same, at 2,048 1.83 / 6.81 (PERF.md §6, PR 38)
-_GROUPED_WIDTH = 256
-
-
-def _widened(w, axis):
-    """A stack of expert matrices with zeros up to a whole `_GROUPED_WIDTH`
-    along ``axis``, the experts' hidden width: relu(0)^2 = 0 times rows of
-    zeros adds nothing, and no gradient comes back to the zeros."""
-    pad = [(0, 0)] * w.ndim
-    pad[axis] = (0, -w.shape[axis] % _GROUPED_WIDTH)
-    return jnp.pad(w, pad)
-
-
-def _moe(u, p, cfg: NemotronHConfig):
-    """-> (y, rows this chip's tokens sent to each of all the experts)."""
-    B, S, E = u.shape
-    xt = u.reshape(B * S, E)
-    with jax.named_scope("route"):
-        weights, experts = sigmoid_route(
-            xt, p["router"], cfg.top_k,
-            1e-20 if cfg.norm_topk_prob else None, cfg.routed_scale)
-
-    up, down = _widened(p["wi_up"], 2), _widened(p["wo"], 1)
-
-    def run(xs, group_sizes):
-        grouped = lambda a, w: jax.lax.ragged_dot(a, w, group_sizes)
-        return _relu2(xs, up, down, matmul=grouped)
-
-    y, rows = moe_dispatch(xt, weights, experts, cfg.n_experts, run,
-                           held=cfg.held)
-    with jax.named_scope("shared"):
-        shared = p["shared"]
-        y = y + _relu2(xt, shared["up_proj"]["kernel"].astype(xt.dtype),
-                       shared["down_proj"]["kernel"].astype(xt.dtype))
-    return y.reshape(B, S, E), rows
+def _route(cfg: NemotronHConfig):
+    """-> route(xt, router) -> (weights (T, k) f32, experts (T, k) int32)
+    over all experts."""
+    return functools.partial(
+        sigmoid_route, top_k=cfg.top_k,
+        eps=1e-20 if cfg.norm_topk_prob else None, scale=cfg.routed_scale)
 
 
 def _layer(x, p, cfg: NemotronHConfig):
@@ -350,45 +313,22 @@ def _layer(x, p, cfg: NemotronHConfig):
         with jax.named_scope("attention"):
             return x + _attention(u, p["attn"], cfg), None
     with jax.named_scope("ffn"), jax.named_scope("moe"):
-        y, rows = _moe(u, p["moe"], cfg)
+        y, rows = routed_layer(u, p["moe"], _route(cfg), cfg.n_experts,
+                               cfg.held, relu2)
     return x + y, rows
 
 
-def _trunk(params, tokens, cfg: NemotronHConfig):
+def _hidden(params, tokens, cfg: NemotronHConfig):
     """-> ((B, S, E) after the final norm, the routers' statistics)."""
-    with jax.named_scope("embed"):
-        x = params["embed_tokens"]["embedding"][tokens].astype(
-            cfg.compute_dtype)
-    layers = [params[f"layer_{i}"] for i in range(cfg.n_layer)]
-    layer = checkpoint_layer(
-        _layer, stack=[(x, p, cfg) for p in layers], static_argnums=(2,),
-        behind=jax.ShapeDtypeStruct((cfg.loss_chunk_rows, cfg.vocab_size),
-                                    jnp.float32)) if cfg.remat else _layer
-    rows = []
-    for p in layers:
-        x, sent = layer(x, p, cfg)
-        if sent is not None:
-            rows.append(sent)
-    rows = jnp.stack(rows)                       # (mixture layers, N)
-    first, count = cfg.held or (0, cfg.n_experts)
-    held = jnp.sum(rows[:, first:first + count], axis=1)
-    buffer = buffer_rows(tokens.size * cfg.top_k, count, cfg.n_experts)
-    biases = jnp.stack([
-        params[f"layer_{i}"]["moe"]["router"][ROUTING_BIAS]
-        for i in cfg.moe_layers])
-    return rms_norm(x, params["norm_f"], cfg.rms_eps), {
-        "expert_rows": rows,
-        "rows_held": jnp.sum(held),
-        "moe_overflow_layers": jnp.sum(held > buffer, dtype=jnp.int32),
-        "max_expert_rows": jnp.max(rows),
-        "max_routing_bias": jnp.max(jnp.abs(biases)),
-    }
+    x, rows = trunk(params, tokens, _layer, cfg)
+    return x, routing_account(params, cfg.moe_layers, rows,
+                              tokens.size * cfg.top_k, cfg.held)
 
 
 def forward(params, tokens, cfg: NemotronHConfig):
     """tokens (B, S) int32 -> (logits (B, S, rows held) f32, routers'
     statistics)."""
-    x, stats = _trunk(params, tokens, cfg)
+    x, stats = _hidden(params, tokens, cfg)
     head = params["lm_head"]["kernel"].astype(cfg.compute_dtype)
     return jnp.matmul(x, head, preferred_element_type=jnp.float32), stats
 
@@ -399,15 +339,9 @@ def loss_fn(params, batch, cfg: NemotronHConfig):
     routers' statistics).  There is no auxiliary loss.  The head's logits
     are made `cfg.loss_chunk_rows` rows at a time and never all held."""
     tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    x, stats = _trunk(params, inputs, cfg)
-    B, S, E = x.shape
-    with jax.named_scope("head_and_loss"):
-        head = params["lm_head"]["kernel"].astype(cfg.compute_dtype)
-        total = chunked_xent(x.reshape(B * S, E), head.T,
-                              targets.reshape(B * S),
-                              -(-B * S // cfg.loss_chunk_rows))
-        xent = total / (B * S)
+    x, stats = _hidden(params, tokens[:, :-1], cfg)
+    xent = head_and_loss(x, params["lm_head"], tokens[:, 1:],
+                         cfg.loss_chunk_rows)
     return xent, dict(stats, loss=xent)
 
 
@@ -415,10 +349,9 @@ def make_train_step(cfg: NemotronHConfig, optimizer):
     """train_step(params, opt_state, batch) -> (params, opt_state, out),
     to be jitted with its shardings and `donate_argnums=(0, 1)` as
     `gpt2.make_train_step`'s; ``optimizer`` comes through `trained_by`.
-    `out` carries what `deepseek_v3.make_train_step`'s does: "loss",
-    "expert_rows" (mixture layers, experts), "rows_held",
-    "moe_overflow_layers", "max_expert_rows" and "max_routing_bias", device
-    values that cost nothing unless fetched."""
+    `out` carries "loss" and the routers' account
+    (`ops/moe.py:routing_account`, a row a mixture layer), device values
+    that cost nothing unless fetched."""
     return train_step(lambda params, batch: loss_fn(params, batch, cfg),
                       optimizer, cfg.compute_dtype,
                       rule=routing_bias_rule(cfg.moe_layers,

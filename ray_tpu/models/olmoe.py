@@ -14,12 +14,17 @@ Muennighoff et al., "OLMoE: Open Mixture-of-Experts Language Models"
   + a router z-loss.
 
 What it shares with the other models it takes from `models/layers.py`
-(RMSNorm, RoPE, the chunked loss, f32 master parameters cast once in the
+(RMSNorm, RoPE, the SwiGLU over grouped matmuls, the walk over the layers,
+the head and its chunked loss, f32 master parameters cast once in the
 mixed-precision step) and `parallel/attention.py` (the flash kernels under
 a mesh); its names are those `parallel/sharding.py` lays out.  The experts
 run dropless (`ops/moe.py:moe_dispatch`): the (token,
 expert) rows are sorted by expert and each group is multiplied by its
-expert with `jax.lax.ragged_dot`, XLA:TPU's grouped-matmul kernel.
+expert with XLA:TPU's grouped-matmul kernel (`layers.grouped_ffn`).  Its
+routed layer is written out here and is not `layers.routed_layer`: the two
+auxiliary losses read the router's logits and probabilities, and the
+benchmark's seeded fault (`benchmark/tests/test_olmoe_cell.py`) patches
+this module's `moe_dispatch`.
 
 `jax.named_scope`s (`models/layers.py:SCOPES`): embed, norm,
 attention/{qkv,kernel,out}, ffn/moe/{route,dispatch,experts,combine},
@@ -36,13 +41,15 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.layers import (
-    checkpoint_layer,
-    chunked_xent,
+    grouped_ffn,
+    head_and_loss,
     named,
     num_params,  # noqa: F401  (`olmoe.num_params` is public)
     rms_norm,
     rope,
+    swiglu,
     train_step,
+    trunk,
 )
 from ray_tpu.ops.moe import ROUTE_NAME, moe_dispatch
 from ray_tpu.parallel.attention import attention
@@ -147,17 +154,6 @@ def _attention(x, p, cfg: OlmoeConfig):
         return named(o.reshape(B, S, E) @ kernel("o_proj"), "attention/out")
 
 
-def _gated_experts(wi_gate, wi_up, wo):
-    """The experts' SiLU-gated feed-forward over rows sorted by expert:
-    three grouped matmuls over the ragged groups."""
-    def run(xs, group_sizes):
-        gate, up = named((jax.lax.ragged_dot(xs, wi_gate, group_sizes),
-                          jax.lax.ragged_dot(xs, wi_up, group_sizes)),
-                         "ffn/hidden")
-        return jax.lax.ragged_dot(jax.nn.silu(gate) * up, wo, group_sizes)
-    return run
-
-
 def _moe(x, p, cfg: OlmoeConfig):
     """-> (y, {load-balancing loss, router z-loss, most rows an expert
     got}) for x of shape (B, S, E)."""
@@ -170,9 +166,8 @@ def _moe(x, p, cfg: OlmoeConfig):
                         ).astype(jnp.float32), ROUTE_NAME)    # (T, N)
         probs = jax.nn.softmax(logits, axis=-1)
         weights, experts = jax.lax.top_k(probs, cfg.top_k)    # (T, k)
-    y, group_sizes = moe_dispatch(
-        xt, weights, experts, cfg.n_experts,
-        _gated_experts(p["wi_gate"], p["wi_up"], p["wo"]))
+    y, group_sizes = moe_dispatch(xt, weights, experts, cfg.n_experts,
+                                  grouped_ffn(p, swiglu))
     with jax.named_scope("route"):
         # f: each expert's share of the T*k assignments (a count: no
         # gradient); P: its mean router probability
@@ -193,24 +188,13 @@ def _layer(x, p, cfg: OlmoeConfig):
     return x + y, stats
 
 
-def _trunk(params, tokens, cfg: OlmoeConfig):
+def _hidden(params, tokens, cfg: OlmoeConfig):
     """-> ((B, S, E) after the final norm, the routers' statistics: the
     auxiliary losses averaged over the layers, the fullest expert of
     any)."""
-    with jax.named_scope("embed"):
-        x = params["embed_tokens"]["embedding"][tokens].astype(
-            cfg.compute_dtype)
-    layers = [params[f"layer_{i}"] for i in range(cfg.n_layer)]
-    layer = checkpoint_layer(
-        _layer, stack=[(x, p, cfg) for p in layers], static_argnums=(2,),
-        behind=jax.ShapeDtypeStruct((cfg.loss_chunk_rows, cfg.vocab_size),
-                                    jnp.float32)) if cfg.remat else _layer
-    stats = []
-    for p in layers:
-        x, s = layer(x, p, cfg)
-        stats.append(s)
+    x, stats = trunk(params, tokens, _layer, cfg)
     mean = lambda key: sum(s[key] for s in stats) / len(stats)
-    return rms_norm(x, params["norm_f"], cfg.rms_eps), {
+    return x, {
         "aux_loss": mean("aux_loss"), "z_loss": mean("z_loss"),
         "max_expert_rows": functools.reduce(
             jnp.maximum, [s["max_expert_rows"] for s in stats])}
@@ -219,7 +203,7 @@ def _trunk(params, tokens, cfg: OlmoeConfig):
 def forward(params, tokens, cfg: OlmoeConfig):
     """tokens (B, S) int32 -> (logits (B, S, vocab) f32, routers'
     statistics)."""
-    x, stats = _trunk(params, tokens, cfg)
+    x, stats = _hidden(params, tokens, cfg)
     head = params["lm_head"]["kernel"].astype(cfg.compute_dtype)
     return jnp.matmul(x, head, preferred_element_type=jnp.float32), stats
 
@@ -232,15 +216,9 @@ def loss_fn(params, batch, cfg: OlmoeConfig):
     fullest expert's rows.  The head's logits are made
     `cfg.loss_chunk_rows` rows at a time and never all held."""
     tokens = batch["tokens"]
-    inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    x, stats = _trunk(params, inputs, cfg)
-    B, S, E = x.shape
-    with jax.named_scope("head_and_loss"):
-        head = params["lm_head"]["kernel"].astype(cfg.compute_dtype)
-        total = chunked_xent(x.reshape(B * S, E), head.T,
-                              targets.reshape(B * S),
-                              -(-B * S // cfg.loss_chunk_rows))
-        xent = total / (B * S)
+    x, stats = _hidden(params, tokens[:, :-1], cfg)
+    xent = head_and_loss(x, params["lm_head"], tokens[:, 1:],
+                         cfg.loss_chunk_rows)
     objective = (xent + cfg.aux_weight * stats["aux_loss"]
                  + cfg.z_weight * stats["z_loss"])
     return objective, dict(stats, loss=xent)

@@ -86,6 +86,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import by_platform
 from ray_tpu.util import tracing
 
 _NEG_INF = -1e30
@@ -763,27 +764,6 @@ def _warn_reference(shape, block_q, block_k, reason):
         AttentionFallbackWarning, stacklevel=4)
 
 
-# q elements up to which a non-TPU backend interprets the kernels (the
-# tests' sizes); beyond it interpretation is too slow to be worth it.
-_INTERPRET_MAX_ELEMS = 1 << 16
-
-
-def _by_platform(kernel, reference, q, *rest):
-    """``kernel(q, *rest, interpret=False)`` — the compiled Mosaic kernel —
-    wherever the computation is lowered for a TPU; on any other platform
-    the same kernel interpreted at test sizes and ``reference(q, *rest)``
-    beyond them.  The choice is made per lowering platform, not from the
-    devices of the tracing process, so an export for a TPU from a CPU host
-    carries the kernel and nothing on a TPU is ever interpreted."""
-    if q.size <= _INTERPRET_MAX_ELEMS:
-        other = functools.partial(kernel, interpret=True)
-    else:
-        other = reference
-    return jax.lax.platform_dependent(
-        q, *rest, tpu=functools.partial(kernel, interpret=False),
-        default=other)
-
-
 def _auto_block(S: int, cap: int) -> int:
     """Largest block <= cap that divides S (so the Pallas path stays
     active for any S with a power-of-two-ish factor, not just S % cap == 0
@@ -935,7 +915,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
         o, lse = reference(q, k, v)
     else:
         _count_tiles(S, bq, bk, causal, (q.shape[1], k.shape[1]))
-        o, lse = _by_platform(
+        o, lse = by_platform(
             functools.partial(_pallas_forward, sm_scale=scale, causal=causal,
                               block_q=bq, block_k=bk, whole=whole),
             reference, q, k, v)
@@ -968,7 +948,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, do, delta=None):
     def reference(q, k, v, o, lse, do, delta):
         return _reference_backward(q, k, v, lse, do, delta, scale, causal)
 
-    return _by_platform(kernel, reference, q, k, v, o, lse, do, delta)
+    return by_platform(kernel, reference, q, k, v, o, lse, do, delta)
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd)
@@ -1014,7 +994,7 @@ def _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k):
                                           causal)
             return _tr(o), lse
 
-        o, lse = _by_platform(
+        o, lse = by_platform(
             functools.partial(_pallas_forward_bshd, sm_scale=scale,
                               causal=causal, block_q=bq, block_k=bk,
                               whole=whole),
@@ -1044,7 +1024,7 @@ def _flash_bwd_bshd(causal, sm_scale, block_q, block_k, res, do):
             return tuple(map(_tr, _reference_backward(
                 qt, kt, vt, lse, dot, delta, scale, causal)))
 
-        return _by_platform(
+        return by_platform(
             functools.partial(_pallas_backward_bshd, sm_scale=scale,
                               causal=causal, block_q=bq, block_k=bk),
             reference, q, k, v, o, lse, do)

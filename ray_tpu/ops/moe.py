@@ -6,12 +6,13 @@ T*k (token, expert) rows are sorted by expert, so that each expert's rows
 are one contiguous group of whatever size the router made it, the experts
 run over the ragged groups (`jax.lax.ragged_dot`, which XLA:TPU lowers to
 a grouped-matmul kernel), and the rows go back to their tokens and are
-summed with their weights.  Shared by `models/olmoe.py` (SiLU-gated
-experts), `models/gpt2.py`'s mixture (GELU experts), and
-`models/deepseek_v3.py` and `models/lfm2_moe.py` (a chip's share of the
-experts), which also share the router at the end of this file: sigmoid
-scores, a routing bias that picks and does not weigh and moves by a rule
-of its own (`sigmoid_route`, `routing_bias_rule`, `trained_by`).
+summed with their weights.  Shared by `models/layers.py:routed_layer`
+(`models/deepseek_v3.py`, `models/lfm2_moe.py` and `models/nemotron_h.py`:
+a chip's share of the experts), `models/olmoe.py` (all of them) and
+`models/gpt2.py`'s mixture (GELU experts).  The first three also share the
+router at the end of this file and what a step says of it: sigmoid scores,
+a routing bias that picks and does not weigh and moves by a rule of its own
+(`sigmoid_route`, `routing_account`, `routing_bias_rule`, `trained_by`).
 
 Where all the experts live here, the buffer between dispatch and combine
 is the T*k rows.  Where a share of under half of them does (``held``), it
@@ -345,12 +346,42 @@ def sigmoid_route(xt, router, top_k, eps, scale):
     return weights * scale, experts
 
 
+def routing_account(params, routed_layers, rows, routed, held):
+    """What a step's `out` says of its routers, device values that cost
+    nothing unless fetched.  ``rows``: what each routed layer sent each of
+    all the experts (N,), one entry a layer in the order the layers were
+    walked, which is ``routed_layers``' (the i of ``params[f"layer_{i}"]
+    ["moe"]["router"]``); ``routed`` = T*k rows a layer routes; ``held`` =
+    (first, count) as `moe_dispatch`'s.  -> {"expert_rows": (routed layers,
+    N), row j the j-th of ``routed_layers``; "rows_held": rows the held
+    experts computed, over the layers; "moe_overflow_layers": routed layers
+    whose held experts were sent more than `buffer_rows` and ran over all
+    the routed rows instead (exact, and slower); "max_expert_rows";
+    "max_routing_bias": |b| as the step used it, 0 where no router has a
+    bias}."""
+    rows = jnp.stack(rows)
+    n_experts = rows.shape[1]
+    first, count = held or (0, n_experts)
+    sent = jnp.sum(rows[:, first:first + count], axis=1)
+    routers = [params[f"layer_{i}"]["moe"]["router"] for i in routed_layers]
+    biases = [r[ROUTING_BIAS] for r in routers if ROUTING_BIAS in r]
+    biases = jnp.stack(biases) if biases else jnp.zeros((1,), jnp.float32)
+    return {
+        "expert_rows": rows,
+        "rows_held": jnp.sum(sent),
+        "moe_overflow_layers": jnp.sum(
+            sent > buffer_rows(routed, count, n_experts), dtype=jnp.int32),
+        "max_expert_rows": jnp.max(rows),
+        "max_routing_bias": jnp.max(jnp.abs(biases)),
+    }
+
+
 def routing_bias_rule(routed_layers, speed):
     """rule(params, out) -> params for `models/layers.py:train_step`: the
-    bias of each routed layer (``params[f"layer_{i}"]["moe"]["router"]``,
-    i in ``routed_layers``, in the order of `out["expert_rows"]`'s rows)
-    moves ``speed`` towards the experts that were sent fewer rows than the
-    mean: b_e += speed * sign(mean(n) - n_e) (arXiv:2412.19437)."""
+    bias of each routed layer (``routed_layers`` and `out["expert_rows"]`
+    as `routing_account` orders them) moves ``speed`` towards the experts
+    that were sent fewer rows than the mean:
+    b_e += speed * sign(mean(n) - n_e) (arXiv:2412.19437)."""
     def rule(params, out):
         with jax.named_scope("routing_bias_update"):
             params = dict(params)

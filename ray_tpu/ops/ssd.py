@@ -71,7 +71,7 @@ multiple of 8 and whose R is a multiple of 8 or all the heads
 other shape runs the batched `einsum`s of `_ssd_einsum`, the form of PR 38,
 and says so (`SsdFallbackWarning`).  That form stays for them, as the
 reference the kernels are timed and tested beside, and as what any platform
-but a TPU runs beyond the interpreter's sizes (`_by_platform`): XLA passes
+but a TPU runs beyond the interpreter's sizes (`ops.by_platform`): XLA passes
 its (Q, Q) decays and scores through HBM, 2 GiB a layer and pass.
 
 Counts itself on the job timeline as the step is traced: `ssm.layers` (one
@@ -90,9 +90,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# the compiled kernel where a call is lowered for a TPU; elsewhere the kernel
-# interpreted up to the tests' sizes and the reference beyond them
-from ray_tpu.ops.flash_attention import _INTERPRET_MAX_ELEMS, _by_platform
+from ray_tpu.ops import by_platform, interpreted
 from ray_tpu.util import tracing
 
 _LANE = 128
@@ -596,19 +594,13 @@ def _backward(x, dt, A, B, C, D, dy, Q, interpret=False):
             dD.astype(D.dtype))
 
 
-def _interpreted(x):
-    """Whether a platform that is no TPU interprets the kernels at x's
-    size (`_by_platform`'s rule)."""
-    return x.size <= _INTERPRET_MAX_ELEMS
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _ssd_kernels(x, dt, A, B, C, D, Q):
     return _ssd_kernels_fwd(x, dt, A, B, C, D, Q)[0]
 
 
 def _ssd_kernels_fwd(x, dt, A, B, C, D, Q):
-    y = _by_platform(
+    y = by_platform(
         lambda *a, interpret: _forward(*a, Q=Q, interpret=interpret),
         lambda *a: _ssd_einsum(*a, Q), x, dt, A, B, C, D)
     return y, (x, dt, A, B, C, D)
@@ -619,7 +611,7 @@ def _ssd_kernels_bwd(Q, inputs, dy):
         *inputs, dy = a
         return jax.vjp(lambda *v: _ssd_einsum(*v, Q), *inputs)[1](dy)
 
-    return _by_platform(
+    return by_platform(
         lambda *a, interpret: _backward(*a, Q=Q, interpret=interpret),
         reference, *inputs, dy)
 
@@ -655,7 +647,7 @@ def ssd_scan(x, dt, A, B, C, D, chunk):
             SsdFallbackWarning, stacklevel=2)
         y = _ssd_einsum(x, dt, A, B, C, D, Q)
     else:
-        if _interpreted(x) or jax.default_backend() == "tpu":
+        if interpreted(x) or jax.default_backend() == "tpu":
             tracing.count("ssm.kernel_layers")
         y = _ssd_kernels(x, dt, A, B, C, D, Q)
     return y[:, :S]

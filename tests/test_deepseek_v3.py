@@ -22,7 +22,7 @@ import pytest
 
 from benchmark.families.deepseek_v3 import to_reference
 from benchmark.reference import deepseek_v3 as reference
-from ray_tpu.models import deepseek_v3 as model
+from ray_tpu.models import deepseek_v3 as model, layers
 from ray_tpu.parallel.sharding import infer_param_logical_dims
 
 F32 = dataclasses.replace(model.DEEPSEEK_V3_TINY, compute_dtype=jnp.float32)
@@ -223,10 +223,10 @@ def test_the_bias_changes_the_choice_and_not_the_weights():
     router = params["layer_1"]["moe"]["router"]
     xt = jax.random.normal(jax.random.PRNGKey(5), (128, cfg.n_embd))
     scores = jax.nn.sigmoid(xt @ router["kernel"])
-    w0, e0 = model._route(xt, router, cfg)
+    w0, e0 = model._route(cfg)(xt, router)
     # a bias that lifts expert 7 over everything and sinks expert 0
     bias = jnp.zeros(8).at[7].set(2.0).at[0].set(-2.0)
-    w1, e1 = model._route(xt, {**router, BIAS: bias}, cfg)
+    w1, e1 = model._route(cfg)(xt, {**router, BIAS: bias})
     assert (np.asarray(e1)[:, 0] == 7).all() and not (np.asarray(e1) == 0).any()
     assert (np.asarray(e0) != np.asarray(e1)).any()
     for w, e in ((w0, e0), (w1, e1)):
@@ -242,14 +242,14 @@ def test_the_shares_add_up_to_the_uncut_layer():
     params = make_params()
     p = params["layer_1"]["moe"]
     u = jax.random.normal(jax.random.PRNGKey(9), (BATCH, SEQ, F32.n_embd))
-    shared = model._mlp(u.reshape(-1, F32.n_embd), p["shared"]).reshape(
-        u.shape)
+    shared = layers.dense_ffn(u, p["shared"], layers.swiglu)
     routed, rows = 0, []
     for first in range(0, 8, 2):
         cfg = dataclasses.replace(F32, held=(first, 2))
         share = {**p, **{k: p[k][first:first + 2]
                          for k in ("wi_gate", "wi_up", "wo")}}
-        y, sent = model._moe(u, share, cfg)
+        y, sent = layers.routed_layer(u, share, model._route(cfg),
+                                      cfg.n_experts, cfg.held, layers.swiglu)
         routed += y - shared
         rows.append(sent)
     whole, biases = to_reference(params)

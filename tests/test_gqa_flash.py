@@ -315,7 +315,7 @@ def test_the_kernels_count_the_heads_they_read():
 
 
 # (query heads, key/value heads, q/k width, v width, S, tile): every shape
-# stays within what a non-TPU backend interprets (`_INTERPRET_MAX_ELEMS`)
+# stays within what a non-TPU backend interprets (`ops.INTERPRET_MAX_ELEMS`)
 LONG_SHAPES = {
     "heads of 64": (2, 2, 64, 64, 256, 128),
     "heads of 128": (2, 2, 128, 128, 256, 128),

@@ -24,7 +24,7 @@ import pytest
 
 from benchmark.families.lfm2_moe import to_reference
 from benchmark.reference import lfm2_moe as reference
-from ray_tpu.models import lfm2_moe as model
+from ray_tpu.models import layers, lfm2_moe as model
 from ray_tpu.parallel.sharding import infer_param_logical_dims
 
 CONV, ATTN = model.CONV, model.ATTENTION
@@ -251,8 +251,8 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     for first in range(0, 64, 8):
         share = {**p, **{k: p[k][first:first + 8]
                          for k in ("wi_gate", "wi_up", "wo")}}
-        y, sent = model._moe(u, share, dataclasses.replace(
-            cfg, held=(first, 8)))
+        y, sent = layers.routed_layer(u, share, model._route(cfg), 64,
+                                      (first, 8), layers.swiglu)
         total += y
         rows.append(sent)
     whole, biases = to_reference(params)

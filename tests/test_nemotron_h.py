@@ -236,13 +236,12 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
     params = make_params(cfg=cfg)
     p = params["layer_1"]["moe"]
     u = jax.random.normal(jax.random.PRNGKey(9), (BATCH, SEQ, cfg.n_embd))
-    shared = model._relu2(u, p["shared"]["up_proj"]["kernel"],
-                          p["shared"]["down_proj"]["kernel"])
+    shared = layers.dense_ffn(u, p["shared"], layers.relu2)
     total, rows = shared, []
     for first in range(0, 128, 8):
         share = {**p, **{k: p[k][first:first + 8] for k in ("wi_up", "wo")}}
-        y, sent = model._moe(u, share, dataclasses.replace(
-            cfg, held=(first, 8)))
+        y, sent = layers.routed_layer(u, share, model._route(cfg), 128,
+                                      (first, 8), layers.relu2)
         total += y - shared          # every chip has the shared expert whole
         rows.append(sent)
     whole, biases = to_reference(params)

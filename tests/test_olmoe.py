@@ -323,7 +323,9 @@ def test_dispatch_alone_against_the_dense_loop(routing):
 
     def got(x, weights, wi_gate, wi_up, wo):
         return olmoe.moe_dispatch(
-            x, weights, experts, n, olmoe._gated_experts(wi_gate, wi_up, wo))
+            x, weights, experts, n, layers.grouped_ffn(
+                {"wi_gate": wi_gate, "wi_up": wi_up, "wo": wo},
+                layers.swiglu))
 
     y, group_sizes = got(x, weights, wi_gate, wi_up, wo)
     assert int(jnp.sum(group_sizes)) == tokens * k            # dropless

@@ -730,9 +730,10 @@ def test_xl_s_own_backward_under_a_checkpointed_layer(monkeypatch):
     kernels' own (B*H, S, 1) so that the replay runs no forward kernel.
     Gradients against the dense reference's."""
     from ray_tpu.models.layers import checkpoint_layer
+    from ray_tpu import ops
     from ray_tpu.ops import flash_attention as fa
 
-    monkeypatch.setattr(fa, "_INTERPRET_MAX_ELEMS", 1 << 20)
+    monkeypatch.setattr(ops, "INTERPRET_MAX_ELEMS", 1 << 20)
     B, S, H, D = 1, 256, 25, 64
     q, k, v = (x.astype(jnp.bfloat16) for x in _bshd_qkv(S, H, D))
     tr = lambda x: x.transpose(0, 2, 1, 3)

@@ -62,6 +62,12 @@ COUNTER = "remat.residuals_kept"
 ROOMY = 1 << 40
 
 
+def walker(module):
+    """The module whose walk calls `checkpoint_layer`: GPT-2's own, and
+    `layers.trunk` for every other model."""
+    return gpt2 if module is gpt2 else layers
+
+
 def forward_calls(lowered_text):
     """Calls of the jitted forward kernel in a lowered module (a second
     instance of the same function is `_pallas_forward_<n>`)."""
@@ -113,7 +119,8 @@ def test_remat_runs_the_forward_kernel_once_a_layer(name, monkeypatch):
 
     # the parent's layer, a bare `jax.checkpoint`: every kernel twice, the
     # same loss and gradients to the last bit
-    monkeypatch.setattr(module, "checkpoint_layer", bare_checkpoint)
+    monkeypatch.setattr(walker(module), "checkpoint_layer",
+                        bare_checkpoint)
     bare_text, (bare_loss, bare_grads), bare_kept = grad_of(
         module, cfg, remat=True)
     assert forward_calls(bare_text) == 2 * attention_layers
@@ -273,12 +280,16 @@ def test_a_checkpointed_pipeline_lowers_and_agrees():
 
 def test_one_function_owns_the_policy():
     """Every per-layer `jax.checkpoint` of `models/` and of the pipeline
-    goes through `checkpoint_layer`; the chunked loss's own is no layer."""
+    goes through `checkpoint_layer`: GPT-2's walk, the pipeline's and
+    `layers.trunk`, which walks every other model; the chunked loss's own
+    is no layer."""
     import inspect
 
-    for module in (gpt2, deepseek_v3, lfm2_moe, olmoe, pipeline):
+    for walk in (gpt2, pipeline, layers.trunk):
+        assert "checkpoint_layer(" in inspect.getsource(walk), walk.__name__
+    for module in (gpt2, deepseek_v3, lfm2_moe, nemotron_h, olmoe, pipeline):
         source = inspect.getsource(module)
-        assert "checkpoint_layer(" in source, module.__name__
+        assert module in (gpt2, pipeline) or "trunk(" in source
         assert not re.search(r"jax\.(checkpoint|remat)\(", source), \
             module.__name__
 
@@ -365,7 +376,8 @@ def test_with_room_the_replay_multiplies_nothing_again(name, monkeypatch):
                                       if n in plan["marked"])
                for plan in plans)
     assert set(kept) <= UNNAMED, kept
-    monkeypatch.setattr(module, "checkpoint_layer", bare_checkpoint)
+    monkeypatch.setattr(walker(module), "checkpoint_layer",
+                        bare_checkpoint)
     bare = replayed_matmuls(module, cfg)
     assert set(bare) - UNNAMED
     assert all(bare[scope] >= kept[scope] for scope in UNNAMED)
@@ -391,7 +403,7 @@ def test_with_no_room_the_layer_is_the_parents(name, monkeypatch):
             fn, policy=jax.checkpoint_policies.save_only_these_names(
                 *KEPT_RESIDUALS), **kw)
 
-    monkeypatch.setattr(module, "checkpoint_layer", pr35)
+    monkeypatch.setattr(walker(module), "checkpoint_layer", pr35)
     assert backward_primitives(module, cfg, remat=True) == none
 
 

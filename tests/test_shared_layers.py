@@ -1,0 +1,362 @@
+"""What the routed models share since PR 46, each piece alone against a
+plain form written here: the routed layer (`models/layers.py:routed_layer`)
+against a loop over tokens and their choices, the walk over a decoder's
+layers (`layers.trunk`) against the loop it replaces, the routers' account
+(`ops/moe.py:routing_account`) on rows made by hand, and the head with its
+chunked loss (`layers.head_and_loss`) against dense logits.  Float32, small
+sizes, the CPU.
+"""
+
+import dataclasses
+import functools
+import itertools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import layers
+from ray_tpu.ops import moe
+from ray_tpu.ops.moe import ROUTING_BIAS
+
+B, S, E = 2, 16, 16
+N, K = 8, 2
+WIDTH, SHARED_WIDTH = 24, 40        # no multiple of the grouped width
+TOL = 2e-5
+
+
+@pytest.fixture(autouse=True)
+def highest_precision():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def max_diff(a, b):
+    return float(jnp.max(jnp.abs(jnp.asarray(a, jnp.float32)
+                                 - jnp.asarray(b, jnp.float32))))
+
+
+# -- the routed layer ---------------------------------------------------------
+
+FFNS = {"swiglu": (layers.swiglu, ("wi_gate", "wi_up", "wo"),
+                   ("gate_proj", "up_proj", "down_proj")),
+        "relu2": (layers.relu2, ("wi_up", "wo"), ("up_proj", "down_proj"))}
+
+
+def softmax_route(xt, router):
+    probs = jax.nn.softmax((xt @ router["kernel"]).astype(jnp.float32), -1)
+    return jax.lax.top_k(probs, K)
+
+
+ROUTES = {
+    "sigmoid_bias": functools.partial(moe.sigmoid_route, top_k=K, eps=1e-20,
+                                      scale=2.5),
+    "sigmoid_plain": functools.partial(moe.sigmoid_route, top_k=K, eps=None,
+                                       scale=1.0),
+    "softmax": softmax_route,
+}
+
+
+def routed_params(route, ffn, shared, held, width=WIDTH, seed=0):
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 12))
+    normal = lambda *shape: jax.random.normal(next(keys), shape) * 0.3
+    first, count = held or (0, N)
+    p = {"router": {"kernel": normal(E, N)}}
+    if route == "sigmoid_bias":
+        p["router"][ROUTING_BIAS] = normal(N) * 0.5
+    for name in FFNS[ffn][1]:
+        p[name] = normal(count, width, E) if name == "wo" \
+            else normal(count, E, width)
+    if shared:
+        p["shared"] = {
+            name: {"kernel": normal(SHARED_WIDTH, E) if name == "down_proj"
+                   else normal(E, SHARED_WIDTH)} for name in FFNS[ffn][2]}
+    return p
+
+
+def plain_ffn(ffn, x, weights):
+    """One token through one expert, as the model files' docstrings write
+    it."""
+    if ffn == "swiglu":
+        gate, up, down = weights
+        return (jax.nn.silu(x @ gate) * (x @ up)) @ down
+    up, down = weights
+    return jnp.square(jnp.maximum(x @ up, 0)) @ down
+
+
+def scores_of(route, xt, router):
+    logits = xt @ router["kernel"]
+    return jax.nn.softmax(logits, -1) if route == "softmax" \
+        else jax.nn.sigmoid(logits)
+
+
+def choices(route, xt, router):
+    """(T, K) numpy: each token's experts, best first."""
+    picks = scores_of(route, xt, router) + router.get(ROUTING_BIAS, 0.0)
+    return np.argsort(-np.asarray(picks), axis=-1, kind="stable")[:, :K]
+
+
+def token_loop(x, p, chosen, route, ffn, held):
+    """The layer as a loop over tokens and their choices; ``chosen`` is
+    concrete, the weights are differentiated through."""
+    xt = x.reshape(-1, E)
+    first, count = held or (0, N)
+    scores = scores_of(route, xt, p["router"])
+    out = []
+    for t in range(xt.shape[0]):
+        picked = jnp.stack([scores[t, e] for e in chosen[t]])
+        if route == "sigmoid_bias":
+            picked = picked / (jnp.sum(picked) + 1e-20) * 2.5
+        y = jnp.zeros((E,))
+        for w, e in zip(picked, chosen[t]):
+            if first <= e < first + count:
+                y = y + w * plain_ffn(ffn, xt[t], [
+                    p[name][e - first] for name in FFNS[ffn][1]])
+        if "shared" in p:
+            y = y + plain_ffn(ffn, xt[t], [
+                p["shared"][name]["kernel"] for name in FFNS[ffn][2]])
+        out.append(y)
+    return jnp.stack(out).reshape(x.shape)
+
+
+ROUTED_CASES = list(itertools.product(
+    ROUTES, FFNS, ("shared", "alone"), ("all_held", "a_share")))
+
+
+@pytest.mark.parametrize("route, ffn, shared, share", ROUTED_CASES)
+def test_the_routed_layer_is_the_loop_over_tokens(route, ffn, shared, share):
+    held = None if share == "all_held" else (2, 4)
+    p = routed_params(route, ffn, shared == "shared", held)
+    x = jax.random.normal(jax.random.PRNGKey(7), (B, S, E))
+    chosen = choices(route, x.reshape(-1, E), p["router"])
+
+    def got(x, p):
+        return layers.routed_layer(x, p, ROUTES[route], N, held,
+                                   FFNS[ffn][0])
+
+    y, rows = got(x, p)
+    assert max_diff(y, token_loop(x, p, chosen, route, ffn, held)) < TOL
+    # over ALL the experts, whatever is held
+    np.testing.assert_array_equal(
+        np.asarray(rows), np.bincount(chosen.ravel(), minlength=N))
+    loss = lambda f: lambda x, p: jnp.sum(f(x, p) ** 2)
+    grads = jax.grad(loss(lambda x, p: got(x, p)[0]), (0, 1))(x, p)
+    wants = jax.grad(loss(lambda x, p: token_loop(
+        x, p, chosen, route, ffn, held)), (0, 1))(x, p)
+    for (path, want), g in zip(
+            jax.tree_util.tree_flatten_with_path(wants)[0],
+            jax.tree.leaves(grads)):
+        if path[-1] != jax.tree_util.DictKey(ROUTING_BIAS):  # picks only
+            assert max_diff(g, want) < 1e-4 * max(
+                1.0, float(jnp.max(jnp.abs(want)))), jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("ffn", sorted(FFNS))
+def test_a_widened_stack_gives_the_unpadded_result(ffn, monkeypatch):
+    """24 wide runs 256 wide (`layers._widened`): the zeros add nothing to
+    the output and take no gradient, and the parameters keep their
+    shape."""
+    p = routed_params("sigmoid_bias", ffn, True, (0, 4))
+    x = jax.random.normal(jax.random.PRNGKey(8), (B, S, E))
+
+    def step(x, p):
+        def loss(x, p):
+            y, _ = layers.routed_layer(x, p, ROUTES["sigmoid_bias"], N,
+                                       (0, 4), FFNS[ffn][0])
+            return jnp.sum(y ** 2), y
+        return jax.value_and_grad(loss, (0, 1), has_aux=True)(x, p)
+
+    seen = []
+    real = jax.lax.ragged_dot
+    monkeypatch.setattr(jax.lax, "ragged_dot", lambda a, w, sizes: (
+        seen.append(w.shape), real(a, w, sizes))[1])
+    (_, y), grads = step(x, p)
+    assert {256} == {s[2] for s in seen if s[1] == E} \
+        == {s[1] for s in seen if s[2] == E}
+    seen.clear()
+    monkeypatch.setattr(layers, "_GROUPED_WIDTH", 1)
+    (_, y0), grads0 = step(x, p)
+    assert {WIDTH} == {s[2] for s in seen if s[1] == E}
+    assert max_diff(y, y0) < 1e-6
+    assert jax.tree.map(jnp.shape, grads) == jax.tree.map(jnp.shape, grads0)
+    assert max(jax.tree.leaves(jax.tree.map(max_diff, grads, grads0))) < 1e-5
+
+
+def test_a_width_in_whole_256s_is_left_alone():
+    """The published widths that are multiples (768, 1,024, 1,792): the
+    stack itself, no pad of width nothing for XLA to find."""
+    w = jnp.ones((2, E, 512))
+    assert layers._widened(w, 2) is w
+    assert layers._widened(jnp.ones((2, 300, E)), 1).shape == (2, 512, E)
+
+
+# -- the walk over the layers -------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class Walked:
+    n_layer: int = 4
+    vocab_size: int = 32
+    remat: bool = False
+    compute_dtype: type = jnp.float32
+    loss_chunk_rows: int = 8
+    rms_eps: float = 1e-5
+
+
+def walked_layer(x, p, cfg):
+    """A toy layer: every other one has a second result."""
+    x = x + jnp.tanh(x @ p["w"])
+    return x, (jnp.sum(x, axis=(0, 1)) if "counted" in p else None)
+
+
+def walked_params(cfg):
+    keys = jax.random.split(jax.random.PRNGKey(1), cfg.n_layer + 1)
+    params = {"embed_tokens": {"embedding": jax.random.normal(
+        keys[0], (cfg.vocab_size, E))},
+        "norm_f": {"scale": jnp.full((E,), 1.5)}}
+    for i in range(cfg.n_layer):
+        params[f"layer_{i}"] = {"w": jax.random.normal(keys[1 + i],
+                                                       (E, E)) * 0.3}
+        if i % 2:
+            params[f"layer_{i}"]["counted"] = jnp.zeros(())
+    return params
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_the_walk_is_the_loop_it_replaces(remat):
+    cfg = Walked(remat=remat)
+    params = walked_params(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (B, S), 0, 32)
+
+    def loop(params):
+        x = params["embed_tokens"]["embedding"][tokens]
+        seconds = []
+        for i in range(cfg.n_layer):
+            x, second = walked_layer(x, params[f"layer_{i}"], cfg)
+            seconds.append(second)
+        return layers.rms_norm(x, params["norm_f"], cfg.rms_eps), seconds
+
+    x, seconds = jax.jit(lambda p: layers.trunk(
+        p, tokens, walked_layer, cfg))(params)
+    want, want_seconds = loop(params)
+    # the layers without a second result are left out, the order kept
+    assert len(seconds) == 2 and want_seconds[0] is None
+    assert max_diff(x, want) < 1e-6
+    for got, wanted in zip(seconds, want_seconds[1::2]):
+        assert max_diff(got, wanted) < 1e-5
+    grad = jax.grad(lambda p: jnp.sum(layers.trunk(
+        p, tokens, walked_layer, cfg)[0] ** 2))(params)
+    want_grad = jax.grad(lambda p: jnp.sum(loop(p)[0] ** 2))(params)
+    assert max(jax.tree.leaves(jax.tree.map(max_diff, grad, want_grad))) \
+        < 1e-4
+
+
+def test_the_walk_recomputes_only_when_asked(monkeypatch):
+    """`remat` on: one `checkpoint_layer` over the whole stack, a chunk of
+    the head's logits behind it; off: none, the layer itself."""
+    asked = []
+
+    def recording(fn, stack=None, behind=(), **kw):
+        asked.append((len(stack), behind.shape, kw))
+        return fn
+
+    monkeypatch.setattr(layers, "checkpoint_layer", recording)
+    tokens = jnp.zeros((B, S), jnp.int32)
+    for remat in (False, True):
+        cfg = Walked(remat=remat)
+        layers.trunk(walked_params(cfg), tokens, walked_layer, cfg)
+    assert asked == [(4, (8, 32), {"static_argnums": (2,)})]
+
+
+# -- the routers' account -----------------------------------------------------
+
+def account_params(biases):
+    return {f"layer_{i}": {"moe": {"router": {
+        "kernel": jnp.zeros((E, 4)),
+        **({ROUTING_BIAS: jnp.asarray(b, jnp.float32)}
+           if b is not None else {})}}}
+        for i, b in biases.items()}
+
+
+ROWS = [[40, 0, 8, 16], [10, 30, 14, 10], [4, 5, 50, 5]]     # 64 a layer
+ACCOUNTS = {
+    # the buffer of a share of 1 of 4 experts is twice 64 / 4 = 32 rows:
+    # layers 0 and 2 send experts 0 and 2 more, layer 1 sends expert 1 30
+    "first_expert_held": ((0, 1), 40 + 10 + 4, 1),
+    "second_expert_held": ((1, 1), 0 + 30 + 5, 0),
+    "third_expert_held": ((2, 1), 8 + 14 + 50, 1),
+    # half of the experts and more: the buffer is all the rows
+    "two_held": ((0, 2), 40 + 40 + 9, 0),
+    "all_held": (None, 3 * 64, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ACCOUNTS))
+def test_the_account_counts_what_the_held_experts_were_sent(case):
+    held, rows_held, overflowed = ACCOUNTS[case]
+    params = account_params({1: [0.1, -0.4, 0, 0], 3: [0, 0, 0.2, 0],
+                             4: [0.3, 0, 0, -0.1]})
+    out = moe.routing_account(
+        params, (1, 3, 4), [jnp.asarray(r, jnp.int32) for r in ROWS], 64,
+        held)
+    np.testing.assert_array_equal(np.asarray(out["expert_rows"]), ROWS)
+    assert int(out["rows_held"]) == rows_held
+    assert int(out["moe_overflow_layers"]) == overflowed
+    assert out["moe_overflow_layers"].dtype == jnp.int32
+    assert int(out["max_expert_rows"]) == 50
+    assert float(out["max_routing_bias"]) == pytest.approx(0.4)
+    assert set(out) == {"expert_rows", "rows_held", "moe_overflow_layers",
+                        "max_expert_rows", "max_routing_bias"}
+
+
+def test_the_account_of_routers_without_a_bias():
+    out = moe.routing_account(
+        account_params({0: None, 2: None}), (0, 2),
+        [jnp.asarray(r, jnp.int32) for r in ROWS[:2]], 64, (0, 1))
+    assert float(out["max_routing_bias"]) == 0.0
+    assert int(out["rows_held"]) == 50 and int(out["moe_overflow_layers"]) == 1
+
+
+def test_the_rule_reads_the_account_in_its_order():
+    """Row j of `expert_rows` is the j-th of the routed layers, for the
+    account and for `routing_bias_rule` alike."""
+    layers_routed = (1, 3, 4)
+    params = account_params({i: [0.0] * 4 for i in layers_routed})
+    out = moe.routing_account(
+        params, layers_routed, [jnp.asarray(r, jnp.int32) for r in ROWS],
+        64, None)
+    moved = moe.routing_bias_rule(layers_routed, 0.5)(params, out)
+    for i, rows in zip(layers_routed, ROWS):
+        np.testing.assert_allclose(
+            moved[f"layer_{i}"]["moe"]["router"][ROUTING_BIAS],
+            0.5 * np.sign(16 - np.asarray(rows)))
+
+
+# -- the head and its loss ----------------------------------------------------
+
+@pytest.mark.parametrize("head", ["tied", "untied"])
+@pytest.mark.parametrize("chunk_rows", [8, 12, 1000])
+def test_the_head_and_loss_is_the_dense_cross_entropy(head, chunk_rows):
+    V = 48
+    keys = jax.random.split(jax.random.PRNGKey(4), 3)
+    x = jax.random.normal(keys[0], (B, S, E))
+    targets = jax.random.randint(keys[1], (B, S), 0, V)
+    rows = jax.random.normal(keys[2], (V, E))
+    p = {"embedding": rows} if head == "tied" else {"kernel": rows.T}
+
+    def dense(x, rows):
+        logp = jax.nn.log_softmax(x @ rows.T, axis=-1)
+        return -jnp.mean(jnp.take_along_axis(logp, targets[..., None], -1))
+
+    def got(x, rows):
+        return layers.head_and_loss(
+            x, jax.tree.map(lambda _: rows if head == "tied" else rows.T, p),
+            targets, chunk_rows)
+
+    assert float(got(x, rows)) == pytest.approx(float(dense(x, rows)),
+                                                rel=1e-6)
+    for g, want in zip(jax.grad(got, (0, 1))(x, rows),
+                       jax.grad(dense, (0, 1))(x, rows)):
+        assert max_diff(g, want) < 1e-6
+    lowered = jax.jit(got).lower(x, rows).as_text(debug_info=True)
+    assert "head_and_loss" in lowered

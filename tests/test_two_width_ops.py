@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops import flash_attention as fa
+from ray_tpu.models import layers
 from ray_tpu.ops import moe
 from ray_tpu.ops.moe import moe_dispatch
 from ray_tpu.parallel.attention import attention
@@ -449,7 +450,7 @@ def test_the_model_says_which_layers_overflowed_and_stays_exact(
     held = np.asarray(out["expert_rows"])[:, :2].sum(axis=1)
     assert int(out["moe_overflow_layers"]) == (2 if favoured else 0)
     assert ((held > buffer) == bool(favoured)).all()
-    monkeypatch.setattr(model, "moe_dispatch", _dispatch_over_all_rows)
+    monkeypatch.setattr(layers, "moe_dispatch", _dispatch_over_all_rows)
     (loss0, out0), grads0 = step()
     assert abs(float(loss) - float(loss0)) < 1e-5
     assert int(out["rows_held"]) == int(out0["rows_held"]) == held.sum()
