@@ -52,6 +52,11 @@ SCOPES = (
     "attention/qkv",
     "attention/latent_down",
     "attention/latent_up",
+    "attention/indexer",
+    "attention/indexer/proj",
+    "attention/indexer/scores",
+    "attention/indexer/select",
+    "attention/indexer/loss",
     "attention/kernel",
     *(f"attention/kernel/{form}" for form in KERNEL_FORMS),
     "attention/out",
@@ -343,6 +348,9 @@ def gated_rms_norm(y, z, p, groups, eps=1e-5):
 KEPT_NAMES = (
     ROUTE_NAME,                 # a router's product (T, N), the experts it
                                 # chose (T, k) and the rows' order by expert
+    "attention/indexer/select", # the mask of the keys each query attends,
+                                # (B, S, S) int8: kept, a replay searches
+                                # no threshold (16 passes over the scores)
     "attention/latent_down",    # DeepSeek-V3's [c | k_r], W_kv_a's result
     "attention/out",            # W_o's result, as wide as the stream
     "short_conv/out_proj",      # W_out's result, the same
@@ -360,6 +368,9 @@ KEPT_NAMES = (
                                 # GiB (PERF.md §6, PR 39)
     "attention/latent_up",      # k and v multiplied out of the latent: the
                                 # widest and the cheapest to remake
+    "attention/indexer/scores", # an indexer's I, (B, S, S) float32: four
+                                # times the mask, and its backward makes a
+                                # block's products again either way
 )
 
 # Of the device's memory limit, the share the budget never spends: the

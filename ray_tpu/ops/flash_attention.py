@@ -66,6 +66,20 @@ a checkpointed layer whose policy keeps them
 (`models/layers.py:checkpoint_layer`) recomputes its forward pass without
 running the forward kernel a second time.
 
+A mask that is DATA: ``mask`` (B, S, S) int8, one value a (query, key)
+pair, not 0 where the pair is attended, shared by all heads of a sequence
+(attention that selects its keys: `ops/sparse_index.py`; a window or a
+segment rule filling the same array).  It is an operand of the head-major
+forward kernel and of the one backward kernel, combined with ``causal``.
+Up to `_WHOLE_SEQ_MAX` a grid step holds a sequence's whole (S, S) mask and
+slices it as it slices k.  Past it the mask comes tile-major (`_tile_major`:
+each (block_q, block_k) tile contiguous, the q tile's row of them in the
+forward, the k tile's column of them in the backward, so a loop picks a
+tile by a leading index: `_mask_specs`).  Every causal tile is visited
+whatever the mask holds of it.  The lane layout declines a mask as it
+declines grouped queries, and the call takes the head-major kernels.  A
+call without a mask is the program it was: no operand, no instruction.
+
 Each kernel adds its tiles and the heads it reads to the job timeline as
 the step is traced (`attention.tiles`, `attention.tiles_skipped`,
 `attention.q_heads`, `attention.kv_heads`: see `_count_tiles`).
@@ -154,7 +168,7 @@ def _bwd_held_bytes(S, D, Dv, dtype):
                 + _lanes(D) * 4)                        # the scratch
 
 
-def _compiler_params(S, D, Dv, dtype, bwd_steps=1):
+def _compiler_params(S, D, Dv, dtype, bwd_steps=1, extra=0):
     """`_COMPILER_PARAMS`, with a higher limit of scoped VMEM where the
     operands a head-major kernel holds for the whole sequence (a width
     padded to whole lanes, every block double-buffered) leave a tile's
@@ -173,13 +187,18 @@ def _compiler_params(S, D, Dv, dtype, bwd_steps=1):
     is that plus the default's 16 MB for the k, v, dk, dv tiles and a
     tile's temporaries, `_VMEM_MAX` at most: S = 16,384 fits at every width
     a cell has; a slice that leaves a tile no room (S = 32,768) never gets
-    here (`_tiling_problem`)."""
+    here (`_tiling_problem`).
+
+    ``extra``: bytes of further blocks a step holds, double-buffered: a
+    mask's (`_mask_block_bytes`)."""
     if bwd_steps > 1:
         return pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=min(
-                _VMEM_MAX, _SCOPED_VMEM + _bwd_held_bytes(S, D, Dv, dtype)))
-    resident = 2 * S * (_lanes(D) + _lanes(Dv)) * jnp.dtype(dtype).itemsize
+                _VMEM_MAX,
+                _SCOPED_VMEM + _bwd_held_bytes(S, D, Dv, dtype) + extra))
+    resident = 2 * S * (_lanes(D) + _lanes(Dv)) * jnp.dtype(dtype).itemsize \
+        + extra
     if resident <= _SCOPED_VMEM - _TILE_VMEM:
         return _COMPILER_PARAMS
     return pltpu.CompilerParams(
@@ -216,7 +235,7 @@ def _causal_mask(s, q_start, k_start):
 
 
 def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
-              seq_len, v_dim):
+              seq_len, v_dim, select=None):
     """Online-softmax forward over one q tile.
 
     At small head_dim the two dots leave the matrix unit half full and the
@@ -239,7 +258,13 @@ def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
     two steps at most; `_tile_loop` loops over them.  read_k/read_v:
     (start, rows) -> (rows, d) of k and (rows, v_dim) of v, which may be
     another width (latent attention: 192 and 128).  Returns (acc f32
-    (block_q, v_dim), m, l)."""
+    (block_q, v_dim), m, l).
+
+    ``select``: (start, rows) -> (block_q, rows), not 0 where the q tile's
+    row attends the key (a mask that is data; with ``causal``, both hold).
+    A row none of whose keys has come yet carries m = -1e30 and sums
+    garbage, which its first attended key's alpha = 0 wipes; every row of a
+    mask attends a key."""
 
     num_k_blocks = seq_len // block_k
 
@@ -253,6 +278,8 @@ def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
         )  # (bq, rows) f32
         if masked:
             s = _causal_mask(s, qi * block_q, start)
+        if select is not None:
+            s = jnp.where(select(start, rows) != 0, s, _NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp2(m_prev - m_new)
         p = jnp.exp2((s - m_new).astype(v.dtype))  # bf16: 2x VPU lanes
@@ -300,7 +327,8 @@ def _when(cond):
 
 def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dq_ref, dk_ref, dv_ref, dq_acc, cols, stat, *,
-                    sm_scale, causal, block_q, block_k, seq_len):
+                    sm_scale, causal, block_q, block_k, seq_len,
+                    selection=()):
     """One head's backward, at every length: for each k tile, the q tiles
     the diagonal crosses masked and the q tiles below it unmasked (q tiles
     above are not visited); each recomputes s and dp ONCE and contracts
@@ -324,7 +352,12 @@ def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     its column of lse/delta (natural-log lse, f32 delta).  ``dq_acc`` is an
     f32 (S, head_dim) scratch: dq sums over k tiles there, zeroed at the
     slice's first step and scaled and written at its last.  q, k and dq, dk
-    have one width, v, do and dv may have another."""
+    have one width, v, do and dv may have another.
+
+    ``selection``: the ref of a mask that is data (`_mask_specs`), none
+    without one.  All S: the sequence's (S, S) mask, sliced as q and k are.
+    One tile: the k tile's column of (block_q, block_k) mask tiles, picked
+    by the q tile."""
     k_rows_held = k_ref.shape[0]
     steps, tiles = seq_len // k_rows_held, k_rows_held // block_k
     step, over = (0, _span) if steps == 1 else (pl.program_id(1), _tile_loop)
@@ -355,6 +388,11 @@ def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 preferred_element_type=jnp.float32)   # (rows, bk) f32
             if masked:
                 s = _causal_mask(s, start, kj * block_k)
+            if selection:
+                mask_ref = selection[0]
+                chosen = mask_ref[q_rows, k_rows] if steps == 1 \
+                    else mask_ref[start // block_q]
+                s = jnp.where(chosen != 0, s, _NEG_INF)
             p = jnp.exp2((s - lse).astype(k.dtype))   # bf16; masked -> 0
             dp = jax.lax.dot_general(
                 do, v, (((1,), (1,)), ((), ())),
@@ -420,20 +458,38 @@ def _fwd_rows(whole, seq_len, block_q):
             for i in range(seq_len // block_q)]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
-                block_q, block_k, seq_len, whole):
+def _fwd_select(selection, whole, rows, block_k):
+    """`_fwd_core`'s ``select`` from the ref of a mask that is data
+    (`_mask_specs`; none without one).  ``whole``: the sequence's (S, S)
+    mask, the q tile's ``rows`` of it sliced as k is.  Else the q tile's
+    row of (block_q, block_k) mask tiles, picked by the k block."""
+    if not selection:
+        return None
+    mask_ref, = selection
+    if whole:
+        return lambda start, n: mask_ref[rows, pl.ds(start, n)]
+    return lambda start, n: mask_ref[start // block_k]
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal, block_q,
+                block_k, seq_len, whole):
+    *selection, o_ref, lse_ref = rest
     for qi, rows, over in _fwd_rows(whole, seq_len, block_q):
         q = q_ref[rows, :] * jnp.asarray(sm_scale * _LOG2E, q_ref.dtype)
         acc, m, l = _fwd_core(
             q, lambda start, n: k_ref[pl.ds(start, n), :],
             lambda start, n: v_ref[pl.ds(start, n), :], qi, over,
             causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len,
-            v_dim=v_ref.shape[-1])
+            v_dim=v_ref.shape[-1],
+            select=_fwd_select(selection, whole, rows, block_k))
         o_ref[rows, :], lse_ref[rows, :] = _finish_fwd(acc, m, l, o_ref.dtype)
 
 
 def _bwd_fused_kernel(*refs, **tiling):
-    _bwd_fused_core(*refs, slice(None), pl.ds(0, 1), **tiling)
+    """refs: q, k, v, do, lse, delta; a mask's (`_mask_specs`), if any; dq,
+    dk, dv; the dq scratch."""
+    _bwd_fused_core(*refs[:6], *refs[-4:], slice(None), pl.ds(0, 1),
+                    selection=refs[6:-4], **tiling)
 
 
 def _kv_rows(group, last=0):
@@ -457,13 +513,52 @@ def _sum_groups(partials, like):
         like.dtype)
 
 
+def _tile_major(mask, block_q, block_k, k_major=False):
+    """mask (B, S, S) -> its (block_q, block_k) tiles, each contiguous:
+    (B, S / block_q, S / block_k, block_q, block_k), or with the k tiles
+    leading.  A kernel's loop then picks a tile by a leading index, which
+    Mosaic takes at any offset; a slice of the mask's lanes it does not."""
+    B, S, _ = mask.shape
+    tiles = mask.reshape(B, S // block_q, block_q, S // block_k, block_k)
+    return tiles.transpose((0, 3, 1, 2, 4) if k_major else (0, 1, 3, 2, 4))
+
+
+def _mask_block_bytes(S, rows):
+    """What a grid step holds of a mask, double-buffered: ``rows`` of its S
+    columns (or S rows of as many columns), a byte a pair."""
+    return 2 * rows * S
+
+
+def _mask_specs(mask, heads, whole, block_q, block_k, k_major=False,
+                last=0):
+    """(the operand, its spec) of a mask that is data, for a head-major
+    `pallas_call` whose grid rows are (batch entry, head) flat; nothing of
+    either without one.  ``whole``: a grid step holds the sequence's (S, S)
+    mask.  Else the mask goes in tile-major and a step holds the row of
+    tiles of its q tile (the forward) or, ``k_major``, the column of tiles
+    of k tile ``last - i`` (the backward, whose k tiles pass from the last
+    to the first)."""
+    if mask is None:
+        return (), []
+    mask = mask.astype(jnp.int8)
+    S = mask.shape[1]
+    if whole:
+        return (mask,), [pl.BlockSpec(
+            (None, S, S), lambda g, i: (g // heads, 0, 0))]
+    tiles = _tile_major(mask, block_q, block_k, k_major)
+    index = (lambda g, i: (g // heads, last - i, 0, 0, 0)) if k_major \
+        else (lambda g, i: (g // heads, i, 0, 0, 0))
+    return (tiles,), [pl.BlockSpec(
+        (None, None, tiles.shape[2], block_q, block_k), index)]
+
+
 @_kernel_call("whole")
 def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, whole,
-                    interpret):
+                    interpret, mask=None):
     """``whole``: a grid step takes the whole sequence and walks its q
     tiles; else one q tile (the forward's two forms: `_fwd_rows`).  q is
     (B, H, S, D) and k (B, H_kv, S, D); v, and so o, may have another last
-    dim."""
+    dim.  ``mask``: (B, S, S), not 0 where a pair is attended."""
     B, H, S, D = q.shape
     Hkv, Dv = k.shape[1], v.shape[-1]
     qf = q.reshape(B * H, S, D)
@@ -471,6 +566,7 @@ def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, whole,
     vf = v.reshape(B * Hkv, S, Dv)
     rows = S if whole else block_q
     grid = (B * H, S // rows)
+    masks, mask_specs = _mask_specs(mask, H, whole, block_q, block_k)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, seq_len=S, whole=whole,
@@ -481,7 +577,8 @@ def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, whole,
         grid=grid,
         in_specs=[qspec,
                   pl.BlockSpec((None, S, D), _kv_rows(H // Hkv)),
-                  pl.BlockSpec((None, S, Dv), _kv_rows(H // Hkv))],
+                  pl.BlockSpec((None, S, Dv), _kv_rows(H // Hkv)),
+                  *mask_specs],
         out_specs=[pl.BlockSpec((None, rows, Dv), lambda g, i: (g, i, 0)),
                    pl.BlockSpec((None, rows, 1), lambda g, i: (g, i, 0))],
         out_shape=[
@@ -489,22 +586,24 @@ def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, whole,
             jax.ShapeDtypeStruct((B * H, S, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_compiler_params(S, D, Dv, q.dtype),
+        compiler_params=_compiler_params(
+            S, D, Dv, q.dtype,
+            extra=_mask_block_bytes(S, rows) if masks else 0),
     )
     with jax.named_scope("fwd_rows"):
-        o, lse = call(qf, kf, vf)
+        o, lse = call(qf, kf, vf, *masks)
     return o.reshape(B, H, S, Dv), lse.reshape(B, H, S)
 
 
 @_kernel_call("k_rows")
 def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
-                     k_rows, interpret, delta=None):
+                     k_rows, interpret, delta=None, mask=None):
     """The one-kernel backward.  ``k_rows``: the rows of k a grid step
     takes, the whole sequence or one k tile of it, a (b, h) slice's tiles
     in order (`_bwd_fused_core`).  v, o and do may have another last dim
     than q and k; k and v may have fewer heads (a group of q's heads reads
     each): every query head then writes its float32 part of dk and dv, and
-    `_sum_groups` adds a group's."""
+    `_sum_groups` adds a group's.  ``mask``: as `_pallas_forward`'s."""
     B, H, S, D = q.shape
     Hkv, Dv = k.shape[1], v.shape[-1]
     group = H // Hkv
@@ -528,23 +627,27 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
     steps = S // k_rows
     k_read, k_write = _kv_rows(group, steps - 1), _kv_rows(1, steps - 1)
     qk, vo, row = (spec(S, width, _kv_rows(1)) for width in (D, Dv, 1))
+    masks, mask_specs = _mask_specs(
+        mask, H, steps == 1, block_q, block_k, k_major=True, last=steps - 1)
     call = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q, block_k=block_k,
                           seq_len=S),
         grid=(B * H, steps),
         in_specs=[qk, spec(k_rows, D, k_read), spec(k_rows, Dv, k_read), vo,
-                  row, row],
+                  row, row, *mask_specs],
         out_specs=[qk, spec(k_rows, D, k_write), spec(k_rows, Dv, k_write)],
         out_shape=[jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
                    jax.ShapeDtypeStruct((B * H, S, D), part(k)),
                    jax.ShapeDtypeStruct((B * H, S, Dv), part(v))],
         scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
         interpret=interpret,
-        compiler_params=_compiler_params(S, D, Dv, q.dtype, bwd_steps=steps),
+        compiler_params=_compiler_params(
+            S, D, Dv, q.dtype, bwd_steps=steps,
+            extra=_mask_block_bytes(S, k_rows) if masks else 0),
     )
     with jax.named_scope("bwd_fused"):
-        dq, dk, dv = call(qf, kf, vf, dof, lsef, delta)
+        dq, dk, dv = call(qf, kf, vf, dof, lsef, delta, *masks)
 
     dq = dq.reshape(B, H, S, D)
     if group == 1:
@@ -699,29 +802,32 @@ def _repeat_groups(q, k, v):
     return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
 
 
-def reference_attention(q, k, v, sm_scale, causal):
+def reference_attention(q, k, v, sm_scale, causal, mask=None):
     k, v = _repeat_groups(q, k, v)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * sm_scale
     if causal:
         S = q.shape[2]
-        mask = jnp.tril(jnp.ones((S, S), bool))
-        s = jnp.where(mask, s, _NEG_INF)
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, _NEG_INF)
+    if mask is not None:
+        s = jnp.where(mask[:, None] != 0, s, _NEG_INF)
     lse = jax.scipy.special.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None])
     o = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
     return o.astype(q.dtype), lse
 
 
-def _reference_backward(q, k, v, lse, do, delta, sm_scale, causal):
+def _reference_backward(q, k, v, lse, do, delta, sm_scale, causal,
+                        mask=None):
     qf = q.astype(jnp.float32)
     kf, vf = _repeat_groups(q, k.astype(jnp.float32), v.astype(jnp.float32))
     dof = do.astype(jnp.float32)
     s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * sm_scale
     if causal:
         S = q.shape[2]
-        mask = jnp.tril(jnp.ones((S, S), bool))
-        s = jnp.where(mask, s, _NEG_INF)
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, _NEG_INF)
+    if mask is not None:
+        s = jnp.where(mask[:, None] != 0, s, _NEG_INF)
     p = jnp.exp(s - lse[..., None])
     dv = jnp.einsum("bhqk,bhqd->bhkd", p, dof)
     dp = jnp.einsum("bhqd,bhkd->bhqk", dof, vf)
@@ -846,10 +952,12 @@ def _count_tiles(S, block_q, block_k, causal, heads):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal=False, sm_scale=None,
-                    block_q=None, block_k=None):
+                    block_q=None, block_k=None, mask=None):
     """Multi-head attention over (batch, heads, seq, head_dim) tensors; k
     and v may have fewer heads than q, a divisor of its count (query head h
-    reads key/value head h // group).
+    reads key/value head h // group).  ``mask``: (batch, seq, seq) int8,
+    not 0 where a (query, key) pair is attended, the same for every head;
+    with ``causal`` a pair must pass both.  Every query attends a key.
 
     Blocks the call does not name come from `_auto_tiles`.  The backward
     is one kernel (5 dots a tile instead of a split's 7): up to
@@ -860,15 +968,16 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
     width a cell has; S = 32,768 does not, where the split compiled, and
     its backward takes the reference under an `AttentionFallbackWarning`:
     `_tiling_problem`)."""
-    o, _ = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k)
+    o, _ = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, mask)
     return o
 
 
-def _named_residuals(q, k, v, o, lse, rows=False):
+def _named_residuals(q, k, v, o, lse, mask=None, rows=False):
     """(o, the residuals) of a public forward rule, o and lse under
     `KEPT_RESIDUALS`.  The rule's result IS the named o: a checkpointed
     layer that keeps the names then needs nothing else of the kernel, and
-    its replay drops the call.  q, k and v stay unnamed (recomputed).
+    its replay drops the call.  q, k and v stay unnamed (recomputed), and so
+    does a mask, which is among the residuals only when there is one.
 
     ``rows``: name lse as the head-major kernels write and read it,
     (B*H, S, 1), whose rows fill a lane each on the chip: 128 times the
@@ -885,7 +994,7 @@ def _named_residuals(q, k, v, o, lse, rows=False):
                               KEPT_RESIDUALS[1]).reshape(B, H, S)
     else:
         lse = checkpoint_name(lse, KEPT_RESIDUALS[1])
-    return o, (q, k, v, o, lse)
+    return o, (q, k, v, o, lse) if mask is None else (q, k, v, o, lse, mask)
 
 
 def _rows_kept(S):
@@ -895,35 +1004,47 @@ def _rows_kept(S):
     return S <= _WHOLE_SEQ_MAX
 
 
-def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k):
+def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, mask=None):
     """`flash_attention`'s forward rule.  The names go on here and not in
     `_flash_fwd`, which the ring calls once per rotating chunk: its
     partials are no residuals of anything."""
-    _, res = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k)
+    _, res = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, mask)
     return _named_residuals(*res, rows=_rows_kept(q.shape[2]))
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
+def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, mask=None):
+    """-> (o, (q, k, v, o, lse, and the mask if there is one))."""
     S = q.shape[2]
     scale, whole, (bq, bk), _ = _resolve(q, S, causal, sm_scale, block_q,
                                          block_k)
     reference = functools.partial(reference_attention, sm_scale=scale,
                                   causal=causal)
-    problem = _tiling_problem(S, bq, bk)
+    problem = _tiling_problem(
+        S, bq, bk, 0 if mask is None else _mask_block_bytes(
+            S, S if whole else bq))
     if problem:
         _warn_reference(q.shape, bq, bk, problem)
-        o, lse = reference(q, k, v)
+        o, lse = reference(q, k, v, mask=mask)
     else:
         _count_tiles(S, bq, bk, causal, (q.shape[1], k.shape[1]))
-        o, lse = by_platform(
-            functools.partial(_pallas_forward, sm_scale=scale, causal=causal,
-                              block_q=bq, block_k=bk, whole=whole),
-            reference, q, k, v)
-    return o, (q, k, v, o, lse)
+        kernel = functools.partial(_pallas_forward, sm_scale=scale,
+                                   causal=causal, block_q=bq, block_k=bk,
+                                   whole=whole)
+        if mask is None:
+            o, lse = by_platform(kernel, reference, q, k, v)
+        else:
+            o, lse = by_platform(
+                lambda q, k, v, mask, interpret: kernel(
+                    q, k, v, interpret=interpret, mask=mask),
+                lambda q, k, v, mask: reference(q, k, v, mask=mask),
+                q, k, v, mask)
+    return o, (q, k, v, o, lse) if mask is None else (q, k, v, o, lse, mask)
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, res, do, delta=None):
-    q, k, v, o, lse = res
+    """-> (dq, dk, dv)."""
+    q, k, v, o, lse, *mask = res
+    mask = mask[0] if mask else None
     S = q.shape[2]
     scale, whole, _, (bq, bk) = _resolve(q, S, causal, sm_scale, block_q,
                                          block_k)
@@ -935,23 +1056,35 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, do, delta=None):
             do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     held = 0 if whole else _bwd_held_bytes(S, q.shape[-1], v.shape[-1],
                                            q.dtype)
+    if mask is not None:
+        held += _mask_block_bytes(S, S if whole else bk)
     problem = _tiling_problem(S, bq, bk, held)
     if problem:
         _warn_reference(q.shape, bq, bk, problem)
-        return _reference_backward(q, k, v, lse, do, delta, scale, causal)
+        return _reference_backward(q, k, v, lse, do, delta, scale, causal,
+                                   mask)
     _count_tiles(S, bq, bk, causal, (q.shape[1], k.shape[1]))
 
-    def kernel(q, k, v, o, lse, do, delta, interpret):
+    def kernel(q, k, v, o, lse, do, delta, mask=None, *, interpret):
         return _pallas_backward(q, k, v, o, lse, do, scale, causal, bq, bk,
-                                S if whole else bk, interpret, delta=delta)
+                                S if whole else bk, interpret, delta=delta,
+                                mask=mask)
 
-    def reference(q, k, v, o, lse, do, delta):
-        return _reference_backward(q, k, v, lse, do, delta, scale, causal)
+    def reference(q, k, v, o, lse, do, delta, mask=None):
+        return _reference_backward(q, k, v, lse, do, delta, scale, causal,
+                                   mask)
 
-    return by_platform(kernel, reference, q, k, v, o, lse, do, delta)
+    return by_platform(kernel, reference, q, k, v, o, lse, do, delta,
+                       *(() if mask is None else (mask,)))
 
 
-flash_attention.defvjp(_flash_fwd_rule, _flash_bwd)
+def _flash_bwd_rule(causal, sm_scale, block_q, block_k, res, do):
+    """`flash_attention`'s backward rule: a mask, if there is one, has no
+    cotangent."""
+    return (*_flash_bwd(causal, sm_scale, block_q, block_k, res, do), None)
+
+
+flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def _bshd_lanes_ok(q, S, bq, bk):
@@ -966,27 +1099,31 @@ def _tr(x):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention_bshd(q, k, v, causal=False, sm_scale=None,
-                         block_q=None, block_k=None):
+                         block_q=None, block_k=None, mask=None):
     """Multi-head attention over (batch, seq, heads, head_dim) tensors —
     the layout models naturally produce from the fused qkv projection.
 
+    ``mask`` as `flash_attention`'s.
+
     When the lane tiling applies (head_dim divides 128, heads fill whole
-    lane blocks, k and v have q's heads; for the backward S <=
+    lane blocks, k and v have q's heads, no mask; for the backward S <=
     `_WHOLE_SEQ_MAX`: the long backward is head-major only) the kernels
     index heads through 128-wide lane blocks and no
     (B,S,H,D) <-> (B,H,S,D) transpose ever materializes; otherwise the
     call transposes to the bhsd kernels (still flash, just with the
     transpose cost the lane path avoids)."""
-    o, _ = _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k)
+    o, _ = _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k, mask)
     return o
 
 
-def _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k):
+def _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k, mask=None):
     S = q.shape[1]
     scale, whole, (bq, bk), _ = _resolve(q, S, causal, sm_scale, block_q,
                                          block_k)
-    # the lane layout slices every operand's heads out of the same lanes
-    if k.shape == v.shape == q.shape and _bshd_lanes_ok(q, S, bq, bk):
+    # the lane layout slices every operand's heads out of the same lanes,
+    # and reads no mask
+    if mask is None and k.shape == v.shape == q.shape \
+            and _bshd_lanes_ok(q, S, bq, bk):
         _count_tiles(S, bq, bk, causal, (q.shape[2], k.shape[2]))
 
         def reference(q, k, v):
@@ -1003,17 +1140,17 @@ def _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k):
     # of o's two layouts the one named is the caller's (B, S, H, D), which
     # is also the rule's result; the backward transposes it to head-major
     # as it always did
-    ot, (_, _, _, _, lse) = _flash_fwd(_tr(q), _tr(k), _tr(v), causal,
-                                       sm_scale, block_q, block_k)
-    return _named_residuals(q, k, v, _tr(ot), lse, rows=_rows_kept(S))
+    ot, (_, _, _, _, lse, *_) = _flash_fwd(_tr(q), _tr(k), _tr(v), causal,
+                                           sm_scale, block_q, block_k, mask)
+    return _named_residuals(q, k, v, _tr(ot), lse, mask, rows=_rows_kept(S))
 
 
 def _flash_bwd_bshd(causal, sm_scale, block_q, block_k, res, do):
-    q, k, v, o, lse = res
+    q, k, v, o, lse, *mask = res
     S = q.shape[1]
     scale, whole, _, (bq, bk) = _resolve(q, S, causal, sm_scale, block_q,
                                          block_k)
-    if whole and k.shape == v.shape == q.shape \
+    if whole and not mask and k.shape == v.shape == q.shape \
             and _bshd_lanes_ok(q, S, bq, bk):
         _count_tiles(S, bq, bk, causal, (q.shape[2], k.shape[2]))
 
@@ -1024,13 +1161,40 @@ def _flash_bwd_bshd(causal, sm_scale, block_q, block_k, res, do):
             return tuple(map(_tr, _reference_backward(
                 qt, kt, vt, lse, dot, delta, scale, causal)))
 
-        return by_platform(
+        return (*by_platform(
             functools.partial(_pallas_backward_bshd, sm_scale=scale,
                               causal=causal, block_q=bq, block_k=bk),
-            reference, q, k, v, o, lse, do)
-    dq, dk, dv = _flash_bwd(causal, sm_scale, block_q, block_k,
-                            (_tr(q), _tr(k), _tr(v), _tr(o), lse), _tr(do))
-    return _tr(dq), _tr(dk), _tr(dv)
+            reference, q, k, v, o, lse, do), None)
+    dq, dk, dv = _flash_bwd(
+        causal, sm_scale, block_q, block_k,
+        (_tr(q), _tr(k), _tr(v), _tr(o), lse, *mask), _tr(do))
+    return _tr(dq), _tr(dk), _tr(dv), None
 
 
 flash_attention_bshd.defvjp(_flash_fwd_bshd, _flash_bwd_bshd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def flash_attention_bshd_lse(q, k, v, causal=False, sm_scale=None,
+                             block_q=None, block_k=None, mask=None):
+    """`flash_attention_bshd` and the kernels' row statistics beside its
+    result: (o, lse (B, H, S) float32, the log of each query's sum of
+    exp(score) over the keys it attends, natural units).  For a caller that
+    needs the probabilities again (an indexer's loss:
+    `ops/sparse_index.py`).  A statistic: nothing is differentiated through
+    lse, whose cotangent is dropped."""
+    return _flash_fwd_bshd_lse(q, k, v, causal, sm_scale, block_q, block_k,
+                               mask)[0]
+
+
+def _flash_fwd_bshd_lse(q, k, v, causal, sm_scale, block_q, block_k,
+                        mask=None):
+    o, res = _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k,
+                             mask)
+    return (o, res[4]), res
+
+
+flash_attention_bshd_lse.defvjp(
+    _flash_fwd_bshd_lse,
+    lambda causal, sm_scale, block_q, block_k, res, g: _flash_bwd_bshd(
+        causal, sm_scale, block_q, block_k, res, g[0]))

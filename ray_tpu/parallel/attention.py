@@ -15,6 +15,7 @@ import jax
 
 from ray_tpu.ops.flash_attention import (
     flash_attention_bshd,
+    flash_attention_bshd_lse,
     reference_attention,
 )
 from ray_tpu.parallel.context import get_mesh, require_mesh
@@ -26,11 +27,20 @@ def _tr(x):
     return x.transpose(0, 2, 1, 3)
 
 
-def attention(q, k, v, *, causal: bool = True, variant: str = "flash"):
+def attention(q, k, v, *, causal: bool = True, variant: str = "flash",
+              mask=None, with_lse: bool = False):
     """Multi-head attention over (batch, seq, heads, head_dim) arrays, at
     head_dim^-1/2.  k and v may have fewer heads than q, a divisor of its
     count (grouped queries: head h reads key/value head h // group); the
     kernels read them as they are and nothing repeats them.
+
+    ``mask``: (batch, seq, seq) int8, not 0 where a (query, key) pair is
+    attended, the same for all heads of a sequence; with ``causal`` a pair
+    must pass both.  A mask that is data: attention that selects its keys
+    (`ops/sparse_index.py`), or a window or a segment rule filling it.
+    ``with_lse``: -> (o, the kernels' row statistics (batch, heads, seq)
+    float32: the log of each query's sum of exp(score) over the keys it
+    attends), which nothing is differentiated through; "flash" only.
 
     ``"flash"``: the layout-native kernel (no (B,S,H,D) <-> (B,H,S,D)
     transposes); under a bound mesh of several devices each runs it on its
@@ -42,7 +52,16 @@ def attention(q, k, v, *, causal: bool = True, variant: str = "flash"):
             f"attention: q has {q.shape[2]} heads and k, v "
             f"{k.shape[2]}, {v.shape[2]}: the key/value heads must be "
             f"alike and divide the query heads")
+    if with_lse and variant != "flash":
+        raise NotImplementedError(
+            f"attention(variant={variant!r}, with_lse=True): the row "
+            f"statistics are the flash kernels' (use \"flash\")")
     if variant in ("ring", "ulysses"):
+        if mask is not None:
+            raise NotImplementedError(
+                f"attention(variant={variant!r}) takes no mask: a mask "
+                f"that is data is not written for the sequence-parallel "
+                f"kernels, whose chunks rotate (use \"flash\")")
         if k.shape[2] != q.shape[2]:
             raise NotImplementedError(
                 f"attention(variant={variant!r}) takes k and v with q's "
@@ -52,23 +71,35 @@ def attention(q, k, v, *, causal: bool = True, variant: str = "flash"):
         return _sequence_parallel(q, k, v, require_mesh(), causal, variant)
     if variant == "dense":
         o, _ = reference_attention(_tr(q), _tr(k), _tr(v),
-                                   q.shape[-1] ** -0.5, causal)
+                                   q.shape[-1] ** -0.5, causal, mask)
         return _tr(o)
     mesh = get_mesh()
     if mesh is None or mesh.size == 1:
-        return flash_attention_bshd(q, k, v, causal)
-    return _flash_sharded(q, k, v, mesh, causal)
+        if mask is None and not with_lse:
+            return flash_attention_bshd(q, k, v, causal)
+        kernel = flash_attention_bshd_lse if with_lse else flash_attention_bshd
+        return kernel(q, k, v, causal, mask=mask)
+    return _flash_sharded(q, k, v, mesh, causal, mask, with_lse)
 
 
-def _flash_sharded(q, k, v, mesh, causal):
+def _flash_sharded(q, k, v, mesh, causal, mask=None, with_lse=False):
     """shard_map of the layout-native kernel: batch and heads cut as the
     rules say, and each device runs the kernel on its own slice.  A
     pallas_call is an opaque custom call to the SPMD partitioner: under a
     mesh of several devices jax refuses to lower one that is not inside a
     shard_map.  The heads' axes are those that divide the key/value heads
     (and so q's: with grouped queries each device holds whole groups, its
-    query heads and the key/value heads they read)."""
+    query heads and the key/value heads they read).  A mask is cut with
+    the batch and whole for every head."""
     spec = dividing_spec(mesh, ("batch", None, "heads", None), k.shape)
+    if mask is not None or with_lse:
+        kernel = flash_attention_bshd_lse if with_lse else flash_attention_bshd
+        rows = type(spec)(spec[0], spec[2])         # lse: (batch, heads, seq)
+        return jax.shard_map(
+            lambda q, k, v, mask: kernel(q, k, v, causal, mask=mask),
+            mesh=mesh, in_specs=(spec, spec, spec, type(spec)(spec[0])),
+            out_specs=(spec, rows) if with_lse else spec, check_vma=False,
+        )(q, k, v, mask)
     return jax.shard_map(
         lambda q, k, v: flash_attention_bshd(q, k, v, causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

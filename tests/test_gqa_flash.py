@@ -401,3 +401,178 @@ def test_a_slice_that_does_not_fit_vmem_falls_back(monkeypatch):
             q, k, v, 32 ** -0.5, True)[0])
     for g, w in zip(got, want):
         assert float(jnp.max(jnp.abs(g - w))) < 2e-5
+
+
+# -- a mask that is data ------------------------------------------------------
+
+# (query heads, key/value heads, q/k width, v width): a group of 8 at one
+# width and at two (q, k wider than v: latent attention's), and equal heads
+MASKED_SHAPES = {
+    "a group of 8": (8, 1, 16, 16),
+    "a group of 8, q, k 24 and v 16": (8, 1, 24, 16),
+    "equal heads of 64": (2, 2, 64, 64),
+}
+
+
+def _random_mask(S, seed=0, empty_tile=None):
+    """(1, S, S) int8: a third of the pairs and every query's own position;
+    ``empty_tile`` (q tile, k tile) of 128 x 128 holds nothing."""
+    mask = np.array(jax.random.uniform(jax.random.PRNGKey(seed), (1, S, S))
+                    < 0.3) | np.eye(S, dtype=bool)
+    if empty_tile is not None:
+        i, j = empty_tile
+        mask[:, 128 * i:128 * (i + 1), 128 * j:128 * (j + 1)] = False
+    return jnp.asarray(mask, jnp.int8)
+
+
+def _masked_dense(q, k, v, mask, causal):
+    """A masked softmax by einsum, k and v repeated: float32, no kernel."""
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    seen = mask[:, None] != 0
+    if causal:
+        seen = seen & jnp.tril(jnp.ones(s.shape[-2:], bool))
+    return jnp.einsum("bhqk,bhkd->bhqd",
+                      jax.nn.softmax(jnp.where(seen, s, -jnp.inf), -1), v)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("form", ["whole", "long"])
+@pytest.mark.parametrize("name", sorted(MASKED_SHAPES))
+def test_masked_kernels_match_a_masked_dense_einsum(name, form, causal,
+                                                    monkeypatch):
+    """o, dq, dk, dv of the interpreted forward and one-kernel backward
+    under a mask that is data, a grid step the whole sequence and the tiles
+    on the grid (`_WHOLE_SEQ_MAX` lowered; 2 x 2 tiles of 128, one of them
+    without an attended pair, which adds nothing), with and without `causal`
+    beside it."""
+    heads, kv_heads, D, Dv = MASKED_SHAPES[name]
+    S = 256
+    if form == "long":
+        monkeypatch.setattr(fa, "_WHOLE_SEQ_MAX", 128)
+    ks = jax.random.split(jax.random.PRNGKey(heads + D), 3)
+    q = jax.random.normal(ks[0], (1, heads, S, D))
+    k = jax.random.normal(ks[1], (1, kv_heads, S, D))
+    v = jax.random.normal(ks[2], (1, kv_heads, S, Dv))
+    mask = _random_mask(S, empty_tile=(1, 0))
+
+    def grads(attend):
+        return _value_and_grads(attend, q, k, v)
+
+    with jax.default_matmul_precision("highest"), warnings.catch_warnings():
+        warnings.simplefilter("error", fa.AttentionFallbackWarning)
+        got = grads(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal, None, 128, 128, mask))
+        want = grads(lambda q, k, v: _masked_dense(q, k, v, mask, causal))
+    for what, g, w in zip(("o", "dq", "dk", "dv"), got, want):
+        assert g.shape == w.shape, what
+        assert float(jnp.max(jnp.abs(g - w))) < 3e-5, what
+    kernels = _kernels(jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+        q, k, v, causal, None, 128, 128, mask)), (0, 1, 2)), q, k, v)
+    assert kernels == {"_fwd_kernel", "_bwd_fused_kernel"}
+
+
+def test_a_mask_of_every_causal_pair_is_the_causal_kernel():
+    """`topk` >= S: the selection is the causal triangle
+    (`ops/sparse_index.py:select_top_k`), and the kernels under it give what
+    the causal kernels give without one, to the last bit; through the
+    (B, S, H, D) entry, which hands a mask to the head-major kernels."""
+    from ray_tpu.ops.sparse_index import select_top_k
+
+    q, k, v = _qkv(1)                            # 8 heads on 1: a group of 8
+    mask = select_top_k(jnp.zeros((1, S, S)), S, block=64)
+    assert np.array_equal(np.asarray(mask[0]), np.tril(np.ones((S, S))))
+    got = _value_and_grads(lambda q, k, v: attention(q, k, v, mask=mask),
+                           q, k, v)
+    want = _value_and_grads(lambda q, k, v: attention(q, k, v), q, k, v)
+    for g, w in zip(got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+    o, lse = attention(q, k, v, mask=mask, with_lse=True)
+    assert np.array_equal(np.asarray(o), np.asarray(want[0]))
+    _, want_lse = fa.reference_attention(_tr(q), _tr(k), _tr(v), D ** -0.5,
+                                         True)
+    assert lse.shape == (1, H, S)
+    assert float(jnp.max(jnp.abs(lse - want_lse))) < 1e-4
+
+
+def test_the_lane_layout_and_the_ring_decline_a_mask():
+    """Equal heads of 64 take the lane layout without a mask and the
+    head-major kernels with one; `ring` and `ulysses` refuse it as they
+    refuse unequal heads; the dense variant applies it."""
+    q, k, v = _qkv(H, D=64)
+    mask = _random_mask(S, seed=3)
+    plain = _kernels(lambda q, k, v: attention(q, k, v), q, k, v)
+    masked = _kernels(lambda q, k, v: attention(q, k, v, mask=mask), q, k, v)
+    assert plain == {"_fwd_kernel_lanes"} and masked == {"_fwd_kernel"}
+    from ray_tpu.parallel.context import use_mesh
+
+    for variant in ("ring", "ulysses"):
+        with use_mesh(create_mesh({"sp": 8})), pytest.raises(
+                NotImplementedError, match="takes no mask"):
+            attention(q, k, v, variant=variant, mask=mask)
+    with pytest.raises(NotImplementedError, match="with_lse"):
+        attention(q, k, v, variant="dense", with_lse=True)
+    with jax.default_matmul_precision("highest"):
+        dense = attention(q, k, v, variant="dense", mask=mask)
+        flash = attention(q, k, v, mask=mask)
+        want = _tr(_masked_dense(_tr(q), _tr(k), _tr(v), mask, True))
+    assert float(jnp.max(jnp.abs(dense - want))) < 2e-5
+    assert float(jnp.max(jnp.abs(flash - want))) < 2e-5
+
+
+@pytest.mark.parametrize("kv_heads", [8, 2])
+def test_flash_sharded_cuts_a_mask_with_the_batch(kv_heads):
+    """Under a mesh of several devices the mask goes into the `shard_map`
+    cut as the batch is and whole for every head; o and the row statistics
+    are the unsharded call's."""
+    mesh = create_mesh({"dp": 2, "tp": 2}, devices=jax.devices()[:4])
+    q, k, v = _qkv(kv_heads, B=2, S=128)
+    mask = jnp.concatenate([_random_mask(128, seed=s) for s in (1, 2)])
+    with jax.default_matmul_precision("highest"):
+        o, lse = jax.jit(lambda q, k, v: _flash_sharded(
+            q, k, v, mesh, True, mask, True))(q, k, v)
+        want, want_lse = attention(q, k, v, mask=mask, with_lse=True)
+    assert float(jnp.max(jnp.abs(o - want))) < 2e-5
+    assert float(jnp.max(jnp.abs(lse - want_lse))) < 2e-5
+
+
+def test_a_masked_call_counts_what_is_known_when_it_is_traced():
+    """`attention.tiles` and `attention.tiles_skipped` are of the causal
+    square, with or without a mask: every causal tile is visited whatever
+    the mask holds of it."""
+    names = ("attention.tiles", "attention.tiles_skipped")
+    q = jax.ShapeDtypeStruct((1, 2048, 8, 64), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 2048, 2, 64), jnp.bfloat16)
+    mask = jax.ShapeDtypeStruct((1, 2048, 2048), jnp.int8)
+
+    def traced(*extra):
+        before = [tracing.counter(name) for name in names]
+        jax.eval_shape(jax.grad(lambda q, k, v, *m: jnp.sum(attention(
+            q, k, v, **dict(zip(("mask",), m))).astype(jnp.float32)),
+            (0, 1, 2)), q, k, k, *extra)
+        return [tracing.counter(name) - b for name, b in zip(names, before)]
+
+    with tracing.timeline_span("train.fit", root=True):
+        assert traced(mask) == traced() == [4 + 16, 1 + 6]
+
+
+@pytest.mark.parametrize("shape,kv_heads", [((2, 8192, 32, 128), 4),
+                                            ((1, 1024, 8, 64), 8)])
+def test_masked_kernels_lower_to_mosaic_for_tpu(shape, kv_heads):
+    """The cell's shape (32 query heads on 4 key/value heads of 128 over
+    8,192 positions: the mask tile-major) and a sequence a
+    grid step takes whole, exported for a TPU from this CPU host: two
+    Mosaic custom calls, no interpreted kernel body and no O(S^2)
+    reference."""
+    B, S, heads, D = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((B, S, kv_heads, D), jnp.bfloat16)
+    mask = jax.ShapeDtypeStruct((B, S, S), jnp.int8)
+    module = jax.export.export(
+        jax.jit(jax.grad(lambda q, k, v, mask: jnp.sum(attention(
+            q, k, v, mask=mask).astype(jnp.float32) ** 2), (0, 1, 2))),
+        platforms=["tpu"])(q, k, k, mask).mlir_module()
+    assert module.count("stablehlo.custom_call @tpu_custom_call") == 2
+    assert "stablehlo.dot_general" not in module
+    assert "stablehlo.while" not in module

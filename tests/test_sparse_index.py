@@ -1,0 +1,230 @@
+"""`ray_tpu/ops/sparse_index.py` at small sizes on the CPU: the index scores
+against a dense einsum, the threshold search against a stable sort and
+`jax.lax.top_k` (ties, zeros of either sign, every k), the indexer's loss and its gradient against the same written densely, and what
+it counts on the job timeline."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import sparse_index as si
+from ray_tpu.parallel.attention import attention
+
+B, S, J, DI = 2, 64, 4, 8
+H, HKV, D = 8, 1, 16          # a group of 8 query heads a key/value head
+TRI = np.tril(np.ones((S, S), bool))
+
+
+@pytest.fixture(autouse=True)
+def highest_precision():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def indexer_inputs(seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (B, S, J, DI)),
+            jax.random.normal(ks[1], (B, S, DI)),
+            jax.random.normal(ks[2], (B, S, J)))
+
+
+def dense_scores(q, k, w):
+    return jnp.einsum("bqj,bjqs->bqs", w, jax.nn.relu(
+        jnp.einsum("bqjd,bsd->bjqs", q, k)))
+
+
+def sorted_selection(scores, top_k):
+    """The first min(top_k, t + 1) entries of a stable descending sort."""
+    scores = jnp.where(TRI, scores, -jnp.inf)
+    rank = jnp.argsort(jnp.argsort(-scores, axis=-1, stable=True), axis=-1)
+    return np.asarray((rank < jnp.minimum(top_k, jnp.arange(S) + 1)[:, None])
+                      & TRI)
+
+
+@pytest.mark.parametrize("block", [16, 64, 512])
+def test_index_scores_match_a_dense_einsum(block):
+    q, k, w = indexer_inputs()
+    got = si.index_scores(q, k, w, block=block)
+    assert got.dtype == jnp.float32 and got.shape == (B, S, S)
+    assert np.array_equal(np.isneginf(got), np.broadcast_to(~TRI, got.shape))
+    want = dense_scores(q, k, w)
+    assert float(jnp.max(jnp.abs(jnp.where(TRI, got - want, 0)))) < 1e-5
+
+
+def test_index_scores_gradients_match_autodiff_of_the_dense_form():
+    q, k, w = indexer_inputs(1)
+    weight = jax.random.normal(jax.random.PRNGKey(5), (B, S, S))
+
+    def loss(scores):
+        return jnp.sum(jnp.where(TRI, scores * weight, 0.0))
+
+    got = jax.grad(lambda *a: loss(si.index_scores(*a, block=16)),
+                   (0, 1, 2))(q, k, w)
+    want = jax.grad(lambda *a: loss(dense_scores(*a)), (0, 1, 2))(q, k, w)
+    for g, v in zip(got, want):
+        assert float(jnp.max(jnp.abs(g - v))) < 1e-4
+
+
+def test_a_sequence_that_is_no_whole_number_of_blocks_is_refused():
+    q, k, w = indexer_inputs()
+    with pytest.raises(ValueError, match="whole number of blocks"):
+        si.index_scores(q, k, w, block=48)
+
+
+@pytest.mark.parametrize("top_k", [1, 7, 16, 20, 33, 63, 64, 100])
+@pytest.mark.parametrize("ties", [False, True])
+def test_selection_is_the_stable_sorts_and_top_ks_set(top_k, ties):
+    """Every k; with scores rounded to halves, so that many tie at the
+    threshold: of equal scores the lower keys are taken."""
+    scores = si.index_scores(*indexer_inputs(2), block=16)
+    if ties:
+        scores = jnp.where(TRI, jnp.round(scores * 2) / 2 + 0.0, -jnp.inf)
+    mask = si.select_top_k(scores, top_k, block=16)
+    assert mask.dtype == jnp.int8 and mask.shape == (B, S, S)
+    assert np.array_equal(np.asarray(mask) != 0,
+                          sorted_selection(scores, top_k))
+    # `jax.lax.top_k` on the rows that have top_k keys to choose from
+    k = min(top_k, S)
+    _, chosen = jax.lax.top_k(scores, k)
+    rows = np.arange(S) + 1 >= k
+    want = np.zeros((B, S, S), bool)
+    np.put_along_axis(want, np.asarray(chosen), True, axis=-1)
+    assert np.array_equal((np.asarray(mask) != 0)[:, rows], want[:, rows])
+    assert int(mask.sum()) == B * sum(min(top_k, t + 1) for t in range(S))
+
+
+def test_equal_scores_take_the_lower_key():
+    scores = jnp.where(TRI, jnp.zeros((1, S, S)), -jnp.inf)
+    mask = si.select_top_k(scores, 5, block=16)
+    want = TRI & (np.arange(S)[None] < 5)
+    assert np.array_equal(np.asarray(mask[0]) != 0, want)
+
+
+def test_zeros_of_either_sign_tie():
+    """-0.0 and 0.0 are one score: the lower key wins, whatever its sign
+    (a sum of w * relu products is -0.0 where every w is negative)."""
+    row = jnp.array([0.0, -0.0, 1.0, -0.0, 0.0, -1.0, 0.0, -0.0])
+    scores = jnp.where(np.tril(np.ones((8, 8), bool)),
+                       jnp.broadcast_to(row, (1, 8, 8)), -jnp.inf)
+    mask = si.select_top_k(scores, 3, block=8)
+    assert np.asarray(mask[0, 7]).tolist() == [1, 1, 1, 0, 0, 0, 0, 0]
+
+
+def test_the_kth_largest_of_any_bits():
+    rng = np.random.default_rng(0)
+    u = rng.integers(0, 2 ** 32, (16, 50), dtype=np.uint32)
+    u[0, :5] = 0xFFFFFFFF
+    u[1, :7] = 0
+    k = rng.integers(1, 51, 16).astype(np.int32)
+    got = si._kth_largest(jnp.asarray(u), jnp.asarray(k))
+    want = np.sort(u, axis=1)[np.arange(16), 50 - k]
+    assert np.array_equal(np.asarray(got), want)
+
+
+def test_the_order_of_the_bits_is_the_order_of_the_floats():
+    x = jnp.array([-jnp.inf, -3.5, -1e-30, -0.0, 0.0, 1e-30, 2.0, jnp.inf])
+    u = np.asarray(si._ordered(x)).astype(np.int64)
+    assert (np.diff(u) >= 0).all() and u[3] == u[4]
+    assert (np.diff(u)[[0, 1, 2, 4, 5, 6]] > 0).all()
+
+
+def test_top_k_past_the_sequence_is_the_causal_triangle():
+    scores = si.index_scores(*indexer_inputs(3), block=16)
+    for top_k in (S, 4 * S):
+        mask = si.select_top_k(scores, top_k, block=16)
+        assert np.array_equal(np.asarray(mask) != 0,
+                              np.broadcast_to(TRI, (B, S, S)))
+
+
+def test_scores_that_fall_with_distance_select_a_window():
+    """Scores that fall with the key's distance: each query takes its own
+    16 nearest keys, a band under the diagonal (a window rule, as the same
+    operand would hold it)."""
+    near = -jnp.abs(jnp.arange(S)[:, None] - jnp.arange(S)[None]) * 1.0
+    scores = jnp.where(TRI, jnp.broadcast_to(near, (1, S, S)), -jnp.inf)
+    mask = si.select_top_k(scores, 16, block=16)
+    distance = np.arange(S)[:, None] - np.arange(S)[None]
+    assert np.array_equal(np.asarray(mask[0]) != 0,
+                          (distance >= 0) & (distance < 16))
+
+
+def attention_inputs(seed=4):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (B, S, H, D)),
+            jax.random.normal(ks[1], (B, S, HKV, D)),
+            jax.random.normal(ks[2], (B, S, HKV, D)))
+
+
+def dense_loss(scores, mask, q, k):
+    """KL(mean over heads of the attention's probabilities over the
+    selected keys || softmax of the selected scores), mean over queries."""
+    chosen = mask != 0
+    s = jnp.einsum("bqhd,bshd->bhqs", q,
+                   jnp.repeat(k, H // HKV, axis=2)) * D ** -0.5
+    p = jnp.mean(jax.nn.softmax(jnp.where(chosen[:, None], s, -jnp.inf), -1),
+                 axis=1)
+    log_q = jax.nn.log_softmax(jnp.where(chosen, scores, -jnp.inf), -1)
+    return jnp.sum(jnp.where(chosen, jax.scipy.special.xlogy(p, p)
+                             - p * log_q, 0.0)) / (B * S)
+
+
+@pytest.mark.parametrize("top_k", [16, 64])
+def test_the_indexers_loss_and_its_gradient_match_the_dense_form(top_k):
+    scores = si.index_scores(*indexer_inputs(5), block=16)
+    mask = si.select_top_k(scores, top_k, block=16)
+    q, k, v = attention_inputs()
+    _, lse = attention(q, k, v, mask=mask, with_lse=True)
+    got, grad = jax.value_and_grad(
+        lambda sc: si.indexer_loss(sc, mask, q, k, lse, block=16))(scores)
+    want, want_grad = jax.value_and_grad(
+        lambda sc: dense_loss(sc, mask, q, k))(scores)
+    assert float(got) > 0.01
+    assert float(jnp.abs(got - want)) < 2e-5 * float(want)
+    assert float(jnp.max(jnp.abs(grad - want_grad))) < 1e-7
+    # nothing outside the selected pairs
+    assert not np.asarray(grad)[np.asarray(mask) == 0].any()
+
+
+def test_the_loss_is_zero_where_the_indexer_is_the_attention():
+    """Index scores that ARE the (one head's) attention scores: the two
+    distributions over the selected keys are one."""
+    q, k, v = (x[:, :, :1] for x in attention_inputs(6))
+    scores = jnp.where(TRI, jnp.einsum("bqhd,bshd->bqs", q, k) * D ** -0.5,
+                       -jnp.inf)
+    mask = si.select_top_k(scores, 16, block=16)
+    _, lse = attention(q, k, v, mask=mask, with_lse=True)
+    assert abs(float(si.indexer_loss(scores, mask, q, k, lse, block=16))) \
+        < 1e-5
+
+
+def test_nothing_of_the_loss_reaches_the_main_attention():
+    scores = si.index_scores(*indexer_inputs(7), block=16)
+    mask = si.select_top_k(scores, 16, block=16)
+    q, k, v = attention_inputs()
+    _, lse = attention(q, k, v, mask=mask, with_lse=True)
+    grads = jax.grad(
+        lambda q, k, lse: si.indexer_loss(scores, mask, q, k, lse, block=16),
+        (0, 1, 2))(q, k, lse)
+    for g in grads:
+        assert not np.asarray(g).any()
+
+
+def test_what_it_counts_as_the_step_is_traced(monkeypatch):
+    counted = {}
+    monkeypatch.setattr(
+        si.tracing, "count",
+        lambda name, n=1: counted.__setitem__(name, counted.get(name, 0) + n))
+    scores = jax.eval_shape(
+        lambda *a: si.index_scores(*a, block=16), *indexer_inputs())
+    jax.eval_shape(lambda s: si.select_top_k(s, 16, block=16), scores)
+    selected = sum(min(16, t + 1) for t in range(S))
+    assert counted == {
+        "attention.indexer_heads": J, "attention.keys_selected": 16,
+        "attention.pairs_causal": B * S * (S + 1) // 2,
+        "attention.pairs_selected": B * selected,
+        "attention.mask_bytes": B * S * S}
+    # the cell's sizes: 43.75 % of the causal pairs
+    full = sum(min(2048, t + 1) for t in range(8192))
+    assert full == 14_681_088
+    assert round(100 * full / (8192 * 8193 // 2), 2) == 43.75
