@@ -57,6 +57,7 @@ SCOPES = (
     "attention/indexer/scores",
     "attention/indexer/select",
     "attention/indexer/loss",
+    "attention/indexer/loss/target",
     "attention/kernel",
     *(f"attention/kernel/{form}" for form in KERNEL_FORMS),
     "attention/out",

@@ -64,7 +64,7 @@ EXPECTED = {
                    "attention/qkv", "attention/out", "ffn/moe/route",
                    "ffn/moe/experts", "ffn/moe/shared", "head_and_loss"},
     "keye_vl": {"attention/qkv", "attention/indexer/proj",
-                "attention/indexer/scores", "attention/indexer/loss",
+                "attention/indexer/scores", "attention/indexer/loss/target",
                 "attention/kernel/fwd_rows", "attention/kernel/bwd_fused",
                 "attention/out", "ffn/moe/route", "ffn/moe/experts",
                 "head_and_loss"},

@@ -1,7 +1,11 @@
 """`ray_tpu/ops/sparse_index.py` at small sizes on the CPU: the index scores
 against a dense einsum, the threshold search against a stable sort and
-`jax.lax.top_k` (ties, zeros of either sign, every k), the indexer's loss and its gradient against the same written densely, and what
-it counts on the job timeline."""
+`jax.lax.top_k` (ties, zeros of either sign, every k), the indexer's loss and its gradient against the same written densely, the
+loss's target kernel interpreted against its plain reference, what it
+counts on the job timeline, and the loss at the cell's shape exported for
+a TPU."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -160,8 +164,10 @@ def dense_loss(scores, mask, q, k):
     """KL(mean over heads of the attention's probabilities over the
     selected keys || softmax of the selected scores), mean over queries."""
     chosen = mask != 0
+    B, S, H, D = q.shape
+    q, k = q.astype(jnp.float32), k.astype(jnp.float32)
     s = jnp.einsum("bqhd,bshd->bhqs", q,
-                   jnp.repeat(k, H // HKV, axis=2)) * D ** -0.5
+                   jnp.repeat(k, H // k.shape[2], axis=2)) * D ** -0.5
     p = jnp.mean(jax.nn.softmax(jnp.where(chosen[:, None], s, -jnp.inf), -1),
                  axis=1)
     log_q = jax.nn.log_softmax(jnp.where(chosen, scores, -jnp.inf), -1)
@@ -210,6 +216,89 @@ def test_nothing_of_the_loss_reaches_the_main_attention():
         assert not np.asarray(g).any()
 
 
+def target_inputs(B, S, H, Hkv, D, dtype, kind, block, seed=8):
+    """(mask, q, k, lse) of a main attention under a mask of ``kind``."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q, k, v = (jax.random.normal(key, (B, S, heads, D)).astype(dtype)
+               for key, heads in zip(ks, (H, Hkv, Hkv)))
+    tri = jnp.tril(jnp.ones((S, S), bool))
+    if kind == "empty_tile":
+        # the later half of the queries attend none of the first half of
+        # the keys: a causal tile with nothing selected
+        late = jnp.arange(S) >= S // 2
+        mask = jnp.broadcast_to(
+            tri & ~(late[:, None] & ~late[None]), (B, S, S)).astype(jnp.int8)
+    else:
+        scores = jnp.where(tri, jax.random.normal(ks[3], (B, S, S)), -jnp.inf)
+        top_k = {"top_k": S // 4, "single_key": 1, "triangle": 4 * S}[kind]
+        mask = si.select_top_k(scores, top_k, block=block)
+    _, lse = attention(q, k, v, mask=mask, with_lse=True)
+    return mask, q, k, lse
+
+
+def target_by_blocks(fn, mask, q, k, lse, block):
+    """(B, S, S): ``fn`` over every block, as `_loss_blocks` calls it."""
+    return si._rows(si._by_blocks(
+        lambda start, mask, lse, q, k: fn(q, k, lse, mask, start), block,
+        (mask, lse.transpose(0, 2, 1)),
+        (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3))))
+
+
+@pytest.mark.parametrize("heads,D,dtype,S,block,kind", [
+    ((8, 2), 64, jnp.float32, 128, 128, "top_k"),       # one q tile
+    ((8, 2), 128, jnp.bfloat16, 256, 128, "top_k"),     # two, a call each
+    ((4, 4), 64, jnp.bfloat16, 256, 256, "top_k"),      # two in one call
+    ((4, 4), 128, jnp.float32, 512, 256, "top_k"),      # four, two a call
+    ((8, 2), 64, jnp.bfloat16, 512, 128, "top_k"),      # four
+    ((8, 2), 128, jnp.float32, 256, 128, "empty_tile"),
+    ((4, 4), 64, jnp.bfloat16, 256, 128, "empty_tile"),
+    ((8, 2), 64, jnp.float32, 256, 128, "single_key"),
+    ((4, 4), 128, jnp.bfloat16, 256, 256, "single_key"),
+    ((8, 2), 64, jnp.float32, 256, 128, "triangle"),
+    ((4, 4), 128, jnp.bfloat16, 256, 128, "triangle"),
+])
+def test_the_target_kernel_is_its_reference_and_the_dense_form(
+        heads, D, dtype, S, block, kind):
+    """`_pallas_target`, interpreted, in tiles of 128 x 128 against
+    `_target_reference` (what `indexer_loss` ran before the kernel): to
+    1e-6 a head in float32; in bfloat16 one head alone bit for bit (its
+    result IS the exponent of the rounded argument) and the sum over the
+    heads to 1e-5 a head; nothing outside the mask.  And `indexer_loss`
+    through it against the dense float32 form."""
+    (H, Hkv), B = heads, 1
+    mask, q, k, lse = target_inputs(B, S, H, Hkv, D, dtype, kind, block)
+    scale = D ** -0.5
+    kernel = functools.partial(si._pallas_target, scale=scale, block_q=128,
+                               block_k=128, interpret=True)
+    reference = functools.partial(si._target_reference, scale=scale)
+    got = target_by_blocks(kernel, mask, q, k, lse, block)
+    want = target_by_blocks(reference, mask, q, k, lse, block)
+    assert got.dtype == jnp.float32 and got.shape == (B, S, S)
+    assert not np.asarray(got)[np.asarray(mask) == 0].any()
+    assert float(jnp.max(want)) > 0.5
+    tolerance = 1e-6 if dtype == jnp.float32 else 1e-5
+    assert float(jnp.max(jnp.abs(got - want))) <= tolerance * H
+    if dtype == jnp.bfloat16:
+        for h in (0, H - 1):
+            one = (mask, q[:, :, h:h + 1], k[:, :, h * Hkv // H:][:, :, :1],
+                   lse[:, h:h + 1], block)
+            assert np.array_equal(
+                np.asarray(target_by_blocks(kernel, *one)),
+                np.asarray(target_by_blocks(reference, *one)))
+    # the loss, the kernel at `_target_tiles`' own tiles where the
+    # interpreter takes the size, against the dense form
+    scores = jnp.where(jnp.tril(jnp.ones((S, S), bool)), jax.random.normal(
+        jax.random.PRNGKey(9), (B, S, S)), -jnp.inf)
+    loss, grad = jax.value_and_grad(
+        lambda sc: si.indexer_loss(sc, mask, q, k, lse, block=block))(scores)
+    dense, dense_grad = jax.value_and_grad(
+        lambda sc: dense_loss(sc, mask, q, k))(scores)
+    close = 2e-5 if dtype == jnp.float32 else 2e-2
+    assert float(jnp.abs(loss - dense)) <= close * max(float(dense), 1e-3)
+    assert float(jnp.max(jnp.abs(grad - dense_grad))) <= close / S
+    assert not np.asarray(grad)[np.asarray(mask) == 0].any()
+
+
 def test_what_it_counts_as_the_step_is_traced(monkeypatch):
     counted = {}
     monkeypatch.setattr(
@@ -228,3 +317,60 @@ def test_what_it_counts_as_the_step_is_traced(monkeypatch):
     full = sum(min(2048, t + 1) for t in range(8192))
     assert full == 14_681_088
     assert round(100 * full / (8192 * 8193 // 2), 2) == 43.75
+
+    def target_tiles(S, block, heads=(H, HKV), dim=D):
+        counted.clear()
+        shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+        jax.eval_shape(        # under the gradient, as a step traces it
+            jax.grad(functools.partial(si.indexer_loss, block=block)),
+            shape(1, S, S), jax.ShapeDtypeStruct((1, S, S), jnp.int8),
+            shape(1, S, heads[0], dim), shape(1, S, heads[1], dim),
+            shape(1, heads[0], S))
+        return (counted["attention.target_tiles"],
+                counted["attention.target_tiles_skipped"])
+
+    # the cell's: 256 x 512 tiles of the 8,192 square, two q tiles a k
+    # tile: 272 on or under the diagonal and 240 above it a sequence
+    assert target_tiles(8192, 512, (32, 4), 128) == (272, 240)
+    assert target_tiles(2048, 256) == (2 * (1 + 2 + 3 + 4), 2 * (3 + 2 + 1))
+    assert target_tiles(S, 16) == (4, 0)     # the whole sequence a k tile
+    # 576 keys are no whole number of 128-lane tiles: the reference
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    assert si._target_tiles(shape(1, 576, H, D), shape(1, 576, HKV, D),
+                            64) is None
+    # a q tile of every head that VMEM does not hold
+    assert si._target_tiles(shape(1, 512, 256, 512), shape(1, 512, 256, 512),
+                            256) is None
+    assert target_tiles(576, 64) == (0, 0)
+
+
+def exported_loss(S, block):
+    """The module of `indexer_loss` and its gradient to the scores at the
+    keye cell's heads (32 on 4 of 128, a batch of 2, bfloat16), shapes
+    only, exported for a TPU from this host."""
+    shape = jax.ShapeDtypeStruct
+    args = (shape((2, S, S), jnp.float32), shape((2, S, S), jnp.int8),
+            shape((2, S, 32, 128), jnp.bfloat16),
+            shape((2, S, 4, 128), jnp.bfloat16),
+            shape((2, 32, S), jnp.float32))
+    with jax.default_matmul_precision("default"):
+        return jax.export.export(jax.jit(jax.value_and_grad(
+            functools.partial(si.indexer_loss, block=block))),
+            platforms=["tpu"])(*args).mlir_module()
+
+
+def test_the_target_lowers_to_mosaic_for_tpu_at_the_cells_shape():
+    """2 x 8,192 by blocks of 512: the target is a Mosaic custom call, no
+    product is left to XLA and no head's scores of a block exist."""
+    module = exported_loss(8192, 512)
+    assert module.count("stablehlo.custom_call @tpu_custom_call") == 1
+    assert "stablehlo.dot_general" not in module
+    assert "4x8x512x8192" not in module
+
+
+def test_a_shape_the_target_kernel_declines_lowers_to_the_reference():
+    """8,256 keys (129 blocks of 64) are no whole number of 128-lane
+    tiles: the five XLA lines on every platform, the TPU included."""
+    module = exported_loss(8256, 64)
+    assert "tpu_custom_call" not in module
+    assert "stablehlo.dot_general" in module

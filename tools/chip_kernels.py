@@ -38,6 +38,18 @@ operator and its gates and taps alone, in each form tried for the taps,
 against a float32 sum over taps and against the least time of the gates'
 and taps' bytes.
 
+``target_8k`` is the indexer loss's target alone
+(`ops/sparse_index.py:_pallas_target`) at the keye cell's shape (2 x 8,192,
+32 query heads on 4 key heads of 128, blocks of 512 queries, 2,048 keys a
+query): every block's head-summed probabilities as `_loss_blocks` makes
+them, the Mosaic kernel beside `_target_reference` (the plain XLA form, which
+alone here XLA fuses into one pass and frees of its rounding to bfloat16:
+inside the step it is two fusions through HBM), device ms of every
+operation (the blocks' slices and the stacking of their results among
+them; `kernel_ms` read 0.0 here at PR 48: a kernel called inside
+`lax.map`'s loop is not among the events it sums) and the largest error
+relative to the same in float32.  ``--sweep target-8k`` times the kernel at other tiles.
+
 ``ssd_8k`` is the chunked state-space scan alone (`ops/ssd.py:ssd_scan`) at
 (2, 8192, 64 heads of 64) with 8 groups and a state of 128, in bfloat16 and
 in float32: the Pallas kernels as `ssd_scan` calls them, and the `einsum`
@@ -91,6 +103,15 @@ SHORTCONV_CASES = {
 # router's, hidden and expert widths
 MOE_CASES = {
     "moe_held_8k": (16384, 6, 16, 128, 2048, 768),
+}
+# (B, S, H, H_kv, D, block, top_k) of one indexer loss's target
+TARGET_CASES = {
+    "target_8k": (2, 8192, 32, 4, 128, 512, 2048),
+}
+# the (q tile, k tile) `--sweep target-8k` times a case of TARGET_CASES at
+TARGET_SWEEP = {
+    "target-8k": ("target_8k", ((128, 512), (256, 256), (256, 512),
+                                (256, 1024), (512, 512))),
 }
 # the tiles of a sequence past `_WHOLE_SEQ_MAX`: square, and the backward's
 # k tile (a grid step) beside another q tile (its loop's step)
@@ -444,6 +465,55 @@ def shortconv_case(name, dtype):
            "least_fwd_bwd_ms": round(4 * flops / 197e12 * 1e3, 4)}
 
 
+def target_case(name, dtype, tiles=None):
+    """The indexer loss's target at ``TARGET_CASES[name]``, every block of
+    both sequences as `_loss_blocks` makes them: a line for the Mosaic
+    kernel (``tiles``: at these instead of `_TARGET_TILE`, and nothing
+    compared) and one for `_target_reference`: device ms of every
+    operation, and the largest error of the (B, S, S) result relative to
+    the reference on float32 operands."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import by_platform
+    from ray_tpu.ops import sparse_index as si
+    from ray_tpu.parallel.attention import attention
+
+    B, S, H, Hkv, D, block, top_k = TARGET_CASES[name]
+    q, k, v = _qkv((B, S, H, D), dtype, Hkv)
+    scores = jnp.where(jnp.tril(jnp.ones((S, S), bool)), jax.random.normal(
+        jax.random.PRNGKey(3), (B, S, S)), -jnp.inf)
+    mask = jax.jit(lambda s: si.select_top_k(s, top_k, block))(scores)
+    del scores
+    _, lse = jax.jit(lambda q, k, v, m: attention(
+        q, k, v, mask=m, with_lse=True))(q, k, v, mask)
+    scale = D ** -0.5
+    reference = functools.partial(si._target_reference, scale=scale)
+    bq, bk = tiles or si._target_tiles(q, k, block)
+    kernel = functools.partial(by_platform, functools.partial(
+        si._pallas_target, scale=scale, block_q=bq, block_k=bk), reference)
+
+    def by_blocks(fn):
+        return jax.jit(lambda mask, q, k, lse: si._rows(si._by_blocks(
+            lambda start, mask, lse, q, k: fn(q, k, lse, mask, start), block,
+            (mask, lse.transpose(0, 2, 1)),
+            (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)))))
+
+    exact = None if tiles else by_blocks(reference)(
+        mask, q.astype(jnp.float32), k.astype(jnp.float32), lse)
+    forms = {"kernel": kernel} if tiles else {"kernel": kernel,
+                                               "reference": reference}
+    for form, fn in forms.items():
+        f = by_blocks(fn)
+        line = {"case": name, "form": form, "tile": [bq, bk],
+                "every_op_ms": busy_ms(f, mask, q, k, lse)}
+        if exact is not None:
+            line["rel_err"] = {"target": round(float(
+                jnp.max(jnp.abs(f(mask, q, k, lse) - exact))
+                / jnp.max(jnp.abs(exact))), 5)}
+        yield line
+
+
 def ssd_case(name, dtype, chunk=None, compare=True):
     """One state-space scan at ``SSD_CASES[name]`` (``chunk`` given: at
     that chunk): a line for each form of it (`ops/ssd.py`) -- the `einsum`
@@ -601,16 +671,18 @@ def main():
                              f"({', '.join(SWEEP)}; none named: at all)")
     parser.add_argument("--cases", nargs="+", metavar="CASE",
                         default=[*CASES, *MOE_CASES, *SHORTCONV_CASES,
-                                 *SSD_CASES],
+                                 *SSD_CASES, *TARGET_CASES],
                         help=f"run these only ({', '.join(CASES)}, "
                              f"{', '.join(MOE_CASES)}, "
                              f"{', '.join(SHORTCONV_CASES)}, "
-                             f"{', '.join(SSD_CASES)}; default: all)")
+                             f"{', '.join(SSD_CASES)}, "
+                             f"{', '.join(TARGET_CASES)}; default: all)")
     args = parser.parse_args()
-    if args.sweep and set(args.sweep) - set(SWEEP) - set(SSD_SWEEP):
+    if args.sweep and set(args.sweep) - set(SWEEP) - set(SSD_SWEEP) \
+            - set(TARGET_SWEEP):
         parser.error(f"--sweep: no such shape in "
-                     f"{sorted([*SWEEP, *SSD_SWEEP])}")
-    known = [*CASES, *MOE_CASES, *SHORTCONV_CASES, *SSD_CASES]
+                     f"{sorted([*SWEEP, *SSD_SWEEP, *TARGET_SWEEP])}")
+    known = [*CASES, *MOE_CASES, *SHORTCONV_CASES, *SSD_CASES, *TARGET_CASES]
     if set(args.cases) - set(known):
         parser.error(f"--cases: no such case in {known}")
 
@@ -632,7 +704,15 @@ def main():
         sys.exit(f"no TPU: jax found {dev.platform!r}")
 
     if args.sweep is not None:
-        for name in args.sweep or [*SWEEP, *SSD_SWEEP]:
+        for name in args.sweep or [*SWEEP, *SSD_SWEEP, *TARGET_SWEEP]:
+            if name in TARGET_SWEEP:
+                case, tiles = TARGET_SWEEP[name]
+                for tile in tiles:
+                    for line in target_case(case, jnp.bfloat16, tile):
+                        print(json.dumps({
+                            "sweep": name, **line,
+                            "device_kind": dev.device_kind}), flush=True)
+                continue
             if name in SSD_SWEEP:
                 case, chunks = SSD_SWEEP[name]
                 for chunk in chunks:
@@ -696,6 +776,14 @@ def main():
             # the scan's forward kernel, and its backward's two
             ok = max(line.get("rel_err", {"": 0}).values()) < TOLERANCE \
                 and line.get("mosaic_kernels", 3) == 3
+            if not ok:
+                failed.append(f"{name}:{line['form']}")
+            print(json.dumps({**line, "ok": ok,
+                              "device_kind": dev.device_kind}), flush=True)
+    for name in TARGET_CASES:
+        for line in target_case(name, jnp.bfloat16) \
+                if name in args.cases else ():
+            ok = line["rel_err"]["target"] < TOLERANCE
             if not ok:
                 failed.append(f"{name}:{line['form']}")
             print(json.dumps({**line, "ok": ok,
