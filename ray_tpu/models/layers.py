@@ -370,8 +370,9 @@ KEPT_NAMES = (
     "attention/latent_up",      # k and v multiplied out of the latent: the
                                 # widest and the cheapest to remake
     "attention/indexer/scores", # an indexer's I, (B, S, S) float32: four
-                                # times the mask, and its backward makes a
-                                # block's products again either way
+                                # times the mask; kept, it saves a replay
+                                # its forward kernel, never the backward's
+                                # products, which are made in VMEM either way
 )
 
 # Of the device's memory limit, the share the budget never spends: the

@@ -11,20 +11,28 @@ names).  Shared by `models/keye_vl.py` and whatever selects next.
               L1-normalised, a constant
 
 The mask is `ops/flash_attention.py`'s operand: (B, S, S) int8, 1 where t
-attends s.  Everything here goes by blocks of ``block`` query rows
+attends s.  The selection and the loss go by blocks of ``block`` query rows
 (`sa_config`'s `q_chunk_size`), one sequence's block at a time
-(`_by_blocks`): what a step holds of a head's scores is one block's, never
+(`_by_blocks`); no step holds a head's scores of more than a block, never
 an S x S array a head; what it holds summed over the heads is the (B, S, S)
-float32 of I and, under the gradient, of dL_I / dI.  The scores, the
-selection and the loss's normalisation, KL and gradient are plain XLA.  The
-loss's TARGET, the main attention's probabilities of a block summed over
-its heads, is one Mosaic kernel a block (`_pallas_target`): every head's
-QK', its exponent and the sum over the heads stay in VMEM, and the key
-tiles above the block's diagonal are not visited.  It runs as the flash
-kernels do (`ops.by_platform`): compiled where the step is lowered for a
-TPU, interpreted elsewhere up to the tests' sizes, and the same
-arithmetic in plain XLA (`_target_reference`) beyond them and for a shape
-the kernel cannot tile (`_target_tiles`).
+float32 of I and, under the gradient, of dL_I / dI.  The selection and the
+loss's normalisation, KL and gradient are plain XLA.  Three Mosaic kernels
+keep the heads' products in VMEM.  The SCORES are one kernel a layer
+(`_pallas_scores`: every indexer head's q . k', its relu, its weight and
+the sum over the heads a (q tile, k tile) at a time, the tiles above the
+diagonal written as -inf and not computed) and their backward another
+(`_pallas_scores_bwd`, under `index_scores`' own `jax.custom_vjp`: the
+products made again once a tile and contracted into dq, dk and dw, the one
+key head's dk summed in VMEM over a sequence's tiles).  The loss's TARGET,
+the main attention's probabilities of a block summed over its heads, is
+one kernel a block (`_pallas_target`): every head's QK', its exponent and
+the sum over the heads, the key tiles above the block's diagonal not
+visited.  All three run as the flash kernels do (`ops.by_platform`):
+compiled where the step is lowered for a TPU, interpreted elsewhere up to
+the tests' sizes, and the same arithmetic in plain XLA by blocks
+(`_scores_reference`, `_scores_reference_bwd`, `_target_reference`) beyond
+them and for a shape a kernel cannot tile (`_scores_tiles`,
+`_target_tiles`).
 
 The selection is a threshold search and no `jax.lax.top_k`: a top-k gives
 the keys' indices, 2,048 a row for 16,384 rows a layer, and a mask of them
@@ -42,7 +50,8 @@ made the step's time wander by).  The result is `jax.lax.top_k`'s set,
 exactly.
 
 Counts itself on the job timeline as the step is traced:
-`attention.indexer_heads` (`index_scores`), `attention.keys_selected`,
+`attention.indexer_heads`, `attention.score_tiles`,
+`attention.score_tiles_skipped` (`index_scores`), `attention.keys_selected`,
 `attention.pairs_causal`, `attention.pairs_selected`, `attention.mask_bytes`
 (`select_top_k`), `attention.target_tiles`, `attention.target_tiles_skipped`
 (`indexer_loss`; a recomputed layer is traced once).
@@ -68,6 +77,17 @@ from ray_tpu.util import tracing
 # every head's q tile and a k tile of every key head
 _TARGET_TILE = (256, 512)
 _TARGET_VMEM_MAX = 64 << 20
+# the scores' kernels' (q tile, k tile) at most (`tools/chip_kernels.py
+# --sweep scores-8k` on a v5e, forward / backward ms a layer of the keye
+# cell's two sequences and the seconds the backward took to compile:
+# 128 x 512 2.13 / 6.23 / 1.4, 256 x 256 2.18 / 7.71 / 1.7, 256 x 512 2.01 /
+# 5.49 / 2.7, 256 x 1,024 2.03 / 5.38 / 5.3, 512 x 512 1.95 / 5.05 / 6.1,
+# 512 x 1,024 2.01 / 5.15 / 11.3: the last 8 % of the backward cost a run
+# 3 s of compiling before its first step; PERF.md §6, PR 49), and the scoped
+# VMEM they may ask for: a step of the backward holds every head's q tile,
+# its dq in float32 and the sequence's dk
+_SCORES_TILE = (256, 512)
+_SCORES_VMEM_MAX = 64 << 20
 
 
 def _by_blocks(fn, block, rows, whole=(), first=0):
@@ -107,25 +127,309 @@ def _causal(start, rows, S):
     return (start + jnp.arange(rows))[:, None] >= jnp.arange(S)[None]
 
 
+def _count_tiles(name, B, S, tiles):
+    """Add a layer's ``name`` tiles to the job timeline, as the step is
+    traced: `attention.<name>_tiles` the (q tile, k tile) grid steps of the
+    S x S square that the kernel computes, over the B sequences, and
+    `attention.<name>_tiles_skipped` those wholly above the diagonal, which
+    it fills and does not compute.  Both 0 without ``tiles``: the shape
+    took the plain reference.  Called by `index_scores` and `indexer_loss`
+    themselves, once a traced layer: a custom rule's functions are all
+    traced under a gradient."""
+    on = above = 0
+    if tiles:
+        block_q, block_k = tiles
+        on = sum(min(S // block_k, ((i + 1) * block_q - 1) // block_k + 1)
+                 for i in range(S // block_q))
+        above = (S // block_q) * (S // block_k) - on
+    tracing.count(f"attention.{name}_tiles", B * on)
+    tracing.count(f"attention.{name}_tiles_skipped", B * above)
+
+
+def _tile(extent, cap, unit, whole=None):
+    """The largest power-of-two part of ``cap`` that divides ``extent``, or
+    None where that is neither the array's ``whole`` dimension (``extent``
+    if not given) nor a multiple of what Mosaic tiles the dimension by."""
+    t = min(cap, extent)
+    while extent % t:
+        t //= 2
+    return t if t == (whole or extent) or t % unit == 0 else None
+
+
+def _lanes(d):
+    """A width padded to whole lanes, as VMEM holds it."""
+    return -(-d // 128) * 128
+
+
+def _kernel_or_reference(kernel, reference, tiles):
+    """What runs a kernel's work: ``kernel`` at ``tiles`` where the call is
+    lowered for a TPU and as `ops.by_platform` says elsewhere, or
+    ``reference`` on every platform for a shape without tiles."""
+    if tiles is None:
+        return reference
+    return functools.partial(by_platform, functools.partial(
+        kernel, block_q=tiles[0], block_k=tiles[1]), reference)
+
+
+def _block_scores(start, q, w, k):
+    """One block of queries' scores in plain XLA: q (rows, J, D), w
+    (rows, J) float32 and k (S, D), the block's first query ``start`` ->
+    (rows, S) float32."""
+    products = jnp.einsum("qjd,sd->jqs", q, k,
+                          preferred_element_type=jnp.float32)
+    total = jnp.sum(w.T[:, :, None] * jax.nn.relu(products), axis=0)
+    return jnp.where(_causal(start, *total.shape), total, -jnp.inf)
+
+
+def _scores_reference(q, k, w, *, block):
+    """`index_scores` in plain XLA by blocks of ``block`` query rows, and
+    what the kernels are held to: a block's (J, block, S) float32 products
+    go through HBM, one block at a time."""
+    return _rows(_by_blocks(_block_scores, block, (q, w), (k,)))
+
+
+def _scores_reference_bwd(q, k, w, g, *, block):
+    """The gradients of `_scores_reference` to q, k and w under the
+    cotangent g (B, S, S), block by block: a block's products are made
+    again, differentiated and dropped; the blocks' parts of dk are summed
+    in float32."""
+    def one(start, q, w, g, k):
+        back = jax.vjp(functools.partial(_block_scores, start), q, w, k)[1]
+        return back(g)
+
+    dq, dw, dk = _by_blocks(one, block, (q, w, g), (k,))
+    return _rows(dq), jnp.sum(dk, axis=1, dtype=jnp.float32).astype(
+        k.dtype), _rows(dw)
+
+
+def _tile_causal(i, j, block_q, block_k):
+    """(block_q, block_k): whether the query of q tile i sees the key of k
+    tile j."""
+    shape = (block_q, block_k)
+    return i * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0) \
+        >= j * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+
+def _scores_kernel(q_ref, k_ref, w_ref, o_ref, *, block_q, block_k):
+    """One (q tile, k tile) of a sequence's scores, the heads inside: q_ref
+    (block_q, J D) the heads side by side in the lanes as they lie, k_ref
+    (block_k, D), w_ref (block_q, J) float32, o_ref (block_q, block_k)
+    float32.  A tile wholly above the diagonal is written as -inf and
+    nothing of it is computed (its k block is the last visited tile's, not
+    fetched again: `_pallas_scores`' index maps)."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    visited = j * block_k < (i + 1) * block_q
+    D = k_ref.shape[1]
+
+    @pl.when(visited)
+    def _():
+        k = k_ref[...]
+        total = None
+        for h in range(w_ref.shape[1]):     # unrolled: a head's products
+            p = jax.lax.dot_general(        # pass while the last one's sum
+                q_ref[:, h * D:(h + 1) * D], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            term = w_ref[:, h:h + 1] * jax.nn.relu(p)
+            total = term if total is None else total + term
+        o_ref[...] = jnp.where(_tile_causal(i, j, block_q, block_k), total,
+                               -jnp.inf)
+
+    @pl.when(jnp.logical_not(visited))
+    def _():
+        o_ref[...] = jnp.full_like(o_ref, -jnp.inf)
+
+
+def _scores_bwd_kernel(q_ref, k_ref, w_ref, g_ref, dq_ref, dk_ref, dw_ref,
+                       dq_acc, dw_acc, *, block_q, block_k):
+    """One (q tile, k tile) of the scores' backward, the heads inside: each
+    head's products made again ONCE and contracted into dq, dk and dw.
+    q_ref and dq_ref (block_q, J D), k_ref (block_k, D), w_ref and dw_ref
+    (block_q, J) float32, g_ref (block_q, block_k) float32; dk_ref (S, D)
+    float32 stays in VMEM while a sequence's tiles pass and every tile adds
+    its rows (it is the one key head's: 2 MB at 8,192 x 64), zeroed at the
+    sequence's first step.  The k tiles are the inner grid axis: dq and dw
+    sum over them in the float32 scratch ``dq_acc``, ``dw_acc``, zeroed at
+    a q tile's first k tile and written at its last on the diagonal; the
+    tiles above it are not visited.  relu's gradient at 0 is 0."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    last = ((i + 1) * block_q - 1) // block_k
+    D = k_ref.shape[1]
+
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dw_acc[...] = jnp.zeros_like(dw_acc)
+
+    @pl.when(j <= last)
+    def _():
+        k = k_ref[...]
+        g = jnp.where(_tile_causal(i, j, block_q, block_k), g_ref[...], 0.0)
+        dk = jnp.zeros(k.shape, jnp.float32)
+        for h in range(w_ref.shape[1]):
+            cols = slice(h * D, (h + 1) * D)
+            q = q_ref[:, cols]
+            p = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            r = jnp.where(p > 0, g, 0.0)
+            dw_acc[:, h:h + 1] += jnp.sum(r * p, axis=1, keepdims=True)
+            a = (r * w_ref[:, h:h + 1]).astype(q.dtype)
+            dq_acc[:, cols] += jnp.dot(a, k,
+                                       preferred_element_type=jnp.float32)
+            dk = dk + jax.lax.dot_general(
+                a, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        dk_ref[rows, :] += dk
+
+    @pl.when(j == last)
+    def _():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+        dw_ref[...] = dw_acc[...]
+
+
+def _scores_vmem_bytes(q, block_q, block_k):
+    """What a grid step of `_scores_bwd_kernel` (the larger of the two)
+    holds in VMEM: its blocks twice (a width padded to whole lanes), the
+    float32 scratch, the sequence's dk and a tile's float32
+    temporaries."""
+    _, S, J, D = q.shape
+    width = q.dtype.itemsize
+    tile = block_q * _lanes(block_k) * 4
+    blocks = 2 * block_q * _lanes(J * D) * width \
+        + block_k * _lanes(D) * width + 2 * block_q * _lanes(J) * 4 + tile
+    scratch = block_q * (_lanes(J * D) + _lanes(J)) * 4
+    return 2 * blocks + scratch + 2 * S * _lanes(D) * 4 + 6 * tile
+
+
+def _scores_tiles(q, block):
+    """(q tile, k tile) of the scores' kernels for the indexer's queries q
+    (B, S, J, D) by blocks of ``block`` rows, or None for a shape they
+    cannot tile, which takes `_scores_reference` on every platform: a q
+    tile divides the block and a k tile the sequence, each the largest
+    power-of-two part of `_SCORES_TILE`'s that does; a tile that is not the
+    whole sequence is a multiple of what Mosaic tiles it by (16 rows of a
+    two-byte q, 128 lanes of scores); a head's columns do not straddle a
+    128-lane tile of q's row; a step's blocks fit `_SCORES_VMEM_MAX`."""
+    S, D = q.shape[1], q.shape[3]
+    tiles = (_tile(block, _SCORES_TILE[0], 16, whole=S),
+             _tile(S, _SCORES_TILE[1], 128))
+    if None in tiles or (128 % D and D % 128) \
+            or _scores_vmem_bytes(q, *tiles) > _SCORES_VMEM_MAX:
+        return None
+    return tiles
+
+
+def _last_on_diagonal(i, j, block_q, block_k):
+    """k tile j of q tile i, or for one above the diagonal the last on it:
+    a skipped step's blocks are those already held, fetched once."""
+    return jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k",
+                                             "interpret"))
+def _pallas_scores(q, k, w, *, block_q, block_k, interpret):
+    """`_scores_reference` as one Mosaic kernel a layer: grid (sequences,
+    q tiles, k tiles); q as it lies, (B, S, J D) with a head's columns
+    sliced from the lanes in the kernel: nothing is transposed."""
+    B, S, J, D = q.shape
+    visited = functools.partial(_last_on_diagonal, block_q=block_q,
+                                block_k=block_k)
+    call = pl.pallas_call(
+        functools.partial(_scores_kernel, block_q=block_q, block_k=block_k),
+        grid=(B, S // block_q, S // block_k),
+        in_specs=[
+            pl.BlockSpec((None, block_q, J * D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, block_k, D),
+                         lambda b, i, j: (b, visited(i, j), 0)),
+            pl.BlockSpec((None, block_q, J), lambda b, i, j: (b, i, 0))],
+        out_specs=pl.BlockSpec((None, block_q, block_k),
+                               lambda b, i, j: (b, i, j)),
+        out_shape=jax.ShapeDtypeStruct((B, S, S), jnp.float32),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_SCORES_VMEM_MAX))
+    return call(q.reshape(B, S, J * D), k, w)
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k",
+                                             "interpret"))
+def _pallas_scores_bwd(q, k, w, g, *, block_q, block_k, interpret):
+    """`_scores_reference_bwd` as one Mosaic kernel a layer: the same grid,
+    the k tiles innermost and in order (dq, dw and the sequence's dk sum
+    over them in VMEM) -> (dq in q's type, dk in k's, dw float32)."""
+    B, S, J, D = q.shape
+    visited = functools.partial(_last_on_diagonal, block_q=block_q,
+                                block_k=block_k)
+    heads = pl.BlockSpec((None, block_q, J * D), lambda b, i, j: (b, i, 0))
+    weights = pl.BlockSpec((None, block_q, J), lambda b, i, j: (b, i, 0))
+    call = pl.pallas_call(
+        functools.partial(_scores_bwd_kernel, block_q=block_q,
+                          block_k=block_k),
+        grid=(B, S // block_q, S // block_k),
+        in_specs=[
+            heads,
+            pl.BlockSpec((None, block_k, D),
+                         lambda b, i, j: (b, visited(i, j), 0)),
+            weights,
+            pl.BlockSpec((None, block_q, block_k),
+                         lambda b, i, j: (b, i, visited(i, j)))],
+        out_specs=[heads,
+                   pl.BlockSpec((None, S, D), lambda b, i, j: (b, 0, 0)),
+                   weights],
+        out_shape=[jax.ShapeDtypeStruct((B, S, J * D), q.dtype),
+                   jax.ShapeDtypeStruct((B, S, D), jnp.float32),
+                   jax.ShapeDtypeStruct((B, S, J), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, J * D), jnp.float32),
+                        pltpu.VMEM((block_q, J), jnp.float32)],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_SCORES_VMEM_MAX))
+    dq, dk, dw = call(q.reshape(B, S, J * D), k, w, g)
+    return dq.reshape(q.shape), dk.astype(k.dtype), dw
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _scores(q, k, w, block, tiles):
+    return _scores_fwd(q, k, w, block, tiles)[0]
+
+
+def _scores_fwd(q, k, w, block, tiles):
+    scores = _kernel_or_reference(_pallas_scores, functools.partial(
+        _scores_reference, block=block), tiles)
+    return scores(q, k, w), (q, k, w)
+
+
+def _scores_bwd(block, tiles, residuals, g):
+    backward = _kernel_or_reference(_pallas_scores_bwd, functools.partial(
+        _scores_reference_bwd, block=block), tiles)
+    return backward(*residuals, g)
+
+
+_scores.defvjp(_scores_fwd, _scores_bwd)
+
+
 def index_scores(q, k, w, block=512):
     """q (B, S, J, D) the indexer's J query heads, k (B, S, D) its one key
     head, w (B, S, J) the heads' weights -> I (B, S, S) float32, -inf
     above the diagonal: sum_j w_j relu(q_j . k), the products in float32
     from q's and k's type, the weighted sum in float32.  Differentiable in
-    q, k and w; a block's (J, block, S) products are made again by the
-    backward pass and never kept."""
-    S, J = q.shape[1:3]
+    q, k and w under a rule of its own that keeps q, k and w alone: the
+    heads' products are made again by the backward pass, and in either
+    pass are one tile's in VMEM (`_pallas_scores`, `_pallas_scores_bwd`),
+    or one block's through HBM where `_scores_tiles` declines the shape
+    (`_scores_reference`)."""
+    B, S, J = q.shape[:3]
     block = _block(S, block)
+    tiles = _scores_tiles(q, block)
     tracing.count("attention.indexer_heads", J)
-
-    @jax.checkpoint
-    def scores(start, q, w, k):
-        products = jnp.einsum("qjd,sd->jqs", q, k,
-                              preferred_element_type=jnp.float32)
-        total = jnp.sum(w.T[:, :, None] * jax.nn.relu(products), axis=0)
-        return jnp.where(_causal(start, block, S), total, -jnp.inf)
-
-    return _rows(_by_blocks(scores, block, (q, w.astype(jnp.float32)), (k,)))
+    _count_tiles("score", B, S, tiles)
+    return _scores(q, k, w.astype(jnp.float32), block, tiles)
 
 
 def _ordered(x):
@@ -245,12 +549,11 @@ def _target_kernel(start_ref, q_ref, k_ref, lse_ref, mask_ref, o_ref, *,
 def _target_vmem_bytes(q, k, block_q, block_k):
     """What a grid step of `_target_kernel` holds in VMEM: its blocks twice
     (a width padded to whole lanes) and a tile's float32 temporaries."""
-    lanes = lambda d: -(-d // 128) * 128
     H, D = q.shape[2:]
-    blocks = (H * block_q + k.shape[2] * block_k) * lanes(D) \
+    blocks = (H * block_q + k.shape[2] * block_k) * _lanes(D) \
         * q.dtype.itemsize \
-        + block_q * lanes(H) * 4 + block_q * lanes(block_k) * (1 + 4)
-    return 2 * blocks + 6 * block_q * lanes(block_k) * 4
+        + block_q * _lanes(H) * 4 + block_q * _lanes(block_k) * (1 + 4)
+    return 2 * blocks + 6 * block_q * _lanes(block_k) * 4
 
 
 def _target_tiles(q, k, block):
@@ -261,14 +564,8 @@ def _target_tiles(q, k, block):
     power-of-two part of `_TARGET_TILE`'s that does; a tile that is not the
     whole extent is a multiple of what Mosaic tiles the mask by (32 rows,
     128 lanes); a step's blocks fit `_TARGET_VMEM_MAX`."""
-    def tile(extent, cap, unit):
-        t = min(cap, extent)
-        while extent % t:
-            t //= 2
-        return t if t == extent or t % unit == 0 else None
-
-    tiles = tile(block, _TARGET_TILE[0], 32), tile(q.shape[1],
-                                                   _TARGET_TILE[1], 128)
+    tiles = (_tile(block, _TARGET_TILE[0], 32),
+             _tile(q.shape[1], _TARGET_TILE[1], 128))
     if None in tiles or _target_vmem_bytes(q, k, *tiles) > _TARGET_VMEM_MAX:
         return None
     return tiles
@@ -316,36 +613,13 @@ def _pallas_target(q, k, lse, mask, start, *, scale, block_q, block_k,
         return call(start, q, k, lse, mask)
 
 
-def _count_target_tiles(B, S, tiles):
-    """Add a layer's target tiles to the job timeline, as the step is
-    traced: `attention.target_tiles` the (q tile, k tile) grid steps of the
-    S x S square that `_target_kernel` computes, over the B sequences, and
-    `attention.target_tiles_skipped` those wholly above the diagonal, which
-    it writes as zeros.  Both 0 without ``tiles``: the shape took
-    `_target_reference`.  Called by `indexer_loss` itself, once a traced
-    layer: its custom rule's two functions are both traced under a
-    gradient."""
-    on = above = 0
-    if tiles:
-        block_q, block_k = tiles
-        on = sum(min(S // block_k, ((i + 1) * block_q - 1) // block_k + 1)
-                 for i in range(S // block_q))
-        above = (S // block_q) * (S // block_k) - on
-    tracing.count("attention.target_tiles", B * on)
-    tracing.count("attention.target_tiles_skipped", B * above)
-
-
 def _loss_blocks(scores, mask, q, k, lse, scale, block):
     """-> (sum over the queries of KL(p || softmax of the selected scores),
     its gradient to ``scores`` (B, S, S) float32) by blocks of queries."""
-    tiles = _target_tiles(q, k, block)
-    reference = functools.partial(_target_reference, scale=scale)
-    if tiles is None:
-        target = reference
-    else:
-        target = functools.partial(by_platform, functools.partial(
-            _pallas_target, scale=scale, block_q=tiles[0], block_k=tiles[1]),
-            reference)
+    target = _kernel_or_reference(
+        functools.partial(_pallas_target, scale=scale),
+        functools.partial(_target_reference, scale=scale),
+        _target_tiles(q, k, block))
 
     def one(start, scores, mask, lse, q, k):
         chosen = mask != 0
@@ -402,6 +676,6 @@ def indexer_loss(scores, mask, q, k, lse, block=512):
     where `_target_tiles` declines the shape."""
     B, S = scores.shape[:2]
     block = _block(S, block)
-    _count_target_tiles(B, S, _target_tiles(q, k, block))
+    _count_tiles("target", B, S, _target_tiles(q, k, block))
     q, k, lse = jax.lax.stop_gradient((q, k, lse))
     return _indexer_loss(scores, mask, q, k, lse, q.shape[-1] ** -0.5, block)

@@ -1,5 +1,6 @@
 """`ray_tpu/ops/sparse_index.py` at small sizes on the CPU: the index scores
-against a dense einsum, the threshold search against a stable sort and
+against a dense einsum, their two kernels interpreted against the blocked
+reference and the dense form, the threshold search against a stable sort and
 `jax.lax.top_k` (ties, zeros of either sign, every k), the indexer's loss and its gradient against the same written densely, the
 loss's target kernel interpreted against its plain reference, what it
 counts on the job timeline, and the loss at the cell's shape exported for
@@ -68,6 +69,91 @@ def test_index_scores_gradients_match_autodiff_of_the_dense_form():
     want = jax.grad(lambda *a: loss(dense_scores(*a)), (0, 1, 2))(q, k, w)
     for g, v in zip(got, want):
         assert float(jnp.max(jnp.abs(g - v))) < 1e-4
+
+
+def scores_inputs(B, S, J, D, dtype, seed=10):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    tri = jnp.tril(jnp.ones((S, S), bool))
+    return (jax.random.normal(ks[0], (B, S, J, D)).astype(dtype),
+            jax.random.normal(ks[1], (B, S, D)).astype(dtype),
+            jax.random.normal(ks[2], (B, S, J)),
+            # a cotangent as the loss's: nothing above the diagonal
+            jnp.where(tri, jax.random.normal(ks[3], (B, S, S)), 0.0), tri)
+
+
+@pytest.mark.parametrize("B,S,J,D,dtype,block_q,block_k", [
+    (1, 512, 4, 16, jnp.float32, 128, 128),     # 4 x 4 tiles: 4 diagonal,
+    (1, 512, 4, 16, jnp.bfloat16, 128, 128),    # 6 full, 6 skipped
+    (2, 256, 8, 16, jnp.float32, 64, 128),      # two q tiles a k tile
+    (2, 256, 8, 16, jnp.bfloat16, 64, 128),
+    (1, 512, 2, 64, jnp.bfloat16, 128, 256),    # the cell's depth
+    (1, 256, 16, 8, jnp.float32, 128, 64),      # two k tiles a q tile
+])
+def test_the_scores_kernels_are_their_reference_and_the_dense_form(
+        B, S, J, D, dtype, block_q, block_k):
+    """`_pallas_scores` and `_pallas_scores_bwd`, interpreted, at tiles
+    that leave several each way, against `_scores_reference` and its
+    backward (what `index_scores` ran before the kernels) and against the
+    dense `einsum` form under autodiff: -inf exactly above the diagonal,
+    the scores to 2e-6 of the largest, the gradients to float32's rounding, and in
+    bfloat16 to its rounding of the backward's operand."""
+    q, k, w, g, tri = scores_inputs(B, S, J, D, dtype)
+    tiles = dict(block_q=block_q, block_k=block_k, interpret=True)
+    got = si._pallas_scores(q, k, w, **tiles)
+    want = si._scores_reference(q, k, w, block=block_q)
+    assert got.dtype == jnp.float32 and got.shape == (B, S, S)
+    assert np.array_equal(np.isneginf(got), np.broadcast_to(~tri, got.shape))
+    f32 = q.astype(jnp.float32), k.astype(jnp.float32), w
+    dense, back = jax.vjp(dense_scores, *f32)
+    largest = float(jnp.max(jnp.abs(jnp.where(tri, dense, 0))))
+    for other in (want, dense):
+        assert float(jnp.max(jnp.abs(jnp.where(tri, got - other, 0)))) \
+            <= 2e-6 * largest
+    grads = si._pallas_scores_bwd(q, k, w, g, **tiles)
+    blocked = si._scores_reference_bwd(q, k, w, g, block=block_q)
+    close = 1e-4 if dtype == jnp.float32 else 2e-2
+    for name, a, b, c in zip("qkw", grads, blocked, back(g)):
+        assert a.dtype == b.dtype == (jnp.float32 if name == "w" else dtype)
+        assert a.shape == b.shape == c.shape
+        scale = float(jnp.max(jnp.abs(c)))
+        for other in (b, c):
+            assert float(jnp.max(jnp.abs(
+                a.astype(jnp.float32) - other))) <= close * scale, name
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_a_product_that_is_exactly_zero_takes_no_gradient(dtype):
+    """relu's gradient at 0 is 0, as `jax.nn.relu`'s: a query of zeros
+    (every product of its row 0.0) and a key of zeros (of its column)
+    get nothing and give nothing, in the kernels and in the reference."""
+    B, S, J, D = 1, 256, 4, 16
+    q, k, w, g, _ = scores_inputs(B, S, J, D, dtype, seed=11)
+    q, k = q.at[:, 100].set(0), k.at[:, 37].set(0)
+    for dq, dk, dw in (
+            si._pallas_scores_bwd(q, k, w, g, block_q=128, block_k=128,
+                                  interpret=True),
+            si._scores_reference_bwd(q, k, w, g, block=128),
+            jax.grad(lambda *a: jnp.sum(jnp.where(
+                g != 0, si.index_scores(*a, block=128) * g, 0.0)),
+                (0, 1, 2))(q, k, w)):
+        assert not np.asarray(dq[:, 100], np.float32).any()
+        assert not np.asarray(dw[:, 100]).any()
+        assert not np.asarray(dk[:, 37], np.float32).any()
+        assert np.asarray(dq[:, 101], np.float32).any()
+        assert np.asarray(dk[:, 38], np.float32).any()
+
+
+def test_the_scores_backward_masks_a_cotangent_above_the_diagonal():
+    """-inf above the diagonal is a constant: whatever cotangent arrives
+    there reaches nothing."""
+    q, k, w, g, tri = scores_inputs(1, 256, 4, 16, jnp.float32, seed=12)
+    loud = jnp.where(tri, g, 7.0)
+    for backward in (
+            functools.partial(si._pallas_scores_bwd, block_q=128,
+                              block_k=128, interpret=True),
+            functools.partial(si._scores_reference_bwd, block=64)):
+        for a, b in zip(backward(q, k, w, loud), backward(q, k, w, g)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_a_sequence_that_is_no_whole_number_of_blocks_is_refused():
@@ -310,6 +396,9 @@ def test_what_it_counts_as_the_step_is_traced(monkeypatch):
     selected = sum(min(16, t + 1) for t in range(S))
     assert counted == {
         "attention.indexer_heads": J, "attention.keys_selected": 16,
+        # q tiles of 16 rows, the whole sequence a k tile
+        "attention.score_tiles": B * S // 16,
+        "attention.score_tiles_skipped": 0,
         "attention.pairs_causal": B * S * (S + 1) // 2,
         "attention.pairs_selected": B * selected,
         "attention.mask_bytes": B * S * S}
@@ -334,6 +423,36 @@ def test_what_it_counts_as_the_step_is_traced(monkeypatch):
     assert target_tiles(8192, 512, (32, 4), 128) == (272, 240)
     assert target_tiles(2048, 256) == (2 * (1 + 2 + 3 + 4), 2 * (3 + 2 + 1))
     assert target_tiles(S, 16) == (4, 0)     # the whole sequence a k tile
+
+    def score_tiles(B, S, block, J=16, dim=64, dtype=jnp.bfloat16):
+        counted.clear()
+        shape = jax.ShapeDtypeStruct
+        jax.eval_shape(        # under the gradient, as a step traces it:
+            jax.grad(lambda *a: jnp.sum(jnp.tril(     # counted once
+                si.index_scores(*a, block=block))), (0, 1, 2)),
+            shape((B, S, J, dim), dtype), shape((B, S, dim), dtype),
+            shape((B, S, J), jnp.float32))
+        assert counted.pop("attention.indexer_heads") == J
+        assert set(counted) == {"attention.score_tiles",
+                                "attention.score_tiles_skipped"}
+        return (counted["attention.score_tiles"],
+                counted["attention.score_tiles_skipped"])
+
+    # the cell's: `_SCORES_TILE` tiles of the 8,192 square of both sequences
+    bq, bk = si._SCORES_TILE
+    on = sum(min(8192 // bk, ((i + 1) * bq - 1) // bk + 1)
+             for i in range(8192 // bq))
+    assert score_tiles(2, 8192, 512) == (
+        2 * on, 2 * ((8192 // bq) * (8192 // bk) - on))
+    assert si._scores_tiles(jax.ShapeDtypeStruct(
+        (2, 8192, 16, 64), jnp.bfloat16), 512) == (bq, bk)
+    # blocks of 24 rows are no whole number of 16-row tiles; 8,256 keys
+    # are no whole number of 128-lane tiles: the reference, nothing counted
+    assert score_tiles(1, 96, 24) == (0, 0)
+    assert score_tiles(1, 8256, 64) == (0, 0)
+    # every head's q tile and dq in float32 that VMEM does not hold
+    assert si._scores_tiles(jax.ShapeDtypeStruct(
+        (1, 8192, 512, 128), jnp.bfloat16), 512) is None
     # 576 keys are no whole number of 128-lane tiles: the reference
     shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
     assert si._target_tiles(shape(1, 576, H, D), shape(1, 576, HKV, D),
@@ -374,3 +493,37 @@ def test_a_shape_the_target_kernel_declines_lowers_to_the_reference():
     module = exported_loss(8256, 64)
     assert "tpu_custom_call" not in module
     assert "stablehlo.dot_general" in module
+
+
+def exported_scores(S, block):
+    """The module of `index_scores` and its gradients at the keye cell's
+    indexer (16 heads of 64 on one key head, a batch of 2, bfloat16),
+    shapes only, exported for a TPU from this host."""
+    shape = jax.ShapeDtypeStruct
+    args = (shape((2, S, 16, 64), jnp.bfloat16),
+            shape((2, S, 64), jnp.bfloat16), shape((2, S, 16), jnp.float32),
+            shape((2, S, S), jnp.float32))
+    with jax.default_matmul_precision("default"):
+        return jax.export.export(jax.jit(jax.value_and_grad(
+            lambda q, k, w, g: jnp.sum(jnp.where(
+                g != 0, si.index_scores(q, k, w, block=block) * g, 0.0)),
+            (0, 1, 2))), platforms=["tpu"])(*args).mlir_module()
+
+
+def test_the_scores_lower_to_mosaic_for_tpu_at_the_cells_shape():
+    """2 x 8,192 by blocks of 512: the scores and their backward are a
+    Mosaic custom call each, no product is left to XLA and no head's
+    products of a block exist."""
+    module = exported_scores(8192, 512)
+    assert module.count("stablehlo.custom_call @tpu_custom_call") == 2
+    assert "stablehlo.dot_general" not in module
+    assert "16x512x8192" not in module
+
+
+def test_a_shape_the_scores_kernels_decline_lowers_to_the_reference():
+    """8,256 keys (129 blocks of 64) are no whole number of 128-lane
+    tiles: the blocked XLA form on every platform, the TPU included."""
+    module = exported_scores(8256, 64)
+    assert "tpu_custom_call" not in module
+    assert "stablehlo.dot_general" in module
+    assert "16x64x8256" in module

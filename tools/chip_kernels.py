@@ -50,6 +50,16 @@ them; `kernel_ms` read 0.0 here at PR 48: a kernel called inside
 `lax.map`'s loop is not among the events it sums) and the largest error
 relative to the same in float32.  ``--sweep target-8k`` times the kernel at other tiles.
 
+``scores_8k`` is the index scores alone (`ops/sparse_index.py:index_scores`'s
+two kernels, `_pallas_scores` and `_pallas_scores_bwd`) at the keye cell's
+shape (2 x 8,192, 16 heads of 64 on one key head, blocks of 512 queries): the
+Mosaic kernels beside `_scores_reference` and `_scores_reference_bwd` (the
+blocked XLA form), forward and backward device ms of every operation, the
+seconds each took to compile, and the largest error of the scores and of the gradients to q, k and
+w relative to the reference on float32 operands at the highest precision; the
+cotangent is zero off 2,048 selected keys a query, as the loss's is.
+``--sweep scores-8k`` times the kernels at other tiles.
+
 ``ssd_8k`` is the chunked state-space scan alone (`ops/ssd.py:ssd_scan`) at
 (2, 8192, 64 heads of 64) with 8 groups and a state of 128, in bfloat16 and
 in float32: the Pallas kernels as `ssd_scan` calls them, and the `einsum`
@@ -112,6 +122,15 @@ TARGET_CASES = {
 TARGET_SWEEP = {
     "target-8k": ("target_8k", ((128, 512), (256, 256), (256, 512),
                                 (256, 1024), (512, 512))),
+}
+# (B, S, J, D_I, block, top_k) of one layer's index scores
+SCORES_CASES = {
+    "scores_8k": (2, 8192, 16, 64, 512, 2048),
+}
+# the (q tile, k tile) `--sweep scores-8k` times a case of SCORES_CASES at
+SCORES_SWEEP = {
+    "scores-8k": ("scores_8k", ((128, 512), (256, 256), (256, 512),
+                                (256, 1024), (512, 512), (512, 1024))),
 }
 # the tiles of a sequence past `_WHOLE_SEQ_MAX`: square, and the backward's
 # k tile (a grid step) beside another q tile (its loop's step)
@@ -514,6 +533,72 @@ def target_case(name, dtype, tiles=None):
         yield line
 
 
+def scores_case(name, dtype, tiles=None):
+    """One layer's index scores at ``SCORES_CASES[name]``: a line for the
+    Mosaic kernels (``tiles``: at these instead of `_scores_tiles`', and
+    nothing compared) and one for the blocked XLA form they are held to:
+    forward and backward device ms of every operation, the seconds each
+    took to compile, and the largest error of the scores and of each
+    gradient relative to the reference on float32 operands at the highest
+    precision."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import sparse_index as si
+
+    B, S, J, D, block, top_k = SCORES_CASES[name]
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(ks[0], (B, S, J, D), dtype)
+    k = jax.random.normal(ks[1], (B, S, D), dtype)
+    w = jax.random.normal(ks[2], (B, S, J), jnp.float32) * (J * D) ** -0.5
+    bq, bk = tiles or si._scores_tiles(q, block)
+    forms = {"kernel": (
+        functools.partial(si._pallas_scores, block_q=bq, block_k=bk,
+                          interpret=False),
+        functools.partial(si._pallas_scores_bwd, block_q=bq, block_k=bk,
+                          interpret=False))}
+    if not tiles:
+        forms["reference"] = (
+            functools.partial(si._scores_reference, block=block),
+            functools.partial(si._scores_reference_bwd, block=block))
+    forward, backward = map(jax.jit, forms.get("reference", forms["kernel"]))
+    scores = forward(q, k, w)
+    g = jnp.where(
+        jax.jit(lambda s: si.select_top_k(s, top_k, block))(scores) != 0,
+        jax.random.normal(ks[3], (B, S, S)), 0.0)
+    exact = None
+    if not tiles:
+        with jax.default_matmul_precision("highest"):
+            f32 = q.astype(jnp.float32), k.astype(jnp.float32), w
+            exact = forward(*f32), *backward(*f32, g)
+    del scores
+    rel = lambda got, want: round(float(jnp.max(jnp.abs(jnp.where(
+        jnp.isfinite(want), got.astype(jnp.float32) - want, 0.0)))
+        / jnp.max(jnp.where(jnp.isfinite(want), jnp.abs(want), 0.0))), 5)
+    for form, fns in forms.items():
+        line = {"case": name, "form": form, "tile": [bq, bk]}
+        got = []
+        for what, fn, args in zip(("fwd", "bwd"), map(jax.jit, fns),
+                                  ((q, k, w), (q, k, w, g))):
+            began = time.perf_counter()
+            compiled = fn.lower(*args).compile()
+            line[f"{what}_compile_s"] = round(time.perf_counter() - began, 2)
+            line[f"{what}_mosaic_kernels"] = compiled.as_text().count(
+                'custom_call_target="tpu_custom_call"')
+            line[f"{what}_ms"] = busy_ms(fn, *args)
+            if exact is not None:
+                out = fn(*args)
+                got += [out] if what == "fwd" else list(out)
+        if exact is not None:
+            line["rel_err"] = {what: rel(a, b) for what, a, b in zip(
+                ("scores", "dq", "dk", "dw"), got, exact)}
+            line["above_diagonal_is_neg_inf"] = bool(jnp.all(
+                jnp.isneginf(got[0]) == jnp.isneginf(exact[0])))
+        yield line
+
+
 def ssd_case(name, dtype, chunk=None, compare=True):
     """One state-space scan at ``SSD_CASES[name]`` (``chunk`` given: at
     that chunk): a line for each form of it (`ops/ssd.py`) -- the `einsum`
@@ -671,18 +756,19 @@ def main():
                              f"({', '.join(SWEEP)}; none named: at all)")
     parser.add_argument("--cases", nargs="+", metavar="CASE",
                         default=[*CASES, *MOE_CASES, *SHORTCONV_CASES,
-                                 *SSD_CASES, *TARGET_CASES],
+                                 *SSD_CASES, *TARGET_CASES, *SCORES_CASES],
                         help=f"run these only ({', '.join(CASES)}, "
                              f"{', '.join(MOE_CASES)}, "
                              f"{', '.join(SHORTCONV_CASES)}, "
                              f"{', '.join(SSD_CASES)}, "
-                             f"{', '.join(TARGET_CASES)}; default: all)")
+                             f"{', '.join(TARGET_CASES)}, "
+                             f"{', '.join(SCORES_CASES)}; default: all)")
     args = parser.parse_args()
-    if args.sweep and set(args.sweep) - set(SWEEP) - set(SSD_SWEEP) \
-            - set(TARGET_SWEEP):
-        parser.error(f"--sweep: no such shape in "
-                     f"{sorted([*SWEEP, *SSD_SWEEP, *TARGET_SWEEP])}")
-    known = [*CASES, *MOE_CASES, *SHORTCONV_CASES, *SSD_CASES, *TARGET_CASES]
+    swept = [*SWEEP, *SSD_SWEEP, *TARGET_SWEEP, *SCORES_SWEEP]
+    if args.sweep and set(args.sweep) - set(swept):
+        parser.error(f"--sweep: no such shape in {sorted(swept)}")
+    known = [*CASES, *MOE_CASES, *SHORTCONV_CASES, *SSD_CASES, *TARGET_CASES,
+             *SCORES_CASES]
     if set(args.cases) - set(known):
         parser.error(f"--cases: no such case in {known}")
 
@@ -704,14 +790,16 @@ def main():
         sys.exit(f"no TPU: jax found {dev.platform!r}")
 
     if args.sweep is not None:
-        for name in args.sweep or [*SWEEP, *SSD_SWEEP, *TARGET_SWEEP]:
-            if name in TARGET_SWEEP:
-                case, tiles = TARGET_SWEEP[name]
+        for name in args.sweep or swept:
+            for table, one in ((TARGET_SWEEP, target_case),
+                               (SCORES_SWEEP, scores_case)):
+                case, tiles = table.get(name, (None, ()))
                 for tile in tiles:
-                    for line in target_case(case, jnp.bfloat16, tile):
+                    for line in one(case, jnp.bfloat16, tile):
                         print(json.dumps({
                             "sweep": name, **line,
                             "device_kind": dev.device_kind}), flush=True)
+            if name in TARGET_SWEEP or name in SCORES_SWEEP:
                 continue
             if name in SSD_SWEEP:
                 case, chunks = SSD_SWEEP[name]
@@ -784,6 +872,17 @@ def main():
         for line in target_case(name, jnp.bfloat16) \
                 if name in args.cases else ():
             ok = line["rel_err"]["target"] < TOLERANCE
+            if not ok:
+                failed.append(f"{name}:{line['form']}")
+            print(json.dumps({**line, "ok": ok,
+                              "device_kind": dev.device_kind}), flush=True)
+    for name in SCORES_CASES:
+        for line in scores_case(name, jnp.bfloat16) \
+                if name in args.cases else ():
+            ok = max(line["rel_err"].values()) < TOLERANCE \
+                and line["above_diagonal_is_neg_inf"] \
+                and line["fwd_mosaic_kernels"] + line["bwd_mosaic_kernels"] \
+                == (2 if line["form"] == "kernel" else 0)
             if not ok:
                 failed.append(f"{name}:{line['form']}")
             print(json.dumps({**line, "ok": ok,
