@@ -1,7 +1,9 @@
 """What the Train-path models share: the norms, rotary positions, the
 feed-forwards (gated and not), the routed layer over them, the gated short
 convolution, the causal convolution and gated norm of a state-space mixer,
-the walk over a decoder's layers, the head and its chunked loss and the
+the walk over a decoder's layers (once, or, for a looped model, several
+times over the same parameters with the final norm after every walk), the
+head and its chunked loss (summed, or handed back row by row) and the
 mixed-precision step.  A model file imports these,
 `ray_tpu.parallel.attention` and `ray_tpu.ops`; it imports no other model
 file: it is its configuration, `init_params`, its mixers and a `_layer` that
@@ -23,7 +25,10 @@ around the part; what the models share names itself (`layer_norm` and
 `rms_norm`: `norm`; `short_conv`'s three parts; `trunk`'s `embed`;
 `routed_layer`'s `route` and `shared` and `ops/moe.py`'s `dispatch`,
 `experts`, `combine` and `routing_bias_update`, which rely on the caller
-standing in `ffn/moe`; `head_and_loss`; the flash kernels' forms;
+standing in `ffn/moe`; `head_and_loss`, also around `head_and_row_losses`;
+the flash kernels' forms; a looped model's `exit_gate` (the gates, the exit
+distribution, its entropy and the weighting of the rows' losses: the model
+writes it);
 `train_step`'s `optimizer_update`).  `norm` is a layer's norm on the residual stream: one
 inside an operator (a norm over a head, the latent's) stands in that
 operator's scope and counts there.
@@ -80,6 +85,7 @@ SCOPES = (
     "ffn/moe/combine",
     "ffn/moe/shared",
     "head_and_loss",
+    "exit_gate",
     "optimizer_update",
     "routing_bias_update",
 )
@@ -597,7 +603,7 @@ def checkpoint_layer(fn, stack=None, behind=(), **kw):
     return jax.checkpoint(fn, policy=_keep(names), **kw)
 
 
-def trunk(params, tokens, layer, cfg):
+def trunk(params, tokens, layer, cfg, walks=None):
     """A decoder's walk, tokens (B, S) int32 -> ((B, S, E) after the final
     norm, the layers' second results in order, a None left out): the
     embedding ``params["embed_tokens"]`` under `embed`, in the compute
@@ -606,23 +612,74 @@ def trunk(params, tokens, layer, cfg):
     when `cfg.remat`, keeping what fits the chip with a chunk of the head's
     logits (`cfg.loss_chunk_rows` of `cfg.vocab_size`) behind the stack
     (`checkpoint_layer`); RMSNorm by ``params["norm_f"]`` at
-    `cfg.rms_eps`.  `models/gpt2.py` walks by itself: positional
-    embeddings, pipeline stages, a mesh's pins."""
+    `cfg.rms_eps`.
+
+    ``walks`` = T, a looped model's: the same layers are walked T times
+    over the same parameters, the final norm after EVERY walk, and what it
+    gives is both what the next walk starts from and what the caller reads
+    -> ((T, B, S, E), every walk's normed state; the second results of all
+    T x n calls in order).  One `checkpoint_layer` is over the T x n calls,
+    so its budget reckons what T visits of a layer keep.  The walks are a
+    Python loop, T x n layer bodies in the program: a `lax.scan` over the
+    walks makes n, compiles 20 s sooner and ran 4 % slower on the chip at
+    the Ouro cell's sizes (PERF.md section 6, PR 50).  Counted on the job
+    timeline as the step is traced: `loop.walks` (T), `loop.layer_calls`
+    (T x n) and `loop.layer_traces` (the layer bodies the program holds:
+    T x n as long as the walks are unrolled).  None: one walk, as every
+    model but a looped one asks for, nothing counted.
+
+    `models/gpt2.py` walks by itself: positional embeddings, pipeline
+    stages, a mesh's pins."""
     with jax.named_scope("embed"):
         x = params["embed_tokens"]["embedding"][tokens].astype(
             cfg.compute_dtype)
     layers = [params[f"layer_{i}"] for i in range(cfg.n_layer)]
     if cfg.remat:
         layer = checkpoint_layer(
-            layer, stack=[(x, p, cfg) for p in layers], static_argnums=(2,),
+            layer, stack=[(x, p, cfg) for p in layers] * (walks or 1),
+            static_argnums=(2,),
             behind=jax.ShapeDtypeStruct(
                 (cfg.loss_chunk_rows, cfg.vocab_size), jnp.float32))
-    seconds = []
-    for p in layers:
-        x, second = layer(x, p, cfg)
-        if second is not None:
-            seconds.append(second)
-    return rms_norm(x, params["norm_f"], cfg.rms_eps), seconds
+
+    def walk(x):
+        seconds = []
+        for p in layers:
+            if walks is not None:
+                tracing.count("loop.layer_traces")
+            x, second = layer(x, p, cfg)
+            if second is not None:
+                seconds.append(second)
+        return rms_norm(x, params["norm_f"], cfg.rms_eps), seconds
+
+    if walks is None:
+        return walk(x)
+    tracing.count("loop.walks", walks)
+    tracing.count("loop.layer_calls", walks * len(layers))
+    states, seconds = [], []
+    for _ in range(walks):
+        x, more = walk(x)
+        states.append(x)
+        seconds += more
+    return jnp.stack(states), seconds
+
+
+def _chunks(x, targets, n_chunks: int):
+    """x (N, E), targets (N,) -> ((n, N / n, E), (n, N / n)): ``n_chunks``
+    chunks, or the next fewer that divide N."""
+    N, E = x.shape
+    n_chunks = max(1, min(n_chunks, N))
+    while N % n_chunks:
+        n_chunks -= 1
+    return (x.reshape(n_chunks, N // n_chunks, E),
+            targets.reshape(n_chunks, N // n_chunks))
+
+
+def _chunk_losses(wte, xi, ti):
+    """One chunk's logits in float32 -> its rows' cross-entropies."""
+    logits = jnp.matmul(xi, wte.T, preferred_element_type=jnp.float32)
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    tgt = jnp.take_along_axis(logits, ti[:, None], axis=-1)[:, 0]
+    return lse - tgt
 
 
 def chunked_xent(x, wte, targets, n_chunks: int):
@@ -640,24 +697,31 @@ def chunked_xent(x, wte, targets, n_chunks: int):
     x: (N, E) compute-dtype; wte: (V, E); targets: (N,) int32.
     Returns summed loss (f32).
     """
-    N, E = x.shape
-    n_chunks = max(1, min(n_chunks, N))
-    while N % n_chunks:
-        n_chunks -= 1
-    xc = x.reshape(n_chunks, N // n_chunks, E)
-    tc = targets.reshape(n_chunks, N // n_chunks)
+    chunks = _chunks(x, targets, n_chunks)
 
     @jax.checkpoint
     def chunk(carry, xt):
-        xi, ti = xt
-        logits = jnp.matmul(xi, wte.T,
-                            preferred_element_type=jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, ti[:, None], axis=-1)[:, 0]
-        return carry + jnp.sum(lse - tgt), None
+        return carry + jnp.sum(_chunk_losses(wte, *xt)), None
 
-    total, _ = jax.lax.scan(chunk, jnp.zeros((), jnp.float32), (xc, tc))
+    total, _ = jax.lax.scan(chunk, jnp.zeros((), jnp.float32), chunks)
     return total
+
+
+def chunked_xent_rows(x, wte, targets, n_chunks: int):
+    """`chunked_xent` handing back the ROWS' losses, (N,) float32, for an
+    objective that weights each row by something of its own (a looped
+    model's exit distribution): the same chunks, each one's logits made
+    again by the backward pass, which contracts the rows' cotangents into
+    (dx, dwte) chunk by chunk."""
+    chunk = jax.checkpoint(lambda xt: _chunk_losses(wte, *xt))
+    return jax.lax.map(chunk, _chunks(x, targets, n_chunks)).reshape(-1)
+
+
+def _head_rows(head, dtype):
+    """(V, E) in ``dtype``: an untied head's {"kernel": (E, V)}, or the
+    embedding a tied one is, {"embedding": (V, E)}."""
+    return head["embedding"].astype(dtype) if "embedding" in head \
+        else head["kernel"].astype(dtype).T
 
 
 def head_and_loss(x, head, targets, chunk_rows):
@@ -668,11 +732,24 @@ def head_and_loss(x, head, targets, chunk_rows):
     time and never all held (`chunked_xent`)."""
     B, S, E = x.shape
     with jax.named_scope("head_and_loss"):
-        rows = head["embedding"].astype(x.dtype) if "embedding" in head \
-            else head["kernel"].astype(x.dtype).T
+        rows = _head_rows(head, x.dtype)
         total = chunked_xent(x.reshape(B * S, E), rows,
                              targets.reshape(B * S), -(-B * S // chunk_rows))
         return total / (B * S)
+
+
+def head_and_row_losses(x, head, targets, chunk_rows):
+    """x (..., E), targets (...) int32 -> every row's next-token
+    cross-entropy, (...) float32, under `head_and_loss`: `head_and_loss`
+    for a caller that weights the rows itself (`chunked_xent_rows`); a
+    looped model hands all its walks' states in at once, the targets
+    repeated, and the head is read by one loop over their chunks."""
+    E = x.shape[-1]
+    with jax.named_scope("head_and_loss"):
+        losses = chunked_xent_rows(
+            x.reshape(-1, E), _head_rows(head, x.dtype), targets.reshape(-1),
+            -(-targets.size // chunk_rows))
+        return losses.reshape(targets.shape)
 
 
 def cast_weights(params, dtype):

@@ -205,6 +205,10 @@ def infer_param_logical_dims(path: Tuple[str, ...], shape: Tuple[int, ...]):
         return ()
     if "router" in name:
         return ("embed", None)[:nd]
+    if "exit_gate" in name:
+        # a looped model's gate, (E, 1) and its one bias: nothing to cut
+        # but the stream's width
+        return ("embed", None) if nd == 2 else (None,)
     if "short_conv/conv" in name or "mamba/conv" in name:
         # a depthwise filter a channel, (E, taps), and a state-space
         # mixer's with its bias: the taps are never cut

@@ -31,6 +31,7 @@ from ray_tpu.models import (
     lfm2_moe,
     nemotron_h,
     olmoe,
+    ouro,
 )
 from ray_tpu.ops import flash_attention as fa
 from ray_tpu.ops.moe import trained_by
@@ -43,6 +44,7 @@ MODELS = {
     "lfm2_moe": (lfm2_moe, lfm2_moe.LFM2_MOE_TINY),
     "nemotron_h": (nemotron_h, nemotron_h.NEMOTRON_H_TINY),
     "keye_vl": (keye_vl, keye_vl.KEYE_VL_TINY),
+    "ouro": (ouro, ouro.OURO_TINY),
 }
 CASES = [(name, remat) for name in MODELS for remat in (False, True)]
 # what every model's step must have a matmul under
@@ -68,6 +70,8 @@ EXPECTED = {
                 "attention/kernel/fwd_rows", "attention/kernel/bwd_fused",
                 "attention/out", "ffn/moe/route", "ffn/moe/experts",
                 "head_and_loss"},
+    "ouro": {"attention/qkv", "attention/out", "ffn/dense", "head_and_loss",
+             "exit_gate"},
 }
 # components of an `op_name` that jax puts there itself (`jnp.einsum` its
 # subscripts: `ops/ssd.py`'s products)
@@ -190,6 +194,27 @@ def test_every_matmul_and_kernel_is_under_a_scope(name, remat):
     if name == "keye_vl":
         # the threshold search has no matmul: compares and counts
         assert "attention/indexer/select" in every
+    if name != "ouro":
+        assert "exit_gate" not in every
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_the_exit_gate_is_a_scope_of_its_own(remat):
+    """A looped model's gate, exit distribution, entropy and the weighting
+    of the rows' losses stand under `exit_gate`, forward and backward, and
+    not under the head's scope; the scopes of a layer keep their names in
+    every walk (whatever a loop over the walks puts in front of them)."""
+    found = full_names(lowered_text("ouro", remat),
+                       re.compile(r"stablehlo\.\w+"))
+    gate = [(op, full) for op, full in found if scope(full) == "exit_gate"]
+    assert {scope_trace.phase_of(full) for _, full in gate} >= {"fwd", "bwd"}
+    # the gate's product, its sigmoid in logarithms, the entropy's exp
+    assert {"stablehlo.dot_general", "stablehlo.exponential"} \
+        <= {op for op, _ in gate}
+    assert not [full for _, full in gate if "head_and_loss" in full]
+    walked = {scope(full) for _, full in found}
+    assert {"attention/qkv", "attention/kernel/fwd_rows", "ffn/dense",
+            "norm", "head_and_loss"} <= walked
 
 
 @pytest.mark.parametrize("name,remat", CASES)

@@ -4,7 +4,9 @@ against a loop over tokens and their choices, the walk over a decoder's
 layers (`layers.trunk`) against the loop it replaces, the routers' account
 (`ops/moe.py:routing_account`) on rows made by hand, and the head with its
 chunked loss (`layers.head_and_loss`) against dense logits.  Float32, small
-sizes, the CPU.
+sizes, the CPU.  Since PR 50 the walk can be repeated and the chunked loss
+can hand back its rows: the five models that walk once are held, bit for
+bit, to the walk and the loss as they were before.
 """
 
 import dataclasses
@@ -16,7 +18,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import layers
+from ray_tpu.models import (
+    deepseek_v3,
+    keye_vl,
+    layers,
+    lfm2_moe,
+    nemotron_h,
+    olmoe,
+)
 from ray_tpu.ops import moe
 from ray_tpu.ops.moe import ROUTING_BIAS
 
@@ -360,3 +369,99 @@ def test_the_head_and_loss_is_the_dense_cross_entropy(head, chunk_rows):
         assert max_diff(g, want) < 1e-6
     lowered = jax.jit(got).lower(x, rows).as_text(debug_info=True)
     assert "head_and_loss" in lowered
+
+
+# -- what the walk and the chunked loss became (PR 50) ------------------------
+
+def parent_trunk(params, tokens, layer, cfg):
+    """`layers.trunk` at PR 49, before a walk could be repeated."""
+    with jax.named_scope("embed"):
+        x = params["embed_tokens"]["embedding"][tokens].astype(
+            cfg.compute_dtype)
+    stack = [params[f"layer_{i}"] for i in range(cfg.n_layer)]
+    if cfg.remat:
+        layer = layers.checkpoint_layer(
+            layer, stack=[(x, p, cfg) for p in stack], static_argnums=(2,),
+            behind=jax.ShapeDtypeStruct(
+                (cfg.loss_chunk_rows, cfg.vocab_size), jnp.float32))
+    seconds = []
+    for p in stack:
+        x, second = layer(x, p, cfg)
+        if second is not None:
+            seconds.append(second)
+    return layers.rms_norm(x, params["norm_f"], cfg.rms_eps), seconds
+
+
+def parent_chunked_xent(x, wte, targets, n_chunks: int):
+    """`layers.chunked_xent` at PR 49, before its chunk was shared with the
+    rows' form."""
+    N, E = x.shape
+    n_chunks = max(1, min(n_chunks, N))
+    while N % n_chunks:
+        n_chunks -= 1
+    xc = x.reshape(n_chunks, N // n_chunks, E)
+    tc = targets.reshape(n_chunks, N // n_chunks)
+
+    @jax.checkpoint
+    def chunk(carry, xt):
+        xi, ti = xt
+        logits = jnp.matmul(xi, wte.T,
+                            preferred_element_type=jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        tgt = jnp.take_along_axis(logits, ti[:, None], axis=-1)[:, 0]
+        return carry + jnp.sum(lse - tgt), None
+
+    total, _ = jax.lax.scan(chunk, jnp.zeros((), jnp.float32), (xc, tc))
+    return total
+
+
+def parent_head_and_loss(x, head, targets, chunk_rows):
+    """`layers.head_and_loss` at PR 49."""
+    B, S, E = x.shape
+    with jax.named_scope("head_and_loss"):
+        rows = head["embedding"].astype(x.dtype) if "embedding" in head \
+            else head["kernel"].astype(x.dtype).T
+        total = parent_chunked_xent(
+            x.reshape(B * S, E), rows, targets.reshape(B * S),
+            -(-B * S // chunk_rows))
+        return total / (B * S)
+
+
+TRUNK_FAMILIES = {
+    "olmoe": (olmoe, olmoe.OLMOE_TINY),
+    "deepseek_v3": (deepseek_v3, deepseek_v3.DEEPSEEK_V3_TINY),
+    "lfm2_moe": (lfm2_moe, lfm2_moe.LFM2_MOE_TINY),
+    "nemotron_h": (nemotron_h, nemotron_h.NEMOTRON_H_TINY),
+    "keye_vl": (keye_vl, keye_vl.KEYE_VL_TINY),
+}
+
+
+@pytest.mark.parametrize("remat", [False, True])
+@pytest.mark.parametrize("family", sorted(TRUNK_FAMILIES))
+def test_a_model_that_walks_once_is_what_it_was(family, remat, monkeypatch):
+    """The loss and every gradient of the five `trunk` families at their
+    test sizes, in their compute type, through `layers.trunk` and
+    `layers.head_and_loss` as they are and as the parent had them: bit for
+    bit."""
+    module, cfg = TRUNK_FAMILIES[family]
+    cfg = dataclasses.replace(cfg, remat=remat)
+    params = module.init_params(jax.random.PRNGKey(3), cfg)
+    batch = {"tokens": jax.random.randint(
+        jax.random.PRNGKey(4), (B, 65), 0, cfg.vocab_size)}
+
+    def run():
+        return jax.jit(jax.value_and_grad(
+            lambda p: module.loss_fn(layers.cast_weights(
+                p, cfg.compute_dtype), batch, cfg)[0]))(params)
+
+    loss, grads = run()
+    monkeypatch.setattr(module, "trunk", parent_trunk)
+    monkeypatch.setattr(module, "head_and_loss", parent_head_and_loss)
+    want_loss, want_grads = run()
+    assert float(loss) == float(want_loss)
+    for (path, want), got in zip(
+            jax.tree_util.tree_flatten_with_path(want_grads)[0],
+            jax.tree.leaves(grads)):
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(want),
+            err_msg=jax.tree_util.keystr(path))
