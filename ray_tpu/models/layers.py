@@ -62,7 +62,6 @@ SCOPES = (
     "attention/indexer/scores",
     "attention/indexer/select",
     "attention/indexer/loss",
-    "attention/indexer/loss/target",
     "attention/kernel",
     *(f"attention/kernel/{form}" for form in KERNEL_FORMS),
     "attention/out",

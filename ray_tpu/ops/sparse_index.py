@@ -11,27 +11,30 @@ names).  Shared by `models/keye_vl.py` and whatever selects next.
               L1-normalised, a constant
 
 The mask is `ops/flash_attention.py`'s operand: (B, S, S) int8, 1 where t
-attends s.  The selection and the loss go by blocks of ``block`` query rows
+attends s.  The selection goes by blocks of ``block`` query rows
 (`sa_config`'s `q_chunk_size`), one sequence's block at a time
-(`_by_blocks`); no step holds a head's scores of more than a block, never
-an S x S array a head; what it holds summed over the heads is the (B, S, S)
-float32 of I and, under the gradient, of dL_I / dI.  The selection and the
-loss's normalisation, KL and gradient are plain XLA.  Three Mosaic kernels
-keep the heads' products in VMEM.  The SCORES are one kernel a layer
-(`_pallas_scores`: every indexer head's q . k', its relu, its weight and
-the sum over the heads a (q tile, k tile) at a time, the tiles above the
-diagonal written as -inf and not computed) and their backward another
-(`_pallas_scores_bwd`, under `index_scores`' own `jax.custom_vjp`: the
-products made again once a tile and contracted into dq, dk and dw, the one
-key head's dk summed in VMEM over a sequence's tiles).  The loss's TARGET,
-the main attention's probabilities of a block summed over its heads, is
-one kernel a block (`_pallas_target`): every head's QK', its exponent and
-the sum over the heads, the key tiles above the block's diagonal not
-visited.  All three run as the flash kernels do (`ops.by_platform`):
-compiled where the step is lowered for a TPU, interpreted elsewhere up to
-the tests' sizes, and the same arithmetic in plain XLA by blocks
-(`_scores_reference`, `_scores_reference_bwd`, `_target_reference`) beyond
-them and for a shape a kernel cannot tile (`_scores_tiles`,
+(`_by_blocks`), in plain XLA; no step holds a head's scores of more than a
+tile, never an S x S array a head; what it holds summed over the heads is
+the (B, S, S) float32 of I and, under the gradient, of dL_I / dI.  Three
+Mosaic kernels keep the heads' products in VMEM.  The SCORES are one kernel
+a layer (`_pallas_scores`: every indexer head's q . k', its relu, its
+weight and the sum over the heads a (q tile, k tile) at a time, the tiles
+above the diagonal written as -inf and not computed) and their backward
+another (`_pallas_scores_bwd`, under `index_scores`' own `jax.custom_vjp`:
+the products made again once a tile and contracted into dq, dk and dw, the
+one key head's dk summed in VMEM over a sequence's tiles).  The LOSS is one
+kernel a layer, whole (`_pallas_loss`): its target, the main attention's
+probabilities summed over its heads (every head's QK', its exponent and
+the sum), stays in VMEM a q tile's whole row at a time beside the row's
+selected scores, the key tiles above the diagonal not visited; the row's
+sums run beside it, and on the row's last tile the kernel writes each
+query's KL and the gradient to the scores where it lies in (B, S, S):
+nothing else of the loss goes through HBM, and no XLA pass follows.  All
+three run as the flash kernels do (`ops.by_platform`): compiled where the
+step is lowered for a TPU, interpreted elsewhere up to the tests' sizes,
+and the same arithmetic in plain XLA by blocks (`_scores_reference`,
+`_scores_reference_bwd`, `_loss_reference` over `_target_reference`)
+beyond them and for a shape a kernel cannot tile (`_scores_tiles`,
 `_target_tiles`).
 
 The selection is a threshold search and no `jax.lax.top_k`: a top-k gives
@@ -53,8 +56,10 @@ Counts itself on the job timeline as the step is traced:
 `attention.indexer_heads`, `attention.score_tiles`,
 `attention.score_tiles_skipped` (`index_scores`), `attention.keys_selected`,
 `attention.pairs_causal`, `attention.pairs_selected`, `attention.mask_bytes`
-(`select_top_k`), `attention.target_tiles`, `attention.target_tiles_skipped`
-(`indexer_loss`; a recomputed layer is traced once).
+(`select_top_k`), `attention.target_tiles`, `attention.target_tiles_skipped`,
+`attention.loss_rows_fused` (`indexer_loss`: the loss kernel's tiles, and
+the query rows whose loss and gradient it made, 0 where the shape took
+the plain form; a recomputed layer is traced once).
 """
 
 from __future__ import annotations
@@ -69,12 +74,15 @@ from jax.experimental.pallas import tpu as pltpu
 from ray_tpu.ops import by_platform
 from ray_tpu.util import tracing
 
-# the target kernel's (q tile, k tile) at most (`tools/chip_kernels.py
-# --sweep target-8k` on a v5e, ms for every block of the keye cell's two
-# sequences: 128 x 512 6.81, 256 x 256 6.67, 256 x 512 6.63, 256 x 1,024
-# 6.76, 512 x 512 6.56, whose unrolled body takes twice as long to compile:
-# PERF.md §6, PR 48), and the scoped VMEM it may ask for: a step holds
-# every head's q tile and a k tile of every key head
+# the loss kernel's (q tile, k tile) at most (`tools/chip_kernels.py --sweep
+# target-8k` on a v5e, ms a layer of the keye cell's two sequences, loss and
+# gradient, and the seconds it took to compile: 128 x 512 4.86 / 1.9,
+# 128 x 1,024 4.95 / 3.1, 256 x 256 4.67 / 2.4, 256 x 512 4.63 / 3.4,
+# 256 x 1,024 4.72 / 5.7; 512 x 512 asks for 77 MiB; the target alone took
+# 3.58 and the passes after it 6.2 to 11.4: PERF.md §6, PR 51), and the scoped
+# VMEM it may ask for: a step holds every head's q tile, a k tile of every key
+# head and three times the q tile's whole row in float32 (34 MiB at the cell's
+# 8,192 keys; the v5e has 128)
 _TARGET_TILE = (256, 512)
 _TARGET_VMEM_MAX = 64 << 20
 # the scores' kernels' (q tile, k tile) at most (`tools/chip_kernels.py
@@ -497,8 +505,8 @@ def select_top_k(scores, top_k, block=512):
 
 
 def _target_reference(q, k, lse, mask, start, *, scale):
-    """The target of one block of queries in plain XLA, and what
-    `_pallas_target` is held to: q (H, S, D) and k (H_kv, S, D) one
+    """The target of one block of queries in plain XLA, as `_loss_kernel`
+    makes it a tile at a time: q (H, S, D) and k (H_kv, S, D) one
     sequence's, head-major; lse (rows, H) and mask (rows, S) the block's,
     whose first query is ``start`` -> (rows, S) float32, the heads'
     probabilities over the selected keys summed: the products in float32,
@@ -514,130 +522,232 @@ def _target_reference(q, k, lse, mask, start, *, scale):
                    axis=(0, 1))
 
 
-def _target_kernel(start_ref, q_ref, k_ref, lse_ref, mask_ref, o_ref, *,
-                   scale, block_q, block_k):
-    """One (q tile, k tile) of a block's target, the heads inside: q_ref
-    (H, block_q, D), k_ref (H_kv, block_k, D), lse_ref (block_q, H),
-    mask_ref and o_ref (block_q, block_k).  A tile wholly above the
-    diagonal is written as zeros and nothing of it is computed (its k and
-    mask blocks are the last visited tile's, not fetched again:
-    `_pallas_target`'s index maps).  The mask is applied once, to the sum:
-    a pair that is not selected is 0 whatever its exponents were."""
-    heads = q_ref.shape[0]
-    group = heads // k_ref.shape[0]
-    i, j = pl.program_id(0), pl.program_id(1)
-    last_query = start_ref[0] + (i + 1) * block_q - 1
+def _loss_kernel(q_ref, k_ref, lse_ref, mask_ref, scores_ref, kl_ref,
+                 grad_ref, z, w, m, l, t_row, i_row, g_row, sem, *, scale,
+                 inv_rows, block_q, block_k):
+    """One (q tile, k tile) of a sequence's loss, the heads inside, and on a
+    q tile's last k tile its rows' loss and gradient.  q_ref (block_q, H D)
+    and k_ref (block_k, H_kv D) the heads side by side in the lanes as they
+    lie, lse_ref (block_q, H), mask_ref and scores_ref (block_q, block_k);
+    kl_ref (block_q, 1); grad_ref the whole
+    (B, S, S) float32 where XLA put it, written by this kernel's own
+    copies.  The k tiles are the inner grid axis, in order; those above a q
+    tile's diagonal are not visited (their k, mask and scores blocks are the
+    last visited tile's, not fetched again: `_pallas_loss`' index maps).
 
-    @pl.when(j * block_k <= last_query)
+    A visited tile: the target t, every head's exponent of its QK' summed
+    (the argument rounded to q's type as the main attention's kernels make
+    it) and masked once; kept in ``t_row`` beside the selected scores
+    (``i_row``, -inf elsewhere): the q tile's WHOLE row, (k tiles, block_q,
+    block_k) float32 in VMEM.  Three sums a row run beside it in float32:
+    z = sum t, w = sum t (log t - I) (0 where t is: xlogy's rule) and the
+    selected scores' maximum and sum of exponents ``m``, ``l``; each a lane
+    at a time, (block_q, 128): a tile's lane tiles are added or compared
+    elementwise, and the 128 lanes are brought together once a row, on its
+    last tile (0.3 ms a layer of the keye cell's less than a reduction
+    across the lanes every tile).  With p = t / z and log_q = I - lse(I)
+    the row's KL(p || softmax I) is w / z - log z + lse(I).
+
+    The last visited tile: the gradient (exp(log_q) - p) ``inv_rows`` over
+    the row held, 0 off the selected pairs, a k tile at a time into
+    ``g_row`` and from there to its place in HBM by a copy a tile, which
+    the next q tile's products hide: they are waited for before ``g_row``
+    is written again, and at the grid's last step.  The tiles above the
+    diagonal are zeroed once a sequence: a later q tile's diagonal lies
+    further right."""
+    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    last = ((i + 1) * block_q - 1) // block_k
+    heads, n_k = lse_ref.shape[1], t_row.shape[0]
+    D = q_ref.shape[1] // heads
+    group = heads * D // k_ref.shape[1]
+    width = z.shape[1]
+    lane_tiles = range(0, block_k, width)
+
+    def by_lane(combine, x):    # (block_q, block_k) -> (block_q, width)
+        return functools.reduce(combine, (x[:, c:c + width]
+                                          for c in lane_tiles))
+
+    def copy(tile):
+        return pltpu.make_async_copy(
+            g_row.at[tile],
+            grad_ref.at[b, pl.ds(i * block_q, block_q),
+                        pl.ds(tile * block_k, block_k)], sem)
+
+    def land():
+        for tile in range(n_k):     # same-sized, so any of them counts one
+            copy(tile).wait()
+
+    @pl.when(j == 0)
+    def _():
+        for ref in (z, w, l):
+            ref[...] = jnp.zeros_like(ref)
+        m[...] = jnp.full_like(m, -jnp.inf)
+
+    @pl.when(j <= last)
     def _():
         total = None
         for h in range(heads):      # unrolled: a head's products pass
-            q = q_ref[h]            # while the last one's exponents do
+            q = q_ref[:, h * D:(h + 1) * D]     # while the last one's
+            g = h // group                      # exponents do
             s = jax.lax.dot_general(
-                q, k_ref[h // group], (((1,), (1,)), ((), ())),
+                q, k_ref[:, g * D:(g + 1) * D], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             s = (s * scale - lse_ref[:, h:h + 1]).astype(q.dtype)
             p = jnp.exp(s.astype(jnp.float32))
             total = p if total is None else total + p
-        o_ref[...] = jnp.where(mask_ref[...] != 0, total, 0.0)
+        chosen = mask_ref[...] != 0
+        scores = scores_ref[...]
+        t = jnp.where(chosen, total, 0.0)
+        logits = jnp.where(chosen, scores, -jnp.inf)
+        t_row[j] = t
+        i_row[j] = logits
+        z[...] += by_lane(jnp.add, t)
+        w[...] += by_lane(jnp.add, jnp.where(
+            t == 0, 0.0, t * (jnp.log(t) - scores)))
+        top = jnp.maximum(m[...], by_lane(jnp.maximum, logits))
+        base = jnp.where(top == -jnp.inf, 0.0, top)     # nothing selected yet
+        l[...] = l[...] * jnp.exp(m[...] - base) + sum(
+            jnp.exp(logits[:, c:c + width] - base) for c in lane_tiles)
+        m[...] = top
 
-    @pl.when(j * block_k > last_query)
+    @pl.when(j == last)
     def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        across = functools.partial(jnp.sum, axis=1, keepdims=True)
+        total = across(z[...])
+        top = jnp.max(m[...], axis=1, keepdims=True)    # a row selects a key
+        lse = top + jnp.log(across(l[...] * jnp.exp(m[...] - top)))
+        kl_ref[...] = across(w[...]) / total - jnp.log(total) + lse
+        share = 1.0 / total
+
+        @pl.when((b > 0) | (i > 0))
+        def _():
+            land()
+
+        def one(tile, carry):
+            @pl.when(tile <= last)
+            def _():
+                logits = i_row[tile]
+                g_row[tile] = jnp.where(
+                    logits > -jnp.inf,
+                    (jnp.exp(logits - lse) - t_row[tile] * share) * inv_rows,
+                    0.0)
+
+            @pl.when((tile > last) & (i == 0))
+            def _():
+                g_row[tile] = jnp.zeros(g_row.shape[1:], jnp.float32)
+
+            copy(tile).start()
+            return carry
+
+        jax.lax.fori_loop(0, n_k, one, 0)
+
+        @pl.when((b == pl.num_programs(0) - 1)
+                 & (i == pl.num_programs(1) - 1))
+        def _():
+            land()
 
 
 def _target_vmem_bytes(q, k, block_q, block_k):
-    """What a grid step of `_target_kernel` holds in VMEM: its blocks twice
-    (a width padded to whole lanes) and a tile's float32 temporaries."""
-    H, D = q.shape[2:]
-    blocks = (H * block_q + k.shape[2] * block_k) * _lanes(D) \
+    """What a grid step of `_loss_kernel` holds in VMEM: its blocks twice
+    (a width padded to whole lanes), the q tile's whole row three times
+    (target, selected scores, gradient) and its sums in float32, and a
+    tile's float32 temporaries."""
+    S, H, D = q.shape[1:]
+    tile = block_q * _lanes(block_k)
+    blocks = (block_q * _lanes(H * D) + block_k * _lanes(k.shape[2] * D)) \
         * q.dtype.itemsize \
-        + block_q * _lanes(H) * 4 + block_q * _lanes(block_k) * (1 + 4)
-    return 2 * blocks + 6 * block_q * _lanes(block_k) * 4
+        + block_q * (_lanes(H) + _lanes(1)) * 4 + tile * (1 + 4)
+    scratch = 3 * (S // block_k) * tile * 4 + 4 * block_q * 128 * 4
+    return 2 * blocks + scratch + 6 * tile * 4
 
 
-def _target_tiles(q, k, block):
-    """(q tile, k tile) of the target kernel for queries q (B, S, H, D) and
-    keys k (B, S, H_kv, D) by blocks of ``block`` rows, or None for a shape
-    it cannot tile, which takes `_target_reference` on every platform: a q
-    tile divides the block and a k tile the sequence, each the largest
-    power-of-two part of `_TARGET_TILE`'s that does; a tile that is not the
-    whole extent is a multiple of what Mosaic tiles the mask by (32 rows,
-    128 lanes); a step's blocks fit `_TARGET_VMEM_MAX`."""
-    tiles = (_tile(block, _TARGET_TILE[0], 32),
-             _tile(q.shape[1], _TARGET_TILE[1], 128))
-    if None in tiles or _target_vmem_bytes(q, k, *tiles) > _TARGET_VMEM_MAX:
+def _target_tiles(q, k):
+    """(q tile, k tile) of the loss's kernel for queries q (B, S, H, D) and
+    keys k (B, S, H_kv, D), or None for a shape it cannot tile, which takes
+    `_loss_reference` on every platform: each tile the largest power-of-two
+    part of `_TARGET_TILE`'s that divides the sequence; a tile that is not
+    the whole sequence is a multiple of what Mosaic tiles the mask by (32
+    rows, 128 lanes); a head's columns do not straddle a 128-lane tile of
+    q's row; a step's blocks and the row it holds fit `_TARGET_VMEM_MAX`."""
+    S, D = q.shape[1], q.shape[3]
+    tiles = (_tile(S, _TARGET_TILE[0], 32), _tile(S, _TARGET_TILE[1], 128))
+    if None in tiles or (128 % D and D % 128) \
+            or _target_vmem_bytes(q, k, *tiles) > _TARGET_VMEM_MAX:
         return None
     return tiles
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block_q", "block_k",
-                                             "interpret"))
-def _pallas_target(q, k, lse, mask, start, *, scale, block_q, block_k,
-                   interpret):
-    """`_target_reference` as one Mosaic kernel: grid (the block's q tiles,
-    the sequence's k tiles), ``start`` a scalar the index maps read: a q
-    tile's rows of the sequence's q, and for a k tile above its diagonal
-    the blocks of the last tile on it, so that a skipped step fetches
-    nothing.  The first result is (rows, S) float32: no attention kernel's
-    by `benchmark/families/keye_vl.py:is_attention_kernel`."""
-    H, S, D = q.shape
-    Hkv, rows = k.shape[0], mask.shape[0]
-
-    def visited(i, j, start):
-        return jnp.minimum(j, (start[0] + (i + 1) * block_q - 1) // block_k)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(rows // block_q, S // block_k),
-        in_specs=[
-            pl.BlockSpec((H, block_q, D),
-                         lambda i, j, start: (0, start[0] // block_q + i, 0)),
-            pl.BlockSpec((Hkv, block_k, D),
-                         lambda i, j, start: (0, visited(i, j, start), 0)),
-            pl.BlockSpec((block_q, H), lambda i, j, start: (i, 0)),
-            pl.BlockSpec((block_q, block_k),
-                         lambda i, j, start: (i, visited(i, j, start)))],
-        out_specs=pl.BlockSpec((block_q, block_k),
-                               lambda i, j, start: (i, j)))
+@functools.partial(jax.jit, static_argnames=("scale", "inv_rows", "block_q",
+                                             "block_k", "interpret"))
+def _pallas_loss(q, k, lse, mask, scores, *, scale, inv_rows, block_q,
+                 block_k, interpret):
+    """`_loss_reference` as one Mosaic kernel a layer: grid (sequences, q
+    tiles, k tiles), all in order: a q tile's row and sums live in VMEM
+    over its k tiles, and its gradient's copies over the next q tile's.  q
+    (B, S, H, D) and k (B, S, H_kv, D) as they lie, a head's columns
+    sliced from the lanes in the kernel: nothing is transposed but the row
+    statistics, lse (B, H, S).  -> (each query's KL (B, S), the gradient
+    (B, S, S) float32 written where it lies: no attention kernel's first
+    result by `benchmark/families/keye_vl.py:is_attention_kernel`)."""
+    B, S, H, D = q.shape
+    Hkv = k.shape[2]
+    visited = functools.partial(_last_on_diagonal, block_q=block_q,
+                                block_k=block_k)
+    tile = pl.BlockSpec((None, block_q, block_k),
+                        lambda b, i, j: (b, i, visited(i, j)))
+    row = pltpu.VMEM((S // block_k, block_q, block_k), jnp.float32)
+    # a lane tile wide, or the whole of a k tile that is less
+    sums = pltpu.VMEM((block_q, min(block_k, 128)), jnp.float32)
     call = pl.pallas_call(
-        functools.partial(_target_kernel, scale=scale, block_q=block_q,
-                          block_k=block_k),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows, S), jnp.float32),
+        functools.partial(_loss_kernel, scale=scale, inv_rows=inv_rows,
+                          block_q=block_q, block_k=block_k),
+        grid=(B, S // block_q, S // block_k),
+        in_specs=[
+            pl.BlockSpec((None, block_q, H * D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, block_k, Hkv * D),
+                         lambda b, i, j: (b, visited(i, j), 0)),
+            pl.BlockSpec((None, block_q, H), lambda b, i, j: (b, i, 0)),
+            tile, tile],
+        out_specs=[pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, i, 0)),
+                   pl.BlockSpec(memory_space=pl.ANY)],
+        out_shape=[jax.ShapeDtypeStruct((B, S, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((B, S, S), jnp.float32)],
+        scratch_shapes=[sums, sums, sums, sums, row, row, row,
+                        pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_TARGET_VMEM_MAX))
-    start, mask = start.reshape(1).astype(jnp.int32), mask.astype(jnp.int8)
-    with jax.named_scope("target"):
-        return call(start, q, k, lse, mask)
+    kl, grad = call(q.reshape(B, S, H * D), k.reshape(B, S, Hkv * D),
+                    lse.transpose(0, 2, 1), mask.astype(jnp.int8), scores)
+    return kl.reshape(B, S), grad
 
 
-def _loss_blocks(scores, mask, q, k, lse, scale, block):
-    """-> (sum over the queries of KL(p || softmax of the selected scores),
-    its gradient to ``scores`` (B, S, S) float32) by blocks of queries."""
-    target = _kernel_or_reference(
-        functools.partial(_pallas_target, scale=scale),
-        functools.partial(_target_reference, scale=scale),
-        _target_tiles(q, k, block))
-
+def _loss_reference(q, k, lse, mask, scores, *, scale, inv_rows, block):
+    """The loss's rows and its gradient in plain XLA by blocks of ``block``
+    queries, what `_pallas_loss` is held to and what a shape it declines
+    runs: q (B, S, H, D), k (B, S, H_kv, D), lse (B, H, S), mask and scores
+    (B, S, S) -> (each query's KL(p || softmax of its
+    selected scores) (B, S), the gradient to ``scores`` times ``inv_rows``
+    (B, S, S) float32).  A block's target and every pass after it go
+    through HBM."""
     def one(start, scores, mask, lse, q, k):
         chosen = mask != 0
-        p = target(q, k, lse, mask, start)
+        p = _target_reference(q, k, lse, mask, start, scale=scale)
         p = p / jnp.sum(p, axis=1, keepdims=True)
         logits = jnp.where(chosen, scores, -jnp.inf)
         log_q = logits - jax.scipy.special.logsumexp(logits, axis=1,
                                                      keepdims=True)
         kl = jnp.sum(jnp.where(chosen, jax.scipy.special.xlogy(p, p)
-                               - p * log_q, 0.0))
-        return kl, jnp.where(chosen, jnp.exp(log_q) - p, 0.0)
+                               - p * log_q, 0.0), axis=1)
+        return kl, jnp.where(chosen, (jnp.exp(log_q) - p) * inv_rows, 0.0)
 
     # q and k head-major, as the masked flash kernels take them: the main
     # attention's own transposes, made once
     kl, grad = _by_blocks(
         one, block, (scores, mask, lse.transpose(0, 2, 1)),
         (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)))
-    return jnp.sum(kl), _rows(grad)
+    return _rows(kl), _rows(grad)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
@@ -646,9 +756,16 @@ def _indexer_loss(scores, mask, q, k, lse, scale, block):
 
 
 def _indexer_loss_fwd(scores, mask, q, k, lse, scale, block):
-    total, grad = _loss_blocks(scores, mask, q, k, lse, scale, block)
+    """-> (the mean over the queries of KL(p || softmax of the selected
+    scores), its gradient to ``scores`` (B, S, S) float32)."""
     rows = scores.shape[0] * scores.shape[1]
-    return total / rows, grad / rows
+    inv_rows = 1.0 / rows
+    both = _kernel_or_reference(
+        functools.partial(_pallas_loss, scale=scale, inv_rows=inv_rows),
+        functools.partial(_loss_reference, scale=scale, inv_rows=inv_rows,
+                          block=block), _target_tiles(q, k))
+    kl, grad = both(q, k, lse, mask, scores)
+    return jnp.sum(kl) / rows, grad
 
 
 _indexer_loss.defvjp(
@@ -670,12 +787,15 @@ def indexer_loss(scores, mask, q, k, lse, block=512):
     gradient, (softmax - p) / (B S) over the selected pairs, is made with
     the loss and held (B, S, S) float32 until the backward pass reads it:
     the heads' probabilities are made once a trace of the forward pass (a
-    recomputed layer makes them again in its replay), a block of
-    ``block`` queries at a time by the target kernel (`_pallas_target`:
-    no head's scores of a block leave VMEM), or by `_target_reference`
+    recomputed layer makes them again in its replay), by one kernel a
+    layer that writes the rows' losses and the gradient and nothing else
+    (`_pallas_loss`: no head's scores and no row of the target leave
+    VMEM), or ``block`` queries at a time through HBM by `_loss_reference`
     where `_target_tiles` declines the shape."""
     B, S = scores.shape[:2]
     block = _block(S, block)
-    _count_tiles("target", B, S, _target_tiles(q, k, block))
+    tiles = _target_tiles(q, k)
+    _count_tiles("target", B, S, tiles)
+    tracing.count("attention.loss_rows_fused", B * S if tiles else 0)
     q, k, lse = jax.lax.stop_gradient((q, k, lse))
     return _indexer_loss(scores, mask, q, k, lse, q.shape[-1] ** -0.5, block)

@@ -66,7 +66,7 @@ EXPECTED = {
                    "attention/qkv", "attention/out", "ffn/moe/route",
                    "ffn/moe/experts", "ffn/moe/shared", "head_and_loss"},
     "keye_vl": {"attention/qkv", "attention/indexer/proj",
-                "attention/indexer/scores", "attention/indexer/loss/target",
+                "attention/indexer/scores", "attention/indexer/loss",
                 "attention/kernel/fwd_rows", "attention/kernel/bwd_fused",
                 "attention/out", "ffn/moe/route", "ffn/moe/experts",
                 "head_and_loss"},

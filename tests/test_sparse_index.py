@@ -2,11 +2,12 @@
 against a dense einsum, their two kernels interpreted against the blocked
 reference and the dense form, the threshold search against a stable sort and
 `jax.lax.top_k` (ties, zeros of either sign, every k), the indexer's loss and its gradient against the same written densely, the
-loss's target kernel interpreted against its plain reference, what it
-counts on the job timeline, and the loss at the cell's shape exported for
-a TPU."""
+loss's kernel (the target, the rows' losses and the gradient in one)
+interpreted against its plain reference, what it counts on the job
+timeline, and the loss at the cell's shape exported for a TPU."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -302,12 +303,26 @@ def test_nothing_of_the_loss_reaches_the_main_attention():
         assert not np.asarray(g).any()
 
 
-def target_inputs(B, S, H, Hkv, D, dtype, kind, block, seed=8):
-    """(mask, q, k, lse) of a main attention under a mask of ``kind``."""
-    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+def loss_inputs(B, S, H, Hkv, D, dtype, kind, block, seed=8):
+    """(scores, mask, q, k, lse) of a main attention under a selection of
+    ``kind``: `select_top_k`'s of random scores at a top_k below, at and
+    above a block, of one key, of every key (the rows before the top_k-th
+    have fewer), of scores rounded to halves (many tie at the threshold);
+    a causal tile with nothing selected; and every key selected beside one
+    whose products are so large that every other selected pair's target
+    is exactly 0."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     q, k, v = (jax.random.normal(key, (B, S, heads, D)).astype(dtype)
                for key, heads in zip(ks, (H, Hkv, Hkv)))
     tri = jnp.tril(jnp.ones((S, S), bool))
+    scores = jnp.where(tri, jax.random.normal(ks[3], (B, S, S)), -jnp.inf)
+    if kind == "ties":
+        scores = jnp.where(tri, jnp.round(scores * 2) / 2 + 0.0, -jnp.inf)
+    if kind == "zero_target":
+        # every query is the one direction key 5 lies far along
+        u = jax.random.normal(ks[4], (D,)).astype(dtype)
+        q = q * 0.1 + u
+        k = k.at[:, 5].set(40.0 * u)
     if kind == "empty_tile":
         # the later half of the queries attend none of the first half of
         # the keys: a causal tile with nothing selected
@@ -315,66 +330,72 @@ def target_inputs(B, S, H, Hkv, D, dtype, kind, block, seed=8):
         mask = jnp.broadcast_to(
             tri & ~(late[:, None] & ~late[None]), (B, S, S)).astype(jnp.int8)
     else:
-        scores = jnp.where(tri, jax.random.normal(ks[3], (B, S, S)), -jnp.inf)
-        top_k = {"top_k": S // 4, "single_key": 1, "triangle": 4 * S}[kind]
+        top_k = {"top_k": S // 4, "single_key": 1, "triangle": 4 * S,
+                 "zero_target": 4 * S, "ties": S // 4,
+                 "below_a_block": block // 2, "a_block": block,
+                 "above_a_block": block + block // 2}[kind]
         mask = si.select_top_k(scores, top_k, block=block)
     _, lse = attention(q, k, v, mask=mask, with_lse=True)
-    return mask, q, k, lse
+    return scores, mask, q, k, lse
 
 
-def target_by_blocks(fn, mask, q, k, lse, block):
-    """(B, S, S): ``fn`` over every block, as `_loss_blocks` calls it."""
-    return si._rows(si._by_blocks(
-        lambda start, mask, lse, q, k: fn(q, k, lse, mask, start), block,
-        (mask, lse.transpose(0, 2, 1)),
-        (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3))))
+def operands(scores, mask, q, k, lse):
+    """`_pallas_loss`' and `_loss_reference`'s, in their order."""
+    return q, k, lse, mask, scores
 
 
 @pytest.mark.parametrize("heads,D,dtype,S,block,kind", [
     ((8, 2), 64, jnp.float32, 128, 128, "top_k"),       # one q tile
-    ((8, 2), 128, jnp.bfloat16, 256, 128, "top_k"),     # two, a call each
-    ((4, 4), 64, jnp.bfloat16, 256, 256, "top_k"),      # two in one call
-    ((4, 4), 128, jnp.float32, 512, 256, "top_k"),      # four, two a call
-    ((8, 2), 64, jnp.bfloat16, 512, 128, "top_k"),      # four
+    ((8, 2), 128, jnp.bfloat16, 256, 128, "top_k"),     # two
+    ((4, 4), 64, jnp.bfloat16, 256, 256, "top_k"),      # a block of both
+    ((4, 4), 128, jnp.float32, 512, 256, "top_k"),      # four
+    ((8, 2), 64, jnp.bfloat16, 512, 128, "top_k"),
     ((8, 2), 128, jnp.float32, 256, 128, "empty_tile"),
     ((4, 4), 64, jnp.bfloat16, 256, 128, "empty_tile"),
     ((8, 2), 64, jnp.float32, 256, 128, "single_key"),
     ((4, 4), 128, jnp.bfloat16, 256, 256, "single_key"),
     ((8, 2), 64, jnp.float32, 256, 128, "triangle"),
     ((4, 4), 128, jnp.bfloat16, 256, 128, "triangle"),
+    ((8, 2), 64, jnp.float32, 256, 128, "below_a_block"),
+    ((8, 2), 64, jnp.bfloat16, 256, 128, "a_block"),
+    ((4, 4), 64, jnp.float32, 512, 128, "above_a_block"),
+    ((8, 2), 64, jnp.float32, 256, 128, "ties"),
+    ((4, 4), 64, jnp.bfloat16, 256, 128, "ties"),
+    ((8, 2), 64, jnp.float32, 256, 128, "zero_target"),
 ])
-def test_the_target_kernel_is_its_reference_and_the_dense_form(
+def test_the_loss_kernel_is_its_reference_and_the_dense_form(
         heads, D, dtype, S, block, kind):
-    """`_pallas_target`, interpreted, in tiles of 128 x 128 against
-    `_target_reference` (what `indexer_loss` ran before the kernel): to
-    1e-6 a head in float32; in bfloat16 one head alone bit for bit (its
-    result IS the exponent of the rounded argument) and the sum over the
-    heads to 1e-5 a head; nothing outside the mask.  And `indexer_loss`
-    through it against the dense float32 form."""
+    """`_pallas_loss`, interpreted, in tiles of 128 x 128 (a row of
+    several k tiles held, the tiles above a q tile's diagonal not visited)
+    against `_loss_reference`, the plain XLA form by blocks (what
+    `indexer_loss` ran before the kernel, and runs for a shape it
+    declines): each row's loss and the gradient, to float32's rounding of
+    their sums, in bfloat16 to 1e-5 a head of the target; nothing outside
+    the mask.  And `indexer_loss` through it against the dense float32
+    form."""
     (H, Hkv), B = heads, 1
-    mask, q, k, lse = target_inputs(B, S, H, Hkv, D, dtype, kind, block)
-    scale = D ** -0.5
-    kernel = functools.partial(si._pallas_target, scale=scale, block_q=128,
+    inputs = loss_inputs(B, S, H, Hkv, D, dtype, kind, block)
+    scores, mask, q, k, lse = inputs
+    sizes = dict(scale=D ** -0.5, inv_rows=1.0 / (B * S))
+    kl, grad = si._pallas_loss(*operands(*inputs), **sizes, block_q=128,
                                block_k=128, interpret=True)
-    reference = functools.partial(si._target_reference, scale=scale)
-    got = target_by_blocks(kernel, mask, q, k, lse, block)
-    want = target_by_blocks(reference, mask, q, k, lse, block)
-    assert got.dtype == jnp.float32 and got.shape == (B, S, S)
-    assert not np.asarray(got)[np.asarray(mask) == 0].any()
-    assert float(jnp.max(want)) > 0.5
+    want_kl, want_grad = si._loss_reference(*operands(*inputs), **sizes,
+                                            block=block)
+    assert kl.dtype == grad.dtype == jnp.float32
+    assert kl.shape == (B, S) and grad.shape == (B, S, S)
+    assert not np.asarray(grad)[np.asarray(mask) == 0].any()
+    if kind == "zero_target":
+        # xlogy(0, 0) = 0: a selected pair whose target underflowed
+        target = si._target_reference(
+            q[0].transpose(1, 0, 2), k[0].transpose(1, 0, 2), lse[0].T,
+            mask[0], 0, scale=D ** -0.5)
+        assert np.asarray((target == 0) & (mask[0] != 0)).sum() > S
+    assert np.isfinite(np.asarray(kl)).all()
     tolerance = 1e-6 if dtype == jnp.float32 else 1e-5
-    assert float(jnp.max(jnp.abs(got - want))) <= tolerance * H
-    if dtype == jnp.bfloat16:
-        for h in (0, H - 1):
-            one = (mask, q[:, :, h:h + 1], k[:, :, h * Hkv // H:][:, :, :1],
-                   lse[:, h:h + 1], block)
-            assert np.array_equal(
-                np.asarray(target_by_blocks(kernel, *one)),
-                np.asarray(target_by_blocks(reference, *one)))
+    assert float(jnp.max(jnp.abs(kl - want_kl))) <= 4 * tolerance * H
+    assert float(jnp.max(jnp.abs(grad - want_grad))) <= tolerance * H / S
     # the loss, the kernel at `_target_tiles`' own tiles where the
     # interpreter takes the size, against the dense form
-    scores = jnp.where(jnp.tril(jnp.ones((S, S), bool)), jax.random.normal(
-        jax.random.PRNGKey(9), (B, S, S)), -jnp.inf)
     loss, grad = jax.value_and_grad(
         lambda sc: si.indexer_loss(sc, mask, q, k, lse, block=block))(scores)
     dense, dense_grad = jax.value_and_grad(
@@ -382,6 +403,42 @@ def test_the_target_kernel_is_its_reference_and_the_dense_form(
     close = 2e-5 if dtype == jnp.float32 else 2e-2
     assert float(jnp.abs(loss - dense)) <= close * max(float(dense), 1e-3)
     assert float(jnp.max(jnp.abs(grad - dense_grad))) <= close / S
+    assert not np.asarray(grad)[np.asarray(mask) == 0].any()
+
+
+@pytest.mark.parametrize("block_q,block_k", [(64, 128), (128, 256),
+                                             (256, 128)])
+def test_the_loss_kernel_at_tiles_that_are_not_square(block_q, block_k):
+    """Two q tiles a k tile, two k tiles a q tile, and a q tile of two
+    diagonals: the same rows' losses and gradient whatever the tiles."""
+    inputs = loss_inputs(2, 256, 4, 2, 64, jnp.float32, "top_k", 128)
+    sizes = dict(scale=64 ** -0.5, inv_rows=1.0 / 512)
+    kl, grad = si._pallas_loss(*operands(*inputs), **sizes,
+                               block_q=block_q, block_k=block_k,
+                               interpret=True)
+    want_kl, want_grad = si._loss_reference(*operands(*inputs), **sizes,
+                                            block=128)
+    assert float(jnp.max(jnp.abs(kl - want_kl))) <= 2e-5
+    assert float(jnp.max(jnp.abs(grad - want_grad))) <= 1e-8
+
+
+def test_a_recomputed_layers_two_walks_make_one_loss():
+    """Under `jax.checkpoint` the loss is made by the first walk and the
+    gradient by the replay: the square of the loss shows the replay's (its
+    gradient is twice the replayed loss times the loss's own), which is
+    the first walk's and the dense form's."""
+    scores, mask, q, k, lse = loss_inputs(2, 64, H, HKV, D, jnp.float32,
+                                          "top_k", 64)
+    loss = lambda sc: si.indexer_loss(sc, mask, q, k, lse, block=64)
+    first = loss(scores)
+    squared, grad = jax.value_and_grad(jax.checkpoint(
+        lambda sc: loss(sc) ** 2))(scores)
+    dense, dense_grad = jax.value_and_grad(
+        lambda sc: dense_loss(sc, mask, q, k))(scores)
+    assert float(squared) == float(first ** 2)
+    assert float(jnp.abs(first - dense)) < 2e-5 * float(dense)
+    assert float(jnp.max(jnp.abs(grad - 2 * first * dense_grad))) \
+        < 1e-7 * float(first)
     assert not np.asarray(grad)[np.asarray(mask) == 0].any()
 
 
@@ -415,6 +472,9 @@ def test_what_it_counts_as_the_step_is_traced(monkeypatch):
             shape(1, S, S), jax.ShapeDtypeStruct((1, S, S), jnp.int8),
             shape(1, S, heads[0], dim), shape(1, S, heads[1], dim),
             shape(1, heads[0], S))
+        # every row's loss and gradient are the kernel's, or none's
+        assert counted["attention.loss_rows_fused"] \
+            == (S if counted["attention.target_tiles"] else 0)
         return (counted["attention.target_tiles"],
                 counted["attention.target_tiles_skipped"])
 
@@ -422,7 +482,7 @@ def test_what_it_counts_as_the_step_is_traced(monkeypatch):
     # tile: 272 on or under the diagonal and 240 above it a sequence
     assert target_tiles(8192, 512, (32, 4), 128) == (272, 240)
     assert target_tiles(2048, 256) == (2 * (1 + 2 + 3 + 4), 2 * (3 + 2 + 1))
-    assert target_tiles(S, 16) == (4, 0)     # the whole sequence a k tile
+    assert target_tiles(S, 16) == (1, 0)     # the whole sequence a tile
 
     def score_tiles(B, S, block, J=16, dim=64, dtype=jnp.bfloat16):
         counted.clear()
@@ -455,11 +515,16 @@ def test_what_it_counts_as_the_step_is_traced(monkeypatch):
         (1, 8192, 512, 128), jnp.bfloat16), 512) is None
     # 576 keys are no whole number of 128-lane tiles: the reference
     shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
-    assert si._target_tiles(shape(1, 576, H, D), shape(1, 576, HKV, D),
-                            64) is None
+    assert si._target_tiles(shape(1, 576, H, D),
+                            shape(1, 576, HKV, D)) is None
     # a q tile of every head that VMEM does not hold
-    assert si._target_tiles(shape(1, 512, 256, 512), shape(1, 512, 256, 512),
-                            256) is None
+    assert si._target_tiles(shape(1, 512, 256, 512),
+                            shape(1, 512, 256, 512)) is None
+    # nor the three rows of a q tile at 65,536 keys (the cell's fits)
+    cell = lambda S: (jax.ShapeDtypeStruct((2, S, 32, 128), jnp.bfloat16),
+                      jax.ShapeDtypeStruct((2, S, 4, 128), jnp.bfloat16))
+    assert si._target_tiles(*cell(8192)) == si._TARGET_TILE
+    assert si._target_tiles(*cell(65536)) is None
     assert target_tiles(576, 64) == (0, 0)
 
 
@@ -478,18 +543,27 @@ def exported_loss(S, block):
             platforms=["tpu"])(*args).mlir_module()
 
 
-def test_the_target_lowers_to_mosaic_for_tpu_at_the_cells_shape():
-    """2 x 8,192 by blocks of 512: the target is a Mosaic custom call, no
-    product is left to XLA and no head's scores of a block exist."""
+def test_the_loss_lowers_to_mosaic_for_tpu_at_the_cells_shape():
+    """2 x 8,192 by blocks of 512: the loss and its gradient are ONE Mosaic
+    custom call, no product is left to XLA, no head's scores and no block
+    of the target exist, and nothing but the kernel makes a (B, S, S)
+    float32: the cotangent's product with the gradient it wrote is the one
+    other operation of that shape."""
     module = exported_loss(8192, 512)
     assert module.count("stablehlo.custom_call @tpu_custom_call") == 1
     assert "stablehlo.dot_general" not in module
-    assert "4x8x512x8192" not in module
+    assert "512x8192" not in module
+    # (the kernel's two results are a tuple's)
+    made = {op for op, result in re.findall(
+        r"= (stablehlo\.\w+).*?(tensor<[^>]*>) loc\(", module)
+        if result == "tensor<2x8192x8192xf32>"}
+    assert made == {"stablehlo.broadcast_in_dim", "stablehlo.multiply"}
 
 
-def test_a_shape_the_target_kernel_declines_lowers_to_the_reference():
+def test_a_shape_the_loss_kernel_declines_lowers_to_the_reference():
     """8,256 keys (129 blocks of 64) are no whole number of 128-lane
-    tiles: the five XLA lines on every platform, the TPU included."""
+    tiles: the plain XLA form by blocks on every platform, the TPU
+    included."""
     module = exported_loss(8256, 64)
     assert "tpu_custom_call" not in module
     assert "stablehlo.dot_general" in module

@@ -38,17 +38,15 @@ operator and its gates and taps alone, in each form tried for the taps,
 against a float32 sum over taps and against the least time of the gates'
 and taps' bytes.
 
-``target_8k`` is the indexer loss's target alone
-(`ops/sparse_index.py:_pallas_target`) at the keye cell's shape (2 x 8,192,
-32 query heads on 4 key heads of 128, blocks of 512 queries, 2,048 keys a
-query): every block's head-summed probabilities as `_loss_blocks` makes
-them, the Mosaic kernel beside `_target_reference` (the plain XLA form, which
-alone here XLA fuses into one pass and frees of its rounding to bfloat16:
-inside the step it is two fusions through HBM), device ms of every
-operation (the blocks' slices and the stacking of their results among
-them; `kernel_ms` read 0.0 here at PR 48: a kernel called inside
-`lax.map`'s loop is not among the events it sums) and the largest error
-relative to the same in float32.  ``--sweep target-8k`` times the kernel at other tiles.
+``target_8k`` is the indexer's loss alone (`ops/sparse_index.py:_pallas_loss`)
+at the keye cell's shape (2 x 8,192, 32 query heads on 4 key heads of 128,
+blocks of 512 queries, 2,048 keys a query): one layer's rows' losses and
+gradient to the scores, the one Mosaic kernel (the target, its sums and the
+gradient, a q tile's whole row in VMEM) beside `_loss_reference` (the plain XLA
+form by blocks: a block's target and every pass after it through HBM), device
+ms of every operation, the seconds each took to compile and the largest error
+of the losses and of the gradient relative to the reference on float32
+operands.  ``--sweep target-8k`` times the kernel at other tiles.
 
 ``scores_8k`` is the index scores alone (`ops/sparse_index.py:index_scores`'s
 two kernels, `_pallas_scores` and `_pallas_scores_bwd`) at the keye cell's
@@ -120,8 +118,8 @@ TARGET_CASES = {
 }
 # the (q tile, k tile) `--sweep target-8k` times a case of TARGET_CASES at
 TARGET_SWEEP = {
-    "target-8k": ("target_8k", ((128, 512), (256, 256), (256, 512),
-                                (256, 1024), (512, 512))),
+    "target-8k": ("target_8k", ((128, 512), (128, 1024), (256, 256),
+                                (256, 512), (256, 1024))),
 }
 # (B, S, J, D_I, block, top_k) of one layer's index scores
 SCORES_CASES = {
@@ -485,16 +483,18 @@ def shortconv_case(name, dtype):
 
 
 def target_case(name, dtype, tiles=None):
-    """The indexer loss's target at ``TARGET_CASES[name]``, every block of
-    both sequences as `_loss_blocks` makes them: a line for the Mosaic
-    kernel (``tiles``: at these instead of `_TARGET_TILE`, and nothing
-    compared) and one for `_target_reference`: device ms of every
-    operation, and the largest error of the (B, S, S) result relative to
-    the reference on float32 operands."""
+    """The indexer's loss at ``TARGET_CASES[name]``, one layer's of both
+    sequences: a line for the fused Mosaic kernel (``tiles``: at these
+    instead of `_target_tiles`', and nothing compared), the rows' losses
+    and the gradient, and one for `_loss_reference`, the plain XLA form by
+    blocks: device ms of every operation, the seconds the form took to
+    compile, and the largest error of the rows' losses and of the (B, S, S)
+    gradient relative to the reference on float32 operands."""
+    import time
+
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.ops import by_platform
     from ray_tpu.ops import sparse_index as si
     from ray_tpu.parallel.attention import attention
 
@@ -503,33 +503,33 @@ def target_case(name, dtype, tiles=None):
     scores = jnp.where(jnp.tril(jnp.ones((S, S), bool)), jax.random.normal(
         jax.random.PRNGKey(3), (B, S, S)), -jnp.inf)
     mask = jax.jit(lambda s: si.select_top_k(s, top_k, block))(scores)
-    del scores
     _, lse = jax.jit(lambda q, k, v, m: attention(
         q, k, v, mask=m, with_lse=True))(q, k, v, mask)
-    scale = D ** -0.5
-    reference = functools.partial(si._target_reference, scale=scale)
-    bq, bk = tiles or si._target_tiles(q, k, block)
-    kernel = functools.partial(by_platform, functools.partial(
-        si._pallas_target, scale=scale, block_q=bq, block_k=bk), reference)
-
-    def by_blocks(fn):
-        return jax.jit(lambda mask, q, k, lse: si._rows(si._by_blocks(
-            lambda start, mask, lse, q, k: fn(q, k, lse, mask, start), block,
-            (mask, lse.transpose(0, 2, 1)),
-            (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3)))))
-
-    exact = None if tiles else by_blocks(reference)(
-        mask, q.astype(jnp.float32), k.astype(jnp.float32), lse)
-    forms = {"kernel": kernel} if tiles else {"kernel": kernel,
-                                               "reference": reference}
+    del v
+    sizes = dict(scale=D ** -0.5, inv_rows=1.0 / (B * S))
+    reference = functools.partial(si._loss_reference, block=block, **sizes)
+    bq, bk = tiles or si._target_tiles(q, k)
+    forms = {"kernel": functools.partial(
+        si._pallas_loss, block_q=bq, block_k=bk, interpret=False, **sizes)}
+    exact = None
+    if not tiles:
+        forms["reference"] = reference
+        exact = jax.jit(reference)(q.astype(jnp.float32),
+                                   k.astype(jnp.float32), lse, mask, scores)
+    rel = lambda got, want: round(float(
+        jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want))), 6)
     for form, fn in forms.items():
-        f = by_blocks(fn)
+        f = jax.jit(fn)
+        began = time.perf_counter()
+        compiled = f.lower(q, k, lse, mask, scores).compile()
         line = {"case": name, "form": form, "tile": [bq, bk],
-                "every_op_ms": busy_ms(f, mask, q, k, lse)}
+                "compile_s": round(time.perf_counter() - began, 2),
+                "mosaic_kernels": compiled.as_text().count(
+                    'custom_call_target="tpu_custom_call"'),
+                "every_op_ms": busy_ms(f, q, k, lse, mask, scores)}
         if exact is not None:
-            line["rel_err"] = {"target": round(float(
-                jnp.max(jnp.abs(f(mask, q, k, lse) - exact))
-                / jnp.max(jnp.abs(exact))), 5)}
+            line["rel_err"] = {what: rel(a, b) for what, a, b in zip(
+                ("kl", "grad"), f(q, k, lse, mask, scores), exact)}
         yield line
 
 
@@ -871,7 +871,8 @@ def main():
     for name in TARGET_CASES:
         for line in target_case(name, jnp.bfloat16) \
                 if name in args.cases else ():
-            ok = line["rel_err"]["target"] < TOLERANCE
+            ok = max(line["rel_err"].values()) < TOLERANCE \
+                and line["mosaic_kernels"] == (line["form"] == "kernel")
             if not ok:
                 failed.append(f"{name}:{line['form']}")
             print(json.dumps({**line, "ok": ok,
