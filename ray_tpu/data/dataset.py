@@ -20,6 +20,8 @@ TPU-first redesign decisions:
 from __future__ import annotations
 
 import itertools
+import threading
+import time
 from collections import deque
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
@@ -474,6 +476,15 @@ def _stream_blocks(sources: List[_Source], ops: List[_OpSpec],
         yield pending.popleft()
 
 
+_waited = threading.local()   # .us: this thread's time in data.block_wait
+
+
+def block_wait_us() -> int:
+    """Microseconds the calling thread has spent under `data.block_wait`
+    (a `train.step` record's `data_us` is its change over the step)."""
+    return getattr(_waited, "us", 0)
+
+
 def _get_block(ref, index: int) -> Block:
     """The consumer's ``get`` of its next block.  In a Train job's
     timeline it is the span ``data.block_wait`` (how long the consumer
@@ -484,11 +495,13 @@ def _get_block(ref, index: int) -> Block:
     if tracing.timeline_ctx() is None:
         return ray_tpu.get(ref)
     ready = bool(ray_tpu.wait([ref], timeout=0)[0])
+    t0 = time.perf_counter_ns()
     with tracing.timeline_span("data.block_wait", block=index,
                                ready=ready) as sp:
         block = ray_tpu.get(ref)
         size = BlockAccessor.for_block(block).size_bytes()
         sp.set_attrs(bytes=size)
+    _waited.us = block_wait_us() + (time.perf_counter_ns() - t0) // 1000
     tracing.count("data.blocks")
     tracing.count("data.block_bytes", size)
     if ready:

@@ -187,6 +187,7 @@ class DataParallelTrainer:
 
         executor.start()
         started = False
+        rounds = 0
         try:
             while True:
                 try:
@@ -197,9 +198,11 @@ class DataParallelTrainer:
                             dataset_splitter=self._dataset_splitter(),
                         )
                         started = True
-                    # the driver's side of one lockstep round
-                    with tracing.timeline_span("train.round"):
+                    # the driver's side of one lockstep round: until a
+                    # restart, `n` is the `n` of the reports it consumes
+                    with tracing.timeline_span("train.round", n=rounds):
                         round_results = executor.get_next_results()
+                    rounds += 1
                 except TrainingWorkerError as e:
                     failures += 1
                     if max_failures >= 0 and failures > max_failures:

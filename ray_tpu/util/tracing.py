@@ -578,8 +578,10 @@ def hop(name: str, parent: Optional[Dict[str, Any]], start: float,
 # Two bounded buffers a process: lifecycle spans (placement, worker start,
 # chip claim, compiles, restarts: kept oldest-first, a few dozen a start) and
 # a ring of the newest per-step spans; whatever either sheds is counted.
+# (`train.stall` is no `train.step`: a long job's ring sheds its steps and
+# keeps the stalls among the lifecycle spans.)
 
-_STEP_SPANS = ("train.report", "train.round", "data.")
+_STEP_SPANS = ("train.report", "train.round", "train.step", "data.")
 TIMELINE_LIFECYCLE_CAP = 4096
 TIMELINE_STEP_CAP = 4096
 _tl_lock = make_lock("tracing.timeline")

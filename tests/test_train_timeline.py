@@ -298,7 +298,14 @@ def test_outside_a_job_nothing_is_recorded(ray_train):
     record, and `timeline_span` is a no-op span."""
     from ray_tpu.data.dataset import Dataset
 
-    before = len(tracing._tl_steps) + len(tracing._tl_lifecycle)
+    def held():
+        # what this test's calls could write; the fixture's own raylet
+        # records its `raylet.worker_spawn` hops while the test runs
+        with tracing._tl_lock:
+            return [r for r in (*tracing._tl_steps, *tracing._tl_lifecycle)
+                    if r["name"].startswith(("data.", "train."))]
+
+    before = held()
     # other files' tests in this process may have left a job's counters
     counted = {job: dict(c) for job, c in tracing._tl_counters.items()}
     ds = Dataset.from_read_fns(
@@ -306,7 +313,7 @@ def test_outside_a_job_nothing_is_recorded(ray_train):
     assert sum(len(b["x"]) for b in ds.iter_batches(batch_size=4)) == 16
     assert tracing.timeline_span("data.block_wait") is tracing._NULL_SPAN
     tracing.count("data.blocks")
-    assert len(tracing._tl_steps) + len(tracing._tl_lifecycle) == before
+    assert held() == before
     assert tracing._tl_counters == counted
 
 
