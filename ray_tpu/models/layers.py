@@ -3,11 +3,13 @@ feed-forwards (gated and not), the routed layer over them, the gated short
 convolution, the causal convolution and gated norm of a state-space mixer,
 the walk over a decoder's layers (once, or, for a looped model, several
 times over the same parameters with the final norm after every walk), the
-head and its chunked loss (summed, or handed back row by row) and the
-mixed-precision step.  A model file imports these,
-`ray_tpu.parallel.attention` and `ray_tpu.ops`; it imports no other model
-file: it is its configuration, `init_params`, its mixers and a `_layer` that
-says which mixer and which feed-forward a layer has.
+head and its chunked loss (one rule, `chunked_xent`: the rows weighted or
+not, their losses handed back beside the sum, the gradient formed in the
+same walk that makes the logits) and the mixed-precision step.  A model
+file imports these, `ray_tpu.parallel.attention` and `ray_tpu.ops`; it
+imports no other model file: it is its configuration, `init_params`, its
+mixers and a `_layer` that says which mixer and which feed-forward a layer
+has.
 
 Imports jax, the names of the flash kernels' residuals and forms
 (`ops/flash_attention.py`, which every model imports through
@@ -25,13 +27,13 @@ around the part; what the models share names itself (`layer_norm` and
 `rms_norm`: `norm`; `short_conv`'s three parts; `trunk`'s `embed`;
 `routed_layer`'s `route` and `shared` and `ops/moe.py`'s `dispatch`,
 `experts`, `combine` and `routing_bias_update`, which rely on the caller
-standing in `ffn/moe`; `head_and_loss`, also around `head_and_row_losses`;
-the flash kernels' forms; a looped model's `exit_gate` (the gates, the exit
-distribution, its entropy and the weighting of the rows' losses: the model
-writes it);
-`train_step`'s `optimizer_update`).  `norm` is a layer's norm on the residual stream: one
-inside an operator (a norm over a head, the latent's) stands in that
-operator's scope and counts there.
+standing in `ffn/moe`; `head_and_loss`, also around
+`head_and_weighted_loss`; the flash kernels' forms; a looped model's
+`exit_gate` (the gates, the exit distribution and its entropy: the model
+writes it; the weighting of the rows' losses is the head's, under
+`head_and_loss`); `train_step`'s `optimizer_update`).  `norm` is a layer's
+norm on the residual stream: one inside an operator (a norm over a head, the
+latent's) stands in that operator's scope and counts there.
 """
 
 from __future__ import annotations
@@ -662,58 +664,116 @@ def trunk(params, tokens, layer, cfg, walks=None):
     return jnp.stack(states), seconds
 
 
-def _chunks(x, targets, n_chunks: int):
-    """x (N, E), targets (N,) -> ((n, N / n, E), (n, N / n)): ``n_chunks``
-    chunks, or the next fewer that divide N."""
-    N, E = x.shape
+def _chunks(n_chunks: int, *rows):
+    """Arrays (N, ...) -> each (n, N / n, ...), a None left as it is:
+    ``n_chunks`` chunks, or the next fewer that divide N."""
+    N = rows[0].shape[0]
     n_chunks = max(1, min(n_chunks, N))
     while N % n_chunks:
         n_chunks -= 1
-    return (x.reshape(n_chunks, N // n_chunks, E),
-            targets.reshape(n_chunks, N // n_chunks))
+    return tuple(r if r is None else
+                 r.reshape(n_chunks, N // n_chunks, *r.shape[1:])
+                 for r in rows)
 
 
-def _chunk_losses(wte, xi, ti):
-    """One chunk's logits in float32 -> its rows' cross-entropies."""
+def _chunk_logits(wte, xi, ti):
+    """One chunk's logits in float32 -> (them, their rows' log-sum-exp,
+    the rows' cross-entropies)."""
     logits = jnp.matmul(xi, wte.T, preferred_element_type=jnp.float32)
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     tgt = jnp.take_along_axis(logits, ti[:, None], axis=-1)[:, 0]
-    return lse - tgt
+    return logits, lse, lse - tgt
 
 
-def chunked_xent(x, wte, targets, n_chunks: int):
-    """Fused linear + softmax cross-entropy, chunked over tokens.
-
-    The naive path materializes (B*S, V) f32 logits in HBM twice (forward
-    residual + backward read) — ~3.3 GB at B=16, S=1024, V=50257.
-    Instead: scan over token chunks, each chunk computing logits ->
-    (lse, target-logit) under ``jax.checkpoint`` so the backward pass
-    RECOMPUTES the chunk's logits and immediately contracts d_logits into
-    (dx, dwte) — the full logits tensor never exists in HBM in either pass.
-    (Same idea as fused linear-cross-entropy kernels; here XLA fuses the
-    chunk, no Pallas needed.)
-
-    x: (N, E) compute-dtype; wte: (V, E); targets: (N,) int32.
-    Returns summed loss (f32).
-    """
-    chunks = _chunks(x, targets, n_chunks)
-
-    @jax.checkpoint
-    def chunk(carry, xt):
-        return carry + jnp.sum(_chunk_losses(wte, *xt)), None
-
-    total, _ = jax.lax.scan(chunk, jnp.zeros((), jnp.float32), chunks)
-    return total
+def _own_buffer(xi):
+    """A chunk of x as a buffer of its own.  Left alone, XLA:TPU fuses the
+    loop's slice of x into each product that reads the chunk, and the
+    product then fetches its rows from the whole (n, R, E) array in HBM
+    again for every tile of its result; behind the barrier the chunk is
+    sliced once, 8 MB at (2,048, 2,048), and the compiler keeps it in the
+    chip's fast memory beside the product.  On the v5e at 2,048 x 2,048 x
+    50,304, ms a chunk: the logits 2.70 -> 2.22, dW 3.18 -> 2.42 (3.77 ->
+    2.41 with its float32 carry, which then costs nothing)
+    (`tools/chip_kernels.py --cases head_loss_8k`; PERF.md section 6,
+    PR 53)."""
+    return jax.lax.optimization_barrier(xi)
 
 
-def chunked_xent_rows(x, wte, targets, n_chunks: int):
-    """`chunked_xent` handing back the ROWS' losses, (N,) float32, for an
-    objective that weights each row by something of its own (a looped
-    model's exit distribution): the same chunks, each one's logits made
-    again by the backward pass, which contracts the rows' cotangents into
-    (dx, dwte) chunk by chunk."""
-    chunk = jax.checkpoint(lambda xt: _chunk_losses(wte, *xt))
-    return jax.lax.map(chunk, _chunks(x, targets, n_chunks)).reshape(-1)
+def _weighted_sum(ce, wc):
+    return jnp.sum(ce if wc is None else ce * wc)
+
+
+def _count_walk(xc):
+    """One walk over the chunks, on the job timeline as it is traced."""
+    tracing.count("loss.chunks", xc.shape[0])
+    tracing.count("loss.logits_passes")
+
+
+@jax.custom_vjp
+def chunked_xent(xc, wte, tc, wc):
+    """Fused linear + softmax cross-entropy, chunked over tokens (the idea
+    of the fused linear-cross-entropy kernels; the products are XLA's, no
+    Pallas needed).  xc (n, R, E) in the compute type: n chunks of R rows;
+    wte (V, E); tc (n, R) int32 targets; wc (n, R) float32, a weight a row,
+    or None: 1 for every row.  -> (sum_r w_r CE_r, the rows' CE (n, R)),
+    float32.  The (N, V) logits never exist: a chunk's are made in float32,
+    read and dropped.
+
+    This, the primal, computes losses only: a call nobody differentiates
+    pays for no gradient.  Under a gradient the forward rule
+    (`_chunked_xent_fwd`) walks the chunks ONCE and forms each chunk's
+    gradient from its logits while they are there; the backward rule makes
+    no product.  The gradient reaches x, wte and the weights (the rows' CE
+    times the cotangent: exact).  The rows handed back carry NO gradient:
+    their cotangent is dropped, and the one caller that hands them on says
+    so with `stop_gradient` (`head_and_weighted_loss`).
+
+    Counted on the job timeline as the step is traced: `loss.chunks` (n)
+    and `loss.logits_passes` (1 a walk: the logits are made once)."""
+    _count_walk(xc)
+    ce = jax.lax.map(
+        lambda xt: _chunk_logits(wte, _own_buffer(xt[0]), xt[1])[2], (xc, tc))
+    return _weighted_sum(ce, wc), ce
+
+
+def _chunked_xent_fwd(xc, wte, tc, wc):
+    """A chunk: logits -> statistics -> the rows' CE -> d logits = w
+    (softmax - onehot), float32 -> in the compute type at once, and
+    contracted at once into the chunk's dx (R, E) and into dW (V, E), a
+    float32 accumulator carried over the chunks: three products a chunk,
+    the two of the gradient with operands in the compute type and float32
+    accumulation (what the MXU makes of a float32 cotangent at default
+    precision).  The residuals are dx, dW in wte's type, the rows' CE and
+    the weights: the gradient for a cotangent of 1."""
+    _count_walk(xc)
+
+    def chunk(dw, xtw):
+        xi, ti, wi = xtw
+        xi = _own_buffer(xi)
+        logits, lse, ce = _chunk_logits(wte, xi, ti)
+        d = jnp.exp(logits - lse[:, None]) - jax.nn.one_hot(
+            ti, logits.shape[-1], dtype=jnp.float32)
+        if wi is not None:
+            d = d * wi[:, None]
+        d = d.astype(xi.dtype)
+        dw = dw + jax.lax.dot_general(
+            d, xi, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return dw, (ce, jnp.matmul(d, wte))
+
+    dw, (ce, dx) = jax.lax.scan(
+        chunk, jnp.zeros(wte.shape, jnp.float32), (xc, tc, wc))
+    return (_weighted_sum(ce, wc), ce), (dx, dw.astype(wte.dtype), ce, wc)
+
+
+def _chunked_xent_bwd(res, cotangents):
+    dx, dw, ce, wc = res
+    g, _ = cotangents           # the rows handed back carry no gradient
+    scaled = lambda a: (a.astype(jnp.float32) * g).astype(a.dtype)
+    return scaled(dx), scaled(dw), None, None if wc is None else ce * g
+
+
+chunked_xent.defvjp(_chunked_xent_fwd, _chunked_xent_bwd)
 
 
 def _head_rows(head, dtype):
@@ -723,32 +783,35 @@ def _head_rows(head, dtype):
         else head["kernel"].astype(dtype).T
 
 
-def head_and_loss(x, head, targets, chunk_rows):
-    """x (B, S, E), targets (B, S) int32 -> the mean next-token
-    cross-entropy, under `head_and_loss`.  ``head``: an untied head's
+def head_and_weighted_loss(x, head, targets, weights, chunk_rows):
+    """x (..., E), targets (...) int32, weights (...) float32 or None (1 for
+    every row) -> (sum_r w_r CE_r, every row's next-token cross-entropy
+    (...) float32), under `head_and_loss`.  ``head``: an untied head's
     parameters {"kernel": (E, V)}, or those of the embedding a tied one is
     {"embedding": (V, E)}.  The logits are made ``chunk_rows`` rows at a
-    time and never all held (`chunked_xent`)."""
-    B, S, E = x.shape
-    with jax.named_scope("head_and_loss"):
-        rows = _head_rows(head, x.dtype)
-        total = chunked_xent(x.reshape(B * S, E), rows,
-                             targets.reshape(B * S), -(-B * S // chunk_rows))
-        return total / (B * S)
-
-
-def head_and_row_losses(x, head, targets, chunk_rows):
-    """x (..., E), targets (...) int32 -> every row's next-token
-    cross-entropy, (...) float32, under `head_and_loss`: `head_and_loss`
-    for a caller that weights the rows itself (`chunked_xent_rows`); a
-    looped model hands all its walks' states in at once, the targets
-    repeated, and the head is read by one loop over their chunks."""
+    time, once, and never all held (`chunked_xent`).  The gradient goes
+    through the SUM, to x, the head and the weights; the rows are for
+    reading (a report, a mean) and carry none.  A looped model hands all
+    its walks' states in at once, the targets repeated and its exit
+    distribution the weights, and the head is read by one loop over their
+    chunks."""
     E = x.shape[-1]
     with jax.named_scope("head_and_loss"):
-        losses = chunked_xent_rows(
-            x.reshape(-1, E), _head_rows(head, x.dtype), targets.reshape(-1),
-            -(-targets.size // chunk_rows))
-        return losses.reshape(targets.shape)
+        xc, tc, wc = _chunks(
+            -(-targets.size // chunk_rows), x.reshape(-1, E),
+            targets.reshape(-1),
+            None if weights is None else weights.reshape(-1))
+        total, rows = chunked_xent(xc, _head_rows(head, x.dtype), tc, wc)
+        return total, jax.lax.stop_gradient(rows).reshape(targets.shape)
+
+
+def head_and_loss(x, head, targets, chunk_rows):
+    """x (B, S, E), targets (B, S) int32 -> the mean next-token
+    cross-entropy, under `head_and_loss`: `head_and_weighted_loss` with
+    every row's weight 1, over the rows' number."""
+    total, _ = head_and_weighted_loss(x, head, targets, None, chunk_rows)
+    with jax.named_scope("head_and_loss"):
+        return total / targets.size
 
 
 def cast_weights(params, dtype):

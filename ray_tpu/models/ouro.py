@@ -29,12 +29,13 @@ stage, which trains the gate alone.
 
 The walk over the layers T times is `models/layers.py:trunk`'s (``walks``),
 as are RMSNorm, RoPE, the SwiGLU, what a recomputed layer keeps, the head
-with the rows' chunked losses and the mixed-precision step; attention is
-`parallel/attention.py`'s.  This file is the configuration, `init_params`,
-the sandwich `_layer`, the gate and the objective.  A weight's gradient is
-the sum of its T uses: `layers.train_step` hands the objective the matrices
-cast to the compute type once, so the T cotangents meet in that type before
-the float32 master sees their sum (`tests/test_ouro.py` has the reading).
+with its chunked loss, the rows weighted, and the mixed-precision step;
+attention is `parallel/attention.py`'s.  This file is the configuration,
+`init_params`, the sandwich `_layer`, the gate and the objective.  A weight's
+gradient is the sum of its T uses: `layers.train_step` hands the objective
+the matrices cast to the compute type once, so the T cotangents meet in that
+type before the float32 master sees their sum (`tests/test_ouro.py` has the
+reading).
 
 `jax.named_scope`s (`models/layers.py:SCOPES`): embed, norm,
 attention/{qkv,kernel,out}, ffn/dense, head_and_loss, exit_gate,
@@ -51,7 +52,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.layers import (
     dense_ffn,
-    head_and_row_losses,
+    head_and_weighted_loss,
     named,
     num_params,  # noqa: F401  (`ouro.num_params` is public)
     rms_norm,
@@ -83,7 +84,7 @@ class OuroConfig:
     # feed-forward's gate and up), those the chip has room for over all the
     # calls (`layers.checkpoint_layer`)
     remat: bool = True
-    # rows of the head's logits alive at once (`layers.chunked_xent_rows`):
+    # rows of the head's logits alive at once (`layers.chunked_xent`):
     # the n_walk heads of 16,384 tokens are 65,536 rows of 49,152 logits
     loss_chunk_rows: int = 2048
 
@@ -203,20 +204,24 @@ def loss_fn(params, batch, cfg: OuroConfig):
     over the tokens of sum_t p_t CE_t - beta H(p).  `parts`: "loss" the
     objective, "xent" (T,) each walk's mean cross-entropy, "exit" (T,) the
     mean exit distribution, "entropy" the mean H(p).  The T heads' logits
-    are made `cfg.loss_chunk_rows` rows at a time and never all held."""
+    are made `cfg.loss_chunk_rows` rows at a time, once, and never all
+    held: p goes into the head's loss as its rows' weights, so the head
+    forms its gradient as it walks, and the gradient reaches the gate
+    through the weights; the rows' losses it hands back are for `parts`
+    and carry none."""
     tokens = batch["tokens"]
     states, log_p = hidden(params, tokens[:, :-1], cfg)
     targets = jnp.broadcast_to(tokens[:, 1:], states.shape[:-1])
-    xent = head_and_row_losses(states, params["lm_head"], targets,
-                               cfg.loss_chunk_rows)            # (T, B, S)
     with jax.named_scope("exit_gate"):
         p = jnp.exp(log_p)
-        entropy = -jnp.sum(p * log_p, axis=0)
-        loss = jnp.mean(jnp.sum(p * xent, axis=0)
-                        - cfg.entropy_weight * entropy)
+    weighted, xent = head_and_weighted_loss(
+        states, params["lm_head"], targets, p, cfg.loss_chunk_rows)
+    with jax.named_scope("exit_gate"):
+        entropy = jnp.mean(-jnp.sum(p * log_p, axis=0))
+        loss = weighted / tokens[:, 1:].size - cfg.entropy_weight * entropy
         return loss, {"loss": loss, "xent": jnp.mean(xent, axis=(1, 2)),
                       "exit": jnp.mean(p, axis=(1, 2)),
-                      "entropy": jnp.mean(entropy)}
+                      "entropy": entropy}
 
 
 def make_train_step(cfg: OuroConfig, optimizer):

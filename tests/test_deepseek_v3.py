@@ -210,9 +210,16 @@ def test_three_steps_match_the_reference_program_and_the_bias_moves_by_rule():
 
 
 def test_bfloat16_train_step_tracks_the_reference_and_a_tripled_rate_does_not():
+    """Three bfloat16 steps against the float32 reference.  The second and
+    third losses stand behind AdamW's first updates, which divide every
+    gradient by its own size, so a rounding anywhere moves them: read here,
+    step by step, 0.0009, 0.0083, 0.0062 with the head's logits made twice
+    (until PR 53; the CPU's product took d logits in float32, which the
+    MXU never did) and 0.0009, 0.0118, 0.0049 since the head rounds
+    d logits to bfloat16 itself before its two products."""
     want = reference_steps()
     got, _, _ = system_steps(BF16)
-    assert max(abs(g - w) for g, w in zip(got, want)) < 0.01, (got, want)
+    assert max(abs(g - w) for g, w in zip(got, want)) < 0.015, (got, want)
     tripled, _, _ = system_steps(BF16, lr=3e-3)
     assert max(abs(g - w) for g, w in zip(tripled, want)) > 0.05
 

@@ -280,6 +280,10 @@ def test_the_budget_reckons_every_visit_of_a_layer():
 
 @pytest.mark.parametrize("chunk_rows", [8, 12, 1000])
 def test_the_rows_losses_are_the_dense_cross_entropies(chunk_rows):
+    """The weighted form of the head's loss, as the objective calls it: the
+    rows it hands back, and the gradient of their weighted sum with respect
+    to the states, the head and the WEIGHTS (which is how the gate learns),
+    against dense logits."""
     V, E = 48, 16
     keys = jax.random.split(jax.random.PRNGKey(4), 4)
     x = jax.random.normal(keys[0], (3, 2, 16, E))
@@ -291,20 +295,28 @@ def test_the_rows_losses_are_the_dense_cross_entropies(chunk_rows):
         logp = jax.nn.log_softmax(x @ head["kernel"], axis=-1)
         return -jnp.take_along_axis(logp, targets[..., None], -1)[..., 0]
 
-    def got(x, head):
-        return layers.head_and_row_losses(x, head, targets, chunk_rows)
+    def got(x, head, weights):
+        return layers.head_and_weighted_loss(x, head, targets, weights,
+                                             chunk_rows)
 
-    assert got(x, head).shape == targets.shape
-    assert max_diff(got(x, head), dense(x, head)) < 1e-5
-    weighted = lambda f: lambda x, head: jnp.sum(weights * f(x, head))
+    rows = got(x, head, weights)[1]
+    assert rows.shape == targets.shape
+    assert max_diff(rows, dense(x, head)) < 1e-5
+    mean = lambda f: lambda x, head, w: f(x, head, w) / targets.size
     for g, want in zip(
-            jax.tree.leaves(jax.grad(weighted(got), (0, 1))(x, head)),
-            jax.tree.leaves(jax.grad(weighted(dense), (0, 1))(x, head))):
+            jax.tree.leaves(jax.grad(mean(
+                lambda *a: got(*a)[0]), (0, 1, 2))(x, head, weights)),
+            jax.tree.leaves(jax.grad(mean(
+                lambda x, head, w: jnp.sum(w * dense(x, head))),
+                (0, 1, 2))(x, head, weights))):
         assert max_diff(g, want) < 1e-5
+    # the rows carry no gradient, and say so: nothing comes through them
+    through_rows = jax.grad(lambda x: jnp.sum(got(x, head, weights)[1]))(x)
+    assert max_diff(through_rows, jnp.zeros_like(x)) == 0.0
     # summed, they are what `head_and_loss` gives
-    assert float(jnp.mean(got(x, head)[0])) == pytest.approx(float(
+    assert float(jnp.mean(rows[0])) == pytest.approx(float(
         layers.head_and_loss(x[0], head, targets[0], chunk_rows)), rel=1e-6)
-    lowered = jax.jit(got).lower(x, head).as_text(debug_info=True)
+    lowered = jax.jit(got).lower(x, head, weights).as_text(debug_info=True)
     assert "head_and_loss" in lowered
 
 

@@ -2,7 +2,8 @@
 model: in the lowered step's text with debug info, every matmul, grouped
 matmul, Mosaic kernel and convolution has an `op_name` under a scope of the
 vocabulary, no other scope appears, `rematted_computation` appears exactly
-when `remat` is on, and the flash kernels' four forms are told apart.
+when `remat` is on, the chunked loss makes a chunk's three products in its
+forward walk and none again, and the flash kernels' four forms are told apart.
 
 The step is lowered for the platform `tpu` (no chip needed: the Mosaic
 kernels become `tpu_custom_call`s at lowering time), so the text holds what
@@ -238,16 +239,16 @@ def test_no_scope_outside_the_vocabulary(name, remat):
 @pytest.mark.parametrize("name,remat", CASES)
 def test_rematted_computation_exactly_under_remat(name, remat):
     """A recomputed layer's operations say so, which is what the reader's
-    `remat_fwd` phase goes by; the chunked loss recomputes its logits
-    whatever `remat` says, inside `head_and_loss`."""
+    `remat_fwd` phase goes by.  The chunked loss replays nothing, whatever
+    `remat` says: a chunk's logits are made once, and the two products of
+    their gradient stand beside them in the forward walk (PR 53)."""
     found = full_names(lowered_text(name, remat),
                        re.compile(r"stablehlo\.dot_general"))
     replayed = {scope(full) for _, full in found
                 if scope_trace.phase_of(full) == "remat_fwd"}
-    # and an indexer makes a block's products again in its own backward
-    # pass (`ops/sparse_index.py:index_scores`), whatever `remat` says
-    layer_scopes = {s for s in replayed if s not in (
-        "head_and_loss", "attention/indexer/scores")}
+    # an indexer makes a block's products again in its own backward pass
+    # (`ops/sparse_index.py:index_scores`), whatever `remat` says
+    layer_scopes = replayed - {"attention/indexer/scores"}
     if remat:
         # a layer of ONE mixer ends in W_o's product, which no backward
         # reads: its replay stops at the kernel
@@ -255,8 +256,17 @@ def test_rematted_computation_exactly_under_remat(name, remat):
         assert layer_scopes >= {last}, replayed
     else:
         assert not layer_scopes, replayed
+    assert "head_and_loss" not in replayed
     phases = {scope_trace.phase_of(full) for _, full in found}
     assert {"fwd", "bwd"} <= phases
+    if name in ("gpt2", "gpt2_moe"):
+        return      # dense logits: their product, and its two transposes
+    # the six families of the chunked loss: one loop over the chunks, whose
+    # body holds a chunk's three products, all of the forward pass
+    head = [full for _, full in found if scope(full) == "head_and_loss"]
+    assert len(head) == 3, head
+    assert {scope_trace.phase_of(full) for full in head} == {"fwd"}
+    assert all("while/body" in full for full in head)
 
 
 def _kernel_names(fn, *shapes, dtype=jnp.bfloat16):

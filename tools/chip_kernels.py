@@ -5,6 +5,7 @@ reference, with the device time of its forward and of its backward.
     python tools/chip_kernels.py --sweep    # tile -> ms at the cells' shapes
     python tools/chip_kernels.py --sweep s512-d64   # at the named shapes only
     python tools/chip_kernels.py --cases moe_held_8k   # the named cases only
+    python tools/chip_kernels.py --cases head_loss_8k  # the head's loss alone
 
 One case on each side of the gates in ``ops/flash_attention.py``: the lane
 kernels with the one-kernel backward (GPT-2 124M's heads), the transposing
@@ -57,6 +58,17 @@ seconds each took to compile, and the largest error of the scores and of the gra
 w relative to the reference on float32 operands at the highest precision; the
 cotangent is zero off 2,048 selected keys a query, as the loss's is.
 ``--sweep scores-8k`` times the kernels at other tiles.
+
+``head_loss_8k`` is the head and its chunked loss alone
+(`models/layers.py:head_and_loss`, an untied head) at the cells' shape: 16,384
+rows of 2,048, chunks of 2,048, a vocabulary of 49,152 (ouro's a walk) and of
+50,304 (OLMoE's): device ms of the loss alone (nobody differentiates: one
+product a chunk) and of its value and gradient (three products a chunk in one
+walk), beside the least time of those products' operations at the chip's peak,
+the longest operations of the gradient by name, and the largest error of the
+loss and of both gradients relative to float32 logits at the highest
+precision.  No cell runs it: it is the instrument for what the head's walk
+costs beyond its three products.
 
 ``ssd_8k`` is the chunked state-space scan alone (`ops/ssd.py:ssd_scan`) at
 (2, 8192, 64 heads of 64) with 8 groups and a state of 128, in bfloat16 and
@@ -153,6 +165,10 @@ SWEEP = {
     # Nemotron-3-Nano's: 32 query heads on 2 key/value heads of 128
     "gqa16-8k": ((2, 8192, 32, 128), LONG_TILES),
 }
+# (rows, E, vocabularies, rows a chunk) of one head and its loss
+HEAD_CASES = {
+    "head_loss_8k": (16384, 2048, (49152, 50304), 2048),
+}
 TOLERANCE = 0.05
 
 
@@ -213,6 +229,79 @@ def busy_ms(f, *args, calls=3):
         busy += max(0.0, start + duration - max(start, until))
         until = max(until, start + duration)
     return round(busy / calls / 1e6, 4)
+
+
+def longest_ops(f, *args, calls=3, top=6):
+    """{operation: device ms a call} of the ``top`` longest operations of
+    jitted ``f`` by name, a name's events summed.  A name here is the
+    instruction's whole text: its own name and its result's type, the
+    first 96 characters, tell it apart."""
+    by_name = {}
+    for name, _, duration in _device_events(f, args, calls):
+        by_name[name[:96]] = by_name.get(name[:96], 0) + duration
+    return {name: round(ns / calls / 1e6, 4) for name, ns in sorted(
+        by_name.items(), key=lambda item: -item[1])[:top]}
+
+
+def head_case(name, dtype):
+    """The head and its chunked loss at ``HEAD_CASES[name]``: a line for
+    each vocabulary (loss-only ms; value-and-gradient ms in x and the
+    head; the least time of one and of three products of rows x E x V at
+    the chip's peak; the gradient's longest operations; errors against
+    float32 logits made chunk by chunk at the highest precision)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import layers
+
+    N, E, vocabularies, chunk_rows = HEAD_CASES[name]
+    for V in vocabularies:
+        ks = jax.random.split(jax.random.PRNGKey(V), 3)
+        x = jax.random.normal(ks[0], (1, N, E), dtype)
+        head = {"kernel": (0.02 * jax.random.normal(ks[1], (E, V))).astype(
+            dtype)}
+        targets = jax.random.randint(ks[2], (1, N), 0, V)
+        loss = jax.jit(lambda x, head: layers.head_and_loss(
+            x, head, targets, chunk_rows))
+        grad = jax.jit(jax.value_and_grad(lambda x, head: layers.head_and_loss(
+            x, head, targets, chunk_rows), (0, 1)))
+
+        @jax.jit
+        def exact(x, head):
+            """float32 operands, the highest precision, a chunk's dense
+            logits at a time: its gradients summed by jax."""
+            def chunk(xi, ti, kernel):
+                logp = jax.nn.log_softmax(jnp.matmul(
+                    xi, kernel, precision="highest"), axis=-1)
+                return -jnp.sum(jnp.take_along_axis(
+                    logp, ti[:, None], -1)) / N
+            xf = x.astype(jnp.float32).reshape(-1, chunk_rows, E)
+            kernel = head["kernel"].astype(jnp.float32)
+            total, dx, dk = 0.0, [], jnp.zeros_like(kernel)
+            for xi, ti in zip(xf, targets.reshape(-1, chunk_rows)):
+                part, (dxi, dki) = jax.value_and_grad(chunk, (0, 2))(
+                    xi, ti, kernel)
+                total, dk = total + part, dk + dki
+                dx.append(dxi)
+            return total, (jnp.concatenate(dx).reshape(x.shape),
+                           {"kernel": dk})
+
+        rel = lambda g, w: round(float(
+            np.max(np.abs(np.asarray(g, np.float32) - np.asarray(w)))
+            / np.max(np.abs(np.asarray(w)))), 5)
+        got, want = grad(x, head), exact(x, head)
+        product_ms = 2 * N * E * V / 197e12 * 1e3
+        yield {"case": name, "form": f"vocab_{V}", "rows": N, "chunk_rows":
+               chunk_rows,
+               "loss_ms": busy_ms(loss, x, head),
+               "loss_and_grad_ms": busy_ms(grad, x, head),
+               "least_loss_ms": round(product_ms, 4),
+               "least_loss_and_grad_ms": round(3 * product_ms, 4),
+               "longest_ops_ms": longest_ops(grad, x, head),
+               "rel_err": {what: rel(g, w) for what, g, w in zip(
+                   ("loss", "dx", "dhead"), jax.tree.leaves(got),
+                   jax.tree.leaves(want))}}
 
 
 def moe_case(name, dtype):
@@ -756,19 +845,21 @@ def main():
                              f"({', '.join(SWEEP)}; none named: at all)")
     parser.add_argument("--cases", nargs="+", metavar="CASE",
                         default=[*CASES, *MOE_CASES, *SHORTCONV_CASES,
-                                 *SSD_CASES, *TARGET_CASES, *SCORES_CASES],
+                                 *SSD_CASES, *TARGET_CASES, *SCORES_CASES,
+                                 *HEAD_CASES],
                         help=f"run these only ({', '.join(CASES)}, "
                              f"{', '.join(MOE_CASES)}, "
                              f"{', '.join(SHORTCONV_CASES)}, "
                              f"{', '.join(SSD_CASES)}, "
                              f"{', '.join(TARGET_CASES)}, "
-                             f"{', '.join(SCORES_CASES)}; default: all)")
+                             f"{', '.join(SCORES_CASES)}, "
+                             f"{', '.join(HEAD_CASES)}; default: all)")
     args = parser.parse_args()
     swept = [*SWEEP, *SSD_SWEEP, *TARGET_SWEEP, *SCORES_SWEEP]
     if args.sweep and set(args.sweep) - set(swept):
         parser.error(f"--sweep: no such shape in {sorted(swept)}")
     known = [*CASES, *MOE_CASES, *SHORTCONV_CASES, *SSD_CASES, *TARGET_CASES,
-             *SCORES_CASES]
+             *SCORES_CASES, *HEAD_CASES]
     if set(args.cases) - set(known):
         parser.error(f"--cases: no such case in {known}")
 
@@ -884,6 +975,14 @@ def main():
                 and line["above_diagonal_is_neg_inf"] \
                 and line["fwd_mosaic_kernels"] + line["bwd_mosaic_kernels"] \
                 == (2 if line["form"] == "kernel" else 0)
+            if not ok:
+                failed.append(f"{name}:{line['form']}")
+            print(json.dumps({**line, "ok": ok,
+                              "device_kind": dev.device_kind}), flush=True)
+    for name in HEAD_CASES:
+        for line in head_case(name, jnp.bfloat16) \
+                if name in args.cases else ():
+            ok = max(line["rel_err"].values()) < TOLERANCE
             if not ok:
                 failed.append(f"{name}:{line['form']}")
             print(json.dumps({**line, "ok": ok,
