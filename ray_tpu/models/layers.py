@@ -31,7 +31,11 @@ standing in `ffn/moe`; `head_and_loss`, also around
 `head_and_weighted_loss`; the flash kernels' forms; a looped model's
 `exit_gate` (the gates, the exit distribution and its entropy: the model
 writes it; the weighting of the rows' losses is the head's, under
-`head_and_loss`); `train_step`'s `optimizer_update`).  `norm` is a layer's
+`head_and_loss`); a block-diffusion model's `diffusion` (the draw of the
+step's noise, the masking of the tokens, the two kinds of row laid side by
+side and taken apart, the rows' weights: everything that objective adds
+outside the kernels, the trunk's products and the head);
+`train_step`'s `optimizer_update`).  `norm` is a layer's
 norm on the residual stream: one inside an operator (a norm over a head, the
 latent's) stands in that operator's scope and counts there.
 """
@@ -87,6 +91,7 @@ SCOPES = (
     "ffn/moe/shared",
     "head_and_loss",
     "exit_gate",
+    "diffusion",
     "optimizer_update",
     "routing_bias_update",
 )
@@ -827,10 +832,19 @@ def cast_weights(params, dtype):
         if x.dtype == jnp.float32 and x.ndim >= 2 else x, params)
 
 
-def train_step(objective, optimizer, compute_dtype, rule=None):
+def train_step(objective, optimizer, compute_dtype, rule=None,
+               counted=False):
     """train_step(params, opt_state, batch) -> (params, opt_state, out) for
     ``objective(cast_params, batch) -> (scalar, out)`` — jit it with the
     appropriate shardings and ``donate_argnums=(0, 1)``.
+
+    ``counted``: the objective asks for the step's number and is called
+    ``objective(cast_params, batch, count)``, count the optimizer's own
+    count of the updates made so far, as ``opt_state`` holds it (int32, 0
+    in the first step): what a step that draws its own noise folds into its
+    key, so that the noise is a function of the run's seed and the step and
+    a resumed run draws what the unbroken one would.  An objective that
+    does not ask is called as it was and its step is what it was.
 
     Mixed precision: f32 master params; the objective sees the weight tree
     cast to ``compute_dtype`` once (see cast_weights), autodiff flows back
@@ -845,8 +859,10 @@ def train_step(objective, optimizer, compute_dtype, rule=None):
 
     # its name is the compiled module's (`jit_train_step`) in every trace
     def train_step(params, opt_state, batch):
+        count = (_update_count(opt_state),) if counted else ()
+
         def cast_objective(p):
-            return objective(cast_weights(p, compute_dtype), batch)
+            return objective(cast_weights(p, compute_dtype), batch, *count)
 
         # what a recomputed stack inside cannot see and its budget needs
         with _telling(state_bytes=state_bytes(params, opt_state,
@@ -861,6 +877,15 @@ def train_step(objective, optimizer, compute_dtype, rule=None):
         return params, opt_state, out
 
     return train_step
+
+
+def _update_count(opt_state):
+    """The first `count` an optax state holds: the updates made so far."""
+    import optax
+
+    (_, count), *_ = optax.tree_utils.tree_get_all_with_path(opt_state,
+                                                             "count")
+    return count
 
 
 def num_params(params) -> int:

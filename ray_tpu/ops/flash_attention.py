@@ -27,6 +27,19 @@ explicitly).  Non-causal calls have nothing to skip and take the whole
 sequence (1024-capped) as one tile; the backward past `_WHOLE_SEQ_MAX`
 takes 512-tiles either way.
 
+``causal`` is a RULE of static integers, of which the diagonal is one
+(`BlockRule`): positions in blocks, a block seeing itself whole, and,
+block diffusion's training form, two kinds of row a sequence (L clean rows,
+then their L noised copies: a noised row attends the clean rows of earlier
+blocks and the noised rows of its own).  Which tiles a rule empties, which
+it leaves whole and which it crosses is worked out from the tiles' indices
+when the step is traced (`_crossed`, walked by rows in `_k_spans` and by
+columns in `_q_spans`): an empty tile is never fetched or multiplied, a
+whole tile is not masked, and a crossed tile's mask is made in the kernel
+from its offsets (`_rule_mask`: block indices compared), never read.
+``causal=True`` is the rule at a block of 1 with one kind of row and
+compiles to the kernel it always was.
+
 Up to S = `_WHOLE_SEQ_MAX` a grid step takes a whole (b, h) slice (a
 128-lane group in the lane layout) and every extent inside it is static,
 so nothing loops: the tiles of a row (forward) or column (backward) that
@@ -80,9 +93,10 @@ whatever the mask holds of it.  The lane layout declines a mask as it
 declines grouped queries, and the call takes the head-major kernels.  A
 call without a mask is the program it was: no operand, no instruction.
 
-Each kernel adds its tiles and the heads it reads to the job timeline as
-the step is traced (`attention.tiles`, `attention.tiles_skipped`,
-`attention.q_heads`, `attention.kv_heads`: see `_count_tiles`).
+Each kernel adds its tiles, the pairs of those it visits and the heads it
+reads to the job timeline as the step is traced (`attention.tiles`,
+`attention.tiles_skipped`, `attention.pairs_visited`, `attention.q_heads`,
+`attention.kv_heads`: see `_count_tiles`).
 
 On non-TPU backends the same kernels run in interpret mode for tiny shapes
 (tests), and a pure-XLA reference path is used otherwise.
@@ -92,7 +106,7 @@ from __future__ import annotations
 
 import functools
 import warnings
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -137,8 +151,10 @@ KEPT_RESIDUALS = ("flash_attention.o", "flash_attention.lse")
 # `tf_op`) then says besides forward / backward.  The head-major forward (a
 # grid step a q tile, or the whole sequence) and the lane layout's; the
 # one-kernel backward of each layout (head-major: a grid step the whole
-# sequence, or a k tile of it).
-KERNEL_FORMS = ("fwd_rows", "fwd_lanes", "bwd_fused", "bwd_fused_lanes")
+# sequence, or a k tile of it); the two head-major ones again under a rule
+# of blocks or of two kinds of row (`_form`).
+KERNEL_FORMS = ("fwd_rows", "fwd_lanes", "bwd_fused", "bwd_fused_lanes",
+                "fwd_rows_blocks", "bwd_fused_blocks")
 
 
 # Mosaic's default limit of scoped VMEM on a v5e, what one tile's
@@ -226,12 +242,125 @@ def _tile_loop(first, last, block, body, carry):
         first, last, lambda i, c: body(i * block, block, c), carry)
 
 
-def _causal_mask(s, q_start, k_start):
-    """Scores of rows q_start.. against keys k_start.., keys after the
-    row's own position at -inf."""
-    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+class BlockRule(NamedTuple):
+    """Which keys a query attends, as a RULE of static integers: the tiles
+    it empties are known when the step is traced and are never fetched, the
+    tiles it leaves whole are not masked, and a tile it crosses is masked in
+    the kernel from the tile's offsets.  Nothing of it is an operand.
+
+    ``block``: positions come in blocks of this many, and a query attends
+    the keys of no later block than its own, its own block whole (1: the
+    diagonal, what ``causal=True`` is).
+
+    ``kinds`` = 2, block diffusion's training form: the S rows are L = S / 2
+    CLEAN rows and then L NOISED rows, row L + i the noised copy of
+    position i.  A clean query attends clean keys as above and no noised
+    key; a noised query attends the clean keys of STRICTLY earlier blocks
+    and the noised keys of its own block.  L (L + block) pairs of the
+    (2 L)^2."""
+    block: int = 1
+    kinds: int = 1
+
+
+CAUSAL = BlockRule()
+
+
+def _rule(causal) -> Optional[BlockRule]:
+    """A call's ``causal`` (False, True or a `BlockRule`) as a rule, None
+    where every pair is attended."""
+    if isinstance(causal, BlockRule):
+        return causal
+    return CAUSAL if causal else None
+
+
+def _int(flag):
+    """A comparison of tile indices as 0 or 1, whether they are Python's
+    (every extent static) or the grid's."""
+    return int(flag) if isinstance(flag, bool) else flag.astype(jnp.int32)
+
+
+def _crossed(tile, rows, cols):
+    """[first, last) of the tiles of ``cols`` positions that share a
+    position with tile ``tile`` of ``rows`` positions: those a rule's
+    diagonal crosses, whichever way the square is walked (a q tile's k
+    tiles, a k tile's q tiles).  The one classification: tiles before
+    ``first`` lie wholly on one side of the diagonal and tiles from ``last``
+    wholly on the other (a rule's blocks divide the tiles:
+    `_tiling_problem`)."""
+    return (tile * rows) // cols, pl.cdiv((tile + 1) * rows, cols)
+
+
+def _k_spans(rule, qi, block_q, block_k, seq_len):
+    """What q tile ``qi`` (a Python int, or the grid's) visits of the k
+    tiles -> (the tile's index among its own kind's, whether its rows are
+    noised (0 or 1), [(first, last, how)]): runs of k tiles, ``how`` None
+    where the rule attends every pair (no mask), "upto" where it crosses
+    the tiles among the clean keys and "own" among the noised keys of the
+    rows' own blocks.  Every other tile is empty and in no run."""
+    n = seq_len // block_k
+    if rule is None:
+        return qi, 0, [(0, n, None)]
+    if rule.kinds == 1:
+        first, last = _crossed(qi, block_q, block_k)
+        return qi, 0, [(0, first, None), (first, last, "upto")]
+    half_q, half_k = seq_len // 2 // block_q, n // 2
+    noised = _int(qi >= half_q)
+    at = qi - noised * half_q
+    first, last = _crossed(at, block_q, block_k)
+    return at, noised, [
+        (0, first, None), (first, last, "upto"),
+        (half_k + first, half_k + first + noised * (last - first), "own")]
+
+
+def _q_spans(rule, kj, block_q, block_k, seq_len):
+    """`_k_spans` the other way: what k tile ``kj`` is visited by, of the q
+    tiles -> (the tile's index among its own kind's, [(first, last, how,
+    whether those q rows are noised)])."""
+    n = seq_len // block_q
+    if rule is None:
+        return kj, [(0, n, None, 0)]
+    if rule.kinds == 1:
+        first, below = _crossed(kj, block_k, block_q)
+        return kj, [(first, below, "upto", 0), (below, n, None, 0)]
+    half_q, half_k = n // 2, seq_len // 2 // block_k
+    noised = _int(kj >= half_k)
+    clean = 1 - noised
+    at = kj - noised * half_k
+    first, below = _crossed(at, block_k, block_q)
+    crossed, rest = below - first, half_q - below
+    noised_first = half_q + first
+    return at, [
+        (first, first + clean * crossed, "upto", 0),
+        (below, below + clean * rest, None, 0),
+        (noised_first, noised_first + clean * crossed, "upto", 1),
+        (noised_first, noised_first + noised * crossed, "own", 1),
+        (half_q + below, half_q + below + clean * rest, None, 1)]
+
+
+def _rule_mask(s, rule, q_start, k_start, how, strict=0):
+    """Scores of the rows at positions q_start.. against the keys at
+    positions k_start.. (each within its own kind's rows), the pairs the
+    rule leaves out at -inf.  ``how`` "upto": keys of no later block than
+    the row's, or, ``strict`` (a noised row's clean keys), of an earlier
+    one; "own": keys of the row's own block.  At a block of 1 the block of
+    a position is the position (the diagonal's compare, on the tile's
+    shape); else a column of row blocks is compared with a row of key
+    blocks, the division made on those and not on the tile."""
+    rows, cols = s.shape
+    if rule.block == 1:
+        q_at = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_at = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    else:
+        block = jnp.int32(rule.block)
+        q_at = jax.lax.div(q_start + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0), block)
+        k_at = jax.lax.div(k_start + jax.lax.broadcasted_iota(
+            jnp.int32, (1, cols), 1), block)
+    if how == "own":
+        return jnp.where(q_at == k_at, s, _NEG_INF)
+    if rule.kinds == 2:
+        q_at = q_at - strict
+    return jnp.where(q_at >= k_at, s, _NEG_INF)
 
 
 def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
@@ -253,9 +382,10 @@ def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
         the p@v dot anyway, and max-subtraction bounds the error).
 
     q: (block_q, d) with scale folded, base-2 units; ``qi`` its tile
-    index.  ``over`` walks the k blocks (`_fwd_rows`): `_span` takes the
-    interior blocks as one span and the diagonal's as another, a pass of
-    two steps at most; `_tile_loop` loops over them.  read_k/read_v:
+    index.  ``over`` walks the runs of k blocks the rule leaves the tile
+    (`_k_spans`; `_fwd_rows`): `_span` takes the interior blocks as one span
+    and the diagonal's as another, a pass of two steps at most (three under
+    two kinds of row); `_tile_loop` loops over them.  read_k/read_v:
     (start, rows) -> (rows, d) of k and (rows, v_dim) of v, which may be
     another width (latent attention: 192 and 128).  Returns (acc f32
     (block_q, v_dim), m, l).
@@ -266,9 +396,9 @@ def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
     garbage, which its first attended key's alpha = 0 wipes; every row of a
     mask attends a key."""
 
-    num_k_blocks = seq_len // block_k
+    rule = _rule(causal)
 
-    def body(start, rows, carry, masked):
+    def body(start, rows, carry, how):
         acc, m_prev, l_prev = carry
         k = read_k(start, rows)
         v = read_v(start, rows)
@@ -276,8 +406,10 @@ def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (bq, rows) f32
-        if masked:
-            s = _causal_mask(s, qi * block_q, start)
+        if how:
+            s = _rule_mask(
+                s, rule, at * block_q,
+                start - seq_len // 2 if how == "own" else start, how, noised)
         if select is not None:
             s = jnp.where(select(start, rows) != 0, s, _NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -296,17 +428,13 @@ def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
         jnp.full((block_q, 1), _NEG_INF, jnp.float32),
         jnp.zeros((block_q, 1), jnp.float32),
     )
-    first_diag = last = num_k_blocks
-    if causal:
-        # interior blocks (strictly below the diagonal): no mask.
-        # blocks intersecting the diagonal band: masked body.  The blocks
-        # divide the sequence, so `last` stays within num_k_blocks.
-        first_diag = (qi * block_q) // block_k
-        last = pl.cdiv((qi + 1) * block_q, block_k)
-    carry = over(0, first_diag, block_k,
-                 functools.partial(body, masked=False), carry)
-    return over(first_diag, last, block_k,
-                functools.partial(body, masked=True), carry)
+    # the runs of k blocks the rule leaves this q tile: those it attends
+    # whole take the unmasked body, those it crosses the masked one
+    at, noised, spans = _k_spans(rule, qi, block_q, block_k, seq_len)
+    for first, last, how in spans:
+        carry = over(first, last, block_k, functools.partial(body, how=how),
+                     carry)
+    return carry
 
 
 def _finish_fwd(acc, m, l, out_dtype):
@@ -365,6 +493,7 @@ def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # also serves the dk dot (rescaled by ln2 at the end), and ds's
     # trailing *sm_scale is hoisted onto dq.
     scale = jnp.asarray(sm_scale * _LOG2E, q_ref.dtype)
+    rule = _rule(causal)
 
     @_when(step == 0)
     def _():
@@ -376,7 +505,7 @@ def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[k_rows, cols]
         v = v_ref[k_rows, cols]
 
-        def q_span(start, rows, carry, masked):
+        def q_span(start, rows, carry, how, noised):
             dk_acc, dv_acc = carry
             q_rows = pl.ds(start, rows)
             q = q_ref[q_rows, cols] * scale
@@ -386,8 +515,10 @@ def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)   # (rows, bk) f32
-            if masked:
-                s = _causal_mask(s, start, kj * block_k)
+            if how:
+                s = _rule_mask(
+                    s, rule, start - seq_len // 2 if noised else start,
+                    at * block_k, how, noised)
             if selection:
                 mask_ref = selection[0]
                 chosen = mask_ref[q_rows, k_rows] if steps == 1 \
@@ -411,23 +542,26 @@ def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         acc = (jnp.zeros((block_k, k.shape[-1]), jnp.float32),
                jnp.zeros((block_k, v.shape[-1]), jnp.float32))
-        first = below = 0
-        if causal:
-            # the blocks divide the sequence, so `below` stays within the
-            # q tiles
-            first = (kj * block_k) // block_q
-            below = pl.cdiv((kj + 1) * block_k, block_q)
-        acc = over(first, below, block_q,
-                   functools.partial(q_span, masked=True), acc)
-        dk_acc, dv_acc = over(
-            below, seq_len // block_q, block_q,
-            functools.partial(q_span, masked=False), acc)
+        # the runs of q tiles the rule has visit this k tile, the crossed
+        # ones masked
+        at, spans = _q_spans(rule, kj, block_q, block_k, seq_len)
+        for first, last, how, noised in spans:
+            acc = over(first, last, block_q, functools.partial(
+                q_span, how=how, noised=noised), acc)
+        dk_acc, dv_acc = acc
         dk_ref[k_rows, cols] = (dk_acc * (1.0 / _LOG2E)).astype(dk_ref.dtype)
         dv_ref[k_rows, cols] = dv_acc.astype(dv_ref.dtype)
 
     @_when(step == steps - 1)
     def _():
         dq_ref[:, cols] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+
+
+def _form(name, causal):
+    """A head-major kernel's scope (`KERNEL_FORMS`): a name of its own
+    under a rule of blocks or of two kinds of row, so that a trace tells
+    those kernels from the diagonal's."""
+    return name if _rule(causal) in (None, CAUSAL) else f"{name}_blocks"
 
 
 def _kernel_call(*form):
@@ -590,7 +724,7 @@ def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, whole,
             S, D, Dv, q.dtype,
             extra=_mask_block_bytes(S, rows) if masks else 0),
     )
-    with jax.named_scope("fwd_rows"):
+    with jax.named_scope(_form("fwd_rows", causal)):
         o, lse = call(qf, kf, vf, *masks)
     return o.reshape(B, H, S, Dv), lse.reshape(B, H, S)
 
@@ -646,7 +780,7 @@ def _pallas_backward(q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
             S, D, Dv, q.dtype, bwd_steps=steps,
             extra=_mask_block_bytes(S, k_rows) if masks else 0),
     )
-    with jax.named_scope("bwd_fused"):
+    with jax.named_scope(_form("bwd_fused", causal)):
         dq, dk, dv = call(qf, kf, vf, dof, lsef, delta, *masks)
 
     dq = dq.reshape(B, H, S, D)
@@ -802,13 +936,27 @@ def _repeat_groups(q, k, v):
     return jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
 
 
+def _attended(rule, S):
+    """The (S, S) pairs a rule attends, written out (the reference's): row
+    r is of kind r // L at position r % L, L = S / kinds.  The diagonal's
+    is the lower triangle it always was (a causal call's jaxpr is held to
+    what it lowered to: `tests/test_gqa_flash.py`)."""
+    if rule == CAUSAL:
+        return jnp.tril(jnp.ones((S, S), bool))
+    row = jnp.arange(S)
+    L = S // rule.kinds
+    noised, block = row // L, row % L // rule.block
+    return jnp.where(noised[None] == 0,
+                     block[None] <= block[:, None] - noised[:, None],
+                     (noised[:, None] == 1) & (block[None] == block[:, None]))
+
+
 def reference_attention(q, k, v, sm_scale, causal, mask=None):
     k, v = _repeat_groups(q, k, v)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * sm_scale
     if causal:
-        S = q.shape[2]
-        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, _NEG_INF)
+        s = jnp.where(_attended(_rule(causal), q.shape[2]), s, _NEG_INF)
     if mask is not None:
         s = jnp.where(mask[:, None] != 0, s, _NEG_INF)
     lse = jax.scipy.special.logsumexp(s, axis=-1)
@@ -824,8 +972,7 @@ def _reference_backward(q, k, v, lse, do, delta, sm_scale, causal,
     dof = do.astype(jnp.float32)
     s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) * sm_scale
     if causal:
-        S = q.shape[2]
-        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, _NEG_INF)
+        s = jnp.where(_attended(_rule(causal), q.shape[2]), s, _NEG_INF)
     if mask is not None:
         s = jnp.where(mask[:, None] != 0, s, _NEG_INF)
     p = jnp.exp(s - lse[..., None])
@@ -844,10 +991,18 @@ class AttentionFallbackWarning(UserWarning):
     on every platform, the TPU included."""
 
 
-def _tiling_problem(S, block_q, block_k, held=0) -> Optional[str]:
+def _tiling_problem(S, block_q, block_k, held=0, rule=None) -> Optional[str]:
     """Why the Pallas kernels cannot tile S with these blocks, or None.
     ``held``: the bytes a kernel keeps in VMEM for all S rows beside its
-    tiles (`_bwd_held_bytes`)."""
+    tiles (`_bwd_held_bytes`).  ``rule``: its kinds of row and its blocks
+    must end where tiles end, which is what lets a tile be classed from its
+    index (`_crossed`)."""
+    L = S // rule.kinds if rule else S
+    if rule not in (None, CAUSAL) and (
+            S % rule.kinds or L % block_q or L % block_k
+            or block_q % rule.block or block_k % rule.block):
+        return (f"the tiles do not divide the rule's {rule.kinds} kinds of "
+                f"row into blocks of {rule.block}")
     if held + _TILE_VMEM > _VMEM_MAX:
         # Mosaic would refuse it: "Ran out of memory in memory space vmem"
         return ("a (batch, head) slice's q, do, statistics and dq leave a "
@@ -858,7 +1013,8 @@ def _tiling_problem(S, block_q, block_k, held=0) -> Optional[str]:
     # sub-tile steps loses to the dense path, and sub-8-sublane blocks risk
     # Mosaic compile errors.  Whole-sequence blocks (bq == S) stay allowed
     # for short-sequence/decode shapes.
-    if (block_q < 128 and block_q != S) or (block_k < 128 and block_k != S):
+    if (block_q < 128 and block_q not in (S, L)) \
+            or (block_k < 128 and block_k not in (S, L)):
         return "the blocks are narrower than one 128-row tile"
     return None
 
@@ -880,7 +1036,7 @@ def _auto_block(S: int, cap: int) -> int:
     return max(b, 1)
 
 
-def _auto_tiles(S: int, causal: bool):
+def _auto_tiles(S: int, causal):
     """((block_q, block_k) of the forward, the same of the backward) for a
     call that names no blocks: a function of what a call can see of itself.
     Non-causal attention has no tile to skip and takes the largest block,
@@ -902,16 +1058,27 @@ def _auto_tiles(S: int, causal: bool):
     1,024 by a twentieth).  So 512: the fastest at 4,096 and within 0.5 %
     of 1,024 at 8,192.  The forward: 5.27 / 2.95 / 3.25, 22.24 / 13.09 /
     13.76, 19.37 / 9.94 / 10.42: 512 would be 5 to 9 % faster than the
-    largest block, which it takes, under 0.7 % of a step and left."""
-    whole = _auto_block(S, 1024)
+    largest block, which it takes, under 0.7 % of a step and left.  Under
+    a rule of two kinds of row (S = 2 x 8,192, 32 on 4 heads of 128, blocks
+    of 4: PERF.md §6, PR 54) the forward at 512 / 1,024 took 21.81 / 23.68
+    ms a layer and forward + backward 60.53 / 62.40 (backward 512 in
+    both): 512-tiles visit 288 of the square's 1,024, 0.889 of the visited
+    pairs attended, 1,024-tiles 80 of 256, 0.800, and there the forward
+    takes 512."""
+    rule = _rule(causal)
+    L = S // rule.kinds if rule else S      # tiles divide a kind's rows
+    whole = _auto_block(L, 1024)
     if S > _WHOLE_SEQ_MAX:
-        bwd = _auto_block(S, 512)
-        return (whole, whole), (bwd, bwd)
-    if not causal:
+        bwd = _auto_block(L, 512)
+        # two kinds of row leave a quarter of the square: there the
+        # forward's smaller tile pays
+        fwd = bwd if rule and rule.kinds == 2 else whole
+        return (fwd, fwd), (bwd, bwd)
+    if rule is None:
         return (whole, whole), (whole, whole)
 
     def tile(cap):
-        b = _auto_block(S, cap)
+        b = _auto_block(L, cap)
         return b if b >= 128 else whole  # under 128 rows is no tile
 
     fwd, bwd = tile(512), tile(256)
@@ -934,20 +1101,24 @@ def _resolve(q, S, causal, sm_scale, block_q, block_k):
 def _count_tiles(S, block_q, block_k, causal, heads):
     """Add one kernel's tiles to the job timeline, as the step is traced:
     `attention.tiles` the (block_q x block_k) tiles of the S x S score
-    square, `attention.tiles_skipped` those of them wholly above the
-    diagonal, which a causal kernel does not visit.  Once per kernel in the
-    traced program (not per head slice or grid step).  Beside them
-    ``heads``: those of the q the kernel is given and of the k it reads
-    from HBM (`attention.q_heads`, `attention.kv_heads`): a quarter where
-    four query heads share a key/value head, equal where a caller repeated
-    k and v first."""
+    square, `attention.tiles_skipped` those of them the rule empties (under
+    the diagonal: those wholly above it), which the kernel does not visit,
+    `attention.pairs_visited` the (query, key) pairs of the tiles it does
+    visit, a head and sequence: what the kernel multiplies, whatever the
+    rule attends of it.  Once per kernel in the traced program (not per
+    head slice or grid step).  Beside them ``heads``: those of the q the
+    kernel is given and of the k it reads from HBM (`attention.q_heads`,
+    `attention.kv_heads`): a quarter where four query heads share a
+    key/value head, equal where a caller repeated k and v first."""
     tracing.count("attention.q_heads", heads[0])
     tracing.count("attention.kv_heads", heads[1])
-    num_q, num_k = S // block_q, S // block_k
-    skipped = sum(num_k - min(num_k, pl.cdiv((i + 1) * block_q, block_k))
-                  for i in range(num_q)) if causal else 0
-    tracing.count("attention.tiles", num_q * num_k)
-    tracing.count("attention.tiles_skipped", skipped)
+    rule, tiles = _rule(causal), (S // block_q) * (S // block_k)
+    visited = sum(last - first for i in range(S // block_q)
+                  for first, last, _ in _k_spans(rule, i, block_q, block_k,
+                                                 S)[2])
+    tracing.count("attention.tiles", tiles)
+    tracing.count("attention.tiles_skipped", tiles - visited)
+    tracing.count("attention.pairs_visited", visited * block_q * block_k)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -1021,7 +1192,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, mask=None):
                                   causal=causal)
     problem = _tiling_problem(
         S, bq, bk, 0 if mask is None else _mask_block_bytes(
-            S, S if whole else bq))
+            S, S if whole else bq), _rule(causal))
     if problem:
         _warn_reference(q.shape, bq, bk, problem)
         o, lse = reference(q, k, v, mask=mask)
@@ -1058,7 +1229,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, do, delta=None):
                                            q.dtype)
     if mask is not None:
         held += _mask_block_bytes(S, S if whole else bk)
-    problem = _tiling_problem(S, bq, bk, held)
+    problem = _tiling_problem(S, bq, bk, held, _rule(causal))
     if problem:
         _warn_reference(q.shape, bq, bk, problem)
         return _reference_backward(q, k, v, lse, do, delta, scale, causal,
@@ -1087,10 +1258,10 @@ def _flash_bwd_rule(causal, sm_scale, block_q, block_k, res, do):
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def _bshd_lanes_ok(q, S, bq, bk):
+def _bshd_lanes_ok(q, S, bq, bk, causal=False):
     B, _, H, D = q.shape
     return (_lanes_config(H, D) is not None and S % 128 == 0
-            and _tiling_problem(S, bq, bk) is None)
+            and _tiling_problem(S, bq, bk, rule=_rule(causal)) is None)
 
 
 def _tr(x):
@@ -1123,7 +1294,7 @@ def _flash_fwd_bshd(q, k, v, causal, sm_scale, block_q, block_k, mask=None):
     # the lane layout slices every operand's heads out of the same lanes,
     # and reads no mask
     if mask is None and k.shape == v.shape == q.shape \
-            and _bshd_lanes_ok(q, S, bq, bk):
+            and _bshd_lanes_ok(q, S, bq, bk, causal):
         _count_tiles(S, bq, bk, causal, (q.shape[2], k.shape[2]))
 
         def reference(q, k, v):
@@ -1151,7 +1322,7 @@ def _flash_bwd_bshd(causal, sm_scale, block_q, block_k, res, do):
     scale, whole, _, (bq, bk) = _resolve(q, S, causal, sm_scale, block_q,
                                          block_k)
     if whole and not mask and k.shape == v.shape == q.shape \
-            and _bshd_lanes_ok(q, S, bq, bk):
+            and _bshd_lanes_ok(q, S, bq, bk, causal):
         _count_tiles(S, bq, bk, causal, (q.shape[2], k.shape[2]))
 
         def reference(q, k, v, o, lse, do):

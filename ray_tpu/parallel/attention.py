@@ -14,6 +14,7 @@ import functools
 import jax
 
 from ray_tpu.ops.flash_attention import (
+    BlockRule,
     flash_attention_bshd,
     flash_attention_bshd_lse,
     reference_attention,
@@ -27,12 +28,17 @@ def _tr(x):
     return x.transpose(0, 2, 1, 3)
 
 
-def attention(q, k, v, *, causal: bool = True, variant: str = "flash",
+def attention(q, k, v, *, causal=True, variant: str = "flash",
               mask=None, with_lse: bool = False):
     """Multi-head attention over (batch, seq, heads, head_dim) arrays, at
     head_dim^-1/2.  k and v may have fewer heads than q, a divisor of its
     count (grouped queries: head h reads key/value head h // group); the
     kernels read them as they are and nothing repeats them.
+
+    ``causal``: True, the diagonal; False, every pair; or a rule of blocks
+    and kinds of row (`ops/flash_attention.py:BlockRule`: block diffusion's
+    clean and noised rows), whose empty tiles the kernels never visit;
+    "flash" and "dense" only.
 
     ``mask``: (batch, seq, seq) int8, not 0 where a (query, key) pair is
     attended, the same for all heads of a sequence; with ``causal`` a pair
@@ -57,6 +63,12 @@ def attention(q, k, v, *, causal: bool = True, variant: str = "flash",
             f"attention(variant={variant!r}, with_lse=True): the row "
             f"statistics are the flash kernels' (use \"flash\")")
     if variant in ("ring", "ulysses"):
+        if isinstance(causal, BlockRule):
+            raise NotImplementedError(
+                f"attention(variant={variant!r}) takes no {causal}: a rule "
+                f"of blocks is not written for the sequence-parallel "
+                f"kernels, whose chunks know the diagonal alone (use "
+                f"\"flash\")")
         if mask is not None:
             raise NotImplementedError(
                 f"attention(variant={variant!r}) takes no mask: a mask "

@@ -33,6 +33,7 @@ from ray_tpu.models import (
     nemotron_h,
     olmoe,
     ouro,
+    sdar,
 )
 from ray_tpu.ops import flash_attention as fa
 from ray_tpu.ops.moe import trained_by
@@ -46,6 +47,7 @@ MODELS = {
     "nemotron_h": (nemotron_h, nemotron_h.NEMOTRON_H_TINY),
     "keye_vl": (keye_vl, keye_vl.KEYE_VL_TINY),
     "ouro": (ouro, ouro.OURO_TINY),
+    "sdar": (sdar, sdar.SDAR_TINY),
 }
 CASES = [(name, remat) for name in MODELS for remat in (False, True)]
 # what every model's step must have a matmul under
@@ -73,6 +75,9 @@ EXPECTED = {
                 "head_and_loss"},
     "ouro": {"attention/qkv", "attention/out", "ffn/dense", "head_and_loss",
              "exit_gate"},
+    "sdar": {"attention/qkv", "attention/kernel/fwd_rows_blocks",
+             "attention/kernel/bwd_fused_blocks", "attention/out",
+             "ffn/moe/route", "ffn/moe/experts", "head_and_loss"},
 }
 # components of an `op_name` that jax puts there itself (`jnp.einsum` its
 # subscripts: `ops/ssd.py`'s products)
@@ -96,7 +101,9 @@ def lowered_text(name: str, remat: bool) -> str:
     optimizer = optax.adamw(1e-4)
     if module in (deepseek_v3, lfm2_moe, nemotron_h):
         optimizer = trained_by(optimizer)
-    step = module.make_train_step(cfg, optimizer)
+    # a step that draws its own noise is built with the run's seed
+    step = module.make_train_step(cfg, optimizer,
+                                  *((0,) if module is sdar else ()))
     params = jax.eval_shape(lambda key: module.init_params(key, cfg),
                             jax.random.PRNGKey(0))
     opt_state = jax.eval_shape(optimizer.init, params)
@@ -197,6 +204,12 @@ def test_every_matmul_and_kernel_is_under_a_scope(name, remat):
         assert "attention/indexer/select" in every
     if name != "ouro":
         assert "exit_gate" not in every
+    # the draw, the masking and the rows' weights of block diffusion; the
+    # diagonal's kernels are not in its step, nor the rule's in another's
+    assert ("diffusion" in every) is (name == "sdar")
+    assert ("attention/kernel/fwd_rows_blocks" in every) is (name == "sdar")
+    if name == "sdar":
+        assert "attention/kernel/fwd_rows" not in every
 
 
 @pytest.mark.parametrize("remat", [False, True])
@@ -309,7 +322,8 @@ def test_no_split_backward_remains():
     import inspect
 
     assert fa.KERNEL_FORMS == ("fwd_rows", "fwd_lanes", "bwd_fused",
-                               "bwd_fused_lanes")
+                               "bwd_fused_lanes", "fwd_rows_blocks",
+                               "bwd_fused_blocks")
     assert [s for s in layers.SCOPES if s.startswith("attention/kernel/")] \
         == [f"attention/kernel/{form}" for form in fa.KERNEL_FORMS]
     source = inspect.getsource(fa) + inspect.getsource(layers)

@@ -28,6 +28,7 @@ from ray_tpu.models import (
     nemotron_h,
     olmoe,
     ouro,
+    sdar,
 )
 from ray_tpu.ops import moe
 from ray_tpu.ops.moe import ROUTING_BIAS
@@ -518,6 +519,7 @@ TRUNK_FAMILIES = {
     "lfm2_moe": (lfm2_moe, lfm2_moe.LFM2_MOE_TINY),
     "nemotron_h": (nemotron_h, nemotron_h.NEMOTRON_H_TINY),
     "keye_vl": (keye_vl, keye_vl.KEYE_VL_TINY),
+    "sdar": (sdar, sdar.SDAR_TINY),
 }
 
 
@@ -525,18 +527,21 @@ def family_case(module, cfg):
     params = module.init_params(jax.random.PRNGKey(3), cfg)
     batch = {"tokens": jax.random.randint(
         jax.random.PRNGKey(4), (B, 65), 0, cfg.vocab_size)}
+    # an objective that draws its own noise takes the run's key and the
+    # step's number
+    noise = (sdar.noise_key(5), 0) if module is sdar else ()
 
     def run():
         return jax.jit(jax.value_and_grad(
             lambda p: module.loss_fn(layers.cast_weights(
-                p, cfg.compute_dtype), batch, cfg)[0]))(params)
+                p, cfg.compute_dtype), batch, cfg, *noise)[0]))(params)
     return run
 
 
 @pytest.mark.parametrize("remat", [False, True])
 @pytest.mark.parametrize("family", sorted(TRUNK_FAMILIES))
 def test_a_model_that_walks_once_is_what_it_was(family, remat, monkeypatch):
-    """The loss and every gradient of the five `trunk` families at their
+    """The loss and every gradient of the six `trunk` families at their
     test sizes, in their compute type, through `layers.trunk` as it is and
     as the parent had it: bit for bit.  (Until PR 53 the head was the
     parent's bit for bit as well; since then it forms its gradient in its
@@ -596,7 +601,7 @@ HEAD_TOL = {"float32": 1e-5, "bfloat16": 2 ** -4}
 @pytest.mark.parametrize("compute", sorted(HEAD_TOL))
 @pytest.mark.parametrize("family", sorted(HEAD_FAMILIES))
 def test_the_head_is_what_it_was_to_a_rounding(family, compute, monkeypatch):
-    """The six families' losses and gradients through the one chunked loss
+    """The seven families' losses and gradients through the one chunked loss
     and through the parent's (`jax.checkpoint` a chunk, the logits made
     twice), computing in float32 and in bfloat16: the loss to 1e-6, every
     gradient to `HEAD_TOL`."""
@@ -604,7 +609,7 @@ def test_the_head_is_what_it_was_to_a_rounding(family, compute, monkeypatch):
     run = family_case(module, dataclasses.replace(
         cfg, compute_dtype=jnp.dtype(compute).type))
     loss, grads = run()
-    if family == "ouro":
+    if family in ("ouro", "sdar"):     # the rows weighted
         monkeypatch.setattr(module, "head_and_weighted_loss",
                             parent_head_and_weighted_loss)
     else:

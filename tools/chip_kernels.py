@@ -106,7 +106,11 @@ CASES = {
     "gqa16_8k": ((2, 8192, 32, 128), 2),
 }
 # key/value heads of the cases and sweeps whose k and v have fewer than q
-KV_HEADS = {"gqa_8k": 8, "gqa-8k": 8, "gqa16_8k": 2, "gqa16-8k": 2}
+KV_HEADS = {"gqa_8k": 8, "gqa-8k": 8, "gqa16_8k": 2, "gqa16-8k": 2,
+            "blocks-16k": 4}
+# (block, kinds) of the sweeps under a rule that is not the diagonal
+# (`ops/flash_attention.py:BlockRule`)
+RULES = {"blocks-16k": (4, 2)}
 # (B, S, H, P, G, N, chunk) of one state-space scan
 SSD_CASES = {
     "ssd_8k": (2, 8192, 64, 64, 8, 128, 128),
@@ -164,6 +168,10 @@ SWEEP = {
     "gqa-8k": ((2, 8192, 32, 64), LONG_TILES),
     # Nemotron-3-Nano's: 32 query heads on 2 key/value heads of 128
     "gqa16-8k": ((2, 8192, 32, 128), LONG_TILES),
+    # SDAR-30B-A3B's block diffusion: 2 x 8,192 clean rows and their noised
+    # copies under the rule of blocks of 4, 32 query heads on 4 of 128
+    "blocks-16k": ((2, 16384, 32, 128), ((256, 256), (512, 512),
+                                         (1024, 1024), (1024, 512))),
 }
 # (rows, E, vocabularies, rows a chunk) of one head and its loss
 HEAD_CASES = {
@@ -454,11 +462,11 @@ def _buffer_of(experts, weights, held, n_experts, C):
 
 
 def time_passes(shape, dtype, block_q=None, block_k=None, kv_heads=None,
-                repeated=False, every_op=False):
+                repeated=False, every_op=False, causal=True):
     """(forward ms, backward ms) on the device of the kernels of causal
-    ``flash_attention_bshd`` at ``shape`` with these tiles (None:
-    ``_auto_tiles``); k and v with ``kv_heads`` heads, ``repeated`` to q's
-    before the call.  ``every_op``: of every operation of the two passes
+    ``flash_attention_bshd`` (or under the rule ``causal`` is) at ``shape``
+    with these tiles (None: ``_auto_tiles``); k and v with ``kv_heads``
+    heads, ``repeated`` to q's before the call.  ``every_op``: of every operation of the two passes
     (the transposes to the head-major kernels, the sums of a group's parts
     of dk and dv), not of the kernels alone."""
     import jax
@@ -470,9 +478,9 @@ def time_passes(shape, dtype, block_q=None, block_k=None, kv_heads=None,
     if repeated:
         k, v = (jnp.repeat(t, shape[2] // kv_heads, axis=2) for t in (k, v))
     forward = jax.jit(lambda q, k, v: fa._flash_fwd_bshd(
-        q, k, v, True, None, block_q, block_k))
+        q, k, v, causal, None, block_q, block_k))
     backward = jax.jit(lambda res, do: fa._flash_bwd_bshd(
-        True, None, block_q, block_k, res, do))
+        causal, None, block_q, block_k, res, do))
     o, res = forward(q, k, v)
     ms = busy_ms if every_op else kernel_ms
     return ms(forward, q, k, v), ms(backward, res, o)
@@ -868,6 +876,7 @@ def main():
 
     from ray_tpu.ops.flash_attention import (
         AttentionFallbackWarning,
+        BlockRule,
         _auto_tiles,
     )
     from ray_tpu.ops.ssd import SsdFallbackWarning
@@ -901,13 +910,15 @@ def main():
                             "device_kind": dev.device_kind}), flush=True)
                 continue
             shape, blocks = SWEEP[name]
+            causal = BlockRule(*RULES[name]) if name in RULES else True
             for block in ((None, None),) + blocks:
                 fwd_ms, bwd_ms = time_passes(shape, jnp.bfloat16, *block,
-                                             kv_heads=KV_HEADS.get(name))
+                                             kv_heads=KV_HEADS.get(name),
+                                             causal=causal)
                 print(json.dumps({
                     "sweep": name, "shape": shape,
                     "tile": block if block[0] else _auto_tiles(
-                        shape[1], True),
+                        shape[1], causal),
                     "fwd_ms": fwd_ms, "bwd_ms": bwd_ms,
                     "device_kind": dev.device_kind}), flush=True)
         return
