@@ -13,7 +13,8 @@ has.
 
 Imports jax, the names of the flash kernels' residuals and forms
 (`ops/flash_attention.py`, which every model imports through
-`parallel/attention.py` anyway) and, of the runtime, only the job
+`parallel/attention.py` anyway), the gated norm's kernels
+(`ops/gated_norm.py`) and, of the runtime, only the job
 timeline's counters (`util/tracing.py`, which imports nothing heavy): a
 worker pays nothing for it before its first step.
 
@@ -50,6 +51,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.ops import gated_norm
 from ray_tpu.ops.flash_attention import KEPT_RESIDUALS, KERNEL_FORMS
 from ray_tpu.ops.moe import ROUTE_NAME, moe_dispatch
 from ray_tpu.parallel.context import get_mesh
@@ -341,12 +343,12 @@ def gated_rms_norm(y, z, p, groups, eps=1e-5):
     """Mamba-2's `MambaRMSNormGated`, y and z (..., C): the gate first,
     y * silu(z), THEN an RMSNorm over each of the ``groups`` runs of
     C / groups channels, times the gain ``p["scale"]`` (C,).  Statistics
-    in float32; the result in y's type."""
-    g = _f32(y) * jax.nn.silu(_f32(z))
-    parts = g.reshape(*g.shape[:-1], groups, g.shape[-1] // groups)
-    parts = parts * jax.lax.rsqrt(
-        jnp.mean(jnp.square(parts), axis=-1, keepdims=True) + eps)
-    return (parts.reshape(g.shape) * p["scale"]).astype(y.dtype)
+    in float32; the result in y's type.  z may come as the first C columns
+    of a wider array, which are then read where they lie.
+    `ops/gated_norm.py` has the rule: one Mosaic kernel a pass where a
+    group fills whole lane tiles and the rows divide into a tile, the plain
+    jax form elsewhere."""
+    return gated_norm.gated_rms_norm(y, z, p["scale"], groups, eps)
 
 
 # What a recomputed layer may keep besides its attention kernel's residuals:

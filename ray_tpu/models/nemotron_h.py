@@ -63,8 +63,10 @@ routed layer, the walk over the layers, the head and its chunked loss, the
 mixed-precision step and its place for state that moves by a rule),
 `parallel/attention.py` (the flash kernels, 16 query heads on each
 key/value head), `ops/moe.py` (dispatch over a share of the experts, the
-sigmoid router, its account and its bias rule) and `ops/ssd.py`; the names
-are those `parallel/sharding.py` lays out.
+sigmoid router, its account and its bias rule), `ops/ssd.py` (the scan's
+kernels) and, through `gated_rms_norm`, `ops/gated_norm.py` (the gate and the
+groups' norm behind the scan, one Mosaic kernel a pass at the published
+widths); the names are those `parallel/sharding.py` lays out.
 
 `jax.named_scope`s (`models/layers.py:SCOPES`): embed, norm,
 ssm/{in_proj,conv,scan,gate_norm,out_proj}, attention/{qkv,kernel,out},
@@ -261,7 +263,7 @@ def _mamba(u, p, cfg: NemotronHConfig):
     with jax.named_scope("in_proj"):
         zxbcdt = named(u @ p["in_proj"]["kernel"].astype(u.dtype),
                        "ssm/in_proj")
-        z, xbc, dt = jnp.split(zxbcdt, [HP, HP + cfg.conv_width], axis=-1)
+        _, xbc, dt = jnp.split(zxbcdt, [HP, HP + cfg.conv_width], axis=-1)
     with jax.named_scope("conv"):
         xbc = causal_conv(xbc, p["conv"], jax.nn.silu)
         x, Bm, Cm = jnp.split(xbc, [HP, HP + G * N], axis=-1)
@@ -272,7 +274,9 @@ def _mamba(u, p, cfg: NemotronHConfig):
             Bm.reshape(B, S, G, N), Cm.reshape(B, S, G, N), p["D"],
             cfg.chunk_size), "ssm/scan").reshape(B, S, HP)
     with jax.named_scope("gate_norm"):
-        y = gated_rms_norm(y, z, p["norm"], G, cfg.rms_eps)
+        # z where it lies, W_in's first HP columns: sliced out for a kernel
+        # it cost a copy a layer and pass (PERF.md §6, PR 55)
+        y = gated_rms_norm(y, zxbcdt, p["norm"], G, cfg.rms_eps)
     with jax.named_scope("out_proj"):
         # the layer's last product: it is added to the stream and no
         # backward reads it, so it carries no name to keep

@@ -74,6 +74,11 @@ reference the kernels are timed and tested beside, and as what any platform
 but a TPU runs beyond the interpreter's sizes (`ops.by_platform`): XLA passes
 its (Q, Q) decays and scores through HBM, 2 GiB a layer and pass.
 
+What a Mamba-2 mixer does with y behind the scan, the gate by z and the
+groups' norm, is no part of these kernels: `ops/gated_norm.py` has a kernel
+a pass of its own over the same (b S, H P) rows, where a scan block's R P
+lanes are one norm group.
+
 Counts itself on the job timeline as the step is traced: `ssm.layers` (one
 a call), `ssm.kernel_layers` (one a call that took the kernels),
 `ssm.heads`, `ssm.state`, `ssm.chunk` (the sizes, not summed).

@@ -560,3 +560,26 @@ def test_the_rule_kernels_compile_for_the_chip_at_the_cells_shape(one_chip):
             .as_text()
     assert text.count("tpu_custom_call") >= 2
     assert "16384,16384" not in text
+
+
+def test_the_gated_norm_kernels_compile_for_the_chip_at_nemotrons_shape(
+        one_chip):
+    """Mosaic takes `ops/gated_norm.py`'s two kernels at the nemotron
+    cell's (2 x 8192, 4096) bfloat16 rows in 8 groups, with a row tile's
+    five blocks twice over in scoped VMEM; nothing runs.  Here and not in
+    `test_gated_norm.py`: one file of the suite, so one worker, loads the
+    TPU's compiler."""
+    from ray_tpu.ops import gated_norm as gn
+
+    rows = jax.ShapeDtypeStruct((16384, 4096), jnp.bfloat16,
+                                sharding=one_chip)
+    gain = jax.ShapeDtypeStruct((4096,), jnp.float32, sharding=one_chip)
+    static = (8, 1e-5, gn._row_tile(16384, 4096, 8))
+
+    def both(y, z, gain, dout):
+        out, vjp = jax.vjp(lambda *a: gn._kernels(*a, static), y, z, gain)
+        return out, vjp(dout)
+
+    text = jax.jit(both).lower(rows, rows, gain, rows).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "f32[16384,4096]" not in text        # nothing float32 in HBM

@@ -80,6 +80,17 @@ device ms forward and forward + backward, beside the least time of the
 scan's operations and bytes (`families/nemotron_h.py:ssd_cost`'s count for
 one layer).  ``--sweep ssd-chunk`` times all three at chunks of 64, 128 and
 256 (the published 128 is what the timed path runs) and compares nothing.
+
+``gatenorm_8k`` is Mamba-2's gated group norm alone
+(`ops/gated_norm.py:gated_rms_norm`) at the nemotron cell's shape, (2, 8192,
+4096) in 8 groups: the plain jax form (`_reference`), the groups' mean of
+squares as a product of g^2 with a (4096, 8) matrix of 0 and 1 and back (no
+re-laying to (..., 8, 512); tried and not kept), and the two Mosaic kernels
+at each row tile of ``GATENORM_TILES`` (``kept``: the one `_row_tile`
+chooses): device ms forward and forward + backward (one `jax.grad` in y, z
+and the gain), the seconds both took to compile, the least time of the
+bytes, and the largest error of the result and of the three gradients
+relative to the plain form on float32 operands.
 """
 
 from __future__ import annotations
@@ -123,6 +134,13 @@ SSD_SWEEP = {
 SHORTCONV_CASES = {
     "shortconv_8k": (2, 8192, 2048, 3),
 }
+# (B, S, C, groups) of one gated group norm, and the row tiles its kernels
+# are timed at (1,024 rows want 80 MiB of VMEM for the backward's five
+# blocks twice over, and the compiler refuses them under the kernels' 48)
+GATENORM_CASES = {
+    "gatenorm_8k": (2, 8192, 4096, 8),
+}
+GATENORM_TILES = (64, 128, 256, 512)
 # (T, k, held, experts, E, W): tokens, choices a token, experts held of the
 # router's, hidden and expert widths
 MOE_CASES = {
@@ -579,6 +597,87 @@ def shortconv_case(name, dtype):
            "least_fwd_bwd_ms": round(4 * flops / 197e12 * 1e3, 4)}
 
 
+def gatenorm_case(name, dtype):
+    """One gated group norm at ``GATENORM_CASES[name]``: a line for each
+    form of it (forward ms; forward and backward ms of one `jax.grad` in
+    y, z and the gain; the seconds both jits took to compile; the Mosaic
+    kernels in them; the least time of the bytes: forward reads y and z
+    and writes the result, backward reads three and writes two; largest
+    error of the result and of the gradients relative to the plain form on
+    float32 operands)."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import gated_norm as gn
+
+    B, S, C, G = GATENORM_CASES[name]
+    eps = 1e-5
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    y, z = (jax.random.normal(k, (B, S, C), dtype) for k in ks[:2])
+    gain = 1 + 0.3 * jax.random.normal(ks[2], (C,), jnp.float32)
+    seed = jax.random.normal(ks[3], (B, S, C), dtype)   # d loss / d out
+
+    def ones_matrix(y, z, gain):
+        """ROADMAP Queue 1 item 7 (a)'s other form: the statistics on the
+        MXU, nothing re-laid."""
+        g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        ones = jnp.repeat(jnp.eye(G, dtype=jnp.float32), C // G, axis=0)
+        r = jax.lax.rsqrt(jnp.dot(
+            g * g, ones, precision=jax.lax.Precision.HIGHEST) / (C // G)
+            + eps)
+        return (g * (r @ ones.T) * gain).astype(y.dtype)
+
+    def kernels(tile):
+        return lambda y, z, gain: gn._kernels(
+            y.reshape(B * S, C), z.reshape(B * S, C), gain,
+            (G, eps, tile)).reshape(y.shape)
+
+    plain = lambda y, z, gain: gn._reference(y, z, gain, G, eps)
+
+    def both(f):
+        return jax.jit(f), jax.jit(jax.value_and_grad(
+            lambda y, z, gain, seed: jnp.sum(
+                f(y, z, gain).astype(jnp.float32)
+                * seed.astype(jnp.float32)), (0, 1, 2)))
+
+    rel = lambda g, w: round(float(
+        np.max(np.abs(np.asarray(g, np.float32) - np.asarray(w)))
+        / np.max(np.abs(np.asarray(w)))), 5)
+    exact = both(plain)
+    f32 = (y.astype(jnp.float32), z.astype(jnp.float32), gain)
+    want = (exact[0](*f32), *exact[1](*f32, seed)[1])
+    kept = gn._row_tile(B * S, C, G)
+    peak = 819e9                       # HBM bytes a second, TPU v5e
+    array = B * S * C * jnp.dtype(dtype).itemsize
+    forms = [("plain", plain, None), ("ones_matrix", ones_matrix, None)] + [
+        ("kernel", kernels(tile), tile) for tile in GATENORM_TILES]
+    for form, f, tile in forms:
+        forward, grad = both(f)
+        began = time.perf_counter()
+        compiled = [forward.lower(y, z, gain).compile(),
+                    grad.lower(y, z, gain, seed).compile()]
+        line = {"case": name, "form": form,
+                "compile_s": round(time.perf_counter() - began, 2),
+                "mosaic_kernels": sum(c.as_text().count(
+                    'custom_call_target="tpu_custom_call"')
+                    for c in compiled),
+                "fwd_ms": busy_ms(forward, y, z, gain),
+                "fwd_bwd_ms": busy_ms(grad, y, z, gain, seed),
+                "least_fwd_ms": round(3 * array / peak * 1e3, 4),
+                "least_fwd_bwd_ms": round(8 * array / peak * 1e3, 4)}
+        if tile:
+            # the two kernels alone, without the loss that reads the result
+            line.update(tile=tile, kept=tile == kept,
+                        fwd_bwd_kernels_ms=kernel_ms(grad, y, z, gain, seed))
+        got = (forward(y, z, gain), *grad(y, z, gain, seed)[1])
+        line["rel_err"] = {what: rel(g, w) for what, g, w in zip(
+            ("out", "dy", "dz", "dgain"), got, want)}
+        yield line
+
+
 def target_case(name, dtype, tiles=None):
     """The indexer's loss at ``TARGET_CASES[name]``, one layer's of both
     sequences: a line for the fused Mosaic kernel (``tiles``: at these
@@ -854,20 +953,21 @@ def main():
     parser.add_argument("--cases", nargs="+", metavar="CASE",
                         default=[*CASES, *MOE_CASES, *SHORTCONV_CASES,
                                  *SSD_CASES, *TARGET_CASES, *SCORES_CASES,
-                                 *HEAD_CASES],
+                                 *HEAD_CASES, *GATENORM_CASES],
                         help=f"run these only ({', '.join(CASES)}, "
                              f"{', '.join(MOE_CASES)}, "
                              f"{', '.join(SHORTCONV_CASES)}, "
                              f"{', '.join(SSD_CASES)}, "
                              f"{', '.join(TARGET_CASES)}, "
                              f"{', '.join(SCORES_CASES)}, "
-                             f"{', '.join(HEAD_CASES)}; default: all)")
+                             f"{', '.join(HEAD_CASES)}, "
+                             f"{', '.join(GATENORM_CASES)}; default: all)")
     args = parser.parse_args()
     swept = [*SWEEP, *SSD_SWEEP, *TARGET_SWEEP, *SCORES_SWEEP]
     if args.sweep and set(args.sweep) - set(swept):
         parser.error(f"--sweep: no such shape in {sorted(swept)}")
     known = [*CASES, *MOE_CASES, *SHORTCONV_CASES, *SSD_CASES, *TARGET_CASES,
-             *SCORES_CASES, *HEAD_CASES]
+             *SCORES_CASES, *HEAD_CASES, *GATENORM_CASES]
     if set(args.cases) - set(known):
         parser.error(f"--cases: no such case in {known}")
 
@@ -996,6 +1096,17 @@ def main():
             ok = max(line["rel_err"].values()) < TOLERANCE
             if not ok:
                 failed.append(f"{name}:{line['form']}")
+            print(json.dumps({**line, "ok": ok,
+                              "device_kind": dev.device_kind}), flush=True)
+    for name in GATENORM_CASES:
+        for line in gatenorm_case(name, jnp.bfloat16) \
+                if name in args.cases else ():
+            # a kernel forward; forward and backward under the gradient
+            ok = max(line["rel_err"].values()) < TOLERANCE \
+                and line["mosaic_kernels"] == (
+                    3 if line["form"] == "kernel" else 0)
+            if not ok:
+                failed.append(f"{name}:{line['form']}:{line.get('tile')}")
             print(json.dumps({**line, "ok": ok,
                               "device_kind": dev.device_kind}), flush=True)
     if failed:
