@@ -45,10 +45,12 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import math
 import threading
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops import gated_norm
@@ -128,10 +130,16 @@ def rms_norm(x, p, eps=1e-5):
             * p["scale"].astype(x.dtype)
 
 
-def rope(x, positions, theta, interleaved=False):
+def rope(x, positions, theta, interleaved=False, scale=None):
     """x: (B, S, H, D); positions: (B, S) or (S,).  Dim i turns with dim
     i + D/2 by frequency i (rotate-half).  A caller that rotates a part of
     a head passes that part; a key part all heads share comes with H = 1.
+
+    ``theta``: the base, frequency i being theta^(-2i/D); or the D/2
+    frequencies themselves, which a caller computed (`yarn_frequencies`),
+    with ``scale`` the factor cos and sin are multiplied by (so q . k
+    carries its square).  Such a call is counted on the job timeline as
+    the step is traced (`rope.scaled`).
 
     ``interleaved``: the pairs that turn together are the adjacent (2i,
     2i+1).  They are taken apart first ([evens | odds]) and rotated as
@@ -141,12 +149,50 @@ def rope(x, positions, theta, interleaved=False):
     D = x.shape[-1]
     if interleaved:
         x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
-    freqs = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) / (D // 2))
+    if isinstance(theta, (int, float)):
+        freqs = theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) / (D // 2))
+    else:
+        tracing.count("rope.scaled")
+        freqs = jnp.asarray(theta, jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
-    cos = jnp.cos(angles)[..., None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[..., None, :].astype(x.dtype)
+
+    def table(turn):
+        t = turn(angles) if scale is None else turn(angles) * scale
+        return t[..., None, :].astype(x.dtype)
+
+    cos, sin = table(jnp.cos), table(jnp.sin)
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+
+
+def yarn_frequencies(dim, theta, factor, original_max_position, beta_fast=32,
+                     beta_slow=1, attention_factor=None):
+    """YaRN's rotary table (Peng et al., arXiv:2309.00071) as `rope` takes
+    it -> ((dim / 2,) float32 frequencies, the scale of cos and sin): a
+    pure function of a config's keys, numpy at the time the step is traced.
+
+    b_i = theta^(2i/dim).  A dim that turns ``n`` times over the
+    ``original_max_position`` positions the model was trained at sits at
+    d(n) = dim ln(original_max_position / (2 pi n)) / (2 ln theta); low =
+    floor(d(beta_fast)), high = ceil(d(beta_slow)), both within
+    [0, dim - 1]; r_i = clip((i - low) / (high - low), 0, 1).  Frequency i
+    is (1 - r_i) / b_i + r_i / (factor b_i): the fast dims as they were,
+    the slow ones stretched ``factor`` times, a ramp between.  The scale is
+    ``attention_factor``, 0.1 ln(factor) + 1 where the config gives none."""
+    half = dim // 2
+    base = theta ** (np.arange(half, dtype=np.float64) / half)
+
+    def turns(n):
+        return dim * math.log(original_max_position / (2 * math.pi * n)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 0.001), 0, 1)
+    freqs = (1 - ramp) / base + ramp / (factor * base)
+    if attention_factor is None:
+        attention_factor = 0.1 * math.log(factor) + 1.0
+    return freqs.astype(np.float32), float(attention_factor)
 
 
 def swiglu(x, gate, up, down, matmul=jnp.matmul):

@@ -38,7 +38,13 @@ columns in `_q_spans`): an empty tile is never fetched or multiplied, a
 whole tile is not masked, and a crossed tile's mask is made in the kernel
 from its offsets (`_rule_mask`: block indices compared), never read.
 ``causal=True`` is the rule at a block of 1 with one kind of row and
-compiles to the kernel it always was.
+compiles to the kernel it always was.  A WINDOW (`BlockRule(window=W)`: a
+row attends its W latest keys) is a second bound inside the same rule: a
+q tile's k tiles run from the first its window reaches, a k tile's q tiles
+end with the last whose window still holds it, and a tile is crossed by the
+window's bound, by the diagonal, by both or by neither (`_two_bounds`); the
+lower compare is `_rule_mask`'s too.  A model whose layers mix windowed
+and full attention pays for the pairs each attends, and no (S, S) mask.
 
 Up to S = `_WHOLE_SEQ_MAX` a grid step takes a whole (b, h) slice (a
 128-lane group in the lane layout) and every extent inside it is static,
@@ -81,8 +87,8 @@ running the forward kernel a second time.
 
 A mask that is DATA: ``mask`` (B, S, S) int8, one value a (query, key)
 pair, not 0 where the pair is attended, shared by all heads of a sequence
-(attention that selects its keys: `ops/sparse_index.py`; a window or a
-segment rule filling the same array).  It is an operand of the head-major
+(attention that selects its keys: `ops/sparse_index.py`; a segment rule
+filling the same array).  It is an operand of the head-major
 forward kernel and of the one backward kernel, combined with ``causal``.
 Up to `_WHOLE_SEQ_MAX` a grid step holds a sequence's whole (S, S) mask and
 slices it as it slices k.  Past it the mask comes tile-major (`_tile_major`:
@@ -96,7 +102,9 @@ call without a mask is the program it was: no operand, no instruction.
 Each kernel adds its tiles, the pairs of those it visits and the heads it
 reads to the job timeline as the step is traced (`attention.tiles`,
 `attention.tiles_skipped`, `attention.pairs_visited`, `attention.q_heads`,
-`attention.kv_heads`: see `_count_tiles`).
+`attention.kv_heads`, and under a window `attention.window_kernels`,
+`attention.window` and `attention.window_pairs_visited`: see
+`_count_tiles`).
 
 On non-TPU backends the same kernels run in interpret mode for tiny shapes
 (tests), and a pure-XLA reference path is used otherwise.
@@ -152,9 +160,10 @@ KEPT_RESIDUALS = ("flash_attention.o", "flash_attention.lse")
 # grid step a q tile, or the whole sequence) and the lane layout's; the
 # one-kernel backward of each layout (head-major: a grid step the whole
 # sequence, or a k tile of it); the two head-major ones again under a rule
-# of blocks or of two kinds of row (`_form`).
+# of blocks or of two kinds of row, and again under a window (`_form`).
 KERNEL_FORMS = ("fwd_rows", "fwd_lanes", "bwd_fused", "bwd_fused_lanes",
-                "fwd_rows_blocks", "bwd_fused_blocks")
+                "fwd_rows_blocks", "bwd_fused_blocks",
+                "fwd_rows_window", "bwd_fused_window")
 
 
 # Mosaic's default limit of scoped VMEM on a v5e, what one tile's
@@ -257,9 +266,16 @@ class BlockRule(NamedTuple):
     position i.  A clean query attends clean keys as above and no noised
     key; a noised query attends the clean keys of STRICTLY earlier blocks
     and the noised keys of its own block.  L (L + block) pairs of the
-    (2 L)^2."""
+    (2 L)^2.
+
+    ``window`` = W: a second bound a row.  A query attends the W latest
+    keys, its own among them (key j iff i - W < j <= i); None: no lower
+    bound.  For the diagonal with one kind of row (`_rule` refuses it
+    beside blocks or two kinds until a model needs that).  It need not
+    divide a tile or be divided by one."""
     block: int = 1
     kinds: int = 1
+    window: Optional[int] = None
 
 
 CAUSAL = BlockRule()
@@ -269,8 +285,20 @@ def _rule(causal) -> Optional[BlockRule]:
     """A call's ``causal`` (False, True or a `BlockRule`) as a rule, None
     where every pair is attended."""
     if isinstance(causal, BlockRule):
+        if causal.window is not None and (
+                causal.window < 1 or (causal.block, causal.kinds) != (1, 1)):
+            raise NotImplementedError(
+                f"{causal}: a window is a second bound of the diagonal with "
+                f"one kind of row (at least one key wide); blocks or two "
+                f"kinds of row under a window are not written, no model "
+                f"asks for them")
         return causal
     return CAUSAL if causal else None
+
+
+def _windowed(causal) -> bool:
+    """Whether a call's rule has a window."""
+    return isinstance(causal, BlockRule) and causal.window is not None
 
 
 def _int(flag):
@@ -290,16 +318,53 @@ def _crossed(tile, rows, cols):
     return (tile * rows) // cols, pl.cdiv((tile + 1) * rows, cols)
 
 
+def _floor0(x):
+    """max(x, 0), of a Python int or of the grid's."""
+    return max(x, 0) if isinstance(x, int) else jnp.maximum(x, 0)
+
+
+def _least(n, x):
+    """min(n, x), likewise."""
+    return min(n, x) if isinstance(x, int) else jnp.minimum(n, x)
+
+
+def _two_bounds(rule, block_q, block_k, before, after):
+    """The runs of tiles a window leaves a tile, in the order they are
+    walked: ``before`` and ``after`` are (first, last, how) of the tiles
+    each of its two bounds crosses (the window's bound "from", the
+    diagonal "upto"), the former starting and ending no later than the
+    latter.  Where a tile pair is wider than the window (W < block_q +
+    block_k - 1) the two ranges meet or overlap at every tile index, and
+    the tiles of both are one run with both compares; else they never
+    overlap and the tiles between them are whole.  Which of the two holds
+    is static, so a kernel has three runs either way."""
+    (a, b, first_how), (c, d, last_how) = before, after
+    if rule.window < block_q + block_k - 1:
+        return [(a, c, first_how), (c, b, "both"), (b, d, last_how)]
+    return [(a, b, first_how), (b, c, None), (c, d, last_how)]
+
+
 def _k_spans(rule, qi, block_q, block_k, seq_len):
     """What q tile ``qi`` (a Python int, or the grid's) visits of the k
     tiles -> (the tile's index among its own kind's, whether its rows are
     noised (0 or 1), [(first, last, how)]): runs of k tiles, ``how`` None
     where the rule attends every pair (no mask), "upto" where it crosses
     the tiles among the clean keys and "own" among the noised keys of the
-    rows' own blocks.  Every other tile is empty and in no run."""
+    rows' own blocks; under a window "from" where its bound crosses them
+    and "both" where that and the diagonal do (`_two_bounds`).  Every other
+    tile is empty and in no run."""
     n = seq_len // block_k
     if rule is None:
         return qi, 0, [(0, n, None)]
+    if rule.window is not None:
+        # the k tiles the window's bound crosses: from the one the tile's
+        # first row still reaches to the first its last row reaches whole
+        top = qi * block_q - rule.window
+        lower = (_floor0(top + 1) // block_k,
+                 pl.cdiv(_floor0(top + block_q), block_k), "from")
+        return qi, 0, _two_bounds(
+            rule, block_q, block_k, lower,
+            (*_crossed(qi, block_q, block_k), "upto"))
     if rule.kinds == 1:
         first, last = _crossed(qi, block_q, block_k)
         return qi, 0, [(0, first, None), (first, last, "upto")]
@@ -319,6 +384,16 @@ def _q_spans(rule, kj, block_q, block_k, seq_len):
     n = seq_len // block_q
     if rule is None:
         return kj, [(0, n, None, 0)]
+    if rule.window is not None:
+        # the q tiles the window's bound crosses: from the first whose
+        # last row no longer reaches the tile's first key to the first
+        # whose first row no longer reaches its last
+        end = kj * block_k + rule.window
+        lower = (_least(n, end // block_q),
+                 _least(n, pl.cdiv(end + block_k - 1, block_q)), "from")
+        return kj, [run + (0,) for run in _two_bounds(
+            rule, block_q, block_k,
+            (*_crossed(kj, block_k, block_q), "upto"), lower)]
     if rule.kinds == 1:
         first, below = _crossed(kj, block_k, block_q)
         return kj, [(first, below, "upto", 0), (below, n, None, 0)]
@@ -347,6 +422,17 @@ def _rule_mask(s, rule, q_start, k_start, how, strict=0):
     shape); else a column of row blocks is compared with a row of key
     blocks, the division made on those and not on the tile."""
     rows, cols = s.shape
+    if how in ("from", "both"):
+        # how far behind its row a key lies, against the window's width
+        # (and, a tile both bounds cross, against the diagonal's 0): the
+        # tiles' offsets go on the scalar side of the compares
+        behind = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
+            - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        apart = q_start - k_start
+        seen = behind < rule.window - apart
+        if how == "both":
+            seen &= behind >= -apart
+        return jnp.where(seen, s, _NEG_INF)
     if rule.block == 1:
         q_at = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         k_at = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -561,6 +647,8 @@ def _form(name, causal):
     """A head-major kernel's scope (`KERNEL_FORMS`): a name of its own
     under a rule of blocks or of two kinds of row, so that a trace tells
     those kernels from the diagonal's."""
+    if _windowed(causal):
+        return f"{name}_window"
     return name if _rule(causal) in (None, CAUSAL) else f"{name}_blocks"
 
 
@@ -944,6 +1032,9 @@ def _attended(rule, S):
     if rule == CAUSAL:
         return jnp.tril(jnp.ones((S, S), bool))
     row = jnp.arange(S)
+    if rule.window is not None:
+        behind = row[:, None] - row[None]
+        return (behind >= 0) & (behind < rule.window)
     L = S // rule.kinds
     noised, block = row // L, row % L // rule.block
     return jnp.where(noised[None] == 0,
@@ -996,9 +1087,9 @@ def _tiling_problem(S, block_q, block_k, held=0, rule=None) -> Optional[str]:
     ``held``: the bytes a kernel keeps in VMEM for all S rows beside its
     tiles (`_bwd_held_bytes`).  ``rule``: its kinds of row and its blocks
     must end where tiles end, which is what lets a tile be classed from its
-    index (`_crossed`)."""
+    index (`_crossed`); a window's width divides nothing and asks nothing."""
     L = S // rule.kinds if rule else S
-    if rule not in (None, CAUSAL) and (
+    if rule and (rule.block, rule.kinds) != (1, 1) and (
             S % rule.kinds or L % block_q or L % block_k
             or block_q % rule.block or block_k % rule.block):
         return (f"the tiles do not divide the rule's {rule.kinds} kinds of "
@@ -1064,15 +1155,24 @@ def _auto_tiles(S: int, causal):
     ms a layer and forward + backward 60.53 / 62.40 (backward 512 in
     both): 512-tiles visit 288 of the square's 1,024, 0.889 of the visited
     pairs attended, 1,024-tiles 80 of 256, 0.800, and there the forward
-    takes 512."""
+    takes 512.  Under a window (W = 1,024, one sequence, 32 on 4 heads of
+    128: PERF.md §6, PR 56; `tools/chip_kernels.py --cases mellum_16k
+    mellum_8k`) a tile is a trade of its own: a q tile of 256 visits 5 k
+    tiles (0.80 of the visited pairs attended), of 512 three (0.667), of
+    1,024 two (0.50), and the two crossed ones are masked whatever their
+    size.  ms a layer at 256 / 512 / 1,024: the forward 6.72 / 4.68 / 5.75
+    at S = 16,384 and 3.28 / 2.29 / 2.80 at 8,192; forward + backward
+    16.52 / 12.70 / 14.97 and 7.92 / 6.04 / 6.88 (the same call with no
+    window: 89.05 / 51.50 / 50.86 and 23.05 / 13.73 / 14.03).  So 512 in
+    both passes under a window."""
     rule = _rule(causal)
     L = S // rule.kinds if rule else S      # tiles divide a kind's rows
     whole = _auto_block(L, 1024)
     if S > _WHOLE_SEQ_MAX:
         bwd = _auto_block(L, 512)
-        # two kinds of row leave a quarter of the square: there the
-        # forward's smaller tile pays
-        fwd = bwd if rule and rule.kinds == 2 else whole
+        # two kinds of row leave a quarter of the square and a window a
+        # band of it: there the forward's smaller tile pays
+        fwd = bwd if rule and (rule.kinds == 2 or rule.window) else whole
         return (fwd, fwd), (bwd, bwd)
     if rule is None:
         return (whole, whole), (whole, whole)
@@ -1098,6 +1198,13 @@ def _resolve(q, S, causal, sm_scale, block_q, block_k):
         for bq, bk in _auto_tiles(S, causal))
 
 
+def _tiles_visited(rule, S, block_q, block_k):
+    """The tiles of the S x S score square a kernel under ``rule`` visits:
+    `_k_spans`' runs, summed over the q tiles."""
+    return sum(last - first for i in range(S // block_q)
+               for first, last, _ in _k_spans(rule, i, block_q, block_k, S)[2])
+
+
 def _count_tiles(S, block_q, block_k, causal, heads):
     """Add one kernel's tiles to the job timeline, as the step is traced:
     `attention.tiles` the (block_q x block_k) tiles of the S x S score
@@ -1113,12 +1220,15 @@ def _count_tiles(S, block_q, block_k, causal, heads):
     tracing.count("attention.q_heads", heads[0])
     tracing.count("attention.kv_heads", heads[1])
     rule, tiles = _rule(causal), (S // block_q) * (S // block_k)
-    visited = sum(last - first for i in range(S // block_q)
-                  for first, last, _ in _k_spans(rule, i, block_q, block_k,
-                                                 S)[2])
+    visited = _tiles_visited(rule, S, block_q, block_k)
     tracing.count("attention.tiles", tiles)
     tracing.count("attention.tiles_skipped", tiles - visited)
     tracing.count("attention.pairs_visited", visited * block_q * block_k)
+    if rule and rule.window is not None:
+        tracing.count("attention.window_kernels")
+        tracing.count("attention.window", rule.window)
+        tracing.count("attention.window_pairs_visited",
+                      visited * block_q * block_k)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -1261,6 +1371,7 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def _bshd_lanes_ok(q, S, bq, bk, causal=False):
     B, _, H, D = q.shape
     return (_lanes_config(H, D) is not None and S % 128 == 0
+            and not _windowed(causal)      # head-major only: `_form`
             and _tiling_problem(S, bq, bk, rule=_rule(causal)) is None)
 
 

@@ -35,15 +35,17 @@ def attention(q, k, v, *, causal=True, variant: str = "flash",
     count (grouped queries: head h reads key/value head h // group); the
     kernels read them as they are and nothing repeats them.
 
-    ``causal``: True, the diagonal; False, every pair; or a rule of blocks
-    and kinds of row (`ops/flash_attention.py:BlockRule`: block diffusion's
-    clean and noised rows), whose empty tiles the kernels never visit;
-    "flash" and "dense" only.
+    ``causal``: True, the diagonal; False, every pair; or a rule
+    (`ops/flash_attention.py:BlockRule`) of blocks and kinds of row (block
+    diffusion's clean and noised rows) or of a window (a row's W latest
+    keys: a model's sliding layers), whose empty tiles the kernels never
+    visit; "flash" and "dense" only.
 
     ``mask``: (batch, seq, seq) int8, not 0 where a (query, key) pair is
     attended, the same for all heads of a sequence; with ``causal`` a pair
     must pass both.  A mask that is data: attention that selects its keys
-    (`ops/sparse_index.py`), or a window or a segment rule filling it.
+    (`ops/sparse_index.py`), or a segment rule filling it (a window is a
+    rule: ``causal``).
     ``with_lse``: -> (o, the kernels' row statistics (batch, heads, seq)
     float32: the log of each query's sum of exp(score) over the keys it
     attends), which nothing is differentiated through; "flash" only.
@@ -66,9 +68,9 @@ def attention(q, k, v, *, causal=True, variant: str = "flash",
         if isinstance(causal, BlockRule):
             raise NotImplementedError(
                 f"attention(variant={variant!r}) takes no {causal}: a rule "
-                f"of blocks is not written for the sequence-parallel "
-                f"kernels, whose chunks know the diagonal alone (use "
-                f"\"flash\")")
+                f"of blocks or of a window is not written for the "
+                f"sequence-parallel kernels, whose chunks know the "
+                f"diagonal alone (use \"flash\")")
         if mask is not None:
             raise NotImplementedError(
                 f"attention(variant={variant!r}) takes no mask: a mask "

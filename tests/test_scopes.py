@@ -30,6 +30,7 @@ from ray_tpu.models import (
     keye_vl,
     layers,
     lfm2_moe,
+    mellum,
     nemotron_h,
     olmoe,
     ouro,
@@ -48,6 +49,7 @@ MODELS = {
     "keye_vl": (keye_vl, keye_vl.KEYE_VL_TINY),
     "ouro": (ouro, ouro.OURO_TINY),
     "sdar": (sdar, sdar.SDAR_TINY),
+    "mellum": (mellum, mellum.MELLUM_TINY),
 }
 CASES = [(name, remat) for name in MODELS for remat in (False, True)]
 # what every model's step must have a matmul under
@@ -78,6 +80,11 @@ EXPECTED = {
     "sdar": {"attention/qkv", "attention/kernel/fwd_rows_blocks",
              "attention/kernel/bwd_fused_blocks", "attention/out",
              "ffn/moe/route", "ffn/moe/experts", "head_and_loss"},
+    "mellum": {"attention/qkv", "attention/kernel/fwd_rows_window",
+               "attention/kernel/bwd_fused_window",
+               "attention/kernel/fwd_rows", "attention/kernel/bwd_fused",
+               "attention/out", "ffn/moe/route", "ffn/moe/experts",
+               "head_and_loss"},
 }
 # components of an `op_name` that jax puts there itself (`jnp.einsum` its
 # subscripts: `ops/ssd.py`'s products)
@@ -210,6 +217,11 @@ def test_every_matmul_and_kernel_is_under_a_scope(name, remat):
     assert ("attention/kernel/fwd_rows_blocks" in every) is (name == "sdar")
     if name == "sdar":
         assert "attention/kernel/fwd_rows" not in every
+    # a windowed layer's kernels under names of their own, in the one
+    # model that has such layers (beside its full layers' plain ones)
+    assert ("attention/kernel/fwd_rows_window" in every) \
+        is ("attention/kernel/bwd_fused_window" in every) \
+        is (name == "mellum")
 
 
 @pytest.mark.parametrize("remat", [False, True])
@@ -316,6 +328,24 @@ def test_the_four_kernel_forms_are_told_apart(fn, shape, forms):
         f"attention/kernel/{form}" for form in forms)
 
 
+@pytest.mark.parametrize("fn,shape", [
+    (fa.flash_attention, (1, 2, 256, 64)),
+    (fa.flash_attention, (1, 2, 2048, 64)),
+    # the lane layout would take these heads; a window goes head-major
+    (fa.flash_attention_bshd, (1, 256, 4, 64)),
+    (fa.flash_attention_bshd, (1, 2048, 4, 64)),
+])
+def test_a_windows_kernels_have_form_names_of_their_own(fn, shape):
+    """Under `BlockRule(window=W)` the two head-major kernels stand under
+    `fwd_rows_window` and `bwd_fused_window`, at every length and from
+    either entry, so a trace tells a windowed layer's kernels from a full
+    one's."""
+    windowed = functools.partial(fn, causal=fa.BlockRule(window=100))
+    assert _kernel_names(windowed, shape, shape, shape) == [
+        "attention/kernel/bwd_fused_window",
+        "attention/kernel/fwd_rows_window"]
+
+
 def test_no_split_backward_remains():
     """PR 45 deleted the dq and dk/dv kernels of the long backward: no
     form, scope, kernel body or gate of theirs is left in the program."""
@@ -323,7 +353,8 @@ def test_no_split_backward_remains():
 
     assert fa.KERNEL_FORMS == ("fwd_rows", "fwd_lanes", "bwd_fused",
                                "bwd_fused_lanes", "fwd_rows_blocks",
-                               "bwd_fused_blocks")
+                               "bwd_fused_blocks", "fwd_rows_window",
+                               "bwd_fused_window")
     assert [s for s in layers.SCOPES if s.startswith("attention/kernel/")] \
         == [f"attention/kernel/{form}" for form in fa.KERNEL_FORMS]
     source = inspect.getsource(fa) + inspect.getsource(layers)

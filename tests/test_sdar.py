@@ -562,6 +562,29 @@ def test_the_rule_kernels_compile_for_the_chip_at_the_cells_shape(one_chip):
     assert "16384,16384" not in text
 
 
+@pytest.mark.parametrize("window", [1024, 1000])
+def test_the_windowed_kernels_compile_for_the_chip_at_the_cells_shape(
+        one_chip, window):
+    """Mosaic takes the kernels under a window (`BlockRule(window=W)`: the
+    Mellum 2 cell's 1,024, and a width no tile divides) at
+    (1, 16384, 32 / 4, 128) in bfloat16, forward and the one backward, the
+    runs' bounds worked out from the grid's tile index; nothing runs, and
+    no (S, S) array exists.  Here beside the rule's, for the reason below."""
+    def both(q, k, v, do):
+        o, vjp = jax.vjp(lambda q, k, v: fa.flash_attention_bshd(
+            q, k, v, BlockRule(window=window)), q, k, v)
+        return o, vjp(do)
+
+    x = lambda heads: jax.ShapeDtypeStruct((1, 16384, heads, 128),
+                                           jnp.bfloat16, sharding=one_chip)
+    with warnings.catch_warnings(), jax.default_matmul_precision("default"):
+        warnings.simplefilter("error", fa.AttentionFallbackWarning)
+        text = jax.jit(both).lower(x(32), x(4), x(4), x(32)).compile() \
+            .as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "16384,16384" not in text
+
+
 def test_the_gated_norm_kernels_compile_for_the_chip_at_nemotrons_shape(
         one_chip):
     """Mosaic takes `ops/gated_norm.py`'s two kernels at the nemotron

@@ -25,6 +25,7 @@ from ray_tpu.models import (
     keye_vl,
     layers,
     lfm2_moe,
+    mellum,
     nemotron_h,
     olmoe,
     ouro,
@@ -520,6 +521,7 @@ TRUNK_FAMILIES = {
     "nemotron_h": (nemotron_h, nemotron_h.NEMOTRON_H_TINY),
     "keye_vl": (keye_vl, keye_vl.KEYE_VL_TINY),
     "sdar": (sdar, sdar.SDAR_TINY),
+    "mellum": (mellum, mellum.MELLUM_TINY),
 }
 
 

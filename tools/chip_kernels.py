@@ -27,6 +27,15 @@ at 256 / 512 / 1,024: OLMoE's shape 7.10 / 4.83 / 5.07, ``mla-8k`` 34.53 /
 28.12 / 27.99, ``gqa-8k`` 27.07 / 17.62 / 17.59, ``gqa16-8k`` 26.78 / 17.56
 / 17.57, and no mixed pair beat square 512 by more than 0.3 %).
 
+``mellum_16k`` and ``mellum_8k`` are the calls under a WINDOW
+(`ops/flash_attention.py:BlockRule(window=1024)`; `WINDOW_CASES`): one
+sequence of 16,384 and of 8,192 rows, 32 query heads on 4 key/value heads
+of 128, then the same call with no window; a line a rule and a tile
+(`_auto_tiles`' own, then 256, 512 and 1,024) with the forward's and
+forward + backward's device ms, the share of the visited pairs the rule
+attends, and the largest error of o, dq, dk and dv relative to a float32
+masked softmax taken 1,024 query rows at a time.
+
 ``moe_held_8k`` is no attention case: one routed layer of kanana's share
 (`ops/moe.py`: dispatch, the held experts, combine) over all the routed
 rows, over the held rows' buffer, and as `moe_dispatch` chooses between
@@ -122,6 +131,14 @@ KV_HEADS = {"gqa_8k": 8, "gqa-8k": 8, "gqa16_8k": 2, "gqa16-8k": 2,
 # (block, kinds) of the sweeps under a rule that is not the diagonal
 # (`ops/flash_attention.py:BlockRule`)
 RULES = {"blocks-16k": (4, 2)}
+# (shape, key/value heads, window) of the calls under a window
+# (`BlockRule(window=W)`): Mellum 2's sliding layers at the cell's length
+# and at half of it, each beside the same call with no window
+WINDOW_CASES = {
+    "mellum_16k": ((1, 16384, 32, 128), 4, 1024),
+    "mellum_8k": ((1, 8192, 32, 128), 4, 1024),
+}
+WINDOW_TILES = ((None, None), (256, 256), (512, 512), (1024, 1024))
 # (B, S, H, P, G, N, chunk) of one state-space scan
 SSD_CASES = {
     "ssd_8k": (2, 8192, 64, 64, 8, 128, 128),
@@ -890,6 +907,81 @@ def ssd_case(name, dtype, chunk=None, compare=True):
     ssd._carry = kept
 
 
+def window_case(name, dtype):
+    """One line a rule (the window, then none) and a tile (`_auto_tiles`',
+    then `WINDOW_TILES`) of ``flash_attention_bshd`` at the case's shape:
+    device ms of the forward kernel and of forward + backward, the share
+    of the visited pairs the rule attends, and the largest error of o, dq,
+    dk and dv relative to a float32 masked softmax taken 1,024 query rows
+    at a time (the rule written out as a comparison of positions)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.mellum import attended_pairs
+    from ray_tpu.ops import flash_attention as fa
+
+    shape, kv_heads, window = WINDOW_CASES[name]
+    B, S, H, D = shape
+    q, k, v = _qkv(shape, dtype, kv_heads)
+
+    def reference(q, k, v, width):
+        """(B, S, H, D) float32, by blocks of query rows."""
+        qf, kf, vf = (t.astype(jnp.float32).transpose(0, 2, 1, 3)
+                      for t in (q, k, v))
+        kf, vf = (jnp.repeat(t, H // kv_heads, axis=1) for t in (kf, vf))
+
+        @jax.checkpoint
+        def some(start):
+            rows = start + jnp.arange(1024)
+            behind = rows[:, None] - jnp.arange(S)[None]
+            seen = (behind >= 0) & (behind < (width or S))
+            qb = jax.lax.dynamic_slice_in_dim(qf, start, 1024, axis=2)
+            scores = jnp.einsum("bhqd,bhkd->bhqk", qb, kf) * D ** -0.5
+            probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
+            return jnp.einsum("bhqk,bhkd->bhqd", probs, vf)
+
+        out = jax.lax.map(some, jnp.arange(0, S, 1024))  # (n, B, H, 1024, D)
+        return out.transpose(1, 0, 3, 2, 4).reshape(B, S, H, D)
+
+    def grad(f):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: (lambda o: (jnp.sum(o.astype(jnp.float32) ** 2),
+                                        o))(f(q, k, v)),
+            (0, 1, 2), has_aux=True))
+
+    for width in (window, None):
+        rule = fa.BlockRule(window=width)
+        with jax.default_matmul_precision("highest"):
+            (_, o_r), g_r = grad(lambda q, k, v: reference(q, k, v, width))(
+                q, k, v)
+        want = [np.asarray(t, np.float32) for t in (o_r, *g_r)]
+        del o_r, g_r
+        for block in WINDOW_TILES:
+            (_, o_k), g_k = grad(lambda q, k, v: fa.flash_attention_bshd(
+                q, k, v, rule, None, *block))(q, k, v)
+            errs = {what: round(float(
+                np.max(np.abs(np.asarray(a, np.float32) - b))
+                / np.max(np.abs(b))), 5)
+                for what, a, b in zip(("o", "dq", "dk", "dv"),
+                                      (o_k, *g_k), want)}
+            fwd_ms, bwd_ms = time_passes(shape, dtype, *block,
+                                         kv_heads=kv_heads, causal=rule)
+            (bq, bk), (cq, ck) = [
+                block if block[0] else t for t in fa._auto_tiles(S, rule)]
+            visited = lambda bq, bk: fa._tiles_visited(rule, S, bq, bk) \
+                * bq * bk
+            attended = attended_pairs(S, width)
+            yield {"case": name, "shape": shape, "kv_heads": kv_heads,
+                   "window": width, "tile": block if block[0] else "auto",
+                   "tiles": [[bq, bk], [cq, ck]],
+                   "fwd_ms": fwd_ms, "fwd_bwd_ms": round(fwd_ms + bwd_ms, 4),
+                   "attended_over_visited": [
+                       round(attended / visited(bq, bk), 3),
+                       round(attended / visited(cq, ck), 3)],
+                   "rel_err": errs}
+
+
 def compare_with_reference(shape, dtype, kv_heads=None):
     """Causal ``flash_attention_bshd`` at ``shape`` (B, S, H, D[, Dv]),
     forward and backward, on the default device: (largest error of o, dq,
@@ -953,8 +1045,10 @@ def main():
     parser.add_argument("--cases", nargs="+", metavar="CASE",
                         default=[*CASES, *MOE_CASES, *SHORTCONV_CASES,
                                  *SSD_CASES, *TARGET_CASES, *SCORES_CASES,
-                                 *HEAD_CASES, *GATENORM_CASES],
+                                 *HEAD_CASES, *GATENORM_CASES,
+                                 *WINDOW_CASES],
                         help=f"run these only ({', '.join(CASES)}, "
+                             f"{', '.join(WINDOW_CASES)}, "
                              f"{', '.join(MOE_CASES)}, "
                              f"{', '.join(SHORTCONV_CASES)}, "
                              f"{', '.join(SSD_CASES)}, "
@@ -967,7 +1061,7 @@ def main():
     if args.sweep and set(args.sweep) - set(swept):
         parser.error(f"--sweep: no such shape in {sorted(swept)}")
     known = [*CASES, *MOE_CASES, *SHORTCONV_CASES, *SSD_CASES, *TARGET_CASES,
-             *SCORES_CASES, *HEAD_CASES, *GATENORM_CASES]
+             *SCORES_CASES, *HEAD_CASES, *GATENORM_CASES, *WINDOW_CASES]
     if set(args.cases) - set(known):
         parser.error(f"--cases: no such case in {known}")
 
@@ -1107,6 +1201,14 @@ def main():
                     3 if line["form"] == "kernel" else 0)
             if not ok:
                 failed.append(f"{name}:{line['form']}:{line.get('tile')}")
+            print(json.dumps({**line, "ok": ok,
+                              "device_kind": dev.device_kind}), flush=True)
+    for name in WINDOW_CASES:
+        for line in window_case(name, jnp.bfloat16) \
+                if name in args.cases else ():
+            ok = max(line["rel_err"].values()) < TOLERANCE
+            if not ok:
+                failed.append(f"{name}:{line['window']}:{line['tile']}")
             print(json.dumps({**line, "ok": ok,
                               "device_kind": dev.device_kind}), flush=True)
     if failed:
