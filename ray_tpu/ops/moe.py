@@ -21,6 +21,16 @@ the rows of held experts alone are gathered into it and summed back out
 of it (`_take`, `_put`); a step whose router sends the share more than
 that takes the T*k path instead, inside the same compiled program
 (`lax.cond`), so the result is exact under any imbalance.
+
+What moves a row.  Over a held share's buffer, the two Mosaic kernels of
+`ops/moe_rows.py`, forward and backward (`_take`'s transpose is `_put`
+without weights, and the other way round): a row moves by one DMA and only
+if it exists, so the half of the buffer that belongs to no group is
+written as zeros and never fetched, and of a token's k choices only those
+whose expert is held are read back; a shape the kernels decline takes the
+XLA forms they stand for (a gather and a mask; a gather a choice and a
+sum).  Over all T*k rows (`_over_all_rows`: every expert held, and the
+overflow branch), XLA's gathers by a permutation, as before.
 """
 
 from __future__ import annotations
@@ -31,6 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.ops import moe_rows
 from ray_tpu.util import tracing
 
 # The name a router's product, its choices and their order by expert carry
@@ -70,50 +81,21 @@ _permute_rows.defvjp(
     lambda inverse, g: (g[inverse], None, None))
 
 
-@jax.custom_vjp
-def _first_of_permuted(v, first, inverse):
-    """v[first] for ``first`` the leading C entries of a permutation whose
-    ``inverse`` is known (v a vector): the cotangent is a gather too."""
-    return v[first]
-
-
-def _first_of_permuted_bwd(inverse, g):
-    C = g.shape[0]
-    return (jnp.where(inverse < C, g[jnp.minimum(inverse, C - 1)], 0),
-            None, None)
-
-
-_first_of_permuted.defvjp(
-    lambda v, first, inverse: (v[first], inverse), _first_of_permuted_bwd)
-
-
-def _sum_into_tokens(rows, scale, where):
-    """(C, E) buffered rows -> (T, E): each token's rows, times ``scale``
-    (C,) if given, summed in float32 in the order of its choices.  A gather
-    of T rows for each of the k choices and one sum, which XLA:TPU fuses:
-    nothing with T*k rows exists (`tools/chip_kernels.py`, case
-    `moe_held_8k`, has the forms tried: a scatter-add is serial on the
-    chip, and the buffer sorted by token with its neighbours added takes
-    two more gathers)."""
-    slot = where[2]                         # (T, k); C: not in the buffer
-    C = rows.shape[0]
-    total = 0
-    for j in range(slot.shape[1]):
-        at = jnp.minimum(slot[:, j], C - 1)
-        row = rows[at].astype(jnp.float32)
-        if scale is not None:
-            row = row * scale[at][:, None]
-        total = total + jnp.where((slot[:, j] < C)[:, None], row, 0)
-    return total.astype(rows.dtype)
+def _sum_into_tokens(rows, weights, where):
+    """(C, E) buffered rows -> (T, E): each token's rows, times its
+    choices' ``weights`` (T, k) if given, summed in float32 in the order
+    of its choices (`moe_rows.sum_rows`: only the choices that are in the
+    buffer are fetched, a row a copy; nothing with T*k rows exists)."""
+    return moe_rows.sum_rows(rows, where[2], where[1], weights)
 
 
 @jax.custom_vjp
 def _take(x, where):
     """(T, E) -> the (C, E) buffer: each buffered row's token's row, zero
-    for the rows of the buffer that belong to no group.  Its transpose is
-    `_put` without weights."""
-    tokens, valid = where[:2]
-    return jnp.where(valid[:, None], x[tokens], 0)
+    for the rows of the buffer that belong to no group, which nobody
+    fetches (`moe_rows.take_rows`).  Its transpose is `_put` without
+    weights."""
+    return moe_rows.take_rows(x, where[0], where[2])
 
 
 _take.defvjp(lambda x, where: (_take(x, where), where),
@@ -121,22 +103,27 @@ _take.defvjp(lambda x, where: (_take(x, where), where),
 
 
 @jax.custom_vjp
-def _put(rows, scale, where):
-    """The (C, E) buffer, each row times its ``scale``, summed into the
-    (T, E) tokens; the rows of no group add nothing.  Its transpose in
-    ``rows`` is `_take` times the scale."""
-    return _sum_into_tokens(rows, scale, where)
+def _put(rows, weights, where):
+    """The (C, E) buffer, each row times its (token, choice)'s weight
+    (T, k), summed into the (T, E) tokens; the rows of no group add
+    nothing.  Its transpose in ``rows`` is `_take` times the weights."""
+    return _sum_into_tokens(rows, weights, where)
 
 
 def _put_bwd(res, g):
-    rows, scale, where = res
+    rows, weights, where = res
+    slot, place = where[1], where[3]
+    C = rows.shape[0]
     g = _take(g, where).astype(jnp.float32)
-    return ((g * scale[:, None]).astype(rows.dtype),
-            jnp.sum(g * rows.astype(jnp.float32), axis=1), None)
+    by_row = jnp.sum(g * rows.astype(jnp.float32), axis=1)      # (C,)
+    return ((g * weights.reshape(-1)[place][:, None]).astype(rows.dtype),
+            jnp.where(slot < C, by_row[jnp.minimum(slot, C - 1).reshape(-1)]
+                      .reshape(slot.shape), 0), None)
 
 
-_put.defvjp(lambda rows, scale, where: (
-    _sum_into_tokens(rows, scale, where), (rows, scale, where)), _put_bwd)
+_put.defvjp(lambda rows, weights, where: (
+    _sum_into_tokens(rows, weights, where), (rows, weights, where)),
+    _put_bwd)
 
 
 def _sort_by_expert(experts, n_experts, held):
@@ -161,14 +148,14 @@ def _sort_by_expert(experts, n_experts, held):
 
 
 def _buffer_index(C, k, by_expert, n_held):
-    """-> (the (token, choice) rows in the C-row buffer, in expert order;
-    `where` for `_take` and `_put`: each buffered row's token, whether it
-    is a held expert's, and each (token, choice)'s place in the buffer
-    (T, k), C for those not in it)."""
+    """-> `where` for `_take` and `_put`: each buffered row's token, each
+    (token, choice)'s place in the buffer (T, k), C for those not in it,
+    the rows the held experts were sent (the buffer's first ``n_held``),
+    and each buffered row's (token, choice), in expert order."""
     _, order, inverse = by_expert
     rows = order[:C]
-    return rows, (rows // k, jnp.arange(C, dtype=jnp.int32) < n_held,
-                  jnp.where(inverse < n_held, inverse, C).reshape(-1, k))
+    return (rows // k, jnp.where(inverse < n_held, inverse, C).reshape(-1, k),
+            n_held, rows)
 
 
 def _over_all_rows(x, weights, by_expert, group_sizes, held, run_experts):
@@ -204,14 +191,15 @@ def _over_held_rows(C, x, weights, by_expert, group_sizes, held,
     first, count = held
     sizes = group_sizes[first:first + count]
     with jax.named_scope("dispatch"):
-        rows, where = _buffer_index(C, k, by_expert, jnp.sum(sizes))
+        where = _buffer_index(C, k, by_expert, jnp.sum(sizes))
         xs = _take(x, where)
     with jax.named_scope("experts"):
-        ys = jnp.where(where[1][:, None], run_experts(xs, sizes), 0)
+        # whatever a grouped matmul makes of the rows of no group stays
+        # there: `_put` fetches none of them, and its transpose hands
+        # their cotangents over as the zeros `_take` wrote
+        ys = run_experts(xs, sizes)
     with jax.named_scope("combine"):
-        scale = _first_of_permuted(weights.reshape(T * k), rows,
-                                   by_expert[2])
-        return _put(ys, scale, where)
+        return _put(ys, weights, where)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
@@ -295,8 +283,14 @@ def moe_dispatch(x, weights, experts, n_experts, run_experts, held=None):
     more runs over all T*k rows instead (`run_experts` is traced at both
     lengths, and called again by the backward pass).  Nothing is dropped
     under any imbalance.  In the job timeline `moe.rows_buffered` against
-    `moe.rows_routed` is what the buffer costs, and `moe.overflow_passes`
-    how many passes (forward, recomputed, backward) took the long way.
+    `moe.rows_routed` is what the buffer costs the grouped matmuls and the
+    passes that write it, no longer what the gathers cost: dispatch
+    fetches the rows the share was sent and combine the choices that are
+    held (`ops/moe_rows.py`; `moe.row_kernel_passes` counts the passes its
+    kernels took as the step was traced and `moe.row_kernel_declined`
+    those a shape sent back to XLA's gathers, which fetch a row for every
+    buffered row and every choice).  `moe.overflow_passes` is how many
+    passes (forward, recomputed, backward) took the long way.
     None: all are held."""
     T, k = experts.shape
     first, count = held or (0, n_experts)

@@ -39,8 +39,18 @@ masked softmax taken 1,024 query rows at a time.
 ``moe_held_8k`` is no attention case: one routed layer of kanana's share
 (`ops/moe.py`: dispatch, the held experts, combine) over all the routed
 rows, over the held rows' buffer, and as `moe_dispatch` chooses between
-them; the forms tried for summing the buffer into its tokens; and all of
-it against a float32 loop over the held experts.
+them, all of it against a float32 loop over the held experts (a line a
+form: forward and forward + backward device ms, the custom calls' part of
+it and the longest operations by name); then the two movements alone
+(`ops/moe_rows.py`), each way tried beside the least time of its bytes:
+the buffer taken (XLA's gather and mask; the row kernel at each of
+``MOE_TAKE_TILES``) and summed back into its tokens (XLA's gather a choice;
+the row kernel at each of ``MOE_SUM_TILES``; the buffer sorted by token; a
+scatter-add), with the kernels' own ms and the largest difference to the
+XLA form, which is 0.  ``moe_held_16k`` is the same at mellum2's share (16
+of 64 experts, 8 choices a token, hidden 2,304, experts 896 wide), whose
+buffer of 65,536 rows is too large for XLA to keep in VMEM.  About six
+minutes a case: the float32 loop is most of it.
 
 ``shortconv_8k`` is no attention case either: one gated short convolution
 (`models/layers.py:short_conv`) at (2, 8192, 2048) with 3 taps, the whole
@@ -162,7 +172,13 @@ GATENORM_TILES = (64, 128, 256, 512)
 # router's, hidden and expert widths
 MOE_CASES = {
     "moe_held_8k": (16384, 6, 16, 128, 2048, 768),
+    # mellum2's share: 16 of 64, 8 choices a token, hidden 2,304
+    "moe_held_16k": (16384, 8, 16, 64, 2304, 896),
 }
+# the result rows of a grid step the two row kernels (`ops/moe_rows.py`)
+# are timed at, beside the ones `_tile` chooses
+MOE_TAKE_TILES = (128, 256, 512)
+MOE_SUM_TILES = (128, 256)
 # (B, S, H, H_kv, D, block, top_k) of one indexer loss's target
 TARGET_CASES = {
     "target_8k": (2, 8192, 32, 4, 128, 512, 2048),
@@ -359,7 +375,7 @@ def moe_case(name, dtype):
     import numpy as np
 
     from ray_tpu.models.layers import swiglu
-    from ray_tpu.ops import moe
+    from ray_tpu.ops import moe, moe_rows
 
     T, k, count, n_experts, E, W = MOE_CASES[name]
     held = (0, count)
@@ -434,12 +450,37 @@ def moe_case(name, dtype):
         yield {"case": name, "form": form, "buffer_rows": C,
                "rows_held": int(jnp.sum(args[0] < count)),
                "fwd_ms": busy_ms(forward, *args),
-               "fwd_bwd_ms": busy_ms(grad, *args), "rel_err": errs}
+               "fwd_bwd_ms": busy_ms(grad, *args),
+               "custom_calls_ms": kernel_ms(grad, *args),
+               "longest_ops_ms": longest_ops(grad, *args, top=8),
+               "rel_err": errs}
 
-    # the sum of the buffer into its tokens, alone, each way tried
+    # the two movements alone, each way tried, beside the least time of
+    # their bytes: the rows that exist read, the result written
     tokens, scale, where, by_token, same, start, some = jax.jit(
         lambda: _buffer_of(experts, weights, held, n_experts, C))()
     ys = jax.random.normal(ks[0], (C, E), dtype)
+    slot, n_held = where[1:3]
+    valid = jnp.arange(C) < n_held
+    size = jnp.dtype(dtype).itemsize
+
+    def least_ms(written):
+        return round((int(n_held) + written) * E * size / 819e9 * 1e3, 4)
+
+    takes = {"gather_and_mask": moe_rows._take_reference, **{
+        f"row_kernel_{tile}": functools.partial(moe_rows._take, tile=tile)
+        for tile in MOE_TAKE_TILES}}
+    kept = None
+    for form, take in takes.items():
+        take = jax.jit(take)
+        out = np.asarray(take(x, tokens, n_held), np.float32)
+        kept = out if kept is None else kept
+        yield {"case": name, "take": form, "rows": C,
+               "fwd_ms": busy_ms(take, x, tokens, n_held),
+               "kernel_ms": kernel_ms(take, x, tokens, n_held),
+               "least_ms": least_ms(C),
+               "max_abs_diff_to_xla": round(float(
+                   np.max(np.abs(out - kept))), 5)}
 
     def sorted_neighbours(ys):
         """The buffer in token order (a sort of C keys, a gather of C
@@ -451,14 +492,19 @@ def moe_case(name, dtype):
             z = z + jnp.where(same_token[:, None], after[d:d + C], 0)
         return jnp.where(some[:, None], z.astype(dtype)[start], 0)
 
+    by_choice = scale[jnp.minimum(slot, C - 1)]          # (T, k)
     puts = {
-        # kept (`ops/moe.py:_sum_into_tokens`): a gather of T rows for
-        # each of the k choices, summed
-        "gather_per_choice": lambda ys: moe._sum_into_tokens(
-            ys, scale, where),
+        # what the kernel stands for (`ops/moe_rows.py:_sum_reference`):
+        # a gather of T rows for each of the k choices, summed
+        "gather_per_choice": lambda ys: moe_rows._sum_reference(
+            ys, n_held, slot, by_choice),
+        **{f"row_kernel_{tile}": functools.partial(
+            lambda ys, tile: moe_rows._sum(ys, n_held, slot, by_choice,
+                                           tile=tile),
+            tile=tile) for tile in MOE_SUM_TILES},
         "sorted_neighbours": sorted_neighbours,
         "scatter_add": lambda ys: jnp.zeros((T, E), jnp.float32).at[
-            tokens].add(jnp.where(where[1][:, None], ys.astype(jnp.float32)
+            tokens].add(jnp.where(valid[:, None], ys.astype(jnp.float32)
                                   * scale[:, None], 0)).astype(dtype),
     }
     kept = None
@@ -467,7 +513,8 @@ def moe_case(name, dtype):
         out = np.asarray(put(ys), np.float32)
         kept = out if kept is None else kept
         yield {"case": name, "put": form, "fwd_ms": busy_ms(put, ys),
-               "max_abs_diff_to_kept": round(float(
+               "kernel_ms": kernel_ms(put, ys), "least_ms": least_ms(T),
+               "max_abs_diff_to_xla": round(float(
                    np.max(np.abs(out - kept))), 5)}
 
 
@@ -485,13 +532,14 @@ def _buffer_of(experts, weights, held, n_experts, C):
     T, k = experts.shape
     by_expert, sizes = moe._sort_by_expert(experts, n_experts, held)
     n_held = jnp.sum(sizes[held[0]:held[0] + held[1]])
-    first, where = moe._buffer_index(C, k, by_expert, n_held)
+    where = moe._buffer_index(C, k, by_expert, n_held)
+    first = where[3]
     in_order, by_token = jax.lax.sort(
-        (jnp.where(where[1], first, T * k), jnp.arange(C, dtype=jnp.int32)),
-        num_keys=1)
+        (jnp.where(jnp.arange(C) < n_held, first, T * k),
+         jnp.arange(C, dtype=jnp.int32)), num_keys=1)
     token = jnp.concatenate([in_order // k, jnp.full((k - 1,), -1, jnp.int32)])
     same = [token[d:d + C] == token[:C] for d in range(1, k)]
-    mine = jnp.sum(where[2] < C, axis=1, dtype=jnp.int32)
+    mine = jnp.sum(where[1] < C, axis=1, dtype=jnp.int32)
     return (where[0], weights.reshape(T * k)[first], where, by_token, same,
             jnp.cumsum(mine) - mine, mine > 0)
 
