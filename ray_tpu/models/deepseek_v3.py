@@ -64,6 +64,7 @@ from ray_tpu.models.layers import (
     dense_ffn,
     head_and_loss,
     named,
+    normal_kernel,
     num_params,  # noqa: F401  (`deepseek_v3.num_params` is public)
     rms_norm,
     rope,
@@ -71,6 +72,7 @@ from ray_tpu.models.layers import (
     swiglu,
     train_step,
     trunk,
+    unit_scale,
 )
 from ray_tpu.ops.moe import (
     ROUTING_BIAS,
@@ -137,40 +139,33 @@ def init_params(rng, cfg: DeepseekV3Config) -> Dict[str, Any]:
     """Normal(0, 0.02) matrices, unit norm gains, routing biases 0.  Names
     are those `parallel/sharding.py:infer_param_logical_dims` lays out; the
     experts' stacks hold the `cfg.n_held` experts that live here."""
-    std = 0.02
     E, H, R = cfg.n_embd, cfg.n_head, cfg.kv_lora_rank
     keys = jax.random.split(rng, 2 + cfg.n_layer)
 
-    def kernel(key, *shape):
-        return {"kernel": jax.random.normal(key, shape, jnp.float32) * std}
-
-    def scale(width=E):
-        return {"scale": jnp.ones((width,), jnp.float32)}
-
     def mlp(ks, width):
-        return {"gate_proj": kernel(ks[0], E, width),
-                "up_proj": kernel(ks[1], E, width),
-                "down_proj": kernel(ks[2], width, E)}
+        return {"gate_proj": normal_kernel(ks[0], E, width),
+                "up_proj": normal_kernel(ks[1], E, width),
+                "down_proj": normal_kernel(ks[2], width, E)}
 
     params = {
         "embed_tokens": {
-            "embedding": kernel(keys[0], cfg.vocab_size, E)["kernel"]},
-        "norm_f": scale(),
-        "lm_head": kernel(keys[1], E, cfg.vocab_size),
+            "embedding": normal_kernel(keys[0], cfg.vocab_size, E)["kernel"]},
+        "norm_f": unit_scale(E),
+        "lm_head": normal_kernel(keys[1], E, cfg.vocab_size),
     }
     for i in range(cfg.n_layer):
         ks = jax.random.split(keys[2 + i], 11)
         layer = {
-            "input_norm": scale(),
+            "input_norm": unit_scale(E),
             "attn": {
-                "q_proj": kernel(ks[0], E, H * cfg.qk_head_dim),
-                "kv_a_proj": kernel(ks[1], E, R + cfg.qk_rope_dim),
-                "kv_a_norm": scale(R),
-                "kv_b_proj": kernel(
+                "q_proj": normal_kernel(ks[0], E, H * cfg.qk_head_dim),
+                "kv_a_proj": normal_kernel(ks[1], E, R + cfg.qk_rope_dim),
+                "kv_a_norm": unit_scale(R),
+                "kv_b_proj": normal_kernel(
                     ks[2], R, H * (cfg.qk_nope_dim + cfg.v_head_dim)),
-                "o_proj": kernel(ks[3], H * cfg.v_head_dim, E),
+                "o_proj": normal_kernel(ks[3], H * cfg.v_head_dim, E),
             },
-            "post_norm": scale(),
+            "post_norm": unit_scale(E),
         }
         if i < cfg.n_dense_layer:
             layer["mlp"] = mlp(ks[4:7], cfg.dense_width)
@@ -178,11 +173,11 @@ def init_params(rng, cfg: DeepseekV3Config) -> Dict[str, Any]:
             n, W = cfg.n_held, cfg.expert_width
             layer["moe"] = {
                 "router": {
-                    **kernel(ks[4], E, cfg.n_experts),
+                    **normal_kernel(ks[4], E, cfg.n_experts),
                     ROUTING_BIAS: jnp.zeros((cfg.n_experts,), jnp.float32)},
-                "wi_gate": kernel(ks[5], n, E, W)["kernel"],
-                "wi_up": kernel(ks[6], n, E, W)["kernel"],
-                "wo": kernel(ks[7], n, W, E)["kernel"],
+                "wi_gate": normal_kernel(ks[5], n, E, W)["kernel"],
+                "wi_up": normal_kernel(ks[6], n, E, W)["kernel"],
+                "wo": normal_kernel(ks[7], n, W, E)["kernel"],
                 "shared": mlp(ks[8:11], cfg.shared_width),
             }
         params[f"layer_{i}"] = layer

@@ -58,11 +58,13 @@ held experts' matrices exist and only their part of the sum is computed
 head held here.
 
 What it shares with the other models: `models/layers.py` (RMSNorm,
-LayerNorm, RoPE, the SwiGLU, the routed layer, the walk over the layers,
-the head and its chunked loss, the mixed-precision step),
-`parallel/attention.py` (the flash kernels, here under a mask that is
-data) and `ops/moe.py`; the names are those `parallel/sharding.py` lays
-out.
+LayerNorm, RoPE, the projections into and out of attention, between which
+the indexer, the masked kernels and the indexer's loss stand here, the
+SwiGLU, the routed layer, the walk over the layers, the head and its
+chunked loss, the mixed-precision step), `parallel/attention.py` (the flash
+kernels, here under a mask that is data) and `ops/moe.py` (the softmax
+route, its balance loss, the routers' account); the names are those
+`parallel/sharding.py` lays out.
 
 `jax.named_scope`s (`models/layers.py:SCOPES`): embed, norm,
 attention/{qkv,indexer/{proj,scores,select,loss},kernel,out},
@@ -71,6 +73,7 @@ ffn/moe/{route,dispatch,experts,combine}, head_and_loss, optimizer_update.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -78,9 +81,12 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.layers import (
+    attention_out,
+    attention_qkv,
     head_and_loss,
     layer_norm,
     named,
+    normal_kernel,
     num_params,  # noqa: F401  (`keye_vl.num_params` is public)
     rms_norm,
     rope,
@@ -88,8 +94,9 @@ from ray_tpu.models.layers import (
     swiglu,
     train_step,
     trunk,
+    unit_scale,
 )
-from ray_tpu.ops.moe import ROUTE_NAME, routing_account
+from ray_tpu.ops.moe import balance_loss, routing_account, softmax_route
 from ray_tpu.ops.sparse_index import (
     index_scores,
     indexer_loss,
@@ -156,49 +163,41 @@ def init_params(rng, cfg: KeyeVlConfig) -> Dict[str, Any]:
     at gain 1 and bias 0.  Names are those `parallel/sharding.py:
     infer_param_logical_dims` lays out; the experts' stacks hold the
     `cfg.n_held` experts that live here."""
-    std = 0.02
     E, H, Hkv, D = cfg.n_embd, cfg.n_head, cfg.n_kv_head, cfg.head_dim
     J, Di = cfg.index_heads, cfg.index_dim
     keys = jax.random.split(rng, 2 + cfg.n_layer)
-
-    def kernel(key, *shape):
-        return {"kernel": jax.random.normal(key, shape, jnp.float32) * std}
-
-    def scale(width=E):
-        return {"scale": jnp.ones((width,), jnp.float32)}
-
     params = {
         "embed_tokens": {
-            "embedding": kernel(keys[0], cfg.vocab_size, E)["kernel"]},
-        "norm_f": scale(),
-        "lm_head": kernel(keys[1], E, cfg.vocab_size),
+            "embedding": normal_kernel(keys[0], cfg.vocab_size, E)["kernel"]},
+        "norm_f": unit_scale(E),
+        "lm_head": normal_kernel(keys[1], E, cfg.vocab_size),
     }
     for i in range(cfg.n_layer):
         ks = jax.random.split(keys[2 + i], 11)
         n, W = cfg.n_held, cfg.expert_width
         params[f"layer_{i}"] = {
-            "input_norm": scale(),
+            "input_norm": unit_scale(E),
             "attn": {
-                "q_proj": kernel(ks[0], E, H * D),
-                "k_proj": kernel(ks[1], E, Hkv * D),
-                "v_proj": kernel(ks[2], E, Hkv * D),
-                "o_proj": kernel(ks[3], H * D, E),
-                "q_norm": scale(D),
-                "k_norm": scale(D),
+                "q_proj": normal_kernel(ks[0], E, H * D),
+                "k_proj": normal_kernel(ks[1], E, Hkv * D),
+                "v_proj": normal_kernel(ks[2], E, Hkv * D),
+                "o_proj": normal_kernel(ks[3], H * D, E),
+                "q_norm": unit_scale(D),
+                "k_norm": unit_scale(D),
                 "indexer": {
-                    "q_proj": kernel(ks[4], E, J * Di),
-                    "k_proj": kernel(ks[5], E, Di),
-                    "weights_proj": kernel(ks[6], E, J),
-                    "k_norm": {**scale(Di),
+                    "q_proj": normal_kernel(ks[4], E, J * Di),
+                    "k_proj": normal_kernel(ks[5], E, Di),
+                    "weights_proj": normal_kernel(ks[6], E, J),
+                    "k_norm": {**unit_scale(Di),
                                "bias": jnp.zeros((Di,), jnp.float32)},
                 },
             },
-            "post_norm": scale(),
+            "post_norm": unit_scale(E),
             "moe": {
-                "router": kernel(ks[7], E, cfg.n_experts),
-                "wi_gate": kernel(ks[8], n, E, W)["kernel"],
-                "wi_up": kernel(ks[9], n, E, W)["kernel"],
-                "wo": kernel(ks[10], n, W, E)["kernel"],
+                "router": normal_kernel(ks[7], E, cfg.n_experts),
+                "wi_gate": normal_kernel(ks[8], n, E, W)["kernel"],
+                "wi_up": normal_kernel(ks[9], n, E, W)["kernel"],
+                "wo": normal_kernel(ks[10], n, W, E)["kernel"],
             },
         }
     return params
@@ -238,50 +237,22 @@ def _select(u, p, cfg: KeyeVlConfig):
 
 def _attention(x, p, cfg: KeyeVlConfig):
     """-> (the operator's result (B, S, E), the layer's indexer loss)."""
-    B, S, _ = x.shape
-    H, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-    kernel = lambda name: p[name]["kernel"].astype(x.dtype)
-    with jax.named_scope("qkv"):
-        # the products, before the norms: a norm's backward reads them
-        q, k, v = named(((x @ kernel("q_proj")).reshape(B, S, H, D),
-                         (x @ kernel("k_proj")).reshape(B, S, Hkv, D),
-                         (x @ kernel("v_proj")).reshape(B, S, Hkv, D)),
-                        "attention/qkv")
-        positions = jnp.arange(S)
-        q = rope(rms_norm(q, p["q_norm"], cfg.rms_eps), positions,
-                 cfg.rope_theta)
-        k = rope(rms_norm(k, p["k_norm"], cfg.rms_eps), positions,
-                 cfg.rope_theta)
+    q, k, v = attention_qkv(x, p, cfg.head_dim, cfg.rms_eps, jnp.arange,
+                            cfg.rope_theta)
     with jax.named_scope("indexer"):
         scores, mask = _select(x, p["indexer"], cfg)
     with jax.named_scope("kernel"):
         o, lse = attention(q, k, v, mask=mask, with_lse=True)
     with jax.named_scope("indexer"), jax.named_scope("loss"):
         loss = indexer_loss(scores, mask, q, k, lse, cfg.index_block)
-    with jax.named_scope("out"):
-        return named(o.reshape(B, S, H * D) @ kernel("o_proj"),
-                     "attention/out"), loss
+    return attention_out(o, p), loss
 
 
-def _route(cfg: KeyeVlConfig, mean_probs=None):
-    """-> route(xt, router) -> (weights (T, k) f32, experts (T, k) int32)
-    over all experts: a softmax over the logits in float32, its top k,
-    over their sum if `norm_topk_prob`.  ``mean_probs``: a list that gets
-    the softmax's mean over the tokens (N,), for the load-balancing loss."""
-    def route(xt, router):
-        # the logits: a softmax's and a top-k's backward read their own
-        # results, which a replay makes from these
-        logits = named(jnp.matmul(
-            xt, router["kernel"].astype(xt.dtype),
-            preferred_element_type=jnp.float32), ROUTE_NAME)      # (T, N)
-        probs = jax.nn.softmax(logits, axis=-1)
-        if mean_probs is not None:
-            mean_probs.append(jnp.mean(probs, axis=0))
-        weights, experts = jax.lax.top_k(probs, cfg.top_k)
-        if cfg.norm_topk_prob:
-            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-        return weights, named(experts, ROUTE_NAME)
-    return route
+def _route(cfg: KeyeVlConfig):
+    """-> route(xt, router) -> (weights (T, k) f32, experts (T, k) int32,
+    the softmax's mean over the tokens (N,)) over all experts."""
+    return functools.partial(softmax_route, top_k=cfg.top_k,
+                             renormalise=cfg.norm_topk_prob)
 
 
 def _layer(x, p, cfg: KeyeVlConfig):
@@ -293,15 +264,10 @@ def _layer(x, p, cfg: KeyeVlConfig):
     x = x + y
     u = rms_norm(x, p["post_norm"], cfg.rms_eps)
     with jax.named_scope("ffn"), jax.named_scope("moe"):
-        mean_probs = []
-        y, rows = routed_layer(u, p["moe"], _route(cfg, mean_probs),
-                               cfg.n_experts, cfg.held, swiglu)
-        with jax.named_scope("route"):
-            # each expert's share of the T k assignments (a count: no
-            # gradient) against its mean probability
-            share = rows.astype(jnp.float32) / (u.shape[0] * u.shape[1]
-                                                * cfg.top_k)
-            balance = cfg.n_experts * jnp.sum(share * mean_probs[0])
+        y, rows, mean_prob = routed_layer(u, p["moe"], _route(cfg),
+                                          cfg.n_experts, cfg.held, swiglu)
+        balance = balance_loss(rows, mean_prob,
+                               u.shape[0] * u.shape[1] * cfg.top_k)
     return x + y, {"rows": rows, "indexer_loss": loss, "aux_loss": balance}
 
 

@@ -1,20 +1,26 @@
-"""What the Train-path models share: the norms, rotary positions, the
-feed-forwards (gated and not), the routed layer over them, the gated short
-convolution, the causal convolution and gated norm of a state-space mixer,
-the walk over a decoder's layers (once, or, for a looped model, several
-times over the same parameters with the final norm after every walk), the
-head and its chunked loss (one rule, `chunked_xent`: the rows weighted or
-not, their losses handed back beside the sum, the gradient formed in the
-same walk that makes the logits) and the mixed-precision step.  A model
-file imports these, `ray_tpu.parallel.attention` and `ray_tpu.ops`; it
-imports no other model file: it is its configuration, `init_params`, its
-mixers and a `_layer` that says which mixer and which feed-forward a layer
-has.
+"""What the Train-path models share: the norms, rotary positions, the way
+into and out of an attention operator (`attention_qkv`: the three
+projections to heads, an RMSNorm a head where the parameters have a gain for
+one, RoPE where the caller gives positions; `attention_out`: W_o), the
+feed-forwards (gated and not), the routed layer over them, which hands on
+what its route gives past the two it needs (`ops/moe.py` has the routes: the
+sigmoid one with a routing bias, the softmax one with its balance loss), the
+gated short convolution, the causal convolution of a state-space mixer, the
+walk over a decoder's layers (once, or, for a looped model, several times
+over the same parameters with the final norm after every walk), the head and
+its chunked loss (one rule, `chunked_xent`: the rows weighted or not, their
+losses handed back beside the sum, the gradient formed in the same walk that
+makes the logits), the mixed-precision step, and the two things every
+`init_params` draws (`normal_kernel`, `unit_scale`).  A model file imports
+these, `ray_tpu.parallel.attention` and `ray_tpu.ops`; it imports no other
+model file: it is its configuration, its table of parameters
+(`init_params`), its mixers (an attention's is the kernels' call between
+`attention_qkv` and `attention_out`, under the model's own name `attention`)
+and a `_layer` that says which mixer and which feed-forward a layer has.
 
 Imports jax, the names of the flash kernels' residuals and forms
 (`ops/flash_attention.py`, which every model imports through
-`parallel/attention.py` anyway), the gated norm's kernels
-(`ops/gated_norm.py`) and, of the runtime, only the job
+`parallel/attention.py` anyway) and, of the runtime, only the job
 timeline's counters (`util/tracing.py`, which imports nothing heavy): a
 worker pays nothing for it before its first step.
 
@@ -25,10 +31,12 @@ profiler carries that into the trace (`tf_op`), and a reader
 every path a Train-path model may use, and the one place a reader or a test
 takes them from.  A model writes plain `with jax.named_scope("attention"):`
 around the part; what the models share names itself (`layer_norm` and
-`rms_norm`: `norm`; `short_conv`'s three parts; `trunk`'s `embed`;
+`rms_norm`: `norm`; `attention_qkv`'s `qkv` and `attention_out`'s `out`,
+which rely on the caller standing in `attention` (the `kernel` between them
+is the model's); `short_conv`'s three parts; `trunk`'s `embed`;
 `routed_layer`'s `route` and `shared` and `ops/moe.py`'s `dispatch`,
-`experts`, `combine` and `routing_bias_update`, which rely on the caller
-standing in `ffn/moe`; `head_and_loss`, also around
+`experts`, `combine`, `balance_loss`'s `route` and `routing_bias_update`,
+which rely on the caller standing in `ffn/moe`; `head_and_loss`, also around
 `head_and_weighted_loss`; the flash kernels' forms; a looped model's
 `exit_gate` (the gates, the exit distribution and its entropy: the model
 writes it; the weighting of the rows' losses is the head's, under
@@ -53,7 +61,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops import gated_norm
 from ray_tpu.ops.flash_attention import KEPT_RESIDUALS, KERNEL_FORMS
 from ray_tpu.ops.moe import ROUTE_NAME, moe_dispatch
 from ray_tpu.parallel.context import get_mesh
@@ -195,6 +202,45 @@ def yarn_frequencies(dim, theta, factor, original_max_position, beta_fast=32,
     return freqs.astype(np.float32), float(attention_factor)
 
 
+def attention_qkv(x, p, head_dim, eps=None, positions=None, theta=None,
+                  scale=None):
+    """The way into an attention operator, x (B, S, E) -> q (B, S, H, D), k
+    and v (B, S, H_kv, D) with D = ``head_dim``, under `qkv`; the caller
+    stands in `attention`, runs its kernels on the three under `kernel` and
+    hands their result to `attention_out`.  q, k, v = x W_q, x W_k, x W_v
+    (``p``'s "q_proj", "k_proj" and "v_proj", each {"kernel": ...}, no
+    bias; a kernel's width over D is its heads), marked `attention/qkv`;
+    where ``p`` has "q_norm" and "k_norm", q and k through an RMSNorm over
+    each head's D at ``eps`` with that gain; where ``positions`` is given,
+    q and k through `rope` at ``positions(S)``, a function of the rows'
+    number (`jnp.arange`: row t stands at t) that is traced here, behind
+    the products, with ``theta`` and ``scale`` as `rope` takes them."""
+    B, S, _ = x.shape
+    with jax.named_scope("qkv"):
+        # the products, before the norms: a norm's backward reads them
+        q, k, v = named(tuple(
+            (x @ p[name]["kernel"].astype(x.dtype)).reshape(
+                B, S, -1, head_dim)
+            for name in ("q_proj", "k_proj", "v_proj")), "attention/qkv")
+        at = None if positions is None else positions(S)
+
+        def turned(heads, norm):
+            if norm in p:
+                heads = rms_norm(heads, p[norm], eps)
+            return heads if at is None else rope(heads, at, theta,
+                                                 scale=scale)
+        return turned(q, "q_norm"), turned(k, "k_norm"), v
+
+
+def attention_out(o, p):
+    """The way out of one: the kernels' result o (B, S, H, D) -> o W_o
+    (B, S, E), ``p``'s "o_proj", under `out`, marked `attention/out`."""
+    B, S = o.shape[:2]
+    with jax.named_scope("out"):
+        return named(o.reshape(B, S, -1)
+                     @ p["o_proj"]["kernel"].astype(o.dtype), "attention/out")
+
+
 def swiglu(x, gate, up, down, matmul=jnp.matmul):
     """down(silu(gate(x)) * up(x)), no bias: the gated feed-forward of a
     dense layer, a shared expert (``matmul`` a plain product) and routed
@@ -260,10 +306,12 @@ def grouped_ffn(p, ffn):
 
 def routed_layer(x, p, route, n_experts, held, ffn):
     """One routed feed-forward, x (B, S, E) -> (y (B, S, E), the rows this
-    chip's tokens sent to each of ALL the experts (n_experts,) int32); the
-    caller stands in the scope `ffn/moe`.  ``route(xt (T, E), p["router"])
-    -> (weights (T, k) f32, experts (T, k) int32)`` is the model's own
-    router, run under `route`; the experts are ``ffn`` over ``p``'s stacks
+    chip's tokens sent to each of ALL the experts (n_experts,) int32, and
+    whatever ``route`` gives past its two); the caller stands in the scope
+    `ffn/moe`.  ``route(xt (T, E), p["router"]) -> (weights (T, k) f32,
+    experts (T, k) int32, ...)`` is the model's router, run under `route`:
+    `ops/moe.py:sigmoid_route` gives the two, `softmax_route` its mean for
+    the balance loss besides; the experts are ``ffn`` over ``p``'s stacks
     (`grouped_ffn`), dropless over the ``held`` = (first, count) of them
     that live here, None: all (`ops/moe.py:moe_dispatch`); a shared expert
     is there if ``p`` has "shared", the same ``ffn`` over every token
@@ -271,13 +319,13 @@ def routed_layer(x, p, route, n_experts, held, ffn):
     B, S, E = x.shape
     xt = x.reshape(B * S, E)
     with jax.named_scope("route"):
-        weights, experts = route(xt, p["router"])
+        weights, experts, *more = route(xt, p["router"])
     y, rows = moe_dispatch(xt, weights, experts, n_experts,
                            grouped_ffn(p, ffn), held=held)
     if "shared" in p:
         with jax.named_scope("shared"):
             y = y + dense_ffn(xt, p["shared"], ffn)
-    return y.reshape(B, S, E), rows
+    return (y.reshape(B, S, E), rows, *more)
 
 
 def _back(x, k):
@@ -383,18 +431,6 @@ def causal_conv(v, p, activation=None):
     if activation is not None:
         out = activation(out)
     return out.astype(v.dtype)
-
-
-def gated_rms_norm(y, z, p, groups, eps=1e-5):
-    """Mamba-2's `MambaRMSNormGated`, y and z (..., C): the gate first,
-    y * silu(z), THEN an RMSNorm over each of the ``groups`` runs of
-    C / groups channels, times the gain ``p["scale"]`` (C,).  Statistics
-    in float32; the result in y's type.  z may come as the first C columns
-    of a wider array, which are then read where they lie.
-    `ops/gated_norm.py` has the rule: one Mosaic kernel a pass where a
-    group fills whole lane tiles and the rows divide into a tile, the plain
-    jax form elsewhere."""
-    return gated_norm.gated_rms_norm(y, z, p["scale"], groups, eps)
 
 
 # What a recomputed layer may keep besides its attention kernel's residuals:
@@ -934,6 +970,18 @@ def _update_count(opt_state):
     (_, count), *_ = optax.tree_utils.tree_get_all_with_path(opt_state,
                                                              "count")
     return count
+
+
+def normal_kernel(key, *shape, std=0.02):
+    """{"kernel": Normal(0, ``std``) of ``shape``, float32}: a matrix, or
+    (its "kernel" taken out) a stack of them or an embedding, as an
+    `init_params` draws it from one key."""
+    return {"kernel": jax.random.normal(key, shape, jnp.float32) * std}
+
+
+def unit_scale(width):
+    """{"scale": ones (width,), float32}: a norm's gain as it starts."""
+    return {"scale": jnp.ones((width,), jnp.float32)}
 
 
 def num_params(params) -> int:

@@ -41,12 +41,13 @@ the embedding held here (a slice of the vocabulary is a smaller
 vocabulary).
 
 What it shares with the other models: `models/layers.py` (RMSNorm, RoPE,
-the SwiGLU, `short_conv`, the routed layer, the walk over the layers, the
-head and its chunked loss, the mixed-precision step and its place for state
-that moves by a rule), `parallel/attention.py` (the flash kernels, here with
-fewer key/value heads than query heads) and `ops/moe.py` (dispatch over a
-share of the experts, the sigmoid router, its account and its bias rule);
-the names are those `parallel/sharding.py` lays out.
+the projections into and out of attention, the SwiGLU, `short_conv`, the
+routed layer, the walk over the layers, the head and its chunked loss, the
+mixed-precision step and its place for state that moves by a rule),
+`parallel/attention.py` (the flash kernels, here with fewer key/value heads
+than query heads) and `ops/moe.py` (dispatch over a share of the experts,
+the sigmoid router, its account and its bias rule); the names are those
+`parallel/sharding.py` lays out.
 
 `jax.named_scope`s (`models/layers.py:SCOPES`): embed, norm,
 short_conv/{in_proj,gate_taps,out_proj}, attention/{qkv,kernel,out},
@@ -64,17 +65,19 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.layers import (
+    attention_out,
+    attention_qkv,
     dense_ffn,
     head_and_loss,
-    named,
+    normal_kernel,
     num_params,  # noqa: F401  (`lfm2_moe.num_params` is public)
     rms_norm,
-    rope,
     routed_layer,
     short_conv,
     swiglu,
     train_step,
     trunk,
+    unit_scale,
 )
 from ray_tpu.ops.moe import (
     ROUTING_BIAS,
@@ -153,82 +156,60 @@ def init_params(rng, cfg: Lfm2MoeConfig) -> Dict[str, Any]:
     Names are those `parallel/sharding.py:infer_param_logical_dims` lays
     out; the experts' stacks hold the `cfg.n_held` experts that live here;
     there is no head: it is the embedding."""
-    std = 0.02
     E, H, Hkv, D = cfg.n_embd, cfg.n_head, cfg.n_kv_head, cfg.head_dim
     keys = jax.random.split(rng, 1 + cfg.n_layer)
-
-    def kernel(key, *shape):
-        return {"kernel": jax.random.normal(key, shape, jnp.float32) * std}
-
-    def scale(width=E):
-        return {"scale": jnp.ones((width,), jnp.float32)}
-
     params = {
         "embed_tokens": {
-            "embedding": kernel(keys[0], cfg.vocab_size, E)["kernel"]},
-        "norm_f": scale(),
+            "embedding": normal_kernel(keys[0], cfg.vocab_size, E)["kernel"]},
+        "norm_f": unit_scale(E),
     }
     for i, kind in enumerate(cfg.layer_types):
         ks = jax.random.split(keys[1 + i], 8)
-        layer = {"operator_norm": scale(), "ffn_norm": scale()}
+        layer = {"operator_norm": unit_scale(E), "ffn_norm": unit_scale(E)}
         if kind == CONV:
             bound = cfg.conv_taps ** -0.5
             layer["short_conv"] = {
-                "in_proj": kernel(ks[0], E, 3 * E),
+                "in_proj": normal_kernel(ks[0], E, 3 * E),
                 "conv": {"kernel": jax.random.uniform(
                     ks[1], (E, cfg.conv_taps), jnp.float32, -bound, bound)},
-                "out_proj": kernel(ks[2], E, E),
+                "out_proj": normal_kernel(ks[2], E, E),
             }
         else:
             layer["attn"] = {
-                "q_proj": kernel(ks[0], E, H * D),
-                "k_proj": kernel(ks[1], E, Hkv * D),
-                "v_proj": kernel(ks[2], E, Hkv * D),
-                "o_proj": kernel(ks[3], H * D, E),
-                "q_norm": scale(D),
-                "k_norm": scale(D),
+                "q_proj": normal_kernel(ks[0], E, H * D),
+                "k_proj": normal_kernel(ks[1], E, Hkv * D),
+                "v_proj": normal_kernel(ks[2], E, Hkv * D),
+                "o_proj": normal_kernel(ks[3], H * D, E),
+                "q_norm": unit_scale(D),
+                "k_norm": unit_scale(D),
             }
         if i < cfg.n_dense_layer:
             layer["mlp"] = {
-                "gate_proj": kernel(ks[4], E, cfg.dense_width),
-                "up_proj": kernel(ks[5], E, cfg.dense_width),
-                "down_proj": kernel(ks[6], cfg.dense_width, E)}
+                "gate_proj": normal_kernel(ks[4], E, cfg.dense_width),
+                "up_proj": normal_kernel(ks[5], E, cfg.dense_width),
+                "down_proj": normal_kernel(ks[6], cfg.dense_width, E)}
         else:
             n, W = cfg.n_held, cfg.expert_width
-            router = kernel(ks[4], E, cfg.n_experts)
+            router = normal_kernel(ks[4], E, cfg.n_experts)
             if cfg.use_expert_bias:
                 router[ROUTING_BIAS] = jnp.zeros((cfg.n_experts,),
                                                  jnp.float32)
             layer["moe"] = {
                 "router": router,
-                "wi_gate": kernel(ks[5], n, E, W)["kernel"],
-                "wi_up": kernel(ks[6], n, E, W)["kernel"],
-                "wo": kernel(ks[7], n, W, E)["kernel"],
+                "wi_gate": normal_kernel(ks[5], n, E, W)["kernel"],
+                "wi_up": normal_kernel(ks[6], n, E, W)["kernel"],
+                "wo": normal_kernel(ks[7], n, W, E)["kernel"],
             }
         params[f"layer_{i}"] = layer
     return params
 
 
 def _attention(x, p, cfg: Lfm2MoeConfig):
-    B, S, _ = x.shape
-    H, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-    kernel = lambda name: p[name]["kernel"].astype(x.dtype)
-    with jax.named_scope("qkv"):
-        # the products, before the norms: a norm's backward reads them
-        q, k, v = named(((x @ kernel("q_proj")).reshape(B, S, H, D),
-                         (x @ kernel("k_proj")).reshape(B, S, Hkv, D),
-                         (x @ kernel("v_proj")).reshape(B, S, Hkv, D)),
-                        "attention/qkv")
-        positions = jnp.arange(S)
-        q = rope(rms_norm(q, p["q_norm"], cfg.rms_eps), positions,
-                 cfg.rope_theta)
-        k = rope(rms_norm(k, p["k_norm"], cfg.rms_eps), positions,
-                 cfg.rope_theta)
+    q, k, v = attention_qkv(x, p, cfg.head_dim, cfg.rms_eps, jnp.arange,
+                            cfg.rope_theta)
     with jax.named_scope("kernel"):
         o = attention(q, k, v)        # 8 key/value heads go in as they are
-    with jax.named_scope("out"):
-        return named(o.reshape(B, S, H * D) @ kernel("o_proj"),
-                     "attention/out")
+    return attention_out(o, p)
 
 
 def _route(cfg: Lfm2MoeConfig):

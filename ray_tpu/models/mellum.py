@@ -27,7 +27,8 @@ the mixture (`mlp_layer_types` all "sparse"):
     at every length.
   loss: the final RMSNorm, the untied head, next-token cross-entropy; the
     objective adds `aux_weight` x L_B, the routers' load-balancing loss
-    (Switch Transformer's, as `models/sdar.py`), summed over the layers.
+    (Switch Transformer's: `ops/moe.py:balance_loss`), summed over the
+    layers.
     `out["loss"]` is the cross-entropy.
 
 A layer's kind is the NAME of its attention subtree (`models/lfm2_moe.py`'s
@@ -35,9 +36,18 @@ idiom): both kinds have the same leaves, so nothing but the name tells
 them apart, and a name is static: `trunk` walks once and `jax.checkpoint`
 traces one body a kind.
 
-``held`` = (first, count): one chip's share of an expert-parallel layer, as
-`models/sdar.py`.  `vocab_size` is the rows of embedding and head held
-here.
+``held`` = (first, count): one chip's share of an expert-parallel layer:
+router and attention are whole; only the held experts' matrices exist and
+only their part of the sum is computed (`ops/moe.py:moe_dispatch`).
+`vocab_size` is the rows of embedding and head held here.
+
+What it shares with the other models: `models/layers.py` (RMSNorm, RoPE and
+YaRN's table, the projections into and out of attention, the SwiGLU, the
+routed layer, the walk over the layers, the head and its chunked loss, the
+mixed-precision step), `parallel/attention.py` (the flash kernels, here
+under a rule a kind of layer) and `ops/moe.py` (the softmax route, its
+balance loss, the routers' account); this file is the configuration, the
+table of parameters, `rotary` and `rule` by a layer's kind and the `_layer`.
 
 Not here: a multi-token head (the config has no key for one), and serving
 (a cache that holds window and global layers side by side).
@@ -49,6 +59,7 @@ head_and_loss, optimizer_update.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -56,19 +67,21 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.layers import (
+    attention_out,
+    attention_qkv,
     head_and_loss,
-    named,
+    normal_kernel,
     num_params,  # noqa: F401  (`mellum.num_params` is public)
     rms_norm,
-    rope,
     routed_layer,
     swiglu,
     train_step,
     trunk,
+    unit_scale,
     yarn_frequencies,
 )
 from ray_tpu.ops.flash_attention import BlockRule
-from ray_tpu.ops.moe import ROUTE_NAME, routing_account
+from ray_tpu.ops.moe import balance_loss, routing_account, softmax_route
 from ray_tpu.parallel.attention import attention
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -139,42 +152,34 @@ def init_params(rng, cfg: MellumConfig) -> Dict[str, Any]:
     `parallel/sharding.py:infer_param_logical_dims` lays out; a layer's
     attention subtree is named by its kind; the experts' stacks hold the
     `cfg.n_held` experts that live here."""
-    std = 0.02
     E, H, Hkv, D = cfg.n_embd, cfg.n_head, cfg.n_kv_head, cfg.head_dim
     keys = jax.random.split(rng, 2 + cfg.n_layer)
-
-    def kernel(key, *shape):
-        return {"kernel": jax.random.normal(key, shape, jnp.float32) * std}
-
-    def scale(width=E):
-        return {"scale": jnp.ones((width,), jnp.float32)}
-
     params = {
         "embed_tokens": {
-            "embedding": kernel(keys[0], cfg.vocab_size, E)["kernel"]},
-        "norm_f": scale(),
-        "lm_head": kernel(keys[1], E, cfg.vocab_size),
+            "embedding": normal_kernel(keys[0], cfg.vocab_size, E)["kernel"]},
+        "norm_f": unit_scale(E),
+        "lm_head": normal_kernel(keys[1], E, cfg.vocab_size),
     }
     for i, kind in enumerate(cfg.layer_types):
         assert kind in (SLIDING, FULL), kind
         ks = jax.random.split(keys[2 + i], 8)
         n, W = cfg.n_held, cfg.expert_width
         params[f"layer_{i}"] = {
-            "input_norm": scale(),
+            "input_norm": unit_scale(E),
             kind: {
-                "q_proj": kernel(ks[0], E, H * D),
-                "k_proj": kernel(ks[1], E, Hkv * D),
-                "v_proj": kernel(ks[2], E, Hkv * D),
-                "o_proj": kernel(ks[3], H * D, E),
-                "q_norm": scale(D),
-                "k_norm": scale(D),
+                "q_proj": normal_kernel(ks[0], E, H * D),
+                "k_proj": normal_kernel(ks[1], E, Hkv * D),
+                "v_proj": normal_kernel(ks[2], E, Hkv * D),
+                "o_proj": normal_kernel(ks[3], H * D, E),
+                "q_norm": unit_scale(D),
+                "k_norm": unit_scale(D),
             },
-            "post_norm": scale(),
+            "post_norm": unit_scale(E),
             "moe": {
-                "router": kernel(ks[4], E, cfg.n_experts),
-                "wi_gate": kernel(ks[5], n, E, W)["kernel"],
-                "wi_up": kernel(ks[6], n, E, W)["kernel"],
-                "wo": kernel(ks[7], n, W, E)["kernel"],
+                "router": normal_kernel(ks[4], E, cfg.n_experts),
+                "wi_gate": normal_kernel(ks[5], n, E, W)["kernel"],
+                "wi_up": normal_kernel(ks[6], n, E, W)["kernel"],
+                "wo": normal_kernel(ks[7], n, W, E)["kernel"],
             },
         }
     return params
@@ -197,46 +202,18 @@ def rule(cfg: MellumConfig, kind: str) -> BlockRule:
 
 
 def _attention(x, p, cfg: MellumConfig, kind: str):
-    B, S, _ = x.shape
-    H, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-    kernel = lambda name: p[name]["kernel"].astype(x.dtype)
-    with jax.named_scope("qkv"):
-        # the products, before the norms: a norm's backward reads them
-        q, k, v = named(((x @ kernel("q_proj")).reshape(B, S, H, D),
-                         (x @ kernel("k_proj")).reshape(B, S, Hkv, D),
-                         (x @ kernel("v_proj")).reshape(B, S, Hkv, D)),
-                        "attention/qkv")
-        positions = jnp.arange(S)
-        theta, scale = rotary(cfg, kind)
-        q = rope(rms_norm(q, p["q_norm"], cfg.rms_eps), positions, theta,
-                 scale=scale)
-        k = rope(rms_norm(k, p["k_norm"], cfg.rms_eps), positions, theta,
-                 scale=scale)
+    q, k, v = attention_qkv(x, p, cfg.head_dim, cfg.rms_eps, jnp.arange,
+                            *rotary(cfg, kind))
     with jax.named_scope("kernel"):
         o = attention(q, k, v, causal=rule(cfg, kind))
-    with jax.named_scope("out"):
-        return named(o.reshape(B, S, H * D) @ kernel("o_proj"),
-                     "attention/out")
+    return attention_out(o, p)
 
 
-def _route(cfg: MellumConfig, mean_probs=None):
-    """-> route(xt, router) -> (weights (T, k) f32, experts (T, k) int32)
-    over all experts: a softmax over the logits in float32, its top k,
-    over their sum if `norm_topk_prob` (`models/sdar.py`'s route).
-    ``mean_probs``: a list that gets the softmax's mean over the rows (N,),
-    for the load-balancing loss."""
-    def route(xt, router):
-        logits = named(jnp.matmul(
-            xt, router["kernel"].astype(xt.dtype),
-            preferred_element_type=jnp.float32), ROUTE_NAME)      # (T, N)
-        probs = jax.nn.softmax(logits, axis=-1)
-        if mean_probs is not None:
-            mean_probs.append(jnp.mean(probs, axis=0))
-        weights, experts = jax.lax.top_k(probs, cfg.top_k)
-        if cfg.norm_topk_prob:
-            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-        return weights, named(experts, ROUTE_NAME)
-    return route
+def _route(cfg: MellumConfig):
+    """-> route(xt, router) -> (weights (T, k) f32, experts (T, k) int32,
+    the softmax's mean over the rows (N,)) over all experts."""
+    return functools.partial(softmax_route, top_k=cfg.top_k,
+                             renormalise=cfg.norm_topk_prob)
 
 
 def _layer(x, p, cfg: MellumConfig):
@@ -249,15 +226,10 @@ def _layer(x, p, cfg: MellumConfig):
     x = x + y
     u = rms_norm(x, p["post_norm"], cfg.rms_eps)
     with jax.named_scope("ffn"), jax.named_scope("moe"):
-        mean_probs = []
-        y, rows = routed_layer(u, p["moe"], _route(cfg, mean_probs),
-                               cfg.n_experts, cfg.held, swiglu)
-        with jax.named_scope("route"):
-            # each expert's share of the T k assignments (a count: no
-            # gradient) against its mean probability
-            share = rows.astype(jnp.float32) / (u.shape[0] * u.shape[1]
-                                                * cfg.top_k)
-            balance = cfg.n_experts * jnp.sum(share * mean_probs[0])
+        y, rows, mean_prob = routed_layer(u, p["moe"], _route(cfg),
+                                          cfg.n_experts, cfg.held, swiglu)
+        balance = balance_loss(rows, mean_prob,
+                               u.shape[0] * u.shape[1] * cfg.top_k)
     return x + y, {"rows": rows, "aux_loss": balance}
 
 

@@ -58,15 +58,16 @@ of the sum is computed (`ops/moe.py:moe_dispatch`).  `vocab_size` is the
 rows of the embedding and of the head held here.
 
 What it shares with the other models: `models/layers.py` (RMSNorm,
-`causal_conv`, `gated_rms_norm`, the ungated feed-forward `relu2`, the
+`causal_conv`, the projections into attention (W_o's result carries no name
+here, so the way out is this file's), the ungated feed-forward `relu2`, the
 routed layer, the walk over the layers, the head and its chunked loss, the
 mixed-precision step and its place for state that moves by a rule),
 `parallel/attention.py` (the flash kernels, 16 query heads on each
 key/value head), `ops/moe.py` (dispatch over a share of the experts, the
 sigmoid router, its account and its bias rule), `ops/ssd.py` (the scan's
-kernels) and, through `gated_rms_norm`, `ops/gated_norm.py` (the gate and the
-groups' norm behind the scan, one Mosaic kernel a pass at the published
-widths); the names are those `parallel/sharding.py` lays out.
+kernels) and `ops/gated_norm.py` (`gated_rms_norm`: the gate and the groups'
+norm behind the scan, one Mosaic kernel a pass at the published widths); the
+names are those `parallel/sharding.py` lays out.
 
 `jax.named_scope`s (`models/layers.py:SCOPES`): embed, norm,
 ssm/{in_proj,conv,scan,gate_norm,out_proj}, attention/{qkv,kernel,out},
@@ -85,17 +86,20 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.layers import (
+    attention_qkv,
     causal_conv,
-    gated_rms_norm,
     head_and_loss,
     named,
+    normal_kernel,
     num_params,  # noqa: F401  (`nemotron_h.num_params` is public)
     relu2,
     rms_norm,
     routed_layer,
     train_step,
     trunk,
+    unit_scale,
 )
+from ray_tpu.ops.gated_norm import gated_rms_norm
 from ray_tpu.ops.moe import (
     ROUTING_BIAS,
     routing_account,
@@ -187,26 +191,18 @@ def init_params(rng, cfg: NemotronHConfig) -> Dict[str, Any]:
     is left.  Names are those `parallel/sharding.py:
     infer_param_logical_dims` lays out; the experts' stacks hold the
     `cfg.n_held` experts that live here."""
-    std, E = 0.02, cfg.n_embd
-    down = 1.0 / math.sqrt(cfg.rescale_depth)
+    E = cfg.n_embd
+    down = 0.02 * (1.0 / math.sqrt(cfg.rescale_depth))
     keys = jax.random.split(rng, 2 + cfg.n_layer)
-
-    def kernel(key, *shape, scale=1.0):
-        return {"kernel": jax.random.normal(key, shape, jnp.float32)
-                * (std * scale)}
-
-    def gain(width=E):
-        return {"scale": jnp.ones((width,), jnp.float32)}
-
     params = {
         "embed_tokens": {
-            "embedding": kernel(keys[0], cfg.vocab_size, E)["kernel"]},
-        "norm_f": gain(),
-        "lm_head": kernel(keys[1], E, cfg.vocab_size),
+            "embedding": normal_kernel(keys[0], cfg.vocab_size, E)["kernel"]},
+        "norm_f": unit_scale(E),
+        "lm_head": normal_kernel(keys[1], E, cfg.vocab_size),
     }
     for i, kind in enumerate(cfg.pattern):
         ks = jax.random.split(keys[2 + i], 6)
-        layer = {"norm": gain()}
+        layer = {"norm": unit_scale(E)}
         if kind == MAMBA:
             H, HP, C = cfg.mamba_heads, cfg.mamba_width, cfg.conv_width
             bound = cfg.conv_taps ** -0.5
@@ -215,7 +211,7 @@ def init_params(rng, cfg: NemotronHConfig) -> Dict[str, Any]:
                 math.log(cfg.time_step_max)))
             dt = jnp.maximum(dt, cfg.time_step_floor)
             layer["mamba"] = {
-                "in_proj": kernel(ks[0], E, HP + C + H),
+                "in_proj": normal_kernel(ks[0], E, HP + C + H),
                 "conv": {
                     "kernel": jax.random.uniform(
                         ks[1], (C, cfg.conv_taps), jnp.float32, -bound,
@@ -225,29 +221,29 @@ def init_params(rng, cfg: NemotronHConfig) -> Dict[str, Any]:
                 "A_log": jnp.log(jnp.arange(1, H + 1, dtype=jnp.float32)),
                 "D": jnp.ones((H,), jnp.float32),
                 "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-                "norm": gain(HP),
-                "out_proj": kernel(ks[4], HP, E, scale=down),
+                "norm": unit_scale(HP),
+                "out_proj": normal_kernel(ks[4], HP, E, std=down),
             }
         elif kind == ATTENTION:
             H, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
             layer["attn"] = {
-                "q_proj": kernel(ks[0], E, H * D),
-                "k_proj": kernel(ks[1], E, Hkv * D),
-                "v_proj": kernel(ks[2], E, Hkv * D),
-                "o_proj": kernel(ks[3], H * D, E, scale=down),
+                "q_proj": normal_kernel(ks[0], E, H * D),
+                "k_proj": normal_kernel(ks[1], E, Hkv * D),
+                "v_proj": normal_kernel(ks[2], E, Hkv * D),
+                "o_proj": normal_kernel(ks[3], H * D, E, std=down),
             }
         elif kind == MOE:
             n, W = cfg.n_held, cfg.expert_width
             layer["moe"] = {
                 "router": {
-                    **kernel(ks[0], E, cfg.n_experts),
+                    **normal_kernel(ks[0], E, cfg.n_experts),
                     ROUTING_BIAS: jnp.zeros((cfg.n_experts,), jnp.float32)},
-                "wi_up": kernel(ks[1], n, E, W)["kernel"],
-                "wo": kernel(ks[2], n, W, E, scale=down)["kernel"],
+                "wi_up": normal_kernel(ks[1], n, E, W)["kernel"],
+                "wo": normal_kernel(ks[2], n, W, E, std=down)["kernel"],
                 "shared": {
-                    "up_proj": kernel(ks[3], E, cfg.shared_width),
-                    "down_proj": kernel(ks[4], cfg.shared_width, E,
-                                        scale=down)},
+                    "up_proj": normal_kernel(ks[3], E, cfg.shared_width),
+                    "down_proj": normal_kernel(ks[4], cfg.shared_width, E,
+                                               std=down)},
             }
         else:
             raise ValueError(f"layer {i}: {kind!r} is no kind of mixer")
@@ -276,7 +272,7 @@ def _mamba(u, p, cfg: NemotronHConfig):
     with jax.named_scope("gate_norm"):
         # z where it lies, W_in's first HP columns: sliced out for a kernel
         # it cost a copy a layer and pass (PERF.md §6, PR 55)
-        y = gated_rms_norm(y, zxbcdt, p["norm"], G, cfg.rms_eps)
+        y = gated_rms_norm(y, zxbcdt, p["norm"]["scale"], G, cfg.rms_eps)
     with jax.named_scope("out_proj"):
         # the layer's last product: it is added to the stream and no
         # backward reads it, so it carries no name to keep
@@ -285,17 +281,12 @@ def _mamba(u, p, cfg: NemotronHConfig):
 
 def _attention(u, p, cfg: NemotronHConfig):
     B, S, _ = u.shape
-    H, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-    kernel = lambda name: p[name]["kernel"].astype(u.dtype)
-    with jax.named_scope("qkv"):
-        q, k, v = named(((u @ kernel("q_proj")).reshape(B, S, H, D),
-                         (u @ kernel("k_proj")).reshape(B, S, Hkv, D),
-                         (u @ kernel("v_proj")).reshape(B, S, Hkv, D)),
-                        "attention/qkv")
+    q, k, v = attention_qkv(u, p, cfg.head_dim)     # no norm, no RoPE
     with jax.named_scope("kernel"):
         o = attention(q, k, v)        # 2 key/value heads go in as they are
     with jax.named_scope("out"):
-        return o.reshape(B, S, H * D) @ kernel("o_proj")    # as W_out's
+        # as W_out's: no name, so not `layers.attention_out`
+        return o.reshape(B, S, -1) @ p["o_proj"]["kernel"].astype(u.dtype)
 
 
 def _route(cfg: NemotronHConfig):

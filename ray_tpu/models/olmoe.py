@@ -44,12 +44,14 @@ from ray_tpu.models.layers import (
     grouped_ffn,
     head_and_loss,
     named,
+    normal_kernel,
     num_params,  # noqa: F401  (`olmoe.num_params` is public)
     rms_norm,
     rope,
     swiglu,
     train_step,
     trunk,
+    unit_scale,
 )
 from ray_tpu.ops.moe import ROUTE_NAME, moe_dispatch
 from ray_tpu.parallel.attention import attention
@@ -96,39 +98,32 @@ def init_params(rng, cfg: OlmoeConfig) -> Dict[str, Any]:
     config), unit norm scales.  Names are those `parallel/sharding.py:
     infer_param_logical_dims` lays out: the experts' stacks are
     ("expert", "embed", "mlp") / ("expert", "mlp", "embed")."""
-    std = 0.02
     E, W, N = cfg.n_embd, cfg.expert_width, cfg.n_experts
     keys = jax.random.split(rng, 2 + cfg.n_layer)
-
-    def normal(key, shape):
-        return jax.random.normal(key, shape, jnp.float32) * std
-
-    def scale():
-        return {"scale": jnp.ones((E,), jnp.float32)}
-
     params = {
-        "embed_tokens": {"embedding": normal(keys[0], (cfg.vocab_size, E))},
-        "norm_f": scale(),
-        "lm_head": {"kernel": normal(keys[1], (E, cfg.vocab_size))},
+        "embed_tokens": {
+            "embedding": normal_kernel(keys[0], cfg.vocab_size, E)["kernel"]},
+        "norm_f": unit_scale(E),
+        "lm_head": normal_kernel(keys[1], E, cfg.vocab_size),
     }
     for i in range(cfg.n_layer):
         ks = jax.random.split(keys[2 + i], 8)
         params[f"layer_{i}"] = {
-            "input_norm": scale(),
+            "input_norm": unit_scale(E),
             "attn": {
-                "q_proj": {"kernel": normal(ks[0], (E, E))},
-                "k_proj": {"kernel": normal(ks[1], (E, E))},
-                "v_proj": {"kernel": normal(ks[2], (E, E))},
-                "o_proj": {"kernel": normal(ks[3], (E, E))},
-                "q_norm": scale(),
-                "k_norm": scale(),
+                "q_proj": normal_kernel(ks[0], E, E),
+                "k_proj": normal_kernel(ks[1], E, E),
+                "v_proj": normal_kernel(ks[2], E, E),
+                "o_proj": normal_kernel(ks[3], E, E),
+                "q_norm": unit_scale(E),
+                "k_norm": unit_scale(E),
             },
-            "post_norm": scale(),
+            "post_norm": unit_scale(E),
             "moe": {
-                "router": {"kernel": normal(ks[4], (E, N))},
-                "wi_gate": normal(ks[5], (N, E, W)),
-                "wi_up": normal(ks[6], (N, E, W)),
-                "wo": normal(ks[7], (N, W, E)),
+                "router": normal_kernel(ks[4], E, N),
+                "wi_gate": normal_kernel(ks[5], N, E, W)["kernel"],
+                "wi_up": normal_kernel(ks[6], N, E, W)["kernel"],
+                "wo": normal_kernel(ks[7], N, W, E)["kernel"],
             },
         }
     return params

@@ -28,9 +28,10 @@ Not here: exit at inference (the last walk's logits are the model's:
 stage, which trains the gate alone.
 
 The walk over the layers T times is `models/layers.py:trunk`'s (``walks``),
-as are RMSNorm, RoPE, the SwiGLU, what a recomputed layer keeps, the head
-with its chunked loss, the rows weighted, and the mixed-precision step;
-attention is `parallel/attention.py`'s.  This file is the configuration,
+as are RMSNorm, RoPE, the projections into and out of attention, the SwiGLU,
+what a recomputed layer keeps, the head with its chunked loss, the rows
+weighted, and the mixed-precision step; the kernels between the projections
+are `parallel/attention.py`'s.  This file is the configuration,
 `init_params`, the sandwich `_layer`, the gate and the objective.  A weight's
 gradient is the sum of its T uses: `layers.train_step` hands the objective
 the matrices cast to the compute type once, so the T cotangents meet in that
@@ -51,15 +52,17 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.layers import (
+    attention_out,
+    attention_qkv,
     dense_ffn,
     head_and_weighted_loss,
-    named,
+    normal_kernel,
     num_params,  # noqa: F401  (`ouro.num_params` is public)
     rms_norm,
-    rope,
     swiglu,
     train_step,
     trunk,
+    unit_scale,
 )
 from ray_tpu.parallel.attention import attention
 
@@ -99,60 +102,42 @@ def init_params(rng, cfg: OuroConfig) -> Dict[str, Any]:
     """Normal(0, 0.02) matrices, the gate's among them, unit norm gains,
     the gate's bias 0.  Names are those `parallel/sharding.py:
     infer_param_logical_dims` lays out."""
-    std = 0.02
     E, W = cfg.n_embd, cfg.dense_width
     H, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     keys = jax.random.split(rng, 3 + cfg.n_layer)
-
-    def matrix(key, shape):
-        return {"kernel": jax.random.normal(key, shape, jnp.float32) * std}
-
-    def scale():
-        return {"scale": jnp.ones((E,), jnp.float32)}
-
     params = {
-        "embed_tokens": {"embedding": matrix(
-            keys[0], (cfg.vocab_size, E))["kernel"]},
-        "norm_f": scale(),
-        "lm_head": matrix(keys[1], (E, cfg.vocab_size)),
-        "exit_gate": dict(matrix(keys[2], (E, 1)),
+        "embed_tokens": {
+            "embedding": normal_kernel(keys[0], cfg.vocab_size, E)["kernel"]},
+        "norm_f": unit_scale(E),
+        "lm_head": normal_kernel(keys[1], E, cfg.vocab_size),
+        "exit_gate": dict(normal_kernel(keys[2], E, 1),
                           bias=jnp.zeros((1,), jnp.float32)),
     }
     for i in range(cfg.n_layer):
         ks = jax.random.split(keys[3 + i], 7)
         params[f"layer_{i}"] = {
-            "input_norm": scale(),
-            "attn": {"q_proj": matrix(ks[0], (E, H * D)),
-                     "k_proj": matrix(ks[1], (E, Hkv * D)),
-                     "v_proj": matrix(ks[2], (E, Hkv * D)),
-                     "o_proj": matrix(ks[3], (H * D, E))},
-            "input_norm_2": scale(),
-            "post_norm": scale(),
-            "mlp": {"gate_proj": matrix(ks[4], (E, W)),
-                    "up_proj": matrix(ks[5], (E, W)),
-                    "down_proj": matrix(ks[6], (W, E))},
-            "post_norm_2": scale(),
+            "input_norm": unit_scale(E),
+            "attn": {"q_proj": normal_kernel(ks[0], E, H * D),
+                     "k_proj": normal_kernel(ks[1], E, Hkv * D),
+                     "v_proj": normal_kernel(ks[2], E, Hkv * D),
+                     "o_proj": normal_kernel(ks[3], H * D, E)},
+            "input_norm_2": unit_scale(E),
+            "post_norm": unit_scale(E),
+            "mlp": {"gate_proj": normal_kernel(ks[4], E, W),
+                    "up_proj": normal_kernel(ks[5], E, W),
+                    "down_proj": normal_kernel(ks[6], W, E)},
+            "post_norm_2": unit_scale(E),
         }
     return params
 
 
 def _attention(x, p, cfg: OuroConfig):
-    B, S, _ = x.shape
-    H, Hkv, D = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-    kernel = lambda name: p[name]["kernel"].astype(x.dtype)
-    with jax.named_scope("qkv"):
-        q, k, v = named(((x @ kernel("q_proj")).reshape(B, S, H, D),
-                         (x @ kernel("k_proj")).reshape(B, S, Hkv, D),
-                         (x @ kernel("v_proj")).reshape(B, S, Hkv, D)),
-                        "attention/qkv")
-        positions = jnp.arange(S)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+    # no norm on q or k: the parameters have no gain for one
+    q, k, v = attention_qkv(x, p, cfg.head_dim, positions=jnp.arange,
+                            theta=cfg.rope_theta)
     with jax.named_scope("kernel"):
         o = attention(q, k, v)
-    with jax.named_scope("out"):
-        return named(o.reshape(B, S, H * D) @ kernel("o_proj"),
-                     "attention/out")
+    return attention_out(o, p)
 
 
 def _layer(x, p, cfg: OuroConfig):

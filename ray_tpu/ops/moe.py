@@ -9,10 +9,14 @@ a grouped-matmul kernel), and the rows go back to their tokens and are
 summed with their weights.  Shared by `models/layers.py:routed_layer`
 (`models/deepseek_v3.py`, `models/lfm2_moe.py` and `models/nemotron_h.py`:
 a chip's share of the experts), `models/olmoe.py` (all of them) and
-`models/gpt2.py`'s mixture (GELU experts).  The first three also share the
-router at the end of this file and what a step says of it: sigmoid scores,
-a routing bias that picks and does not weigh and moves by a rule of its own
-(`sigmoid_route`, `routing_account`, `routing_bias_rule`, `trained_by`).
+`models/gpt2.py`'s mixture (GELU experts).  Behind the dispatch, the two
+routers the models of `routed_layer` share and what a step says of them
+(`routing_account`): sigmoid scores with a routing bias that picks and does
+not weigh and moves by a rule of its own (`sigmoid_route`,
+`routing_bias_rule`, `trained_by`: the first three), and a softmax with the
+load-balancing loss that is read off its mean and the rows the dispatch
+counted (`softmax_route`, `balance_loss`: `models/keye_vl.py`,
+`models/sdar.py`, `models/mellum.py`).
 
 Where all the experts live here, the buffer between dispatch and combine
 is the T*k rows.  Where a share of under half of them does (``held``), it
@@ -47,7 +51,7 @@ from ray_tpu.util import tracing
 # The name a router's product, its choices and their order by expert carry
 # for a recomputed layer (`models/layers.py:KEPT_NAMES`, the first of them):
 # kilobytes a token, and with them kept a replay runs no router and no sort.
-# A softmax router in a model file marks its logits with the same word.
+# OLMoE's router, in its model file, marks its logits with the same word.
 ROUTE_NAME = "ffn/moe/route"
 
 # A share's buffer over the rows a balanced router sends it.  Twice: a
@@ -403,3 +407,35 @@ def trained_by(optimizer):
 
     return optax.multi_transform(
         {"optimizer": optimizer, "rule": optax.set_to_zero()}, labels)
+
+
+# -- the softmax router with a load-balancing loss (Qwen3-MoE's) -------------
+
+def softmax_route(xt, router, top_k, renormalise):
+    """xt (T, E) -> (weights (T, k) f32, experts (T, k) int32, the mean over
+    the rows of the softmax (N,) f32, which `balance_loss` reads) over all
+    the experts ``router["kernel"]`` (E, N) scores: p = softmax(xt W) in
+    float32; the top k of p; weights p at the chosen, over their sum if
+    ``renormalise``."""
+    # the logits: a softmax's and a top-k's backward read their own
+    # results, which a replay makes from these
+    logits = checkpoint_name(jnp.matmul(
+        xt, router["kernel"].astype(xt.dtype),
+        preferred_element_type=jnp.float32), ROUTE_NAME)          # (T, N)
+    probs = jax.nn.softmax(logits, axis=-1)
+    mean = jnp.mean(probs, axis=0)
+    weights, experts = jax.lax.top_k(probs, top_k)
+    if renormalise:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, checkpoint_name(experts, ROUTE_NAME), mean
+
+
+def balance_loss(rows, mean_probs, routed):
+    """A router's load-balancing loss (Switch Transformer's), N sum_e f_e
+    P_e, under `route`: f_e the share of the ``routed`` = T*k assignments
+    that went to expert e, from the ``rows`` (N,) `moe_dispatch` counted (a
+    count: no gradient), P_e the batch's mean probability
+    (`softmax_route`'s third).  1 for a router in balance."""
+    with jax.named_scope("route"):
+        share = rows.astype(jnp.float32) / routed
+        return rows.shape[0] * jnp.sum(share * mean_probs)

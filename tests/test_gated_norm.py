@@ -89,10 +89,10 @@ def test_the_kernels_are_the_rule_in_float64(groups):
     parts = g.reshape(*g.shape[:-1], groups, -1)
     parts = parts / np.sqrt((parts ** 2).mean(-1, keepdims=True) + EPS)
     want = parts.reshape(g.shape) * f64(gain)
-    got = layers.gated_rms_norm(y, z, {"scale": gain}, groups, EPS)
+    got = gn.gated_rms_norm(y, z, gain, groups, EPS)
     np.testing.assert_allclose(got, want, atol=1e-5)
     # one norm for all the groups is another function
-    other = layers.gated_rms_norm(y, z, {"scale": gain}, 1, EPS)
+    other = gn.gated_rms_norm(y, z, gain, 1, EPS)
     assert np.abs(np.asarray(other) - want).max() > 0.05
 
 
@@ -133,8 +133,8 @@ def test_it_counts_the_rows_it_fuses_once_a_traced_call():
     rows = y.shape[0] * y.shape[1]
 
     def traced():
-        jax.eval_shape(lambda y, z: layers.gated_rms_norm(
-            y, z, {"scale": gain}, 8, EPS), y, z)
+        jax.eval_shape(lambda y, z: gn.gated_rms_norm(y, z, gain, 8, EPS),
+                       y, z)
         return tracing.counter("ssm.gate_norm_rows_fused")
 
     assert traced() == 0                    # no job, no count
@@ -152,7 +152,7 @@ def test_a_replayed_layer_gives_the_same_gradients():
     y, z, gain, dout = make()
 
     def layer(y, z, gain):
-        out = layers.gated_rms_norm(jnp.tanh(y), z, {"scale": gain}, 8, EPS)
+        out = gn.gated_rms_norm(jnp.tanh(y), z, gain, 8, EPS)
         return jnp.sum(jnp.square(out) * dout)
 
     walked = jax.jit(jax.value_and_grad(layer, (0, 1, 2)))(y, z, gain)

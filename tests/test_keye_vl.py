@@ -288,7 +288,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     for first in range(0, 64, 8):
         share = {**p, **{k: p[k][first:first + 8]
                          for k in ("wi_gate", "wi_up", "wo")}}
-        y, sent = layers.routed_layer(u, share, model._route(cfg), 64,
+        y, sent, _ = layers.routed_layer(u, share, model._route(cfg), 64,
                                       (first, 8), layers.swiglu)
         total += y
         rows.append(sent)

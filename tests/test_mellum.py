@@ -298,7 +298,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     for first in range(0, 64, 16):
         share = {**p, **{k: p[k][first:first + 16]
                          for k in ("wi_gate", "wi_up", "wo")}}
-        y, sent = layers.routed_layer(u, share, model._route(cfg), 64,
+        y, sent, _ = layers.routed_layer(u, share, model._route(cfg), 64,
                                       (first, 16), layers.swiglu)
         total += y
         rows.append(sent)

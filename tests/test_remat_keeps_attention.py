@@ -518,7 +518,8 @@ def test_without_remat_a_mark_is_nothing(name, monkeypatch):
     assert plans == []
     bare = lambda x, name: x
     monkeypatch.setattr(layers, "named", bare)
-    monkeypatch.setattr(module, "named", bare)
+    # a model file that marks nothing itself imports no `named`
+    monkeypatch.setattr(module, "named", bare, raising=False)
     from ray_tpu.ops import moe
     monkeypatch.setattr(moe, "checkpoint_name", bare)
     jax.clear_caches()

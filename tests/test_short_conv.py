@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import layers
+from ray_tpu.ops.gated_norm import gated_rms_norm
 from ray_tpu.util import tracing
 
 B, S, E, L = 2, 12, 8, 3
@@ -205,11 +206,11 @@ def test_gated_rms_norm_is_the_gate_then_a_norm_a_group(groups):
     parts = g.reshape(B, S, groups, 16 // groups)
     parts = parts / np.sqrt((parts ** 2).mean(-1, keepdims=True) + 1e-5)
     want = parts.reshape(B, S, 16) * np.asarray(gain)
-    got = layers.gated_rms_norm(y, z, {"scale": gain}, groups, 1e-5)
+    got = gated_rms_norm(y, z, gain, groups, 1e-5)
     np.testing.assert_allclose(got, want, atol=1e-5)
     # the norm before the gate is another function
-    other = np.asarray(layers.gated_rms_norm(
-        y, jnp.ones_like(z) * 30.0, {"scale": gain}, groups, 1e-5)) \
+    other = np.asarray(gated_rms_norm(
+        y, jnp.ones_like(z) * 30.0, gain, groups, 1e-5)) \
         * np.asarray(jax.nn.silu(z)) / 30.0
     assert np.abs(other - want).max() > 0.1
 
