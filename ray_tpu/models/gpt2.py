@@ -39,6 +39,7 @@ from ray_tpu.models.layers import (
     named,
     train_step,
 )
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.ops.moe import ROUTE_NAME, moe_dispatch
 from ray_tpu.parallel.attention import attention
 from ray_tpu.parallel.sharding import constrain
@@ -172,8 +173,8 @@ def _moe_mlp(x, p, cfg: GPT2Config):
     wi, wo = p["wi"].astype(x.dtype), p["wo"].astype(x.dtype)
 
     def gelu_experts(xs, group_sizes):
-        h = jax.nn.gelu(jax.lax.ragged_dot(xs, wi, group_sizes))
-        return jax.lax.ragged_dot(h, wo, group_sizes)
+        matmul = grouped_matmul.over(group_sizes, xs.shape[0])
+        return matmul(jax.nn.gelu(matmul(xs, wi)), wo)
 
     y, _ = moe_dispatch(xt, gate_vals, gate_idx, cfg.moe_experts,
                         gelu_experts)

@@ -61,6 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.ops.flash_attention import KEPT_RESIDUALS, KERNEL_FORMS
 from ray_tpu.ops.moe import ROUTE_NAME, moe_dispatch
 from ray_tpu.parallel.context import get_mesh
@@ -107,13 +108,15 @@ SCOPES = (
     "routing_bias_update",
 )
 
-# What the compiler names itself: XLA:TPU replaces `jax.lax.ragged_dot` by
-# its own grouped-matmul kernel, whose `op_name` is the compiler's
+# What the compiler names itself: XLA:TPU replaces `jax.lax.ragged_dot`, the
+# form a shape that `ops/grouped_matmul.py`'s kernels decline takes, by its
+# own grouped-matmul kernel, whose `op_name` is the compiler's
 # (`ragged-dot-none`, and `ragged-dot-metadata` for the kernel that lays out
-# the groups) and no longer the caller's.  Every `ragged_dot` of the
-# Train-path models is the routed experts' (`tests/test_scopes.py` holds them
-# to it), so a reader puts an operation whose name starts so under that
-# scope; whether it ran forward or backward the name does not say.
+# the groups) and no longer the caller's (the repo's kernels carry the
+# caller's).  Every `ragged_dot` of the Train-path models is the routed
+# experts' (`tests/test_scopes.py` holds them to it), so a reader puts an
+# operation whose name starts so under that scope; whether it ran forward or
+# backward the name does not say.
 COMPILER_NAMED = (("ragged-dot", "ffn/moe/experts"),)
 
 
@@ -268,19 +271,15 @@ def dense_ffn(x, p, ffn):
                     ("gate_proj", "up_proj", "down_proj") if name in p))
 
 
-# XLA:TPU's grouped-matmul kernel (`ragged_dot`) runs an expert width that is
-# no multiple of this at under half its speed: 8 groups of 768 rows, 2,688
-# wide, forward / forward + backward ms, at 1,856 wide 4.49 / 14.48, at 1,920
-# the same, at 2,048 1.83 / 6.81 (PERF.md §6, PR 38)
-_GROUPED_WIDTH = 256
-
-
 def _widened(w, axis):
-    """A stack of expert matrices with zeros up to a whole `_GROUPED_WIDTH`
-    along ``axis``, the experts' hidden width: silu(0) * 0 and relu(0)^2 are
-    0, times rows of zeros they add nothing, and no gradient comes back to
-    the zeros.  A width that is a multiple already is left as it is."""
-    extra = -w.shape[axis] % _GROUPED_WIDTH
+    """A stack of expert matrices with zeros along ``axis``, the experts'
+    hidden width, up to whole lane tiles, which is all the grouped kernels
+    ask of a width (`ops/grouped_matmul.py`; XLA:TPU's own kernel wanted
+    whole 256s and ran 1,856 and 1,920 alike at under half its speed at
+    2,048, PERF.md §6, PR 38): silu(0) * 0 and relu(0)^2 are 0, times rows
+    of zeros they add nothing, and no gradient comes back to the zeros.  A
+    width of whole lane tiles is left as it is."""
+    extra = -w.shape[axis] % grouped_matmul.LANE
     if not extra:
         return w
     pad = [(0, 0)] * w.ndim
@@ -291,16 +290,17 @@ def _widened(w, axis):
 def grouped_ffn(p, ffn):
     """-> `run_experts(rows, group_sizes)` for `ops/moe.py:moe_dispatch`:
     ``ffn`` (`swiglu` or `relu2`) over rows sorted by expert, every product
-    a grouped matmul (`jax.lax.ragged_dot`) with one of ``p``'s stacks, an
-    expert a matrix: "wi_gate" where the experts are gated, "wi_up" (n, E,
-    W) and "wo" (n, W, E), each widened to XLA:TPU's grouped width
-    (`_widened`) once, outside the run."""
+    a grouped matmul (`ops/grouped_matmul.py`, one walk of the rows for
+    all of them) with one of ``p``'s stacks, an expert a matrix: "wi_gate"
+    where the experts are gated, "wi_up" (n, E, W) and "wo" (n, W, E),
+    each widened to whole lane tiles (`_widened`) once, outside the
+    run."""
     stacks = [_widened(p[name], axis) for name, axis in
               (("wi_gate", 2), ("wi_up", 2), ("wo", 1)) if name in p]
 
     def run(xs, group_sizes):
-        grouped = lambda a, w: jax.lax.ragged_dot(a, w, group_sizes)
-        return ffn(xs, *stacks, matmul=grouped)
+        return ffn(xs, *stacks, matmul=grouped_matmul.over(
+            group_sizes, xs.shape[0]))
     return run
 
 

@@ -4,9 +4,9 @@ A routed mixture multiplies each token by the few experts its router
 chose.  Here no token is ever dropped and no expert has a capacity: the
 T*k (token, expert) rows are sorted by expert, so that each expert's rows
 are one contiguous group of whatever size the router made it, the experts
-run over the ragged groups (`jax.lax.ragged_dot`, which XLA:TPU lowers to
-a grouped-matmul kernel), and the rows go back to their tokens and are
-summed with their weights.  Shared by `models/layers.py:routed_layer`
+run over the ragged groups (`ops/grouped_matmul.py`: the repo's grouped
+matmul kernels), and the rows go back to their tokens and are summed with
+their weights.  Shared by `models/layers.py:routed_layer`
 (`models/deepseek_v3.py`, `models/lfm2_moe.py` and `models/nemotron_h.py`:
 a chip's share of the experts), `models/olmoe.py` (all of them) and
 `models/gpt2.py`'s mixture (GELU experts).  Behind the dispatch, the two

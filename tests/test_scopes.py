@@ -360,3 +360,58 @@ def test_no_split_backward_remains():
     source = inspect.getsource(fa) + inspect.getsource(layers)
     for gone in ("bwd_dq", "bwd_dkv", "_SPLIT_BWD_MAX_BLOCK"):
         assert gone not in source, gone
+
+
+def _experts_ops(E, W, held, ffn):
+    """[(operation, scope)] of the grouped products and kernels of one
+    routed layer's value and gradient, lowered for a TPU: 64 rows of 2,
+    8 experts of hidden width W on a hidden size of E."""
+    from ray_tpu.ops.moe import sigmoid_route
+
+    n, count = 8, held[1] if held else 8
+    stack = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    p = {"router": {"kernel": stack(E, n)}, "wi_up": stack(count, E, W),
+         "wo": stack(count, W, E)}
+    if ffn is layers.swiglu:
+        p["wi_gate"] = stack(count, E, W)
+
+    def loss(x, p):
+        with jax.named_scope("ffn"), jax.named_scope("moe"):
+            y, _ = layers.routed_layer(
+                x, p, lambda xt, router: sigmoid_route(xt, router, 2, 1e-20,
+                                                       1.0), n, held, ffn)
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1))).trace(stack(1, 64, E), p).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    return [(op, scope(full)) for op, full in full_names(
+        text, re.compile(r"chlo\.ragged_dot|@tpu_custom_call"))
+        if "dispatch" not in full and "combine" not in full]
+
+
+@pytest.mark.parametrize("ffn", [layers.swiglu, layers.relu2])
+@pytest.mark.parametrize("held", [None, (2, 2)])
+@pytest.mark.parametrize("width", [128, 100])
+def test_the_experts_lower_to_the_grouped_kernels_under_their_scope(
+        width, held, ffn):
+    """A hidden size of whole lane tiles (every routed cell's), whatever
+    the experts' width (`layers._widened`): each of a layer's products is
+    the repo's Mosaic kernel, forward and both gradients, under
+    `ffn/moe/experts` by the caller's name, and no `ragged_dot` is left
+    (a held share traces its products at both buffer lengths, and its
+    backward what it needs of the forward again)."""
+    found = _experts_ops(128, width, held, ffn)
+    assert {op for op, _ in found} == {"@tpu_custom_call"}
+    assert {where for _, where in found} == {"ffn/moe/experts"}
+    stacks = 3 if ffn is layers.swiglu else 2
+    assert len(found) == stacks * 3 if held is None \
+        else len(found) >= 2 * stacks * 3
+
+
+@pytest.mark.parametrize("ffn", [layers.swiglu, layers.relu2])
+def test_a_declined_width_lowers_to_ragged_dot_under_the_same_scope(ffn):
+    """A hidden size of half a lane tile: `ragged_dot`, still the
+    experts'."""
+    found = _experts_ops(64, 128, None, ffn)
+    assert {op for op, _ in found} == {"chlo.ragged_dot"}
+    assert {where for _, where in found} == {"ffn/moe/experts"}

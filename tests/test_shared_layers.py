@@ -36,7 +36,7 @@ from ray_tpu.ops.moe import ROUTING_BIAS
 
 B, S, E = 2, 16, 16
 N, K = 8, 2
-WIDTH, SHARED_WIDTH = 24, 40        # no multiple of the grouped width
+WIDTH, SHARED_WIDTH = 24, 40        # no whole number of lane tiles
 TOL = 2e-5
 
 
@@ -168,9 +168,11 @@ def test_the_routed_layer_is_the_loop_over_tokens(route, ffn, shared, share):
 
 @pytest.mark.parametrize("ffn", sorted(FFNS))
 def test_a_widened_stack_gives_the_unpadded_result(ffn, monkeypatch):
-    """24 wide runs 256 wide (`layers._widened`): the zeros add nothing to
-    the output and take no gradient, and the parameters keep their
-    shape."""
+    """24 wide runs 128 wide, whole lane tiles and no more
+    (`layers._widened`; 16 of hidden width is a shape the grouped kernels
+    decline, so the products are `ragged_dot`'s and seen here): the zeros
+    add nothing to the output and take no gradient, and the parameters
+    keep their shape."""
     p = routed_params("sigmoid_bias", ffn, True, (0, 4))
     x = jax.random.normal(jax.random.PRNGKey(8), (B, S, E))
 
@@ -186,10 +188,10 @@ def test_a_widened_stack_gives_the_unpadded_result(ffn, monkeypatch):
     monkeypatch.setattr(jax.lax, "ragged_dot", lambda a, w, sizes: (
         seen.append(w.shape), real(a, w, sizes))[1])
     (_, y), grads = step(x, p)
-    assert {256} == {s[2] for s in seen if s[1] == E} \
+    assert {128} == {s[2] for s in seen if s[1] == E} \
         == {s[1] for s in seen if s[2] == E}
     seen.clear()
-    monkeypatch.setattr(layers, "_GROUPED_WIDTH", 1)
+    monkeypatch.setattr(layers, "_widened", lambda w, axis: w)
     (_, y0), grads0 = step(x, p)
     assert {WIDTH} == {s[2] for s in seen if s[1] == E}
     assert max_diff(y, y0) < 1e-6
@@ -197,12 +199,18 @@ def test_a_widened_stack_gives_the_unpadded_result(ffn, monkeypatch):
     assert max(jax.tree.leaves(jax.tree.map(max_diff, grads, grads0))) < 1e-5
 
 
-def test_a_width_in_whole_256s_is_left_alone():
-    """The published widths that are multiples (768, 1,024, 1,792): the
-    stack itself, no pad of width nothing for XLA to find."""
-    w = jnp.ones((2, E, 512))
-    assert layers._widened(w, 2) is w
-    assert layers._widened(jnp.ones((2, 300, E)), 1).shape == (2, 512, E)
+@pytest.mark.parametrize("width, run", [
+    (768, 768), (896, 896), (1024, 1024), (1536, 1536), (1856, 1920),
+    (300, 384)])
+def test_a_width_is_widened_to_whole_lane_tiles_and_no_further(width, run):
+    """The published widths: those of whole lane tiles are the stack
+    itself (mellum2's 896, that XLA's kernel wanted at 1,024: no pad of
+    width nothing for XLA to find), nemotron's 1,856 runs 1,920 wide and
+    not 2,048."""
+    up, down = jnp.ones((2, E, width)), jnp.ones((2, width, E))
+    assert layers._widened(up, 2).shape == (2, E, run)
+    assert layers._widened(down, 1).shape == (2, run, E)
+    assert (layers._widened(up, 2) is up) == (width == run)
 
 
 # -- the walk over the layers -------------------------------------------------

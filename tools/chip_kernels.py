@@ -5,6 +5,7 @@ reference, with the device time of its forward and of its backward.
     python tools/chip_kernels.py --sweep    # tile -> ms at the cells' shapes
     python tools/chip_kernels.py --sweep s512-d64   # at the named shapes only
     python tools/chip_kernels.py --cases moe_held_8k   # the named cases only
+    python tools/chip_kernels.py --cases moe_all_4k    # the grouped products alone
     python tools/chip_kernels.py --cases head_loss_8k  # the head's loss alone
 
 One case on each side of the gates in ``ops/flash_attention.py``: the lane
@@ -50,7 +51,16 @@ scatter-add), with the kernels' own ms and the largest difference to the
 XLA form, which is 0.  ``moe_held_16k`` is the same at mellum2's share (16
 of 64 experts, 8 choices a token, hidden 2,304, experts 896 wide), whose
 buffer of 65,536 rows is too large for XLA to keep in VMEM.  About six
-minutes a case: the float32 loop is most of it.
+minutes a case: the float32 loop is most of it.  Both end with the grouped
+products alone (`grouped_case`; ``moe_all_4k`` is nothing else, at OLMoE's
+64 groups of 2,048 rows with every expert held): `swiglu` over the three
+stacks of the share at a random router's ragged groups, each product
+`jax.lax.ragged_dot` at the width XLA:TPU's kernel wants (whole 256s) and
+the repo's kernels (`ops/grouped_matmul.py`) at each of ``GROUPED_TILES``
+rows a tile (``kept``: the one `_row_tile` chooses), a line a form: forward
+and forward + backward device ms beside the least time of the operations
+and of the bytes, and the largest error of the result and of the four
+gradients relative to `ragged_dot` over float32 operands.
 
 ``shortconv_8k`` is no attention case either: one gated short convolution
 (`models/layers.py:short_conv`) at (2, 8192, 2048) with 3 taps, the whole
@@ -177,6 +187,14 @@ MOE_CASES = {
 }
 # the result rows of a grid step the two row kernels (`ops/moe_rows.py`)
 # are timed at, beside the ones `_tile` chooses
+# every expert held: OLMoE's 64 groups of 2,048 rows (`grouped_case` only)
+MOE_ALL_CASES = {
+    "moe_all_4k": (16384, 8, 64, 64, 2048, 1024),
+}
+# the rows of a tile the grouped kernels (`ops/grouped_matmul.py`) are
+# timed at, and the multiple XLA:TPU's own kernel wants of a width
+GROUPED_TILES = (128, 256, 512)
+XLA_GROUPED_WIDTH = 256
 MOE_TAKE_TILES = (128, 256, 512)
 MOE_SUM_TILES = (128, 256)
 # (B, S, H, H_kv, D, block, top_k) of one indexer loss's target
@@ -375,7 +393,7 @@ def moe_case(name, dtype):
     import numpy as np
 
     from ray_tpu.models.layers import swiglu
-    from ray_tpu.ops import moe, moe_rows
+    from ray_tpu.ops import grouped_matmul, moe, moe_rows
 
     T, k, count, n_experts, E, W = MOE_CASES[name]
     held = (0, count)
@@ -395,8 +413,8 @@ def moe_case(name, dtype):
         """The layer with ``over`` between the sorts and the result."""
         def y(experts, x, weights, gate, up, down):
             def run(xs, sizes):
-                grouped = lambda a, w: jax.lax.ragged_dot(a, w, sizes)
-                return swiglu(xs, gate, up, down, matmul=grouped)
+                return swiglu(xs, gate, up, down,
+                              matmul=grouped_matmul.over(sizes, xs.shape[0]))
             if over is None:
                 return moe.moe_dispatch(x, weights, experts, n_experts, run,
                                         held=held)[0]
@@ -516,6 +534,82 @@ def moe_case(name, dtype):
                "kernel_ms": kernel_ms(put, ys), "least_ms": least_ms(T),
                "max_abs_diff_to_xla": round(float(
                    np.max(np.abs(out - kept))), 5)}
+
+
+def grouped_case(name, dtype):
+    """The grouped products of one layer's experts alone, at the case's
+    share and a random router's ragged groups: `swiglu` over the three
+    stacks with each product `jax.lax.ragged_dot` at the width XLA:TPU's
+    kernel wants (whole `XLA_GROUPED_WIDTH`s) and with the repo's kernels
+    (`ops/grouped_matmul.py`) at each of ``GROUPED_TILES`` (``kept``: the
+    one `_row_tile` chooses): a line a form with forward and forward +
+    backward device ms (one `jax.grad` in the rows and the three stacks),
+    the least time of the operations (6 and 18 rows E W at the bf16 peak)
+    and of the bytes, and the largest error of the result and of each
+    gradient relative to `ragged_dot` over float32 operands."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models.layers import swiglu
+    from ray_tpu.ops import grouped_matmul, moe
+
+    T, k, count, n_experts, E, W = {**MOE_CASES, **MOE_ALL_CASES}[name]
+    R = moe.buffer_rows(T * k, count, n_experts)
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    scores = jax.nn.sigmoid(jax.random.normal(ks[1], (T, n_experts)))
+    _, experts = jax.lax.top_k(scores, k)
+    sizes = moe._sort_by_expert(experts, n_experts, (0, count))[1][:count]
+    rows = int(jnp.sum(sizes))
+    held = (jnp.arange(R) < rows)[:, None]
+    xs = jnp.where(held, jax.random.normal(ks[0], (R, E), dtype), 0)
+    stacks = [(0.02 * jax.random.normal(key, shape)).astype(dtype)
+              for key, shape in zip(ks[2:5], ((count, E, W), (count, E, W),
+                                              (count, W, E)))]
+    seed = jnp.where(held, jax.random.normal(ks[5], (R, E), jnp.float32), 0)
+
+    def both(matmul, widen=0):
+        def y(xs, gate, up, down):
+            pad = lambda w, axis: jnp.pad(w, [
+                (0, -w.shape[a] % widen if widen and a == axis else 0)
+                for a in range(3)])
+            return jnp.where(held, swiglu(
+                xs, pad(gate, 2), pad(up, 2), pad(down, 1),
+                matmul=matmul), 0)
+        # the cotangent an argument: a constant of a gigabyte would be
+        # compiled into every form's program
+        return jax.jit(y), jax.jit(jax.grad(
+            lambda *a: jnp.sum(y(*a[:4]).astype(jnp.float32) * a[4]),
+            (0, 1, 2, 3)))
+
+    ragged = lambda a, w: jax.lax.ragged_dot(a, w, sizes)
+    args = (xs, *stacks)
+    exact = both(ragged)
+    in_f32 = [a.astype(jnp.float32) for a in args]
+    want = (exact[0](*in_f32), *exact[1](*in_f32, seed))
+    size = jnp.dtype(dtype).itemsize
+    # the rows read and written by each product and the stacks once
+    moved = size * (rows * (2 * (E + W) + W + E) + 3 * count * E * W)
+    kept = grouped_matmul._row_tile(R)
+    forms = {"ragged_dot": both(ragged, XLA_GROUPED_WIDTH), **{
+        f"kernel_{tile}": both(grouped_matmul.over(sizes, R, tile))
+        for tile in GROUPED_TILES}}
+    for form, (forward, grad) in forms.items():
+        got = (forward(*args), *grad(*args, seed))
+        yield {"case": name, "form": form, "buffer_rows": R,
+               "rows_held": rows, "groups": count,
+               "kept": form == f"kernel_{kept}",
+               "fwd_ms": busy_ms(forward, *args),
+               "fwd_bwd_ms": busy_ms(grad, *args, seed),
+               "least_ops_ms": [round(n * rows * E * W / 197e12 * 1e3, 4)
+                                for n in (6, 18)],
+               "least_bytes_ms": [round(n * moved / 819e9 * 1e3, 4)
+                                  for n in (1, 3)],
+               "rel_err": {what: round(float(
+                   np.max(np.abs(np.asarray(g, np.float32) - np.asarray(w)))
+                   / np.max(np.abs(np.asarray(w)))), 5)
+                   for what, g, w in zip(
+                       ("y", "dxs", "dgate", "dup", "ddown"), got, want)}}
 
 
 def _buffer_of(experts, weights, held, n_experts, C):
@@ -1091,13 +1185,14 @@ def main():
                         help="time forced tiles at these of SWEEP's shapes "
                              f"({', '.join(SWEEP)}; none named: at all)")
     parser.add_argument("--cases", nargs="+", metavar="CASE",
-                        default=[*CASES, *MOE_CASES, *SHORTCONV_CASES,
+                        default=[*CASES, *MOE_CASES, *MOE_ALL_CASES,
+                                 *SHORTCONV_CASES,
                                  *SSD_CASES, *TARGET_CASES, *SCORES_CASES,
                                  *HEAD_CASES, *GATENORM_CASES,
                                  *WINDOW_CASES],
                         help=f"run these only ({', '.join(CASES)}, "
                              f"{', '.join(WINDOW_CASES)}, "
-                             f"{', '.join(MOE_CASES)}, "
+                             f"{', '.join((*MOE_CASES, *MOE_ALL_CASES))}, "
                              f"{', '.join(SHORTCONV_CASES)}, "
                              f"{', '.join(SSD_CASES)}, "
                              f"{', '.join(TARGET_CASES)}, "
@@ -1108,7 +1203,8 @@ def main():
     swept = [*SWEEP, *SSD_SWEEP, *TARGET_SWEEP, *SCORES_SWEEP]
     if args.sweep and set(args.sweep) - set(swept):
         parser.error(f"--sweep: no such shape in {sorted(swept)}")
-    known = [*CASES, *MOE_CASES, *SHORTCONV_CASES, *SSD_CASES, *TARGET_CASES,
+    known = [*CASES, *MOE_CASES, *MOE_ALL_CASES, *SHORTCONV_CASES,
+             *SSD_CASES, *TARGET_CASES,
              *SCORES_CASES, *HEAD_CASES, *GATENORM_CASES, *WINDOW_CASES]
     if set(args.cases) - set(known):
         parser.error(f"--cases: no such case in {known}")
@@ -1191,12 +1287,13 @@ def main():
                 shape, jnp.bfloat16, kv_heads=kv_heads, repeated=True,
                 every_op=True)
         print(json.dumps(line), flush=True)
-    for name in MOE_CASES:
-        for line in moe_case(name, jnp.bfloat16) \
+    for name, case in [(n, moe_case) for n in MOE_CASES] + [
+            (n, grouped_case) for n in (*MOE_CASES, *MOE_ALL_CASES)]:
+        for line in case(name, jnp.bfloat16) \
                 if name in args.cases else ():
             ok = max(line.get("rel_err", {"": 0}).values()) < TOLERANCE
             if not ok:
-                failed.append(f"{name}:{line['form']}")
+                failed.append(f"{name}:{line.get('form')}")
             print(json.dumps({**line, "ok": ok,
                               "device_kind": dev.device_kind}), flush=True)
     for name, case, dtype in [
