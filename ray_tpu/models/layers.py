@@ -1,7 +1,8 @@
 """What the Train-path models share: the norms, rotary positions, the way
 into and out of an attention operator (`attention_qkv`: the three
 projections to heads, an RMSNorm a head where the parameters have a gain for
-one, RoPE where the caller gives positions; `attention_out`: W_o), the
+one, RoPE where the caller gives positions, over the whole head or its first
+dims; `attention_out`: a gate a head where the caller gives its input, W_o), the
 feed-forwards (gated and not), the routed layer over them, which hands on
 what its route gives past the two it needs (`ops/moe.py` has the routes: the
 sigmoid one with a routing bias, the softmax one with its balance loss), the
@@ -31,9 +32,9 @@ profiler carries that into the trace (`tf_op`), and a reader
 every path a Train-path model may use, and the one place a reader or a test
 takes them from.  A model writes plain `with jax.named_scope("attention"):`
 around the part; what the models share names itself (`layer_norm` and
-`rms_norm`: `norm`; `attention_qkv`'s `qkv` and `attention_out`'s `out`,
-which rely on the caller standing in `attention` (the `kernel` between them
-is the model's); `short_conv`'s three parts; `trunk`'s `embed`;
+`rms_norm`: `norm`; `attention_qkv`'s `qkv` and `attention_out`'s `gate`
+and `out`, which rely on the caller standing in `attention` (the `kernel`
+between them is the model's); `short_conv`'s three parts; `trunk`'s `embed`;
 `routed_layer`'s `route` and `shared` and `ops/moe.py`'s `dispatch`,
 `experts`, `combine`, `balance_loss`'s `route` and `routing_bias_update`,
 which rely on the caller standing in `ffn/moe`; `head_and_loss`, also around
@@ -82,6 +83,7 @@ SCOPES = (
     "attention/indexer/loss",
     "attention/kernel",
     *(f"attention/kernel/{form}" for form in KERNEL_FORMS),
+    "attention/gate",
     "attention/out",
     "short_conv",
     "short_conv/in_proj",
@@ -206,7 +208,7 @@ def yarn_frequencies(dim, theta, factor, original_max_position, beta_fast=32,
 
 
 def attention_qkv(x, p, head_dim, eps=None, positions=None, theta=None,
-                  scale=None):
+                  scale=None, rotary_dim=None):
     """The way into an attention operator, x (B, S, E) -> q (B, S, H, D), k
     and v (B, S, H_kv, D) with D = ``head_dim``, under `qkv`; the caller
     stands in `attention`, runs its kernels on the three under `kernel` and
@@ -217,8 +219,16 @@ def attention_qkv(x, p, head_dim, eps=None, positions=None, theta=None,
     each head's D at ``eps`` with that gain; where ``positions`` is given,
     q and k through `rope` at ``positions(S)``, a function of the rows'
     number (`jnp.arange`: row t stands at t) that is traced here, behind
-    the products, with ``theta`` and ``scale`` as `rope` takes them."""
+    the products, with ``theta`` and ``scale`` as `rope` takes them.
+    ``rotary_dim``: the FIRST dims of each head that turn (``theta``'s
+    frequencies are then of that width), the rest passing as they are
+    (`partial_rotary_factor`); None, or D: the whole head.  A call that
+    rotates a part is counted on the job timeline as the step is traced
+    (`rope.partial`)."""
     B, S, _ = x.shape
+    part = rotary_dim is not None and rotary_dim != head_dim
+    if part and positions is not None:
+        tracing.count("rope.partial")
     with jax.named_scope("qkv"):
         # the products, before the norms: a norm's backward reads them
         q, k, v = named(tuple(
@@ -230,15 +240,36 @@ def attention_qkv(x, p, head_dim, eps=None, positions=None, theta=None,
         def turned(heads, norm):
             if norm in p:
                 heads = rms_norm(heads, p[norm], eps)
-            return heads if at is None else rope(heads, at, theta,
-                                                 scale=scale)
+            if at is None:
+                return heads
+            if not part:
+                return rope(heads, at, theta, scale=scale)
+            return jnp.concatenate(
+                [rope(heads[..., :rotary_dim], at, theta, scale=scale),
+                 heads[..., rotary_dim:]], axis=-1)
         return turned(q, "q_norm"), turned(k, "k_norm"), v
 
 
-def attention_out(o, p):
+def attention_out(o, p, gate_input=None):
     """The way out of one: the kernels' result o (B, S, H, D) -> o W_o
-    (B, S, E), ``p``'s "o_proj", under `out`, marked `attention/out`."""
+    (B, S, E), ``p``'s "o_proj", under `out`, marked `attention/out`.
+    ``gate_input``: u (B, S, E), the operator's own input; each head's
+    result is first multiplied by g = sigmoid(u W_g) in float32, one scalar
+    a head and token, W_g ``p``'s "g_proj" (E, H), no bias (the head-wise
+    gate at the kernels' output of Qiu et al., arXiv:2505.06708), under
+    `gate`, the product marked `attention/gate`; such a call is counted on
+    the job timeline as the step is traced (`attention.gated`).  None: no
+    gate, and the program it always was."""
     B, S = o.shape[:2]
+    if gate_input is not None:
+        tracing.count("attention.gated")
+        with jax.named_scope("gate"):
+            # the product and not its sigmoid, whose backward reads its own
+            # result (as a router's, `ops/moe.py:sigmoid_route`)
+            g = jax.nn.sigmoid(named(jnp.matmul(
+                gate_input, p["g_proj"]["kernel"].astype(gate_input.dtype),
+                preferred_element_type=jnp.float32), "attention/gate"))
+            o = (o.astype(jnp.float32) * g[..., None]).astype(o.dtype)
     with jax.named_scope("out"):
         return named(o.reshape(B, S, -1)
                      @ p["o_proj"]["kernel"].astype(o.dtype), "attention/out")
@@ -449,6 +480,9 @@ KEPT_NAMES = (
                                 # (B, S, S) int8: kept, a replay searches
                                 # no threshold (16 passes over the scores)
     "attention/latent_down",    # DeepSeek-V3's [c | k_r], W_kv_a's result
+    "attention/gate",           # a gated attention's u W_g, (B, S, H) float32:
+                                # a product that reads the whole stream for
+                                # a result a head wide, as a router's
     "attention/out",            # W_o's result, as wide as the stream
     "short_conv/out_proj",      # W_out's result, the same
     "short_conv/gate_taps",     # c * conv(b * z), the same

@@ -1164,7 +1164,17 @@ def _auto_tiles(S: int, causal):
     at S = 16,384 and 3.28 / 2.29 / 2.80 at 8,192; forward + backward
     16.52 / 12.70 / 14.97 and 7.92 / 6.04 / 6.88 (the same call with no
     window: 89.05 / 51.50 / 50.86 and 23.05 / 13.73 / 14.03).  So 512 in
-    both passes under a window."""
+    both passes under a window.  A window NARROWER than a pair of those
+    tiles was swept too (W = 512, one sequence of 16,384, 64 query heads on
+    8 of 128: PERF.md §6, PR 60; `tools/chip_kernels.py --cases laguna_16k`
+    and `--sweep laguna-16k`): a q tile of 512 visits 2 k tiles, both
+    crossed by both bounds (0.50 of the visited pairs attended), of 256
+    three (0.667), of 128 five (0.80), and the smaller tiles' skipping does
+    not pay their fixed cost: ms a layer at 128 / 256 / 512, the forward
+    17.22 / 9.25 / 7.06, forward + backward 39.37 / 23.18 / 19.42; a q tile
+    beside another k tile is no better (256 on 512: 7.10 and 13.37 against
+    7.06 and 12.36 square; 512 on 256: 9.59 and 12.66).  So the window's
+    width is read and changes nothing yet: 512 at every width swept."""
     rule = _rule(causal)
     L = S // rule.kinds if rule else S      # tiles divide a kind's rows
     whole = _auto_block(L, 1024)

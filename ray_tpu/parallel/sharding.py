@@ -224,8 +224,10 @@ def infer_param_logical_dims(path: Tuple[str, ...], shape: Tuple[int, ...]):
     if any(k in name for k in ("ln", "layernorm", "layer_norm", "norm",
                                "scale", "bias", "rmsnorm")) and nd == 1:
         return (None,)
-    if any(k in name for k in ("q_proj", "k_proj", "v_proj", "qkv", "c_attn",
-                               "wq", "wk", "wv", "query", "key", "value")):
+    # "g_proj": a gated attention's W_g (E, H), a scalar a head
+    if any(k in name for k in ("q_proj", "k_proj", "v_proj", "g_proj", "qkv",
+                               "c_attn", "wq", "wk", "wv", "query", "key",
+                               "value")):
         return ("embed", "heads") if nd == 2 else ("embed", "heads", "kv")[:nd]
     if any(k in name for k in ("o_proj", "c_proj/attn", "attn/c_proj", "wo",
                                "out_proj")):

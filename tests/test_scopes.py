@@ -28,6 +28,7 @@ from ray_tpu.models import (
     deepseek_v3,
     gpt2,
     keye_vl,
+    laguna,
     layers,
     lfm2_moe,
     mellum,
@@ -50,6 +51,7 @@ MODELS = {
     "ouro": (ouro, ouro.OURO_TINY),
     "sdar": (sdar, sdar.SDAR_TINY),
     "mellum": (mellum, mellum.MELLUM_TINY),
+    "laguna": (laguna, laguna.LAGUNA_TINY),
 }
 CASES = [(name, remat) for name in MODELS for remat in (False, True)]
 # what every model's step must have a matmul under
@@ -85,6 +87,12 @@ EXPECTED = {
                "attention/kernel/fwd_rows", "attention/kernel/bwd_fused",
                "attention/out", "ffn/moe/route", "ffn/moe/experts",
                "head_and_loss"},
+    "laguna": {"attention/qkv", "attention/kernel/fwd_rows_window",
+               "attention/kernel/bwd_fused_window",
+               "attention/kernel/fwd_rows", "attention/kernel/bwd_fused",
+               "attention/gate", "attention/out", "ffn/dense",
+               "ffn/moe/route", "ffn/moe/experts", "ffn/moe/shared",
+               "head_and_loss"},
 }
 # components of an `op_name` that jax puts there itself (`jnp.einsum` its
 # subscripts: `ops/ssd.py`'s products)
@@ -106,7 +114,7 @@ def lowered_text(name: str, remat: bool) -> str:
     module, cfg = MODELS[name]
     cfg = dataclasses.replace(cfg, remat=remat)
     optimizer = optax.adamw(1e-4)
-    if module in (deepseek_v3, lfm2_moe, nemotron_h):
+    if module in (deepseek_v3, lfm2_moe, nemotron_h, laguna):
         optimizer = trained_by(optimizer)
     # a step that draws its own noise is built with the run's seed
     step = module.make_train_step(cfg, optimizer,
@@ -200,7 +208,7 @@ def test_every_matmul_and_kernel_is_under_a_scope(name, remat):
     every = {scope(full) for _, full in full_names(
         lowered_text(name, remat), re.compile(r"stablehlo\.\w+"))}
     assert {"optimizer_update", "norm", "embed"} <= every
-    if name in ("deepseek_v3", "lfm2_moe", "nemotron_h"):
+    if name in ("deepseek_v3", "lfm2_moe", "nemotron_h", "laguna"):
         assert "routing_bias_update" in every
     if name == "lfm2_moe":
         assert "short_conv/gate_taps" in every
@@ -217,11 +225,45 @@ def test_every_matmul_and_kernel_is_under_a_scope(name, remat):
     assert ("attention/kernel/fwd_rows_blocks" in every) is (name == "sdar")
     if name == "sdar":
         assert "attention/kernel/fwd_rows" not in every
-    # a windowed layer's kernels under names of their own, in the one
-    # model that has such layers (beside its full layers' plain ones)
+    # a windowed layer's kernels under names of their own, in the models
+    # that have such layers (beside their full layers' plain ones)
     assert ("attention/kernel/fwd_rows_window" in every) \
         is ("attention/kernel/bwd_fused_window" in every) \
-        is (name == "mellum")
+        is (name in ("mellum", "laguna"))
+    # a gate on attention's result, in the one model that has one
+    assert ("attention/gate" in every) is (name == "laguna")
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_the_gate_is_a_scope_of_its_own(remat):
+    """A gated attention's product with the layer's normed input, its
+    sigmoid and the multiply over the kernels' result stand under
+    `attention/gate`, forward and backward (and a recomputed layer's
+    replay), and W_o's product stays under `attention/out`: one product a
+    pass under the gate in each of the five layers, and no kernel."""
+    found = full_names(lowered_text("laguna", remat),
+                       re.compile(r"stablehlo\.\w+"))
+    gate = [(op, full) for op, full in found
+            if scope(full) == "attention/gate"]
+    phases = {scope_trace.phase_of(full) for _, full in gate}
+    assert phases >= {"fwd", "bwd"}
+    assert ("remat_fwd" in phases) is remat
+    ops = {op for op, _ in gate}
+    # u W_g, the sigmoid, the multiply a head; no kernel, no reshape to W_o
+    assert {"stablehlo.dot_general", "stablehlo.multiply"} <= ops, ops
+    assert ops & {"stablehlo.logistic", "stablehlo.exponential"}, ops
+    assert "stablehlo.custom_call" not in ops
+    products = [full for op, full in gate if op == "stablehlo.dot_general"]
+    forward = [full for full in products
+               if scope_trace.phase_of(full) == "fwd"]
+    backward = [full for full in products
+                if scope_trace.phase_of(full) == "bwd"]
+    # one body a shape of layer when recomputed: three shapes, five layers
+    assert len(forward) == 5
+    assert len(backward) == 2 * 5         # du and dW_g a layer
+    out = [full for op, full in found if op == "stablehlo.dot_general"
+           and scope(full) == "attention/out"]
+    assert out and not [full for full in out if "gate" in full]
 
 
 @pytest.mark.parametrize("remat", [False, True])

@@ -35,7 +35,11 @@ of 128, then the same call with no window; a line a rule and a tile
 (`_auto_tiles`' own, then 256, 512 and 1,024) with the forward's and
 forward + backward's device ms, the share of the visited pairs the rule
 attends, and the largest error of o, dq, dk and dv relative to a float32
-masked softmax taken 1,024 query rows at a time.
+masked softmax taken 1,024 query rows at a time.  ``laguna_16k`` is
+Laguna-XS.2's pair: 64 query heads on 8 under a window of 512 (tiles of
+128, 256 and 512: the window is narrower than a pair of 512-tiles), then
+its full layers' 48 on 8 with no window; ``--sweep laguna-16k`` times the
+windowed call at those tiles and at a q tile beside another k tile.
 
 ``moe_held_8k`` is no attention case: one routed layer of kanana's share
 (`ops/moe.py`: dispatch, the held experts, combine) over all the routed
@@ -146,19 +150,26 @@ CASES = {
     "gqa16_8k": ((2, 8192, 32, 128), 2),
 }
 # key/value heads of the cases and sweeps whose k and v have fewer than q
-KV_HEADS = {"gqa_8k": 8, "gqa-8k": 8, "gqa16_8k": 2, "gqa16-8k": 2,
+KV_HEADS = {"laguna-16k": 8,
+            "gqa_8k": 8, "gqa-8k": 8, "gqa16_8k": 2, "gqa16-8k": 2,
             "blocks-16k": 4}
 # (block, kinds) of the sweeps under a rule that is not the diagonal
 # (`ops/flash_attention.py:BlockRule`)
-RULES = {"blocks-16k": (4, 2)}
-# (shape, key/value heads, window) of the calls under a window
-# (`BlockRule(window=W)`): Mellum 2's sliding layers at the cell's length
-# and at half of it, each beside the same call with no window
+RULES = {"blocks-16k": (4, 2), "laguna-16k": (1, 1, 512)}
+# (shape, key/value heads, window[, the query heads of the call with no
+# window]) of the calls under a window (`BlockRule(window=W)`): Mellum 2's
+# sliding layers at the cell's length and at half of it, each beside the
+# same call with no window; Laguna-XS.2's sliding layers (64 query heads on
+# 8, a window narrower than a pair of 512-tiles) beside its full layers'
+# call (48 on 8, no window)
 WINDOW_CASES = {
     "mellum_16k": ((1, 16384, 32, 128), 4, 1024),
     "mellum_8k": ((1, 8192, 32, 128), 4, 1024),
+    "laguna_16k": ((1, 16384, 64, 128), 8, 512, 48),
 }
 WINDOW_TILES = ((None, None), (256, 256), (512, 512), (1024, 1024))
+# a window of 512 is swept a tile further down
+NARROW_TILES = ((None, None), (128, 128), (256, 256), (512, 512))
 # (B, S, H, P, G, N, chunk) of one state-space scan
 SSD_CASES = {
     "ssd_8k": (2, 8192, 64, 64, 8, 128, 128),
@@ -241,6 +252,11 @@ SWEEP = {
     # copies under the rule of blocks of 4, 32 query heads on 4 of 128
     "blocks-16k": ((2, 16384, 32, 128), ((256, 256), (512, 512),
                                          (1024, 1024), (1024, 512))),
+    # Laguna-XS.2's sliding layers: a window of 512, 64 query heads on 8 of
+    # 128, one sequence (a q tile beside another k tile too)
+    "laguna-16k": ((1, 16384, 64, 128), ((128, 128), (256, 256), (512, 512),
+                                         (128, 256), (256, 128), (256, 512),
+                                         (512, 256))),
 }
 # (rows, E, vocabularies, rows a chunk) of one head and its loss
 HEAD_CASES = {
@@ -1063,27 +1079,29 @@ def window_case(name, dtype):
     from ray_tpu.models.mellum import attended_pairs
     from ray_tpu.ops import flash_attention as fa
 
-    shape, kv_heads, window = WINDOW_CASES[name]
-    B, S, H, D = shape
-    q, k, v = _qkv(shape, dtype, kv_heads)
+    shape, kv_heads, window, *full_heads = WINDOW_CASES[name]
+    tiles = WINDOW_TILES if window >= 1024 else NARROW_TILES
 
     def reference(q, k, v, width):
-        """(B, S, H, D) float32, by blocks of query rows."""
+        """(B, S, H, D) float32, by blocks of query rows (1,024 of up to
+        32 heads: 2 GiB of scores alive)."""
+        B, S, H, D = q.shape
+        block = 1024 if H <= 32 else 512
         qf, kf, vf = (t.astype(jnp.float32).transpose(0, 2, 1, 3)
                       for t in (q, k, v))
         kf, vf = (jnp.repeat(t, H // kv_heads, axis=1) for t in (kf, vf))
 
         @jax.checkpoint
         def some(start):
-            rows = start + jnp.arange(1024)
+            rows = start + jnp.arange(block)
             behind = rows[:, None] - jnp.arange(S)[None]
             seen = (behind >= 0) & (behind < (width or S))
-            qb = jax.lax.dynamic_slice_in_dim(qf, start, 1024, axis=2)
+            qb = jax.lax.dynamic_slice_in_dim(qf, start, block, axis=2)
             scores = jnp.einsum("bhqd,bhkd->bhqk", qb, kf) * D ** -0.5
             probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
             return jnp.einsum("bhqk,bhkd->bhqd", probs, vf)
 
-        out = jax.lax.map(some, jnp.arange(0, S, 1024))  # (n, B, H, 1024, D)
+        out = jax.lax.map(some, jnp.arange(0, S, block))  # (n, B, H, ., D)
         return out.transpose(1, 0, 3, 2, 4).reshape(B, S, H, D)
 
     def grad(f):
@@ -1093,13 +1111,17 @@ def window_case(name, dtype):
             (0, 1, 2), has_aux=True))
 
     for width in (window, None):
+        if width is None and full_heads:
+            shape = (*shape[:2], *full_heads, shape[3])
+        S = shape[1]
+        q, k, v = _qkv(shape, dtype, kv_heads)
         rule = fa.BlockRule(window=width)
         with jax.default_matmul_precision("highest"):
             (_, o_r), g_r = grad(lambda q, k, v: reference(q, k, v, width))(
                 q, k, v)
         want = [np.asarray(t, np.float32) for t in (o_r, *g_r)]
         del o_r, g_r
-        for block in WINDOW_TILES:
+        for block in tiles if width else WINDOW_TILES:
             (_, o_k), g_k = grad(lambda q, k, v: fa.flash_attention_bshd(
                 q, k, v, rule, None, *block))(q, k, v)
             errs = {what: round(float(
