@@ -11,18 +11,22 @@ names).  Shared by `models/keye_vl.py` and whatever selects next.
               L1-normalised, a constant
 
 The mask is `ops/flash_attention.py`'s operand: (B, S, S) int8, 1 where t
-attends s.  The selection goes by blocks of ``block`` query rows
-(`sa_config`'s `q_chunk_size`), one sequence's block at a time
-(`_by_blocks`), in plain XLA; no step holds a head's scores of more than a
-tile, never an S x S array a head; what it holds summed over the heads is
-the (B, S, S) float32 of I and, under the gradient, of dL_I / dI.  Three
-Mosaic kernels keep the heads' products in VMEM.  The SCORES are one kernel
+attends s.  No step holds a head's scores of more than a tile, never an
+S x S array a head; what it holds summed over the heads is the (B, S, S)
+float32 of I and, under the gradient, of dL_I / dI.  Four Mosaic kernels
+keep the rest in VMEM.  The SCORES are one kernel
 a layer (`_pallas_scores`: every indexer head's q . k', its relu, its
 weight and the sum over the heads a (q tile, k tile) at a time, the tiles
 above the diagonal written as -inf and not computed) and their backward
 another (`_pallas_scores_bwd`, under `index_scores`' own `jax.custom_vjp`:
 the products made again once a tile and contracted into dq, dk and dw, the
-one key head's dk summed in VMEM over a sequence's tiles).  The LOSS is one
+one key head's dk summed in VMEM over a sequence's tiles).  The SELECTION
+is one kernel a layer (`_pallas_select`): a q tile's row of scores lands in
+VMEM once, the key tiles above the diagonal not read, and the ordering,
+both searches, the compare and the int8 write happen there; the kernel
+writes the tile's rows of the mask where they lie in (B, S, S), the free
+rows' triangle too: nothing of the selection but the scores' read and the
+mask's write goes through HBM, and no XLA pass follows.  The LOSS is one
 kernel a layer, whole (`_pallas_loss`): its target, the main attention's
 probabilities summed over its heads (every head's QK', its exponent and
 the sum), stays in VMEM a q tile's whole row at a time beside the row's
@@ -30,33 +34,41 @@ selected scores, the key tiles above the diagonal not visited; the row's
 sums run beside it, and on the row's last tile the kernel writes each
 query's KL and the gradient to the scores where it lies in (B, S, S):
 nothing else of the loss goes through HBM, and no XLA pass follows.  All
-three run as the flash kernels do (`ops.by_platform`): compiled where the
+four run as the flash kernels do (`ops.by_platform`): compiled where the
 step is lowered for a TPU, interpreted elsewhere up to the tests' sizes,
-and the same arithmetic in plain XLA by blocks (`_scores_reference`,
-`_scores_reference_bwd`, `_loss_reference` over `_target_reference`)
-beyond them and for a shape a kernel cannot tile (`_scores_tiles`,
-`_target_tiles`).
+and the same arithmetic in plain XLA by blocks of ``block`` query rows
+(`sa_config`'s `q_chunk_size`), one sequence's block at a time
+(`_by_blocks`: `_scores_reference`, `_scores_reference_bwd`,
+`_select_reference`, `_loss_reference` over `_target_reference`) beyond
+them and for a shape a kernel cannot tile (`_scores_tiles`,
+`_select_tiles`, `_target_tiles`).
 
 The selection is a threshold search and no `jax.lax.top_k`: a top-k gives
 the keys' indices, 2,048 a row for 16,384 rows a layer, and a mask of them
 is a scatter, which the chip runs serially; and XLA:TPU's top-k at a k of
 thousands is a sort of the whole row.  The k-th largest score of a row is
-found by its bits, two at a time (16 passes of three compares and three
-counts over the block; the float32 scores taken as integers that order as
-they do), and the mask is a compare with it; of the keys that TIE with the
-k-th the lowest are taken, up to the last that still fits, which the same
-search finds over the keys' places (7 passes at 8,192 keys).  Every row
-pays both searches whatever its scores are: a step's time does not depend
-on how many rows have a tie (one row in a thousand at float32 sums of
+found by its bits from the top (the float32 scores taken as integers that
+order as they do; a pass counts the keys that reach a candidate), and the
+mask is a compare with it; of the keys that TIE with the k-th the lowest
+are taken, up to the last that still fits, which the same search finds
+over the keys' places.  In the kernel a pass is one bit, one compare and
+one count over the row in VMEM (32 over the scores, 14 over the places at
+8,192 keys: a load costs nothing beside the vector unit there, and two
+bits a pass were slower); in the plain XLA form two bits, three compares
+and three counts over the block through HBM (16 and 7).  Every row pays
+both searches whatever its scores are: a step's time does not depend on
+how many rows have a tie (one row in a thousand at float32 sums of
 bfloat16 products, so two blocks in five, which a branch taken only then
 made the step's time wander by).  The result is `jax.lax.top_k`'s set,
-exactly.
+exactly, in either form.
 
 Counts itself on the job timeline as the step is traced:
 `attention.indexer_heads`, `attention.score_tiles`,
 `attention.score_tiles_skipped` (`index_scores`), `attention.keys_selected`,
-`attention.pairs_causal`, `attention.pairs_selected`, `attention.mask_bytes`
-(`select_top_k`), `attention.target_tiles`, `attention.target_tiles_skipped`,
+`attention.pairs_causal`, `attention.pairs_selected`, `attention.mask_bytes`,
+`attention.select_rows_fused` (`select_top_k`: the last the query rows whose
+selection the kernel made, 0 where the shape took the plain form),
+`attention.target_tiles`, `attention.target_tiles_skipped`,
 `attention.loss_rows_fused` (`indexer_loss`: the loss kernel's tiles, and
 the query rows whose loss and gradient it made, 0 where the shape took
 the plain form; a recomputed layer is traced once).
@@ -96,6 +108,20 @@ _TARGET_VMEM_MAX = 64 << 20
 # its dq in float32 and the sequence's dk
 _SCORES_TILE = (256, 512)
 _SCORES_VMEM_MAX = 64 << 20
+# the selection's kernel's (q tile, k tile) at most and the bits of a threshold
+# it finds a pass (`tools/chip_kernels.py --sweep select-8k` on a v5e, ms a
+# layer of the keye cell's two sequences and the seconds it took to compile,
+# at one bit a pass: 64 x 1,024 3.22 / 0.5, 128 x 256 3.30 / 0.5, 128 x 512
+# 2.86 / 0.5, 128 x 1,024 2.72 / 0.5, 128 x 2,048 2.78 / 0.8, 256 x 512 3.77 /
+# 0.7, 256 x 1,024 4.16 / 0.7, 512 x 512 5.17 / 0.9: a pass's counts of 128
+# rows are 16 registers, of 256 half the file; at two bits, three counts a
+# pass: 64 x 1,024 4.03 / 0.5, 128 x 1,024 4.15 / 0.7, 256 x 1,024 6.34 / 1.3,
+# 512 x 512 8.10 / 1.8; the plain XLA form 10.03 alone and 13.2 in the step;
+# PERF.md §6, PR 61), and the scoped VMEM it may ask for: a q tile's row of
+# integers twice and its row of the mask (9 MiB at the cell's 8,192 keys)
+_SELECT_TILE = (128, 1024)
+_SELECT_BITS = 1
+_SELECT_VMEM_MAX = 64 << 20
 
 
 def _by_blocks(fn, block, rows, whole=(), first=0):
@@ -463,21 +489,14 @@ def _kth_largest(u, k, bits=32):
                              jnp.zeros(u.shape[:1], jnp.uint32))
 
 
-def select_top_k(scores, top_k, block=512):
-    """scores (B, S, S) float32, -inf above the diagonal (`index_scores`)
-    -> the mask (B, S, S) int8: 1 where query t attends key s, the
-    min(``top_k``, t + 1) keys s <= t of largest score, of equal scores the
-    lower s (`jax.lax.top_k`'s set).  A constant: no gradient passes.  The
-    first ``top_k`` queries attend every key they see: their blocks are the
-    causal triangle and search nothing."""
+def _select_reference(scores, *, top_k, block):
+    """`select_top_k` in plain XLA by blocks of ``block`` query rows, what
+    `_pallas_select` is held to and what a shape it declines runs: a
+    block's scores, their integers, the ties' places and every pass of
+    both searches over them go through HBM, and the blocks are joined to
+    the free rows' triangle by a copy of the whole mask."""
     B, S, _ = scores.shape
-    block = _block(S, block)
     free = min(top_k // block * block, S)   # rows whose whole block is free
-    selected = sum(min(top_k, t + 1) for t in range(S))
-    tracing.count("attention.keys_selected", top_k)
-    tracing.count("attention.pairs_causal", B * S * (S + 1) // 2)
-    tracing.count("attention.pairs_selected", B * selected)
-    tracing.count("attention.mask_bytes", B * S * S)
 
     # a key's place counted from the END (S - s, under 2^place_bits): of
     # the keys that tie, those of largest place are the lowest keys
@@ -498,10 +517,256 @@ def select_top_k(scores, top_k, block=512):
     parts = [jnp.broadcast_to(_causal(0, free, S).astype(jnp.int8),
                               (B, free, S))]
     if free < S:
-        parts.append(_rows(_by_blocks(
-            select, block, (jax.lax.stop_gradient(scores)[:, free:],),
-            first=free)))
+        parts.append(_rows(_by_blocks(select, block, (scores[:, free:],),
+                                      first=free)))
     return jnp.concatenate(parts, axis=1)
+
+
+_INT_MIN = -2 ** 31
+
+
+def _select_kernel(scores_ref, mask_ref, row, out, threshold, landed, sent,
+                   *, top_k, block_q, block_k, bits):
+    """One q tile's rows of a sequence's mask, the selection whole.
+    scores_ref (B, S, S) float32 and mask_ref (B, S, S) int8 where XLA put
+    them, read and written by this kernel's own copies, a (block_q,
+    block_k) tile each; only the key tiles at or under the q tile's
+    diagonal are read (the scores above it are -inf by `index_scores`'
+    word: no key there is ever among a row's top_k), and a q tile wholly
+    within the first ``top_k`` rows reads none: its rows attend every key
+    they see.
+
+    A searched tile: its row of scores lands in ``row`` ((2 slots, k
+    tiles, block_q, block_k) int32: the float32 bits as they are), the
+    NEXT searched tile's row starting for the other slot before anything
+    is counted, and is turned in place into integers that order, signed,
+    as the floats do (-0.0 with 0.0: `_ordered` less its last flip).  The
+    k-th largest of each query's row is found by its bits from the top,
+    ``bits`` a pass (`search`: the candidates that set them, a count a
+    candidate of the keys that reach it, a lane at a time in (block_q,
+    128) and across the lanes once a pass).  The row is then turned into
+    what the SAME search finishes on: S + 1 for a key above the k-th, the
+    key's place from the end (S - s) for one that ties with it, 0 under
+    it; the k-th largest of those is the last place that still fits, and
+    a key is selected where it reaches that.  Every row pays both
+    searches; no branch looks at a score.
+
+    Every tile: the compare with the rows' ``threshold`` (the least
+    integer for a free tile: every key), the causal `and` and the int8
+    write into ``out`` ((k tiles, block_q, block_k)), a copy a tile to its
+    place in HBM, which the next q tile's search hides: they are waited
+    for before ``out`` is written again, and at the grid's last step.  The
+    tiles above the diagonal are zeroed once a sequence: a later q tile's
+    diagonal lies further right."""
+    b, i = pl.program_id(0), pl.program_id(1)
+    n_q, n_k = pl.num_programs(1), out.shape[0]
+    step, steps = b * n_q + i, pl.num_programs(0) * n_q
+    S = n_k * block_k
+    tile = (block_q, block_k)
+    width = min(block_k, 128)
+    slot = step % 2
+    ints = row.at[slot]
+    as_bits = scores_ref.bitcast(jnp.int32)
+
+    def last_tile(i):
+        return ((i + 1) * block_q - 1) // block_k
+
+    def searched(i):
+        return (i + 1) * block_q > top_k
+
+    def visited(body, i=i):     # body(j) over the key tiles of q tile i
+        def one(j, carry):
+            body(j)
+            return carry
+        jax.lax.fori_loop(0, last_tile(i) + 1, one, 0)
+
+    def fetch(b, i, slot, j):
+        return pltpu.make_async_copy(
+            as_bits.at[b, pl.ds(i * block_q, block_q),
+                       pl.ds(j * block_k, block_k)],
+            row.at[slot, j], landed.at[slot, j])
+
+    def send(j):
+        return pltpu.make_async_copy(
+            out.at[j], mask_ref.at[b, pl.ds(i * block_q, block_q),
+                                   pl.ds(j * block_k, block_k)], sent)
+
+    def sent_all():
+        for j in range(n_k):    # same-sized, so any of them counts one
+            send(j).wait()
+
+    def search(k, least, n_bits):
+        """(block_q, 1): each row's k-th largest of ``ints``' integers,
+        which lie in [least, least + 2^n_bits)."""
+        def one(p, found):
+            shift = n_bits - bits * (p + 1)
+            candidates = [jnp.broadcast_to(found ^ (jnp.int32(c) << shift),
+                                           (block_q, width))
+                          for c in range(1, 1 << bits)]
+
+            def count(j, reached):
+                x = ints[j]
+                return tuple(r + functools.reduce(jnp.add, (
+                    jnp.where(x[:, c:c + width] >= candidate, 1, 0)
+                    for c in range(0, block_k, width)))
+                    for r, candidate in zip(reached, candidates))
+
+            reached = jax.lax.fori_loop(
+                0, last_tile(i) + 1, count,
+                (jnp.zeros((block_q, width), jnp.int32),) * len(candidates))
+            # the candidates that k keys reach are the lower ones: their
+            # number is the pass's bits
+            digit = sum((jnp.sum(r, axis=1, keepdims=True) >= k).astype(
+                jnp.int32) for r in reached)
+            return found ^ (digit << shift)
+
+        return jax.lax.fori_loop(0, n_bits // bits, one,
+                                 jnp.full((block_q, 1), least, jnp.int32))
+
+    @pl.when((step == 0) & searched(i))
+    def _():
+        visited(lambda j: fetch(b, i, slot, j).start())
+
+    wraps = i + 1 == n_q
+    next_b, next_i = jnp.where(wraps, b + 1, b), jnp.where(wraps, 0, i + 1)
+
+    @pl.when((step + 1 < steps) & searched(next_i))
+    def _():
+        visited(lambda j: fetch(next_b, next_i, 1 - slot, j).start(), next_i)
+
+    @pl.when(jnp.logical_not(searched(i)))
+    def _():
+        threshold[...] = jnp.full_like(threshold, _INT_MIN)
+
+    @pl.when(searched(i))
+    def _():
+        k = jnp.minimum(top_k, i * block_q + 1 + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0))
+
+        def order(j):
+            fetch(b, i, slot, j).wait()
+            x = ints[j]
+            x = jnp.where(x == _INT_MIN, 0, x)      # -0.0
+            ints[j] = x ^ ((x >> 31) & 0x7FFFFFFF)
+
+        visited(order)
+        kth = search(k, _INT_MIN, 32)
+
+        def place(j):
+            x = ints[j]
+            from_end = (S - j * block_k) - jax.lax.broadcasted_iota(
+                jnp.int32, tile, 1)
+            ints[j] = jnp.where(x > kth, S + 1,
+                                jnp.where(x == kth, from_end, 0))
+
+        visited(place)
+        threshold[...] = search(k, 0, -(-(S + 1).bit_length() // bits) * bits)
+
+    @pl.when(step > 0)
+    def _():
+        sent_all()
+
+    def write(j, carry):
+        @pl.when(j <= last_tile(i))
+        def _():
+            # a free tile's ``ints`` are whatever the slot held: every
+            # integer reaches the least
+            chosen = (ints[j] >= threshold[...]) \
+                & _tile_causal(i, j, block_q, block_k)
+            out[j] = jnp.where(chosen, 1, 0).astype(jnp.int8)
+
+        @pl.when((j > last_tile(i)) & (i == 0))
+        def _():
+            out[j] = jnp.zeros(tile, jnp.int8)
+
+        send(j).start()
+        return carry
+
+    jax.lax.fori_loop(0, n_k, write, 0)
+
+    @pl.when(step == steps - 1)
+    def _():
+        sent_all()
+
+
+def _select_vmem_bytes(S, block_q, block_k):
+    """What `_select_kernel` holds in VMEM: a q tile's whole row of
+    integers twice (this tile's and the next one's, landing), its row of
+    the mask, the rows' threshold a lane tile wide, and a tile's int32
+    temporaries."""
+    tile = block_q * _lanes(block_k) * 4
+    return (2 * 4 + 1) * S * block_q + block_q * 128 * 4 + 8 * tile
+
+
+def _select_tiles(S):
+    """(q tile, k tile) of the selection's kernel for a sequence of S, or
+    None for a shape it cannot tile, which takes `_select_reference` on
+    every platform: each tile the largest power-of-two part of
+    `_SELECT_TILE`'s that divides the sequence and a multiple of what
+    Mosaic tiles the mask by (32 rows, 128 lanes), the whole sequence
+    included: the kernel's own copies cut the tiles out of HBM; the rows it
+    holds fit `_SELECT_VMEM_MAX`."""
+    block_q, block_k = (_tile(S, cap, 1) for cap in _SELECT_TILE)
+    if block_q % 32 or block_k % 128 \
+            or _select_vmem_bytes(S, block_q, block_k) > _SELECT_VMEM_MAX:
+        return None
+    return block_q, block_k
+
+
+@functools.partial(jax.jit, static_argnames=("top_k", "block_q", "block_k",
+                                             "bits", "interpret"))
+def _pallas_select(scores, *, top_k, block_q, block_k, interpret,
+                   bits=_SELECT_BITS):
+    """`_select_reference` as one Mosaic kernel a layer: grid (sequences, q
+    tiles), in order: a q tile's row lives in VMEM over both searches, the
+    next one's lands beside it and its mask's copies run over the next
+    tile's.  -> the mask (B, S, S) int8, written where it lies: no
+    attention kernel's first result by
+    `benchmark/families/lfm2_moe.py:is_attention_kernel`."""
+    B, S, _ = scores.shape
+    n_k = S // block_k
+    return pl.pallas_call(
+        functools.partial(_select_kernel, top_k=top_k, block_q=block_q,
+                          block_k=block_k, bits=bits),
+        grid=(B, S // block_q),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((B, S, S), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((2, n_k, block_q, block_k), jnp.int32),
+                        pltpu.VMEM((n_k, block_q, block_k), jnp.int8),
+                        pltpu.VMEM((block_q, 1), jnp.int32),
+                        pltpu.SemaphoreType.DMA((2, n_k)),
+                        pltpu.SemaphoreType.DMA(())],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_SELECT_VMEM_MAX))(scores)
+
+
+def select_top_k(scores, top_k, block=512):
+    """scores (B, S, S) float32, -inf above the diagonal (`index_scores`)
+    -> the mask (B, S, S) int8: 1 where query t attends key s, the
+    min(``top_k``, t + 1) keys s <= t of largest score, of equal scores the
+    lower s (`jax.lax.top_k`'s set).  A constant: no gradient passes.  The
+    first ``top_k`` queries attend every key they see: their rows are the
+    causal triangle and search nothing.  One kernel a layer that reads the
+    scores once and writes the mask and nothing else (`_pallas_select`),
+    or ``block`` queries at a time through HBM by `_select_reference`
+    where `_select_tiles` declines the shape."""
+    B, S, _ = scores.shape
+    block = _block(S, block)
+    tiles = _select_tiles(S)
+    selected = sum(min(top_k, t + 1) for t in range(S))
+    tracing.count("attention.keys_selected", top_k)
+    tracing.count("attention.pairs_causal", B * S * (S + 1) // 2)
+    tracing.count("attention.pairs_selected", B * selected)
+    tracing.count("attention.mask_bytes", B * S * S)
+    tracing.count("attention.select_rows_fused", B * S if tiles else 0)
+    select = _kernel_or_reference(
+        functools.partial(_pallas_select, top_k=top_k),
+        functools.partial(_select_reference, top_k=top_k, block=block),
+        tiles)
+    return select(jax.lax.stop_gradient(scores))
 
 
 def _target_reference(q, k, lse, mask, start, *, scale):

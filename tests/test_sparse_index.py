@@ -1,8 +1,9 @@
 """`ray_tpu/ops/sparse_index.py` at small sizes on the CPU: the index scores
 against a dense einsum, their two kernels interpreted against the blocked
 reference and the dense form, the threshold search against a stable sort and
-`jax.lax.top_k` (ties, zeros of either sign, every k), the indexer's loss and its gradient against the same written densely, the
-loss's kernel (the target, the rows' losses and the gradient in one)
+`jax.lax.top_k` (ties, zeros of either sign, every k), the selection's
+kernel interpreted against that search in plain XLA, the indexer's loss and
+its gradient against the same written densely, the loss's kernel (the target, the rows' losses and the gradient in one)
 interpreted against its plain reference, what it counts on the job
 timeline, and the loss at the cell's shape exported for a TPU."""
 
@@ -46,6 +47,14 @@ def sorted_selection(scores, top_k):
     rank = jnp.argsort(jnp.argsort(-scores, axis=-1, stable=True), axis=-1)
     return np.asarray((rank < jnp.minimum(top_k, jnp.arange(S) + 1)[:, None])
                       & TRI)
+
+
+def top_ks_set(scores, k):
+    """`jax.lax.top_k`'s k keys of every row as a mask (B, S, S) of bools."""
+    _, chosen = jax.lax.top_k(scores, k)
+    want = np.zeros(scores.shape, bool)
+    np.put_along_axis(want, np.asarray(chosen), True, axis=-1)
+    return want
 
 
 @pytest.mark.parametrize("block", [16, 64, 512])
@@ -177,11 +186,9 @@ def test_selection_is_the_stable_sorts_and_top_ks_set(top_k, ties):
                           sorted_selection(scores, top_k))
     # `jax.lax.top_k` on the rows that have top_k keys to choose from
     k = min(top_k, S)
-    _, chosen = jax.lax.top_k(scores, k)
     rows = np.arange(S) + 1 >= k
-    want = np.zeros((B, S, S), bool)
-    np.put_along_axis(want, np.asarray(chosen), True, axis=-1)
-    assert np.array_equal((np.asarray(mask) != 0)[:, rows], want[:, rows])
+    assert np.array_equal((np.asarray(mask) != 0)[:, rows],
+                          top_ks_set(scores, k)[:, rows])
     assert int(mask.sum()) == B * sum(min(top_k, t + 1) for t in range(S))
 
 
@@ -238,6 +245,63 @@ def test_scores_that_fall_with_distance_select_a_window():
     distance = np.arange(S)[:, None] - np.arange(S)[None]
     assert np.array_equal(np.asarray(mask[0]) != 0,
                           (distance >= 0) & (distance < 16))
+
+
+def selection_scores(B, S, kind, seed=20):
+    """Scores (B, S, S) of ``kind``, -inf above the diagonal: random;
+    rounded to halves (many tie at the threshold); one value in every row;
+    zeros of either sign beside a few other values; and -inf under the
+    diagonal too, on a third of the pairs (rows with fewer finite scores
+    than top_k: the -inf keys tie, and the lower win)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    scores = jax.random.normal(ks[0], (B, S, S))
+    if kind == "ties":
+        scores = jnp.round(scores * 2) / 2 + 0.0
+    if kind == "one_value":
+        scores = jnp.broadcast_to(
+            jax.random.normal(ks[1], (B, S, 1)), (B, S, S))
+    if kind == "signed_zeros":
+        sign = jnp.where(jax.random.bernoulli(ks[1], 0.5, (B, S, S)),
+                         0.0, -0.0)
+        scores = jnp.where(jnp.abs(scores) < 1.2, sign, jnp.round(scores))
+    if kind == "neg_inf_below":
+        scores = jnp.where(jax.random.bernoulli(ks[1], 0.33, (B, S, S)),
+                           -jnp.inf, scores)
+    return jnp.where(jnp.tril(jnp.ones((S, S), bool)), scores, -jnp.inf)
+
+
+@pytest.mark.parametrize("kind", ["random", "ties", "one_value",
+                                  "signed_zeros", "neg_inf_below"])
+@pytest.mark.parametrize("B,S,block,block_q,block_k,top_k", [
+    (1, 256, 64, 64, 128, 32),      # top_k below a tile, two q tiles a k tile
+    (2, 256, 64, 64, 128, 64),      # a tile: the first is free
+    (1, 256, 128, 32, 256, 100),    # above one: a tile half free, searched
+    (2, 512, 128, 128, 128, 200),   # four tiles each way
+    (1, 512, 64, 256, 128, 256),    # two k tiles a q tile, one free
+    (1, 256, 64, 128, 128, 700),    # past the sequence: nothing searched
+])
+def test_the_selection_kernel_is_its_reference_and_top_ks_set(
+        B, S, block, block_q, block_k, top_k, kind):
+    """`_pallas_select`, interpreted, at tiles that leave several each way
+    and at either number of bits a pass, against `_select_reference` (the
+    plain XLA form by blocks, what `select_top_k` ran before the kernel
+    and runs for a shape it declines): the same bytes; and against
+    `jax.lax.top_k`'s set on the rows that have top_k keys to choose
+    from (of the scores + 0.0: XLA's sort on this host puts -0.0 under
+    0.0, the selection holds them one score)."""
+    scores = selection_scores(B, S, kind)
+    want = si._select_reference(scores, top_k=top_k, block=block)
+    for bits in (1, 2):
+        got = si._pallas_select(scores, top_k=top_k, block_q=block_q,
+                                block_k=block_k, bits=bits, interpret=True)
+        assert got.dtype == jnp.int8 and got.shape == (B, S, S)
+        assert np.array_equal(np.asarray(got), np.asarray(want)), bits
+    k = min(top_k, S)
+    rows = np.arange(S) + 1 >= k
+    assert np.array_equal((np.asarray(got) != 0)[:, rows],
+                          top_ks_set(scores + 0.0, k)[:, rows])
+    assert int(jnp.sum(got, dtype=jnp.int32)) \
+        == B * sum(min(top_k, t + 1) for t in range(S))
 
 
 def attention_inputs(seed=4):
@@ -458,11 +522,30 @@ def test_what_it_counts_as_the_step_is_traced(monkeypatch):
         "attention.score_tiles_skipped": 0,
         "attention.pairs_causal": B * S * (S + 1) // 2,
         "attention.pairs_selected": B * selected,
-        "attention.mask_bytes": B * S * S}
+        "attention.mask_bytes": B * S * S,
+        # 64 keys are no whole 128-lane tile: the plain form's, all of them
+        "attention.select_rows_fused": 0}
     # the cell's sizes: 43.75 % of the causal pairs
     full = sum(min(2048, t + 1) for t in range(8192))
     assert full == 14_681_088
     assert round(100 * full / (8192 * 8193 // 2), 2) == 43.75
+
+    def rows_selected_fused(B, S, block):
+        counted.clear()
+        jax.eval_shape(lambda s: si.select_top_k(s, 2048, block=block),
+                       jax.ShapeDtypeStruct((B, S, S), jnp.float32))
+        return counted["attention.select_rows_fused"]
+
+    # the cell's: `_SELECT_TILE` tiles, every row of both sequences
+    assert si._select_tiles(8192) == si._SELECT_TILE
+    assert rows_selected_fused(2, 8192, 512) == 2 * 8192
+    assert rows_selected_fused(1, 16384, 512) == 16384
+    assert si._select_tiles(256) == (128, 256)
+    # 8,256 keys are no whole number of 128-lane tiles, and two rows of a
+    # q tile's integers at 65,536 keys are more than VMEM may hold: the
+    # reference, no row counted
+    assert si._select_tiles(8256) is None and si._select_tiles(65536) is None
+    assert rows_selected_fused(1, 8256, 64) == 0
 
     def target_tiles(S, block, heads=(H, HKV), dim=D):
         counted.clear()
@@ -567,6 +650,40 @@ def test_a_shape_the_loss_kernel_declines_lowers_to_the_reference():
     module = exported_loss(8256, 64)
     assert "tpu_custom_call" not in module
     assert "stablehlo.dot_general" in module
+
+
+def exported_selection(S, block):
+    """The module of `select_top_k` at the keye cell's batch and top_k,
+    shapes only, exported for a TPU from this host."""
+    return jax.export.export(
+        jax.jit(lambda s: si.select_top_k(s, 2048, block=block)),
+        platforms=["tpu"])(
+            jax.ShapeDtypeStruct((2, S, S), jnp.float32)).mlir_module()
+
+
+def test_the_selection_lowers_to_mosaic_for_tpu_at_the_cells_shape():
+    """2 x 8,192 by blocks of 512: the selection is ONE Mosaic custom call
+    and nothing else: no loop, no block of the scores, and no operation
+    but the kernel makes a mask (no triangle joined to it)."""
+    module = exported_selection(8192, 512)
+    assert module.count("stablehlo.custom_call @tpu_custom_call") == 1
+    assert "stablehlo.while" not in module
+    assert "512x8192" not in module
+    assert "stablehlo.concatenate" not in module
+    made = {op for op, result in re.findall(
+        r"= (stablehlo\.\w+).*?(tensor<[^>]*>) loc\(", module)
+        if result.endswith("xi8>")}
+    assert made == {"stablehlo.custom_call"}
+
+
+def test_a_shape_the_selection_kernel_declines_lowers_to_the_reference():
+    """8,256 keys (129 blocks of 64) are no whole number of 128-lane
+    tiles: the threshold search in plain XLA by blocks on every platform,
+    the TPU included."""
+    module = exported_selection(8256, 64)
+    assert "tpu_custom_call" not in module
+    assert "stablehlo.while" in module
+    assert "stablehlo.concatenate" in module
 
 
 def exported_scores(S, block):

@@ -92,6 +92,15 @@ w relative to the reference on float32 operands at the highest precision; the
 cotangent is zero off 2,048 selected keys a query, as the loss's is.
 ``--sweep scores-8k`` times the kernels at other tiles.
 
+``select_8k`` is the selection alone (`ops/sparse_index.py:select_top_k`'s
+kernel, `_pallas_select`) at the same cell's shape (2 x 8,192 rows of 8,192
+scores, the kernels' own of random bfloat16 queries and keys, 2,048 keys a
+query): the one Mosaic kernel beside `_select_reference` (the threshold
+search in plain XLA by blocks of 512 queries), device ms of every operation,
+the seconds each took to compile, and the bytes of the mask in which the two
+differ, which is 0.  ``--sweep select-8k`` times the kernel at other tiles
+and at two bits of a threshold a pass, each against the reference's bytes.
+
 ``head_loss_8k`` is the head and its chunked loss alone
 (`models/layers.py:head_and_loss`, an untied head) at the cells' shape: 16,384
 rows of 2,048, chunks of 2,048, a vocabulary of 49,152 (ouro's a walk) and of
@@ -225,6 +234,18 @@ SCORES_CASES = {
 SCORES_SWEEP = {
     "scores-8k": ("scores_8k", ((128, 512), (256, 256), (256, 512),
                                 (256, 1024), (512, 512), (512, 1024))),
+}
+# (B, S, J, D_I, block, top_k) of the index scores one layer's selection reads
+SELECT_CASES = {
+    "select_8k": SCORES_CASES["scores_8k"],
+}
+# the (q tile, k tile, bits a pass) `--sweep select-8k` times a case of
+# SELECT_CASES at
+SELECT_SWEEP = {
+    "select-8k": ("select_8k", (
+        (64, 1024, 1), (128, 256, 1), (128, 512, 1), (128, 1024, 1),
+        (128, 2048, 1), (256, 512, 1), (256, 1024, 1), (512, 512, 1),
+        (64, 1024, 2), (128, 1024, 2), (256, 1024, 2), (512, 512, 2))),
 }
 # the tiles of a sequence past `_WHOLE_SEQ_MAX`: square, and the backward's
 # k tile (a grid step) beside another q tile (its loop's step)
@@ -970,6 +991,50 @@ def scores_case(name, dtype, tiles=None):
         yield line
 
 
+def select_case(name, dtype, tiles=None):
+    """One layer's selection at ``SELECT_CASES[name]``: a line for the
+    Mosaic kernel (``tiles``: at this (q tile, k tile, bits a pass) instead
+    of `_select_tiles`' and `_SELECT_BITS`) and, without ``tiles``, one for
+    `_select_reference`: device ms of every operation, the seconds the form
+    took to compile, and the bytes of the mask that differ from the
+    reference's."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import sparse_index as si
+
+    B, S, J, D, block, top_k = SELECT_CASES[name]
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    scores = jax.jit(functools.partial(si.index_scores, block=block))(
+        jax.random.normal(ks[0], (B, S, J, D), dtype),
+        jax.random.normal(ks[1], (B, S, D), dtype),
+        jax.random.normal(ks[2], (B, S, J), jnp.float32) * (J * D) ** -0.5)
+    reference = jax.jit(functools.partial(
+        si._select_reference, top_k=top_k, block=block))
+    bq, bk, bits = tiles or (*si._select_tiles(S), si._SELECT_BITS)
+    forms = {"kernel": jax.jit(functools.partial(
+        si._pallas_select, top_k=top_k, block_q=bq, block_k=bk, bits=bits,
+        interpret=False))}
+    if not tiles:
+        forms["reference"] = reference
+    compiled, seconds = {}, {}
+    for form, f in forms.items():       # before the reference's first call
+        began = time.perf_counter()
+        compiled[form] = f.lower(scores).compile()
+        seconds[form] = round(time.perf_counter() - began, 2)
+    want = reference(scores)
+    for form, f in forms.items():
+        yield {"case": name, "form": form, "tile": [bq, bk], "bits": bits,
+               "compile_s": seconds[form],
+               "mosaic_kernels": compiled[form].as_text().count(
+                   'custom_call_target="tpu_custom_call"'),
+               "every_op_ms": busy_ms(f, scores),
+               "bytes_differ": int(jnp.sum(f(scores) != want)),
+               "selected": int(jnp.sum(want, dtype=jnp.int32))}
+
+
 def ssd_case(name, dtype, chunk=None, compare=True):
     """One state-space scan at ``SSD_CASES[name]`` (``chunk`` given: at
     that chunk): a line for each form of it (`ops/ssd.py`) -- the `einsum`
@@ -1210,8 +1275,8 @@ def main():
                         default=[*CASES, *MOE_CASES, *MOE_ALL_CASES,
                                  *SHORTCONV_CASES,
                                  *SSD_CASES, *TARGET_CASES, *SCORES_CASES,
-                                 *HEAD_CASES, *GATENORM_CASES,
-                                 *WINDOW_CASES],
+                                 *SELECT_CASES, *HEAD_CASES,
+                                 *GATENORM_CASES, *WINDOW_CASES],
                         help=f"run these only ({', '.join(CASES)}, "
                              f"{', '.join(WINDOW_CASES)}, "
                              f"{', '.join((*MOE_CASES, *MOE_ALL_CASES))}, "
@@ -1219,15 +1284,16 @@ def main():
                              f"{', '.join(SSD_CASES)}, "
                              f"{', '.join(TARGET_CASES)}, "
                              f"{', '.join(SCORES_CASES)}, "
+                             f"{', '.join(SELECT_CASES)}, "
                              f"{', '.join(HEAD_CASES)}, "
                              f"{', '.join(GATENORM_CASES)}; default: all)")
     args = parser.parse_args()
-    swept = [*SWEEP, *SSD_SWEEP, *TARGET_SWEEP, *SCORES_SWEEP]
+    swept = [*SWEEP, *SSD_SWEEP, *TARGET_SWEEP, *SCORES_SWEEP, *SELECT_SWEEP]
     if args.sweep and set(args.sweep) - set(swept):
         parser.error(f"--sweep: no such shape in {sorted(swept)}")
     known = [*CASES, *MOE_CASES, *MOE_ALL_CASES, *SHORTCONV_CASES,
-             *SSD_CASES, *TARGET_CASES,
-             *SCORES_CASES, *HEAD_CASES, *GATENORM_CASES, *WINDOW_CASES]
+             *SSD_CASES, *TARGET_CASES, *SCORES_CASES, *SELECT_CASES,
+             *HEAD_CASES, *GATENORM_CASES, *WINDOW_CASES]
     if set(args.cases) - set(known):
         parser.error(f"--cases: no such case in {known}")
 
@@ -1252,14 +1318,15 @@ def main():
     if args.sweep is not None:
         for name in args.sweep or swept:
             for table, one in ((TARGET_SWEEP, target_case),
-                               (SCORES_SWEEP, scores_case)):
+                               (SCORES_SWEEP, scores_case),
+                               (SELECT_SWEEP, select_case)):
                 case, tiles = table.get(name, (None, ()))
                 for tile in tiles:
                     for line in one(case, jnp.bfloat16, tile):
                         print(json.dumps({
                             "sweep": name, **line,
                             "device_kind": dev.device_kind}), flush=True)
-            if name in TARGET_SWEEP or name in SCORES_SWEEP:
+            if name in (*TARGET_SWEEP, *SCORES_SWEEP, *SELECT_SWEEP):
                 continue
             if name in SSD_SWEEP:
                 case, chunks = SSD_SWEEP[name]
@@ -1347,6 +1414,15 @@ def main():
                 and line["above_diagonal_is_neg_inf"] \
                 and line["fwd_mosaic_kernels"] + line["bwd_mosaic_kernels"] \
                 == (2 if line["form"] == "kernel" else 0)
+            if not ok:
+                failed.append(f"{name}:{line['form']}")
+            print(json.dumps({**line, "ok": ok,
+                              "device_kind": dev.device_kind}), flush=True)
+    for name in SELECT_CASES:
+        for line in select_case(name, jnp.bfloat16) \
+                if name in args.cases else ():
+            ok = line["bytes_differ"] == 0 \
+                and line["mosaic_kernels"] == (line["form"] == "kernel")
             if not ok:
                 failed.append(f"{name}:{line['form']}")
             print(json.dumps({**line, "ok": ok,
