@@ -62,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.ops import causal_conv as conv_kernels
 from ray_tpu.ops import grouped_matmul
 from ray_tpu.ops.flash_attention import KEPT_RESIDUALS, KERNEL_FORMS
 from ray_tpu.ops.moe import ROUTE_NAME, moe_dispatch
@@ -451,17 +452,30 @@ def short_conv(u, p):
                      "short_conv/out_proj")
 
 
-def causal_conv(v, p, activation=None):
-    """A causal depthwise convolution with a bias, v (B, S, C) in the
-    (B, S, C) layout: out_t = activation(sum_j w_j * v_{t-(K-1)+j} + b), w
-    ``p["kernel"]`` (C, K), one filter a channel, b ``p["bias"]`` (C,),
-    zeros before the sequence starts.  K shifted multiply-adds in float32,
-    `short_conv`'s taps without its gates; jax differentiates it."""
-    out = _taps(p["kernel"], lambda k: _f32(_back(v, k))) \
-        + p["bias"].astype(jnp.float32)
-    if activation is not None:
-        out = activation(out)
-    return out.astype(v.dtype)
+# `causal_conv`'s activations, by the name `ops/causal_conv.py` knows them
+_CONV_ACTIVATIONS = {None: None, jax.nn.silu: "silu"}
+
+
+def causal_conv(v, p, activation=None, start=0, widths=None):
+    """A causal depthwise convolution with a bias in the (B, S, C) layout:
+    out_t = activation(sum_j w_j * v_{t-(K-1)+j} + b), w ``p["kernel"]``
+    (C, K), one filter a channel, b ``p["bias"]`` (C,), zeros before the
+    sequence starts; ``activation`` None or `jax.nn.silu`.  v (B, S, C), or
+    wider with the C channels its columns from ``start`` (a Mamba-2 mixer's
+    xBC in W_in's result); ``widths`` cuts the result's columns into a tuple
+    of arrays.  `ops/causal_conv.py` makes it: on a TPU, where ``start``
+    and the widths are whole 128-lane blocks and the sequence divides into
+    row tiles, a Mosaic kernel a pass, forward and backward, that reads the
+    columns where they lie; elsewhere `short_conv`'s K shifted
+    multiply-adds in float32 without its gates, which jax differentiates.
+    Counts itself on the job timeline as the step is traced: `conv.layers`,
+    and `conv.kernel_layers` the calls the kernels make."""
+    w, b = p["kernel"], p["bias"]
+    tracing.count("conv.layers")
+    tracing.count("conv.kernel_layers",
+                  int(conv_kernels.takes(v, w, start, widths)))
+    return conv_kernels.causal_conv(
+        v, w, b, _CONV_ACTIVATIONS[activation], start, widths)
 
 
 # What a recomputed layer may keep besides its attention kernel's residuals:

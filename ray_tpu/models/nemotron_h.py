@@ -65,9 +65,11 @@ mixed-precision step and its place for state that moves by a rule),
 `parallel/attention.py` (the flash kernels, 16 query heads on each
 key/value head), `ops/moe.py` (dispatch over a share of the experts, the
 sigmoid router, its account and its bias rule), `ops/ssd.py` (the scan's
-kernels) and `ops/gated_norm.py` (`gated_rms_norm`: the gate and the groups'
-norm behind the scan, one Mosaic kernel a pass at the published widths); the
-names are those `parallel/sharding.py` lays out.
+kernels), `ops/causal_conv.py` (behind `layers.causal_conv`: the taps, bias
+and SiLU of xBC read where W_in wrote it and x, B and C written as three
+results, one Mosaic kernel a pass at the published widths) and
+`ops/gated_norm.py` (`gated_rms_norm`: the gate and the groups' norm behind
+the scan, likewise); the names are those `parallel/sharding.py` lays out.
 
 `jax.named_scope`s (`models/layers.py:SCOPES`): embed, norm,
 ssm/{in_proj,conv,scan,gate_norm,out_proj}, attention/{qkv,kernel,out},
@@ -259,10 +261,12 @@ def _mamba(u, p, cfg: NemotronHConfig):
     with jax.named_scope("in_proj"):
         zxbcdt = named(u @ p["in_proj"]["kernel"].astype(u.dtype),
                        "ssm/in_proj")
-        _, xbc, dt = jnp.split(zxbcdt, [HP, HP + cfg.conv_width], axis=-1)
+        dt = zxbcdt[..., HP + cfg.conv_width:]
     with jax.named_scope("conv"):
-        xbc = causal_conv(xbc, p["conv"], jax.nn.silu)
-        x, Bm, Cm = jnp.split(xbc, [HP, HP + G * N], axis=-1)
+        # xBC where it lies, W_in's columns behind z, and x, B and C each a
+        # result of its own: no slice before the taps and none behind them
+        x, Bm, Cm = causal_conv(zxbcdt, p["conv"], jax.nn.silu, start=HP,
+                                widths=(HP, G * N, G * N))
     with jax.named_scope("scan"):
         dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
         y = named(ssd_scan(

@@ -606,3 +606,26 @@ def test_the_gated_norm_kernels_compile_for_the_chip_at_nemotrons_shape(
     text = jax.jit(both).lower(rows, rows, gain, rows).compile().as_text()
     assert text.count("tpu_custom_call") == 2
     assert "f32[16384,4096]" not in text        # nothing float32 in HBM
+
+
+def test_the_convolutions_kernels_compile_for_the_chip_at_nemotrons_shape(
+        one_chip):
+    """Mosaic takes `ops/causal_conv.py`'s kernels at the nemotron cell's
+    shape: x, B and C out of W_in's (2, 8192, 10304) result at column 4,096,
+    4 taps, a bias and a SiLU in bfloat16, a kernel a result forward and
+    backward; nothing runs.  Here for `test_gated_norm`'s reason."""
+    from ray_tpu.ops import causal_conv as cc
+
+    def both(v, w, b, dys):
+        outs, vjp = jax.vjp(lambda v, w, b: cc.causal_conv(
+            v, w, b, "silu", 4096, (4096, 1024, 1024)), v, w, b)
+        return outs, vjp(dys)
+
+    x = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    text = jax.jit(both).lower(
+        x(2, 8192, 10304), x(6144, 4, dtype=jnp.float32),
+        x(6144, dtype=jnp.float32),
+        tuple(x(2, 8192, width) for width in (4096, 1024, 1024))) \
+        .compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 6

@@ -133,6 +133,21 @@ chooses): device ms forward and forward + backward (one `jax.grad` in y, z
 and the gain), the seconds both took to compile, the least time of the
 bytes, and the largest error of the result and of the three gradients
 relative to the plain form on float32 operands.
+
+``conv_8k`` is Mamba-2's causal convolution alone
+(`ops/causal_conv.py:causal_conv`) at the nemotron cell's shape: 6,144
+channels of 4 taps with a bias and a SiLU, read out of a (2, 8192, 10304)
+source at column 4,096.  The plain jax form (`_reference`, the slice
+included) beside the two Mosaic kernels at each pair of ``CONV_TILES``
+(the forward's and the backward's (row tile, channel block, turns of the
+inner loop written out)), as one result and, at the kept pair, cut into x,
+B and C (``kept``: `_FORWARD` and `_BACKWARD` of the module): device ms of
+the forward alone and of a `jax.vjp` under a given cotangent (forward,
+backward and the padding of v's gradient), the kernels' own ms in both,
+the seconds both took to trace and lower and to compile, the least time of
+the bytes (forward reads and writes the channels, backward reads twice and writes once), and
+the largest error of the result and of the three gradients relative to the
+plain form on float32 operands.
 """
 
 from __future__ import annotations
@@ -198,6 +213,17 @@ GATENORM_CASES = {
     "gatenorm_8k": (2, 8192, 4096, 8),
 }
 GATENORM_TILES = (64, 128, 256, 512)
+# (B, S, source's width, first column, (x, B, C) widths, taps) of one causal
+# convolution, and the forward's and the backward's (row tile, channel block,
+# turns written out) its kernels are timed at
+CONV_CASES = {
+    "conv_8k": (2, 8192, 10304, 4096, (4096, 1024, 1024), 4),
+}
+CONV_TILES = tuple(((512, 1024, 1), bwd) for bwd in (
+    (2048, 256, 4), (1024, 256, 4), (512, 256, 4), (2048, 256, 2),
+    (2048, 256, 8), (2048, 512, 2), (2048, 128, 8))) + tuple(
+    (fwd, (2048, 256, 4)) for fwd in (
+        (2048, 1024, 1), (512, 512, 2), (1024, 256, 4)))
 # (T, k, held, experts, E, W): tokens, choices a token, experts held of the
 # router's, hidden and expert widths
 MOE_CASES = {
@@ -874,6 +900,93 @@ def gatenorm_case(name, dtype):
         yield line
 
 
+def conv_case(name, dtype):
+    """One causal convolution at ``CONV_CASES[name]``: a line for each form
+    of it, as the module's text says."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import causal_conv as cc
+
+    B, S, W, start, widths, K = CONV_CASES[name]
+    C = sum(widths)
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    v = jax.random.normal(ks[0], (B, S, W), dtype)
+    w = jax.random.uniform(ks[1], (C, K), jnp.float32, -0.5, 0.5)
+    b = jax.random.uniform(ks[2], (C,), jnp.float32, -0.5, 0.5)
+    seed = jax.random.normal(ks[3], (B, S, C), dtype)   # d loss / d out
+
+    def plain(v, w, b):
+        return cc._reference(v, w, b, start=start, activation="silu")
+
+    def kernels(cut):
+        def f(v, w, b):
+            outs, at = [], 0
+            for width in cut:
+                outs.append(cc._kernels(
+                    v, w[at:at + width], b[at:at + width], start + at,
+                    "silu"))
+                at += width
+            return jnp.concatenate(outs, axis=-1)
+        return f
+
+    def both(f):
+        return jax.jit(f), jax.jit(
+            lambda v, w, b, seed: jax.vjp(f, v, w, b)[1](seed))
+
+    rel = lambda g, w: round(float(
+        np.max(np.abs(np.asarray(g, np.float32) - np.asarray(w)))
+        / np.max(np.abs(np.asarray(w)))), 5)
+    exact = both(plain)
+    v32 = v.astype(jnp.float32)
+    want = (exact[0](v32, w, b),
+            *exact[1](v32, w, b, seed.astype(jnp.float32)))
+    del v32
+    kept = (cc._FORWARD, cc._BACKWARD)
+    peak = 819e9                       # HBM bytes a second, TPU v5e
+    array = B * S * C * jnp.dtype(dtype).itemsize
+    forms = [("plain", plain, None, None)] + [
+        ("kernel", kernels(cut), at, len(cut))
+        for at in CONV_TILES
+        for cut in ((C,), widths)[:1 + (at == kept)]]
+    for form, f, at, results in forms:
+        if at:
+            cc._FORWARD, cc._BACKWARD = at
+            jax.clear_caches()
+        forward, vjp = both(f)
+        began = time.perf_counter()
+        lowered = [forward.lower(v, w, b), vjp.lower(v, w, b, seed)]
+        traced = time.perf_counter()
+        compiled = [low.compile() for low in lowered]
+        line = {"case": name, "form": form,
+                "trace_lower_s": round(traced - began, 2),
+                "compile_s": round(time.perf_counter() - traced, 2),
+                "mosaic_kernels": sum(c.as_text().count(
+                    'custom_call_target="tpu_custom_call"')
+                    for c in compiled),
+                "fwd_ms": busy_ms(forward, v, w, b),
+                "vjp_ms": busy_ms(vjp, v, w, b, seed),
+                "least_fwd_ms": round(2 * array / peak * 1e3, 4),
+                "least_bwd_ms": round(3 * array / peak * 1e3, 4)}
+        if at:
+            line.update(forward=at[0], backward=at[1], results=results,
+                        kept=at == kept,
+                        fwd_kernels_ms=kernel_ms(forward, v, w, b),
+                        vjp_kernels_ms=kernel_ms(vjp, v, w, b, seed))
+        got = (forward(v, w, b), *vjp(v, w, b, seed))
+        line["rel_err"] = {what: rel(g, x) for what, g, x in zip(
+            ("out", "dv", "dw", "db"), got, want)}
+        if form == "plain" or at == kept:
+            line["fwd_ops"] = longest_ops(forward, v, w, b, top=4)
+            line["vjp_ops"] = longest_ops(vjp, v, w, b, seed, top=6)
+        yield line
+    cc._FORWARD, cc._BACKWARD = kept
+    jax.clear_caches()
+
+
 def target_case(name, dtype, tiles=None):
     """The indexer's loss at ``TARGET_CASES[name]``, one layer's of both
     sequences: a line for the fused Mosaic kernel (``tiles``: at these
@@ -1276,7 +1389,7 @@ def main():
                                  *SHORTCONV_CASES,
                                  *SSD_CASES, *TARGET_CASES, *SCORES_CASES,
                                  *SELECT_CASES, *HEAD_CASES,
-                                 *GATENORM_CASES, *WINDOW_CASES],
+                                 *GATENORM_CASES, *CONV_CASES, *WINDOW_CASES],
                         help=f"run these only ({', '.join(CASES)}, "
                              f"{', '.join(WINDOW_CASES)}, "
                              f"{', '.join((*MOE_CASES, *MOE_ALL_CASES))}, "
@@ -1286,14 +1399,15 @@ def main():
                              f"{', '.join(SCORES_CASES)}, "
                              f"{', '.join(SELECT_CASES)}, "
                              f"{', '.join(HEAD_CASES)}, "
-                             f"{', '.join(GATENORM_CASES)}; default: all)")
+                             f"{', '.join(GATENORM_CASES)}, "
+                             f"{', '.join(CONV_CASES)}; default: all)")
     args = parser.parse_args()
     swept = [*SWEEP, *SSD_SWEEP, *TARGET_SWEEP, *SCORES_SWEEP, *SELECT_SWEEP]
     if args.sweep and set(args.sweep) - set(swept):
         parser.error(f"--sweep: no such shape in {sorted(swept)}")
     known = [*CASES, *MOE_CASES, *MOE_ALL_CASES, *SHORTCONV_CASES,
              *SSD_CASES, *TARGET_CASES, *SCORES_CASES, *SELECT_CASES,
-             *HEAD_CASES, *GATENORM_CASES, *WINDOW_CASES]
+             *HEAD_CASES, *GATENORM_CASES, *CONV_CASES, *WINDOW_CASES]
     if set(args.cases) - set(known):
         parser.error(f"--cases: no such case in {known}")
 
@@ -1444,6 +1558,18 @@ def main():
                     3 if line["form"] == "kernel" else 0)
             if not ok:
                 failed.append(f"{name}:{line['form']}:{line.get('tile')}")
+            print(json.dumps({**line, "ok": ok,
+                              "device_kind": dev.device_kind}), flush=True)
+    for name in CONV_CASES:
+        for line in conv_case(name, jnp.bfloat16) \
+                if name in args.cases else ():
+            # a kernel a result forward; the vjp runs no forward of its own
+            ok = max(line["rel_err"].values()) < TOLERANCE \
+                and line["mosaic_kernels"] == 2 * line.get("results", 0)
+            if not ok:
+                failed.append(
+                    f"{name}:{line['form']}:{line.get('forward')}:"
+                    f"{line.get('backward')}")
             print(json.dumps({**line, "ok": ok,
                               "device_kind": dev.device_kind}), flush=True)
     for name in WINDOW_CASES:
