@@ -86,6 +86,8 @@ SCOPES = (
     *(f"attention/kernel/{form}" for form in KERNEL_FORMS),
     "attention/gate",
     "attention/out",
+    "attention/cross",
+    "attention/diff",
     "short_conv",
     "short_conv/in_proj",
     "short_conv/gate_taps",
@@ -96,6 +98,13 @@ SCOPES = (
     "ssm/scan",
     "ssm/gate_norm",
     "ssm/out_proj",
+    "mamba",
+    "mamba/in_proj",
+    "mamba/conv",
+    "mamba/x_proj",
+    "mamba/scan",
+    "mamba/out_proj",
+    "gmu",
     "ffn",
     "ffn/dense",
     "ffn/moe",
@@ -647,10 +656,13 @@ def _layer_marks(fn, args, static_argnums):
     return marked
 
 
-def keep_plan(fn, calls, static_argnums=(), behind=(), room=None):
+def keep_plan(fn, calls, static_argnums=(), behind=(), room=None,
+              shared=()):
     """What a stack of recomputed layers keeps.  ``calls``: the arguments
     of each call of ``fn``, the first argument the residual stream;
-    ``behind``: what the caller makes right behind the stack.
+    ``behind``: what the caller makes right behind the stack; ``shared``:
+    what layers hand on to later layers (`trunk`), alive from its maker's
+    pass to its last reader's backward whatever the budget: "already".
     -> {"names": the names of `KEPT_NAMES` kept, in its order;
     "bytes_kept": what they hold on one chip over the whole stack;
     "declined": the marked names that did not fit; "room": the budget;
@@ -681,6 +693,7 @@ def keep_plan(fn, calls, static_argnums=(), behind=(), room=None):
         already += stream
         heaviest = max(heaviest, stream + sum(marks[key].values()))
     already += sum(marked.pop(name, 0) for name in KEPT_RESIDUALS)
+    already += _activation_bytes(shared)
     reserve = int(max(_LIVE_LAYERS * heaviest,
                       _LIVE_BEHIND * _activation_bytes(behind)))
     state = getattr(_told, "state_bytes", None)
@@ -715,7 +728,7 @@ def _keep(names):
     return policy
 
 
-def checkpoint_layer(fn, stack=None, behind=(), **kw):
+def checkpoint_layer(fn, stack=None, behind=(), shared=(), **kw):
     """`jax.checkpoint(fn, **kw)` for a model's layer, and the one owner of
     what a recomputed layer keeps.  Always its attention kernel's output
     and row statistics (`ops/flash_attention.py:KEPT_RESIDUALS`, what the
@@ -726,13 +739,15 @@ def checkpoint_layer(fn, stack=None, behind=(), **kw):
     stack (`keep_plan`): the backward pass then recomputes the cheap glue
     between kept matmul results and no more.  ``behind``: arrays or avals
     of what the caller makes right behind the stack, with every kept value
-    alive (the head's logits, or a chunk of them).  The plan is made here, once
-    per traced stack, and counted on the job timeline: `remat.bytes_kept`
-    (one chip, all layers) and `remat.names_declined`.  A layer that marks
-    nothing is a bare `jax.checkpoint`."""
+    alive (the head's logits, or a chunk of them); ``shared``: what the
+    layers hand on to later layers, as `keep_plan` counts it.  The plan is
+    made here, once per traced stack, and counted on the job timeline:
+    `remat.bytes_kept` (one chip, all layers) and `remat.names_declined`.
+    A layer that marks nothing is a bare `jax.checkpoint`."""
     names = KEPT_RESIDUALS
     if stack is not None:
-        plan = keep_plan(fn, stack, kw.get("static_argnums", ()), behind)
+        plan = keep_plan(fn, stack, kw.get("static_argnums", ()), behind,
+                         shared=shared)
         names += plan["names"]
         tracing.count("remat.bytes_kept", plan["bytes_kept"])
         tracing.count("remat.names_declined", len(plan["declined"]))
@@ -741,7 +756,43 @@ def checkpoint_layer(fn, stack=None, behind=(), **kw):
     return jax.checkpoint(fn, policy=_keep(names), **kw)
 
 
-def trunk(params, tokens, layer, cfg, walks=None):
+def _final_norm(x, p, cfg):
+    """The norm behind a decoder's last layer, by its leaves: a LayerNorm at
+    `cfg.norm_eps` where ``p`` has a bias, else an RMSNorm at
+    `cfg.rms_eps`."""
+    if "bias" in p:
+        return layer_norm(x, p, cfg.norm_eps)
+    return rms_norm(x, p, cfg.rms_eps)
+
+
+def _shared_walk(x, layers, layer, cfg, behind):
+    """`trunk`'s walk for layers that hand tensors on: -> (x behind the last
+    layer, the layers' second results, a None left out).  What each call is
+    handed is known before any runs (an abstract walk, nothing counted), so
+    that one budget reckons every layer (`checkpoint_layer`) with what is
+    handed on counted as held whatever it decides."""
+    calls, made, held = [], [], None
+    with tracing.outside_job():
+        for i, p in enumerate(layers):
+            calls.append((x, p, cfg, held, i))
+            _, _, after = jax.eval_shape(
+                lambda x, p, held, i=i: layer(x, p, cfg, held, i), x, p, held)
+            made += [leaf for name, leaf in (after or {}).items()
+                     if name not in (held or {})]
+            held = after
+    tracing.count("shared.bytes_kept", _activation_bytes(made))
+    if cfg.remat:
+        layer = checkpoint_layer(layer, stack=calls, static_argnums=(2, 4),
+                                 behind=behind, shared=made)
+    seconds, held = [], None
+    for i, p in enumerate(layers):
+        x, second, held = layer(x, p, cfg, held, i)
+        if second is not None:
+            seconds.append(second)
+    return x, seconds
+
+
+def trunk(params, tokens, layer, cfg, walks=None, shared=False):
     """A decoder's walk, tokens (B, S) int32 -> ((B, S, E) after the final
     norm, the layers' second results in order, a None left out): the
     embedding ``params["embed_tokens"]`` under `embed`, in the compute
@@ -749,8 +800,20 @@ def trunk(params, tokens, layer, cfg, walks=None):
     `cfg.n_layer` layers in order, each recomputed by the backward pass
     when `cfg.remat`, keeping what fits the chip with a chunk of the head's
     logits (`cfg.loss_chunk_rows` of `cfg.vocab_size`) behind the stack
-    (`checkpoint_layer`); RMSNorm by ``params["norm_f"]`` at
-    `cfg.rms_eps`.
+    (`checkpoint_layer`); the final norm by ``params["norm_f"]``'s leaves
+    (`_final_norm`: an RMSNorm at `cfg.rms_eps`, or with a bias a LayerNorm
+    at `cfg.norm_eps`).
+
+    ``shared``: a model whose later layers read what earlier layers MADE
+    (a scan's result, a layer's keys and values).  Its layer is called
+    ``layer(x, p, cfg, held, i) -> (x, anything, held)``: ``held`` what the
+    layers before handed on, a dict of arrays or None, which the layer hands
+    on with what it adds; ``i`` its number here, static.  What is handed on
+    is a result of its maker's recomputed pass, so it lives from there to
+    its last reader's backward and the stack's budget counts it as held
+    (`keep_plan`'s ``shared``); its bytes on one chip are counted on the job
+    timeline as the step is traced (`shared.bytes_kept`).  False: no layer
+    is handed anything, and the program is what it always was.
 
     ``walks`` = T, a looped model's: the same layers are walked T times
     over the same parameters, the final norm after EVERY walk, and what it
@@ -772,12 +835,15 @@ def trunk(params, tokens, layer, cfg, walks=None):
         x = params["embed_tokens"]["embedding"][tokens].astype(
             cfg.compute_dtype)
     layers = [params[f"layer_{i}"] for i in range(cfg.n_layer)]
+    behind = jax.ShapeDtypeStruct((cfg.loss_chunk_rows, cfg.vocab_size),
+                                  jnp.float32)
+    if shared:
+        x, seconds = _shared_walk(x, layers, layer, cfg, behind)
+        return _final_norm(x, params["norm_f"], cfg), seconds
     if cfg.remat:
         layer = checkpoint_layer(
             layer, stack=[(x, p, cfg) for p in layers] * (walks or 1),
-            static_argnums=(2,),
-            behind=jax.ShapeDtypeStruct(
-                (cfg.loss_chunk_rows, cfg.vocab_size), jnp.float32))
+            static_argnums=(2,), behind=behind)
 
     def walk(x):
         seconds = []
@@ -787,7 +853,7 @@ def trunk(params, tokens, layer, cfg, walks=None):
             x, second = layer(x, p, cfg)
             if second is not None:
                 seconds.append(second)
-        return rms_norm(x, params["norm_f"], cfg.rms_eps), seconds
+        return _final_norm(x, params["norm_f"], cfg), seconds
 
     if walks is None:
         return walk(x)

@@ -30,3 +30,11 @@ def by_platform(kernel, reference, q, *rest):
     return jax.lax.platform_dependent(
         q, *rest, tpu=functools.partial(kernel, interpret=False),
         default=other)
+
+
+# Mamba-1's scan, by the name its callers know (behind the rule above, which
+# its module reads from here).  The function takes the module's place as this
+# package's attribute: the module is `sys.modules[__name__ + ".selective_scan"]`
+# (`importlib.import_module`), and `from ray_tpu.ops.selective_scan import ...`
+# reads it as ever.
+from ray_tpu.ops.selective_scan import selective_scan  # noqa: E402

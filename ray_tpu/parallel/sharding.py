@@ -213,6 +213,18 @@ def infer_param_logical_dims(path: Tuple[str, ...], shape: Tuple[int, ...]):
         # a depthwise filter a channel, (E, taps), and a state-space
         # mixer's with its bias: the taps are never cut
         return ("embed", None)[:nd]
+    if nd == 2 and ("mamba/x_proj" in name or "mamba/a_log" in name):
+        # a Mamba-1 mixer's W_x (C, R + 2 N) and A_log (C, N): the channels
+        # cut as the stream's width is, the rank and the state never (a
+        # Mamba-2 mixer's A_log is a vector of its heads, whole everywhere)
+        return ("embed", None)
+    if "mamba/dt_proj" in name:
+        # W_dt (R, C) and dt's bias (C,): the channels again
+        return (None, "embed") if nd == 2 else ("embed",)
+    if "lambda_" in name or "diff_norm" in name:
+        # differential attention's four vectors of a head's width and the
+        # gain over a pair of heads: whole on every chip
+        return (None,) * nd
     if "moe" in name and "/wi" in name:
         return ("expert", "embed", "mlp")[:nd]
     if "moe" in name and "/wo" in name:

@@ -35,6 +35,7 @@ from ray_tpu.models import (
     nemotron_h,
     olmoe,
     ouro,
+    phi4flash,
     sdar,
 )
 from ray_tpu.ops import flash_attention as fa
@@ -52,6 +53,7 @@ MODELS = {
     "sdar": (sdar, sdar.SDAR_TINY),
     "mellum": (mellum, mellum.MELLUM_TINY),
     "laguna": (laguna, laguna.LAGUNA_TINY),
+    "phi4flash": (phi4flash, phi4flash.PHI4FLASH_TINY),
 }
 CASES = [(name, remat) for name in MODELS for remat in (False, True)]
 # what every model's step must have a matmul under
@@ -93,6 +95,12 @@ EXPECTED = {
                "attention/gate", "attention/out", "ffn/dense",
                "ffn/moe/route", "ffn/moe/experts", "ffn/moe/shared",
                "head_and_loss"},
+    "phi4flash": {"mamba/in_proj", "mamba/x_proj", "mamba/out_proj",
+                  "mamba/conv", "attention/qkv", "attention/cross",
+                  "attention/kernel/fwd_rows_window",
+                  "attention/kernel/bwd_fused_window",
+                  "attention/kernel/fwd_rows", "attention/kernel/bwd_fused",
+                  "attention/out", "gmu", "ffn/dense", "head_and_loss"},
 }
 # components of an `op_name` that jax puts there itself (`jnp.einsum` its
 # subscripts: `ops/ssd.py`'s products)
@@ -229,7 +237,11 @@ def test_every_matmul_and_kernel_is_under_a_scope(name, remat):
     # that have such layers (beside their full layers' plain ones)
     assert ("attention/kernel/fwd_rows_window" in every) \
         is ("attention/kernel/bwd_fused_window" in every) \
-        is (name in ("mellum", "laguna"))
+        is (name in ("mellum", "laguna", "phi4flash"))
+    # a Mamba-1 mixer's scan and what differential attention adds behind
+    # its kernels, in the one model that has them
+    assert ({"mamba/scan", "attention/diff"} <= every) \
+        is (name == "phi4flash")
     # a gate on attention's result, in the one model that has one
     assert ("attention/gate" in every) is (name == "laguna")
 

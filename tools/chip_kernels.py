@@ -155,6 +155,7 @@ from __future__ import annotations
 import argparse
 import functools
 import glob
+import importlib
 import json
 import os
 import sys
@@ -190,10 +191,17 @@ WINDOW_CASES = {
     "mellum_16k": ((1, 16384, 32, 128), 4, 1024),
     "mellum_8k": ((1, 8192, 32, 128), 4, 1024),
     "laguna_16k": ((1, 16384, 64, 128), 8, 512, 48),
+    # Phi-4-mini-flash's differential attention, one of a layer's two
+    # calls: 20 query heads on 10, q and k 64 wide on v 128 wide
+    "phi4_16k": ((1, 16384, 20, 64, 128), 10, 512),
 }
 WINDOW_TILES = ((None, None), (256, 256), (512, 512), (1024, 1024))
 # a window of 512 is swept a tile further down
 NARROW_TILES = ((None, None), (128, 128), (256, 256), (512, 512))
+# (B, S, C, N) of one Mamba-1 selective scan
+SSCAN_CASES = {
+    "sscan_16k": (1, 16384, 5120, 16),
+}
 # (B, S, H, P, G, N, chunk) of one state-space scan
 SSD_CASES = {
     "ssd_8k": (2, 8192, 64, 64, 8, 128, 128),
@@ -1243,6 +1251,85 @@ def ssd_case(name, dtype, chunk=None, compare=True):
     ssd._carry = kept
 
 
+def sscan_case(name, dtype):
+    """One Mamba-1 selective scan at ``SSCAN_CASES[name]``
+    (`ops/selective_scan.py`): a line for the float32 recurrence run
+    position by position in recomputed blocks of 64
+    (`benchmark/reference/phi4flash.py:recurrence`; the plain `lax.scan`
+    `ops/selective_scan.py:_reference` keeps every position's state under a
+    gradient, 5.4 GB at this shape, and does not fit the chip) and one for
+    the Mosaic kernels at each block of positions of `_TIME_BLOCKS`
+    (`kept`: the one `_blocks` chooses): forward ms and forward + backward
+    ms of one `jax.grad` in all six operands, every operation counted (the
+    re-laying of u, dt and y to the kernels' tiles among them) and the
+    kernels alone, beside the least time of the scan's bytes, and the
+    largest error of y and of the six gradients relative to the
+    recurrence's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.reference import phi4flash as reference
+
+    sscan = importlib.import_module("ray_tpu.ops.selective_scan")
+    B, S, C, N = SSCAN_CASES[name]
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    u = jax.random.normal(ks[0], (B, S, C), dtype)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, C)) - 4.0)
+    A = -jnp.broadcast_to(jnp.arange(1, N + 1, dtype=jnp.float32), (C, N))
+    Bm = jax.random.normal(ks[2], (B, S, N), dtype)
+    Cm = jax.random.normal(ks[3], (B, S, N), dtype)
+    D = jnp.ones((C,), jnp.float32)
+    seed = jax.random.normal(ks[4], (B, S, C), jnp.float32)
+    args = (u, dt, A, Bm, Cm, D)
+
+    def by_positions(u, dt, A, Bm, Cm, D):
+        f32 = lambda v: v.astype(jnp.float32)
+        return jax.lax.map(lambda a: reference.recurrence(
+            f32(a[0]), a[1], A, f32(a[2]), f32(a[3]), D, 64),
+            (u, dt, Bm, Cm))
+
+    def both(scan):
+        return jax.jit(scan), jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(scan(*a).astype(jnp.float32) * seed),
+            tuple(range(6))))
+
+    rel = lambda g, w: round(float(
+        np.max(np.abs(np.asarray(g, np.float32) - np.asarray(w, np.float32)))
+        / np.max(np.abs(np.asarray(w, np.float32)))), 5)
+    width = jnp.dtype(dtype).itemsize
+    read = (C + 2 * N) * width + C * 4
+    least = lambda b: round(B * S * b / 819e9 * 1e3, 4)
+    exact = both(by_positions)
+    want = (exact[0](*args), *exact[1](*args)[1])
+    chosen = sscan._blocks(S, C, N)[0]
+    kept = sscan._TIME_BLOCKS
+    forms = [("recurrence", None)] + [("kernels", (t,)) for t in kept
+                                      if t <= chosen and S % t == 0]
+    for form, blocks in forms:
+        if blocks:
+            sscan._TIME_BLOCKS = blocks
+            jax.clear_caches()
+        forward, grad = both(sscan.selective_scan) if blocks else exact
+        line = {"case": name, "form": form, "dtype": jnp.dtype(dtype).name,
+                "positions": blocks and blocks[0],
+                "kept": bool(blocks) and blocks[0] == chosen,
+                "fwd_ms": busy_ms(forward, *args),
+                "fwd_bwd_ms": busy_ms(grad, *args),
+                "fwd_kernel_ms": kernel_ms(forward, *args),
+                "fwd_bwd_kernel_ms": kernel_ms(grad, *args),
+                "least_fwd_ms": least(read + C * width),
+                "least_fwd_bwd_ms": least(3 * read + 2 * C * width),
+                "mosaic_kernels": grad.lower(*args).compile().as_text(
+                    ).count('custom_call_target="tpu_custom_call"')}
+        got = (forward(*args), *grad(*args)[1])
+        line["rel_err"] = {what: rel(g, t) for what, g, t in zip(
+            ("y", "du", "ddt", "dA", "dB", "dC", "dD"), got, want)}
+        yield line
+    sscan._TIME_BLOCKS = kept
+    jax.clear_caches()
+
+
 def window_case(name, dtype):
     """One line a rule (the window, then none) and a tile (`_auto_tiles`',
     then `WINDOW_TILES`) of ``flash_attention_bshd`` at the case's shape:
@@ -1280,7 +1367,7 @@ def window_case(name, dtype):
             return jnp.einsum("bhqk,bhkd->bhqd", probs, vf)
 
         out = jax.lax.map(some, jnp.arange(0, S, block))  # (n, B, H, ., D)
-        return out.transpose(1, 0, 3, 2, 4).reshape(B, S, H, D)
+        return out.transpose(1, 0, 3, 2, 4).reshape(B, S, H, v.shape[-1])
 
     def grad(f):
         return jax.jit(jax.value_and_grad(
@@ -1389,7 +1476,8 @@ def main():
                                  *SHORTCONV_CASES,
                                  *SSD_CASES, *TARGET_CASES, *SCORES_CASES,
                                  *SELECT_CASES, *HEAD_CASES,
-                                 *GATENORM_CASES, *CONV_CASES, *WINDOW_CASES],
+                                 *GATENORM_CASES, *CONV_CASES, *WINDOW_CASES,
+                                 *SSCAN_CASES],
                         help=f"run these only ({', '.join(CASES)}, "
                              f"{', '.join(WINDOW_CASES)}, "
                              f"{', '.join((*MOE_CASES, *MOE_ALL_CASES))}, "
@@ -1400,14 +1488,16 @@ def main():
                              f"{', '.join(SELECT_CASES)}, "
                              f"{', '.join(HEAD_CASES)}, "
                              f"{', '.join(GATENORM_CASES)}, "
-                             f"{', '.join(CONV_CASES)}; default: all)")
+                             f"{', '.join(CONV_CASES)}, "
+                             f"{', '.join(SSCAN_CASES)}; default: all)")
     args = parser.parse_args()
     swept = [*SWEEP, *SSD_SWEEP, *TARGET_SWEEP, *SCORES_SWEEP, *SELECT_SWEEP]
     if args.sweep and set(args.sweep) - set(swept):
         parser.error(f"--sweep: no such shape in {sorted(swept)}")
     known = [*CASES, *MOE_CASES, *MOE_ALL_CASES, *SHORTCONV_CASES,
              *SSD_CASES, *TARGET_CASES, *SCORES_CASES, *SELECT_CASES,
-             *HEAD_CASES, *GATENORM_CASES, *CONV_CASES, *WINDOW_CASES]
+             *HEAD_CASES, *GATENORM_CASES, *CONV_CASES, *WINDOW_CASES,
+             *SSCAN_CASES]
     if set(args.cases) - set(known):
         parser.error(f"--cases: no such case in {known}")
 
@@ -1578,6 +1668,17 @@ def main():
             ok = max(line["rel_err"].values()) < TOLERANCE
             if not ok:
                 failed.append(f"{name}:{line['window']}:{line['tile']}")
+            print(json.dumps({**line, "ok": ok,
+                              "device_kind": dev.device_kind}), flush=True)
+    for name in SSCAN_CASES:
+        for line in sscan_case(name, jnp.bfloat16) \
+                if name in args.cases else ():
+            # a kernel forward (with the entering states), one backward
+            ok = max(line["rel_err"].values()) < TOLERANCE \
+                and line["mosaic_kernels"] == (
+                    2 if line["form"] == "kernels" else 0)
+            if not ok:
+                failed.append(f"{name}:{line['form']}:{line['positions']}")
             print(json.dumps({**line, "ok": ok,
                               "device_kind": dev.device_kind}), flush=True)
     if failed:
