@@ -39,12 +39,26 @@ whole tile is not masked, and a crossed tile's mask is made in the kernel
 from its offsets (`_rule_mask`: block indices compared), never read.
 ``causal=True`` is the rule at a block of 1 with one kind of row and
 compiles to the kernel it always was.  A WINDOW (`BlockRule(window=W)`: a
-row attends its W latest keys) is a second bound inside the same rule: a
-q tile's k tiles run from the first its window reaches, a k tile's q tiles
-end with the last whose window still holds it, and a tile is crossed by the
-window's bound, by the diagonal, by both or by neither (`_two_bounds`); the
-lower compare is `_rule_mask`'s too.  A model whose layers mix windowed
-and full attention pays for the pairs each attends, and no (S, S) mask.
+row attends its W latest keys) is a second bound inside the same rule, the
+lower compare `_rule_mask`'s too.  Past `_WHOLE_SEQ_MAX` a tile's keys are
+ONE band (`_band`, PR 64): a q tile's W' + block_q keys (the backward: a k
+tile's block_k + W' query rows; W' the window in whole lanes) are one slice
+of the k and v (q, do, statistics and dq) the kernel holds anyway, one
+product, one mask with both bounds and one turn of the softmax, and a grid
+step takes `_BAND_STEP` rows, its tiles written out.  On a v5e at
+S = 16,384 (ms a layer, forward + backward, the walk of 512-tiles beside
+the band of 256-tiles; PERF.md §6, PR 64): W = 512 with 64 query heads on
+8 of 128 19.42 -> 11.40, W = 1,024 with 32 on 4 12.70 -> 8.27, W = 512
+with 20 on 10 and q, k 64 wide on v 128 6.18 -> 3.67; the band's mask is
+0.14 of 3.16 ms of the forward and nothing of the backward, so it is
+compared in the kernel at every tile and not made once.  Where no band is
+taken (a grid step the whole sequence, a mask that is data, a window too
+wide for `_TILE_VMEM` or as long as the sequence) a q tile's k tiles run
+from the first its window reaches, a k tile's q tiles end with the last
+whose window still holds it, and a tile is crossed by the window's bound,
+by the diagonal, by both or by neither (`_two_bounds`).  A model whose
+layers mix windowed and full attention pays for the pairs each attends,
+and no (S, S) mask.
 
 Up to S = `_WHOLE_SEQ_MAX` a grid step takes a whole (b, h) slice (a
 128-lane group in the lane layout) and every extent inside it is static,
@@ -103,8 +117,8 @@ Each kernel adds its tiles, the pairs of those it visits and the heads it
 reads to the job timeline as the step is traced (`attention.tiles`,
 `attention.tiles_skipped`, `attention.pairs_visited`, `attention.q_heads`,
 `attention.kv_heads`, and under a window `attention.window_kernels`,
-`attention.window` and `attention.window_pairs_visited`: see
-`_count_tiles`).
+`attention.window`, `attention.window_pairs_visited` and, where it takes a
+band, `attention.window_band_kernels`: see `_count_tiles`).
 
 On non-TPU backends the same kernels run in interpret mode for tiny shapes
 (tests), and a pure-XLA reference path is used otherwise.
@@ -251,6 +265,13 @@ def _tile_loop(first, last, block, body, carry):
         first, last, lambda i, c: body(i * block, block, c), carry)
 
 
+def _run(start, rows, block, body, carry):
+    """A band's walker (`_band`): its one run comes in rows already,
+    ``rows`` of them (a Python int) from ``start`` (the grid's), and is ONE
+    visit whatever the tile."""
+    return body(start, rows, carry)
+
+
 class BlockRule(NamedTuple):
     """Which keys a query attends, as a RULE of static integers: the tiles
     it empties are known when the step is traced and are never fetched, the
@@ -301,6 +322,52 @@ def _windowed(causal) -> bool:
     return isinstance(causal, BlockRule) and causal.window is not None
 
 
+def _band(causal, S, block, whole, masked=False):
+    """The rows of the ONE band a tile of ``block`` rows takes under a
+    window past `_WHOLE_SEQ_MAX`, None where the call keeps the walk of
+    tiles (`_two_bounds`).  A q tile's keys are the one run [q_start - W +
+    1, q_start + block), a k tile's query rows [k_start, k_start + block +
+    W - 1): W' + block rows, W' the window rounded up to whole lanes, which
+    keeps the run's start (clamped at 0, or so that it ends at S) where a
+    slice of the held rows may start.  The kernel reads it as one slice,
+    makes one product, one mask with both bounds and, forward, one turn of
+    the softmax, where a walk of 512-tiles visits two or three tiles, each
+    with its own mask and turn.  What decides is what the call can see:
+    ``whole`` (a grid step the whole sequence: every extent is static and
+    `_span` merges already), ``masked`` (a mask that is data comes
+    tile-major, a tile a visit), a tile that is no whole lanes, a band
+    longer than the sequence (W >= S - block: little or nothing to leave
+    out) and a band whose temporaries (s and dp in float32, p and ds in
+    bfloat16: 12 bytes a pair) outgrow `_TILE_VMEM`: at 512-tiles a window
+    past 1,152, whose walk has whole tiles between the crossed ones."""
+    rule = _rule(causal)
+    if whole or masked or rule is None or rule.window is None \
+            or block % _LANE:
+        return None
+    rows = _lanes(rule.window) + block
+    if rows > S or 12 * block * rows > _TILE_VMEM:
+        return None
+    return rows
+
+
+# The rows a grid step takes under a band, as whole tiles of the band's
+# (`_band_step`; swept on a v5e at 256-tiles, a tile a step and steps of 512,
+# 1,024 and 2,048 rows, forward + backward ms a layer: W = 512, 64 heads on 8
+# of 128, 13.08 / 12.03 / 11.40 / 11.05; W = 1,024, 32 on 4, 9.32 / 8.81 /
+# 8.27 / 7.93: PERF.md §6, PR 64.  2,048 writes out eight tiles a kernel for
+# 3 % more).
+_BAND_STEP = 1024
+
+
+def _band_step(S, block, band):
+    """The rows of a grid step whose tile is ``block``: under a ``band`` at
+    least `_BAND_STEP` of them where they divide S, its tiles written out
+    one after the other (independent chains, which the scheduler may
+    interleave), so that a step's fixed cost is paid once for several."""
+    step = max(block, _BAND_STEP)
+    return step if band and S % step == 0 else block
+
+
 def _int(flag):
     """A comparison of tile indices as 0 or 1, whether they are Python's
     (every extent static) or the grid's."""
@@ -328,6 +395,12 @@ def _least(n, x):
     return min(n, x) if isinstance(x, int) else jnp.minimum(n, x)
 
 
+def _aligned(x):
+    """A band's first row, which is whole lanes from the sequence's start
+    (`_band`): said to Mosaic where it is the grid's."""
+    return x if isinstance(x, int) else pl.multiple_of(x, _LANE)
+
+
 def _two_bounds(rule, block_q, block_k, before, after):
     """The runs of tiles a window leaves a tile, in the order they are
     walked: ``before`` and ``after`` are (first, last, how) of the tiles
@@ -344,7 +417,7 @@ def _two_bounds(rule, block_q, block_k, before, after):
     return [(a, b, first_how), (b, c, None), (c, d, last_how)]
 
 
-def _k_spans(rule, qi, block_q, block_k, seq_len):
+def _k_spans(rule, qi, block_q, block_k, seq_len, band=None):
     """What q tile ``qi`` (a Python int, or the grid's) visits of the k
     tiles -> (the tile's index among its own kind's, whether its rows are
     noised (0 or 1), [(first, last, how)]): runs of k tiles, ``how`` None
@@ -352,8 +425,14 @@ def _k_spans(rule, qi, block_q, block_k, seq_len):
     the tiles among the clean keys and "own" among the noised keys of the
     rows' own blocks; under a window "from" where its bound crosses them
     and "both" where that and the diagonal do (`_two_bounds`).  Every other
-    tile is empty and in no run."""
+    tile is empty and in no run.  ``band`` (`_band`): the one run of that
+    many KEYS that ends with the tile's last row, (its first key, ``band``,
+    "both"), `_run`'s to walk; the first tiles' starts at key 0 and the
+    diagonal's compare takes the keys past their rows."""
     n = seq_len // block_k
+    if band:
+        return qi, 0, [
+            (_aligned(_floor0((qi + 1) * block_q - band)), band, "both")]
     if rule is None:
         return qi, 0, [(0, n, None)]
     if rule.window is not None:
@@ -377,11 +456,17 @@ def _k_spans(rule, qi, block_q, block_k, seq_len):
         (half_k + first, half_k + first + noised * (last - first), "own")]
 
 
-def _q_spans(rule, kj, block_q, block_k, seq_len):
+def _q_spans(rule, kj, block_q, block_k, seq_len, band=None):
     """`_k_spans` the other way: what k tile ``kj`` is visited by, of the q
     tiles -> (the tile's index among its own kind's, [(first, last, how,
-    whether those q rows are noised)])."""
+    whether those q rows are noised)]).  ``band``: the one run of that many
+    query ROWS from the tile's first key, (its first row, ``band``, "both",
+    0); the last tiles' ends at row S and the diagonal's compare takes the
+    rows before their keys."""
     n = seq_len // block_q
+    if band:
+        return kj, [(_aligned(_least(seq_len - band, kj * block_k)), band,
+                     "both", 0)]
     if rule is None:
         return kj, [(0, n, None, 0)]
     if rule.window is not None:
@@ -450,7 +535,7 @@ def _rule_mask(s, rule, q_start, k_start, how, strict=0):
 
 
 def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
-              seq_len, v_dim, select=None):
+              seq_len, v_dim, select=None, band=None):
     """Online-softmax forward over one q tile.
 
     At small head_dim the two dots leave the matrix unit half full and the
@@ -471,7 +556,9 @@ def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
     index.  ``over`` walks the runs of k blocks the rule leaves the tile
     (`_k_spans`; `_fwd_rows`): `_span` takes the interior blocks as one span
     and the diagonal's as another, a pass of two steps at most (three under
-    two kinds of row); `_tile_loop` loops over them.  read_k/read_v:
+    two kinds of row); `_tile_loop` loops over them; under a window's
+    ``band`` (`_band`) `_run` takes the tile's keys as one run, one mask
+    and one turn of the softmax.  read_k/read_v:
     (start, rows) -> (rows, d) of k and (rows, v_dim) of v, which may be
     another width (latent attention: 192 and 128).  Returns (acc f32
     (block_q, v_dim), m, l).
@@ -516,7 +603,7 @@ def _fwd_core(q, read_k, read_v, qi, over, *, causal, block_q, block_k,
     )
     # the runs of k blocks the rule leaves this q tile: those it attends
     # whole take the unmasked body, those it crosses the masked one
-    at, noised, spans = _k_spans(rule, qi, block_q, block_k, seq_len)
+    at, noised, spans = _k_spans(rule, qi, block_q, block_k, seq_len, band)
     for first, last, how in spans:
         carry = over(first, last, block_k, functools.partial(body, how=how),
                      carry)
@@ -556,8 +643,11 @@ def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     one step, the k tiles unrolled and every extent static, so each kind of
     q tile is merged into one span (`_span`, S rows at most).  One tile
     (past it): the slice's k tiles are the grid's second axis and each
-    loops over its q tiles (`_tile_loop`); they pass from the LAST to the
-    first (`_kv_rows`), because a causal slice's last k tile has one q tile
+    loops over its q tiles (`_tile_loop`); under a window's band (`_band`)
+    a step holds `_band_step` rows of k, and each k tile of them takes its
+    query rows as one run (`_run`: five dots a band, one mask).  The steps
+    pass from the LAST k tile to the first (`_kv_rows`), because a causal
+    slice's last k tile has one q tile
     to visit and its first all of them, and the next slice's q, do and
     statistics (14 MB at S = 8,192, 192 / 128 wide) are fetched during a
     slice's last step: the longest step hides the fetch, not the shortest.
@@ -574,7 +664,9 @@ def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     by the q tile."""
     k_rows_held = k_ref.shape[0]
     steps, tiles = seq_len // k_rows_held, k_rows_held // block_k
-    step, over = (0, _span) if steps == 1 else (pl.program_id(1), _tile_loop)
+    band = _band(causal, seq_len, block_k, steps == 1, bool(selection))
+    step, over = (0, _span) if steps == 1 else (
+        pl.program_id(1), _run if band else _tile_loop)
     # sm_scale * log2(e) folded into the q rows: s is in base-2 units, q
     # also serves the dk dot (rescaled by ln2 at the end), and ds's
     # trailing *sm_scale is hoisted onto dq.
@@ -630,7 +722,7 @@ def _bwd_fused_core(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                jnp.zeros((block_k, v.shape[-1]), jnp.float32))
         # the runs of q tiles the rule has visit this k tile, the crossed
         # ones masked
-        at, spans = _q_spans(rule, kj, block_q, block_k, seq_len)
+        at, spans = _q_spans(rule, kj, block_q, block_k, seq_len, band)
         for first, last, how, noised in spans:
             acc = over(first, last, block_q, functools.partial(
                 q_span, how=how, noised=noised), acc)
@@ -668,12 +760,18 @@ def _kernel_call(*form):
 # bhsd layout: arrays viewed (B*H, S, D), one head per grid step
 # ---------------------------------------------------------------------------
 
-def _fwd_rows(whole, seq_len, block_q):
+def _fwd_rows(whole, seq_len, block_q, band=None, held=None):
     """[(q tile index, its rows in the q block, how it walks its k blocks)]
     of one grid step, the two forms of the forward.  ``whole``: the step
     holds the whole sequence and the kernel walks its q tiles, every extent
     static (`_span`).  Else the grid has a step per q tile, which loops
-    over its k blocks (`_tile_loop`)."""
+    over its k blocks (`_tile_loop`).  Under a window's ``band`` a step
+    holds ``held`` rows (`_band_step`), whose q tiles each take their keys
+    as one run (`_run`)."""
+    if band:
+        tiles = held // block_q
+        return [(pl.program_id(1) * tiles + i, pl.ds(i * block_q, block_q),
+                 _run) for i in range(tiles)]
     if not whole:
         return [(pl.program_id(1), slice(None), _tile_loop)]
     return [(i, pl.ds(i * block_q, block_q), _span)
@@ -696,14 +794,16 @@ def _fwd_select(selection, whole, rows, block_k):
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal, block_q,
                 block_k, seq_len, whole):
     *selection, o_ref, lse_ref = rest
-    for qi, rows, over in _fwd_rows(whole, seq_len, block_q):
+    band = _band(causal, seq_len, block_q, whole, bool(selection))
+    for qi, rows, over in _fwd_rows(whole, seq_len, block_q, band,
+                                    q_ref.shape[0]):
         q = q_ref[rows, :] * jnp.asarray(sm_scale * _LOG2E, q_ref.dtype)
         acc, m, l = _fwd_core(
             q, lambda start, n: k_ref[pl.ds(start, n), :],
             lambda start, n: v_ref[pl.ds(start, n), :], qi, over,
             causal=causal, block_q=block_q, block_k=block_k, seq_len=seq_len,
             v_dim=v_ref.shape[-1],
-            select=_fwd_select(selection, whole, rows, block_k))
+            select=_fwd_select(selection, whole, rows, block_k), band=band)
         o_ref[rows, :], lse_ref[rows, :] = _finish_fwd(acc, m, l, o_ref.dtype)
 
 
@@ -786,7 +886,8 @@ def _pallas_forward(q, k, v, sm_scale, causal, block_q, block_k, whole,
     qf = q.reshape(B * H, S, D)
     kf = k.reshape(B * Hkv, S, D)
     vf = v.reshape(B * Hkv, S, Dv)
-    rows = S if whole else block_q
+    rows = S if whole else _band_step(
+        S, block_q, _band(causal, S, block_q, whole, mask is not None))
     grid = (B * H, S // rows)
     masks, mask_specs = _mask_specs(mask, H, whole, block_q, block_k)
     kernel = functools.partial(
@@ -1173,12 +1274,29 @@ def _auto_tiles(S: int, causal):
     not pay their fixed cost: ms a layer at 128 / 256 / 512, the forward
     17.22 / 9.25 / 7.06, forward + backward 39.37 / 23.18 / 19.42; a q tile
     beside another k tile is no better (256 on 512: 7.10 and 13.37 against
-    7.06 and 12.36 square; 512 on 256: 9.59 and 12.66).  So the window's
-    width is read and changes nothing yet: 512 at every width swept."""
+    7.06 and 12.36 square; 512 on 256: 9.59 and 12.66).  Those were WALKS of
+    tiles, two or three visits a row of tiles, each with its mask and its
+    turn of the softmax.  Taken as ONE band (`_band`; PERF.md §6, PR 64;
+    `tools/chip_kernels.py --sweep laguna-16k mellum-16k phi4-16k`) a tile
+    is another trade: one visit whatever its size, W' + tile keys for W
+    attended (at W = 512: 0.80 of the visited pairs at 128, 0.667 at 256,
+    0.50 at 512).  ms a layer at S = 16,384, forward / backward, a tile a
+    grid step at 128 / 256 / 512 beside the walk at 512: W = 512 (64 on 8
+    of 128) 5.06 / 13.37, 4.02 / 9.06, 4.23 / 10.27 beside 7.06 / 12.36;
+    W = 1,024 (32 on 4) 3.27 / 9.72, 3.11 / 6.21, 3.24 / 6.87 beside 4.68 /
+    8.02; W = 512 with q, k 64 and v 128 wide (20 on 10) 1.71 / 4.18, 1.38 /
+    2.83, 1.43 / 3.22 beside 2.32 / 3.86.  With `_BAND_STEP` = 1,024 rows a
+    grid step, at 128 / 256: 3.65 / 8.55 and 3.16 / 8.24; 2.58 / 6.44 and
+    2.49 / 5.78; 1.24 / 2.67 and 1.10 / 2.58.  So 256 in both passes
+    wherever a band of 256-tiles is taken (W up to 3,072; swept at 512 and
+    1,024, wider ones take it unmeasured), and a window too wide for a band
+    keeps the walk at 512."""
     rule = _rule(causal)
     L = S // rule.kinds if rule else S      # tiles divide a kind's rows
     whole = _auto_block(L, 1024)
     if S > _WHOLE_SEQ_MAX:
+        if S % 256 == 0 and _band(causal, S, 256, False):
+            return (256, 256), (256, 256)
         bwd = _auto_block(L, 512)
         # two kinds of row leave a quarter of the square and a window a
         # band of it: there the forward's smaller tile pays
@@ -1215,7 +1333,7 @@ def _tiles_visited(rule, S, block_q, block_k):
                for first, last, _ in _k_spans(rule, i, block_q, block_k, S)[2])
 
 
-def _count_tiles(S, block_q, block_k, causal, heads):
+def _count_tiles(S, block_q, block_k, causal, heads, band=None):
     """Add one kernel's tiles to the job timeline, as the step is traced:
     `attention.tiles` the (block_q x block_k) tiles of the S x S score
     square, `attention.tiles_skipped` those of them the rule empties (under
@@ -1226,19 +1344,30 @@ def _count_tiles(S, block_q, block_k, causal, heads):
     head slice or grid step).  Beside them ``heads``: those of the q the
     kernel is given and of the k it reads from HBM (`attention.q_heads`,
     `attention.kv_heads`): a quarter where four query heads share a
-    key/value head, equal where a caller repeated k and v first."""
+    key/value head, equal where a caller repeated k and v first.  Under
+    a window's ``band`` (`_band`: its rows, of the pass's own tile) the
+    kernel multiplies every row of the sequence by a band, the clamped
+    ends as they are: S x band pairs, in whole tiles rounded up, and
+    `attention.window_band_kernels` counts the kernel beside
+    `attention.window_kernels`."""
     tracing.count("attention.q_heads", heads[0])
     tracing.count("attention.kv_heads", heads[1])
     rule, tiles = _rule(causal), (S // block_q) * (S // block_k)
-    visited = _tiles_visited(rule, S, block_q, block_k)
+    if band:
+        pairs = S * band
+        visited = pl.cdiv(pairs, block_q * block_k)
+    else:
+        visited = _tiles_visited(rule, S, block_q, block_k)
+        pairs = visited * block_q * block_k
     tracing.count("attention.tiles", tiles)
     tracing.count("attention.tiles_skipped", tiles - visited)
-    tracing.count("attention.pairs_visited", visited * block_q * block_k)
+    tracing.count("attention.pairs_visited", pairs)
     if rule and rule.window is not None:
         tracing.count("attention.window_kernels")
         tracing.count("attention.window", rule.window)
-        tracing.count("attention.window_pairs_visited",
-                      visited * block_q * block_k)
+        tracing.count("attention.window_pairs_visited", pairs)
+        if band:
+            tracing.count("attention.window_band_kernels")
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -1317,7 +1446,8 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, mask=None):
         _warn_reference(q.shape, bq, bk, problem)
         o, lse = reference(q, k, v, mask=mask)
     else:
-        _count_tiles(S, bq, bk, causal, (q.shape[1], k.shape[1]))
+        _count_tiles(S, bq, bk, causal, (q.shape[1], k.shape[1]),
+                     _band(causal, S, bq, whole, mask is not None))
         kernel = functools.partial(_pallas_forward, sm_scale=scale,
                                    causal=causal, block_q=bq, block_k=bk,
                                    whole=whole)
@@ -1354,12 +1484,13 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, res, do, delta=None):
         _warn_reference(q.shape, bq, bk, problem)
         return _reference_backward(q, k, v, lse, do, delta, scale, causal,
                                    mask)
-    _count_tiles(S, bq, bk, causal, (q.shape[1], k.shape[1]))
+    band = _band(causal, S, bk, whole, mask is not None)
+    _count_tiles(S, bq, bk, causal, (q.shape[1], k.shape[1]), band)
 
     def kernel(q, k, v, o, lse, do, delta, mask=None, *, interpret):
         return _pallas_backward(q, k, v, o, lse, do, scale, causal, bq, bk,
-                                S if whole else bk, interpret, delta=delta,
-                                mask=mask)
+                                S if whole else _band_step(S, bk, band),
+                                interpret, delta=delta, mask=mask)
 
     def reference(q, k, v, o, lse, do, delta, mask=None):
         return _reference_backward(q, k, v, lse, do, delta, scale, causal,

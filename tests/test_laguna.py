@@ -683,28 +683,30 @@ def test_groups_of_three_and_four_heads_match_a_dense_mask(
 # -- the tiles a call takes by itself -----------------------------------------
 
 def test_auto_tiles_reads_the_windows_width():
-    """A call at W = 1,024 and a call with no window take the tiles they
-    took; a window narrower than a pair of 512-tiles takes what the sweep
-    on the chip found fastest (PERF.md §6, PR 60), which is 512 again."""
+    """A call with no window takes the tiles it took; a window a band of
+    256-tiles holds (`_band`) takes what the band's sweep on the chip found
+    fastest (PERF.md §6, PR 64: 256 in both passes at W = 512 and at
+    1,024, where the walk of PR 60 took 512), a wider one the walk's 512."""
     for S in (8192, 16384):
         assert fa._auto_tiles(S, BlockRule(window=1024)) \
-            == ((512, 512), (512, 512))
+            == ((256, 256), (256, 256))
         assert fa._auto_tiles(S, BlockRule(window=4096)) \
             == ((512, 512), (512, 512))
         assert fa._auto_tiles(S, True) == fa._auto_tiles(S, BlockRule()) \
             == ((1024, 1024), (512, 512))
         assert fa._auto_tiles(S, BlockRule(4, 2)) \
             == ((512, 512), (512, 512))
-        # 512 won at W = 512 too: forward + backward 19.42 ms a layer
-        # against 23.18 at 256 and 39.37 at 128
+        # at W = 512 the walk read 19.42 ms a layer at 512 (23.18 at 256,
+        # 39.37 at 128); the band 11.40 at 256, 12.20 at 128
         assert fa._auto_tiles(S, BlockRule(window=512)) \
             == fa._auto_tiles(S, BlockRule(window=300)) \
-            == ((512, 512), (512, 512))
+            == ((256, 256), (256, 256))
     assert fa._auto_tiles(1024, BlockRule(window=512)) \
         == fa._auto_tiles(1024, True)
     # the counters mean under W = 512 what they mean under W = 1,024: at the
-    # cell's sizes a windowed kernel's 512-tiles visit 63 of the square's
-    # 1,024 (two a q tile, one for the first), 256-tiles 189, 128-tiles 630
+    # cell's sizes a windowed kernel's walk of 512-tiles visited 63 of the
+    # square's 1,024 (two a q tile, one for the first), of 256-tiles 189, of
+    # 128-tiles 630; its band multiplies 512 + a tile's keys a row
     names = ("attention.window", "attention.window_pairs_visited")
 
     def traced(block):
@@ -716,11 +718,15 @@ def test_auto_tiles_reads_the_windows_width():
         return [tracing.counter(name) - b for name, b in zip(names, before)]
 
     with tracing.timeline_span("train.fit", root=True) as job:
-        assert traced(512) == [512, 63 * 512 * 512]
-        assert traced(256) == [512, 189 * 256 * 256]
-        assert traced(128) == [512, 630 * 128 * 128]
+        assert traced(512) == [512, 16384 * 1024]
+        assert traced(256) == [512, 16384 * 768]
+        assert traced(128) == [512, 16384 * 640]
     tracing.timeline_take(job.trace_id)
+    rule = BlockRule(window=512)
+    assert [fa._tiles_visited(rule, 16384, b, b) for b in (512, 256, 128)] \
+        == [63, 189, 630]
     attended = model.attended_pairs(16384, 512)
     assert attended / (63 * 512 * 512) == pytest.approx(0.500, abs=5e-3)
-    assert attended / (189 * 256 * 256) == pytest.approx(0.667, abs=5e-3)
-    assert attended / (630 * 128 * 128) == pytest.approx(0.800, abs=5e-3)
+    assert attended / (16384 * 1024) == pytest.approx(0.492, abs=5e-3)
+    assert attended / (16384 * 768) == pytest.approx(0.656, abs=5e-3)
+    assert attended / (16384 * 640) == pytest.approx(0.788, abs=5e-3)

@@ -526,8 +526,10 @@ def test_the_counters_equal_their_formulas_under_a_window():
     diagonal; `attention.window_kernels` counts the kernels traced under
     one, `attention.window` sums their widths and
     `attention.window_pairs_visited` their part of the pairs.  At the cell's sizes a
-    head's windowed kernel visits 93 of the square's 1,024 512-tiles and
-    the attended pairs are 0.667 of the visited, 0.80 with 256-tiles; a
+    head's windowed kernel WALKED 93 of the square's 1,024 512-tiles and
+    the attended pairs were 0.667 of the visited, 0.80 with 256-tiles; since
+    PR 64 it takes a band of 1,024 + a tile's keys a row (`_band`: 96 tiles'
+    worth at 512-tiles, 320 at 256; `tests/test_flash_window_band.py`); a
     full layer's 528 and 0.970."""
     names = ("attention.tiles", "attention.tiles_skipped",
              "attention.pairs_visited", "attention.window_kernels",
@@ -550,9 +552,13 @@ def test_the_counters_equal_their_formulas_under_a_window():
                 visited * block * block]
         assert traced(1024, True, 256)[3:] == [0, 0, 0]
         assert traced(16384, BlockRule(window=1024), 512) == [
-            1024, 1024 - 93, 93 * 512 * 512, 1, 1024, 93 * 512 * 512]
+            1024, 1024 - 96, 16384 * 1536, 1, 1024, 16384 * 1536]
         assert traced(16384, BlockRule(window=1024), 256)[2] \
-            == 310 * 256 * 256
+            == 16384 * 1280 == 320 * 256 * 256
+        # the walk's 93 and 310 are what `_tiles_visited` still says
+        rule = BlockRule(window=1024)
+        assert fa._tiles_visited(rule, 16384, 512, 512) == 93
+        assert fa._tiles_visited(rule, 16384, 256, 256) == 310
         assert traced(16384, True, 512)[:3] == [
             1024, 1024 - 528, 528 * 512 * 512]
     tracing.timeline_take(job.trace_id)

@@ -33,13 +33,20 @@ at 256 / 512 / 1,024: OLMoE's shape 7.10 / 4.83 / 5.07, ``mla-8k`` 34.53 /
 sequence of 16,384 and of 8,192 rows, 32 query heads on 4 key/value heads
 of 128, then the same call with no window; a line a rule and a tile
 (`_auto_tiles`' own, then 256, 512 and 1,024) with the forward's and
-forward + backward's device ms, the share of the visited pairs the rule
-attends, and the largest error of o, dq, dk and dv relative to a float32
-masked softmax taken 1,024 query rows at a time.  ``laguna_16k`` is
-Laguna-XS.2's pair: 64 query heads on 8 under a window of 512 (tiles of
-128, 256 and 512: the window is narrower than a pair of 512-tiles), then
-its full layers' 48 on 8 with no window; ``--sweep laguna-16k`` times the
-windowed call at those tiles and at a q tile beside another k tile.
+forward + backward's device ms, the rows of the band each pass takes
+(`ops/flash_attention.py:_band`; null where it walks its tiles: at 1,024
+here), the share of the visited pairs the rule attends, and the largest
+error of o, dq, dk and dv relative to a float32 masked softmax taken 1,024
+query rows at a time; under the window a last line is the WALK of
+512-tiles (`walking`: the module's `_band` saying no), what the band
+replaced.  ``laguna_16k`` is Laguna-XS.2's pair: 64 query heads on 8 under
+a window of 512 (tiles of 128, 256 and 512), then its full layers' 48 on 8
+with no window; ``phi4_16k`` one call of Phi-4-mini-flash's differential
+attention (20 on 10, q and k 64 wide on v 128, W = 512).  ``--sweep
+laguna-16k``, ``mellum-16k`` and ``phi4-16k`` time the windowed call alone,
+forward and backward apart: `_auto_tiles`' own, the band at tiles of 128,
+256 and 512 (``BAND_TILES``) and the walk at 512 (``WALK_TILE``), which is
+what `_auto_tiles` takes under a window is set from (a minute a shape).
 
 ``moe_held_8k`` is no attention case: one routed layer of kanana's share
 (`ops/moe.py`: dispatch, the held experts, combine) over all the routed
@@ -153,6 +160,7 @@ plain form on float32 operands.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import functools
 import glob
 import importlib
@@ -175,12 +183,18 @@ CASES = {
     "gqa16_8k": ((2, 8192, 32, 128), 2),
 }
 # key/value heads of the cases and sweeps whose k and v have fewer than q
-KV_HEADS = {"laguna-16k": 8,
+KV_HEADS = {"laguna-16k": 8, "mellum-16k": 4, "phi4-16k": 10,
             "gqa_8k": 8, "gqa-8k": 8, "gqa16_8k": 2, "gqa16-8k": 2,
             "blocks-16k": 4}
 # (block, kinds) of the sweeps under a rule that is not the diagonal
 # (`ops/flash_attention.py:BlockRule`)
-RULES = {"blocks-16k": (4, 2), "laguna-16k": (1, 1, 512)}
+RULES = {"blocks-16k": (4, 2), "laguna-16k": (1, 1, 512),
+         "mellum-16k": (1, 1, 1024), "phi4-16k": (1, 1, 512)}
+# the tiles a window's band is swept at (`ops/flash_attention.py:_band`: the
+# forward reads its q tile alone and the backward its k tile alone, so
+# square ones say everything), then the walk of tiles at `WALK_TILE`
+BAND_TILES = ((128, 128), (256, 256), (512, 512))
+WALK_TILE = (512, 512)
 # (shape, key/value heads, window[, the query heads of the call with no
 # window]) of the calls under a window (`BlockRule(window=W)`): Mellum 2's
 # sliding layers at the cell's length and at half of it, each beside the
@@ -308,10 +322,11 @@ SWEEP = {
     "blocks-16k": ((2, 16384, 32, 128), ((256, 256), (512, 512),
                                          (1024, 1024), (1024, 512))),
     # Laguna-XS.2's sliding layers: a window of 512, 64 query heads on 8 of
-    # 128, one sequence (a q tile beside another k tile too)
-    "laguna-16k": ((1, 16384, 64, 128), ((128, 128), (256, 256), (512, 512),
-                                         (128, 256), (256, 128), (256, 512),
-                                         (512, 256))),
+    # 128, one sequence; Mellum 2's: 1,024, 32 on 4; one call of
+    # Phi-4-mini-flash's: 512, 20 on 10, q and k 64 wide on v 128
+    "laguna-16k": ((1, 16384, 64, 128), BAND_TILES),
+    "mellum-16k": ((1, 16384, 32, 128), BAND_TILES),
+    "phi4-16k": ((1, 16384, 20, 64, 128), BAND_TILES),
 }
 # (rows, E, vocabularies, rows a chunk) of one head and its loss
 HEAD_CASES = {
@@ -707,6 +722,32 @@ def _buffer_of(experts, weights, held, n_experts, C):
     mine = jnp.sum(where[1] < C, axis=1, dtype=jnp.int32)
     return (where[0], weights.reshape(T * k)[first], where, by_token, same,
             jnp.cumsum(mine) - mine, mine > 0)
+
+
+@contextlib.contextmanager
+def walking():
+    """The windowed calls made inside keep the walk of tiles: no call takes
+    a band (`ops/flash_attention.py:_band` says None; the kernels are jitted
+    on their static arguments, so the caches go before and after)."""
+    import jax
+
+    from ray_tpu.ops import flash_attention as fa
+
+    band, fa._band = fa._band, lambda *args, **kwargs: None
+    jax.clear_caches()
+    try:
+        yield
+    finally:
+        fa._band = band
+        jax.clear_caches()
+
+
+def then_the_walk(blocks, windowed):
+    """[(tile, the context to run it in)] of a windowed call's lines: each
+    of ``blocks`` as the module takes it and then, under a window,
+    `WALK_TILE` with the band taken out (`walking`)."""
+    return [(block, contextlib.nullcontext) for block in blocks] \
+        + [(WALK_TILE, walking)] * bool(windowed)
 
 
 def time_passes(shape, dtype, block_q=None, block_k=None, kv_heads=None,
@@ -1386,28 +1427,34 @@ def window_case(name, dtype):
                 q, k, v)
         want = [np.asarray(t, np.float32) for t in (o_r, *g_r)]
         del o_r, g_r
-        for block in tiles if width else WINDOW_TILES:
-            (_, o_k), g_k = grad(lambda q, k, v: fa.flash_attention_bshd(
-                q, k, v, rule, None, *block))(q, k, v)
+        for block, how in then_the_walk(
+                tiles if width else WINDOW_TILES, width):
+            with how():
+                (_, o_k), g_k = grad(lambda q, k, v: fa.flash_attention_bshd(
+                    q, k, v, rule, None, *block))(q, k, v)
+                fwd_ms, bwd_ms = time_passes(shape, dtype, *block,
+                                             kv_heads=kv_heads, causal=rule)
+                (bq, bk), (cq, ck) = [
+                    block if block[0] else t for t in fa._auto_tiles(S, rule)]
+                bands = [fa._band(rule, S, bq, False),
+                         fa._band(rule, S, ck, False)]
             errs = {what: round(float(
                 np.max(np.abs(np.asarray(a, np.float32) - b))
                 / np.max(np.abs(b))), 5)
                 for what, a, b in zip(("o", "dq", "dk", "dv"),
                                       (o_k, *g_k), want)}
-            fwd_ms, bwd_ms = time_passes(shape, dtype, *block,
-                                         kv_heads=kv_heads, causal=rule)
-            (bq, bk), (cq, ck) = [
-                block if block[0] else t for t in fa._auto_tiles(S, rule)]
-            visited = lambda bq, bk: fa._tiles_visited(rule, S, bq, bk) \
-                * bq * bk
+            # what a pass multiplies: every row by its band, or the tiles
+            # its walk visits
+            visited = [S * band if band else fa._tiles_visited(
+                rule, S, *tile) * tile[0] * tile[1]
+                for band, tile in zip(bands, ((bq, bk), (cq, ck)))]
             attended = attended_pairs(S, width)
             yield {"case": name, "shape": shape, "kv_heads": kv_heads,
                    "window": width, "tile": block if block[0] else "auto",
-                   "tiles": [[bq, bk], [cq, ck]],
+                   "tiles": [[bq, bk], [cq, ck]], "band": bands,
                    "fwd_ms": fwd_ms, "fwd_bwd_ms": round(fwd_ms + bwd_ms, 4),
                    "attended_over_visited": [
-                       round(attended / visited(bq, bk), 3),
-                       round(attended / visited(cq, ck), 3)],
+                       round(attended / n, 3) for n in visited],
                    "rel_err": errs}
 
 
@@ -1509,6 +1556,7 @@ def main():
         BlockRule,
         _auto_tiles,
     )
+    from ray_tpu.ops import flash_attention as fa
     from ray_tpu.ops.ssd import SsdFallbackWarning
     from ray_tpu.util.compile_cache import ensure_compile_cache
 
@@ -1542,16 +1590,27 @@ def main():
                 continue
             shape, blocks = SWEEP[name]
             causal = BlockRule(*RULES[name]) if name in RULES else True
-            for block in ((None, None),) + blocks:
-                fwd_ms, bwd_ms = time_passes(shape, jnp.bfloat16, *block,
-                                             kv_heads=KV_HEADS.get(name),
-                                             causal=causal)
-                print(json.dumps({
-                    "sweep": name, "shape": shape,
-                    "tile": block if block[0] else _auto_tiles(
-                        shape[1], causal),
-                    "fwd_ms": fwd_ms, "bwd_ms": bwd_ms,
-                    "device_kind": dev.device_kind}), flush=True)
+            # under a window: each tile's band, then the walk at one tile
+            windowed = getattr(causal, "window", None) is not None
+            for block, how in then_the_walk(((None, None),) + blocks,
+                                            windowed):
+                with how():
+                    fwd_ms, bwd_ms = time_passes(
+                        shape, jnp.bfloat16, *block,
+                        kv_heads=KV_HEADS.get(name), causal=causal)
+                    tiles = [block if block[0] else t
+                             for t in _auto_tiles(shape[1], causal)]
+                    line = {"sweep": name, "shape": shape,
+                            "tile": block if block[0] else tiles,
+                            "fwd_ms": fwd_ms, "bwd_ms": bwd_ms}
+                    if windowed:
+                        # the rows of the forward's band of keys and of the
+                        # backward's of query rows, None on the walk
+                        line["band"] = [
+                            fa._band(causal, shape[1], tiles[0][0], False),
+                            fa._band(causal, shape[1], tiles[1][1], False)]
+                print(json.dumps({**line, "device_kind": dev.device_kind}),
+                      flush=True)
         return
 
     failed = []
