@@ -63,11 +63,10 @@ import jax.numpy as jnp
 from ray_tpu.models.layers import (
     dense_ffn,
     head_and_loss,
-    named,
+    latent_attention,
     normal_kernel,
     num_params,  # noqa: F401  (`deepseek_v3.num_params` is public)
     rms_norm,
-    rope,
     routed_layer,
     swiglu,
     train_step,
@@ -81,7 +80,6 @@ from ray_tpu.ops.moe import (
     trained_by,  # noqa: F401  (`deepseek_v3.trained_by` is public)
 )
 from ray_tpu.ops.moe import routing_bias_rule as _bias_rule_over
-from ray_tpu.parallel.attention import attention
 
 
 @dataclass(frozen=True)
@@ -184,34 +182,6 @@ def init_params(rng, cfg: DeepseekV3Config) -> Dict[str, Any]:
     return params
 
 
-def _attention(x, p, cfg: DeepseekV3Config):
-    B, S, _ = x.shape
-    H, R, nope = cfg.n_head, cfg.kv_lora_rank, cfg.qk_nope_dim
-    kernel = lambda name: p[name]["kernel"].astype(x.dtype)
-    positions = jnp.arange(S)
-    turn = functools.partial(rope, positions=positions,
-                             theta=cfg.rope_theta, interleaved=True)
-    with jax.named_scope("latent_down"):
-        q = (x @ kernel("q_proj")).reshape(B, S, H, cfg.qk_head_dim)
-        latent = named(x @ kernel("kv_a_proj"), "attention/latent_down")
-        c = rms_norm(latent[..., :R], p["kv_a_norm"], cfg.rms_eps)
-        k_rope = turn(latent[..., None, R:])            # (B, S, 1, rope)
-    with jax.named_scope("latent_up"):
-        kv = (c @ kernel("kv_b_proj")).reshape(
-            B, S, H, nope + cfg.v_head_dim)
-        q = named(jnp.concatenate(
-            [q[..., :nope], turn(q[..., nope:])], axis=-1), "attention/qkv")
-        k, v = named((jnp.concatenate([
-            kv[..., :nope],
-            jnp.broadcast_to(k_rope, (B, S, H, cfg.qk_rope_dim))], axis=-1),
-            kv[..., nope:]), "attention/latent_up")
-    with jax.named_scope("kernel"):
-        o = attention(q, k, v)                          # (B, S, H, v_head_dim)
-    with jax.named_scope("out"):
-        return named(o.reshape(B, S, H * cfg.v_head_dim) @ kernel("o_proj"),
-                     "attention/out")
-
-
 def _route(cfg: DeepseekV3Config):
     """-> route(xt, router) -> (weights (T, k) f32, experts (T, k) int32)
     over all experts."""
@@ -223,7 +193,7 @@ def _layer(x, p, cfg: DeepseekV3Config):
     """-> (x, the rows sent to each expert; None from a dense layer)."""
     u = rms_norm(x, p["input_norm"], cfg.rms_eps)
     with jax.named_scope("attention"):
-        x = x + _attention(u, p["attn"], cfg)
+        x = x + latent_attention(u, p["attn"], cfg)
     u = rms_norm(x, p["post_norm"], cfg.rms_eps)
     with jax.named_scope("ffn"):
         if "mlp" in p:

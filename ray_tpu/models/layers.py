@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import math
 import threading
 
@@ -66,6 +67,7 @@ from ray_tpu.ops import causal_conv as conv_kernels
 from ray_tpu.ops import grouped_matmul
 from ray_tpu.ops.flash_attention import KEPT_RESIDUALS, KERNEL_FORMS
 from ray_tpu.ops.moe import ROUTE_NAME, moe_dispatch
+from ray_tpu.parallel.attention import attention
 from ray_tpu.parallel.context import get_mesh
 from ray_tpu.parallel.sharding import chip_bytes, param_logical_dims
 from ray_tpu.util import tracing
@@ -105,6 +107,13 @@ SCOPES = (
     "mamba/scan",
     "mamba/out_proj",
     "gmu",
+    "kda",
+    "kda/proj",
+    "kda/conv",
+    "kda/gate",
+    "kda/rule",
+    "kda/gate_norm",
+    "kda/out_proj",
     "ffn",
     "ffn/dense",
     "ffn/moe",
@@ -283,6 +292,44 @@ def attention_out(o, p, gate_input=None):
     with jax.named_scope("out"):
         return named(o.reshape(B, S, -1)
                      @ p["o_proj"]["kernel"].astype(o.dtype), "attention/out")
+
+
+def latent_attention(x, p, cfg, gated=False):
+    """DeepSeek-V3's latent attention (MLA) as training multiplies it out,
+    x (B, S, E) the normed stream -> (B, S, E); the caller stands in
+    `attention`.  q = x W_q, a head's [q_nope | q_rope];  [c | k_r] =
+    x W_kv_a;  c = RMSNorm(c);  a head's [k_nope | v] = c W_kv_b;  RoPE
+    (interleaved pairs, `rope`) on q_rope of every head and on the ONE k_r
+    all heads share;  k = [k_nope | k_r];  the flash kernels' causal softmax
+    of q k' at (nope + rope)^-1/2 with v of another width;  W_o
+    (`attention_out`; ``gated``: behind a gate a head from x, ``p``'s
+    "g_proj").  ``p``: "q_proj", "kv_a_proj", "kv_a_norm", "kv_b_proj",
+    "o_proj"; ``cfg``: `n_head` (the heads HELD here, which the matrices'
+    widths agree with), `kv_lora_rank`, `qk_nope_dim`, `qk_rope_dim`,
+    `v_head_dim`, `rope_theta`, `rms_eps`."""
+    B, S, _ = x.shape
+    H, R, nope = cfg.n_head, cfg.kv_lora_rank, cfg.qk_nope_dim
+    kernel = lambda name: p[name]["kernel"].astype(x.dtype)
+    positions = jnp.arange(S)
+    turn = functools.partial(rope, positions=positions,
+                             theta=cfg.rope_theta, interleaved=True)
+    with jax.named_scope("latent_down"):
+        q = (x @ kernel("q_proj")).reshape(B, S, H, nope + cfg.qk_rope_dim)
+        latent = named(x @ kernel("kv_a_proj"), "attention/latent_down")
+        c = rms_norm(latent[..., :R], p["kv_a_norm"], cfg.rms_eps)
+        k_rope = turn(latent[..., None, R:])            # (B, S, 1, rope)
+    with jax.named_scope("latent_up"):
+        kv = (c @ kernel("kv_b_proj")).reshape(
+            B, S, H, nope + cfg.v_head_dim)
+        q = named(jnp.concatenate(
+            [q[..., :nope], turn(q[..., nope:])], axis=-1), "attention/qkv")
+        k, v = named((jnp.concatenate([
+            kv[..., :nope],
+            jnp.broadcast_to(k_rope, (B, S, H, cfg.qk_rope_dim))], axis=-1),
+            kv[..., nope:]), "attention/latent_up")
+    with jax.named_scope("kernel"):
+        o = attention(q, k, v)                          # (B, S, H, v_head_dim)
+    return attention_out(o, p, gate_input=x if gated else None)
 
 
 def swiglu(x, gate, up, down, matmul=jnp.matmul):
@@ -502,17 +549,28 @@ KEPT_NAMES = (
     "attention/indexer/select", # the mask of the keys each query attends,
                                 # (B, S, S) int8: kept, a replay searches
                                 # no threshold (16 passes over the scores)
+    "kda/rule",                 # the delta rule's o, (B, S, H V): kept, a
+                                # replay drops the rule's forward kernel
+                                # (its backward reads the rule's inputs
+                                # alone, `ops/kda.py`): 56 ms a step for
+                                # 0.375 GiB on ling, 150 ms a GiB (PERF.md
+                                # §6, PR 65)
     "attention/latent_down",    # DeepSeek-V3's [c | k_r], W_kv_a's result
     "attention/gate",           # a gated attention's u W_g, (B, S, H) float32:
                                 # a product that reads the whole stream for
                                 # a result a head wide, as a router's
     "attention/out",            # W_o's result, as wide as the stream
     "short_conv/out_proj",      # W_out's result, the same
+    "kda/out_proj",             # a delta-rule mixer's W_o's result, the same
+                                # (13 ms a GiB on ling)
     "short_conv/gate_taps",     # c * conv(b * z), the same
     "attention/qkv",            # GPT-2's fused qkv; W_q's, W_k's and W_v's
                                 # results; DeepSeek-V3's q with its RoPE part
     "short_conv/in_proj",       # [b c z], W_in's result, 3E wide
     "ssm/in_proj",              # [z | xBC | dt], W_in's result, 3.8E wide
+    "kda/proj",                 # a delta-rule mixer's [q | k | v], u W_f,
+                                # u W_g and u W_b: 5 H K + H wide (21 ms a
+                                # GiB on ling, a feed-forward's hidden 17)
     "ffn/hidden",               # c_fc's result (4E); a SwiGLU's gate and up;
                                 # an ungated expert's up
     "ssm/scan",                 # the scan's y: kept, a replay drops the scan
@@ -522,6 +580,9 @@ KEPT_NAMES = (
                                 # GiB (PERF.md §6, PR 39)
     "attention/latent_up",      # k and v multiplied out of the latent: the
                                 # widest and the cheapest to remake
+    "kda/conv",                 # q, k and v behind their taps and SiLU: a
+                                # pass over its bytes remakes them (4 ms a
+                                # GiB on ling)
     "attention/indexer/scores", # an indexer's I, (B, S, S) float32: four
                                 # times the mask; kept, it saves a replay
                                 # its forward kernel, never the backward's

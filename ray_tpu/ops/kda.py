@@ -1,0 +1,521 @@
+"""Kimi Delta Attention's rule (Kimi Linear, arXiv:2510.26692): a gated
+delta rule whose decay is a vector a head, one factor a KEY CHANNEL, chunked,
+as plain `jax.numpy` and as a Pallas (Mosaic) kernel a pass, forward and
+backward, under one `jax.custom_vjp`.
+
+For one head, q_t and k_t of K channels, v_t of V, g_t of K (the log of the
+decay, negative: alpha_t = exp(g_t)), beta_t a scalar, the state S (K, V)
+float32, zero where the sequence starts:
+
+    S_t = (I - beta_t k_t k_t') Diag(alpha_t) S_{t-1} + beta_t k_t v_t'
+    o_t = S_t' q_t
+
+q, k (B, S, H, K); v (B, S, H, V); g (B, S, H, K) float32; beta (B, S, H).
+What a mixer does before (the projections, the convolution, the L2 norms,
+the gate's map) and behind (the head's norm, the output gate) is no part of
+this file.
+
+**The chunked form.**  With u_t = beta_t (v_t - S_{t-1}' (alpha_t * k_t)) the
+rule is S_t = Diag(alpha_t) S_{t-1} + k_t u_t'.  Over a chunk of C positions,
+G_t the sum of g over the chunk's positions up to t, S_0 the state that
+enters it and D(t, i) = exp(G_t - G_i) a vector of K:
+
+    A_ti = beta_t sum(k_t * k_i * D(t, i)), i < t   (C, C), strictly lower
+    P_ti = sum(q_t * k_i * D(t, i)), i <= t
+    (I + A) U = beta * V - (beta * K * exp(G)) S_0
+        so U = T (beta * V) - T (beta * K * exp(G)) S_0,  T = (I + A)^-1
+    O = (Q * exp(G)) S_0 + P U
+    S_C = Diag(exp(G_C)) S_0 + (K * exp(G_C - G))' U
+
+Never is exp(-G_i) formed over the chunk: at the gate's bound g = -5 a
+position it passes float32 after 18 positions.  The pair decays are taken
+against an origin inside the row's own sub-block of `_SUB` = 16 positions:
+with R_a the sum of g up to the EIGHTH position of sub-block a, the row side
+is exp(G_t - R_a) and the column side exp(R_a - G_i), both within exp(+-40)
+for the pairs inside the sub-block and the column side at most 1 for the
+columns of earlier ones (where it underflows the pair's decay is nothing in
+float32 either).  An origin at the sub-block's start would do by the
+exponents alone, exp(80) being float32's, but exp(-80) times a key's entry
+of 1e-3 is no normal number and the chip flushes it: at the bound for a
+whole chunk that cost o a part in a hundred (`tests/test_kda.py`).  The
+cumulative sums are float32 products with triangles of ones, inside a
+sub-block and up to it apart, so that neither rounds the other.
+
+T: the triangle is inverted without a loop over its rows, by products alone
+(the MXU is all but idle here).  Its 16-wide diagonal blocks A_d are
+nilpotent, so (I + A_d)^-1 = (I - A_d)(I + A_d^2)(I + A_d^4)(I + A_d^8); with
+that D and N = D (A - A_d), which is nilpotent over the C / 16 blocks,
+T = (I - N)(I + N^2) D.  Ten products of (C, C) a chunk, operands in the
+inputs' type and sums in float32, the identity kept apart so that no
+1 + small is ever rounded.
+
+**The kernels.**  A grid of (B, H, steps), the steps walked in order
+(`arbitrary`), a step `_STEP_CHUNKS` chunks one after the other; q, k,
+beta k, beta v and g are read where they lie, a head's 128 lanes of
+(B, S, H K); the state, transposed (V, K) so that the decay of a key channel
+is a factor a lane, lives in a VMEM scratch.  beta never enters a kernel:
+XLA makes beta k and beta v (`_scaled`) and takes their cotangents apart.
+The backward is a kernel of its own over the same grid from last to first.
+Its residuals are the forward's INPUTS: a first pass makes the state that
+entered each chunk again (the forward kernel without its O), the second
+remakes the chunk's A, T, U and W from it, carries the state's cotangent in
+VMEM and writes dq, dk, d(beta k), d(beta v) and dg.  No product in it has
+exp(-G) over the chunk either: every cotangent of a pair decay is taken
+with the same row and column factors as the forward.
+
+**What the shape decides** (`_kernel_problem`): K = V = 128 (a head is a
+lane tile) and a chunk of 16, 32 or 64.  Every other shape runs the chunked
+form as plain `jax.numpy` (`_plain`: the same chunk's algebra, `vmap`ped
+over batch and heads, a `lax.scan` over the chunks' carry), which `jax.grad`
+differentiates and which is also what any platform but a TPU runs beyond the
+interpreter's sizes (`ops.by_platform`).
+
+Counts itself on the job timeline as the step is traced: `kda.layers` (a
+call), `kda.rule_kernel` / `kda.rule_plain` (a call that took the kernels /
+that a shape or a platform declined), `kda.bwd_kernel` (a backward rule
+traced with its kernel).
+"""
+
+from __future__ import annotations
+
+import functools
+import warnings
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import by_platform, interpreted
+from ray_tpu.util import tracing
+
+_LANE = 128
+# positions a pair decay's origin lies back at most: exp(5 x 16) is float32's
+_SUB = 16
+_HIGHEST = jax.lax.Precision.HIGHEST
+_F32 = jnp.float32
+# chunks a grid step takes, one after the other, unrolled: what of a chunk
+# does not wait for the carried state (its sums, A, T) overlaps the chunk
+# before it
+_STEP_CHUNKS = 2
+
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=64 << 20)
+
+
+class KdaFallbackWarning(UserWarning):
+    """A shape the rule's kernels do not take ran the plain chunked form,
+    on every platform, the TPU included."""
+
+
+# ---------------------------------------------------------------------------
+# one chunk's algebra: 2-D arrays only, so that a kernel's body and the plain
+# form are the same lines
+# ---------------------------------------------------------------------------
+
+def _dot(a, b, dims):
+    """a . b in float32, contracting a's axis dims[0] with b's dims[1];
+    float32 operands are multiplied as float32."""
+    exact = a.dtype == _F32 and b.dtype == _F32
+    return jax.lax.dot_general(
+        a, b, (((dims[0],), (dims[1],)), ((), ())),
+        preferred_element_type=_F32, precision=_HIGHEST if exact else None)
+
+
+def _iota(C):
+    rows = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    return rows, cols
+
+
+def _doublings(n):
+    """Squarings after which (I - X)(I + X^2)... holds every power of an X
+    that is nilpotent at n."""
+    return max(n - 1, 0).bit_length() - 1 if n > 1 else 0
+
+
+def _inverse_less_identity(X, steps, mm):
+    """(I + X)^-1 - I for X nilpotent at 2^(steps + 1):
+    (I - X)(I + X^2)...(I + X^(2^steps)), the identity kept apart."""
+    M = -X
+    for _ in range(steps):
+        X = mm(X, X)
+        M = M + X + mm(M, X)
+    return M
+
+
+def _solve(A, dtype):
+    """T - I for T = (I + A)^-1, A (C, C) float32 strictly lower; products
+    with operands in ``dtype``."""
+    C = A.shape[0]
+    mm = lambda x, y: _dot(x.astype(dtype), y.astype(dtype), (1, 0))
+    rows, cols = _iota(C)
+    near = rows // _SUB == cols // _SUB
+    A_d = jnp.where(near, A, 0.0)
+    D = _inverse_less_identity(A_d, _doublings(min(_SUB, C)), mm)
+    if C <= _SUB:
+        return D
+    A_o = A - A_d
+    N = A_o + mm(D, A_o)
+    M = _inverse_less_identity(N, _doublings(C // _SUB), mm)
+    return D + M + mm(M, D)
+
+
+class _Chunk:
+    """What both passes make of a chunk's q, k, beta k and g (C, K): the
+    sums, the decays' factors, A, P and T."""
+
+    def __init__(self, q, k, kb, g):
+        C = q.shape[0]
+        self.C, self.dtype = C, q.dtype
+        dtype = q.dtype
+        rows, cols = _iota(C)
+        self.rows, self.cols = rows, cols
+        g = g.astype(_F32)
+        near = rows // _SUB == cols // _SUB
+        # the sums inside a sub-block and up to it, apart
+        first = rows // _SUB * _SUB
+        inside = _dot(((cols <= rows) & near).astype(_F32), g, (1, 0))
+        middle = _dot(((cols <= first + _SUB // 2 - 1) & near).astype(_F32),
+                      g, (1, 0))
+        before = _dot((cols < first).astype(_F32), g, (1, 0))
+        G = inside + before
+        self.row = jnp.exp(inside - middle)         # exp(G_t - R_a(t))
+        self.start = jnp.exp(G)                     # exp(G_t), from the chunk's
+        self.total = G[C - 1:C]                     # (1, K)
+        self.end = jnp.exp(self.total - G)          # exp(G_C - G_i)
+        # the column side of sub-block a's rows: exp(R_a - G_i) up to the
+        # sub-block's last column, 0 behind it
+        row_of = jax.lax.broadcasted_iota(jnp.int32, G.shape, 0)
+        self.col = []
+        for a in range(C // _SUB):
+            at = slice(a * _SUB, a * _SUB + 1)
+            origin = before[at] + middle[at]        # (1, K): R_a
+            self.col.append(jnp.exp(jnp.where(
+                row_of < (a + 1) * _SUB, origin - G, -jnp.inf)))
+        f32 = lambda x: x.astype(_F32)
+        self.q_row = (f32(q) * self.row).astype(dtype)
+        self.kb_row = (f32(kb) * self.row).astype(dtype)
+        self.k_col = [(f32(k) * col).astype(dtype) for col in self.col]
+        self.q_start = (f32(q) * self.start).astype(dtype)
+        self.kb_start = (f32(kb) * self.start).astype(dtype)
+        self.k_end = (f32(k) * self.end).astype(dtype)
+        self.A = jnp.where(cols < rows, self.pairs(self.kb_row), 0.0)
+        self.P = jnp.where(cols <= rows, self.pairs(self.q_row), 0.0)
+        self.T = _solve(self.A, dtype)              # less the identity
+
+    def blocks(self):
+        return [slice(a * _SUB, (a + 1) * _SUB) for a in range(self.C // _SUB)]
+
+    def pairs(self, x_row):
+        """(C, C): sum(x_t * k_i * D(t, i)), sub-block of rows by sub-block;
+        the entries above a sub-block's last column are 0, those above the
+        diagonal inside it finite and for the caller to mask."""
+        return jnp.concatenate(
+            [_dot(x_row[rows], self.k_col[a], (1, 1))
+             for a, rows in enumerate(self.blocks())], axis=0)
+
+    def solved(self, x):
+        """T x, x (C, .) in the inputs' type."""
+        return x.astype(_F32) + _dot(self.T.astype(self.dtype), x, (1, 0))
+
+    def solved_back(self, x):
+        """T' x, x (C, .) float32."""
+        return x + _dot(self.T.astype(self.dtype), x.astype(self.dtype),
+                        (0, 0))
+
+
+def _chunk_forward(q, k, kb, vb, g, state, want_o=True):
+    """One chunk of one head: q, k, kb = beta k (C, K), vb = beta v (C, V),
+    g (C, K) float32, ``state`` the state that enters, TRANSPOSED (V, K)
+    float32 -> (o (C, V) float32 or None, the state that leaves)."""
+    c = _Chunk(q, k, kb, g)
+    dtype = c.dtype
+    state_x = state.astype(dtype)
+    W = c.solved(c.kb_start)                                    # (C, K)
+    U = c.solved(vb) - _dot(W.astype(dtype), state_x, (1, 1))   # (C, V)
+    U_x = U.astype(dtype)
+    o = None
+    if want_o:
+        o = _dot(c.q_start, state_x, (1, 1)) \
+            + _dot(c.P.astype(dtype), U_x, (1, 0))
+    return o, jnp.exp(c.total) * state + _dot(U_x, c.k_end, (0, 0))
+
+
+def _chunk_backward(q, k, kb, vb, g, state, do, dstate):
+    """The same chunk's cotangents: ``do`` (C, V), ``dstate`` that of the
+    state that LEFT, transposed (V, K) float32 -> (dq, dk, dkb, dvb, dg
+    (C, .) float32, the cotangent of the state that entered)."""
+    c = _Chunk(q, k, kb, g)
+    dtype, C = c.dtype, c.C
+    f32 = lambda x: x.astype(_F32)
+    x = lambda v: v.astype(dtype)
+    state_x, dstate_x, do_x = x(state), x(dstate), x(do)
+    grown = jnp.exp(c.total)                                    # (1, K)
+    # the forward's values again
+    Ubar = c.solved(vb)
+    W = c.solved(c.kb_start)
+    U = Ubar - _dot(x(W), state_x, (1, 1))
+    U_x = x(U)
+    # back through O, the state that leaves, and U
+    dU = _dot(x(c.P), do_x, (0, 0)) + _dot(c.k_end, dstate_x, (1, 1))
+    dP = jnp.where(c.cols <= c.rows, _dot(do_x, U_x, (1, 1)), 0.0)
+    dW = -_dot(x(dU), state_x, (1, 0))                          # (C, K)
+    dstate_in = grown * dstate + _dot(do_x, c.q_start, (0, 0)) \
+        - _dot(x(dU), x(W), (0, 0))
+    dvb = c.solved_back(dU)                                     # T' dU
+    dkb_start = c.solved_back(dW)                               # T' dW
+    dA = jnp.where(c.cols < c.rows,
+                   -_dot(x(dvb), x(Ubar), (1, 1))
+                   - _dot(x(dkb_start), x(W), (1, 1)), 0.0)
+    # back through the pair decays: a sub-block of rows at a time, the row
+    # side times exp(G_t - R_a), the column side times exp(R_a - G_i)
+    dq_rows, dkb_rows = [], []
+    dk_col = jnp.zeros(k.shape, _F32)
+    for a, rows in enumerate(c.blocks()):
+        d_pairs = x(jnp.concatenate([dP[rows], dA[rows]], axis=0))
+        sides = jnp.concatenate([c.q_row[rows], c.kb_row[rows]], axis=0)
+        back = _dot(d_pairs, c.k_col[a], (1, 0))                # (2 SUB, K)
+        dq_rows.append(back[:_SUB])
+        dkb_rows.append(back[_SUB:])
+        dk_col = dk_col + c.col[a] * _dot(d_pairs, sides, (0, 0))
+    dq = c.row * jnp.concatenate(dq_rows, axis=0) \
+        + c.start * _dot(do_x, state_x, (1, 0))
+    dkb = c.row * jnp.concatenate(dkb_rows, axis=0) + c.start * dkb_start
+    dk_end = c.end * _dot(U_x, dstate_x, (1, 0))
+    dk = dk_col + dk_end
+    # d G_t, then dg_s = the sum of d G_t over t >= s
+    last = jnp.sum(f32(k) * dk_end, axis=0, keepdims=True) \
+        + grown * jnp.sum(dstate * state, axis=0, keepdims=True)
+    dG = f32(q) * dq + f32(kb) * dkb - f32(k) * dk
+    dg = _dot((c.rows <= c.cols).astype(_F32), dG, (1, 0)) + last
+    return dq, dk, dkb, dvb, dg, dstate_in
+
+
+# ---------------------------------------------------------------------------
+# the plain form
+# ---------------------------------------------------------------------------
+
+def _chunk_size(S, chunk):
+    """The chunk a sequence of S takes: ``chunk``, or for a shorter sequence
+    its length up to whole sub-blocks."""
+    return min(chunk, -(-S // _SUB) * _SUB)
+
+
+def _by_chunks(x, C):
+    """(B, S, H, D) -> (chunks, B, H, C, D)."""
+    B, S, H, D = x.shape
+    return x.reshape(B, S // C, C, H, D).transpose(1, 0, 3, 2, 4)
+
+
+def _plain(q, k, kb, vb, g, C):
+    """The chunked form over (B, S, H, .) arrays, S a multiple of C: the
+    chunk's algebra `vmap`ped over batch and heads, a `lax.scan` over the
+    chunks' carry.  -> o (B, S, H, V) in q's type."""
+    B, S, H, K = q.shape
+    V = vb.shape[-1]
+    over = jax.vmap(jax.vmap(_chunk_forward))
+
+    def chunk(state, xs):
+        o, state = over(*xs, state)
+        return state, o.astype(q.dtype)
+
+    _, o = jax.lax.scan(chunk, jnp.zeros((B, H, V, K), _F32),
+                        tuple(_by_chunks(v, C) for v in (q, k, kb, vb, g)))
+    return o.transpose(1, 0, 3, 2, 4).reshape(B, S, H, V)
+
+
+# ---------------------------------------------------------------------------
+# the kernels
+# ---------------------------------------------------------------------------
+
+def _kernel_problem(K, V, C) -> Optional[str]:
+    """Why the kernels do not take a rule of these sizes, or None."""
+    if K != _LANE or V != _LANE:
+        return "a head's keys and values are not one 128-lane tile each"
+    if C not in (_SUB, 2 * _SUB, 4 * _SUB):
+        return "the chunk is not 16, 32 or 64 positions"
+    return None
+
+
+def _step_chunks(chunks):
+    return max(n for n in range(1, _STEP_CHUNKS + 1) if chunks % n == 0)
+
+
+def _forward_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, out_ref, state_ref,
+                    *, C, states):
+    """A grid step: one head's few chunks, one after the other.  ``states``:
+    write the state that entered each chunk and no o (the backward's first
+    pass)."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    state = state_ref[...]
+    for j in range(q_ref.shape[1] // C):
+        rows = slice(j * C, (j + 1) * C)
+        if states:
+            out_ref[0, 0, j] = state
+        o, state = _chunk_forward(
+            q_ref[0, rows], k_ref[0, rows], kb_ref[0, rows], vb_ref[0, rows],
+            g_ref[0, rows], state, want_o=not states)
+        if not states:
+            out_ref[0, rows] = o.astype(out_ref.dtype)
+    state_ref[...] = state
+
+
+def _backward_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, before_ref, do_ref,
+                     dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref, dstate_ref,
+                     *, C):
+    """A grid step: the steps and a step's chunks from last to first;
+    `dstate_ref` carries the cotangent of the state that LEFT the chunk."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate_ref[...] = jnp.zeros_like(dstate_ref)
+
+    dstate = dstate_ref[...]
+    for j in reversed(range(q_ref.shape[1] // C)):
+        rows = slice(j * C, (j + 1) * C)
+        dq, dk, dkb, dvb, dg, dstate = _chunk_backward(
+            q_ref[0, rows], k_ref[0, rows], kb_ref[0, rows], vb_ref[0, rows],
+            g_ref[0, rows], before_ref[0, 0, j], do_ref[0, rows], dstate)
+        dq_ref[0, rows] = dq.astype(dq_ref.dtype)
+        dk_ref[0, rows] = dk.astype(dk_ref.dtype)
+        dkb_ref[0, rows] = dkb.astype(dkb_ref.dtype)
+        dvb_ref[0, rows] = dvb.astype(dvb_ref.dtype)
+        dg_ref[0, rows] = dg
+    dstate_ref[...] = dstate
+
+
+def _flat(x):
+    """(B, S, H, D) -> (B, S, H D): a head's D lanes side by side, as the
+    projections wrote them."""
+    return x.reshape(*x.shape[:2], -1)
+
+
+def _specs(B, S, H, C, n, step_of):
+    """The grid and the block of a (B, S, H x 128) operand, n chunks a
+    step; ``step_of`` maps the grid's third index to the step's place in the
+    sequence."""
+    return (B, H, S // (n * C)), pl.BlockSpec(
+        (1, n * C, _LANE), lambda b, h, s: (b, step_of(s), h))
+
+
+@functools.partial(jax.jit, static_argnames=("C", "states", "interpret"))
+def _forward(q, k, kb, vb, g, C, states=False, interpret=False):
+    """-> o (B, S, H, V) in q's type; or, ``states``, the state that entered
+    each chunk, transposed: (B, H, chunks, V, K) float32."""
+    B, S, H, K = q.shape
+    V = vb.shape[-1]
+    n = _step_chunks(S // C)
+    grid, block = _specs(B, S, H, C, n, lambda s: s)
+    if states:
+        shape = jax.ShapeDtypeStruct((B, H, S // C, V, K), _F32)
+        spec = pl.BlockSpec((1, 1, n, V, K), lambda b, h, s: (b, h, s, 0, 0))
+    else:
+        shape = jax.ShapeDtypeStruct((B, S, H * V), q.dtype)
+        spec = block
+    out = pl.pallas_call(
+        functools.partial(_forward_kernel, C=C, states=states),
+        grid=grid, in_specs=[block] * 5, out_specs=spec, out_shape=shape,
+        scratch_shapes=[pltpu.VMEM((V, K), _F32)],
+        compiler_params=_COMPILER_PARAMS, interpret=interpret,
+    )(*(_flat(x) for x in (q, k, kb, vb, g)))
+    return out if states else out.reshape(B, S, H, V)
+
+
+@functools.partial(jax.jit, static_argnames=("C", "interpret"))
+def _backward(q, k, kb, vb, g, do, C, interpret=False):
+    """-> (dq, dk, dkb, dvb, dg), each in its primal's shape and type."""
+    B, S, H, K = q.shape
+    V = vb.shape[-1]
+    n = _step_chunks(S // C)
+    before = _forward(q, k, kb, vb, g, C=C, states=True, interpret=interpret)
+    steps = S // (n * C)
+    back = lambda s: steps - 1 - s
+    grid, block = _specs(B, S, H, C, n, back)
+    primals = (q, k, kb, vb, g)
+    grads = pl.pallas_call(
+        functools.partial(_backward_kernel, C=C),
+        grid=grid,
+        in_specs=[block] * 5 + [
+            pl.BlockSpec((1, 1, n, V, K),
+                         lambda b, h, s: (b, h, back(s), 0, 0)), block],
+        out_specs=[block] * 5,
+        out_shape=[jax.ShapeDtypeStruct(_flat(x).shape, x.dtype)
+                   for x in primals],
+        scratch_shapes=[pltpu.VMEM((V, K), _F32)],
+        compiler_params=_COMPILER_PARAMS, interpret=interpret,
+    )(*(_flat(x) for x in primals), before, _flat(do))
+    return tuple(d.reshape(x.shape) for d, x in zip(grads, primals))
+
+
+def _runs_kernels(q) -> bool:
+    """Whether `by_platform` gives a call whose first operand is ``q`` the
+    kernels where this process traces it: on a TPU, or interpreted."""
+    return interpreted(q) or jax.default_backend() == "tpu"
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kernels(q, k, kb, vb, g, C):
+    return _kernels_fwd(q, k, kb, vb, g, C)[0]
+
+
+def _kernels_fwd(q, k, kb, vb, g, C):
+    o = by_platform(
+        lambda *a, interpret: _forward(*a, C=C, interpret=interpret),
+        lambda *a: _plain(*a, C), q, k, kb, vb, g)
+    return o, (q, k, kb, vb, g)
+
+
+def _kernels_bwd(C, inputs, do):
+    def reference(*a):
+        *inputs, do = a
+        return jax.vjp(lambda *v: _plain(*v, C), *inputs)[1](do)
+
+    if _runs_kernels(inputs[0]):
+        tracing.count("kda.bwd_kernel")
+    return by_platform(
+        lambda *a, interpret: _backward(*a, C=C, interpret=interpret),
+        reference, *inputs, do)
+
+
+_kernels.defvjp(_kernels_fwd, _kernels_bwd)
+
+
+def _scaled(x, beta):
+    """beta x, a scalar a head and position, in x's type."""
+    return (x.astype(_F32) * beta.astype(_F32)[..., None]).astype(x.dtype)
+
+
+def kda(q, k, v, g, beta, chunk=64):
+    """-> o (B, S, H, V) in q's type: the rule above by chunks of ``chunk``
+    positions.  g float32, at least -5 a position (`_SUB`'s reason: eight
+    positions of it are exp(-40)).  S need not divide by the chunk: the tail is
+    padded with positions of k = 0, beta = 0 and g = 0, which neither move
+    the state nor are read."""
+    B, S, H, K = q.shape
+    V = v.shape[-1]
+    tracing.count("kda.layers")
+    C = _chunk_size(S, chunk)
+    kb, vb = _scaled(k, beta), _scaled(v, beta)
+    g = g.astype(_F32)
+    pad = -S % C
+    if pad:
+        q, k, kb, vb, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                           for x in (q, k, kb, vb, g))
+    problem = _kernel_problem(K, V, C)
+    if problem:
+        warnings.warn(
+            f"the delta rule of {H} heads, keys {K} and values {V} wide, "
+            f"chunk {C} runs the plain chunked form: {problem}",
+            KdaFallbackWarning, stacklevel=2)
+        tracing.count("kda.rule_plain")
+        o = _plain(q, k, kb, vb, g, C)
+    else:
+        tracing.count("kda.rule_kernel" if _runs_kernels(q)
+                      else "kda.rule_plain")
+        o = _kernels(q, k, kb, vb, g, C)
+    return o[:, :S]

@@ -320,13 +320,30 @@ def moe_dispatch(x, weights, experts, n_experts, run_experts, held=None):
 ROUTING_BIAS = "e_score_correction_bias"
 
 
-def sigmoid_route(xt, router, top_k, eps, scale):
+def _in_best_groups(picks, n_group, topk_group):
+    """picks (T, N) -> picks with -inf outside each row's ``topk_group`` best
+    of ``n_group`` groups of N / n_group consecutive experts, a group's score
+    the sum of its two largest picks (DeepSeek-V3's group-limited choice)."""
+    T, N = picks.shape
+    best_two, _ = jax.lax.top_k(picks.reshape(T, n_group, N // n_group), 2)
+    _, groups = jax.lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+    kept = jnp.any(groups[:, :, None] == jnp.arange(n_group)[None, None],
+                   axis=1)                                      # (T, n_group)
+    return jnp.where(jnp.repeat(kept, N // n_group, axis=1), picks, -jnp.inf)
+
+
+def sigmoid_route(xt, router, top_k, eps, scale, n_group=1, topk_group=1):
     """xt (T, E) -> (weights (T, k) f32, experts (T, k) int32) over all the
     experts ``router["kernel"]`` (E, N) scores: s = sigmoid(xt W) in
     float32; the top k of s + b, b the leaf `ROUTING_BIAS` where the router
     has one (it picks and does not weigh, and nothing differentiates
     through it); weights s at the chosen, over their sum + ``eps`` unless
-    ``eps`` is None (weights not renormalised), times ``scale``."""
+    ``eps`` is None (weights not renormalised), times ``scale``.
+    ``n_group`` > 1: the top k are taken inside the ``topk_group`` best of
+    ``n_group`` groups of consecutive experts (`_in_best_groups`); such a
+    call is counted on the job timeline as the step is traced
+    (`moe.route_groups`).  One group: every expert stands, and the program
+    is what it always was."""
     # the product and not its sigmoid, whose backward reads its own result
     scores = jax.nn.sigmoid(checkpoint_name(jnp.matmul(
         xt, router["kernel"].astype(xt.dtype),
@@ -334,6 +351,10 @@ def sigmoid_route(xt, router, top_k, eps, scale):
     picks = scores
     if ROUTING_BIAS in router:
         picks = scores + jax.lax.stop_gradient(router[ROUTING_BIAS])
+    if n_group > 1:
+        tracing.count("moe.route_groups")
+        picks = _in_best_groups(jax.lax.stop_gradient(picks), n_group,
+                                topk_group)
     _, experts = jax.lax.top_k(picks, top_k)
     experts = checkpoint_name(experts, ROUTE_NAME)
     # and the scores at the chosen: (T, k), and a gather a replay would run

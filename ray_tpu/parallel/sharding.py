@@ -221,6 +221,18 @@ def infer_param_logical_dims(path: Tuple[str, ...], shape: Tuple[int, ...]):
     if "mamba/dt_proj" in name:
         # W_dt (R, C) and dt's bias (C,): the channels again
         return (None, "embed") if nd == 2 else ("embed",)
+    if "kda/" in name:
+        # a delta-rule mixer's leaves that the names below do not tell: the
+        # taps (3 H K, taps), a filter a channel of q, k and v, cut by heads
+        # and the taps never; A_log (H,) and dt_bias (H K,), a head's own;
+        # W_f (E, H K) and W_b (E, H), the decay's and beta's maps (W_g is a
+        # "g_proj" and the gain over a head a norm's scale, below)
+        if "kda/conv" in name:
+            return ("heads", None)[:nd]
+        if "a_log" in name or "dt_bias" in name:
+            return ("heads",)
+        if "f_proj" in name or "b_proj" in name:
+            return ("embed", "heads")
     if "lambda_" in name or "diff_norm" in name:
         # differential attention's four vectors of a head's width and the
         # gain over a pair of heads: whole on every chip

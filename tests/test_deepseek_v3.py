@@ -382,3 +382,98 @@ def test_counts_at_the_published_widths():
     n = (16032 * 2048 + 5 * attn + 3 * 2048 * 6144 + 4 * (
         2048 * 128 + 3 * 2048 * 1536 + 0.75 * 3 * 2048 * 768))
     assert flops == 6 * n + 6 * 5 * 8192 * 32 * 320
+
+
+# -- the group-limited choice (ISSUE 65) ---------------------------------------
+
+def _numpy_group_route(x, kernel, bias, top_k, n_group, topk_group, scale):
+    """`sigmoid_route` with groups, written out in numpy float64 a token at a
+    time: the two largest picks of each group summed, the best groups kept,
+    the top k among their experts, weights over their sum."""
+    x, kernel, bias = (np.asarray(a, np.float64) for a in (x, kernel, bias))
+    scores = 1.0 / (1.0 + np.exp(-(x @ kernel)))
+    weights, experts = [], []
+    per = kernel.shape[1] // n_group
+    for s in scores:
+        picks = s + bias
+        by_group = picks.reshape(n_group, per)
+        score = np.sort(by_group, axis=1)[:, -2:].sum(axis=1)
+        kept = np.argsort(-score, kind="stable")[:topk_group]
+        masked = np.full_like(picks, -np.inf)
+        for g in kept:
+            masked[g * per:(g + 1) * per] = picks[g * per:(g + 1) * per]
+        chosen = np.argsort(-masked, kind="stable")[:top_k]
+        experts.append(chosen)
+        weights.append(s[chosen] / (s[chosen].sum() + 1e-20) * scale)
+    return np.asarray(weights), np.asarray(experts)
+
+
+@pytest.mark.parametrize("n_group, topk_group, top_k", [
+    (8, 4, 8), (4, 1, 3), (2, 2, 5)])
+def test_the_group_limited_choice_is_the_written_out_one(n_group, topk_group,
+                                                        top_k):
+    from ray_tpu.ops.moe import sigmoid_route
+    from ray_tpu.util import tracing
+
+    T, E, N = 96, 32, 64
+    x = jax.random.normal(jax.random.PRNGKey(0), (T, E))
+    router = {"kernel": jax.random.normal(jax.random.PRNGKey(1), (E, N)),
+              BIAS: 0.3 * jax.random.normal(jax.random.PRNGKey(2), (N,))}
+    with tracing.timeline_span("train.fit", root=True) as job:
+        weights, experts = sigmoid_route(
+            x, router, top_k, 1e-20, 2.5, n_group=n_group,
+            topk_group=topk_group)
+        assert tracing.counter("moe.route_groups") == 1
+    tracing.timeline_take(job.trace_id)
+    want_w, want_e = _numpy_group_route(
+        x, router["kernel"], router[BIAS], top_k, n_group, topk_group, 2.5)
+    np.testing.assert_array_equal(np.asarray(experts), want_e)
+    np.testing.assert_allclose(np.asarray(weights), want_w, rtol=1e-5)
+    # every choice lies in one of at most `topk_group` groups
+    groups = np.asarray(experts) // (N // n_group)
+    assert max(len(set(row)) for row in groups) <= topk_group
+    # the bias picks and does not weigh, and carries no gradient
+    grads = jax.grad(lambda r: jnp.sum(sigmoid_route(
+        x, r, top_k, 1e-20, 2.5, n_group=n_group,
+        topk_group=topk_group)[0] ** 2))(router)
+    assert not np.asarray(grads[BIAS]).any()
+    assert np.asarray(grads["kernel"]).any()
+
+
+def test_one_group_is_the_program_it_always_was():
+    """At the defaults `sigmoid_route`'s jaxpr is the parent's, written out
+    here as it stood before the groups; kanana's step counts no grouped
+    router."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from ray_tpu.ops.moe import ROUTE_NAME, sigmoid_route
+    from ray_tpu.util import tracing
+
+    def parent_route(xt, router, top_k, eps, scale):
+        scores = jax.nn.sigmoid(checkpoint_name(jnp.matmul(
+            xt, router["kernel"].astype(xt.dtype),
+            preferred_element_type=jnp.float32), ROUTE_NAME))
+        picks = scores
+        if BIAS in router:
+            picks = scores + jax.lax.stop_gradient(router[BIAS])
+        _, experts = jax.lax.top_k(picks, top_k)
+        experts = checkpoint_name(experts, ROUTE_NAME)
+        weights = checkpoint_name(
+            jnp.take_along_axis(scores, experts, axis=-1), ROUTE_NAME)
+        if eps is not None:
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                                 + eps)
+        return weights * scale, experts
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (16, 8))
+    for router in ({"kernel": jnp.ones((8, 12))},
+                   {"kernel": jnp.ones((8, 12)), BIAS: jnp.zeros((12,))}):
+        for eps in (1e-20, None):
+            assert str(jax.make_jaxpr(lambda x: sigmoid_route(
+                x, router, 3, eps, 2.448))(x)) == str(jax.make_jaxpr(
+                    lambda x: parent_route(x, router, 3, eps, 2.448))(x))
+    with tracing.timeline_span("train.fit", root=True) as job:
+        jax.eval_shape(lambda p: model.forward(
+            p, make_tokens()[:, :-1], F32), make_params())
+        assert tracing.counter("moe.route_groups") == 0
+    tracing.timeline_take(job.trace_id)

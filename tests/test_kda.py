@@ -1,0 +1,259 @@
+"""`ops/kda.py`: Kimi Delta Attention's rule.  The plain chunked form and the
+Pallas kernels (interpreted here) against the recurrence run position by
+position in float64 numpy and under `jax.grad` of the same recurrence in jax:
+o and all five gradients, at one chunk and at many, with g drawn AT the
+gate's bound; the state carried from chunk to chunk; what the kernels take;
+a declined shape counted; a recomputed layer's replay; and what a TPU is
+given."""
+
+import warnings
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import layers
+from ray_tpu.ops import interpreted
+from ray_tpu.ops import kda as K
+from ray_tpu.util import tracing
+
+NAMES = ("q", "k", "v", "g", "beta")
+# (B, S, H, K, V): a head is a lane tile, as the kernels ask
+ONE_CHUNK = (1, 64, 2, 128, 128)
+MANY = (1, 192, 1, 128, 128)
+BATCHED = (2, 128, 1, 128, 128)
+# a shape the kernels decline: heads of 32 and 48
+DECLINED = (2, 128, 2, 32, 48)
+
+
+def make(shape, seed=0, at_bound=False, dtype=jnp.float32):
+    """(q, k, v, g, beta) as a mixer hands them over, and a cotangent."""
+    B, S, H, Kd, V = shape
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (B, S, H, Kd))) * Kd ** -0.5
+    k = unit(jax.random.normal(ks[1], (B, S, H, Kd)))
+    v = jax.random.normal(ks[2], (B, S, H, V))
+    g = -5.0 * jax.nn.sigmoid(2.0 * jax.random.normal(ks[3], (B, S, H, Kd)))
+    if at_bound:
+        g = jnp.full_like(g, -5.0)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, H)))
+    return ((q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta),
+            jax.random.normal(ks[5], (B, S, H, V)))
+
+
+def by_positions(q, k, v, g, beta):
+    """The rule as its equation reads, numpy float64, one position after
+    another: S_t = (I - beta k k') Diag(alpha) S_{t-1} + beta k v'."""
+    q, k, v, g, beta = (np.asarray(x, np.float64) for x in (q, k, v, g, beta))
+    B, S, H, Kd = q.shape
+    o = np.zeros(v.shape)
+    for b in range(B):
+        for h in range(H):
+            state = np.zeros((Kd, v.shape[-1]))
+            for t in range(S):
+                kt, bt = k[b, t, h], beta[b, t, h]
+                state = np.exp(g[b, t, h])[:, None] * state
+                state = state - bt * np.outer(kt, kt @ state) \
+                    + bt * np.outer(kt, v[b, t, h])
+                o[b, t, h] = state.T @ q[b, t, h]
+    return o
+
+
+def recurrence(q, k, v, g, beta):
+    """The same in jax float32, for `jax.grad`."""
+    B, S, H, Kd = q.shape
+
+    def step(state, x):
+        qt, kt, vt, gt, bt = x
+        state = jnp.exp(gt)[..., None] * state
+        seen = jnp.einsum("bhkv,bhk->bhv", state, kt)
+        state = state + (bt[..., None] * kt)[..., None] \
+            * (vt - seen)[..., None, :]
+        return state, jnp.einsum("bhkv,bhk->bhv", state, qt)
+
+    xs = tuple(jnp.moveaxis(x.astype(jnp.float32), 1, 0)
+               for x in (q, k, v, g, beta))
+    with jax.default_matmul_precision("highest"):
+        _, o = jax.lax.scan(
+            step, jnp.zeros((B, H, Kd, v.shape[-1]), jnp.float32), xs)
+    return jnp.moveaxis(o, 0, 1)
+
+
+def value_and_grads(rule, args, do):
+    out, back = jax.vjp(rule, *args)
+    return (out, *back(do.astype(out.dtype)))
+
+
+def close(got, want, tol):
+    got, want = (np.asarray(x, np.float64) for x in (got, want))
+    assert np.max(np.abs(got - want)) <= tol * max(np.max(np.abs(want)),
+                                                   1e-30)
+
+
+A_PASS = 2      # `pallas_call`s of a traced pass: a TPU's and the interpreter's
+
+
+def n_kernels(f, *args):
+    return str(jax.make_jaxpr(f)(*args)).count("pallas_call")
+
+
+def plain(q, k, v, g, beta, chunk=64):
+    C = K._chunk_size(q.shape[1], chunk)
+    return K._plain(q, k, K._scaled(k, beta), K._scaled(v, beta), g, C)
+
+
+@pytest.mark.parametrize("shape", [ONE_CHUNK, MANY, BATCHED],
+                         ids=["one_chunk", "three_chunks", "batched"])
+@pytest.mark.parametrize("rule", [plain, K.kda], ids=["plain", "kernels"])
+def test_the_rule_is_the_recurrence_position_by_position(shape, rule):
+    args, _ = make(shape)
+    close(rule(*args), by_positions(*args), 1e-5)
+
+
+@pytest.mark.parametrize("shape", [ONE_CHUNK, MANY],
+                         ids=["one_chunk", "three_chunks"])
+@pytest.mark.parametrize("rule", [plain, K.kda], ids=["plain", "kernels"])
+def test_all_five_gradients_are_the_recurrences(shape, rule):
+    """The plain form under `jax.grad`; the kernels' own backward."""
+    args, do = make(shape)
+    for name, g, w in zip(("o", *NAMES), value_and_grads(rule, args, do),
+                          value_and_grads(recurrence, args, do)):
+        close(g, w, 2e-5), name
+
+
+@pytest.mark.parametrize("rule", [plain, K.kda], ids=["plain", "kernels"])
+def test_at_the_gates_bound_for_a_whole_chunk_it_is_finite_and_the_recurrence(
+        rule):
+    """g = -5 at every one of 64 positions: exp(-G) over the chunk would be
+    exp(320); against origins 16 back nothing overflows and nothing is
+    lost."""
+    args, do = make(ONE_CHUNK, at_bound=True)
+    got = value_and_grads(rule, args, do)
+    assert all(np.isfinite(np.asarray(x)).all() for x in got)
+    close(got[0], by_positions(*args), 1e-5)
+    # dg is what is left of sums that all but cancel: 4e-4 at its largest
+    for g, w in zip(got, value_and_grads(recurrence, args, do)):
+        close(g, w, 2e-4)
+
+
+@pytest.mark.parametrize("rule", [plain, K.kda], ids=["plain", "kernels"])
+def test_the_state_is_carried_from_chunk_to_chunk(rule, monkeypatch):
+    """The second chunk's o depends on the first chunk's keys and values;
+    with the carry zeroed (a fault) it is another result."""
+    args, _ = make(MANY)
+    sound = np.asarray(rule(*args))
+    moved = list(args)
+    moved[2] = args[2].at[:, :64].multiply(2.0)         # v of chunk 0
+    assert np.max(np.abs(np.asarray(rule(*moved))[:, 64:]
+                         - sound[:, 64:])) > 1e-3
+    forward = K._chunk_forward
+
+    def no_carry(q, k, kb, vb, g, state, want_o=True):
+        o, after = forward(q, k, kb, vb, g, jnp.zeros_like(state), want_o)
+        return o, after
+
+    monkeypatch.setattr(K, "_chunk_forward", no_carry)
+    jax.clear_caches()
+    faulty = np.asarray(rule(*args))
+    jax.clear_caches()
+    want = by_positions(*args)
+    assert np.max(np.abs(faulty[:, :64] - want[:, :64])) < 1e-5
+    assert np.max(np.abs(faulty[:, 64:] - want[:, 64:])) \
+        > 0.05 * np.max(np.abs(want))
+
+
+def test_bfloat16_operands_stay_close_to_the_recurrence():
+    args, do = make(MANY, dtype=jnp.bfloat16)
+    exact = value_and_grads(recurrence, args, do)
+    for rule in (plain, K.kda):
+        for g, w in zip(value_and_grads(rule, args, do), exact):
+            close(g, w, 0.03)
+
+
+def test_a_ragged_length_is_padded_with_positions_that_move_nothing():
+    args, do = make((1, 100, 1, 128, 128))
+    for g, w in zip(value_and_grads(K.kda, args, do),
+                    value_and_grads(recurrence, args, do)):
+        close(g, w, 2e-5)
+
+
+@pytest.mark.parametrize("sizes, problem", [
+    ((128, 128, 64), None), ((128, 128, 32), None), ((128, 128, 16), None),
+    ((64, 128, 64), "tile"), ((128, 256, 64), "tile"),
+    ((128, 128, 128), "chunk"), ((128, 128, 48), "chunk"),
+])
+def test_what_the_kernels_take(sizes, problem):
+    said = K._kernel_problem(*sizes)
+    assert (said is None) if problem is None else (problem in said)
+
+
+@pytest.mark.parametrize("shape, taken", [(ONE_CHUNK, 1), (DECLINED, 0)],
+                         ids=["taken", "declined"])
+def test_a_call_counts_itself_and_a_declined_shape_is_the_plain_form(
+        shape, taken):
+    """A declined shape warns, holds no `pallas_call`, gives the plain
+    form's result and gradients to the last bit and counts
+    `kda.rule_plain`; a taken one the kernels, forward, the states' pass
+    and the backward, and `kda.bwd_kernel`."""
+    args, do = make(shape)
+    names = ("kda.layers", "kda.rule_kernel", "kda.rule_plain",
+             "kda.bwd_kernel")
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", K.KdaFallbackWarning)
+        jax.eval_shape(K.kda, *args)
+        assert [tracing.counter(n) for n in names] == [0, 0, 0, 0]  # no job
+        with tracing.timeline_span("train.fit", root=True):
+            kernels = n_kernels(
+                lambda *a: value_and_grads(K.kda, a, do), *args)
+            assert [tracing.counter(n) for n in names] == [
+                1, taken, 1 - taken, taken]
+        assert kernels == 3 * taken * A_PASS
+        if not taken:
+            with pytest.warns(K.KdaFallbackWarning, match="plain chunked"):
+                got = value_and_grads(K.kda, args, do)
+            for g, w in zip(got, value_and_grads(
+                    lambda *a: plain(*a), args, do)):
+                np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+            for g, w in zip(got, value_and_grads(recurrence, args, do)):
+                close(g, w, 5e-5)
+
+
+def test_a_replayed_layer_gives_the_same_gradients():
+    """Under `checkpoint_layer` with no room the backward pass makes the
+    rule's inputs again and runs the states' pass and the backward kernel;
+    with `kda/rule` kept by name no forward kernel is replayed either."""
+    args, do = make(ONE_CHUNK)
+
+    def layer(*a):
+        return jnp.sum(jnp.square(layers.named(K.kda(*a), "kda/rule")) * do)
+
+    walked = jax.jit(jax.value_and_grad(layer, range(5)))(*args)
+    replay = jax.value_and_grad(layers.checkpoint_layer(layer), range(5))
+    for g, w in zip(jax.tree.leaves(jax.jit(replay)(*args)),
+                    jax.tree.leaves(walked)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    kept = jax.value_and_grad(jax.checkpoint(
+        layer, policy=jax.checkpoint_policies.save_only_these_names(
+            "kda/rule")), range(5))
+    # forward; then the states' pass and the backward, no forward again
+    assert n_kernels(kept, *args) == 3 * A_PASS
+    assert n_kernels(replay, *args) == 4 * A_PASS
+
+
+def test_past_the_interpreters_size_another_platform_runs_the_plain_form():
+    """A shape the kernels take, too large to interpret: lowered for the
+    CPU it is the plain form and counted so, for a TPU the Mosaic
+    kernels."""
+    args, do = make((1, 128, 8, 128, 128))
+    assert not interpreted(args[0])
+    f = jax.jit(lambda *a: value_and_grads(K.kda, a, do))
+    with tracing.timeline_span("train.fit", root=True):
+        text = f.lower(*args).as_text()
+        assert tracing.counter("kda.rule_kernel") == 0
+        assert tracing.counter("kda.rule_plain") == 1
+        assert tracing.counter("kda.bwd_kernel") == 0
+    assert "tpu_custom_call" not in text
+    exported = jax.export.export(f, platforms=["tpu"])(*args)
+    assert exported.mlir_module().count("tpu_custom_call") >= 3

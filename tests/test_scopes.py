@@ -25,6 +25,7 @@ import pytest
 
 from benchmark.harness import scope_trace
 from ray_tpu.models import (
+    bailing_hybrid,
     deepseek_v3,
     gpt2,
     keye_vl,
@@ -54,6 +55,10 @@ MODELS = {
     "mellum": (mellum, mellum.MELLUM_TINY),
     "laguna": (laguna, laguna.LAGUNA_TINY),
     "phi4flash": (phi4flash, phi4flash.PHI4FLASH_TINY),
+    # heads as wide as the published ones: the rule's kernels, not its
+    # plain form
+    "bailing_hybrid": (bailing_hybrid, dataclasses.replace(
+        bailing_hybrid.BAILING_HYBRID_TINY, head_dim=128, kda_chunk=64)),
 }
 CASES = [(name, remat) for name in MODELS for remat in (False, True)]
 # what every model's step must have a matmul under
@@ -101,6 +106,12 @@ EXPECTED = {
                   "attention/kernel/bwd_fused_window",
                   "attention/kernel/fwd_rows", "attention/kernel/bwd_fused",
                   "attention/out", "gmu", "ffn/dense", "head_and_loss"},
+    "bailing_hybrid": {"kda/proj", "kda/conv", "kda/rule", "kda/out_proj",
+                       "attention/latent_down", "attention/latent_up",
+                       "attention/kernel/fwd_rows",
+                       "attention/kernel/bwd_fused", "attention/gate",
+                       "attention/out", "ffn/dense", "ffn/moe/route",
+                       "ffn/moe/experts", "ffn/moe/shared", "head_and_loss"},
 }
 # components of an `op_name` that jax puts there itself (`jnp.einsum` its
 # subscripts: `ops/ssd.py`'s products)
@@ -122,7 +133,8 @@ def lowered_text(name: str, remat: bool) -> str:
     module, cfg = MODELS[name]
     cfg = dataclasses.replace(cfg, remat=remat)
     optimizer = optax.adamw(1e-4)
-    if module in (deepseek_v3, lfm2_moe, nemotron_h, laguna):
+    if module in (deepseek_v3, lfm2_moe, nemotron_h, laguna,
+                  bailing_hybrid):
         optimizer = trained_by(optimizer)
     # a step that draws its own noise is built with the run's seed
     step = module.make_train_step(cfg, optimizer,
@@ -216,7 +228,8 @@ def test_every_matmul_and_kernel_is_under_a_scope(name, remat):
     every = {scope(full) for _, full in full_names(
         lowered_text(name, remat), re.compile(r"stablehlo\.\w+"))}
     assert {"optimizer_update", "norm", "embed"} <= every
-    if name in ("deepseek_v3", "lfm2_moe", "nemotron_h", "laguna"):
+    if name in ("deepseek_v3", "lfm2_moe", "nemotron_h", "laguna",
+                "bailing_hybrid"):
         assert "routing_bias_update" in every
     if name == "lfm2_moe":
         assert "short_conv/gate_taps" in every
@@ -242,8 +255,13 @@ def test_every_matmul_and_kernel_is_under_a_scope(name, remat):
     # its kernels, in the one model that has them
     assert ({"mamba/scan", "attention/diff"} <= every) \
         is (name == "phi4flash")
-    # a gate on attention's result, in the one model that has one
-    assert ("attention/gate" in every) is (name == "laguna")
+    # a gate on attention's result, in the models that have one
+    assert ("attention/gate" in every) \
+        is (name in ("laguna", "bailing_hybrid"))
+    # a delta-rule mixer's parts that hold no matmul, and the rule's
+    # kernels, in the one model that has them
+    assert ({"kda/gate", "kda/gate_norm", "kda/rule"} <= every) \
+        is (name == "bailing_hybrid")
 
 
 @pytest.mark.parametrize("remat", [False, True])

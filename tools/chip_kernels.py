@@ -155,6 +155,14 @@ the seconds both took to trace and lower and to compile, the least time of
 the bytes (forward reads and writes the channels, backward reads twice and writes once), and
 the largest error of the result and of the three gradients relative to the
 plain form on float32 operands.
+
+``ling_16k`` is Kimi Delta Attention's rule alone (`ops/kda.py`) at the ling
+cell's shape, one sequence of 16,384, 16 heads of 128, chunks of 64, g over
+the whole of (-5, 0) with a sixteenth of the positions at the bound
+(``KDA_CASES``): a line for the float32 recurrence position by position (the
+benchmark's reference's), one for the plain chunked form and one for the
+Mosaic kernels at each count of chunks a grid step of ``KDA_STEP_CHUNKS``
+(see `kda_case`; two minutes).
 """
 
 from __future__ import annotations
@@ -217,6 +225,13 @@ SSCAN_CASES = {
     "sscan_16k": (1, 16384, 5120, 16),
 }
 # (B, S, H, P, G, N, chunk) of one state-space scan
+# (B, S, H, K = V, chunk): the delta rule of the ling cell's KDA layers
+KDA_CASES = {
+    "ling_16k": (1, 16384, 16, 128, 64),
+}
+# the chunks a grid step takes that `kda_case` times the kernels at
+KDA_STEP_CHUNKS = (1, 2, 4)
+
 SSD_CASES = {
     "ssd_8k": (2, 8192, 64, 64, 8, 128, 128),
 }
@@ -1371,6 +1386,91 @@ def sscan_case(name, dtype):
     jax.clear_caches()
 
 
+def kda_case(name, dtype):
+    """One gated delta rule at ``KDA_CASES[name]`` (`ops/kda.py`): a line for
+    the float32 recurrence run position by position in recomputed blocks of
+    64 (`benchmark/reference/bailing_hybrid.py:delta_rule`), one for the
+    plain chunked form (`_plain`: what a declined shape runs) and one for the
+    Mosaic kernels at each count of chunks a grid step of
+    ``KDA_STEP_CHUNKS`` (`kept`: the module's `_STEP_CHUNKS`): forward ms and
+    forward + backward ms of one `jax.grad` in all five operands, every
+    operation counted (beta k and beta v among them) and the kernels alone,
+    beside the least time of the rule's bytes, and the largest error of o and
+    of the five gradients relative to the recurrence's.  g is drawn over the
+    whole of (-5, 0) and a sixteenth of the positions stand AT the bound."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.reference import bailing_hybrid as reference
+
+    kda = importlib.import_module("ray_tpu.ops.kda")
+    B, S, H, D, C = KDA_CASES[name]
+    ks = jax.random.split(jax.random.PRNGKey(0), 7)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = (unit(jax.random.normal(ks[0], (B, S, H, D))) * D ** -0.5).astype(dtype)
+    k = unit(jax.random.normal(ks[1], (B, S, H, D))).astype(dtype)
+    v = jax.nn.silu(jax.random.normal(ks[2], (B, S, H, D))).astype(dtype)
+    g = -5.0 * jax.nn.sigmoid(4.0 * jax.random.normal(ks[3], (B, S, H, D)))
+    g = jnp.where(jax.random.uniform(ks[4], (B, S, 1, 1)) < 1 / 16, -5.0, g)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, S, H)))
+    seed = jax.random.normal(ks[6], (B, S, H, D), jnp.float32)
+    args = (q, k, v, g, beta)
+
+    def by_positions(q, k, v, g, beta):
+        f32 = lambda x: x.astype(jnp.float32)
+        return jax.lax.map(lambda a: reference.delta_rule(
+            f32(a[0]), f32(a[1]), f32(a[2]), a[3], a[4], 64),
+            (q, k, v, g, beta))
+
+    def plain(q, k, v, g, beta):
+        return kda._plain(q, k, kda._scaled(k, beta), kda._scaled(v, beta),
+                          g, C)
+
+    def both(rule):
+        return jax.jit(rule), jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(rule(*a).astype(jnp.float32) * seed),
+            tuple(range(5))))
+
+    rel = lambda got, want: round(float(
+        np.max(np.abs(np.asarray(got, np.float32)
+                      - np.asarray(want, np.float32)))
+        / np.max(np.abs(np.asarray(want, np.float32)))), 5)
+    width = jnp.dtype(dtype).itemsize
+    read = 3 * D * width + D * 4 + 4
+    least = lambda b: round(B * S * H * b / 819e9 * 1e3, 4)
+    exact = both(by_positions)
+    # traced here: its einsums in float32, not in bfloat16 passes
+    with jax.default_matmul_precision("highest"):
+        want = (exact[0](*args), *exact[1](*args)[1])
+    kept = kda._STEP_CHUNKS
+    forms = [("recurrence", None), ("plain", None)] \
+        + [("kernels", n) for n in KDA_STEP_CHUNKS]
+    for form, chunks in forms:
+        if chunks:
+            kda._STEP_CHUNKS = chunks
+            jax.clear_caches()
+        forward, grad = exact if form == "recurrence" else both(
+            plain if form == "plain" else
+            lambda *a: kda.kda(*a, chunk=C))
+        line = {"case": name, "form": form, "dtype": jnp.dtype(dtype).name,
+                "step_chunks": chunks, "kept": chunks == kept,
+                "fwd_ms": busy_ms(forward, *args),
+                "fwd_bwd_ms": busy_ms(grad, *args),
+                "fwd_kernel_ms": kernel_ms(forward, *args),
+                "fwd_bwd_kernel_ms": kernel_ms(grad, *args),
+                "least_fwd_ms": least(read + D * width),
+                "least_fwd_bwd_ms": least(3 * read + 2 * D * width),
+                "mosaic_kernels": grad.lower(*args).compile().as_text(
+                    ).count('custom_call_target="tpu_custom_call"')}
+        got = (forward(*args), *grad(*args)[1])
+        line["rel_err"] = {what: rel(g_, w_) for what, g_, w_ in zip(
+            ("o", "dq", "dk", "dv", "dg", "dbeta"), got, want)}
+        yield line
+    kda._STEP_CHUNKS = kept
+    jax.clear_caches()
+
+
 def window_case(name, dtype):
     """One line a rule (the window, then none) and a tile (`_auto_tiles`',
     then `WINDOW_TILES`) of ``flash_attention_bshd`` at the case's shape:
@@ -1524,7 +1624,7 @@ def main():
                                  *SSD_CASES, *TARGET_CASES, *SCORES_CASES,
                                  *SELECT_CASES, *HEAD_CASES,
                                  *GATENORM_CASES, *CONV_CASES, *WINDOW_CASES,
-                                 *SSCAN_CASES],
+                                 *SSCAN_CASES, *KDA_CASES],
                         help=f"run these only ({', '.join(CASES)}, "
                              f"{', '.join(WINDOW_CASES)}, "
                              f"{', '.join((*MOE_CASES, *MOE_ALL_CASES))}, "
@@ -1536,7 +1636,8 @@ def main():
                              f"{', '.join(HEAD_CASES)}, "
                              f"{', '.join(GATENORM_CASES)}, "
                              f"{', '.join(CONV_CASES)}, "
-                             f"{', '.join(SSCAN_CASES)}; default: all)")
+                             f"{', '.join(SSCAN_CASES)}, "
+                             f"{', '.join(KDA_CASES)}; default: all)")
     args = parser.parse_args()
     swept = [*SWEEP, *SSD_SWEEP, *TARGET_SWEEP, *SCORES_SWEEP, *SELECT_SWEEP]
     if args.sweep and set(args.sweep) - set(swept):
@@ -1544,7 +1645,7 @@ def main():
     known = [*CASES, *MOE_CASES, *MOE_ALL_CASES, *SHORTCONV_CASES,
              *SSD_CASES, *TARGET_CASES, *SCORES_CASES, *SELECT_CASES,
              *HEAD_CASES, *GATENORM_CASES, *CONV_CASES, *WINDOW_CASES,
-             *SSCAN_CASES]
+             *SSCAN_CASES, *KDA_CASES]
     if set(args.cases) - set(known):
         parser.error(f"--cases: no such case in {known}")
 
@@ -1557,12 +1658,14 @@ def main():
         _auto_tiles,
     )
     from ray_tpu.ops import flash_attention as fa
+    from ray_tpu.ops.kda import KdaFallbackWarning
     from ray_tpu.ops.ssd import SsdFallbackWarning
     from ray_tpu.util.compile_cache import ensure_compile_cache
 
     ensure_compile_cache()
     warnings.simplefilter("error", AttentionFallbackWarning)
     warnings.simplefilter("error", SsdFallbackWarning)
+    warnings.simplefilter("error", KdaFallbackWarning)
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         sys.exit(f"no TPU: jax found {dev.platform!r}")
@@ -1738,6 +1841,17 @@ def main():
                     2 if line["form"] == "kernels" else 0)
             if not ok:
                 failed.append(f"{name}:{line['form']}:{line['positions']}")
+            print(json.dumps({**line, "ok": ok,
+                              "device_kind": dev.device_kind}), flush=True)
+    for name in KDA_CASES:
+        for line in kda_case(name, jnp.bfloat16) \
+                if name in args.cases else ():
+            # the value's forward, the states' pass and the backward kernel
+            ok = max(line["rel_err"].values()) < TOLERANCE \
+                and line["mosaic_kernels"] == (
+                    3 if line["form"] == "kernels" else 0)
+            if not ok:
+                failed.append(f"{name}:{line['form']}:{line['step_chunks']}")
             print(json.dumps({**line, "ok": ok,
                               "device_kind": dev.device_kind}), flush=True)
     if failed:
