@@ -53,7 +53,9 @@ latent_up,kernel,gate,out}, ffn/dense, ffn/moe/{route,dispatch,experts,
 combine,shared}, head_and_loss, optimizer_update, routing_bias_update.
 Counted on the job timeline as the step is traced: `kda.layers`,
 `kda.rule_kernel`, `kda.rule_plain`, `kda.bwd_kernel` (`ops/kda.py`),
-`moe.route_groups` (`ops/moe.py`), `attention.gated`.
+`kda.head_norm_rows_fused` (`ops/gated_norm.py`: the rows of q's and k's L2
+norms and of the head's norm whose kernels ran, three calls a traced KDA
+layer), `moe.route_groups` (`ops/moe.py`), `attention.gated`.
 """
 
 from __future__ import annotations
@@ -81,6 +83,7 @@ from ray_tpu.models.layers import (
     trunk,
     unit_scale,
 )
+from ray_tpu.ops.gated_norm import head_rms_norm
 from ray_tpu.ops.kda import kda
 from ray_tpu.ops.moe import (
     ROUTING_BIAS,
@@ -245,14 +248,44 @@ def _l2(x, eps):
                              + eps)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _bounded(f, rate, bias, bound):
+    """bound sigmoid(rate (f + bias)) in float32: f (B, S, C) rows, rate and
+    bias (C,) a lane.  Its own backward rule for the two vectors' gradients,
+    the columns' sums of (rows, C) products: as `jnp.sum`s XLA took them
+    over a (B, S, H, D) view whose float32 tiles it re-laid the rows for,
+    and pulled the replayed map into the same view (PERF.md section 6, PR
+    66); as products with a row of ones they ride the pass that makes df."""
+    return bound * jax.nn.sigmoid(rate * (f.astype(jnp.float32) + bias))
+
+
+def _bounded_fwd(f, rate, bias, bound):
+    return _bounded(f, rate, bias, bound), (f, rate, bias)
+
+
+def _bounded_bwd(bound, inputs, dg):
+    f, rate, bias = inputs
+    C = f.shape[-1]
+    x = f.astype(jnp.float32) + bias
+    s = jax.nn.sigmoid(rate * x)
+    t = dg * (bound * s * (1 - s))
+    dx = t * rate
+    ones = jnp.ones((1, f.size // C), jnp.float32)
+    sums = lambda v: jnp.dot(ones, v.reshape(-1, C), precision="highest")[0]
+    return dx.astype(f.dtype), sums(t * x), sums(dx)
+
+
+_bounded.defvjp(_bounded_fwd, _bounded_bwd)
+
+
 def _decay(f, p, cfg: BailingHybridConfig):
     """u W_f (B, S, H D) -> g (B, S, H, D) float32, the log of the decay a
     key channel, in (`gate_bound`, 0)."""
     B, S, _ = f.shape
     H, D = cfg.n_head, cfg.head_dim
-    x = (f.astype(jnp.float32) + p["dt_bias"]).reshape(B, S, H, D)
-    return cfg.gate_bound * jax.nn.sigmoid(
-        jnp.exp(p["A_log"])[:, None] * x)
+    g = _bounded(f, jnp.repeat(jnp.exp(p["A_log"]), D), p["dt_bias"],
+                 cfg.gate_bound)
+    return g.reshape(B, S, H, D)
 
 
 def _head_norm(o, gain, eps):
@@ -276,8 +309,45 @@ def _out_gate(gate):
     return jax.nn.sigmoid(gate.astype(jnp.float32))
 
 
+# `_l2`, `_head_norm` and `_out_gate` are the mixer's norms on a (B, S, H, D)
+# view, as PR 65 ran them: what the two functions below are tested against,
+# and names the benchmark's seeded faults patch
+# (`benchmark/tests/bailing_hybrid_faults.py`), so a mixer traced while one
+# of them is not its own runs the view with it
+_VIEWED = (_l2, _head_norm, _out_gate)
+
+
+def _unit(x, cfg: BailingHybridConfig, scale=1.0):
+    """x (B, S, H D) as the convolution wrote it -> each head's D lanes over
+    their L2 norm, times ``scale``, in x's type: `_l2` as
+    `ops/gated_norm.py`'s second rule over the rows as they lie,
+    x rsqrt(sum x^2 + eps) = x rsqrt(mean x^2 + eps / D) D^-1/2."""
+    H, D = cfg.n_head, cfg.head_dim
+    if _l2 is not _VIEWED[0]:
+        viewed = _l2(x.reshape(*x.shape[:2], H, D), cfg.l2_eps) * scale
+        return viewed.astype(x.dtype).reshape(x.shape)
+    return head_rms_norm(x, scale * D ** -0.5, H, cfg.l2_eps / D)
+
+
+def _gated_head_norm(o, gate, gain, cfg: BailingHybridConfig):
+    """o (B, S, H D) as the rule wrote it, gate = u W_g its like, gain (D,)
+    the heads share -> RMSNorm over each head's D of o, times sigmoid(gate),
+    in o's type, by the same rule: the gain tiled a head, so that the
+    tiling's transpose sums the heads' gradients."""
+    H, D = cfg.n_head, cfg.head_dim
+    if (_head_norm, _out_gate) != _VIEWED[1:]:
+        viewed = lambda x: x.reshape(*x.shape[:2], H, D)
+        out = _head_norm(viewed(o), gain, cfg.rms_eps) \
+            * _out_gate(viewed(gate))
+        return out.astype(o.dtype).reshape(o.shape)
+    return head_rms_norm(o, jnp.tile(gain, H), H, cfg.rms_eps, gate)
+
+
 def _kda_mixer(u, p, cfg: BailingHybridConfig):
-    """u (B, S, E) the normed stream -> (B, S, E): the held heads' part."""
+    """u (B, S, E) the normed stream -> (B, S, E): the held heads' part.
+    Between the convolution's kernel and W_o a head stays 128 lanes of a
+    (B, S, H D) row: the rule's (B, S, H, D) operands are views that its
+    own `_flat` undoes, and nothing reduces over one."""
     B, S, _ = u.shape
     H, D = cfg.n_head, cfg.head_dim
     kernel = lambda name: p[name]["kernel"].astype(u.dtype)
@@ -294,17 +364,16 @@ def _kda_mixer(u, p, cfg: BailingHybridConfig):
         q, k, v = named(causal_conv(qkv, taps, jax.nn.silu,
                                     widths=(H * D,) * 3), "kda/conv")
     with jax.named_scope("gate"):
-        q = (_l2(heads(q), cfg.l2_eps) * _query_scale(cfg)).astype(u.dtype)
-        k = _l2(heads(k), cfg.l2_eps).astype(u.dtype)
+        q = _unit(q, cfg, _query_scale(cfg))
+        k = _unit(k, cfg)
         g = _decay(f, p, cfg)
         beta = _beta(b)
     with jax.named_scope("rule"):
-        o = named(kda(q, k, heads(v), g, beta, chunk=cfg.kda_chunk),
-                  "kda/rule")
+        o = named(kda(heads(q), heads(k), heads(v), g, beta,
+                      chunk=cfg.kda_chunk), "kda/rule")
     with jax.named_scope("gate_norm"):
-        o = _head_norm(o, p["head_norm"]["scale"], cfg.rms_eps) \
-            * _out_gate(heads(gate))
-        o = o.astype(u.dtype).reshape(B, S, H * D)
+        o = _gated_head_norm(o.reshape(B, S, H * D), gate,
+                             p["head_norm"]["scale"], cfg)
     with jax.named_scope("out_proj"):
         return named(o @ kernel("o_proj"), "kda/out_proj")
 

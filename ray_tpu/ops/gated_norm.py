@@ -1,11 +1,42 @@
-"""Mamba-2's `MambaRMSNormGated`: the gate first, g = y * silu(z), THEN an
-RMSNorm over each of G runs of C / G channels, times the gain.  A Pallas
-(Mosaic) kernel a pass, forward and backward, under one `jax.custom_vjp`.
+"""Two rules of a gate and an RMSNorm over runs of a row's channels, each a
+Pallas (Mosaic) kernel a pass, forward and backward, under one
+`jax.custom_vjp`, over the (rows, C) arrays as they lie.  They share the
+grid, the blocks, the row loop and the choice of where a kernel runs; the
+arithmetic is each rule's own kernel bodies, and which runs is which function
+a model calls.
+
+**`gated_rms_norm`**, Mamba-2's `MambaRMSNormGated`: the gate first, g = y *
+silu(z), THEN an RMSNorm over each of G runs of C / G channels, times the
+gain.
 
     out = g * rsqrt(mean_group(g^2) + eps) * scale,   g = y * silu(z)
 
-y and z (..., C); scale (C,).  Statistics and products in float32; the
-result in y's type.  z may be handed over as the first C columns of a wider
+**`head_rms_norm`**, a Kimi Delta Attention mixer's norms
+(`models/bailing_hybrid.py`): an RMSNorm over each of H heads' C / H lanes
+FIRST, times a gain, and an optional gate BEHIND it, a sigmoid:
+
+    out = x * rsqrt(mean_head(x^2) + eps) * gain [* sigmoid(z)]
+
+the head's norm with its output gate, and with a constant gain and no gate an
+L2 norm (x rsqrt(sum x^2 + e) = x rsqrt(mean x^2 + e / D) D^-1/2).  Its
+kernels follow the first rule's below in everything but the arithmetic: the
+residuals are the inputs, n = x r is made again, and with dn = d out * gain
+[* s], s = sigmoid(z):
+
+    dx = r (dn - n mean_head(dn n)),   dz = d out (n gain) s (1 - s),
+    d gain = sum_rows d out n [s]
+
+A constant gain is a number in the kernel: no block, no gradient.  A head
+here is ONE 128-lane tile, so a row takes 16 sums across lanes where
+Mamba-2's takes 8 over four tiles each; on the chip (`tools/chip_kernels.py
+--cases headnorm_16k`, PR 66) the gated forward still takes its bytes' time
+over 0.76 and both kernels over 0.88, and the sums as products with a (128,
+128) block of ones on the idle MXU were slower (float32 `HIGHEST` 0.49 ms
+forward for 0.32, three bfloat16 passes by hand 0.39; backward 2.3 for
+0.43).
+
+The first rule in full.  y and z (..., C); scale (C,).  Statistics and
+products in float32; the result in y's type.  z may be handed over as the first C columns of a wider
 array (Mamba-2's z is the head of W_in's result [z | xBC | dt]): the kernels
 read those columns where they lie, and XLA slices nothing out for them.
 
@@ -41,9 +72,9 @@ other shape runs `_reference`, the plain jax the kernels are tested and
 timed beside, which is also what any platform but a TPU runs beyond the
 interpreter's sizes (`ops.by_platform`).
 
-Counts itself on the job timeline as the step is traced:
-`ssm.gate_norm_rows_fused`, the rows whose gate and norm the kernels make
-(0 where the shape took the plain form).
+Each rule counts itself on the job timeline as the step is traced:
+`ssm.gate_norm_rows_fused` and `kda.head_norm_rows_fused`, the rows whose
+norm the kernels make (0 where the shape took the plain form).
 """
 
 from __future__ import annotations
@@ -71,7 +102,13 @@ _SUB = 16
 # 1.604 (0.57), 512 0.597 / 1.603 (0.54); 1,024 want 80 MiB of VMEM and
 # are refused.  The bytes alone take 0.492 / 1.311.  256: level with 512
 # at half its VMEM, 20 MiB for the backward's five blocks twice over, which
-# is more than the 16 MiB a kernel gets unasked
+# is more than the 16 MiB a kernel gets unasked.  The second rule at
+# (16384, 2048) bfloat16 in 16 heads (`--cases headnorm_16k`, PR 66),
+# gated / as an L2 norm, forward ms and both kernels' ms: 128 rows 0.337 and
+# 0.776 / 0.233 and 0.507, 256 0.323 and 0.748 / 0.212 and 0.484, 512 0.316
+# and 0.739 / 0.210 and 0.484, 1,024 0.316 and 0.747 / 0.214 and 0.494; the
+# bytes alone 0.246 and 0.655 / 0.164 and 0.410.  256 there too: 512 is 1 %
+# ahead on one rule and level on the other
 _ROW_TILE = 256
 
 _COMPILER_PARAMS = pltpu.CompilerParams(
@@ -168,13 +205,24 @@ def _backward_kernel(y_ref, z_ref, scale_ref, dout_ref, dy_ref, dz_ref,
 def _specs(y, z, scale, tile):
     """The grid, a block of ``tile`` whole rows (of z: the first C columns
     of however many it has), the gain's block, and y, z and the gain as the
-    blocks cut them: (rows, C), (rows, C or more), (1, C) float32."""
+    blocks cut them: (rows, C), (rows, C or more), (1, C) float32; a z or a
+    gain that is None stays None."""
     C = y.shape[-1]
     rows = y.size // C
     block = pl.BlockSpec((tile, C), lambda i: (i, 0))
     return ((rows // tile,), block, pl.BlockSpec((1, C), lambda i: (0, 0)),
-            (y.reshape(rows, C), z.reshape(rows, z.shape[-1]),
-             scale.astype(_F32).reshape(1, C)))
+            (y.reshape(rows, C),
+             None if z is None else z.reshape(rows, z.shape[-1]),
+             None if scale is None else scale.astype(_F32).reshape(1, C)))
+
+
+def _padded(dz, z, C):
+    """dz (rows, C) in z's own shape, zeros behind its first C columns, as
+    the cotangent of a slice of it is: XLA then folds it into the sum with
+    the other parts' (padded as (rows, width) it was an operation of its
+    own, 0.82 ms a layer on nemotron)."""
+    return jnp.pad(dz.reshape(*z.shape[:-1], C),
+                   ((0, 0),) * (z.ndim - 1) + ((0, z.shape[-1] - C),))
 
 
 @functools.partial(jax.jit,
@@ -208,12 +256,7 @@ def _norm_backward(y, z, scale, dout, *, groups, eps, tile, interpret=False):
                    jax.ShapeDtypeStruct((8, C), _F32)],
         compiler_params=_COMPILER_PARAMS, interpret=interpret,
     )(*operands, dout.reshape(flat))
-    # padded in z's own shape, as the cotangent of a slice of it is: XLA
-    # then folds it into the sum with the other parts' (padded as (rows,
-    # width) it was an operation of its own, 0.82 ms a layer on nemotron)
-    dz = jnp.pad(dz.reshape(*z.shape[:-1], C),
-                 ((0, 0),) * (z.ndim - 1) + ((0, z.shape[-1] - C),))
-    return (dy.reshape(y.shape), dz,
+    return (dy.reshape(y.shape), _padded(dz, z, C),
             jnp.sum(dscale, axis=0).astype(scale.dtype))
 
 
@@ -259,3 +302,204 @@ def gated_rms_norm(y, z, scale, groups, eps):
     if tile is None:
         return _reference(y, z, scale, groups, eps)
     return _kernels(y, z, scale, (groups, eps, tile))
+
+
+# ---------------------------------------------------------------------------
+# the second rule: an RMSNorm a head, the gate BEHIND it
+# ---------------------------------------------------------------------------
+
+def _head_reference(x, z, gain, heads, eps):
+    """The second rule in plain jax, x (..., C), z its like, wider or None,
+    gain (C,) or a number: what its kernels are held to and what a shape
+    they decline runs."""
+    v = x.astype(_F32)
+    parts = v.reshape(*v.shape[:-1], heads, v.shape[-1] // heads)
+    parts = parts * jax.lax.rsqrt(
+        jnp.mean(jnp.square(parts), axis=-1, keepdims=True) + eps)
+    out = parts.reshape(v.shape) * gain
+    if z is not None:
+        out = out * jax.nn.sigmoid(z[..., :x.shape[-1]].astype(_F32))
+    return out.astype(x.dtype)
+
+
+def _head_mean(v):
+    """v (rows, a head's lanes) float32 -> the mean over the lanes a row,
+    (rows, 1)."""
+    return jnp.mean(v, axis=-1, keepdims=True)
+
+
+def _head_refs(refs, gated, const):
+    """A kernel's leading refs as (x, z or None, the gain or None, the
+    rest): z is there where the rule is gated, the gain's block where the
+    gain is no constant."""
+    x_ref, *rest = refs
+    z_ref = rest.pop(0) if gated else None
+    gain_ref = rest.pop(0) if const is None else None
+    return x_ref, z_ref, gain_ref, rest
+
+
+def _head_forward_kernel(*refs, heads, eps, gated, const):
+    x_ref, z_ref, gain_ref, (out_ref,) = _head_refs(refs, gated, const)
+    rows, C = x_ref.shape
+    width = C // heads
+
+    def body(at):
+        for k in range(heads):
+            cols = pl.ds(k * width, width)
+            x = x_ref[at, cols].astype(_F32)
+            n = x * jax.lax.rsqrt(_head_mean(x * x) + eps)
+            out = n * (const if gain_ref is None else gain_ref[:, cols])
+            if gated:
+                out = out * jax.nn.sigmoid(z_ref[at, cols].astype(_F32))
+            out_ref[at, cols] = out.astype(out_ref.dtype)
+
+    _over_rows(rows, body)
+
+
+def _head_backward_kernel(*refs, heads, eps, gated, const):
+    x_ref, z_ref, gain_ref, (dout_ref, dx_ref, *rest) = _head_refs(
+        refs, gated, const)
+    dz_ref = rest.pop(0) if gated else None
+    rows, C = x_ref.shape
+    width = C // heads
+
+    if gain_ref is not None:
+        dgain_ref, = rest
+
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            dgain_ref[...] = jnp.zeros_like(dgain_ref)
+
+    def body(at):
+        for k in range(heads):
+            cols = pl.ds(k * width, width)
+            x = x_ref[at, cols].astype(_F32)
+            dout = dout_ref[at, cols].astype(_F32)
+            r = jax.lax.rsqrt(_head_mean(x * x) + eps)
+            n = x * r
+            gain = const if gain_ref is None else gain_ref[:, cols]
+            dgain_rows = dout * n
+            dn = dout * gain
+            if gated:
+                s = jax.nn.sigmoid(z_ref[at, cols].astype(_F32))
+                dz_ref[at, cols] = (
+                    dn * n * (s * (1 - s))).astype(dz_ref.dtype)
+                dgain_rows, dn = dgain_rows * s, dn * s
+            if gain_ref is not None:
+                dgain_ref[:, cols] += sum(
+                    dgain_rows[i:i + 8] for i in range(0, _SUB, 8))
+            dx_ref[at, cols] = (
+                r * (dn - n * _head_mean(dn * n))).astype(dx_ref.dtype)
+
+    _over_rows(rows, body)
+
+
+_HEAD_STATIC = ("heads", "eps", "tile", "const", "interpret")
+
+
+def _head_specs(x, z, gain, tile):
+    """`_specs` with what is None left out: the grid, a rows' block, the
+    blocks of the operands that are there, and those operands."""
+    grid, block, gain_block, operands = _specs(x, z, gain, tile)
+    there = [(spec, a) for spec, a in zip(
+        (block, block, gain_block), operands) if a is not None]
+    return grid, block, [spec for spec, _ in there], [a for _, a in there]
+
+
+@functools.partial(jax.jit, static_argnames=_HEAD_STATIC)
+def _head_forward(x, z, gain, *, heads, eps, tile, const, interpret=False):
+    """x (..., C), z (..., C or more) or None, gain (C,) or None where
+    ``const`` is the gain -> out in x's shape and type."""
+    grid, block, in_specs, operands = _head_specs(x, z, gain, tile)
+    return pl.pallas_call(
+        functools.partial(_head_forward_kernel, heads=heads, eps=eps,
+                          gated=z is not None, const=const),
+        grid=grid, in_specs=in_specs, out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(operands[0].shape, x.dtype),
+        compiler_params=_COMPILER_PARAMS, interpret=interpret,
+    )(*operands).reshape(x.shape)
+
+
+@functools.partial(jax.jit, static_argnames=_HEAD_STATIC)
+def _head_backward(x, z, gain, dout, *, heads, eps, tile, const,
+                   interpret=False):
+    """-> (dx, dz, d gain), each in its primal's shape and type, None for
+    a z or a gain that is None."""
+    C = x.shape[-1]
+    grid, block, in_specs, operands = _head_specs(x, z, gain, tile)
+    flat = operands[0].shape
+    results = [(block, jax.ShapeDtypeStruct(flat, x.dtype))]
+    if z is not None:
+        results.append((block, jax.ShapeDtypeStruct(flat, z.dtype)))
+    if gain is not None:
+        results.append((pl.BlockSpec((8, C), lambda i: (0, 0)),
+                        jax.ShapeDtypeStruct((8, C), _F32)))
+    dx, *rest = pl.pallas_call(
+        functools.partial(_head_backward_kernel, heads=heads, eps=eps,
+                          gated=z is not None, const=const),
+        grid=grid, in_specs=in_specs + [block],
+        out_specs=[spec for spec, _ in results],
+        out_shape=[shape for _, shape in results],
+        compiler_params=_COMPILER_PARAMS, interpret=interpret,
+    )(*operands, dout.reshape(flat))
+    dz = None if z is None else _padded(rest.pop(0), z, C)
+    dgain = None if gain is None else jnp.sum(
+        rest.pop(0), axis=0).astype(gain.dtype)
+    return dx.reshape(x.shape), dz, dgain
+
+
+def _head_plain(static):
+    """`_head_reference` over a kernel's operands (x, z or None, the gain or
+    None where it is the constant)."""
+    heads, eps, _, const = static
+    return lambda x, z, gain: _head_reference(
+        x, z, const if gain is None else gain, heads, eps)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _head_kernels(x, z, gain, static):
+    return _head_kernels_fwd(x, z, gain, static)[0]
+
+
+def _head_kernels_fwd(x, z, gain, static):
+    heads, eps, tile, const = static
+    out = by_platform(
+        functools.partial(_head_forward, heads=heads, eps=eps, tile=tile,
+                          const=const),
+        _head_plain(static), x, z, gain)
+    return out, (x, z, gain)
+
+
+def _head_kernels_bwd(static, inputs, dout):
+    heads, eps, tile, const = static
+
+    def reference(*a):
+        *inputs, dout = a
+        return jax.vjp(_head_plain(static), *inputs)[1](dout)
+
+    return by_platform(
+        functools.partial(_head_backward, heads=heads, eps=eps, tile=tile,
+                          const=const),
+        reference, *inputs, dout)
+
+
+_head_kernels.defvjp(_head_kernels_fwd, _head_kernels_bwd)
+
+
+def head_rms_norm(x, gain, heads, eps, z=None):
+    """-> out (..., C) in x's type: the second rule, by its kernels where
+    the shape lets them (`_row_tile`) and by `_head_reference` elsewhere.
+    gain: (C,) a lane, or a number for every lane, which is a constant of
+    the rule and has no gradient; z: None for no gate, (..., C), or wider
+    with the gate in its first C columns."""
+    C = x.shape[-1]
+    rows = x.size // C
+    tile = _row_tile(rows, C, heads)
+    runs = tile and (interpreted(x) or jax.default_backend() == "tpu")
+    # on every timeline that has such a norm, a 0 too
+    tracing.count("kda.head_norm_rows_fused", rows if runs else 0)
+    if tile is None:
+        return _head_reference(x, z, gain, heads, eps)
+    const = float(gain) if isinstance(gain, (int, float)) else None
+    return _head_kernels(x, z, None if const is not None else gain,
+                         (heads, eps, tile, const))

@@ -55,6 +55,11 @@ beta k, beta v and g are read where they lie, a head's 128 lanes of
 (B, S, H K); the state, transposed (V, K) so that the decay of a key channel
 is a factor a lane, lives in a VMEM scratch.  beta never enters a kernel:
 XLA makes beta k and beta v (`_scaled`) and takes their cotangents apart.
+Between `kda`'s two ends everything is held as those (B, S, H K) rows, the
+`custom_vjp`'s operands and cotangents included, and a head's beta reaches
+its lanes by a product with a 0 / 1 matrix (`_whose`): a reduction or a
+broadcast over a (B, S, H, K) view makes XLA re-tile the float32 rows, a
+head's 128 lanes into a tile of their own, and re-tile them back.
 The backward is a kernel of its own over the same grid from last to first.
 Its residuals are the forward's INPUTS: a first pass makes the state that
 entered each chunk again (the forward kernel without its O), the second
@@ -458,36 +463,62 @@ def _runs_kernels(q) -> bool:
     return interpreted(q) or jax.default_backend() == "tpu"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _kernels(q, k, kb, vb, g, C):
-    return _kernels_fwd(q, k, kb, vb, g, C)[0]
+def _heads(arrays, H):
+    """(B, S, H D) each -> (B, S, H, D) each: `_flat`'s inverse."""
+    return tuple(x.reshape(*x.shape[:2], H, -1) for x in arrays)
 
 
-def _kernels_fwd(q, k, kb, vb, g, C):
+# The rule over (B, S, H D) rows, H heads a row: its operands and their
+# cotangents stay what the kernels read and write, so the sum of k's two
+# cotangents (the rule's own and beta k's) is one of rows as they lie.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _kernels(q, k, kb, vb, g, H, C):
+    return _kernels_fwd(q, k, kb, vb, g, H, C)[0]
+
+
+def _kernels_fwd(q, k, kb, vb, g, H, C):
     o = by_platform(
-        lambda *a, interpret: _forward(*a, C=C, interpret=interpret),
-        lambda *a: _plain(*a, C), q, k, kb, vb, g)
+        lambda *a, interpret: _flat(
+            _forward(*_heads(a, H), C=C, interpret=interpret)),
+        lambda *a: _flat(_plain(*_heads(a, H), C)), q, k, kb, vb, g)
     return o, (q, k, kb, vb, g)
 
 
-def _kernels_bwd(C, inputs, do):
+def _kernels_bwd(H, C, inputs, do):
     def reference(*a):
         *inputs, do = a
-        return jax.vjp(lambda *v: _plain(*v, C), *inputs)[1](do)
+        return jax.vjp(lambda *v: _flat(_plain(*_heads(v, H), C)),
+                       *inputs)[1](do)
 
     if _runs_kernels(inputs[0]):
         tracing.count("kda.bwd_kernel")
     return by_platform(
-        lambda *a, interpret: _backward(*a, C=C, interpret=interpret),
+        lambda *a, interpret: tuple(_flat(d) for d in _backward(
+            *_heads(a, H), C=C, interpret=interpret)),
         reference, *inputs, do)
 
 
 _kernels.defvjp(_kernels_fwd, _kernels_bwd)
 
 
+def _whose(H, D):
+    """(H, H D) float32 of 0 and 1: which of a row's lanes are which head's."""
+    return jnp.repeat(jnp.eye(H, dtype=_F32), D, axis=1)
+
+
 def _scaled(x, beta):
-    """beta x, a scalar a head and position, in x's type."""
-    return (x.astype(_F32) * beta.astype(_F32)[..., None]).astype(x.dtype)
+    """beta x, x (B, S, H, D) or the same as (B, S, H D) rows, beta
+    (B, S, H) a scalar a head and position, in x's shape and type.  beta is
+    spread over a head's D lanes, and d beta summed back from them, by a
+    product with `_whose` over the rows as they lie: as a broadcast over the
+    (B, S, H, D) view and a reduction over its last axis, XLA re-tiled the
+    float32 arrays for them (a head's 128 lanes into a tile of their own,
+    0.67 ms an array at the ling cell's shape: PERF.md section 6, PR 66)."""
+    H = beta.shape[-1]
+    spread = jnp.dot(beta.astype(_F32), _whose(H, x.size // beta.size),
+                     precision=_HIGHEST)
+    rows = x.reshape(spread.shape).astype(_F32)
+    return (rows * spread).astype(x.dtype).reshape(x.shape)
 
 
 def kda(q, k, v, g, beta, chunk=64):
@@ -500,11 +531,13 @@ def kda(q, k, v, g, beta, chunk=64):
     V = v.shape[-1]
     tracing.count("kda.layers")
     C = _chunk_size(S, chunk)
+    # from here on (B, S, H D) rows, as the kernels read them and as a mixer
+    # has them: the reshapes at both ends undo the caller's
+    q, k, v, g = _flat(q), _flat(k), _flat(v), _flat(g.astype(_F32))
     kb, vb = _scaled(k, beta), _scaled(v, beta)
-    g = g.astype(_F32)
     pad = -S % C
     if pad:
-        q, k, kb, vb, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        q, k, kb, vb, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
                            for x in (q, k, kb, vb, g))
     problem = _kernel_problem(K, V, C)
     if problem:
@@ -513,9 +546,9 @@ def kda(q, k, v, g, beta, chunk=64):
             f"chunk {C} runs the plain chunked form: {problem}",
             KdaFallbackWarning, stacklevel=2)
         tracing.count("kda.rule_plain")
-        o = _plain(q, k, kb, vb, g, C)
+        o = _flat(_plain(*_heads((q, k, kb, vb, g), H), C))
     else:
         tracing.count("kda.rule_kernel" if _runs_kernels(q)
                       else "kda.rule_plain")
-        o = _kernels(q, k, kb, vb, g, C)
-    return o[:, :S]
+        o = _kernels(q, k, kb, vb, g, H, C)
+    return o.reshape(B, -1, H, V)[:, :S]

@@ -14,6 +14,7 @@ these widths a mixer's output is a thousandth of the residual stream and a
 fault would hide under any tolerance.
 """
 
+import contextlib
 import dataclasses
 import functools
 import warnings
@@ -28,6 +29,7 @@ from benchmark.families.bailing_hybrid import (Family, from_reference,
 from benchmark.harness import registry
 from benchmark.reference import bailing_hybrid as reference
 from benchmark.tests.bailing_hybrid_faults import FAULTS
+from benchmark.tests.mellum_faults import patched
 from ray_tpu.models import bailing_hybrid as model
 from ray_tpu.models import layers
 from ray_tpu.ops.kda import KdaFallbackWarning
@@ -222,6 +224,10 @@ def test_heads_of_128_take_the_kernels_and_match():
             assert tracing.counter("kda.rule_plain") == 0
             assert tracing.counter("kda.bwd_kernel") >= 1
             assert tracing.counter("moe.route_groups") == 1
+            # q's and k's L2 norms and the head's norm with its gate, the
+            # rows of each, a traced KDA layer
+            assert tracing.counter("kda.head_norm_rows_fused") \
+                == 3 * BATCH * SEQ * tracing.counter("kda.layers") > 0
         tracing.timeline_take(job.trace_id)
     ref_params, biases = to_reference(params)
     want = jax.value_and_grad(lambda p: reference.losses(
@@ -230,6 +236,43 @@ def test_heads_of_128_take_the_kernels_and_match():
     want = from_reference(want[1], [jnp.zeros_like(b) for b in biases])
     for g, w in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want)):
         assert max_diff(g, w) < 2e-4 * float(jnp.max(jnp.abs(w))) + 1e-7
+
+
+@pytest.mark.parametrize("head_dim", [16, 128])
+def test_the_flat_path_is_the_norms_on_the_view(head_dim):
+    """`_unit` and `_gated_head_norm` over (B, S, H D) rows against the
+    arithmetic PR 65 ran on a (B, S, H, D) view (`_l2`, `_head_norm`,
+    `_out_gate`: a mixer traced while one of them is not the module's own
+    runs them, which is how the benchmark's seeded faults reach it): the
+    logits and every leaf's gradient.  Heads of 16 take the rule's plain
+    form and count no row; heads of 128 its kernels, interpreted."""
+    cfg = dataclasses.replace(F32, n_layer=2, head_dim=head_dim,
+                              kda_chunk=64 if head_dim == 128 else 32)
+    params, tokens = make_params(cfg=cfg), make_tokens()
+
+    def run():
+        with tracing.timeline_span("train.fit", root=True) as job:
+            out = jax.jit(lambda p: (
+                model.forward(p, tokens[:, :-1], cfg)[0],
+                jax.grad(lambda p: model.loss_fn(
+                    p, {"tokens": tokens}, cfg)[0])(p)))(params)
+            rows = tracing.counter("kda.head_norm_rows_fused")
+        tracing.timeline_take(job.trace_id)
+        return out, rows
+
+    got, rows = run()
+    assert rows == (3 * BATCH * SEQ * 2 if head_dim == 128 else 0)
+    same = lambda f: lambda *a: f(*a)       # the arithmetic, not the object
+    with contextlib.ExitStack() as patches:
+        for name in ("_l2", "_head_norm", "_out_gate"):
+            patches.enter_context(patched(model, name, same))
+        want, viewed_rows = run()
+    assert viewed_rows == 0
+    assert max_diff(got[0], want[0]) < F32_TOL
+    for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got[1])[0],
+                            jax.tree.leaves(want[1])):
+        scale = float(jnp.max(jnp.abs(w))) + 1e-6
+        assert max_diff(g, w) < 2e-4 * scale + 1e-7, jax.tree_util.keystr(path)
 
 
 # -- the seeded faults --------------------------------------------------------

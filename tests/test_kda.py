@@ -179,6 +179,38 @@ def test_a_ragged_length_is_padded_with_positions_that_move_nothing():
         close(g, w, 2e-5)
 
 
+@pytest.mark.parametrize("flat", [False, True], ids=["viewed", "rows"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("D", [128, 48])
+def test_beta_is_spread_and_summed_by_a_product_with_the_lanes_matrix(
+        D, dtype, flat):
+    """`_scaled` against beta broadcast over the (B, S, H, D) view, which
+    it stands for without the view: beta x in x's shape and type, dx, and
+    d beta, the sum over a head's D; x handed over as (B, S, H, D) or as
+    the (B, S, H D) rows the rule holds."""
+    B, S, H = 2, 24, 3
+    ks = jax.random.split(jax.random.PRNGKey(D), 3)
+    x = jax.random.normal(ks[0], (B, S, H, D)).astype(dtype)
+    beta = jax.nn.sigmoid(jax.random.normal(ks[1], (B, S, H)))
+    dout = jax.random.normal(ks[2], x.shape).astype(dtype)
+    handed = (lambda a: a.reshape(B, S, H * D)) if flat else (lambda a: a)
+
+    def viewed(x, beta):
+        return (x.astype(jnp.float32) * beta[..., None]).astype(x.dtype)
+
+    got, vjp = jax.vjp(K._scaled, handed(x), beta)
+    want, want_vjp = jax.vjp(viewed, x, beta)
+    assert got.shape == handed(x).shape and got.dtype == dtype
+    tol = 1e-6 if dtype == jnp.float32 else 2 ** -7
+    close(got.reshape(x.shape), want, tol)
+    (dx, dbeta), (want_dx, want_dbeta) = vjp(handed(dout)), want_vjp(dout)
+    close(dx.reshape(x.shape), want_dx, tol)
+    close(dbeta, want_dbeta, 1e-6)
+    assert "reduce_sum" not in str(jax.make_jaxpr(
+        lambda x, beta: jax.vjp(K._scaled, x, beta)[1](handed(dout)))(
+            handed(x), beta))
+
+
 @pytest.mark.parametrize("sizes, problem", [
     ((128, 128, 64), None), ((128, 128, 32), None), ((128, 128, 16), None),
     ((64, 128, 64), "tile"), ((128, 256, 64), "tile"),

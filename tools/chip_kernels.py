@@ -141,6 +141,16 @@ and the gain), the seconds both took to compile, the least time of the
 bytes, and the largest error of the result and of the three gradients
 relative to the plain form on float32 operands.
 
+``headnorm_16k`` is the same file's second rule alone
+(`ops/gated_norm.py:head_rms_norm`: an RMSNorm a head, the gate behind it) at
+the ling cell's shape, (1, 16384, 2048) in 16 heads of one lane tile, as a
+KDA mixer calls it: ``gated`` (the head's norm with its gain and the output
+gate) and ``l2`` (q's and k's L2 norm: a constant gain, no gate).  A line a
+rule for the plain jax form (`_head_reference`, the (..., 16, 128) view XLA
+re-tiles the rows for) and one for the two Mosaic kernels at each row tile of
+``HEADNORM_TILES`` (``kept``: `_row_tile`'s), with what ``gatenorm_8k``'s
+lines hold.
+
 ``conv_8k`` is Mamba-2's causal convolution alone
 (`ops/causal_conv.py:causal_conv`) at the nemotron cell's shape: 6,144
 channels of 4 taps with a bias and a SiLU, read out of a (2, 8192, 10304)
@@ -250,6 +260,12 @@ GATENORM_CASES = {
     "gatenorm_8k": (2, 8192, 4096, 8),
 }
 GATENORM_TILES = (64, 128, 256, 512)
+# (B, S, C, heads) of one norm a head, and the row tiles its kernels are
+# timed at
+HEADNORM_CASES = {
+    "headnorm_16k": (1, 16384, 2048, 16),
+}
+HEADNORM_TILES = (128, 256, 512, 1024)
 # (B, S, source's width, first column, (x, B, C) widths, taps) of one causal
 # convolution, and the forward's and the backward's (row tile, channel block,
 # turns written out) its kernels are timed at
@@ -964,6 +980,85 @@ def gatenorm_case(name, dtype):
         yield line
 
 
+def headnorm_case(name, dtype):
+    """One norm a head at ``HEADNORM_CASES[name]``, gated and as an L2 norm:
+    a line a rule and a form (forward ms; forward and backward ms of one
+    `jax.grad` in every operand that has a gradient; the seconds both jits
+    took to compile; the Mosaic kernels in them; the least time of the
+    bytes: the gated rule's forward reads x and z and writes the result, its
+    backward reads three and writes two; the L2 norm's read one and write
+    one, read two and write one; largest error of the result and of the
+    gradients relative to the plain form on float32 operands)."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import gated_norm as gn
+
+    B, S, C, H = HEADNORM_CASES[name]
+    D = C // H
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    x, z = (jax.random.normal(k, (B, S, C), dtype) for k in ks[:2])
+    gain = 1 + 0.3 * jax.random.normal(ks[2], (C,), jnp.float32)
+    seed = jax.random.normal(ks[3], (B, S, C), dtype)   # d loss / d out
+    # rule -> (operands, eps, the constant gain, arrays a forward and a
+    # forward + backward move at the least)
+    rules = {"gated": ((x, z, gain), 1e-6, None, 3, 8),
+             "l2": ((x,), 1e-6 / D, D ** -0.5, 2, 5)}
+    rel = lambda g, w: round(float(
+        np.max(np.abs(np.asarray(g, np.float32) - np.asarray(w)))
+        / np.max(np.abs(np.asarray(w)))), 5)
+    peak = 819e9                       # HBM bytes a second, TPU v5e
+    array = B * S * C * jnp.dtype(dtype).itemsize
+    for rule, (operands, eps, const, moved, moved_both) in rules.items():
+        def plain(x, z=None, gain=const):
+            return gn._head_reference(x, z, gain, H, eps)
+
+        def kernels(tile):
+            return lambda x, z=None, gain=None: gn._head_kernels(
+                x, z, gain, (H, eps, tile, const))
+
+        def both(f):
+            return jax.jit(f), jax.jit(jax.value_and_grad(
+                lambda *a: jnp.sum(f(*a[:-1]).astype(jnp.float32)
+                                   * a[-1].astype(jnp.float32)),
+                tuple(range(len(operands)))))
+
+        exact = both(plain)
+        f32 = tuple(a.astype(jnp.float32) for a in operands)
+        want = (exact[0](*f32), *exact[1](*f32, seed)[1])
+        kept = gn._row_tile(B * S, C, H)
+        forms = [("plain", plain, None)] + [
+            ("kernel", kernels(tile), tile) for tile in HEADNORM_TILES]
+        for form, f, tile in forms:
+            forward, grad = both(f)
+            began = time.perf_counter()
+            compiled = [forward.lower(*operands).compile(),
+                        grad.lower(*operands, seed).compile()]
+            line = {"case": name, "rule": rule, "form": form,
+                    "compile_s": round(time.perf_counter() - began, 2),
+                    "mosaic_kernels": sum(c.as_text().count(
+                        'custom_call_target="tpu_custom_call"')
+                        for c in compiled),
+                    "fwd_ms": busy_ms(forward, *operands),
+                    "fwd_bwd_ms": busy_ms(grad, *operands, seed),
+                    "least_fwd_ms": round(moved * array / peak * 1e3, 4),
+                    "least_fwd_bwd_ms": round(
+                        moved_both * array / peak * 1e3, 4)}
+            if tile:
+                # the two kernels alone, without the loss that reads the
+                # result
+                line.update(tile=tile, kept=tile == kept,
+                            fwd_bwd_kernels_ms=kernel_ms(
+                                grad, *operands, seed))
+            got = (forward(*operands), *grad(*operands, seed)[1])
+            line["rel_err"] = {what: rel(g, w) for what, g, w in zip(
+                ("out", "dx", "dz", "dgain"), got, want)}
+            yield line
+
+
 def conv_case(name, dtype):
     """One causal convolution at ``CONV_CASES[name]``: a line for each form
     of it, as the module's text says."""
@@ -1623,7 +1718,8 @@ def main():
                                  *SHORTCONV_CASES,
                                  *SSD_CASES, *TARGET_CASES, *SCORES_CASES,
                                  *SELECT_CASES, *HEAD_CASES,
-                                 *GATENORM_CASES, *CONV_CASES, *WINDOW_CASES,
+                                 *GATENORM_CASES, *HEADNORM_CASES,
+                                 *CONV_CASES, *WINDOW_CASES,
                                  *SSCAN_CASES, *KDA_CASES],
                         help=f"run these only ({', '.join(CASES)}, "
                              f"{', '.join(WINDOW_CASES)}, "
@@ -1635,6 +1731,7 @@ def main():
                              f"{', '.join(SELECT_CASES)}, "
                              f"{', '.join(HEAD_CASES)}, "
                              f"{', '.join(GATENORM_CASES)}, "
+                             f"{', '.join(HEADNORM_CASES)}, "
                              f"{', '.join(CONV_CASES)}, "
                              f"{', '.join(SSCAN_CASES)}, "
                              f"{', '.join(KDA_CASES)}; default: all)")
@@ -1644,8 +1741,8 @@ def main():
         parser.error(f"--sweep: no such shape in {sorted(swept)}")
     known = [*CASES, *MOE_CASES, *MOE_ALL_CASES, *SHORTCONV_CASES,
              *SSD_CASES, *TARGET_CASES, *SCORES_CASES, *SELECT_CASES,
-             *HEAD_CASES, *GATENORM_CASES, *CONV_CASES, *WINDOW_CASES,
-             *SSCAN_CASES, *KDA_CASES]
+             *HEAD_CASES, *GATENORM_CASES, *HEADNORM_CASES, *CONV_CASES,
+             *WINDOW_CASES, *SSCAN_CASES, *KDA_CASES]
     if set(args.cases) - set(known):
         parser.error(f"--cases: no such case in {known}")
 
@@ -1810,6 +1907,19 @@ def main():
                     3 if line["form"] == "kernel" else 0)
             if not ok:
                 failed.append(f"{name}:{line['form']}:{line.get('tile')}")
+            print(json.dumps({**line, "ok": ok,
+                              "device_kind": dev.device_kind}), flush=True)
+    for name in HEADNORM_CASES:
+        for line in headnorm_case(name, jnp.bfloat16) \
+                if name in args.cases else ():
+            # a kernel forward; forward and backward under the gradient
+            ok = max(line["rel_err"].values()) < TOLERANCE \
+                and line["mosaic_kernels"] == (
+                    3 if line["form"] == "kernel" else 0)
+            if not ok:
+                failed.append(
+                    f"{name}:{line['rule']}:{line['form']}:"
+                    f"{line.get('tile')}")
             print(json.dumps({**line, "ok": ok,
                               "device_kind": dev.device_kind}), flush=True)
     for name in CONV_CASES:
