@@ -26,6 +26,7 @@ import math
 import os
 import socket
 import sys
+import threading
 import time
 from dataclasses import dataclass
 from typing import Dict, Optional
@@ -240,17 +241,29 @@ def _check_granted_chips(granted: int):
             f"TPU_VISIBLE_CHIPS={os.environ.get('TPU_VISIBLE_CHIPS')!r})")
 
 
-# jax.monitoring's durations, by event, as spans of the job timeline
+# jax.monitoring's durations, by event, as spans of the job timeline, and
+# the kind each is booked under in the set-up account
 _JAX_SPANS = {
     "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
     "/jax/core/compile/backend_compile_duration": "jax.backend_compile",
     "/jax/compilation_cache/cache_retrieval_time_sec": "jax.cache_read",
 }
-_JAX_COUNTERS = {
-    "/jax/compilation_cache/cache_hits": "jax.cache_hits",
-    "/jax/compilation_cache/cache_misses": "jax.cache_misses",
+_KINDS = {"jax.trace": "trace", "jax.lower": "lower",
+          "jax.backend_compile": "compile", "jax.cache_read": "cache_read"}
+# jax's plain events: (the job's counter, what the event says of the compile
+# it fell in).  A compile asks the cache wherever caching is not switched
+# off, directory or none, and only an entry that was written counts as one
+# of jax's misses: asked and not served is a miss where there is a directory
+_JAX_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": (None, "miss"),
+    "/jax/compilation_cache/cache_hits": ("jax.cache_hits", "hit"),
+    "/jax/compilation_cache/cache_misses": ("jax.cache_misses", None),
 }
+# `models/layers.py:train_step`'s function as jax.monitoring names it, traced,
+# and lowered or compiled: what jax takes for a function of this name is the
+# step's in the set-up account, whoever wrote it
+STEP_NAMES = ("train_step", "jit(train_step)")
 # jax reports every function traced inside another's trace, thousands a
 # step: a trace shorter than this is counted (`jax.traces`), not spanned
 _SHORT_TRACE_S = 0.005
@@ -259,54 +272,175 @@ _compiles: Dict[str, int] = {}     # backend compiles, by trace id + function
 _recompile_warned = set()
 
 
+class _JaxEvents:
+    """One thread's open jax events, and what the events it has closed for
+    one job took: the set-up account.
+
+    jax reports a trace, a lowering or a compile twice on the thread that
+    makes it, as it starts and as it ends, and they nest (a kernel traced
+    inside a lowering, a cache read inside a compile, thousands of small
+    jitted functions inside the step's trace).  Between two such reports the
+    thread's time is the OWN time of the innermost open event: its duration
+    less the durations of the events that closed inside it.  Own time is
+    booked by kind and by whose function the OUTERMOST open event is: the
+    step's (`STEP_NAMES`) or another's.  jax keeps a jitted function's trace
+    by its arguments' shapes, so a function called at two sites is booked
+    where it was traced, the first: the account says what the tracer paid,
+    not what a site would cost alone."""
+
+    __slots__ = ("trace_id", "open", "step", "last", "traces", "own",
+                 "step_cache", "listen")
+
+    def __init__(self, trace_id):
+        self.trace_id = trace_id
+        self.open = []      # [span, fun_name, start, own s, cache]
+        self.step = False   # the outermost open event is the step's
+        self.last = 0.0     # when jax last reported on this thread
+        self.traces = 0     # closed under the outermost open event
+        self.own = {}       # (span, the step's) -> seconds
+        self.step_cache = None      # the `cache` of the step's last compile
+        self.listen = 0.0   # seconds the listeners themselves took
+
+    def report(self, now):
+        """jax reports at ``now``: the time since its last report is the
+        innermost open event's own."""
+        if self.open:
+            self.open[-1][3] += now - self.last
+        self.last = now
+
+    def start(self, span, fun_name, start):
+        self.report(start)
+        if not self.open:
+            self.step = fun_name in STEP_NAMES
+        self.open.append([span, fun_name, start, 0.0, "off"])
+
+    def end(self, span, fun_name, seconds):
+        """The open event this end belongs to, closed and booked."""
+        stack = self.open
+        for at in range(len(stack) - 1, -1, -1):
+            if stack[at][0] == span and stack[at][1] == fun_name:
+                if at + 1 < len(stack):     # what an exception left open
+                    del stack[at + 1:]
+                break
+        else:           # jax reported no start: it ends now
+            self.start(span, fun_name, time.time() - seconds)
+        event = stack[-1]
+        self.report(event[2] + seconds)
+        stack.pop()
+        booked = span, self.step
+        self.own[booked] = self.own.get(booked, 0.0) + event[3]
+        return event
+
+
+_jax_thread = threading.local()
+
+
+def _jax_events(ctx) -> _JaxEvents:
+    """The calling thread's account of ``ctx``'s job: another job's is
+    left."""
+    mine = getattr(_jax_thread, "events", None)
+    if mine is None or mine.trace_id != ctx["trace_id"]:
+        mine = _jax_thread.events = _JaxEvents(ctx["trace_id"])
+    return mine
+
+
+def setup_account(ctx) -> dict:
+    """What the calling thread's closed jax events took so far under
+    ``ctx``'s job, as `train.setup` carries it (`train/session.py`)."""
+    mine = _jax_events(ctx)
+    return {
+        "own_us": {f"{kind}/{whose}": int(mine.own.get((span, step), 0) * 1e6)
+                   for step, whose in ((True, "step"), (False, "other"))
+                   for span, kind in _KINDS.items()},
+        "step_cache": mine.step_cache,
+        "listen_us": int(mine.listen * 1e6),
+    }
+
+
 def _listen_to_jax():
     """Runs inside each training worker, once a process: what this process
     pays JAX's tracer, its lowering (Mosaic included), its compiler and
     its compile cache becomes spans and counters of the job whose context
-    is active when jax reports it (end = now, start = now - the seconds
-    reported; a cache read lies inside its `jax.backend_compile`, a
-    function traced inside another's trace inside that one's span).  A
-    function that compiles a second time after the job's first
-    `train.report` is named in one WARNING line: the step recompiled."""
+    is active when jax reports it (a cache read lies inside its
+    `jax.backend_compile`, a function traced inside another's trace inside
+    that one's span), and every event's own time an entry of the thread's
+    set-up account (`_JaxEvents`), spanned or not.  A function that
+    compiles a second time after the job's first `train.report` is named in
+    one WARNING line: the step recompiled."""
     global _jax_listening
     if _jax_listening:
         return
     _jax_listening = True
     import jax
 
-    def on_duration(event, seconds, **kwargs):
-        name = _JAX_SPANS.get(event)
-        ctx = name and tracing.timeline_ctx()
+    def on_start(event, start, **kwargs):
+        span = _JAX_SPANS.get(event)
+        ctx = span and tracing.timeline_ctx()
         if not ctx:
             return
-        if name == "jax.trace":
-            tracing.count("jax.traces")
-            if seconds < _SHORT_TRACE_S:
-                return
-        now = time.time()
-        fun_name = str(kwargs.get("fun_name", ""))
-        tracing.timeline_hop(name, ctx, now - seconds, now,
-                             **({"fun_name": fun_name} if fun_name else {}))
-        if name != "jax.backend_compile":
+        t0 = time.perf_counter()
+        mine = _jax_events(ctx)
+        mine.start(span, str(kwargs.get("fun_name", "")), start)
+        mine.listen += time.perf_counter() - t0
+
+    def on_duration(event, seconds, **kwargs):
+        span = _JAX_SPANS.get(event)
+        ctx = span and tracing.timeline_ctx()
+        if not ctx:
             return
-        tracing.count("jax.compiles")
-        key = ctx["trace_id"] + fun_name
-        seen = _compiles[key] = _compiles.get(key, 0) + 1
-        if (fun_name and seen > 1 and key not in _recompile_warned
-                and tracing.counter("train.reports")):
-            _recompile_warned.add(key)
-            logger.warning(
-                "%s compiled again after the job's first train.report: "
-                "a shape, dtype or static argument of it changes between "
-                "steps", fun_name)
+        t0 = time.perf_counter()
+        mine, fun_name = _jax_events(ctx), str(kwargs.get("fun_name", ""))
+        _, _, start, own, cache = mine.end(span, fun_name, seconds)
+        if span == "jax.trace":
+            mine.traces += 1
+        if mine.traces and not mine.open:   # a lock a root, not an event
+            tracing.count("jax.traces", mine.traces)
+            mine.traces = 0
+        if span != "jax.trace" or seconds >= _SHORT_TRACE_S:
+            attrs = {"own_us": int(own * 1e6), "step": mine.step}
+            if span == "jax.backend_compile":
+                if cache == "miss" \
+                        and not jax.config.jax_compilation_cache_dir:
+                    cache = "off"
+                if mine.step:
+                    mine.step_cache = cache
+                attrs["cache"] = cache
+            _record(span, ctx, fun_name, start, seconds, attrs)
+        mine.listen += time.perf_counter() - t0
 
     def on_event(event, **_):
-        name = _JAX_COUNTERS.get(event)
-        if name:
-            tracing.count(name)
+        counter, cache = _JAX_EVENTS.get(event, (None, None))
+        if counter:
+            tracing.count(counter)
+        mine = cache and getattr(_jax_thread, "events", None)
+        if mine and mine.open and mine.open[-1][4] != "hit" \
+                and mine.open[-1][0] == "jax.backend_compile":
+            mine.open[-1][4] = cache
 
+    jax.monitoring.register_scalar_listener(on_start)
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     jax.monitoring.register_event_listener(on_event)
+
+
+def _record(span, ctx, fun_name, start, seconds, attrs):
+    """A closed jax event as a span of the job's timeline; a compile is
+    counted, and warned of where it is a second one after the job's first
+    report."""
+    if fun_name:
+        attrs["fun_name"] = fun_name
+    tracing.timeline_hop(span, ctx, start, start + seconds, **attrs)
+    if span != "jax.backend_compile":
+        return
+    tracing.count("jax.compiles")
+    key = ctx["trace_id"] + fun_name
+    seen = _compiles[key] = _compiles.get(key, 0) + 1
+    if (fun_name and seen > 1 and key not in _recompile_warned
+            and tracing.counter("train.reports")):
+        _recompile_warned.add(key)
+        logger.warning(
+            "%s compiled again after the job's first train.report: "
+            "a shape, dtype or static argument of it changes between "
+            "steps", fun_name)
 
 
 class JaxBackend(Backend):

@@ -10,7 +10,9 @@ Inside a job the session also keeps the step as the program sees it
 (`_StepWatch`): every interval between two reports of the rank is a
 `train.step` record of the job timeline with what this process did in it,
 and an interval far over the running median is a `train.stall` that names
-where the loop's thread stood.
+where the loop's thread stood.  The rank's first report closes `train.setup`:
+the loop's start to there, by what jax's tracer, lowering, compiler and cache
+took of it and for whose function.
 """
 
 from __future__ import annotations
@@ -92,6 +94,19 @@ def stall_text(record: dict) -> str:
             f"process cpu {ms(a['process_cpu_us'])} ms, "
             f"{a['nivcsw']} pre-emptions, {a['majflt']} major faults"
             + (", in a profiler session" if a["profiled"] else ""))
+
+
+def setup_text(record: dict) -> str:
+    """A `train.setup` record as the worker's line words it, in seconds."""
+    a = record["attributes"]
+    s = lambda *keys: f"{sum(a['own_us'][key] for key in keys) / 1e6:.1f}"
+    others = [key for key in a["own_us"] if key.endswith("/other")]
+    return (f"train: set-up {record['duration_us'] / 1e6:.1f} s to the "
+            f"first report: step trace {s('trace/step')}, lower "
+            f"{s('lower/step')}, compile "
+            f"{s('compile/step', 'cache_read/step')} "
+            f"({a['step_cache'] or 'none'}); other functions "
+            f"{s(*others)}; running {a['run_us'] / 1e6:.1f}")
 
 
 class _StepWatch:
@@ -228,6 +243,7 @@ class _TrainSession:
         # the loop's thread parents its spans under that call's context
         self._trace_ctx = tracing.timeline_ctx()
         self._reports = 0
+        self._loop_start = 0.0      # `train.loop` entered
         # outside a job there is no timeline to keep the steps in
         self._steps = (_StepWatch(context.world_rank) if self._trace_ctx
                        else None)
@@ -255,6 +271,7 @@ class _TrainSession:
                 pass
             with tracing.timeline_span("train.loop", parent=self._trace_ctx,
                                        rank=self.context.world_rank):
+                self._loop_start = time.time()
                 if takes_config:
                     train_fn(config if config is not None else {})
                 else:
@@ -286,8 +303,28 @@ class _TrainSession:
         tracing.count("train.reports")
         ctx = self._steps and tracing.timeline_ctx()
         if ctx:
+            if not self._reports:
+                self._setup_done(ctx)
             self._steps.step(ctx, self._reports, put)
         self._reports += 1
+
+    def _setup_done(self, ctx):
+        """The rank's first report has returned: `train.setup`, the loop's
+        start to now, with what jax's tracer, lowering, compiler and cache
+        took of it on this thread (`backend.py:setup_account`) and, as
+        `run_us`, what is left: the thread executing, waiting on the device
+        or in Python that is no trace.  One INFO line words it."""
+        from ray_tpu.train.backend import setup_account  # imports this file
+
+        end = time.time()
+        took_us = int((end - self._loop_start) * 1e6)
+        attrs = setup_account(ctx)
+        attrs.update(rank=self.context.world_rank,
+                     run_us=took_us - sum(attrs["own_us"].values()))
+        tracing.timeline_hop("train.setup", ctx, self._loop_start, end,
+                             **attrs)
+        logger.info(setup_text({"duration_us": took_us,
+                                "attributes": attrs}))
 
     # ---------------------------------------------------------------- driver side
 

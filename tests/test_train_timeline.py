@@ -251,6 +251,7 @@ def test_a_forced_restart_shows_one_train_restart(ray_train, tmp_path):
     assert len(by_name["train.start_session"]) == 2
     assert len(by_name["raylet.worker_spawn"]) == 2
     assert len(by_name["train.loop"]) == 1
+    assert len(by_name["train.setup"]) == 1     # the new group's own
     # the second group's start lies inside the restart
     second = by_name["train.workers_up"][1]
     assert second["parent_id"] == restart["span_id"]
@@ -359,3 +360,266 @@ def test_a_fit_counts_the_mixture_in_its_timeline(ray_train, tmp_path, held,
     assert counters["moe.experts_held"] == 2 * held
     assert counters["moe.rows_routed"] == 2 * 128 * 3
     assert counters["moe.rows_buffered"] == 2 * buffered
+
+
+# -- the set-up account: what jax's tracer, lowering, compiler and cache take
+# before the first step, booked by the program (`train/backend.py`) ---------
+
+EIGHT = {f"{kind}/{whose}" for whose in ("step", "other")
+         for kind in ("trace", "lower", "compile", "cache_read")}
+
+
+@pytest.fixture
+def listening(monkeypatch):
+    """The listeners on, every trace spanned however short, and what this
+    thread booked for earlier jobs forgotten."""
+    from ray_tpu.train import backend
+
+    backend._listen_to_jax()
+    monkeypatch.setattr(backend, "_SHORT_TRACE_S", 0.0)
+    backend._jax_thread.__dict__.pop("events", None)
+    return backend
+
+
+def _nested_step():
+    """A function whose trace holds a nested jitted call, a `jax.checkpoint`
+    and a `custom_vjp` whose backward calls a jitted function."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def inner(x):                   # `sin` and `cos` trace inside it
+        return jnp.cos(jnp.sin(x))
+
+    triple = jax.jit(lambda g: g * 3.0)
+    triple.__wrapped__.__name__ = "triple"
+
+    @jax.custom_vjp
+    def scaled(x):
+        return x * 3.0
+
+    scaled.defvjp(lambda x: (x * 3.0, None), lambda _, g: (triple(g),))
+
+    def layer(x, w):
+        return jnp.tanh(inner(x)) @ w
+
+    def loss(w, x):
+        return jnp.sum(scaled(jax.checkpoint(layer)(x, w)) ** 2)
+
+    return jax.grad(loss), (jnp.ones((8, 8)), jnp.ones((4, 8)))
+
+
+def _jax_spans(part, name="jax.trace"):
+    return {r["attributes"]["fun_name"]: r for r in part["spans"]
+            if r["name"] == name}
+
+
+def test_own_times_sum_to_the_root_and_nested_traces_keep_their_own(
+        listening):
+    import jax
+
+    fn, args = _nested_step()
+    with tracing.timeline_span("train.fit", root=True) as job:
+        jax.jit(fn).trace(*args)
+    part = tracing.timeline_take(job.trace_id)
+    traces = [r for r in part["spans"] if r["name"] == "jax.trace"]
+    by_fun = _jax_spans(part)
+    root = by_fun[fn.__name__]
+    # every trace of the run lies in the root's span: their own times are
+    # its duration, each cut to a whole microsecond
+    assert part["counters"]["jax.traces"] == len(traces) > 8
+    own = sum(r["attributes"]["own_us"] for r in traces)
+    assert 0 <= root["duration_us"] - own < 1000, (root["duration_us"], own)
+    assert 0 < root["attributes"]["own_us"] < root["duration_us"]
+    assert all(r["attributes"]["own_us"] <= r["duration_us"] for r in traces)
+    # `sin` and `cos` closed inside `inner`: its own time is the rest
+    inner, sin, cos = by_fun["inner"], by_fun["sin"], by_fun["cos"]
+    assert inner["start_us"] <= sin["start_us"] <= cos["start_us"]
+    assert inner["attributes"]["own_us"] <= inner["duration_us"] \
+        - sin["duration_us"] - cos["duration_us"] + 2
+    # the backward's jitted call is traced in the root's span too
+    assert "triple" in by_fun
+    # no function here is the step's
+    assert not any(r["attributes"]["step"] for r in traces)
+    assert not any("scope" in r["attributes"] for r in traces)
+
+
+def test_train_step_books_to_the_step_and_any_other_function_to_other(
+        listening):
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from ray_tpu.models import layers
+
+    backend = listening
+
+    def objective(params, batch):
+        loss = jnp.sum(jnp.tanh(batch @ params["w"]) ** 2)
+        return loss, {"loss": loss}
+
+    optimizer = optax.sgd(1e-2)
+    params = {"w": jnp.ones((8, 8))}
+    state, batch = optimizer.init(params), jnp.ones((4, 8))
+    step = layers.train_step(objective, optimizer, jnp.float32)
+    assert step.__name__ in backend.STEP_NAMES
+    with tracing.timeline_span("train.fit", root=True) as job:
+        jax.jit(step).lower(params, state, batch).compile()
+        only_step = backend.setup_account(tracing.timeline_ctx())
+        jax.jit(lambda x: x * 2 + 1)(batch).block_until_ready()
+        account = backend.setup_account(tracing.timeline_ctx())
+    part = tracing.timeline_take(job.trace_id)
+    assert set(account["own_us"]) == EIGHT
+    assert all(only_step["own_us"][f"{kind}/step"] > 0
+               for kind in ("trace", "lower", "compile"))
+    assert not any(only_step["own_us"][f"{kind}/other"]
+                   for kind in ("trace", "lower", "compile"))
+    # the second function added to `other` alone
+    assert {k: v for k, v in account["own_us"].items() if "/step" in k} \
+        == {k: v for k, v in only_step["own_us"].items() if "/step" in k}
+    assert all(account["own_us"][f"{kind}/other"] > 0
+               for kind in ("lower", "compile"))
+    assert set(account) == {"own_us", "step_cache", "listen_us"}
+    # on the spans: `step` by the outermost function, whatever their own
+    for name in ("jax.trace", "jax.lower", "jax.backend_compile"):
+        spans = [r for r in part["spans"] if r["name"] == name]
+        mine = [r for r in spans if r["attributes"]["step"]]
+        assert mine and len(mine) < len(spans)
+        assert {"own_us", "step"} <= set(mine[0]["attributes"])
+    compiled = _jax_spans(part, "jax.backend_compile")
+    assert compiled["jit(train_step)"]["attributes"]["step"]
+    assert not compiled["jit(<lambda>)"]["attributes"]["step"]
+    assert account["step_cache"] == \
+        compiled["jit(train_step)"]["attributes"]["cache"]
+
+
+def test_cache_reads_miss_then_hit_and_off_without_a_directory(
+        listening, tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    x = jnp.ones(8)         # its own little program compiles outside
+
+    def compiles(fn):
+        with tracing.timeline_span("train.fit", root=True) as job:
+            jax.jit(fn).lower(x).compile()
+        part = tracing.timeline_take(job.trace_id)
+        span, = [r for r in part["spans"]
+                 if r["name"] == "jax.backend_compile"]
+        reads = [r for r in part["spans"] if r["name"] == "jax.cache_read"]
+        return span, reads
+
+    def served():
+        """A new function each call (jit's own caches miss) of one module
+        (the cache's key is the same)."""
+        def served(x):
+            return jnp.cos(x) * 5.0 + 2.0
+        return served
+
+    was = {name: getattr(jax.config, name) for name in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    assert not was["jax_compilation_cache_dir"]
+    span, reads = compiles(served())
+    assert span["attributes"]["cache"] == "off" and not reads
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        compilation_cache.reset_cache()
+        first, reads = compiles(served())
+        assert first["attributes"]["cache"] == "miss" and not reads
+        second, (read,) = compiles(served())
+        assert second["attributes"]["cache"] == "hit"
+        # the read lies inside its compile, whose own time is the rest
+        assert second["start_us"] <= read["start_us"]
+        assert second["attributes"]["own_us"] \
+            <= second["duration_us"] - read["duration_us"] + 1
+    finally:
+        for name, value in was.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+
+
+def test_outside_a_job_the_jax_listeners_record_nothing(listening):
+    import jax
+    import jax.numpy as jnp
+
+    backend = listening
+    with tracing._tl_lock:
+        held = len(tracing._tl_lifecycle)
+    jax.jit(lambda x: jnp.sin(x) + 4.0)(jnp.ones(4)).block_until_ready()
+    with tracing._tl_lock:
+        assert not [r for r in list(tracing._tl_lifecycle)[held:]
+                    if r["name"].startswith("jax.")]
+    assert not hasattr(backend._jax_thread, "events")
+
+
+def test_train_setup_is_written_once_a_rank_at_its_first_report(
+        two_report_job):
+    from ray_tpu.train.session import setup_text
+
+    result, doc, by_name = two_report_job
+    setups = by_name["train.setup"]
+    assert sorted(r["attributes"]["rank"] for r in setups) == [0, 1]
+    for setup in setups:
+        loop, = [r for r in by_name["train.loop"] if r["pid"] == setup["pid"]]
+        first, = [r for r in by_name["train.report"]
+                  if r["pid"] == setup["pid"] and r["attributes"]["n"] == 0]
+        a = setup["attributes"]
+        # the loop's start to the first report's return, under the loop
+        assert setup["parent_id"] == loop["span_id"]
+        assert 0 <= setup["start_us"] - loop["start_us"] < 10_000
+        end = setup["start_us"] + setup["duration_us"]
+        assert 0 <= end - (first["start_us"] + first["duration_us"]) < 50_000
+        assert set(a["own_us"]) == EIGHT
+        assert a["run_us"] >= 0
+        assert a["run_us"] + sum(a["own_us"].values()) == setup["duration_us"]
+        # `double` is no step of `layers.train_step`: all of it is `other`
+        assert a["own_us"]["compile/other"] > 0
+        assert not any(us for key, us in a["own_us"].items()
+                       if key.endswith("/step"))
+        assert a["step_cache"] is None
+        assert 0 < a["listen_us"] < 1_000_000
+        line = setup_text(setup)
+        assert line.startswith("train: set-up ") and "other functions" in line
+
+
+def _traces_a_round(n, x):
+    """``n`` small functions traced (two a turn: the function and the `add`
+    inside it) inside one function's trace, as a step's are."""
+    import jax
+
+    def body(x):
+        for _ in range(n // 2):
+            x = jax.jit(lambda x: x + 1)(x)     # a new function: a trace
+        return x
+
+    jax.jit(body).trace(x)
+
+
+def test_the_listeners_cost_microseconds_a_trace(listening, monkeypatch):
+    """What 2,000 small traces cost in a job, where the listeners keep their
+    stack and book, by what the listeners time of themselves (`listen_us`):
+    a few microseconds a trace on an idle machine.  The limit is generous,
+    25 us, so that a loaded machine does not fail it; the wall clock's
+    difference to a run outside a job swings by tens of microseconds a trace
+    either way and is asserted nowhere."""
+    import jax.numpy as jnp
+
+    backend = listening
+    monkeypatch.setattr(backend, "_SHORT_TRACE_S", 0.005)
+    n, x = 2000, jnp.ones(4)
+    _traces_a_round(200, x)
+    listened = []
+    for _ in range(2):
+        with tracing.timeline_span("train.fit", root=True) as job:
+            _traces_a_round(n, x)
+            listened.append(backend.setup_account(
+                tracing.timeline_ctx())["listen_us"])
+        part = tracing.timeline_take(job.trace_id)
+        assert part["counters"]["jax.traces"] == n + 1      # and `body`
+    print(f"listen_us a trace {min(listened) / n:.2f}")
+    assert 0 < min(listened) / n < 25, listened
