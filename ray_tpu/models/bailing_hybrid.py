@@ -52,7 +52,8 @@ kda/{proj,conv,gate,rule,gate_norm,out_proj}, attention/{latent_down,
 latent_up,kernel,gate,out}, ffn/dense, ffn/moe/{route,dispatch,experts,
 combine,shared}, head_and_loss, optimizer_update, routing_bias_update.
 Counted on the job timeline as the step is traced: `kda.layers`,
-`kda.rule_kernel`, `kda.rule_plain`, `kda.bwd_kernel` (`ops/kda.py`),
+`kda.rule_kernel`, `kda.rule_plain`, `kda.bwd_kernel`, `kda.kernel_calls`
+(`ops/kda.py`),
 `kda.head_norm_rows_fused` (`ops/gated_norm.py`: the rows of q's and k's L2
 norms and of the head's norm whose kernels ran, three calls a traced KDA
 layer), `moe.route_groups` (`ops/moe.py`), `attention.gated`.
@@ -369,8 +370,7 @@ def _kda_mixer(u, p, cfg: BailingHybridConfig):
         g = _decay(f, p, cfg)
         beta = _beta(b)
     with jax.named_scope("rule"):
-        o = named(kda(heads(q), heads(k), heads(v), g, beta,
-                      chunk=cfg.kda_chunk), "kda/rule")
+        o = kda(heads(q), heads(k), heads(v), g, beta, chunk=cfg.kda_chunk)
     with jax.named_scope("gate_norm"):
         o = _gated_head_norm(o.reshape(B, S, H * D), gate,
                              p["head_norm"]["scale"], cfg)

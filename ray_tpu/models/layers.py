@@ -549,12 +549,6 @@ KEPT_NAMES = (
     "attention/indexer/select", # the mask of the keys each query attends,
                                 # (B, S, S) int8: kept, a replay searches
                                 # no threshold (16 passes over the scores)
-    "kda/rule",                 # the delta rule's o, (B, S, H V): kept, a
-                                # replay drops the rule's forward kernel
-                                # (its backward reads the rule's inputs
-                                # alone, `ops/kda.py`): 56 ms a step for
-                                # 0.375 GiB on ling, 150 ms a GiB (PERF.md
-                                # §6, PR 65)
     "attention/latent_down",    # DeepSeek-V3's [c | k_r], W_kv_a's result
     "attention/gate",           # a gated attention's u W_g, (B, S, H) float32:
                                 # a product that reads the whole stream for

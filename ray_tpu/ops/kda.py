@@ -61,12 +61,16 @@ its lanes by a product with a 0 / 1 matrix (`_whose`): a reduction or a
 broadcast over a (B, S, H, K) view makes XLA re-tile the float32 rows, a
 head's 128 lanes into a tile of their own, and re-tile them back.
 The backward is a kernel of its own over the same grid from last to first.
-Its residuals are the forward's INPUTS: a first pass makes the state that
-entered each chunk again (the forward kernel without its O), the second
-remakes the chunk's A, T, U and W from it, carries the state's cotangent in
-VMEM and writes dq, dk, d(beta k), d(beta v) and dg.  No product in it has
-exp(-G) over the chunk either: every cotangent of a pair decay is taken
-with the same row and column factors as the forward.
+Its residuals are the forward's inputs and what the forward kernel writes
+beside o under differentiation: the state that ENTERED each chunk, (B, H,
+chunks, V, K) float32, from the scratch it carries anyway, and each chunk's T
+less the identity, (B, H, chunks, C, C) in the inputs' type, the only form a
+pass reads it in (ten dependent products of the chain that the backward does
+not make again: 3.8 of its 9.5 ms a layer at the ling cell's shape, PERF.md
+section 6, PR 68).  It remakes the chunk's sums, P, U and W from them, carries
+the state's cotangent in VMEM and writes dq, dk, d(beta k), d(beta v) and dg.
+No product in it has exp(-G) over the chunk either: every cotangent of a pair
+decay is taken with the same row and column factors as the forward.
 
 **What the shape decides** (`_kernel_problem`): K = V = 128 (a head is a
 lane tile) and a chunk of 16, 32 or 64.  Every other shape runs the chunked
@@ -78,7 +82,7 @@ interpreter's sizes (`ops.by_platform`).
 Counts itself on the job timeline as the step is traced: `kda.layers` (a
 call), `kda.rule_kernel` / `kda.rule_plain` (a call that took the kernels /
 that a shape or a platform declined), `kda.bwd_kernel` (a backward rule
-traced with its kernel).
+traced with its kernel), `kda.kernel_calls` (`pallas_call`s built: 2 a layer).
 """
 
 from __future__ import annotations
@@ -170,9 +174,10 @@ def _solve(A, dtype):
 
 class _Chunk:
     """What both passes make of a chunk's q, k, beta k and g (C, K): the
-    sums, the decays' factors, A, P and T."""
+    sums, the decays' factors, P and T less the identity in the inputs' type
+    (``T``: the forward's, handed to the backward; None: made here from A)."""
 
-    def __init__(self, q, k, kb, g):
+    def __init__(self, q, k, kb, g, T=None):
         C = q.shape[0]
         self.C, self.dtype = C, q.dtype
         dtype = q.dtype
@@ -207,9 +212,10 @@ class _Chunk:
         self.q_start = (f32(q) * self.start).astype(dtype)
         self.kb_start = (f32(kb) * self.start).astype(dtype)
         self.k_end = (f32(k) * self.end).astype(dtype)
-        self.A = jnp.where(cols < rows, self.pairs(self.kb_row), 0.0)
+        if T is None:       # A before P: the order the forward was timed in
+            A = jnp.where(cols < rows, self.pairs(self.kb_row), 0.0)
         self.P = jnp.where(cols <= rows, self.pairs(self.q_row), 0.0)
-        self.T = _solve(self.A, dtype)              # less the identity
+        self.T = _solve(A, dtype).astype(dtype) if T is None else T
 
     def blocks(self):
         return [slice(a * _SUB, (a + 1) * _SUB) for a in range(self.C // _SUB)]
@@ -224,36 +230,35 @@ class _Chunk:
 
     def solved(self, x):
         """T x, x (C, .) in the inputs' type."""
-        return x.astype(_F32) + _dot(self.T.astype(self.dtype), x, (1, 0))
+        return x.astype(_F32) + _dot(self.T, x, (1, 0))
 
     def solved_back(self, x):
         """T' x, x (C, .) float32."""
-        return x + _dot(self.T.astype(self.dtype), x.astype(self.dtype),
-                        (0, 0))
+        return x + _dot(self.T, x.astype(self.dtype), (0, 0))
 
 
 def _chunk_forward(q, k, kb, vb, g, state, want_o=True):
     """One chunk of one head: q, k, kb = beta k (C, K), vb = beta v (C, V),
     g (C, K) float32, ``state`` the state that enters, TRANSPOSED (V, K)
-    float32 -> (o (C, V) float32 or None, the state that leaves)."""
+    float32 -> (o (C, V) float32, the state that leaves, the chunk's T as
+    `_Chunk` holds it).  Nothing reads ``want_o``:
+    `benchmark/tests/bailing_hybrid_faults.py` hands it on."""
     c = _Chunk(q, k, kb, g)
     dtype = c.dtype
     state_x = state.astype(dtype)
     W = c.solved(c.kb_start)                                    # (C, K)
     U = c.solved(vb) - _dot(W.astype(dtype), state_x, (1, 1))   # (C, V)
     U_x = U.astype(dtype)
-    o = None
-    if want_o:
-        o = _dot(c.q_start, state_x, (1, 1)) \
-            + _dot(c.P.astype(dtype), U_x, (1, 0))
-    return o, jnp.exp(c.total) * state + _dot(U_x, c.k_end, (0, 0))
+    o = _dot(c.q_start, state_x, (1, 1)) + _dot(c.P.astype(dtype), U_x, (1, 0))
+    return o, jnp.exp(c.total) * state + _dot(U_x, c.k_end, (0, 0)), c.T
 
 
-def _chunk_backward(q, k, kb, vb, g, state, do, dstate):
-    """The same chunk's cotangents: ``do`` (C, V), ``dstate`` that of the
-    state that LEFT, transposed (V, K) float32 -> (dq, dk, dkb, dvb, dg
-    (C, .) float32, the cotangent of the state that entered)."""
-    c = _Chunk(q, k, kb, g)
+def _chunk_backward(q, k, kb, vb, g, state, T, do, dstate):
+    """The same chunk's cotangents: ``T`` the forward's, ``do`` (C, V),
+    ``dstate`` that of the state that LEFT, transposed (V, K) float32 ->
+    (dq, dk, dkb, dvb, dg (C, .) float32, the cotangent of the state that
+    entered)."""
+    c = _Chunk(q, k, kb, g, T)
     dtype, C = c.dtype, c.C
     f32 = lambda x: x.astype(_F32)
     x = lambda v: v.astype(dtype)
@@ -318,18 +323,21 @@ def _by_chunks(x, C):
 def _plain(q, k, kb, vb, g, C):
     """The chunked form over (B, S, H, .) arrays, S a multiple of C: the
     chunk's algebra `vmap`ped over batch and heads, a `lax.scan` over the
-    chunks' carry.  -> o (B, S, H, V) in q's type."""
+    chunks' carry.  -> (o (B, S, H, V) in q's type, the state that entered
+    each chunk, transposed: (B, H, chunks, V, K) float32, each chunk's T:
+    (B, H, chunks, C, C) in q's type)."""
     B, S, H, K = q.shape
     V = vb.shape[-1]
     over = jax.vmap(jax.vmap(_chunk_forward))
 
     def chunk(state, xs):
-        o, state = over(*xs, state)
-        return state, o.astype(q.dtype)
+        o, after, T = over(*xs, state)
+        return after, (o.astype(q.dtype), state, T)
 
-    _, o = jax.lax.scan(chunk, jnp.zeros((B, H, V, K), _F32),
-                        tuple(_by_chunks(v, C) for v in (q, k, kb, vb, g)))
-    return o.transpose(1, 0, 3, 2, 4).reshape(B, S, H, V)
+    _, (o, *kept) = jax.lax.scan(chunk, jnp.zeros((B, H, V, K), _F32), tuple(
+        _by_chunks(v, C) for v in (q, k, kb, vb, g)))
+    return (o.transpose(1, 0, 3, 2, 4).reshape(B, S, H, V),
+            *(x.transpose(1, 2, 0, 3, 4) for x in kept))
 
 
 # ---------------------------------------------------------------------------
@@ -349,11 +357,12 @@ def _step_chunks(chunks):
     return max(n for n in range(1, _STEP_CHUNKS + 1) if chunks % n == 0)
 
 
-def _forward_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, out_ref, state_ref,
-                    *, C, states):
-    """A grid step: one head's few chunks, one after the other.  ``states``:
-    write the state that entered each chunk and no o (the backward's first
-    pass)."""
+def _forward_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, *rest, C):
+    """A grid step: one head's few chunks, one after the other; with the
+    backward's residuals for results (``states``), the state that entered
+    each chunk and the chunk's T into them."""
+    *kept_refs, state_ref = rest
+
     @pl.when(pl.program_id(2) == 0)
     def _():
         state_ref[...] = jnp.zeros_like(state_ref)
@@ -361,19 +370,19 @@ def _forward_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, out_ref, state_ref,
     state = state_ref[...]
     for j in range(q_ref.shape[1] // C):
         rows = slice(j * C, (j + 1) * C)
-        if states:
-            out_ref[0, 0, j] = state
-        o, state = _chunk_forward(
+        o, after, T = _chunk_forward(
             q_ref[0, rows], k_ref[0, rows], kb_ref[0, rows], vb_ref[0, rows],
-            g_ref[0, rows], state, want_o=not states)
-        if not states:
-            out_ref[0, rows] = o.astype(out_ref.dtype)
+            g_ref[0, rows], state)
+        for ref, kept in zip(kept_refs, (state, T)):
+            ref[0, 0, j] = kept
+        state = after
+        o_ref[0, rows] = o.astype(o_ref.dtype)
     state_ref[...] = state
 
 
-def _backward_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, before_ref, do_ref,
-                     dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref, dstate_ref,
-                     *, C):
+def _backward_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, before_ref, t_ref,
+                     do_ref, dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref,
+                     dstate_ref, *, C):
     """A grid step: the steps and a step's chunks from last to first;
     `dstate_ref` carries the cotangent of the state that LEFT the chunk."""
     @pl.when(pl.program_id(2) == 0)
@@ -385,7 +394,8 @@ def _backward_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, before_ref, do_ref,
         rows = slice(j * C, (j + 1) * C)
         dq, dk, dkb, dvb, dg, dstate = _chunk_backward(
             q_ref[0, rows], k_ref[0, rows], kb_ref[0, rows], vb_ref[0, rows],
-            g_ref[0, rows], before_ref[0, 0, j], do_ref[0, rows], dstate)
+            g_ref[0, rows], before_ref[0, 0, j], t_ref[0, 0, j],
+            do_ref[0, rows], dstate)
         dq_ref[0, rows] = dq.astype(dq_ref.dtype)
         dk_ref[0, rows] = dk.astype(dk_ref.dtype)
         dkb_ref[0, rows] = dkb.astype(dkb_ref.dtype)
@@ -401,59 +411,63 @@ def _flat(x):
 
 
 def _specs(B, S, H, C, n, step_of):
-    """The grid and the block of a (B, S, H x 128) operand, n chunks a
-    step; ``step_of`` maps the grid's third index to the step's place in the
+    """The grid, the block of a (B, S, H x 128) operand and the blocks of
+    the backward's two residuals (the entering states, (B, H, chunks, 128,
+    128), and the chunks' T, (B, H, chunks, C, C)), n chunks a step;
+    ``step_of`` maps the grid's third index to the step's place in the
     sequence."""
+    kept = [pl.BlockSpec((1, 1, n, *tile),
+                         lambda b, h, s: (b, h, step_of(s), 0, 0))
+            for tile in ((_LANE, _LANE), (C, C))]
     return (B, H, S // (n * C)), pl.BlockSpec(
-        (1, n * C, _LANE), lambda b, h, s: (b, step_of(s), h))
+        (1, n * C, _LANE), lambda b, h, s: (b, step_of(s), h)), kept
 
 
 @functools.partial(jax.jit, static_argnames=("C", "states", "interpret"))
 def _forward(q, k, kb, vb, g, C, states=False, interpret=False):
-    """-> o (B, S, H, V) in q's type; or, ``states``, the state that entered
-    each chunk, transposed: (B, H, chunks, V, K) float32."""
+    """-> (o (B, S, H, V) in q's type,) and with ``states``, behind it, the
+    backward's residuals: the state that entered each chunk, transposed,
+    (B, H, chunks, V, K) float32, and each chunk's T, (B, H, chunks, C, C)
+    in q's type."""
     B, S, H, K = q.shape
     V = vb.shape[-1]
     n = _step_chunks(S // C)
-    grid, block = _specs(B, S, H, C, n, lambda s: s)
+    grid, block, kept = _specs(B, S, H, C, n, lambda s: s)
+    out_specs = [block]
+    out_shape = [jax.ShapeDtypeStruct((B, S, H * V), q.dtype)]
     if states:
-        shape = jax.ShapeDtypeStruct((B, H, S // C, V, K), _F32)
-        spec = pl.BlockSpec((1, 1, n, V, K), lambda b, h, s: (b, h, s, 0, 0))
-    else:
-        shape = jax.ShapeDtypeStruct((B, S, H * V), q.dtype)
-        spec = block
-    out = pl.pallas_call(
-        functools.partial(_forward_kernel, C=C, states=states),
-        grid=grid, in_specs=[block] * 5, out_specs=spec, out_shape=shape,
-        scratch_shapes=[pltpu.VMEM((V, K), _F32)],
+        out_specs += kept
+        out_shape += [jax.ShapeDtypeStruct((B, H, S // C, V, K), _F32),
+                      jax.ShapeDtypeStruct((B, H, S // C, C, C), q.dtype)]
+    o, *kept = pl.pallas_call(
+        functools.partial(_forward_kernel, C=C),
+        grid=grid, in_specs=[block] * 5, out_specs=out_specs,
+        out_shape=out_shape, scratch_shapes=[pltpu.VMEM((V, K), _F32)],
         compiler_params=_COMPILER_PARAMS, interpret=interpret,
     )(*(_flat(x) for x in (q, k, kb, vb, g)))
-    return out if states else out.reshape(B, S, H, V)
+    return (o.reshape(B, S, H, V), *kept)
 
 
 @functools.partial(jax.jit, static_argnames=("C", "interpret"))
-def _backward(q, k, kb, vb, g, do, C, interpret=False):
-    """-> (dq, dk, dkb, dvb, dg), each in its primal's shape and type."""
+def _backward(q, k, kb, vb, g, do, before, solved, C, interpret=False):
+    """``before``, ``solved``: the states and the T `_forward` wrote ->
+    (dq, dk, dkb, dvb, dg), each in its primal's shape and type."""
     B, S, H, K = q.shape
     V = vb.shape[-1]
     n = _step_chunks(S // C)
-    before = _forward(q, k, kb, vb, g, C=C, states=True, interpret=interpret)
     steps = S // (n * C)
     back = lambda s: steps - 1 - s
-    grid, block = _specs(B, S, H, C, n, back)
+    grid, block, kept = _specs(B, S, H, C, n, back)
     primals = (q, k, kb, vb, g)
     grads = pl.pallas_call(
         functools.partial(_backward_kernel, C=C),
-        grid=grid,
-        in_specs=[block] * 5 + [
-            pl.BlockSpec((1, 1, n, V, K),
-                         lambda b, h, s: (b, h, back(s), 0, 0)), block],
+        grid=grid, in_specs=[block] * 5 + [*kept, block],
         out_specs=[block] * 5,
         out_shape=[jax.ShapeDtypeStruct(_flat(x).shape, x.dtype)
                    for x in primals],
         scratch_shapes=[pltpu.VMEM((V, K), _F32)],
         compiler_params=_COMPILER_PARAMS, interpret=interpret,
-    )(*(_flat(x) for x in primals), before, _flat(do))
+    )(*(_flat(x) for x in primals), before, solved, _flat(do))
     return tuple(d.reshape(x.shape) for d, x in zip(grads, primals))
 
 
@@ -473,29 +487,31 @@ def _heads(arrays, H):
 # cotangents (the rule's own and beta k's) is one of rows as they lie.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _kernels(q, k, kb, vb, g, H, C):
-    return _kernels_fwd(q, k, kb, vb, g, H, C)[0]
+    return by_platform(
+        lambda *a, interpret: _flat(
+            _forward(*_heads(a, H), C=C, interpret=interpret)[0]),
+        lambda *a: _flat(_plain(*_heads(a, H), C)[0]), q, k, kb, vb, g)
 
 
 def _kernels_fwd(q, k, kb, vb, g, H, C):
-    o = by_platform(
-        lambda *a, interpret: _flat(
-            _forward(*_heads(a, H), C=C, interpret=interpret)),
-        lambda *a: _flat(_plain(*_heads(a, H), C)), q, k, kb, vb, g)
-    return o, (q, k, kb, vb, g)
+    o, *kept = by_platform(
+        lambda *a, interpret: _forward(
+            *_heads(a, H), C=C, states=True, interpret=interpret),
+        lambda *a: _plain(*_heads(a, H), C), q, k, kb, vb, g)
+    return _flat(o), (q, k, kb, vb, g, *kept)
 
 
-def _kernels_bwd(H, C, inputs, do):
-    def reference(*a):
-        *inputs, do = a
-        return jax.vjp(lambda *v: _flat(_plain(*_heads(v, H), C)),
-                       *inputs)[1](do)
-
+def _kernels_bwd(H, C, residuals, do):
+    inputs, kept = residuals[:5], residuals[5:]
+    reference = lambda *a: jax.vjp(
+        lambda *v: _flat(_plain(*_heads(v, H), C)[0]), *a[:5])[1](a[5])
     if _runs_kernels(inputs[0]):
         tracing.count("kda.bwd_kernel")
+        tracing.count("kda.kernel_calls")
     return by_platform(
         lambda *a, interpret: tuple(_flat(d) for d in _backward(
-            *_heads(a, H), C=C, interpret=interpret)),
-        reference, *inputs, do)
+            *_heads(a[:6], H), *a[6:], C=C, interpret=interpret)),
+        reference, *inputs, do, *kept)
 
 
 _kernels.defvjp(_kernels_fwd, _kernels_bwd)
@@ -546,9 +562,10 @@ def kda(q, k, v, g, beta, chunk=64):
             f"chunk {C} runs the plain chunked form: {problem}",
             KdaFallbackWarning, stacklevel=2)
         tracing.count("kda.rule_plain")
-        o = _flat(_plain(*_heads((q, k, kb, vb, g), H), C))
+        o = _flat(_plain(*_heads((q, k, kb, vb, g), H), C)[0])
     else:
-        tracing.count("kda.rule_kernel" if _runs_kernels(q)
-                      else "kda.rule_plain")
+        taken = _runs_kernels(q)
+        tracing.count("kda.rule_kernel" if taken else "kda.rule_plain")
+        tracing.count("kda.kernel_calls", int(taken))
         o = _kernels(q, k, kb, vb, g, H, C)
     return o.reshape(B, -1, H, V)[:, :S]

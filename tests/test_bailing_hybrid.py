@@ -210,6 +210,62 @@ def test_a_recomputed_stack_is_the_same_step():
         assert max_diff(g, w) < 1e-4 * (float(jnp.max(jnp.abs(w))) + 1e-6)
 
 
+# what `keep_plan` gave the ling cell with the rule's o among its marks (my
+# no-chip compile, PR 68, of the parent: `tools/aot_collectives.py`)
+PARENT_KEPT = ("ffn/moe/route", "attention/gate")
+PARENT_DECLINED = ("kda/rule", "attention/latent_down", "attention/out",
+                   "kda/out_proj", "attention/qkv", "kda/proj", "ffn/hidden",
+                   "attention/latent_up", "kda/conv")
+
+
+@pytest.mark.parametrize("room", ["parents", "its_own"])
+def test_the_cells_plan_neither_marks_nor_weighs_the_rules_o(room):
+    """The stack of the ling cell at its shape (1 x 16,384 at the published
+    widths, abstract) under a v5e's limit.  `kda/rule` is no mark any more
+    (kept, the rule's o would drop no kernel: the replay runs the forward
+    kernel for the backward's states), so in the room the parent had the
+    plan keeps the same names and declines one fewer.  Its own room is wider
+    by what the reserve held for the unmarked o, `_LIVE_LAYERS` times its
+    64 MiB, and the one latent-attention layer's two narrow products fit."""
+    config = registry.config("ling-3.0-flash-ep64")
+    traffic = registry.traffic("resident-16k")
+    family = registry.family(config)
+    cfg = family.model_config()
+    params = jax.eval_shape(family._init, jax.random.PRNGKey(0))
+    opt_state = jax.eval_shape(family.optimizer().init, params)
+    cast = jax.eval_shape(
+        lambda p: layers.cast_weights(p, cfg.compute_dtype), params)
+    x = jax.ShapeDtypeStruct((traffic["batch"], traffic["seq"], cfg.n_embd),
+                             cfg.compute_dtype)
+    o_bytes = x.size // cfg.n_embd * cfg.n_head * cfg.head_dim * 2
+    calls = [(x, cast[f"layer_{i}"], cfg) for i in range(cfg.n_layer)]
+    behind = jax.ShapeDtypeStruct((cfg.loss_chunk_rows, cfg.vocab_size),
+                                  jnp.float32)
+    with layers._telling(
+            state_bytes=layers.state_bytes(params, opt_state,
+                                           cfg.compute_dtype),
+            memory_limit=int(15.75 * 2 ** 30)):
+        own = layers.keep_plan(model._layer, calls, (2,), behind)
+        plan = own if room == "its_own" else layers.keep_plan(
+            model._layer, calls, (2,), behind,
+            room=own["room"] - int(layers._LIVE_LAYERS * o_bytes))
+    assert "kda/rule" not in plan["marked"]
+    assert "kda/rule" not in layers.KEPT_NAMES
+    gib = 2.0 ** 30
+    if room == "parents":
+        assert abs(plan["room"] / gib - 0.209) < 1e-3
+        assert plan["names"] == PARENT_KEPT
+        assert plan["declined"] == PARENT_DECLINED[1:]
+    else:
+        assert abs(plan["room"] / gib - 0.366) < 1e-3
+        assert plan["names"] == (
+            "ffn/moe/route", "attention/latent_down", "attention/gate",
+            "attention/out")
+        assert abs(plan["bytes_kept"] / gib - 0.299) < 1e-3
+        assert set(plan["declined"]) == set(PARENT_DECLINED[1:]) - set(
+            plan["names"])
+
+
 def test_heads_of_128_take_the_kernels_and_match():
     """A KDA head as wide as the published one: the Pallas kernels
     (interpreted) inside the model's step, against the reference."""
@@ -223,6 +279,9 @@ def test_heads_of_128_take_the_kernels_and_match():
             assert tracing.counter("kda.rule_kernel") >= 1
             assert tracing.counter("kda.rule_plain") == 0
             assert tracing.counter("kda.bwd_kernel") >= 1
+            # a kernel a pass: no second forward for the backward's states
+            assert tracing.counter("kda.kernel_calls") == tracing.counter(
+                "kda.rule_kernel") + tracing.counter("kda.bwd_kernel")
             assert tracing.counter("moe.route_groups") == 1
             # q's and k's L2 norms and the head's norm with its gate, the
             # rows of each, a traced KDA layer

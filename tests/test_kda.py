@@ -2,9 +2,9 @@
 Pallas kernels (interpreted here) against the recurrence run position by
 position in float64 numpy and under `jax.grad` of the same recurrence in jax:
 o and all five gradients, at one chunk and at many, with g drawn AT the
-gate's bound; the state carried from chunk to chunk; what the kernels take;
-a declined shape counted; a recomputed layer's replay; and what a TPU is
-given."""
+gate's bound; the state carried from chunk to chunk, and handed to the
+backward as it entered each chunk; what the kernels take; a declined shape
+counted; a recomputed layer's replay; and what a TPU is given."""
 
 import warnings
 
@@ -43,22 +43,27 @@ def make(shape, seed=0, at_bound=False, dtype=jnp.float32):
             jax.random.normal(ks[5], (B, S, H, V)))
 
 
-def by_positions(q, k, v, g, beta):
+def by_positions(q, k, v, g, beta, every=None):
     """The rule as its equation reads, numpy float64, one position after
-    another: S_t = (I - beta k k') Diag(alpha) S_{t-1} + beta k v'."""
+    another: S_t = (I - beta k k') Diag(alpha) S_{t-1} + beta k v'.  -> o;
+    with ``every`` the state before each ``every``-th position instead,
+    transposed as the kernels hold it: (B, H, S / every up, V, K)."""
     q, k, v, g, beta = (np.asarray(x, np.float64) for x in (q, k, v, g, beta))
     B, S, H, Kd = q.shape
     o = np.zeros(v.shape)
+    entered = np.zeros((B, H, -(-S // (every or S)), v.shape[-1], Kd))
     for b in range(B):
         for h in range(H):
             state = np.zeros((Kd, v.shape[-1]))
             for t in range(S):
+                if every and t % every == 0:
+                    entered[b, h, t // every] = state.T
                 kt, bt = k[b, t, h], beta[b, t, h]
                 state = np.exp(g[b, t, h])[:, None] * state
                 state = state - bt * np.outer(kt, kt @ state) \
                     + bt * np.outer(kt, v[b, t, h])
                 o[b, t, h] = state.T @ q[b, t, h]
-    return o
+    return entered if every else o
 
 
 def recurrence(q, k, v, g, beta):
@@ -101,7 +106,7 @@ def n_kernels(f, *args):
 
 def plain(q, k, v, g, beta, chunk=64):
     C = K._chunk_size(q.shape[1], chunk)
-    return K._plain(q, k, K._scaled(k, beta), K._scaled(v, beta), g, C)
+    return K._plain(q, k, K._scaled(k, beta), K._scaled(v, beta), g, C)[0]
 
 
 @pytest.mark.parametrize("shape", [ONE_CHUNK, MANY, BATCHED],
@@ -150,9 +155,8 @@ def test_the_state_is_carried_from_chunk_to_chunk(rule, monkeypatch):
                          - sound[:, 64:])) > 1e-3
     forward = K._chunk_forward
 
-    def no_carry(q, k, kb, vb, g, state, want_o=True):
-        o, after = forward(q, k, kb, vb, g, jnp.zeros_like(state), want_o)
-        return o, after
+    def no_carry(q, k, kb, vb, g, state):
+        return forward(q, k, kb, vb, g, jnp.zeros_like(state))
 
     monkeypatch.setattr(K, "_chunk_forward", no_carry)
     jax.clear_caches()
@@ -164,6 +168,45 @@ def test_the_state_is_carried_from_chunk_to_chunk(rule, monkeypatch):
         > 0.05 * np.max(np.abs(want))
 
 
+RAGGED = (1, 100, 1, 128, 128)
+
+
+@pytest.mark.parametrize("shape", [ONE_CHUNK, MANY, BATCHED, RAGGED],
+                         ids=["one_chunk", "three_chunks", "batched",
+                              "ragged"])
+def test_the_forward_hands_over_the_state_that_entered_each_chunk(shape):
+    """What the rule's forward keeps for its backward under differentiation,
+    the kernel's second result and the plain form's stacked carries: the
+    recurrence's state at every chunk's start (zeros at the first), the
+    two forms alike; behind it each chunk's T in the inputs' type, which
+    solves the chunk's triangle; and the kernel's o is what it writes
+    alone."""
+    (q, k, v, g, beta), _ = make(shape)
+    C = K._chunk_size(shape[1], 64)
+    pad = lambda x: jnp.pad(
+        x, ((0, 0), (0, -shape[1] % C)) + ((0, 0),) * (x.ndim - 2))
+    rows = tuple(pad(x) for x in (q, k, K._scaled(k, beta),
+                                  K._scaled(v, beta), g))
+    want = by_positions(q, k, v, g, beta, every=C)
+    assert want.shape[2] == -(-shape[1] // C) and not want[:, :, 0].any()
+    o, states, T = K._forward(*rows, C=C, states=True, interpret=True)
+    assert states.dtype == jnp.float32 and states.shape == want.shape
+    close(states, want, 2e-5)
+    o_plain, carries, T_plain = K._plain(*rows, C)
+    close(carries, want, 2e-5)
+    close(states, carries, 1e-6)
+    close(o, o_plain, 1e-6)
+    assert T.dtype == q.dtype and T.shape == (*want.shape[:3], C, C)
+    close(T, T_plain, 1e-6)
+    # (I + A)(I + T) = I over the first chunk of the first head
+    chunk = K._Chunk(*(rows[i][0, :C, 0] for i in (0, 1, 2, 4)))
+    A = np.tril(np.asarray(chunk.pairs(chunk.kb_row), np.float64), -1)
+    eye = np.eye(C)
+    close((eye + A) @ (eye + np.asarray(T[0, 0, 0], np.float64)), eye, 1e-5)
+    alone, = K._forward(*rows, C=C, interpret=True)
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(alone))
+
+
 def test_bfloat16_operands_stay_close_to_the_recurrence():
     args, do = make(MANY, dtype=jnp.bfloat16)
     exact = value_and_grads(recurrence, args, do)
@@ -173,7 +216,7 @@ def test_bfloat16_operands_stay_close_to_the_recurrence():
 
 
 def test_a_ragged_length_is_padded_with_positions_that_move_nothing():
-    args, do = make((1, 100, 1, 128, 128))
+    args, do = make(RAGGED)
     for g, w in zip(value_and_grads(K.kda, args, do),
                     value_and_grads(recurrence, args, do)):
         close(g, w, 2e-5)
@@ -227,21 +270,22 @@ def test_a_call_counts_itself_and_a_declined_shape_is_the_plain_form(
         shape, taken):
     """A declined shape warns, holds no `pallas_call`, gives the plain
     form's result and gradients to the last bit and counts
-    `kda.rule_plain`; a taken one the kernels, forward, the states' pass
-    and the backward, and `kda.bwd_kernel`."""
+    `kda.rule_plain`; a taken one two kernels, the forward (which hands the
+    entering states over) and the backward, `kda.bwd_kernel` and
+    `kda.kernel_calls`."""
     args, do = make(shape)
     names = ("kda.layers", "kda.rule_kernel", "kda.rule_plain",
-             "kda.bwd_kernel")
+             "kda.bwd_kernel", "kda.kernel_calls")
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", K.KdaFallbackWarning)
         jax.eval_shape(K.kda, *args)
-        assert [tracing.counter(n) for n in names] == [0, 0, 0, 0]  # no job
+        assert [tracing.counter(n) for n in names] == [0] * 5    # no job
         with tracing.timeline_span("train.fit", root=True):
             kernels = n_kernels(
                 lambda *a: value_and_grads(K.kda, a, do), *args)
             assert [tracing.counter(n) for n in names] == [
-                1, taken, 1 - taken, taken]
-        assert kernels == 3 * taken * A_PASS
+                1, taken, 1 - taken, taken, 2 * taken]
+        assert kernels == 2 * taken * A_PASS
         if not taken:
             with pytest.warns(K.KdaFallbackWarning, match="plain chunked"):
                 got = value_and_grads(K.kda, args, do)
@@ -254,12 +298,15 @@ def test_a_call_counts_itself_and_a_declined_shape_is_the_plain_form(
 
 def test_a_replayed_layer_gives_the_same_gradients():
     """Under `checkpoint_layer` with no room the backward pass makes the
-    rule's inputs again and runs the states' pass and the backward kernel;
-    with `kda/rule` kept by name no forward kernel is replayed either."""
+    rule's inputs again and runs the forward kernel and the backward kernel:
+    three kernels a layer, the replayed forward handing the backward its
+    states.  Keeping o by a policy of one's own drops none of them: the
+    replay still runs the kernel for the states."""
     args, do = make(ONE_CHUNK)
 
     def layer(*a):
-        return jnp.sum(jnp.square(layers.named(K.kda(*a), "kda/rule")) * do)
+        return jnp.sum(jnp.square(
+            jax.ad_checkpoint.checkpoint_name(K.kda(*a), "o")) * do)
 
     walked = jax.jit(jax.value_and_grad(layer, range(5)))(*args)
     replay = jax.value_and_grad(layers.checkpoint_layer(layer), range(5))
@@ -267,11 +314,12 @@ def test_a_replayed_layer_gives_the_same_gradients():
                     jax.tree.leaves(walked)):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
     kept = jax.value_and_grad(jax.checkpoint(
-        layer, policy=jax.checkpoint_policies.save_only_these_names(
-            "kda/rule")), range(5))
-    # forward; then the states' pass and the backward, no forward again
+        layer, policy=jax.checkpoint_policies.save_only_these_names("o")),
+        range(5))
+    assert n_kernels(jax.value_and_grad(layer, range(5)), *args) \
+        == 2 * A_PASS
+    assert n_kernels(replay, *args) == 3 * A_PASS
     assert n_kernels(kept, *args) == 3 * A_PASS
-    assert n_kernels(replay, *args) == 4 * A_PASS
 
 
 def test_past_the_interpreters_size_another_platform_runs_the_plain_form():
@@ -288,4 +336,4 @@ def test_past_the_interpreters_size_another_platform_runs_the_plain_form():
         assert tracing.counter("kda.bwd_kernel") == 0
     assert "tpu_custom_call" not in text
     exported = jax.export.export(f, platforms=["tpu"])(*args)
-    assert exported.mlir_module().count("tpu_custom_call") >= 3
+    assert exported.mlir_module().count("tpu_custom_call") == 2
