@@ -65,6 +65,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops import causal_conv as conv_kernels
 from ray_tpu.ops import grouped_matmul
+from ray_tpu.ops.eva import SUMMARY_NAME
 from ray_tpu.ops.flash_attention import KEPT_RESIDUALS, KERNEL_FORMS
 from ray_tpu.ops.moe import ROUTE_NAME, moe_dispatch
 from ray_tpu.parallel.attention import attention
@@ -114,6 +115,17 @@ SCOPES = (
     "kda/rule",
     "kda/gate_norm",
     "kda/out_proj",
+    "eva",
+    "eva/qkv",
+    "eva/summary",
+    "eva/local",
+    # the flash kernels of the local half, head-major under a rule of
+    # aligned windows (`ops/flash_attention.py:_form`)
+    "eva/local/fwd_rows_blocks",
+    "eva/local/bwd_fused_blocks",
+    "eva/remote",
+    "eva/merge",
+    "eva/out",
     "ffn",
     "ffn/dense",
     "ffn/moe",
@@ -153,12 +165,16 @@ def layer_norm(x, p, eps=1e-5):
         return (y * p["scale"] + p["bias"]).astype(x.dtype)
 
 
-def rms_norm(x, p, eps=1e-5):
+def rms_norm(x, p, eps=1e-5, unit_offset=False):
+    """``unit_offset``: the gain is 1 + w, w the parameter, which starts at
+    0 (a release's `norm_add_unit_offset`); False: the gain is w and the
+    program it always was."""
     with jax.named_scope("norm"):
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1,
                        keepdims=True)
+        gain = 1.0 + p["scale"] if unit_offset else p["scale"]
         return (x * jax.lax.rsqrt(var + eps).astype(x.dtype)) \
-            * p["scale"].astype(x.dtype)
+            * gain.astype(x.dtype)
 
 
 def rope(x, positions, theta, interleaved=False, scale=None):
@@ -553,6 +569,10 @@ KEPT_NAMES = (
     "attention/gate",           # a gated attention's u W_g, (B, S, H) float32:
                                 # a product that reads the whole stream for
                                 # a result a head wide, as a router's
+    SUMMARY_NAME,               # an EVA mixer's chunk summaries of k and v,
+                                # a sixteenth of either: kept, a replay drops
+                                # the pooling kernel (first by the byte on
+                                # evabyte, 57 ms a GiB: PERF.md section 5, PR 69)
     "attention/out",            # W_o's result, as wide as the stream
     "short_conv/out_proj",      # W_out's result, the same
     "kda/out_proj",             # a delta-rule mixer's W_o's result, the same
@@ -817,6 +837,8 @@ def _final_norm(x, p, cfg):
     `cfg.rms_eps`."""
     if "bias" in p:
         return layer_norm(x, p, cfg.norm_eps)
+    if getattr(cfg, "norm_unit_offset", False):
+        return rms_norm(x, p, cfg.rms_eps, unit_offset=True)
     return rms_norm(x, p, cfg.rms_eps)
 
 
@@ -884,11 +906,17 @@ def trunk(params, tokens, layer, cfg, walks=None, shared=False):
     T x n as long as the walks are unrolled).  None: one walk, as every
     model but a looped one asks for, nothing counted.
 
+    A configuration with a `stream_dtype` (float32 beside bfloat16
+    products: a release's `fp32_skip_add`) has the embedding's rows, and so
+    the residual stream, in that type; its layer casts what it multiplies.
+    One with `norm_unit_offset` has the final RMSNorm's gain as 1 + w
+    (`rms_norm`).  Neither: the program it always was.
+
     `models/gpt2.py` walks by itself: positional embeddings, pipeline
     stages, a mesh's pins."""
     with jax.named_scope("embed"):
         x = params["embed_tokens"]["embedding"][tokens].astype(
-            cfg.compute_dtype)
+            getattr(cfg, "stream_dtype", None) or cfg.compute_dtype)
     layers = [params[f"layer_{i}"] for i in range(cfg.n_layer)]
     behind = jax.ShapeDtypeStruct((cfg.loss_chunk_rows, cfg.vocab_size),
                                   jnp.float32)

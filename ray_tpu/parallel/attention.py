@@ -37,9 +37,11 @@ def attention(q, k, v, *, causal=True, variant: str = "flash",
 
     ``causal``: True, the diagonal; False, every pair; or a rule
     (`ops/flash_attention.py:BlockRule`) of blocks and kinds of row (block
-    diffusion's clean and noised rows) or of a window (a row's W latest
-    keys: a model's sliding layers), whose empty tiles the kernels never
-    visit; "flash" and "dense" only.
+    diffusion's clean and noised rows), of a window (a row's W latest
+    keys: a model's sliding layers) or of aligned windows (the keys of a
+    row's own window up to itself: `ops/eva.py` calls the kernels under it
+    directly), whose empty tiles the kernels never visit; "flash" and
+    "dense" only.
 
     ``mask``: (batch, seq, seq) int8, not 0 where a (query, key) pair is
     attended, the same for all heads of a sequence; with ``causal`` a pair

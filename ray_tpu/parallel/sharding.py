@@ -233,6 +233,10 @@ def infer_param_logical_dims(path: Tuple[str, ...], shape: Tuple[int, ...]):
             return ("heads",)
         if "f_proj" in name or "b_proj" in name:
             return ("embed", "heads")
+    if "eva/phi" in name or "eva/mu" in name:
+        # an EVA mixer's two learned vectors a head, (H, D): the pooling's
+        # direction and the summaries' offset, cut as the heads are
+        return ("heads", None)
     if "lambda_" in name or "diff_norm" in name:
         # differential attention's four vectors of a head's width and the
         # gain over a pair of heads: whole on every chip

@@ -332,3 +332,104 @@ def test_a_window_as_long_as_the_sequence_is_causal_under_a_band_too():
     band = _both_passes(q, k, v, do, BlockRule(window=1792), 256)
     assert max_diff(band[0][:, :, :1792], causal[0][:, :, :1792]) < 1e-5
     assert max_diff(band[1][:, :, :1792], causal[1][:, :, :1792]) < 1e-5
+
+
+# -- aligned windows (PR 69): the diagonal inside windows that do not slide ---
+
+ALIGNED_CASES = [
+    # S, aligned, block (None: `_auto_tiles`'), H, Hkv, D
+    (512, 256, None, 2, 2, 64),         # a grid step the whole sequence
+    (1024, 128, 128, 2, 1, 32),         # a tile a window, grouped queries
+    (2048, 512, None, 1, 1, 32),        # the walk of tiles, traced bounds
+    (2048, 1024, 256, 2, 1, 32),        # four tiles a window
+]
+
+
+@pytest.mark.parametrize("S,aligned,block,H,Hkv,D", ALIGNED_CASES)
+def test_aligned_windows_match_the_reference(S, aligned, block, H, Hkv, D):
+    """Key j iff j <= i and j // A == i // A, forward and backward, against
+    the masked softmax; the tiles of earlier windows are never visited."""
+    rule = BlockRule(aligned=aligned)
+    q, k, v, do = _qkv(S, H, Hkv, D, D)
+    got = _both_passes(q, k, v, do, rule, block)
+    row = jnp.arange(S)
+    seen = (row[None] <= row[:, None]) \
+        & (row[None] // aligned == row[:, None] // aligned)
+    np.testing.assert_array_equal(np.asarray(fa._attended(rule, S)),
+                                  np.asarray(seen))
+    for g, w in zip(got, _reference(q, k, v, do, rule)):
+        assert max_diff(g, w) < 2e-5
+    # a window's triangle is every pair attended, and its tiles all that is
+    # visited: S / A squares of (A / b) (A / b + 1) / 2 tiles
+    b = block or fa._auto_tiles(S, rule)[0][0]
+    assert fa._tiles_visited(rule, S, b, b) \
+        == (S // aligned) * (aligned // b) * (aligned // b + 1) // 2
+    assert int(seen.sum()) == (S // aligned) * aligned * (aligned + 1) // 2
+
+
+@pytest.mark.parametrize("rule", [
+    BlockRule(aligned=256, window=64), BlockRule(4, 1, None, 256),
+    BlockRule(1, 2, None, 256), BlockRule(4, 2, None, 256),
+    BlockRule(aligned=0)])
+def test_aligned_windows_are_refused_beside_the_other_rules(rule):
+    """Beside a sliding window, blocks or two kinds of row: not written
+    until a model asks."""
+    q = jnp.zeros((1, 1, 512, 32))
+    with pytest.raises(NotImplementedError, match="aligned windows"):
+        fa.flash_attention(q, q, q, rule)
+    with pytest.raises(NotImplementedError, match="aligned windows"):
+        fa.flash_attention_bshd(_tr(q), _tr(q), _tr(q), rule)
+
+
+def _tr(x):
+    return x.transpose(0, 2, 1, 3)
+
+
+def test_tiles_that_do_not_divide_a_window_take_the_reference():
+    q = jnp.zeros((1, 1, 1024, 32))
+    with pytest.warns(fa.AttentionFallbackWarning, match="aligned windows"):
+        fa.flash_attention(q, q, q, BlockRule(aligned=384), None, 256, 256)
+    # and windows that do not divide the sequence
+    with pytest.warns(fa.AttentionFallbackWarning, match="aligned windows"):
+        fa.flash_attention(q, q, q, BlockRule(aligned=768), None, 256, 256)
+
+
+# what `_auto_tiles` gave every other rule before aligned windows came
+# (the parent of PR 69), (forward, backward) tiles by sequence length
+TILES_BEFORE = {
+    True: {512: (512, 256), 1024: (512, 256), 2048: (1024, 512),
+           16384: (1024, 512)},
+    False: {512: (512, 512), 1024: (1024, 1024), 2048: (1024, 512),
+            16384: (1024, 512)},
+    BlockRule(4, 2): {512: (256, 256), 1024: (512, 256), 2048: (512, 512),
+                      16384: (512, 512)},
+    BlockRule(window=1024): {512: (512, 256), 1024: (512, 256),
+                             2048: (256, 256), 16384: (256, 256)},
+    BlockRule(window=4096): {512: (512, 256), 2048: (512, 512),
+                             16384: (512, 512)},
+}
+
+
+@pytest.mark.parametrize("causal", list(TILES_BEFORE), ids=str)
+def test_every_other_rule_keeps_its_tiles_and_its_runs(causal):
+    """The new field changes nothing where it is None: the tiles a call takes
+    and the runs of tiles a kernel walks are the parent's."""
+    for S, (fwd, bwd) in TILES_BEFORE[causal].items():
+        assert fa._auto_tiles(S, causal) == ((fwd, fwd), (bwd, bwd))
+    assert fa._k_spans(fa.CAUSAL, 3, 512, 512, 4096) \
+        == (3, 0, [(0, 3, None), (3, 4, "upto")])
+    assert fa._q_spans(fa.CAUSAL, 3, 512, 512, 4096) \
+        == (3, [(3, 4, "upto", 0), (4, 8, None, 0)])
+    # under aligned windows of 2,048 a row of tiles starts, and a column of
+    # them ends, with its own window's
+    rule = BlockRule(aligned=2048)
+    assert fa._k_spans(rule, 6, 512, 512, 4096) \
+        == (6, 0, [(4, 6, None), (6, 7, "upto")])
+    assert fa._q_spans(rule, 1, 512, 512, 4096) \
+        == (1, [(1, 2, "upto", 0), (2, 4, None, 0)])
+    assert fa._auto_tiles(16384, rule) == ((512, 512), (512, 512))
+    assert fa._auto_tiles(512, BlockRule(aligned=128)) \
+        == ((128, 128), (128, 128))
+    assert fa.BlockRule()._fields == ("block", "kinds", "window", "aligned")
+    assert fa._form("fwd_rows", rule) == "fwd_rows_blocks"
+    assert fa._band(rule, 16384, 256, False) is None

@@ -27,6 +27,7 @@ from jax.ad_checkpoint import checkpoint_name, print_saved_residuals
 from ray_tpu.models import (
     bailing_hybrid,
     deepseek_v3,
+    evabyte,
     gpt2,
     keye_vl,
     laguna,
@@ -533,22 +534,26 @@ def test_the_names_live_in_one_tuple(monkeypatch):
     `checkpoint_name` (`layers.named` does, once, and refuses a word that
     is not the tuple's), and every name of the tuple is marked by some
     model's layer."""
-    for module in (gpt2, bailing_hybrid, deepseek_v3, keye_vl, laguna,
-                   lfm2_moe, nemotron_h, olmoe, pipeline):
+    for module in (gpt2, bailing_hybrid, deepseek_v3, evabyte, keye_vl,
+                   laguna, lfm2_moe, nemotron_h, olmoe, pipeline):
         assert "checkpoint_name(" not in inspect.getsource(module), \
             module.__name__
     assert inspect.getsource(layers).count("checkpoint_name(") == 1
     plans = with_room(monkeypatch, ROOMY)
     # the four, the model whose layers mark the state-space names, the one
-    # whose layers mark an indexer's, the one whose attention has a gate and
-    # the one whose layers mark a delta-rule mixer's
+    # whose layers mark an indexer's, the one whose attention has a gate, the
+    # one whose layers mark a delta-rule mixer's and the one whose layers
+    # mark an EVA mixer's summaries (at a shape its kernels take)
     for module, cfg in [v[:2] for v in FOUR.values()] + [
             (nemotron_h, dataclasses.replace(nemotron_h.NEMOTRON_H_TINY,
                                              **F32)),
             (keye_vl, dataclasses.replace(keye_vl.KEYE_VL_TINY, **F32)),
             (laguna, dataclasses.replace(laguna.LAGUNA_TINY, **F32)),
             (bailing_hybrid, dataclasses.replace(
-                bailing_hybrid.BAILING_HYBRID_TINY, **F32))]:
+                bailing_hybrid.BAILING_HYBRID_TINY, **F32)),
+            (evabyte, dataclasses.replace(
+                evabyte.EVABYTE_TINY, head_dim=128, window=128, chunk=8,
+                **F32))]:
         backward_jaxpr(module, cfg, remat=True)
     assert {n for plan in plans for n in plan["marked"]} == set(KEPT_NAMES)
     assert not set(KEPT_NAMES) & set(KEPT_RESIDUALS)

@@ -27,6 +27,7 @@ from benchmark.harness import scope_trace
 from ray_tpu.models import (
     bailing_hybrid,
     deepseek_v3,
+    evabyte,
     gpt2,
     keye_vl,
     laguna,
@@ -59,7 +60,12 @@ MODELS = {
     # plain form
     "bailing_hybrid": (bailing_hybrid, dataclasses.replace(
         bailing_hybrid.BAILING_HYBRID_TINY, head_dim=128, kda_chunk=64)),
+    # heads, windows and chunks the EVA kernels take, not the plain form
+    "evabyte": (evabyte, dataclasses.replace(
+        evabyte.EVABYTE_TINY, head_dim=128, window=128, chunk=8)),
 }
+# the positions of a model's sequence: two of evabyte's windows
+SEQ = {"evabyte": 256}
 CASES = [(name, remat) for name in MODELS for remat in (False, True)]
 # what every model's step must have a matmul under
 EXPECTED = {
@@ -112,6 +118,9 @@ EXPECTED = {
                        "attention/kernel/bwd_fused", "attention/gate",
                        "attention/out", "ffn/dense", "ffn/moe/route",
                        "ffn/moe/experts", "ffn/moe/shared", "head_and_loss"},
+    "evabyte": {"eva/qkv", "eva/summary", "eva/local/fwd_rows_blocks",
+                "eva/local/bwd_fused_blocks", "eva/remote", "eva/out",
+                "ffn/dense", "head_and_loss"},
 }
 # components of an `op_name` that jax puts there itself (`jnp.einsum` its
 # subscripts: `ops/ssd.py`'s products)
@@ -142,7 +151,8 @@ def lowered_text(name: str, remat: bool) -> str:
     params = jax.eval_shape(lambda key: module.init_params(key, cfg),
                             jax.random.PRNGKey(0))
     opt_state = jax.eval_shape(optimizer.init, params)
-    batch = {"tokens": jax.ShapeDtypeStruct((2, 65), jnp.int32)}
+    batch = {"tokens": jax.ShapeDtypeStruct((2, SEQ.get(name, 64) + 1),
+                                            jnp.int32)}
     traced = jax.jit(step).trace(params, opt_state, batch)
     return traced.lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
 
@@ -262,6 +272,12 @@ def test_every_matmul_and_kernel_is_under_a_scope(name, remat):
     # kernels, in the one model that has them
     assert ({"kda/gate", "kda/gate_norm", "kda/rule"} <= every) \
         is (name == "bailing_hybrid")
+    # an EVA mixer's merge holds no matmul; its kernels stand under the
+    # mixer's scopes (the flash pair under `eva/local`, not `attention`)
+    assert ({"eva/merge", "eva/local/fwd_rows_blocks", "eva/remote",
+             "eva/summary"} <= every) is (name == "evabyte")
+    if name == "evabyte":
+        assert not {s for s in every if s.startswith("attention")}
 
 
 @pytest.mark.parametrize("remat", [False, True])
@@ -349,7 +365,8 @@ def test_rematted_computation_exactly_under_remat(name, remat):
     if remat:
         # a layer of ONE mixer ends in W_o's product, which no backward
         # reads: its replay stops at the kernel
-        last = "attention/qkv" if name == "nemotron_h" else "attention/out"
+        last = {"nemotron_h": "attention/qkv",
+                "evabyte": "eva/out"}.get(name, "attention/out")
         assert layer_scopes >= {last}, replayed
     else:
         assert not layer_scopes, replayed
@@ -361,7 +378,9 @@ def test_rematted_computation_exactly_under_remat(name, remat):
     # the six families of the chunked loss: one loop over the chunks, whose
     # body holds a chunk's three products, all of the forward pass
     head = [full for _, full in found if scope(full) == "head_and_loss"]
-    assert len(head) == 3, head
+    # (a walk a prediction head where a model has several)
+    walks = MODELS[name][1].n_pred_heads if name == "evabyte" else 1
+    assert len(head) == 3 * walks, head
     assert {scope_trace.phase_of(full) for full in head} == {"fwd"}
     assert all("while/body" in full for full in head)
 

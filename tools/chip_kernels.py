@@ -173,6 +173,16 @@ the whole of (-5, 0) with a sixteenth of the positions at the bound
 benchmark's reference's), one for the plain chunked form and one for the
 Mosaic kernels at each count of chunks a grid step of ``KDA_STEP_CHUNKS``
 (see `kda_case`; two minutes).
+
+``evabyte_16k`` is EVA attention alone (`ops/eva.py`) at the evabyte cell's
+shape, one sequence of 16,384, 16 heads of 128, windows of 2,048 in chunks of
+16 (``EVA_CASES``): a line for the float32 definition (every key and every
+summary scored under boolean masks, 256 query rows at a time: the
+benchmark's reference's), one for the plain masked form by windows and one
+for the kernels (the flash pair under `BlockRule(aligned=2048)`, the remote
+pair, the pooling pair): forward and forward + backward ms, the Mosaic
+kernels' own ms, the longest operations, and the error of o and the five
+gradients against the definition (see `eva_case`; two minutes).
 """
 
 from __future__ import annotations
@@ -241,6 +251,10 @@ KDA_CASES = {
 }
 # the chunks a grid step takes that `kda_case` times the kernels at
 KDA_STEP_CHUNKS = (1, 2, 4)
+# (B, S, H, D, window, chunk): EVA attention of the evabyte cell's layers
+EVA_CASES = {
+    "evabyte_16k": (1, 16384, 16, 128, 2048, 16),
+}
 
 SSD_CASES = {
     "ssd_8k": (2, 8192, 64, 64, 8, 128, 128),
@@ -1572,6 +1586,98 @@ def kda_case(name, dtype):
     jax.clear_caches()
 
 
+def eva_case(name, dtype):
+    """EVA attention at ``EVA_CASES[name]`` (`ops/eva.py`): a line for the
+    float32 definition (`benchmark/reference/evabyte.py`: the summaries by a
+    reshape and a softmax, a block of 256 query rows scored against ALL keys
+    and ALL summaries under the masks of A_i and B_i), one for the plain
+    masked form by windows (`_plain`: what a declined shape runs) and one for
+    the kernels: forward ms and forward + backward ms of one `jax.grad` in
+    all five operands, every operation counted (the transposes to head-major,
+    the merge, delta and the sums of the cotangents among them) and the
+    Mosaic kernels alone, the longest operations, the pairs attended over
+    those the forward kernels visit, and the largest error of o and of the
+    five gradients relative to the definition's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.reference import evabyte as reference
+
+    eva = importlib.import_module("ray_tpu.ops.eva")
+    B, S, H, D, window, chunk = EVA_CASES[name]
+    ks = jax.random.split(jax.random.PRNGKey(0), 6)
+    q, k, v = (jax.random.normal(key, (B, S, H, D)).astype(dtype)
+               for key in ks[:3])
+    phi, mu = (jnp.clip(jax.random.normal(key, (H, D)), -1, 1) * D ** -0.5
+               for key in ks[3:5])
+    phi, mu = phi.astype(dtype), mu.astype(dtype)
+    seed = jax.random.normal(ks[5], (B, S, H, D), jnp.float32)
+    args = (q, k, v, phi, mu)
+    sizes = reference.Sizes(H, chunk, window, 1, 1e5, 1e-5, 256, 2048)
+
+    def definition(q, k, v, phi, mu):
+        """`reference.eva` between its projections: one sequence a time."""
+        f32 = lambda x: x.astype(jnp.float32)
+
+        def one(q, k, v):
+            ks, vs = reference.summaries(k, v, f32(phi), f32(mu), chunk)
+            keys = jnp.concatenate([k, ks], 0).transpose(1, 0, 2)
+            values = jnp.concatenate([v, vs], 0).transpose(1, 0, 2)
+            qh = q.transpose(1, 0, 2)
+
+            @jax.checkpoint
+            def some(start):
+                seen = jnp.concatenate(reference.attended(
+                    start + jnp.arange(256), S, sizes), axis=1)
+                qb = jax.lax.dynamic_slice_in_dim(qh, start, 256, axis=1)
+                scores = qb @ keys.transpose(0, 2, 1) / jnp.sqrt(
+                    jnp.float32(D))
+                return jax.nn.softmax(jnp.where(seen, scores, -jnp.inf),
+                                      axis=-1) @ values
+
+            out = jax.lax.map(some, jnp.arange(0, S, 256))
+            return out.transpose(0, 2, 1, 3).reshape(S, H, D)
+
+        return jax.lax.map(lambda a: one(*a), (f32(q), f32(k), f32(v)))
+
+    def both(rule):
+        return jax.jit(rule), jax.jit(jax.value_and_grad(
+            lambda *a: jnp.sum(rule(*a).astype(jnp.float32) * seed),
+            tuple(range(5))))
+
+    rel = lambda got, want: round(float(
+        np.max(np.abs(np.asarray(got, np.float32)
+                      - np.asarray(want, np.float32)))
+        / np.max(np.abs(np.asarray(want, np.float32)))), 5)
+    exact = both(definition)
+    with jax.default_matmul_precision("highest"):
+        want = (exact[0](*args), *exact[1](*args)[1])
+    local, remote = eva.attended_pairs(S, window, chunk)
+    for form in ("definition", "plain", "kernels"):
+        forward, grad = exact if form == "definition" else both(
+            (lambda *a: eva._plain(*a, window, chunk)) if form == "plain"
+            else lambda *a: eva.eva_attention(*a, window=window, chunk=chunk))
+        line = {"case": name, "form": form, "dtype": jnp.dtype(dtype).name,
+                "fwd_ms": busy_ms(forward, *args),
+                "fwd_bwd_ms": busy_ms(grad, *args),
+                "fwd_kernel_ms": kernel_ms(forward, *args),
+                "fwd_bwd_kernel_ms": kernel_ms(grad, *args),
+                # the attended pairs' operations at the matrix unit's peak
+                "least_fwd_ms": round(
+                    B * H * (local + remote) * 4 * D / 197e12 * 1e3, 4),
+                "least_fwd_bwd_ms": round(
+                    B * H * (local + remote) * 12 * D / 197e12 * 1e3, 4),
+                "mosaic_kernels": grad.lower(*args).compile().as_text(
+                    ).count('custom_call_target="tpu_custom_call"')}
+        if form == "kernels":
+            line["longest_ops"] = longest_ops(grad, *args, top=10)
+        got = (forward(*args), *grad(*args)[1])
+        line["rel_err"] = {what: rel(g_, w_) for what, g_, w_ in zip(
+            ("o", "dq", "dk", "dv", "dphi", "dmu"), got, want)}
+        yield line
+
+
 def window_case(name, dtype):
     """One line a rule (the window, then none) and a tile (`_auto_tiles`',
     then `WINDOW_TILES`) of ``flash_attention_bshd`` at the case's shape:
@@ -1726,7 +1832,7 @@ def main():
                                  *SELECT_CASES, *HEAD_CASES,
                                  *GATENORM_CASES, *HEADNORM_CASES,
                                  *CONV_CASES, *WINDOW_CASES,
-                                 *SSCAN_CASES, *KDA_CASES],
+                                 *SSCAN_CASES, *KDA_CASES, *EVA_CASES],
                         help=f"run these only ({', '.join(CASES)}, "
                              f"{', '.join(WINDOW_CASES)}, "
                              f"{', '.join((*MOE_CASES, *MOE_ALL_CASES))}, "
@@ -1740,7 +1846,8 @@ def main():
                              f"{', '.join(HEADNORM_CASES)}, "
                              f"{', '.join(CONV_CASES)}, "
                              f"{', '.join(SSCAN_CASES)}, "
-                             f"{', '.join(KDA_CASES)}; default: all)")
+                             f"{', '.join(KDA_CASES)}, "
+                             f"{', '.join(EVA_CASES)}; default: all)")
     args = parser.parse_args()
     swept = [*SWEEP, *SSD_SWEEP, *TARGET_SWEEP, *SCORES_SWEEP, *SELECT_SWEEP]
     if args.sweep and set(args.sweep) - set(swept):
@@ -1748,7 +1855,7 @@ def main():
     known = [*CASES, *MOE_CASES, *MOE_ALL_CASES, *SHORTCONV_CASES,
              *SSD_CASES, *TARGET_CASES, *SCORES_CASES, *SELECT_CASES,
              *HEAD_CASES, *GATENORM_CASES, *HEADNORM_CASES, *CONV_CASES,
-             *WINDOW_CASES, *SSCAN_CASES, *KDA_CASES]
+             *WINDOW_CASES, *SSCAN_CASES, *KDA_CASES, *EVA_CASES]
     if set(args.cases) - set(known):
         parser.error(f"--cases: no such case in {known}")
 
@@ -1761,6 +1868,7 @@ def main():
         _auto_tiles,
     )
     from ray_tpu.ops import flash_attention as fa
+    from ray_tpu.ops.eva import EvaFallbackWarning
     from ray_tpu.ops.kda import KdaFallbackWarning
     from ray_tpu.ops.ssd import SsdFallbackWarning
     from ray_tpu.util.compile_cache import ensure_compile_cache
@@ -1769,6 +1877,7 @@ def main():
     warnings.simplefilter("error", AttentionFallbackWarning)
     warnings.simplefilter("error", SsdFallbackWarning)
     warnings.simplefilter("error", KdaFallbackWarning)
+    warnings.simplefilter("error", EvaFallbackWarning)
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         sys.exit(f"no TPU: jax found {dev.platform!r}")
@@ -1968,6 +2077,18 @@ def main():
                     2 if line["form"] == "kernels" else 0)
             if not ok:
                 failed.append(f"{name}:{line['form']}:{line['step_chunks']}")
+            print(json.dumps({**line, "ok": ok,
+                              "device_kind": dev.device_kind}), flush=True)
+    for name in EVA_CASES:
+        for line in eva_case(name, jnp.bfloat16) \
+                if name in args.cases else ():
+            # forward and backward of the flash pair, the remote pair and
+            # the pooling pair
+            ok = max(line["rel_err"].values()) < TOLERANCE \
+                and line["mosaic_kernels"] == (
+                    6 if line["form"] == "kernels" else 0)
+            if not ok:
+                failed.append(f"{name}:{line['form']}")
             print(json.dumps({**line, "ok": ok,
                               "device_kind": dev.device_kind}), flush=True)
     if failed:
