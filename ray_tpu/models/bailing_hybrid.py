@@ -52,8 +52,8 @@ kda/{proj,conv,gate,rule,gate_norm,out_proj}, attention/{latent_down,
 latent_up,kernel,gate,out}, ffn/dense, ffn/moe/{route,dispatch,experts,
 combine,shared}, head_and_loss, optimizer_update, routing_bias_update.
 Counted on the job timeline as the step is traced: `kda.layers`,
-`kda.rule_kernel`, `kda.rule_plain`, `kda.bwd_kernel`, `kda.kernel_calls`
-(`ops/kda.py`),
+`kda.rule_kernel`, `kda.rule_plain`, `kda.bwd_kernel`, `kda.kernel_passes`,
+`kda.heads_per_step` (`ops/kda.py`),
 `kda.head_norm_rows_fused` (`ops/gated_norm.py`: the rows of q's and k's L2
 norms and of the head's norm whose kernels ran, three calls a traced KDA
 layer), `moe.route_groups` (`ops/moe.py`), `attention.gated`.

@@ -41,19 +41,31 @@ whole chunk that cost o a part in a hundred (`tests/test_kda.py`).  The
 cumulative sums are float32 products with triangles of ones, inside a
 sub-block and up to it apart, so that neither rounds the other.
 
-T: the triangle is inverted without a loop over its rows, by products alone
-(the MXU is all but idle here).  Its 16-wide diagonal blocks A_d are
+T: the triangle is inverted without a loop over its rows, by products
+alone.  Its 16-wide diagonal blocks A_d are
 nilpotent, so (I + A_d)^-1 = (I - A_d)(I + A_d^2)(I + A_d^4)(I + A_d^8); with
 that D and N = D (A - A_d), which is nilpotent over the C / 16 blocks,
 T = (I - N)(I + N^2) D.  Ten products of (C, C) a chunk, operands in the
 inputs' type and sums in float32, the identity kept apart so that no
 1 + small is ever rounded.
 
-**The kernels.**  A grid of (B, H, steps), the steps walked in order
-(`arbitrary`), a step `_STEP_CHUNKS` chunks one after the other; q, k,
-beta k, beta v and g are read where they lie, a head's 128 lanes of
-(B, S, H K); the state, transposed (V, K) so that the decay of a key channel
-is a factor a lane, lives in a VMEM scratch.  beta never enters a kernel:
+**The kernels.**  A chunk of one head is a chain: the sums, A, the ten
+products of T one after the other, U against the carried state, o, the
+state; each product fills a quarter of a 128 x 128 matrix unit and the next
+waits for it.  The heads' chains are independent, so a grid step takes
+several heads and runs their chains side by side: the chunk's algebra above
+is written over (heads, ., .) arrays, every product batched over the heads
+(`_dot`), and of two products that follow each other in the unrolled body
+the second is another head's and waits for nothing.  The grid is (B,
+H / heads, chunks), the chunks walked in order (`arbitrary`), a step one
+chunk of its heads; `_step_heads` takes the most heads that divide H, up to
+`_STEP_HEADS`, whose blocks and temporaries fit the kernels' VMEM
+(`_step_bytes`), and one head a step is the same body with a batch of one.
+q, k, beta k, beta v and g are read where they lie, blocks of (1, C,
+heads x 128) of (B, S, H K), a head's 128 lanes cut out
+of the block as one matrix of the batch (`_by_heads`); the states,
+transposed (V, K) so that the decay of a key channel is a factor a lane,
+live in a VMEM scratch of (heads, V, K).  beta never enters a kernel:
 XLA makes beta k and beta v (`_scaled`) and takes their cotangents apart.
 Between `kda`'s two ends everything is held as those (B, S, H K) rows, the
 `custom_vjp`'s operands and cotangents included, and a head's beta reaches
@@ -74,15 +86,19 @@ decay is taken with the same row and column factors as the forward.
 
 **What the shape decides** (`_kernel_problem`): K = V = 128 (a head is a
 lane tile) and a chunk of 16, 32 or 64.  Every other shape runs the chunked
-form as plain `jax.numpy` (`_plain`: the same chunk's algebra, `vmap`ped
-over batch and heads, a `lax.scan` over the chunks' carry), which `jax.grad`
+form as plain `jax.numpy` (`_plain`: the same chunk's algebra over all H
+heads at once, the batch the kernels cut into grid steps, `vmap`ped over
+the batch rows, a `lax.scan` over the chunks' carry), which `jax.grad`
 differentiates and which is also what any platform but a TPU runs beyond the
 interpreter's sizes (`ops.by_platform`).
 
 Counts itself on the job timeline as the step is traced: `kda.layers` (a
 call), `kda.rule_kernel` / `kda.rule_plain` (a call that took the kernels /
 that a shape or a platform declined), `kda.bwd_kernel` (a backward rule
-traced with its kernel), `kda.kernel_calls` (`pallas_call`s built: 2 a layer).
+traced with its kernel), `kda.kernel_passes` (passes traced with a kernel,
+forward or backward: 2 a layer) and `kda.heads_per_step` (the heads a grid
+step of each of those passes takes, summed: over `kda.kernel_passes`, the
+heads a step).
 """
 
 from __future__ import annotations
@@ -104,10 +120,16 @@ _LANE = 128
 _SUB = 16
 _HIGHEST = jax.lax.Precision.HIGHEST
 _F32 = jnp.float32
-# chunks a grid step takes, one after the other, unrolled: what of a chunk
-# does not wait for the carried state (its sums, A, T) overlaps the chunk
-# before it
-_STEP_CHUNKS = 2
+# heads a grid step takes at most, their chains side by side: all 16 of the
+# ling cell's (a layer's kernels at 1, 2, 4, 8 and 16 heads a step: forward
+# 8.02, 4.43, 2.76, 2.03 and 1.83 ms, backward 6.22, 3.93, 2.77, 2.44 and
+# 2.39; a second chunk a step, unrolled, which was worth 6 % at one head, is
+# worth nothing from 8 heads on and went: PERF.md section 6, PR 70)
+_STEP_HEADS = 16
+# float32 (C, 128) arrays a chunk's body holds a head at once, as
+# `_step_bytes` counts them: the least VMEM limit under which Mosaic compiles
+# the backward, less its blocks, is 20 to 21 of them at 4, 8 and 16 heads
+_LIVE = 24
 
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -120,16 +142,20 @@ class KdaFallbackWarning(UserWarning):
 
 
 # ---------------------------------------------------------------------------
-# one chunk's algebra: 2-D arrays only, so that a kernel's body and the plain
+# one chunk's algebra, several heads side by side: arrays of (heads, ., .),
+# every product batched over the heads, so that a kernel's body and the plain
 # form are the same lines
 # ---------------------------------------------------------------------------
 
 def _dot(a, b, dims):
-    """a . b in float32, contracting a's axis dims[0] with b's dims[1];
+    """a . b a head, in float32: a and b (heads, ., .), the axis dims[0] of
+    a's matrices contracted with dims[1] of b's; a 2-D a is every head's.
     float32 operands are multiplied as float32."""
+    if a.ndim == 2:
+        a = jnp.broadcast_to(a, (b.shape[0], *a.shape))
     exact = a.dtype == _F32 and b.dtype == _F32
     return jax.lax.dot_general(
-        a, b, (((dims[0],), (dims[1],)), ((), ())),
+        a, b, (((dims[0] + 1,), (dims[1] + 1,)), ((0,), (0,))),
         preferred_element_type=_F32, precision=_HIGHEST if exact else None)
 
 
@@ -156,9 +182,9 @@ def _inverse_less_identity(X, steps, mm):
 
 
 def _solve(A, dtype):
-    """T - I for T = (I + A)^-1, A (C, C) float32 strictly lower; products
-    with operands in ``dtype``."""
-    C = A.shape[0]
+    """T - I for T = (I + A)^-1, A (heads, C, C) float32 strictly lower;
+    products with operands in ``dtype``."""
+    C = A.shape[-1]
     mm = lambda x, y: _dot(x.astype(dtype), y.astype(dtype), (1, 0))
     rows, cols = _iota(C)
     near = rows // _SUB == cols // _SUB
@@ -173,12 +199,13 @@ def _solve(A, dtype):
 
 
 class _Chunk:
-    """What both passes make of a chunk's q, k, beta k and g (C, K): the
-    sums, the decays' factors, P and T less the identity in the inputs' type
-    (``T``: the forward's, handed to the backward; None: made here from A)."""
+    """What both passes make of a chunk's q, k, beta k and g (heads, C, K):
+    the sums, the decays' factors, P and T less the identity in the inputs'
+    type (``T``: the forward's, handed to the backward; None: made here from
+    A)."""
 
     def __init__(self, q, k, kb, g, T=None):
-        C = q.shape[0]
+        C = q.shape[1]
         self.C, self.dtype = C, q.dtype
         dtype = q.dtype
         rows, cols = _iota(C)
@@ -194,15 +221,15 @@ class _Chunk:
         G = inside + before
         self.row = jnp.exp(inside - middle)         # exp(G_t - R_a(t))
         self.start = jnp.exp(G)                     # exp(G_t), from the chunk's
-        self.total = G[C - 1:C]                     # (1, K)
+        self.total = G[:, C - 1:C]                  # (heads, 1, K)
         self.end = jnp.exp(self.total - G)          # exp(G_C - G_i)
         # the column side of sub-block a's rows: exp(R_a - G_i) up to the
         # sub-block's last column, 0 behind it
-        row_of = jax.lax.broadcasted_iota(jnp.int32, G.shape, 0)
+        row_of = jax.lax.broadcasted_iota(jnp.int32, G.shape, 1)
         self.col = []
         for a in range(C // _SUB):
             at = slice(a * _SUB, a * _SUB + 1)
-            origin = before[at] + middle[at]        # (1, K): R_a
+            origin = before[:, at] + middle[:, at]  # (heads, 1, K): R_a
             self.col.append(jnp.exp(jnp.where(
                 row_of < (a + 1) * _SUB, origin - G, -jnp.inf)))
         f32 = lambda x: x.astype(_F32)
@@ -221,49 +248,49 @@ class _Chunk:
         return [slice(a * _SUB, (a + 1) * _SUB) for a in range(self.C // _SUB)]
 
     def pairs(self, x_row):
-        """(C, C): sum(x_t * k_i * D(t, i)), sub-block of rows by sub-block;
-        the entries above a sub-block's last column are 0, those above the
-        diagonal inside it finite and for the caller to mask."""
+        """(heads, C, C): sum(x_t * k_i * D(t, i)), sub-block of rows by
+        sub-block; the entries above a sub-block's last column are 0, those
+        above the diagonal inside it finite and for the caller to mask."""
         return jnp.concatenate(
-            [_dot(x_row[rows], self.k_col[a], (1, 1))
-             for a, rows in enumerate(self.blocks())], axis=0)
+            [_dot(x_row[:, rows], self.k_col[a], (1, 1))
+             for a, rows in enumerate(self.blocks())], axis=1)
 
     def solved(self, x):
-        """T x, x (C, .) in the inputs' type."""
+        """T x, x (heads, C, .) in the inputs' type."""
         return x.astype(_F32) + _dot(self.T, x, (1, 0))
 
     def solved_back(self, x):
-        """T' x, x (C, .) float32."""
+        """T' x, x (heads, C, .) float32."""
         return x + _dot(self.T, x.astype(self.dtype), (0, 0))
 
 
 def _chunk_forward(q, k, kb, vb, g, state, want_o=True):
-    """One chunk of one head: q, k, kb = beta k (C, K), vb = beta v (C, V),
-    g (C, K) float32, ``state`` the state that enters, TRANSPOSED (V, K)
-    float32 -> (o (C, V) float32, the state that leaves, the chunk's T as
-    `_Chunk` holds it).  Nothing reads ``want_o``:
-    `benchmark/tests/bailing_hybrid_faults.py` hands it on."""
+    """One chunk of a few heads: q, k, kb = beta k (heads, C, K), vb = beta v
+    (heads, C, V), g (heads, C, K) float32, ``state`` the state that enters,
+    TRANSPOSED (heads, V, K) float32 -> (o (heads, C, V) float32, the state
+    that leaves, the chunk's T as `_Chunk` holds it).  Nothing reads
+    ``want_o``: `benchmark/tests/bailing_hybrid_faults.py` hands it on."""
     c = _Chunk(q, k, kb, g)
     dtype = c.dtype
     state_x = state.astype(dtype)
-    W = c.solved(c.kb_start)                                    # (C, K)
-    U = c.solved(vb) - _dot(W.astype(dtype), state_x, (1, 1))   # (C, V)
+    W = c.solved(c.kb_start)                                    # (., C, K)
+    U = c.solved(vb) - _dot(W.astype(dtype), state_x, (1, 1))   # (., C, V)
     U_x = U.astype(dtype)
     o = _dot(c.q_start, state_x, (1, 1)) + _dot(c.P.astype(dtype), U_x, (1, 0))
     return o, jnp.exp(c.total) * state + _dot(U_x, c.k_end, (0, 0)), c.T
 
 
 def _chunk_backward(q, k, kb, vb, g, state, T, do, dstate):
-    """The same chunk's cotangents: ``T`` the forward's, ``do`` (C, V),
-    ``dstate`` that of the state that LEFT, transposed (V, K) float32 ->
-    (dq, dk, dkb, dvb, dg (C, .) float32, the cotangent of the state that
-    entered)."""
+    """The same chunk's cotangents: ``T`` the forward's, ``do`` (heads, C,
+    V), ``dstate`` that of the state that LEFT, transposed (heads, V, K)
+    float32 -> (dq, dk, dkb, dvb, dg (heads, C, .) float32, the cotangent of
+    the state that entered)."""
     c = _Chunk(q, k, kb, g, T)
-    dtype, C = c.dtype, c.C
+    dtype = c.dtype
     f32 = lambda x: x.astype(_F32)
     x = lambda v: v.astype(dtype)
     state_x, dstate_x, do_x = x(state), x(dstate), x(do)
-    grown = jnp.exp(c.total)                                    # (1, K)
+    grown = jnp.exp(c.total)                                    # (., 1, K)
     # the forward's values again
     Ubar = c.solved(vb)
     W = c.solved(c.kb_start)
@@ -272,7 +299,7 @@ def _chunk_backward(q, k, kb, vb, g, state, T, do, dstate):
     # back through O, the state that leaves, and U
     dU = _dot(x(c.P), do_x, (0, 0)) + _dot(c.k_end, dstate_x, (1, 1))
     dP = jnp.where(c.cols <= c.rows, _dot(do_x, U_x, (1, 1)), 0.0)
-    dW = -_dot(x(dU), state_x, (1, 0))                          # (C, K)
+    dW = -_dot(x(dU), state_x, (1, 0))                          # (., C, K)
     dstate_in = grown * dstate + _dot(do_x, c.q_start, (0, 0)) \
         - _dot(x(dU), x(W), (0, 0))
     dvb = c.solved_back(dU)                                     # T' dU
@@ -285,22 +312,24 @@ def _chunk_backward(q, k, kb, vb, g, state, T, do, dstate):
     dq_rows, dkb_rows = [], []
     dk_col = jnp.zeros(k.shape, _F32)
     for a, rows in enumerate(c.blocks()):
-        d_pairs = x(jnp.concatenate([dP[rows], dA[rows]], axis=0))
-        sides = jnp.concatenate([c.q_row[rows], c.kb_row[rows]], axis=0)
-        back = _dot(d_pairs, c.k_col[a], (1, 0))                # (2 SUB, K)
-        dq_rows.append(back[:_SUB])
-        dkb_rows.append(back[_SUB:])
+        d_pairs = x(jnp.concatenate([dP[:, rows], dA[:, rows]], axis=1))
+        sides = jnp.concatenate([c.q_row[:, rows], c.kb_row[:, rows]], axis=1)
+        back = _dot(d_pairs, c.k_col[a], (1, 0))                # (., 2 SUB, K)
+        dq_rows.append(back[:, :_SUB])
+        dkb_rows.append(back[:, _SUB:])
         dk_col = dk_col + c.col[a] * _dot(d_pairs, sides, (0, 0))
-    dq = c.row * jnp.concatenate(dq_rows, axis=0) \
+    dq = c.row * jnp.concatenate(dq_rows, axis=1) \
         + c.start * _dot(do_x, state_x, (1, 0))
-    dkb = c.row * jnp.concatenate(dkb_rows, axis=0) + c.start * dkb_start
+    dkb = c.row * jnp.concatenate(dkb_rows, axis=1) + c.start * dkb_start
     dk_end = c.end * _dot(U_x, dstate_x, (1, 0))
     dk = dk_col + dk_end
     # d G_t, then dg_s = the sum of d G_t over t >= s
-    last = jnp.sum(f32(k) * dk_end, axis=0, keepdims=True) \
-        + grown * jnp.sum(dstate * state, axis=0, keepdims=True)
+    last = jnp.sum(f32(k) * dk_end, axis=1, keepdims=True) \
+        + grown * jnp.sum(dstate * state, axis=1, keepdims=True)
     dG = f32(q) * dq + f32(kb) * dkb - f32(k) * dk
-    dg = _dot((c.rows <= c.cols).astype(_F32), dG, (1, 0)) + last
+    # `last` first: behind the product Mosaic takes a row spread over the
+    # chunk for the product's accumulator, and cannot cut it by heads
+    dg = last + _dot((c.rows <= c.cols).astype(_F32), dG, (1, 0))
     return dq, dk, dkb, dvb, dg, dstate_in
 
 
@@ -322,13 +351,13 @@ def _by_chunks(x, C):
 
 def _plain(q, k, kb, vb, g, C):
     """The chunked form over (B, S, H, .) arrays, S a multiple of C: the
-    chunk's algebra `vmap`ped over batch and heads, a `lax.scan` over the
-    chunks' carry.  -> (o (B, S, H, V) in q's type, the state that entered
+    chunk's algebra over all the heads, `vmap`ped over the batch, a
+    `lax.scan` over the chunks' carry.  -> (o (B, S, H, V) in q's type, the state that entered
     each chunk, transposed: (B, H, chunks, V, K) float32, each chunk's T:
     (B, H, chunks, C, C) in q's type)."""
     B, S, H, K = q.shape
     V = vb.shape[-1]
-    over = jax.vmap(jax.vmap(_chunk_forward))
+    over = jax.vmap(_chunk_forward)
 
     def chunk(state, xs):
         o, after, T = over(*xs, state)
@@ -353,14 +382,44 @@ def _kernel_problem(K, V, C) -> Optional[str]:
     return None
 
 
-def _step_chunks(chunks):
-    return max(n for n in range(1, _STEP_CHUNKS + 1) if chunks % n == 0)
+def _step_bytes(heads, C, width):
+    """The VMEM a grid step of ``heads`` heads holds, by the backward, the
+    larger of the two: its sixteen blocks twice (the five inputs and do, the
+    five cotangents, the two residuals; q's type is ``width`` bytes), the
+    carried state and the float32 (C, 128) arrays the chunk's body keeps a
+    head, `_LIVE` of them."""
+    rows = heads * C * _LANE
+    blocks = rows * (9 * width + 2 * 4) \
+        + heads * (_LANE * _LANE * 4 + C * C * width)
+    return 2 * blocks + rows * 4 * _LIVE + heads * _LANE * _LANE * 4
 
 
-def _forward_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, *rest, C):
-    """A grid step: one head's few chunks, one after the other; with the
+def _step_heads(H, C, dtype):
+    """The heads a grid step takes: the most that divide H, up to
+    `_STEP_HEADS`, whose step fits the kernels' VMEM."""
+    width = jnp.dtype(dtype).itemsize
+    return max(h for h in range(1, min(H, _STEP_HEADS) + 1)
+               if H % h == 0 and (h == 1 or _step_bytes(h, C, width)
+                                  <= _COMPILER_PARAMS.vmem_limit_bytes))
+
+
+def _by_heads(ref):
+    """A (1, C, heads x 128) block as (heads, C, 128): a head's lane tile a
+    matrix of the batch."""
+    return jnp.stack([ref[0, :, h * _LANE:(h + 1) * _LANE]
+                      for h in range(ref.shape[2] // _LANE)])
+
+
+def _to_heads(ref, x):
+    """`_by_heads`' inverse: x (heads, C, 128) into the block."""
+    for h in range(x.shape[0]):
+        ref[0, :, h * _LANE:(h + 1) * _LANE] = x[h].astype(ref.dtype)
+
+
+def _forward_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, *rest):
+    """A grid step: one chunk of a few heads, side by side; with the
     backward's residuals for results (``states``), the state that entered
-    each chunk and the chunk's T into them."""
+    the chunk and the chunk's T into them."""
     *kept_refs, state_ref = rest
 
     @pl.when(pl.program_id(2) == 0)
@@ -368,39 +427,29 @@ def _forward_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, *rest, C):
         state_ref[...] = jnp.zeros_like(state_ref)
 
     state = state_ref[...]
-    for j in range(q_ref.shape[1] // C):
-        rows = slice(j * C, (j + 1) * C)
-        o, after, T = _chunk_forward(
-            q_ref[0, rows], k_ref[0, rows], kb_ref[0, rows], vb_ref[0, rows],
-            g_ref[0, rows], state)
-        for ref, kept in zip(kept_refs, (state, T)):
-            ref[0, 0, j] = kept
-        state = after
-        o_ref[0, rows] = o.astype(o_ref.dtype)
-    state_ref[...] = state
+    o, after, T = _chunk_forward(
+        *map(_by_heads, (q_ref, k_ref, kb_ref, vb_ref, g_ref)), state)
+    for ref, kept in zip(kept_refs, (state, T)):
+        ref[0, :, 0] = kept
+    state_ref[...] = after
+    _to_heads(o_ref, o)
 
 
 def _backward_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, before_ref, t_ref,
                      do_ref, dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref,
-                     dstate_ref, *, C):
-    """A grid step: the steps and a step's chunks from last to first;
-    `dstate_ref` carries the cotangent of the state that LEFT the chunk."""
+                     dstate_ref):
+    """A grid step: the chunks from last to first; `dstate_ref` carries the
+    cotangent of the state that LEFT the chunk."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate_ref[...] = jnp.zeros_like(dstate_ref)
 
-    dstate = dstate_ref[...]
-    for j in reversed(range(q_ref.shape[1] // C)):
-        rows = slice(j * C, (j + 1) * C)
-        dq, dk, dkb, dvb, dg, dstate = _chunk_backward(
-            q_ref[0, rows], k_ref[0, rows], kb_ref[0, rows], vb_ref[0, rows],
-            g_ref[0, rows], before_ref[0, 0, j], t_ref[0, 0, j],
-            do_ref[0, rows], dstate)
-        dq_ref[0, rows] = dq.astype(dq_ref.dtype)
-        dk_ref[0, rows] = dk.astype(dk_ref.dtype)
-        dkb_ref[0, rows] = dkb.astype(dkb_ref.dtype)
-        dvb_ref[0, rows] = dvb.astype(dvb_ref.dtype)
-        dg_ref[0, rows] = dg
+    *grads, dstate = _chunk_backward(
+        *map(_by_heads, (q_ref, k_ref, kb_ref, vb_ref, g_ref)),
+        before_ref[0, :, 0], t_ref[0, :, 0], _by_heads(do_ref),
+        dstate_ref[...])
+    for ref, grad in zip((dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref), grads):
+        _to_heads(ref, grad)
     dstate_ref[...] = dstate
 
 
@@ -410,17 +459,21 @@ def _flat(x):
     return x.reshape(*x.shape[:2], -1)
 
 
-def _specs(B, S, H, C, n, step_of):
-    """The grid, the block of a (B, S, H x 128) operand and the blocks of
-    the backward's two residuals (the entering states, (B, H, chunks, 128,
-    128), and the chunks' T, (B, H, chunks, C, C)), n chunks a step;
-    ``step_of`` maps the grid's third index to the step's place in the
-    sequence."""
-    kept = [pl.BlockSpec((1, 1, n, *tile),
-                         lambda b, h, s: (b, h, step_of(s), 0, 0))
+def _specs(B, S, H, C, dtype, chunk_of):
+    """The grid (B, H / heads, chunks), the block of a (B, S, H x 128)
+    operand, (1, C, heads x 128), the blocks of the backward's two residuals
+    (the entering states, (B, H, chunks, 128, 128), and the chunks' T, (B, H,
+    chunks, C, C): (1, heads, 1, ., .) of each) and the carried state's
+    scratch, for `_step_heads` heads a step; ``chunk_of`` maps the grid's
+    third index to the chunk's place in the sequence."""
+    heads = _step_heads(H, C, dtype)
+    kept = [pl.BlockSpec((1, heads, 1, *tile),
+                         lambda b, h, c: (b, h, chunk_of(c), 0, 0))
             for tile in ((_LANE, _LANE), (C, C))]
-    return (B, H, S // (n * C)), pl.BlockSpec(
-        (1, n * C, _LANE), lambda b, h, s: (b, step_of(s), h)), kept
+    block = pl.BlockSpec(
+        (1, C, heads * _LANE), lambda b, h, c: (b, chunk_of(c), h))
+    return (B, H // heads, S // C), block, kept, [
+        pltpu.VMEM((heads, _LANE, _LANE), _F32)]
 
 
 @functools.partial(jax.jit, static_argnames=("C", "states", "interpret"))
@@ -431,8 +484,7 @@ def _forward(q, k, kb, vb, g, C, states=False, interpret=False):
     in q's type."""
     B, S, H, K = q.shape
     V = vb.shape[-1]
-    n = _step_chunks(S // C)
-    grid, block, kept = _specs(B, S, H, C, n, lambda s: s)
+    grid, block, kept, scratch = _specs(B, S, H, C, q.dtype, lambda c: c)
     out_specs = [block]
     out_shape = [jax.ShapeDtypeStruct((B, S, H * V), q.dtype)]
     if states:
@@ -440,9 +492,9 @@ def _forward(q, k, kb, vb, g, C, states=False, interpret=False):
         out_shape += [jax.ShapeDtypeStruct((B, H, S // C, V, K), _F32),
                       jax.ShapeDtypeStruct((B, H, S // C, C, C), q.dtype)]
     o, *kept = pl.pallas_call(
-        functools.partial(_forward_kernel, C=C),
+        _forward_kernel,
         grid=grid, in_specs=[block] * 5, out_specs=out_specs,
-        out_shape=out_shape, scratch_shapes=[pltpu.VMEM((V, K), _F32)],
+        out_shape=out_shape, scratch_shapes=scratch,
         compiler_params=_COMPILER_PARAMS, interpret=interpret,
     )(*(_flat(x) for x in (q, k, kb, vb, g)))
     return (o.reshape(B, S, H, V), *kept)
@@ -453,19 +505,17 @@ def _backward(q, k, kb, vb, g, do, before, solved, C, interpret=False):
     """``before``, ``solved``: the states and the T `_forward` wrote ->
     (dq, dk, dkb, dvb, dg), each in its primal's shape and type."""
     B, S, H, K = q.shape
-    V = vb.shape[-1]
-    n = _step_chunks(S // C)
-    steps = S // (n * C)
-    back = lambda s: steps - 1 - s
-    grid, block, kept = _specs(B, S, H, C, n, back)
+    last = S // C - 1
+    grid, block, kept, scratch = _specs(
+        B, S, H, C, q.dtype, lambda c: last - c)
     primals = (q, k, kb, vb, g)
     grads = pl.pallas_call(
-        functools.partial(_backward_kernel, C=C),
+        _backward_kernel,
         grid=grid, in_specs=[block] * 5 + [*kept, block],
         out_specs=[block] * 5,
         out_shape=[jax.ShapeDtypeStruct(_flat(x).shape, x.dtype)
                    for x in primals],
-        scratch_shapes=[pltpu.VMEM((V, K), _F32)],
+        scratch_shapes=scratch,
         compiler_params=_COMPILER_PARAMS, interpret=interpret,
     )(*(_flat(x) for x in primals), before, solved, _flat(do))
     return tuple(d.reshape(x.shape) for d, x in zip(grads, primals))
@@ -475,6 +525,14 @@ def _runs_kernels(q) -> bool:
     """Whether `by_platform` gives a call whose first operand is ``q`` the
     kernels where this process traces it: on a TPU, or interpreted."""
     return interpreted(q) or jax.default_backend() == "tpu"
+
+
+def _count_pass(rows, H, C):
+    """A pass over ``rows`` (B, S, H D) traced with its kernel:
+    `kda.kernel_passes`, and under `kda.heads_per_step` the heads its grid
+    step takes."""
+    tracing.count("kda.kernel_passes")
+    tracing.count("kda.heads_per_step", _step_heads(H, C, rows.dtype))
 
 
 def _heads(arrays, H):
@@ -507,7 +565,7 @@ def _kernels_bwd(H, C, residuals, do):
         lambda *v: _flat(_plain(*_heads(v, H), C)[0]), *a[:5])[1](a[5])
     if _runs_kernels(inputs[0]):
         tracing.count("kda.bwd_kernel")
-        tracing.count("kda.kernel_calls")
+        _count_pass(inputs[0], H, C)
     return by_platform(
         lambda *a, interpret: tuple(_flat(d) for d in _backward(
             *_heads(a[:6], H), *a[6:], C=C, interpret=interpret)),
@@ -566,6 +624,7 @@ def kda(q, k, v, g, beta, chunk=64):
     else:
         taken = _runs_kernels(q)
         tracing.count("kda.rule_kernel" if taken else "kda.rule_plain")
-        tracing.count("kda.kernel_calls", int(taken))
+        if taken:
+            _count_pass(q, H, C)
         o = _kernels(q, k, kb, vb, g, H, C)
     return o.reshape(B, -1, H, V)[:, :S]

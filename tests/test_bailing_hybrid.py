@@ -280,8 +280,11 @@ def test_heads_of_128_take_the_kernels_and_match():
             assert tracing.counter("kda.rule_plain") == 0
             assert tracing.counter("kda.bwd_kernel") >= 1
             # a kernel a pass: no second forward for the backward's states
-            assert tracing.counter("kda.kernel_calls") == tracing.counter(
+            assert tracing.counter("kda.kernel_passes") == tracing.counter(
                 "kda.rule_kernel") + tracing.counter("kda.bwd_kernel")
+            # both of this size's heads a grid step in every one of them
+            assert tracing.counter("kda.heads_per_step") \
+                == cfg.n_head * tracing.counter("kda.kernel_passes")
             assert tracing.counter("moe.route_groups") == 1
             # q's and k's L2 norms and the head's norm with its gate, the
             # rows of each, a traced KDA layer

@@ -1,11 +1,13 @@
 """`ops/kda.py`: Kimi Delta Attention's rule.  The plain chunked form and the
 Pallas kernels (interpreted here) against the recurrence run position by
 position in float64 numpy and under `jax.grad` of the same recurrence in jax:
-o and all five gradients, at one chunk and at many, with g drawn AT the
-gate's bound; the state carried from chunk to chunk, and handed to the
+o and all five gradients, at one chunk and at many, at 1, 2, 3 and 16 heads
+(so many a grid step, or a divisor of them) and chunks of 16 and 64, with g
+drawn AT the gate's bound; the state carried from chunk to chunk, and handed to the
 backward as it entered each chunk; what the kernels take; a declined shape
 counted; a recomputed layer's replay; and what a TPU is given."""
 
+import functools
 import warnings
 
 import jax
@@ -13,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import ray_tpu.ops
 from ray_tpu.models import layers
 from ray_tpu.ops import interpreted
 from ray_tpu.ops import kda as K
@@ -25,6 +28,25 @@ MANY = (1, 192, 1, 128, 128)
 BATCHED = (2, 128, 1, 128, 128)
 # a shape the kernels decline: heads of 32 and 48
 DECLINED = (2, 128, 2, 32, 48)
+# (shape, chunk): the heads a grid step takes (`_step_heads`) are all of 1, 2
+# and 3 and a divisor of 16; 16 heads of 64 positions are past the
+# interpreter's size, which `interpret_all` lifts
+HEADS = {
+    "three_heads": ((1, 128, 3, 128, 128), 64),
+    "sixteen_heads": ((1, 128, 16, 128, 128), 64),
+    "one_head_chunks_of_16": ((1, 48, 1, 128, 128), 16),
+    "two_heads_chunks_of_16": ((2, 32, 2, 128, 128), 16),
+    "three_heads_chunks_of_16": ((1, 64, 3, 128, 128), 16),
+    "sixteen_heads_chunks_of_16": ((1, 32, 16, 128, 128), 16),
+}
+SHAPES = {"one_chunk": (ONE_CHUNK, 64), "three_chunks": (MANY, 64),
+          "batched": (BATCHED, 64), **HEADS}
+
+
+@pytest.fixture
+def interpret_all(monkeypatch):
+    """Every size of these tests runs the kernels interpreted."""
+    monkeypatch.setattr(ray_tpu.ops, "INTERPRET_MAX_ELEMS", 1 << 20)
 
 
 def make(shape, seed=0, at_bound=False, dtype=jnp.float32):
@@ -109,32 +131,72 @@ def plain(q, k, v, g, beta, chunk=64):
     return K._plain(q, k, K._scaled(k, beta), K._scaled(v, beta), g, C)[0]
 
 
-@pytest.mark.parametrize("shape", [ONE_CHUNK, MANY, BATCHED],
-                         ids=["one_chunk", "three_chunks", "batched"])
+@pytest.mark.parametrize("case", SHAPES)
 @pytest.mark.parametrize("rule", [plain, K.kda], ids=["plain", "kernels"])
-def test_the_rule_is_the_recurrence_position_by_position(shape, rule):
+def test_the_rule_is_the_recurrence_position_by_position(
+        case, rule, interpret_all):
+    shape, chunk = SHAPES[case]
     args, _ = make(shape)
-    close(rule(*args), by_positions(*args), 1e-5)
+    close(rule(*args, chunk=chunk), by_positions(*args), 1e-5)
 
 
-@pytest.mark.parametrize("shape", [ONE_CHUNK, MANY],
-                         ids=["one_chunk", "three_chunks"])
+@pytest.mark.parametrize("case", ["one_chunk", "three_chunks", *HEADS])
 @pytest.mark.parametrize("rule", [plain, K.kda], ids=["plain", "kernels"])
-def test_all_five_gradients_are_the_recurrences(shape, rule):
+def test_all_five_gradients_are_the_recurrences(case, rule, interpret_all):
     """The plain form under `jax.grad`; the kernels' own backward."""
+    shape, chunk = SHAPES[case]
     args, do = make(shape)
-    for name, g, w in zip(("o", *NAMES), value_and_grads(rule, args, do),
+    by_chunks = functools.partial(rule, chunk=chunk)
+    for name, g, w in zip(("o", *NAMES), value_and_grads(by_chunks, args, do),
                           value_and_grads(recurrence, args, do)):
         close(g, w, 2e-5), name
 
 
+@pytest.mark.parametrize("heads", [1, 4, 8])
+@pytest.mark.parametrize("case", [c for c in HEADS if "sixteen" in c])
+def test_the_kernels_are_the_plain_form_a_divisor_of_the_heads_a_step(
+        case, heads, interpret_all, monkeypatch):
+    """16 heads, a grid step 1, 4 or 8 of them (the grid's second axis walks
+    the rest): o and the five gradients of the kernels beside the plain
+    form's, which holds all heads at once."""
+    shape, chunk = SHAPES[case]
+    args, do = make(shape)
+    monkeypatch.setattr(K, "_STEP_HEADS", heads)
+    jax.clear_caches()
+    assert K._step_heads(16, K._chunk_size(shape[1], chunk),
+                         jnp.float32) == heads
+    for g, w in zip(
+            value_and_grads(functools.partial(K.kda, chunk=chunk), args, do),
+            value_and_grads(functools.partial(plain, chunk=chunk), args, do)):
+        close(g, w, 2e-6)
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("H, most, limit, heads", [
+    (1, 16, 64, 1), (2, 16, 64, 2), (3, 16, 64, 3), (16, 16, 64, 16),
+    (16, 8, 64, 8), (12, 8, 64, 6), (7, 4, 64, 1), (16, 1, 64, 1),
+    # by VMEM: 16 heads of a chunk of 64 in bfloat16 count 21.75 MiB, 8
+    # heads half of it, and one head is taken whatever it counts
+    (16, 16, 16, 8), (16, 16, 2, 1), (16, 16, 0, 1),
+])
+def test_the_heads_a_grid_step_takes_divide_the_heads_and_fit_vmem(
+        H, most, limit, heads, monkeypatch):
+    monkeypatch.setattr(K, "_STEP_HEADS", most)
+    monkeypatch.setattr(K, "_COMPILER_PARAMS", K.pltpu.CompilerParams(
+        vmem_limit_bytes=limit << 20))
+    assert K._step_heads(H, 64, jnp.bfloat16) == heads
+    assert K._step_bytes(16, 64, 2) == 21.75 * 2 ** 20
+
+
+@pytest.mark.parametrize("shape", [ONE_CHUNK, (1, 64, 16, 128, 128)],
+                         ids=["two_heads", "sixteen_heads"])
 @pytest.mark.parametrize("rule", [plain, K.kda], ids=["plain", "kernels"])
 def test_at_the_gates_bound_for_a_whole_chunk_it_is_finite_and_the_recurrence(
-        rule):
+        rule, shape, interpret_all):
     """g = -5 at every one of 64 positions: exp(-G) over the chunk would be
     exp(320); against origins 16 back nothing overflows and nothing is
-    lost."""
-    args, do = make(ONE_CHUNK, at_bound=True)
+    lost; several heads a grid step alike."""
+    args, do = make(shape, at_bound=True)
     got = value_and_grads(rule, args, do)
     assert all(np.isfinite(np.asarray(x)).all() for x in got)
     close(got[0], by_positions(*args), 1e-5)
@@ -199,8 +261,9 @@ def test_the_forward_hands_over_the_state_that_entered_each_chunk(shape):
     assert T.dtype == q.dtype and T.shape == (*want.shape[:3], C, C)
     close(T, T_plain, 1e-6)
     # (I + A)(I + T) = I over the first chunk of the first head
-    chunk = K._Chunk(*(rows[i][0, :C, 0] for i in (0, 1, 2, 4)))
-    A = np.tril(np.asarray(chunk.pairs(chunk.kb_row), np.float64), -1)
+    chunk = K._Chunk(*(rows[i][0, :C, :1].swapaxes(0, 1)
+                       for i in (0, 1, 2, 4)))
+    A = np.tril(np.asarray(chunk.pairs(chunk.kb_row)[0], np.float64), -1)
     eye = np.eye(C)
     close((eye + A) @ (eye + np.asarray(T[0, 0, 0], np.float64)), eye, 1e-5)
     alone, = K._forward(*rows, C=C, interpret=True)
@@ -271,20 +334,21 @@ def test_a_call_counts_itself_and_a_declined_shape_is_the_plain_form(
     """A declined shape warns, holds no `pallas_call`, gives the plain
     form's result and gradients to the last bit and counts
     `kda.rule_plain`; a taken one two kernels, the forward (which hands the
-    entering states over) and the backward, `kda.bwd_kernel` and
-    `kda.kernel_calls`."""
+    entering states over) and the backward, `kda.bwd_kernel`,
+    `kda.kernel_passes` and, both heads a grid step in either,
+    `kda.heads_per_step`."""
     args, do = make(shape)
     names = ("kda.layers", "kda.rule_kernel", "kda.rule_plain",
-             "kda.bwd_kernel", "kda.kernel_calls")
+             "kda.bwd_kernel", "kda.kernel_passes", "kda.heads_per_step")
     with warnings.catch_warnings():
         warnings.simplefilter("ignore", K.KdaFallbackWarning)
         jax.eval_shape(K.kda, *args)
-        assert [tracing.counter(n) for n in names] == [0] * 5    # no job
+        assert [tracing.counter(n) for n in names] == [0] * 6    # no job
         with tracing.timeline_span("train.fit", root=True):
             kernels = n_kernels(
                 lambda *a: value_and_grads(K.kda, a, do), *args)
             assert [tracing.counter(n) for n in names] == [
-                1, taken, 1 - taken, taken, 2 * taken]
+                1, taken, 1 - taken, taken, 2 * taken, 4 * taken]
         assert kernels == 2 * taken * A_PASS
         if not taken:
             with pytest.warns(K.KdaFallbackWarning, match="plain chunked"):

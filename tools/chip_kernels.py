@@ -171,8 +171,10 @@ cell's shape, one sequence of 16,384, 16 heads of 128, chunks of 64, g over
 the whole of (-5, 0) with a sixteenth of the positions at the bound
 (``KDA_CASES``): a line for the float32 recurrence position by position (the
 benchmark's reference's), one for the plain chunked form and one for the
-Mosaic kernels at each count of chunks a grid step of ``KDA_STEP_CHUNKS``
-(see `kda_case`; two minutes).
+Mosaic kernels as the module plans them, with the heads a grid step takes
+(``Hb``; see `kda_case`; a minute).  ``--sweep ling-16k`` times the kernels
+alone at each count of heads a grid step of ``KDA_SWEEP``, ``kept`` the
+module's own plan.
 
 ``evabyte_16k`` is EVA attention alone (`ops/eva.py`) at the evabyte cell's
 shape, one sequence of 16,384, 16 heads of 128, windows of 2,048 in chunks of
@@ -249,8 +251,10 @@ SSCAN_CASES = {
 KDA_CASES = {
     "ling_16k": (1, 16384, 16, 128, 64),
 }
-# the chunks a grid step takes that `kda_case` times the kernels at
-KDA_STEP_CHUNKS = (1, 2, 4)
+# (case, heads a grid step) `--sweep ling-16k` times a case of KDA_CASES at
+KDA_SWEEP = {
+    "ling-16k": ("ling_16k", (1, 2, 4, 8, 16)),
+}
 # (B, S, H, D, window, chunk): EVA attention of the evabyte cell's layers
 EVA_CASES = {
     "evabyte_16k": (1, 16384, 16, 128, 2048, 16),
@@ -1495,20 +1499,23 @@ def sscan_case(name, dtype):
     jax.clear_caches()
 
 
-def kda_case(name, dtype):
+def kda_case(name, dtype, plans=None):
     """One gated delta rule at ``KDA_CASES[name]`` (`ops/kda.py`): a line for
     the float32 recurrence run position by position in recomputed blocks of
     64 (`benchmark/reference/bailing_hybrid.py:delta_rule`), one for the
     plain chunked form (`_plain`: what a declined shape runs) and one for the
-    Mosaic kernels at each count of chunks a grid step of
-    ``KDA_STEP_CHUNKS`` (`kept`: the module's `_STEP_CHUNKS`): forward ms and
-    forward + backward ms of one `jax.grad` in all five operands, every
-    operation counted (beta k and beta v among them) and the kernels alone
-    (`fwd_states_kernel_ms`: the forward kernel as differentiation runs it,
-    the entering states and the chunks' T behind o), beside the least time of
-    the rule's bytes, and the largest error of o and of the five gradients
-    relative to the recurrence's.  g is drawn over the whole of (-5, 0) and a
-    sixteenth of the positions stand AT the bound."""
+    Mosaic kernels as the module plans them (`Hb`: the heads a grid step
+    takes): forward ms and forward + backward ms of one `jax.grad` in all
+    five operands, every operation counted (beta k and beta v among them)
+    and the kernels alone (`fwd_states_kernel_ms`:
+    the forward kernel as differentiation runs it, the entering states and
+    the chunks' T behind o), beside the least time of the rule's bytes, and
+    the largest error of o and of the five gradients relative to the
+    recurrence's.  g is drawn over the whole of (-5, 0) and a sixteenth of
+    the positions stand AT the bound.  With ``plans``, counts of heads a
+    grid step, the kernels' lines alone, one a count forced on the module
+    (`kept`: the count is the module's own plan; one it cannot take, by H or
+    VMEM, is left out)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1543,6 +1550,7 @@ def kda_case(name, dtype):
             lambda *a: jnp.sum(rule(*a).astype(jnp.float32) * seed),
             tuple(range(5))))
 
+    planned = lambda: kda._step_heads(H, C, dtype)
     rel = lambda got, want: round(float(
         np.max(np.abs(np.asarray(got, np.float32)
                       - np.asarray(want, np.float32)))
@@ -1554,18 +1562,20 @@ def kda_case(name, dtype):
     # traced here: its einsums in float32, not in bfloat16 passes
     with jax.default_matmul_precision("highest"):
         want = (exact[0](*args), *exact[1](*args)[1])
-    kept = kda._STEP_CHUNKS
-    forms = [("recurrence", None), ("plain", None)] \
-        + [("kernels", n) for n in KDA_STEP_CHUNKS]
-    for form, chunks in forms:
-        if chunks:
-            kda._STEP_CHUNKS = chunks
+    kept, most = planned(), kda._STEP_HEADS
+    forms = [("kernels", plan) for plan in plans] if plans else [
+        ("recurrence", None), ("plain", None), ("kernels", kept)]
+    for form, plan in forms:
+        if plan:
+            kda._STEP_HEADS = plan
             jax.clear_caches()
+            if planned() != plan:
+                continue
         forward, grad = exact if form == "recurrence" else both(
             plain if form == "plain" else
             lambda *a: kda.kda(*a, chunk=C))
         line = {"case": name, "form": form, "dtype": jnp.dtype(dtype).name,
-                "step_chunks": chunks, "kept": chunks == kept,
+                "Hb": plan, "kept": plan == kept,
                 "fwd_ms": busy_ms(forward, *args),
                 "fwd_bwd_ms": busy_ms(grad, *args),
                 "fwd_kernel_ms": kernel_ms(forward, *args),
@@ -1582,7 +1592,7 @@ def kda_case(name, dtype):
         line["rel_err"] = {what: rel(g_, w_) for what, g_, w_ in zip(
             ("o", "dq", "dk", "dv", "dg", "dbeta"), got, want)}
         yield line
-    kda._STEP_CHUNKS = kept
+    kda._STEP_HEADS = most
     jax.clear_caches()
 
 
@@ -1849,7 +1859,8 @@ def main():
                              f"{', '.join(KDA_CASES)}, "
                              f"{', '.join(EVA_CASES)}; default: all)")
     args = parser.parse_args()
-    swept = [*SWEEP, *SSD_SWEEP, *TARGET_SWEEP, *SCORES_SWEEP, *SELECT_SWEEP]
+    swept = [*SWEEP, *SSD_SWEEP, *TARGET_SWEEP, *SCORES_SWEEP, *SELECT_SWEEP,
+             *KDA_SWEEP]
     if args.sweep and set(args.sweep) - set(swept):
         parser.error(f"--sweep: no such shape in {sorted(swept)}")
     known = [*CASES, *MOE_CASES, *MOE_ALL_CASES, *SHORTCONV_CASES,
@@ -1894,6 +1905,13 @@ def main():
                             "sweep": name, **line,
                             "device_kind": dev.device_kind}), flush=True)
             if name in (*TARGET_SWEEP, *SCORES_SWEEP, *SELECT_SWEEP):
+                continue
+            if name in KDA_SWEEP:
+                case, heads = KDA_SWEEP[name]
+                for line in kda_case(case, jnp.bfloat16, heads):
+                    print(json.dumps({
+                        "sweep": name, **line,
+                        "device_kind": dev.device_kind}), flush=True)
                 continue
             if name in SSD_SWEEP:
                 case, chunks = SSD_SWEEP[name]
@@ -2076,7 +2094,7 @@ def main():
                 and line["mosaic_kernels"] == (
                     2 if line["form"] == "kernels" else 0)
             if not ok:
-                failed.append(f"{name}:{line['form']}:{line['step_chunks']}")
+                failed.append(f"{name}:{line['form']}:{line['Hb']}")
             print(json.dumps({**line, "ok": ok,
                               "device_kind": dev.device_kind}), flush=True)
     for name in EVA_CASES:
