@@ -439,8 +439,31 @@ class HostLeakError(AssertionError):
     """A runtime process, shm segment, or socket fd outlived its cluster."""
 
 
-def _runtime_pids() -> Dict[int, str]:
-    """pid -> module name for every live runtime process on this host."""
+def _started_under(pid: int, scope: Dict[str, str]) -> Optional[Dict[str, str]]:
+    """What process ``pid`` was STARTED with for the variables of ``scope``
+    (those it lacks left out): ``/proc/<pid>/environ`` is the environment
+    at exec, which an orphan keeps after it is re-parented.  None when it
+    cannot be read (the process is gone, or another user's)."""
+    try:
+        with open(f"/proc/{pid}/environ", "rb") as f:
+            items = f.read().split(b"\x00")
+    except OSError:
+        return None
+    out: Dict[str, str] = {}
+    for item in items:
+        name, _, value = item.partition(b"=")
+        name = name.decode("utf-8", "replace")
+        if name in scope:
+            out[name] = value.decode("utf-8", "replace")
+    return out
+
+
+def _runtime_pids(scope: Optional[Dict[str, str]] = None) -> Dict[int, str]:
+    """pid -> module name for every live runtime process on this host,
+    less those started under another ``scope``: a process whose
+    environment gives a variable of ``scope`` another value is a
+    neighbour's (another xdist worker's cluster).  One that lacks the
+    variables, or cannot be read, still counts."""
     out: Dict[int, str] = {}
     me = os.getpid()
     try:
@@ -458,18 +481,39 @@ def _runtime_pids() -> Dict[int, str]:
         for arg in argv:
             name = arg.decode("utf-8", "replace")
             if name in _RUNTIME_MODULES:
-                out[pid] = name
+                theirs = _started_under(pid, scope) if scope else None
+                if not theirs or all(theirs.get(k, v) == v
+                                     for k, v in scope.items()):
+                    out[pid] = name
                 break
     return out
 
 
-def _shm_segments() -> List[str]:
-    """Live ray_tpu object-store segments under /dev/shm."""
+def _shm_segments(scope: Optional[Dict[str, str]] = None) -> List[str]:
+    """Live ray_tpu object-store segments under /dev/shm, less those in
+    use under another ``scope``.  A segment is named by the process that
+    made it (``rt_store_<pid>_<hex>``: a raylet, or a driver with its
+    raylet in-process): one whose maker is alive, is not this process and
+    was not started under this ``scope`` is a neighbour's at work; one
+    whose maker is gone counts, whoever made it."""
     try:
-        return sorted(n for n in os.listdir("/dev/shm")
-                      if n.startswith("rt_store"))
+        names = sorted(n for n in os.listdir("/dev/shm")
+                       if n.startswith("rt_store"))
     except OSError:  # pragma: no cover — no /dev/shm
         return []
+    if not scope:
+        return names
+    out = []
+    for name in names:
+        try:
+            maker = int(name.split("_")[2])
+        except (IndexError, ValueError):
+            maker = os.getpid()         # no maker to ask: it counts
+        theirs = None if maker == os.getpid() \
+            else _started_under(maker, scope)
+        if theirs is None or theirs == scope:
+            out.append(name)
+    return out
 
 
 def _socket_fd_count() -> int:
@@ -488,12 +532,19 @@ def _socket_fd_count() -> int:
     return n
 
 
-def snapshot_host() -> dict:
+def snapshot_host(scope: Optional[Dict[str, str]] = None) -> dict:
     """Baseline for :func:`assert_clean_host`: take it BEFORE starting a
     cluster so pre-existing processes/segments (other sessions, the test
-    harness itself) are excluded from the leak check."""
-    return {"pids": _runtime_pids(), "shm": set(_shm_segments()),
-            "socket_fds": _socket_fd_count()}
+    harness itself) are excluded from the leak check.
+
+    ``scope`` keeps the audit to what the asker started where several
+    audits share a host: environment variables of THIS process (name ->
+    value) that everything it starts inherits and a neighbour's differ
+    in, as ``PYTEST_XDIST_WORKER`` does between the xdist workers of one
+    run.  Without it every runtime process of the host counts."""
+    scope = dict(scope or {})
+    return {"pids": _runtime_pids(scope), "shm": set(_shm_segments(scope)),
+            "socket_fds": _socket_fd_count(), "scope": scope}
 
 
 def assert_clean_host(baseline: Optional[dict] = None,
@@ -504,7 +555,9 @@ def assert_clean_host(baseline: Optional[dict] = None,
 
     Teardown is asynchronous (workers die on socket EOF, raylets reap on
     SIGTERM), so the check POLLS up to ``grace_s`` before declaring a
-    leak.  Raises :class:`HostLeakError` listing the survivors.
+    leak.  Raises :class:`HostLeakError` listing the survivors.  A
+    ``baseline`` taken under a scope (:func:`snapshot_host`) holds the
+    check to that scope.
 
     ``check_sockets`` compares this process's open socket-fd count to the
     baseline — off by default because long-lived test fixtures (shared
@@ -512,11 +565,12 @@ def assert_clean_host(baseline: Optional[dict] = None,
     """
     base_pids = set((baseline or {}).get("pids", {}))
     base_shm = set((baseline or {}).get("shm", ()))
+    scope = (baseline or {}).get("scope")   # the baseline's own
     deadline = time.monotonic() + grace_s
     while True:
-        pids = {p: m for p, m in _runtime_pids().items()
+        pids = {p: m for p, m in _runtime_pids(scope).items()
                 if p not in base_pids}
-        shm = [s for s in _shm_segments() if s not in base_shm]
+        shm = [s for s in _shm_segments(scope) if s not in base_shm]
         leaks = []
         if pids:
             leaks.append("orphan processes: " + ", ".join(

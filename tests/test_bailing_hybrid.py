@@ -16,13 +16,14 @@ fault would hide under any tolerance.
 
 import contextlib
 import dataclasses
-import functools
 import warnings
 
 import jax
 import jax.numpy as jnp
+import model_kit as kit
 import numpy as np
 import pytest
+from model_kit import max_diff
 
 from benchmark.families.bailing_hybrid import (Family, from_reference,
                                                to_reference)
@@ -47,8 +48,9 @@ F32_TOL = 5e-5
 # two thousand times float32's tolerance
 FAULT_MARGIN = 0.1
 
-pytestmark = pytest.mark.filterwarnings(
-    "ignore::ray_tpu.ops.kda.KdaFallbackWarning")
+pytestmark = [
+    pytest.mark.usefixtures("highest_precision"),
+    pytest.mark.filterwarnings("ignore::ray_tpu.ops.kda.KdaFallbackWarning")]
 
 
 def sizes(cfg=F32, **changed):
@@ -65,77 +67,65 @@ def sizes(cfg=F32, **changed):
         query_block=16, scan_block=16, row_block=32), **changed})
 
 
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+def vectors(cfg):
+    """The vectors that are not what they start as, in the order their keys
+    are drawn."""
+    for i in range(cfg.n_layer):
+        layer = f"layer_{i}"
+        yield kit.Vector((layer, "input_norm"), 0.2)
+        yield kit.Vector((layer, "post_norm"), 0.2)
+        if cfg.kind(i) == model.KDA:
+            yield kit.Vector((layer, model.KDA, "head_norm", "scale"), 0.3)
+            # decays over the whole of (-5, 0), a channel its own
+            yield kit.Vector((layer, model.KDA, "A_log"), 0.3, start=0.0)
+            yield kit.Vector((layer, model.KDA, "dt_bias"), 1.0, start=0.0)
+        else:
+            yield kit.Vector((layer, model.MLA, "kv_a_norm", "scale"), 0.3)
+        if i in cfg.moe_layers:
+            yield kit.Vector(
+                (layer, "moe", "router", model.ROUTING_BIAS), 0.1)
+    yield kit.Vector(("norm_f",), 0.2)
 
 
+@kit.once
 def make_params(seed=0, cfg=F32):
     """Seeded weights four times as wide, and vectors that are not what
     they start as."""
-    params = model.init_params(jax.random.PRNGKey(seed), cfg)
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, x: 4.0 * x if x.ndim >= 2
-        and path[-2].key != "conv" else x, params)
-    keys = iter(jax.random.split(jax.random.PRNGKey(100 + seed), 96))
-    noisy = lambda x, scale=0.5: x + scale * jax.random.normal(
-        next(keys), x.shape)
-    for i in range(cfg.n_layer):
-        layer = params[f"layer_{i}"]
-        for norm in ("input_norm", "post_norm"):
-            layer[norm] = jax.tree.map(lambda x: noisy(x, 0.2), layer[norm])
-        if model.KDA in layer:
-            m = layer[model.KDA]
-            m["head_norm"]["scale"] = noisy(m["head_norm"]["scale"], 0.3)
-            # decays over the whole of (-5, 0), a channel its own
-            m["A_log"] = noisy(jnp.zeros_like(m["A_log"]), 0.3)
-            m["dt_bias"] = noisy(jnp.zeros_like(m["dt_bias"]), 1.0)
-        else:
-            m = layer[model.MLA]
-            m["kv_a_norm"]["scale"] = noisy(m["kv_a_norm"]["scale"], 0.3)
-        if "moe" in layer:
-            router = layer["moe"]["router"]
-            router[model.ROUTING_BIAS] = noisy(router[model.ROUTING_BIAS], 0.1)
-    params["norm_f"] = jax.tree.map(lambda x: noisy(x, 0.2),
-                                    params["norm_f"])
-    return params
+    return kit.drawn(lambda key: model.init_params(key, cfg), seed,
+                     vectors(cfg), narrow=("conv",),
+                     sequence=(100 + seed, 96))
 
 
 def make_tokens(seed=0, batch=BATCH):
-    return jax.random.randint(jax.random.PRNGKey(50 + seed),
-                              (batch, SEQ + 1), 0, F32.vocab_size)
-
-
-def max_diff(a, b):
-    return float(jnp.max(jnp.abs(jnp.asarray(a, jnp.float32)
-                                 - jnp.asarray(b, jnp.float32))))
+    return kit.tokens(50 + seed, batch, SEQ, F32.vocab_size)
 
 
 def system_logits(params, tokens, cfg=F32):
+    """A program of its own a call: what a fault's patch needs."""
     return jax.jit(lambda p, t: model.forward(
         layers.cast_weights(p, cfg.compute_dtype), t, cfg)[0])(params, tokens)
 
 
-def reference_logits(params, tokens, **changed):
-    return reference.logits(*to_reference(params), tokens, sizes(**changed))
+@kit.once
+def sound_reference_logits(seed=0):
+    """The reference's logits of `make_params(seed)` on
+    `make_tokens(seed)`, one jitted program."""
+    return jax.jit(lambda p, b, t: reference.logits(p, b, t, sizes()))(
+        *to_reference(make_params(seed)), make_tokens(seed)[:, :-1])
 
 
-@functools.lru_cache(maxsize=None)
-def sound_reference_logits():
-    """The reference's logits of `make_params()` on `make_tokens()`."""
-    with jax.default_matmul_precision("highest"):
-        return reference_logits(make_params(), make_tokens()[:, :-1])
+@kit.once
+def sound_system_logits(seed=0):
+    return system_logits(make_params(seed), make_tokens(seed)[:, :-1])
 
 
 # -- against the reference ----------------------------------------------------
 
 @pytest.mark.parametrize("seed", [0, 1])
 def test_the_forward_pass_matches_the_reference_in_float32(seed):
-    params, tokens = make_params(seed), make_tokens(seed)[:, :-1]
-    want = reference_logits(params, tokens)
+    want = sound_reference_logits(seed)
     assert float(jnp.std(want)) > 0.5
-    assert max_diff(system_logits(params, tokens), want) < F32_TOL * 10
+    assert max_diff(sound_system_logits(seed), want) < F32_TOL * 10
 
 
 def test_the_stream_after_every_layer_matches():
@@ -166,7 +156,7 @@ def test_three_steps_match_the_reference_program():
     """AdamW on every leaf but the routing biases, which move by their rule:
     the losses, and the biases after three steps."""
     params, tokens = make_params(), make_tokens()
-    ref_params, biases = jax.tree.map(jnp.array, to_reference(params))
+    ref_params, biases = kit.own(to_reference(params))
     want = reference.first_losses(
         ref_params, biases, jnp.stack([tokens] * 3), sizes(), OPTIMIZER)
     optimizer = model.trained_by(reference.adamw(OPTIMIZER))

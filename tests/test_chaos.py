@@ -8,13 +8,19 @@ retriable-FIFO worker killing (`src/ray/common/memory_monitor.h:52`,
 `worker_killing_policy_retriable_fifo.cc`).
 """
 
+import os
+import signal
+import subprocess
+import sys
 import time
 
 import numpy as np
 import pytest
+from conftest import audit_scope
 
 import ray_tpu
 from ray_tpu.cluster_utils import Cluster
+from ray_tpu.util import chaos
 from ray_tpu.util.chaos import NetworkChaos, NodeKiller
 
 # Every test here spawns real cluster processes — audit for leaked
@@ -631,3 +637,68 @@ def test_oom_killer_retriable_fifo(tmp_path):
         assert marker.read_text().count("x") >= 2  # it really was killed
     finally:
         c.shutdown()
+
+
+# -- the audit itself: whose strays it counts ---------------------------------
+
+def _orphaned_stray(env):
+    """-> the pid of a process that reads as a runtime worker to the audit
+    (``ray_tpu.core.worker_main`` an element of its argv) and only sleeps,
+    started under ``env`` by a parent that is gone: re-parented, as a worker
+    whose raylet died."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import subprocess, sys\n"
+         "quiet = subprocess.DEVNULL\n"
+         "print(subprocess.Popen([sys.executable, '-c', "
+         "'import time; time.sleep(120)', 'ray_tpu.core.worker_main'], "
+         "stdin=quiet, stdout=quiet, stderr=quiet).pid)"],
+        env=env, capture_output=True, text=True, timeout=60, check=True)
+    return int(out.stdout)
+
+
+@pytest.mark.parametrize("whose", ["its_own", "a_neighbours"])
+def test_the_audit_counts_the_strays_of_its_own_scope(whose, monkeypatch):
+    """Two xdist workers on one host: an orphan (and the segment named by
+    it) started under THIS worker's scope fails this worker's audit; one
+    started under a neighbour's is the neighbour's to report, and counts
+    here only where no scope is given, as it did before there was one."""
+    scope = audit_scope() or {"PYTEST_XDIST_WORKER": "gw0",
+                              "PYTEST_XDIST_TESTRUNUID": "a-run-alone"}
+    started = dict(scope) if whose == "its_own" else dict(
+        scope, PYTEST_XDIST_WORKER=scope["PYTEST_XDIST_WORKER"] + "-next")
+    baseline = chaos.snapshot_host(scope)
+    pid = _orphaned_stray({**os.environ, **started})
+    segment = f"/dev/shm/rt_store_{pid}_a5d17e"
+    try:
+        open(segment, "wb").close()
+        names = f"pid {pid} \\(ray_tpu.core.worker_main\\).*" \
+            + os.path.basename(segment)
+        if whose == "its_own":
+            with pytest.raises(chaos.HostLeakError, match=names) as caught:
+                chaos.assert_clean_host(baseline, grace_s=0.5)
+            # and nothing of a neighbour's at work beside it
+            assert str(caught.value).count("pid ") == 1
+        else:
+            chaos.assert_clean_host(baseline, grace_s=0.5)
+            with pytest.raises(chaos.HostLeakError, match=names):
+                chaos.assert_clean_host(dict(baseline, scope=None),
+                                        grace_s=0.5)
+        # a segment whose maker is gone is a leak, whoever made it (read
+        # from a listing of this test's own: on a shared host the next
+        # driver to start sweeps a dead maker's file, `_gc_stale_stores`)
+        os.kill(pid, signal.SIGKILL)
+        deadline = time.monotonic() + 10
+        while os.path.exists(f"/proc/{pid}") and time.monotonic() < deadline:
+            time.sleep(0.05)
+        monkeypatch.setattr(chaos.os, "listdir",
+                            lambda path: [os.path.basename(segment)])
+        assert chaos._shm_segments(scope) == [os.path.basename(segment)]
+        monkeypatch.undo()
+    finally:
+        try:
+            os.kill(pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+        if os.path.exists(segment):
+            os.unlink(segment)

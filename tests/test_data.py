@@ -441,10 +441,21 @@ def test_streaming_split_feeds_train_workers(ray, tmp_path):
 
         shard = session.get_dataset_shard("train")
         assert isinstance(shard, StreamSplitDataIterator), type(shard)
+        import os
+        import time
+
         ids = []
+        rank = session.get_world_rank()
         for batch in shard.iter_batches(batch_size=32):
             ids.extend(int(x) for x in batch["id"])
-        rank = session.get_world_rank()
+            # the split is pulled: whoever asks takes.  A worker holds its
+            # first batch until the other has one too, so that neither,
+            # started late on a loaded host, finds the blocks all taken
+            open(f"{config['out']}/first_{rank}", "w").close()
+            deadline = time.monotonic() + 120
+            while not os.path.exists(f"{config['out']}/first_{1 - rank}") \
+                    and time.monotonic() < deadline:
+                time.sleep(0.02)
         with open(f"{config['out']}/rank_{rank}.json", "w") as f:
             json.dump(ids, f)
         session.report({"n": len(ids)})

@@ -13,12 +13,13 @@ and a routing fault would hide under any tolerance.
 """
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
+import model_kit as kit
 import numpy as np
 import pytest
+from model_kit import max_diff
 
 from benchmark.families.deepseek_v3 import to_reference
 from benchmark.reference import deepseek_v3 as reference
@@ -48,40 +49,29 @@ BF16_LOGITS_TOL = 0.08
 ROUTER_GAP = 0.01
 
 
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+pytestmark = pytest.mark.usefixtures("highest_precision")
 
 
+@kit.once
 def make_params(seed=0, cfg=F32, bias=True):
     """Seeded weights, and routing biases that are not 0 (up to 0.05: a
     score is a sigmoid, and the k-th and k+1-th are often closer)."""
-    params = model.init_params(jax.random.PRNGKey(seed), cfg)
-    params = jax.tree.map(lambda x: 4.0 * x if x.ndim >= 2 else x, params)
-    if bias:
-        for n, i in enumerate(cfg.moe_layers):
-            router = params[f"layer_{i}"]["moe"]["router"]
-            router[BIAS] = 0.05 * jax.random.normal(
-                jax.random.PRNGKey(77 + n), router[BIAS].shape)
-    return params
+    return kit.drawn(
+        lambda key: model.init_params(key, cfg), seed,
+        [kit.Vector((f"layer_{i}", "moe", "router", BIAS), 0.05, key=77 + n,
+                    start=0.0)
+         for n, i in enumerate(cfg.moe_layers) if bias])
 
 
 def make_tokens(seed=0):
-    return jax.random.randint(jax.random.PRNGKey(1000 + seed),
-                              (BATCH, SEQ + 1), 0, F32.vocab_size)
-
-
-def max_diff(a, b):
-    return float(jnp.max(jnp.abs(jnp.asarray(a, jnp.float32)
-                                 - jnp.asarray(b, jnp.float32))))
+    return kit.tokens(1000 + seed, BATCH, SEQ, F32.vocab_size)
 
 
 def reference_tree(tree):
     return to_reference(tree)[0]
 
 
-@functools.lru_cache(maxsize=None)
+@kit.once
 def results(which, sizes=SIZES, fault=None):
     """(logits, loss, rows sent to the experts, gradients in the
     reference's layout) of the system in float32 or of the reference (with
@@ -108,6 +98,17 @@ def results(which, sizes=SIZES, fault=None):
                 reference.losses, has_aux=True)(params, biases, tokens, sizes)
             return logits, loss, rows, grads
         return jax.jit(run)(ref_params, biases)
+
+
+def reference_logits(sizes=SIZES, fault=None):
+    """The reference's logits alone (with a seeded fault), a program of
+    its own a call: the side a fault is in."""
+    tokens = make_tokens()[:, :-1]
+    ref_params, biases = to_reference(make_params())
+    if fault:
+        ref_params, biases = fault(ref_params, biases)
+    return jax.jit(lambda p, b: reference.logits(p, b, tokens, sizes))(
+        ref_params, biases)
 
 
 @pytest.mark.parametrize("what", ["logits", "loss", "expert_rows"])
@@ -174,9 +175,9 @@ def system_steps(cfg, steps=3, lr=None):
     return losses, outs, opt_state
 
 
-@functools.lru_cache(maxsize=None)
+@kit.once
 def reference_steps():
-    params, biases = to_reference(make_params())
+    params, biases = kit.own(to_reference(make_params()))
     tokens = make_tokens()
     with jax.default_matmul_precision("highest"):
         return reference.first_losses(
@@ -310,7 +311,7 @@ def test_a_seeded_fault_fails_both_tolerances(monkeypatch, name):
     if "patch" in spec:
         attr, fn = spec.pop("patch")
         monkeypatch.setattr(reference, attr, fn)
-    logits = results.__wrapped__("reference", **spec)[0]   # not cached
+    logits = reference_logits(**spec)   # the faulted side, nothing else
     monkeypatch.undo()
     moved = max_diff(logits, results("system")[0])
     assert moved > BF16_LOGITS_TOL > F32_TOL, (name, moved)

@@ -13,8 +13,10 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+import model_kit as kit
 import numpy as np
 import pytest
+from model_kit import max_diff
 
 from benchmark.families import evabyte as family_module
 from benchmark.reference import evabyte as reference
@@ -49,18 +51,18 @@ def sizes(cfg):
                            32, 32)
 
 
+@kit.once
 def seeded(cfg, seq, seed=1):
     """Parameters with the norms' w, phi and mu away from where they start,
     and a batch."""
-    params = evabyte.init_params(jax.random.PRNGKey(seed), cfg)
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, x: x + 0.2 * jax.random.normal(next(keys), x.shape)
-        if x.ndim == 1 or "phi" in str(path) or "mu" in str(path) else x,
-        params)
-    tokens = jax.random.randint(jax.random.PRNGKey(seed + 2), (2, seq + 1), 0,
-                                cfg.vocab_size)
-    return params, tokens
+    init = lambda key: evabyte.init_params(key, cfg)
+    away = [kit.Vector(tuple(k.key for k in path), 0.2)
+            for path, x in jax.tree_util.tree_flatten_with_path(
+                jax.eval_shape(init, jax.random.PRNGKey(0)))[0]
+            if x.ndim == 1 or "phi" in str(path) or "mu" in str(path)]
+    params = kit.drawn(init, seed, away, factor=1.0,
+                       sequence=(seed + 1, 64))
+    return params, kit.tokens(seed + 2, 2, seq, cfg.vocab_size)
 
 
 @pytest.mark.parametrize("name", list(CONFIGS))
@@ -80,8 +82,8 @@ def test_logits_and_three_adamw_losses_are_the_references(name):
         for _ in range(3):
             *state, out = step(*state, {"tokens": tokens})
             losses.append(float(out["loss"]))
-        want = reference.first_losses(theirs, jnp.stack([tokens] * 3),
-                                      sizes(cfg), OPTIMIZER)
+        want = reference.first_losses(
+            kit.own(theirs), jnp.stack([tokens] * 3), sizes(cfg), OPTIMIZER)
     np.testing.assert_allclose(losses, want, atol=2e-5)
 
 
@@ -124,22 +126,22 @@ def readings(cfg, seq):
     return logits, float(loss), dphi
 
 
-_sound = {}
+@kit.once
+def sound_readings(which):
+    return readings(CONFIGS[which], SEQ[which])
 
 
 @pytest.mark.parametrize("name", sorted(evabyte_faults.FAULTS))
 def test_each_seeded_fault_is_caught(name):
     which = "kernels" if name in KERNEL_FAULTS else "plain"
     cfg, seq = CONFIGS[which], SEQ[which]
-    if which not in _sound:
-        _sound[which] = readings(cfg, seq)
-    sound = _sound[which]
+    sound = sound_readings(which)
     family = object.__new__(evabyte_faults.FAULTS[name])
     faulty_cfg = dataclasses.replace(cfg, stream_dtype=jnp.bfloat16) \
         if name == "bf16_stream" else cfg
     with family.patch():
         faulty = readings(faulty_cfg, seq)
-    moved = float(jnp.max(jnp.abs(faulty[0] - sound[0])))
+    moved = max_diff(faulty[0], sound[0])
     scale = float(jnp.max(jnp.abs(sound[0])))
     if name in MAY_PASS:
         assert moved < 0.02 * scale
@@ -148,7 +150,7 @@ def test_each_seeded_fault_is_caught(name):
         if name == "target_a_byte_early":
             assert abs(faulty[1] - sound[1]) > 1e-3
         else:
-            assert float(jnp.max(jnp.abs(faulty[2] - sound[2]))) \
+            assert max_diff(faulty[2], sound[2]) \
                 > 0.1 * float(jnp.max(jnp.abs(sound[2])))
     else:
         # the sound program read twice differs by nothing

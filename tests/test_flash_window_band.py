@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from model_kit import max_diff
 
 import ray_tpu.ops
 from ray_tpu.ops import flash_attention as fa
@@ -23,11 +24,6 @@ def interpret_these_sizes(monkeypatch):
     """The kernels interpreted at S = 4,096 too (`ops.by_platform` takes the
     reference past the tests' usual sizes)."""
     monkeypatch.setattr(ray_tpu.ops, "INTERPRET_MAX_ELEMS", 1 << 24)
-
-
-def max_diff(a, b):
-    return float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                                 - b.astype(jnp.float32))))
 
 
 def _qkv(S, H, Hkv, D, Dv, seed=0):

@@ -13,12 +13,13 @@ and a fault would hide under any tolerance.
 """
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
+import model_kit as kit
 import numpy as np
 import pytest
+from model_kit import max_diff
 
 from benchmark.families.keye_vl import to_reference
 from benchmark.reference import keye_vl as reference
@@ -44,38 +45,27 @@ F32_TOL = 2e-5
 BF16_LOGITS_TOL = 0.08
 
 
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+pytestmark = pytest.mark.usefixtures("highest_precision")
 
 
 def is_indexer(path) -> bool:
     return any(getattr(k, "key", None) == "indexer" for k in path)
 
 
+@kit.once
 def make_params(seed=0, cfg=F32):
     """Seeded weights, the indexer's LayerNorm bias not 0."""
-    params = model.init_params(jax.random.PRNGKey(seed), cfg)
-    params = jax.tree.map(lambda x: 4.0 * x if x.ndim >= 2 else x, params)
-    for i in range(cfg.n_layer):
-        norm = params[f"layer_{i}"]["attn"]["indexer"]["k_norm"]
-        norm["bias"] = 0.1 * jax.random.normal(
-            jax.random.PRNGKey(77 + i), norm["bias"].shape)
-    return params
+    return kit.drawn(
+        lambda key: model.init_params(key, cfg), seed,
+        [kit.Vector((f"layer_{i}", "attn", "indexer", "k_norm", "bias"), 0.1,
+                    key=77 + i, start=0.0) for i in range(cfg.n_layer)])
 
 
 def make_tokens(seed=0):
-    return jax.random.randint(jax.random.PRNGKey(1000 + seed),
-                              (BATCH, SEQ + 1), 0, F32.vocab_size)
+    return kit.tokens(1000 + seed, BATCH, SEQ, F32.vocab_size)
 
 
-def max_diff(a, b):
-    return float(jnp.max(jnp.abs(jnp.asarray(a, jnp.float32)
-                                 - jnp.asarray(b, jnp.float32))))
-
-
-@functools.lru_cache(maxsize=None)
+@kit.once
 def results(which, sizes=SIZES):
     """(logits, L_LM, L_I, L_B, rows sent to the experts, gradients of the
     objective L_LM + L_I + 0.1 L_B in the reference's layout) of the system
@@ -205,12 +195,12 @@ def system_steps(cfg, steps=3, lr=None):
     return losses, outs
 
 
-@functools.lru_cache(maxsize=None)
+@kit.once
 def reference_steps():
     with jax.default_matmul_precision("highest"):
         return reference.first_losses(
-            to_reference(make_params()), jnp.stack([make_tokens()] * 3),
-            SIZES, OPTIMIZER)
+            kit.own(to_reference(make_params())),
+            jnp.stack([make_tokens()] * 3), SIZES, OPTIMIZER)
 
 
 def test_three_steps_match_the_reference_program():
@@ -367,10 +357,12 @@ def test_a_seeded_fault_fails_both_tolerances(monkeypatch, name):
     attr, make = FAULTS[name]
     monkeypatch.setattr(reference, attr, make(getattr(reference, attr)))
     jax.clear_caches()      # `jax.checkpoint` keeps a layer's trace
-    faulty = results.__wrapped__("reference")               # not cached
+    tokens = make_tokens()[:, :-1]      # the faulted side, nothing else
+    logits = jax.jit(lambda p: reference.logits(p, tokens, SIZES))(
+        to_reference(make_params()))
     monkeypatch.undo()
     jax.clear_caches()
-    moved = max_diff(faulty[0], results("system")[0])
+    moved = max_diff(logits, results("system")[0])
     assert moved > BF16_LOGITS_TOL > F32_TOL, (name, moved)
 
 

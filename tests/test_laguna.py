@@ -18,14 +18,15 @@ and a fault would hide under any tolerance.
 """
 
 import dataclasses
-import functools
 import math
 import warnings
 
 import jax
 import jax.numpy as jnp
+import model_kit as kit
 import numpy as np
 import pytest
+from model_kit import max_diff
 
 from benchmark.families.laguna import groups_of, to_reference
 from benchmark.reference import laguna as reference
@@ -56,35 +57,24 @@ F32_TOL = 2e-5
 SEEDS = [0, 1, 2147483900]
 
 
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+pytestmark = pytest.mark.usefixtures("highest_precision")
 
 
+@kit.once
 def make_params(seed=0, cfg=F32):
     """Matrices four times as wide as drawn; routing biases that differ by
     expert, so that they pick."""
-    params = model.init_params(jax.random.PRNGKey(seed), cfg)
-    params = jax.tree.map(lambda x: 4.0 * x if x.ndim >= 2 else x, params)
-    for i in cfg.moe_layers:
-        router = params[f"layer_{i}"]["moe"]["router"]
-        router[ROUTING_BIAS] = 0.05 * jax.random.normal(
-            jax.random.PRNGKey(seed + i), router[ROUTING_BIAS].shape)
-    return params
+    return kit.drawn(
+        lambda key: model.init_params(key, cfg), seed,
+        [kit.Vector((f"layer_{i}", "moe", "router", ROUTING_BIAS), 0.05,
+                    key=seed + i, start=0.0) for i in cfg.moe_layers])
 
 
 def make_tokens(seed=0):
-    return jax.random.randint(jax.random.PRNGKey(1000 + seed % 1000),
-                              (BATCH, SEQ + 1), 0, 512)
+    return kit.tokens(1000 + seed % 1000, BATCH, SEQ, 512)
 
 
-def max_diff(a, b):
-    return float(jnp.max(jnp.abs(jnp.asarray(a, jnp.float32)
-                                 - jnp.asarray(b, jnp.float32))))
-
-
-@functools.lru_cache(maxsize=None)
+@kit.once
 def results(which, seed):
     """(the cross-entropy, every row's cross-entropy, rows sent to the
     experts, the loss's gradients in the reference's layout) of the system
@@ -141,7 +131,7 @@ def test_three_steps_match_the_reference_program(seed):
     rule, on both sides."""
     params, tokens = make_params(seed), make_tokens(seed)
     want = reference.first_losses(
-        *jax.tree.map(jnp.copy, to_reference(params)),
+        *kit.own(to_reference(params)),
         jnp.stack([tokens] * 3), SIZES, OPTIMIZER)
     optimizer = trained_by(reference.adamw(OPTIMIZER))
     step = jax.jit(model.make_train_step(F32, optimizer))

@@ -15,12 +15,13 @@ and a fault would hide under any tolerance.
 """
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
+import model_kit as kit
 import numpy as np
 import pytest
+from model_kit import max_diff
 
 from benchmark.families.lfm2_moe import to_reference
 from benchmark.reference import lfm2_moe as reference
@@ -48,37 +49,24 @@ F32_TOL = 2e-5
 BF16_LOGITS_TOL = 0.08
 
 
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+pytestmark = pytest.mark.usefixtures("highest_precision")
 
 
+@kit.once
 def make_params(seed=0, cfg=F32, bias=True):
     """Seeded weights, and routing biases that are not 0."""
-    params = model.init_params(jax.random.PRNGKey(seed), cfg)
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, x: 4.0 * x if x.ndim >= 2
-        and "conv" != path[-2].key else x, params)
-    if bias:
-        for n, i in enumerate(cfg.moe_layers):
-            router = params[f"layer_{i}"]["moe"]["router"]
-            router[BIAS] = 0.05 * jax.random.normal(
-                jax.random.PRNGKey(77 + n), router[BIAS].shape)
-    return params
+    return kit.drawn(
+        lambda key: model.init_params(key, cfg), seed,
+        [kit.Vector((f"layer_{i}", "moe", "router", BIAS), 0.05, key=77 + n,
+                    start=0.0)
+         for n, i in enumerate(cfg.moe_layers) if bias], narrow=("conv",))
 
 
 def make_tokens(seed=0):
-    return jax.random.randint(jax.random.PRNGKey(1000 + seed),
-                              (BATCH, SEQ + 1), 0, F32.vocab_size)
+    return kit.tokens(1000 + seed, BATCH, SEQ, F32.vocab_size)
 
 
-def max_diff(a, b):
-    return float(jnp.max(jnp.abs(jnp.asarray(a, jnp.float32)
-                                 - jnp.asarray(b, jnp.float32))))
-
-
-@functools.lru_cache(maxsize=None)
+@kit.once
 def results(which, sizes=SIZES):
     """(logits, loss, rows sent to the experts, gradients in the
     reference's layout) of the system in float32 or of the reference, each
@@ -101,6 +89,14 @@ def results(which, sizes=SIZES):
                 reference.losses, has_aux=True)(params, biases, tokens, sizes)
             return logits, loss, rows, grads
         return jax.jit(run)(*to_reference(params))
+
+
+def reference_logits(sizes=SIZES):
+    """The reference's logits alone, a program of its own a call: the side
+    a seeded fault is in."""
+    tokens = make_tokens()[:, :-1]
+    return jax.jit(lambda p, b: reference.logits(p, b, tokens, sizes))(
+        *to_reference(make_params()))
 
 
 @pytest.mark.parametrize("what", ["logits", "loss", "expert_rows"])
@@ -169,9 +165,9 @@ def system_steps(cfg, steps=3, lr=None):
     return losses, outs, opt_state
 
 
-@functools.lru_cache(maxsize=None)
+@kit.once
 def reference_steps():
-    params, biases = to_reference(make_params())
+    params, biases = kit.own(to_reference(make_params()))
     tokens = make_tokens()
     with jax.default_matmul_precision("highest"):
         return reference.first_losses(
@@ -344,7 +340,7 @@ def test_a_seeded_fault_fails_both_tolerances(monkeypatch, name):
     attr, make = FAULTS[name]
     monkeypatch.setattr(reference, attr, make(getattr(reference, attr)))
     jax.clear_caches()      # `jax.checkpoint` keeps a layer's trace
-    logits = results.__wrapped__("reference")[0]            # not cached
+    logits = reference_logits()     # the faulted side, and nothing else
     monkeypatch.undo()
     jax.clear_caches()
     moved = max_diff(logits, results("system")[0])

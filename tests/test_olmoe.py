@@ -10,12 +10,13 @@ stream and a routing fault would hide under any tolerance.
 """
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
+import model_kit as kit
 import numpy as np
 import pytest
+from model_kit import max_diff
 
 from benchmark.families.olmoe import to_reference
 from benchmark.reference import olmoe as reference
@@ -44,35 +45,26 @@ BF16_LOGITS_TOL = 0.06
 ROUTER_GAP = 0.03
 
 
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+pytestmark = pytest.mark.usefixtures("highest_precision")
 
 
+@kit.once
 def make_params(seed=0, cfg=F32):
-    params = olmoe.init_params(jax.random.PRNGKey(seed), cfg)
-    return jax.tree.map(lambda x: 4.0 * x if x.ndim >= 2 else x, params)
+    return kit.drawn(lambda key: olmoe.init_params(key, cfg), seed)
 
 
 def make_tokens(seed=0):
-    return jax.random.randint(jax.random.PRNGKey(1000 + seed),
-                              (BATCH, SEQ + 1), 0, F32.vocab_size)
+    return kit.tokens(1000 + seed, BATCH, SEQ, F32.vocab_size)
 
 
-def max_diff(a, b):
-    return float(jnp.max(jnp.abs(jnp.asarray(a, jnp.float32)
-                                 - jnp.asarray(b, jnp.float32))))
-
-
-@functools.lru_cache(maxsize=None)
+@kit.once
 def case(kind="plain"):
     """(params, tokens) of a seeded case, made once."""
     params = imbalanced_params() if kind == "imbalanced" else make_params()
     return params, make_tokens()
 
 
-@functools.lru_cache(maxsize=None)
+@kit.once
 def results(which, kind="plain"):
     """(logits, objective, parts, gradients in the reference's layout) of
     the system in float32 or of the reference, each one jitted program,
@@ -136,7 +128,7 @@ def run_steps(cfg, params, tokens, steps=3):
     return params, outs
 
 
-@functools.lru_cache(maxsize=None)
+@kit.once
 def reference_steps():
     """Three steps of the reference on the plain case, run once."""
     return run_reference_steps(*case())

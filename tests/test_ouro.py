@@ -14,13 +14,14 @@ would hide under any tolerance.
 """
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
+import model_kit as kit
 import numpy as np
 import optax
 import pytest
+from model_kit import max_diff
 
 from benchmark.families.ouro import from_reference, to_reference
 from benchmark.reference import ouro as reference
@@ -50,27 +51,18 @@ EXIT_TOL = 0.01
 LOSS_TOL = 0.003
 
 
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+pytestmark = pytest.mark.usefixtures("highest_precision")
 
 
+@kit.once
 def make_params(seed=0, cfg=F32):
-    params = model.init_params(jax.random.PRNGKey(seed), cfg)
-    params = jax.tree.map(lambda x: 5.0 * x if x.ndim >= 2 else x, params)
-    params["exit_gate"]["bias"] = jnp.array([0.3], jnp.float32)
-    return params
+    return kit.drawn(lambda key: model.init_params(key, cfg), seed,
+                     [kit.Vector(("exit_gate", "bias"), start=0.3)],
+                     factor=5.0)
 
 
 def make_tokens(seed=0):
-    return jax.random.randint(jax.random.PRNGKey(1000 + seed),
-                              (BATCH, SEQ + 1), 0, F32.vocab_size)
-
-
-def max_diff(a, b):
-    return float(jnp.max(jnp.abs(jnp.asarray(a, jnp.float32)
-                                 - jnp.asarray(b, jnp.float32))))
+    return kit.tokens(1000 + seed, BATCH, SEQ, F32.vocab_size)
 
 
 def relative(got, want):
@@ -92,7 +84,7 @@ def reference_states(params, inputs):
     return states, p
 
 
-@functools.lru_cache(maxsize=None)
+@kit.once
 def results(which):
     """(states, logits, exit distribution, the objective's parts, its
     gradients in the reference's layout) of the system in float32 or of the
@@ -181,7 +173,7 @@ def test_three_adamw_steps_follow_the_reference(compute):
     step = jax.jit(model.make_train_step(cfg, optimizer))
     got = train(lambda p, o, t: step(p, o, {"tokens": t}), params,
                 optimizer.init(params), batches)
-    want = reference.first_losses(to_reference(make_params()),
+    want = reference.first_losses(kit.own(to_reference(make_params())),
                                   jnp.stack(batches), SIZES, OPTIMIZER)
     tolerance = 2e-5 if compute == "float32" else LOSS_TOL
     assert max(abs(g - w) for g, w in zip(got, want)) < tolerance, (got, want)

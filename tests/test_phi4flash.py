@@ -19,8 +19,10 @@ import re
 
 import jax
 import jax.numpy as jnp
+import model_kit as kit
 import numpy as np
 import pytest
+from model_kit import max_diff
 
 from benchmark.families.phi4flash import Family, from_reference, to_reference
 from benchmark.harness import registry
@@ -51,70 +53,69 @@ def sizes(cfg=F32):
         rms_eps=cfg.rms_eps, query_block=16, scan_block=16, row_block=32)
 
 
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+pytestmark = pytest.mark.usefixtures("highest_precision")
 
 
+def vectors(cfg):
+    """The vectors that are not what they start as, in the order their keys
+    are drawn."""
+    for i in range(cfg.n_layer):
+        layer, kind = f"layer_{i}", cfg.kind(i)
+        yield kit.Vector((layer, "norm1"), 0.2)
+        yield kit.Vector((layer, "norm2"), 0.2)
+        if kind == model.MAMBA:
+            yield kit.Vector((layer, kind, "D"), 0.5)
+            yield kit.Vector((layer, kind, "A_log"), 0.5)
+            # steps of a half to two: the decays differ by their state index
+            yield kit.Vector((layer, kind, "dt_proj", "bias"), plus=5.0)
+        elif kind != model.GMU:
+            yield kit.Vector((layer, kind, "diff_norm", "scale"), 0.3)
+            # a cross layer reads another's k and v
+            for name in ("o_proj", "q_proj") if kind == model.CROSS \
+                    else ("k_proj", "o_proj", "q_proj", "v_proj"):
+                yield kit.Vector((layer, kind, name, "bias"), 0.1)
+    yield kit.Vector(("norm_f",), 0.2)
+
+
+@kit.once
 def make_params(seed=0, cfg=F32):
     """Seeded weights four times as wide, and vectors that are not what
     they start as."""
-    params = model.init_params(jax.random.PRNGKey(seed), cfg)
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, x: 4.0 * x if x.ndim >= 2
-        and path[-2].key != "conv" and path[-1].key != "A_log" else x,
-        params)
-    keys = iter(jax.random.split(jax.random.PRNGKey(100 + seed), 64))
-    noisy = lambda x, scale=0.5: x + scale * jax.random.normal(
-        next(keys), x.shape)
-    for i in range(cfg.n_layer):
-        layer = params[f"layer_{i}"]
-        for norm in ("norm1", "norm2"):
-            layer[norm] = jax.tree.map(lambda x: noisy(x, 0.2), layer[norm])
-        kind = cfg.kind(i)
-        if kind == model.MAMBA:
-            m = layer[kind]
-            m["D"], m["A_log"] = noisy(m["D"]), noisy(m["A_log"])
-            # steps of a half to two: the decays differ by their state index
-            m["dt_proj"]["bias"] = m["dt_proj"]["bias"] + 5.0
-        elif kind != model.GMU:
-            a = layer[kind]
-            a["diff_norm"]["scale"] = noisy(a["diff_norm"]["scale"], 0.3)
-            for name in [n for n in a if n.endswith("_proj")]:
-                a[name]["bias"] = noisy(a[name]["bias"], 0.1)
-    params["norm_f"] = jax.tree.map(lambda x: noisy(x, 0.2),
-                                    params["norm_f"])
-    return params
+    return kit.drawn(lambda key: model.init_params(key, cfg), seed,
+                     vectors(cfg), narrow=("conv", "A_log"),
+                     sequence=(100 + seed, 64))
 
 
 def make_tokens(seed=0, batch=BATCH):
-    return jax.random.randint(jax.random.PRNGKey(50 + seed),
-                              (batch, SEQ + 1), 0, F32.vocab_size)
-
-
-def max_diff(a, b):
-    return float(jnp.max(jnp.abs(jnp.asarray(a, jnp.float32)
-                                 - jnp.asarray(b, jnp.float32))))
+    return kit.tokens(50 + seed, batch, SEQ, F32.vocab_size)
 
 
 def system_logits(params, tokens, cfg=F32):
+    """A program of its own a call: what a fault's patch needs."""
     return jax.jit(lambda p, t: model.forward(
         layers.cast_weights(p, cfg.compute_dtype), t, cfg))(params, tokens)
 
 
-def reference_logits(params, tokens):
-    return reference.logits(to_reference(params), tokens, sizes())
+@kit.once
+def sound_reference_logits(seed=0):
+    """The reference's logits of `make_params(seed)` on
+    `make_tokens(seed)`, one jitted program."""
+    return jax.jit(lambda p, t: reference.logits(p, t, sizes()))(
+        to_reference(make_params(seed)), make_tokens(seed)[:, :-1])
+
+
+@kit.once
+def sound_system_logits(seed=0):
+    return system_logits(make_params(seed), make_tokens(seed)[:, :-1])
 
 
 # -- against the reference ----------------------------------------------------
 
 @pytest.mark.parametrize("seed", [0, 1])
 def test_the_forward_pass_matches_the_reference_in_float32(seed):
-    params, tokens = make_params(seed), make_tokens(seed)[:, :-1]
-    want = reference_logits(params, tokens)
+    want = sound_reference_logits(seed)
     assert float(jnp.std(want)) > 0.5
-    assert max_diff(system_logits(params, tokens), want) < F32_TOL * 10
+    assert max_diff(sound_system_logits(seed), want) < F32_TOL * 10
 
 
 def test_the_stream_after_every_layer_matches():
@@ -143,7 +144,7 @@ def test_gradients_of_every_leaf_match():
 def test_three_steps_match_the_reference_program():
     params, tokens = make_params(), make_tokens()
     want = reference.first_losses(
-        jax.tree.map(jnp.array, to_reference(params)),
+        kit.own(to_reference(params)),
         jnp.stack([tokens] * 3), sizes(), OPTIMIZER)
     optimizer = reference.adamw(OPTIMIZER)
     step = jax.jit(model.make_train_step(F32, optimizer))
@@ -158,7 +159,7 @@ def test_three_steps_match_the_reference_program():
 def test_bfloat16_compute_stays_close():
     params, tokens = make_params(), make_tokens()[:, :-1]
     moved = max_diff(system_logits(params, tokens, BF16),
-                     reference_logits(params, tokens))
+                     sound_reference_logits())
     assert F32_TOL < moved < BF16_LOGITS_TOL
 
 
@@ -184,11 +185,13 @@ def test_a_seeded_fault_moves_the_logits_past_the_margin(name):
     tolerance."""
     config = registry.config("phi-4-mini-flash-reasoning-vp8", rehearse=True)
     params, tokens = make_params(), make_tokens()[:, :-1]
-    want = reference_logits(params, tokens)
-    with FAULTS[name](config).patch():
-        moved = max_diff(system_logits(params, tokens), want)
+    want = sound_reference_logits()
+    # that the system as it is stands inside the tolerance is the first
+    # test of this file; here, that the fault's patch leaves it as it was
+    with kit.patches_undone():
+        with FAULTS[name](config).patch():
+            moved = max_diff(system_logits(params, tokens), want)
     assert moved > BF16_LOGITS_TOL > F32_TOL, (name, moved)
-    assert max_diff(system_logits(params, tokens), want) < F32_TOL * 10
 
 
 # -- the kinds by their published index ---------------------------------------

@@ -56,13 +56,19 @@ def test_sampler_folds_tagged_stacks(monkeypatch):
     t = threading.Thread(target=busy_probe_fn, args=(stop,),
                          name="busy-probe", daemon=True)
     t.start()
+    # until a sample of the busy thread is there, not for a fixed 0.6 s: on
+    # a host whose cores are all taken (six xdist workers compiling) the
+    # sampler's thread may not get its first tick in that time
+    records, deadline = [], time.monotonic() + 30
     try:
-        time.sleep(0.6)
+        while time.monotonic() < deadline and not any(
+                "busy_probe_fn" in r["stack"] for r in records):
+            time.sleep(0.2)
+            records += profiling.drain_samples()[0]
     finally:
         stop.set()
         t.join()
-    records, _dropped = profiling.drain_samples()
-    assert records, "sampler produced nothing in 0.6s at 97Hz"
+    assert records, "sampler produced nothing in 30s at 97Hz"
     tagged = [r for r in records if "busy_probe_fn" in r["stack"]]
     assert tagged, [r["stack"] for r in records]
     rec = tagged[0]

@@ -15,14 +15,15 @@ and a fault would hide under any tolerance.
 """
 
 import dataclasses
-import functools
 import warnings
 
 import jax
 import jax.numpy as jnp
+import model_kit as kit
 import numpy as np
 import optax
 import pytest
+from model_kit import max_diff
 
 from benchmark.families.sdar import to_reference
 from benchmark.reference import sdar as reference
@@ -46,28 +47,19 @@ F32_TOL = 2e-5
 SEEDS = [0, 1, 2147483900]
 
 
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+pytestmark = pytest.mark.usefixtures("highest_precision")
 
 
+@kit.once
 def make_params(seed=0, cfg=F32):
-    params = model.init_params(jax.random.PRNGKey(seed), cfg)
-    return jax.tree.map(lambda x: 4.0 * x if x.ndim >= 2 else x, params)
+    return kit.drawn(lambda key: model.init_params(key, cfg), seed)
 
 
 def make_tokens(seed=0):
-    return jax.random.randint(jax.random.PRNGKey(1000 + seed % 1000),
-                              (BATCH, SEQ + 1), 0, 500)
+    return kit.tokens(1000 + seed % 1000, BATCH, SEQ, 500)
 
 
-def max_diff(a, b):
-    return float(jnp.max(jnp.abs(jnp.asarray(a, jnp.float32)
-                                 - jnp.asarray(b, jnp.float32))))
-
-
-@functools.lru_cache(maxsize=None)
+@kit.once
 def results(which, seed, step=0):
     """(L_D, L_B, the noised rows' cross-entropies, rows sent to the
     experts, the objective's gradients in the reference's layout) of the
@@ -128,7 +120,7 @@ def test_three_steps_match_the_reference_program(seed):
     noise: the system's step draws from the optimizer's count."""
     params, tokens = make_params(seed), make_tokens(seed)
     want = reference.first_losses(
-        jax.tree.map(jnp.copy, to_reference(params)),
+        kit.own(to_reference(params)),
         jnp.stack([tokens] * 3), seed, SIZES, OPTIMIZER)
     optimizer = reference.adamw(OPTIMIZER)
     step = jax.jit(model.make_train_step(F32, optimizer, seed))

@@ -17,8 +17,10 @@ import itertools
 
 import jax
 import jax.numpy as jnp
+import model_kit as kit
 import numpy as np
 import pytest
+from model_kit import max_diff
 
 from ray_tpu.models import (
     deepseek_v3,
@@ -40,15 +42,7 @@ WIDTH, SHARED_WIDTH = 24, 40        # no whole number of lane tiles
 TOL = 2e-5
 
 
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
-
-
-def max_diff(a, b):
-    return float(jnp.max(jnp.abs(jnp.asarray(a, jnp.float32)
-                                 - jnp.asarray(b, jnp.float32))))
+pytestmark = pytest.mark.usefixtures("highest_precision")
 
 
 # -- the routed layer ---------------------------------------------------------
@@ -531,21 +525,33 @@ TRUNK_FAMILIES = {
     "sdar": (sdar, sdar.SDAR_TINY),
     "mellum": (mellum, mellum.MELLUM_TINY),
 }
+HEAD_FAMILIES = {**TRUNK_FAMILIES, "ouro": (ouro, ouro.OURO_TINY)}
 
 
-def family_case(module, cfg):
-    params = module.init_params(jax.random.PRNGKey(3), cfg)
-    batch = {"tokens": jax.random.randint(
-        jax.random.PRNGKey(4), (B, 65), 0, cfg.vocab_size)}
+@kit.once
+def family_inputs(family):
+    """A family's parameters as drawn at its test size, and a batch."""
+    module, cfg = HEAD_FAMILIES[family]
+    return (module.init_params(jax.random.PRNGKey(3), cfg),
+            {"tokens": jax.random.randint(
+                jax.random.PRNGKey(4), (B, 65), 0, cfg.vocab_size)})
+
+
+def family_step(family, cfg):
+    """(the loss, every gradient) of a family's objective under ``cfg``, a
+    program of its own a call: the side a test patches."""
+    module, _ = HEAD_FAMILIES[family]
+    params, batch = family_inputs(family)
     # an objective that draws its own noise takes the run's key and the
     # step's number
     noise = (sdar.noise_key(5), 0) if module is sdar else ()
+    return jax.jit(jax.value_and_grad(
+        lambda p: module.loss_fn(layers.cast_weights(
+            p, cfg.compute_dtype), batch, cfg, *noise)[0]))(params)
 
-    def run():
-        return jax.jit(jax.value_and_grad(
-            lambda p: module.loss_fn(layers.cast_weights(
-                p, cfg.compute_dtype), batch, cfg, *noise)[0]))(params)
-    return run
+
+# the same of the code as it is: once for all the witnesses that read it
+as_it_is = kit.once(family_step)
 
 
 @pytest.mark.parametrize("remat", [False, True])
@@ -558,10 +564,10 @@ def test_a_model_that_walks_once_is_what_it_was(family, remat, monkeypatch):
     forward walk and rounds otherwise:
     `test_the_head_is_what_it_was_to_a_rounding`.)"""
     module, cfg = TRUNK_FAMILIES[family]
-    run = family_case(module, dataclasses.replace(cfg, remat=remat))
-    loss, grads = run()
+    cfg = dataclasses.replace(cfg, remat=remat)
+    loss, grads = as_it_is(family, cfg)
     monkeypatch.setattr(module, "trunk", parent_trunk)
-    want_loss, want_grads = run()
+    want_loss, want_grads = family_step(family, cfg)
     assert float(loss) == float(want_loss)
     for (path, want), got in zip(
             jax.tree_util.tree_flatten_with_path(want_grads)[0],
@@ -595,7 +601,6 @@ def parent_head_and_weighted_loss(x, head, targets, weights, chunk_rows):
     return jnp.sum(weights * rows), jax.lax.stop_gradient(rows)
 
 
-HEAD_FAMILIES = {**TRUNK_FAMILIES, "ouro": (ouro, ouro.OURO_TINY)}
 # A gradient against the parent's, as a share of the largest entry of the
 # parent's gradient of the same leaf.  In float32 the two differ by the
 # order of their sums.  In bfloat16 the head's own results differ by a
@@ -616,15 +621,14 @@ def test_the_head_is_what_it_was_to_a_rounding(family, compute, monkeypatch):
     twice), computing in float32 and in bfloat16: the loss to 1e-6, every
     gradient to `HEAD_TOL`."""
     module, cfg = HEAD_FAMILIES[family]
-    run = family_case(module, dataclasses.replace(
-        cfg, compute_dtype=jnp.dtype(compute).type))
-    loss, grads = run()
+    cfg = dataclasses.replace(cfg, compute_dtype=jnp.dtype(compute).type)
+    loss, grads = as_it_is(family, cfg)
     if family in ("ouro", "sdar"):     # the rows weighted
         monkeypatch.setattr(module, "head_and_weighted_loss",
                             parent_head_and_weighted_loss)
     else:
         monkeypatch.setattr(module, "head_and_loss", parent_head_and_loss)
-    want_loss, want_grads = run()
+    want_loss, want_grads = family_step(family, cfg)
     assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
     moved = 0
     for (path, want), got in zip(

@@ -26,10 +26,7 @@ from ray_tpu.parallel.attention import attention
 TOL = 2e-5
 
 
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+pytestmark = pytest.mark.usefixtures("highest_precision")
 
 
 # -- the softmax route and its balance loss ---------------------------------

@@ -14,10 +14,7 @@ from ray_tpu.util import tracing
 B, S, E, L = 2, 12, 8, 3
 
 
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+pytestmark = pytest.mark.usefixtures("highest_precision")
 
 
 def make(seed=0, taps=L, dtype=jnp.float32):

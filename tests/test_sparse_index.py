@@ -23,10 +23,7 @@ H, HKV, D = 8, 1, 16          # a group of 8 query heads a key/value head
 TRI = np.tril(np.ones((S, S), bool))
 
 
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+pytestmark = pytest.mark.usefixtures("highest_precision")
 
 
 def indexer_inputs(seed=0):

@@ -44,10 +44,7 @@ CASES = [(name, groups, chunk) for name, form in FORMS.items()
          for groups in form.heads for chunk in form.chunks]
 
 
-@pytest.fixture(autouse=True)
-def highest_precision():
-    with jax.default_matmul_precision("highest"):
-        yield
+pytestmark = pytest.mark.usefixtures("highest_precision")
 
 
 def make(form, groups, seed=0, dtype=jnp.float32):
