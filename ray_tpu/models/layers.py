@@ -55,7 +55,14 @@ from __future__ import annotations
 import collections
 import contextlib
 import functools
+import hashlib
+import itertools
+import json
+import logging
 import math
+import os
+import re
+import sys
 import threading
 
 import jax
@@ -70,8 +77,12 @@ from ray_tpu.ops.flash_attention import KEPT_RESIDUALS, KERNEL_FORMS
 from ray_tpu.ops.moe import ROUTE_NAME, moe_dispatch
 from ray_tpu.parallel.attention import attention
 from ray_tpu.parallel.context import get_mesh
-from ray_tpu.parallel.sharding import chip_bytes, param_logical_dims
+from ray_tpu.parallel.sharding import (chip_bytes, logical_spec,
+                                       param_logical_dims)
 from ray_tpu.util import tracing
+from ray_tpu.util.compile_cache import compile_cache_dir
+
+logger = logging.getLogger(__name__)
 
 SCOPES = (
     "embed",
@@ -606,14 +617,18 @@ KEPT_NAMES = (
 # Of the device's memory limit, the share the budget never spends: the
 # program's own code, the batch, what the allocator loses between buffers.
 _HEADROOM = 0.05
-# One layer's live backward pass, in units of that layer's input and marked
+# The FIRST GUESS at what a step holds besides its state and what the stack
+# keeps (`keep_plan`'s static plan; since PR 72 no longer the budget where
+# the compiler gives its own account of the step, `_measured_plan`).  One
+# layer's live backward pass, in units of that layer's input and marked
 # values (the matmul results and kernel residuals, which are what a replay
 # writes out whole; the glue between them the compiler fuses away): the
 # replay's values, their head-major and float32 copies and the cotangents in
 # flight beside them.  And what runs behind the stack with every kept value
 # alive (the head's logits), in units of its bytes: the value and its
 # gradient.  Sized with the no-chip compile (`tools/aot_collectives.py`;
-# PERF.md §6, PR 37), to err towards keeping less.
+# PERF.md §6, PR 37), to err towards keeping less: what a CPU, a device
+# that states no limit and a measurement that fails fall back on.
 _LIVE_LAYERS = 2.5
 _LIVE_BEHIND = 2.0
 
@@ -623,8 +638,12 @@ _told = threading.local()
 @contextlib.contextmanager
 def _telling(**what):
     """Bind what a caller further out knows and a stack of layers cannot
-    see: ``state_bytes`` (`train_step`), ``memory_limit``
-    (`assume_memory_limit`)."""
+    see: ``state_bytes`` (`train_step`), ``memory_limit`` and ``plans``
+    (`assume_memory_limit`); and what `keep_plan` measures a step through:
+    ``account`` (`_compiled_account`, or a test's), ``stacks`` (a count of
+    the step's stacks as they are traced), ``decided`` ({a stack's number:
+    the names it keeps}) and, in a trace made to be measured, ``forced``
+    (the same, handed in)."""
     before = dict(vars(_told))
     vars(_told).update(what)
     try:
@@ -634,13 +653,23 @@ def _telling(**what):
         vars(_told).update(before)
 
 
-def assume_memory_limit(limit, plans=None):
+def assume_memory_limit(limit, plans=None, account=None):
     """Context manager: take ``limit`` bytes for the device's memory limit
     whatever the device states (a described device of the no-chip compile
     states none: `tools/aot_collectives.py`; the CPU of a test neither).
     ``plans``: a list that gets the `keep_plan` of each stack traced
-    inside."""
-    return _telling(memory_limit=limit, plans=plans)
+    inside.  ``account``: stands for the compiler's account of the step
+    (`_compiled_account`), ``account({a stack's number: names}) -> (the
+    step's peak bytes under them, the instructions the compiler made again
+    itself) or None``: a test's, where no compiler gives one."""
+    return _telling(memory_limit=limit, plans=plans, account=account)
+
+
+def _first_device():
+    """The first device the step is traced for: the bound mesh's, or
+    jax's."""
+    mesh = get_mesh()
+    return mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
 
 
 def _memory_limit():
@@ -649,10 +678,8 @@ def _memory_limit():
     told = getattr(_told, "memory_limit", None)
     if told is not None:
         return told
-    mesh = get_mesh()
-    device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
     try:
-        return (device.memory_stats() or {}).get("bytes_limit")
+        return (_first_device().memory_stats() or {}).get("bytes_limit")
     except jax.errors.JaxRuntimeError:  # a described device has no runtime
         return None
 
@@ -740,20 +767,32 @@ def keep_plan(fn, calls, static_argnums=(), behind=(), room=None,
     pass to its last reader's backward whatever the budget: "already".
     -> {"names": the names of `KEPT_NAMES` kept, in its order;
     "bytes_kept": what they hold on one chip over the whole stack;
-    "declined": the marked names that did not fit; "room": the budget;
-    "already": what the stack keeps whatever the budget (each layer's
-    input, `KEPT_RESIDUALS`); "state": the training state as `train_step`
-    told it; "reserve"; "marked": {name: bytes over the stack}}.
+    "declined": the marked names that did not fit; "room": the static
+    budget; "already": what the stack keeps whatever the budget (each
+    layer's input, `KEPT_RESIDUALS`); "state": the training state as
+    `train_step` told it; "reserve"; "marked": {name: bytes over the
+    stack}; "measured": whether the compiler's account of the step decided
+    (then "measured_room", "peak": the step's compiled peak under the names
+    kept, "admitted": the names it added to the static plan's, and
+    "recorded": whether a record served all that and nothing was compiled;
+    else no "measured_room", 0, () and False)}.
 
-    Greedy over `KEPT_NAMES`: a name is kept when all its values, every
-    layer counted, fit what is left of the room; one that does not is
-    skipped whole and the next is tried.  The room (``room`` given: that)
-    is the device's memory limit less `_HEADROOM`, the training state on
-    one chip, what the stack keeps already and a reserve: `_LIVE_LAYERS`
-    times the heaviest layer's input and marked values, or `_LIVE_BEHIND`
-    times ``behind`` if that is more.  A device that states no limit,
-    or a step whose state nobody told (`train_step` does), has no room:
-    the stack keeps `KEPT_RESIDUALS` alone."""
+    The static plan first.  Greedy over `KEPT_NAMES`: a name is kept when
+    all its values, every layer counted, fit what is left of the room; one
+    that does not is skipped whole and the next is tried.  The room
+    (``room`` given: that, and nothing is measured) is the device's memory
+    limit less `_HEADROOM`, the training state on one chip, what the stack
+    keeps already and a reserve: `_LIVE_LAYERS` times the heaviest layer's
+    input and marked values, or `_LIVE_BEHIND` times ``behind`` if that is
+    more.  A device that states no limit, or a step whose state nobody
+    told (`train_step` does), has no room: the stack keeps
+    `KEPT_RESIDUALS` alone.
+
+    That sum is a guess, wrong by gigabytes either way (PERF.md section 6,
+    PR 72).  Where it declines a name and the step can be compiled for its
+    device (`_compiled_account`), the room is what the COMPILED step under
+    the static plan leaves of the limit less `_HEADROOM`, and the declined
+    names are offered that (`_measured_plan`)."""
     marked, already, heaviest = collections.Counter(), 0, 0
     marks = {}
     for args in calls:
@@ -772,21 +811,343 @@ def keep_plan(fn, calls, static_argnums=(), behind=(), room=None,
     reserve = int(max(_LIVE_LAYERS * heaviest,
                       _LIVE_BEHIND * _activation_bytes(behind)))
     state = getattr(_told, "state_bytes", None)
+    limit = None        # the device's, where the room is this function's
     if room is None:
         limit = _memory_limit()
         room = 0 if limit is None or state is None else \
             int(limit * (1 - _HEADROOM)) - state - already - reserve
-    plan = {"names": (), "bytes_kept": 0, "declined": (), "room": room,
-            "state": state, "already": already, "reserve": reserve,
-            "marked": dict(marked)}
-    for name in KEPT_NAMES:
-        need = marked.get(name, 0)
-        if need and plan["bytes_kept"] + need <= room:
-            plan["names"] += (name,)
-            plan["bytes_kept"] += need
-        elif need:
-            plan["declined"] += (name,)
+    plan = {"room": room, "state": state, "already": already,
+            "reserve": reserve, "marked": dict(marked), "measured": False,
+            "recorded": False, "peak": 0, "admitted": ()}
+    _keeping(plan, _admitted(marked, KEPT_NAMES, room))
+    # which of the step's stacks this is, in the order they are traced
+    at = next(getattr(_told, "stacks", None) or itertools.count())
+    forced = getattr(_told, "forced", None)
+    if forced is not None:      # a trace made to be measured: as it is told
+        return _keeping(plan, forced.get(at, plan["names"]))
+    account = getattr(_told, "account", None)
+    if limit is not None and account is not None and plan["declined"]:
+        decided = getattr(_told, "decided", None) or {}
+        key = _plan_key(calls, static_argnums, behind, shared, plan, limit,
+                        {**decided, at: None})
+        _measured_plan(plan, int(limit * (1 - _HEADROOM)), key,
+                       lambda names: account({**decided, at: names}))
+    if getattr(_told, "decided", None) is not None:
+        _told.decided[at] = plan["names"]
     return plan
+
+
+def _admitted(marked, names, room):
+    """Those of ``names`` that ``room`` bytes admit, offered in order: a
+    name is admitted when all it marks fits what is left, one that does
+    not is skipped whole and the next is tried."""
+    admitted = []
+    for name in names:
+        if 0 < marked.get(name, 0) <= room:
+            admitted.append(name)
+            room -= marked[name]
+    return admitted
+
+
+def _keeping(plan, names):
+    """``plan`` keeping ``names`` of its marked values, and declining the
+    rest: in `KEPT_NAMES`' order both."""
+    marked = plan["marked"]
+    plan["names"] = tuple(n for n in KEPT_NAMES if n in marked and n in names)
+    plan["declined"] = tuple(n for n in KEPT_NAMES
+                             if n in marked and n not in names)
+    plan["bytes_kept"] = sum(marked[n] for n in plan["names"])
+    return plan
+
+
+def _measured_plan(plan, budget, key, peak_under):
+    """The static ``plan`` of a stack that declines a name, given the room
+    the compiled step has: ``budget`` (the limit less `_HEADROOM`) less
+    ``peak_under(names)``, the step's compiled peak with this stack keeping
+    ``names`` (`_step_peak`'s pair; None: no account).  The declined names
+    are offered that room greedily, in `KEPT_NAMES`' order and whole, as
+    the static room was; if that admits any, the step keeping them too is
+    compiled and has to read at or under the budget itself, with no more
+    instructions made again by the compiler than under the static plan (a
+    step XLA had to squeeze under its scheduler's limit reads under the
+    budget and runs slower: nemotron's, PERF.md section 6, PR 72), else (a
+    kept value costs more than its bytes, or the compiler refuses the
+    step) the last name admitted is given back and the check repeated: the
+    plan handed on was SEEN to fit.  A measurement that cannot be made
+    leaves the static plan: never less than before there was one.
+
+    Measured once a program and chip: the outcome is written under
+    ``key`` (`_plan_key`) beside the compiled programs, and a later trace
+    that finds it there takes the names from it and compiles nothing."""
+    marked, static = plan["marked"], plan["names"]
+    recorded = _read_record(key, marked)
+    if recorded is not None:
+        names, p0_peak, peak = recorded
+    else:
+        try:
+            seen = peak_under(static)
+        except Exception as e:      # whatever a compile raises: the guess
+            logger.warning("keep_plan: the step's account failed (%s: %s); "
+                           "the static plan stands", type(e).__name__, e)
+            seen = None
+        if seen is None:
+            return plan
+        p0_peak, p0_remade = seen
+        admitted = _admitted(marked, plan["declined"], budget - p0_peak)
+        names, peak = static, p0_peak
+        while admitted:
+            try:
+                seen = peak_under(static + tuple(admitted))
+            except Exception as e:
+                logger.warning("keep_plan: the step keeping %s too does not "
+                               "compile (%s: %s)", admitted[-1],
+                               type(e).__name__, e)
+                seen = None
+            if seen is not None and seen[0] <= budget \
+                    and seen[1] <= p0_remade:
+                names, peak = static + tuple(admitted), seen[0]
+                break
+            admitted.pop()
+        _write_record(key, names, p0_peak, peak)
+    _keeping(plan, names)
+    plan.update(measured=True, recorded=recorded is not None,
+                measured_room=budget - p0_peak, peak=peak,
+                admitted=tuple(n for n in plan["names"] if n not in static))
+    return plan
+
+
+# -- the step's account, from the compiler -----------------------------------
+
+def placed_shapes(params, opt_state, batch):
+    """((params, opt_state, batch) as shapes placed where a caller places
+    the arrays, the shardings of the first two) under the bound mesh:
+    parameters by `parallel/sharding.py`'s rules for their names, each
+    optimizer leaf beside the parameter whose path ends its own, whatever
+    else (the step count) everywhere, a batch's leading dim cut as a
+    batch's.  No mesh bound: shapes alone, for jax's first device."""
+    mesh = get_mesh()
+
+    def struct(x, sharding=None):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    if mesh is None:
+        return jax.tree.map(struct, (params, opt_state, batch)), None
+
+    def at(*dims):
+        return jax.sharding.NamedSharding(mesh, logical_spec(mesh, dims))
+
+    paths = [path for path, _ in
+             jax.tree_util.tree_flatten_with_path(params)[0]]
+    by_path = {path: at(*dims) for path, (_, dims)
+               in zip(paths, param_logical_dims(params)[1])}
+
+    def beside(path):
+        for start in range(len(path)):
+            if path[start:] in by_path:
+                return by_path[path[start:]]
+        return at()
+
+    placed = (
+        jax.tree_util.tree_map_with_path(
+            lambda path, x: struct(x, by_path[path]), params),
+        jax.tree_util.tree_map_with_path(
+            lambda path, x: struct(x, beside(path)), opt_state),
+        jax.tree.map(lambda x: struct(
+            x, at("batch", *(None,) * (x.ndim - 1)) if x.ndim else at()),
+            batch))
+    return placed, jax.tree.map(lambda x: x.sharding, placed[:2])
+
+
+def _step_peak(step, args, forced):
+    """The peak bytes of ``step`` compiled for the device ``args`` (its
+    params, opt_state and batch, arrays or tracers) are being traced for,
+    with its recomputed stacks keeping the names of ``forced`` ({a stack's
+    number: names}; a stack left out: its static plan), as the compiler
+    accounts for it (`memory_analysis()`: arguments + scratch + the outputs
+    that alias no argument), and the instructions the compiler made again
+    ITSELF to get there (XLA's own rematerialization names its copies
+    `<name>.remat`: what it does to a step past its scheduler's limit, at a
+    price in time no account of bytes shows) -> (bytes, instructions); None
+    where the compiler gives no account.  The step
+    is jitted as `train_step` says a caller does (the state donated and
+    handed back placed as it came), on the arguments' shapes as
+    `placed_shapes` places them, so the program measured under the plan a
+    stack ends with is the one the caller compiles next, and jax's cache
+    serves that.
+    Nothing of it counts on the job timeline."""
+    avals, kept = placed_shapes(*args)
+    placing = {} if kept is None else {"out_shardings": (*kept, None)}
+
+    # a function of its own under the step's name (the compiled module's):
+    # jit keeps a function's trace by its arguments' shapes
+    def train_step(*args):
+        return step(*args)
+
+    with tracing.outside_job(), \
+            _telling(forced=forced, plans=None, account=None):
+        compiled = jax.jit(train_step, donate_argnums=(0, 1), **placing
+                           ).lower(*avals).compile()
+    memory = compiled.memory_analysis()
+    if memory is None:
+        return None
+    peak = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + max(0, memory.output_size_in_bytes
+                  - memory.alias_size_in_bytes))
+    return peak, len(_COMPILER_REMADE.findall(compiled.as_text()))
+
+
+# an instruction XLA's rematerialization defined, in a compiled module's text
+_COMPILER_REMADE = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]*\.remat[\w.\-]* = ",
+                              re.M)
+
+
+def _compiled_account(step, params, opt_state, batch):
+    """-> ``account(forced) -> (peak bytes, instructions the compiler made
+    again itself) or None``: `_step_peak` of ``step`` on these arguments;
+    None on a CPU, whose compiler's buffers say nothing of a chip's
+    memory."""
+    if _first_device().platform == "cpu":
+        return None
+    return functools.partial(_step_peak, step, (params, opt_state, batch))
+
+
+# -- what was measured, remembered -------------------------------------------
+
+@functools.cache
+def _sources_digest():
+    """A digest of the sources a step's program is made from: this
+    directory, `ops` and `parallel`."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    digest = hashlib.sha256()
+    for part in ("models", "ops", "parallel"):
+        for name in sorted(os.listdir(os.path.join(root, part))):
+            if name.endswith(".py"):
+                with open(os.path.join(root, part, name), "rb") as f:
+                    digest.update(name.encode() + f.read())
+    return digest.hexdigest()
+
+
+def _versions():
+    """jax's, jaxlib's and libtpu's versions: the compiler's."""
+    from importlib import metadata
+
+    found = []
+    for package in ("jax", "jaxlib", "libtpu"):
+        try:
+            found.append(metadata.version(package))
+        except metadata.PackageNotFoundError:
+            found.append(None)
+    return found
+
+
+def _plan_key(calls, static_argnums, behind, shared, plan, limit, among):
+    """The name a stack's measured plan is remembered under: a digest of
+    what `keep_plan` has before any heavy trace and the measured outcome
+    depends on.  The stack's calls by their arrays' shapes and dtypes and
+    their static arguments as they print (an address left out); what is
+    marked, by name; what is kept already; the static plan; the state; the
+    limit; what lies behind; ``among``: which of the step's stacks this is
+    and what those before it keep; the mesh's shape; the device's kind;
+    the compiler's versions; the sources."""
+    mesh = get_mesh()
+    shapes = lambda tree: [[list(x.shape), str(x.dtype)]
+                           for x in jax.tree.leaves(tree)
+                           if hasattr(x, "shape")]
+    what = {
+        "calls": [[re.sub(r"0x[0-9a-f]+", "", repr(a)) if i in static_argnums
+                   else shapes(a) for i, a in enumerate(args)]
+                  for args in calls],
+        "marked": plan["marked"], "already": plan["already"],
+        "static": plan["names"], "state": plan["state"], "limit": limit,
+        "behind": shapes(behind), "shared": shapes(shared),
+        "among": sorted(among.items()),
+        "mesh": dict(mesh.shape) if mesh is not None else None,
+        "device": _first_device().device_kind, "versions": _versions(),
+        "sources": _sources_digest()}
+    return hashlib.sha256(
+        json.dumps(what, sort_keys=True).encode()).hexdigest()[:40]
+
+
+def _record_path(key):
+    """Where the plan measured under ``key`` is kept: below the directory
+    of jax's compilation cache; None where this process keeps no cache."""
+    cache = compile_cache_dir()
+    return cache and os.path.join(cache, "keep_plans", key + ".json")
+
+
+# the records whose plans this process handed out and no compile of the step
+# has borne out yet
+_on_trial = set()
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _step_compiled(event, _seconds, fun_name="", **_):
+    """jax reports a compile's end (`jax.monitoring`), also of one that
+    raised: the first of a step's after a record served its plan bears the
+    record out, or, refused by the compiler, deletes it, and the next
+    set-up measures anew.  Not the compiles `_compiled_account` makes."""
+    if (event != _COMPILE_EVENT or not _on_trial
+            or "train_step" not in str(fun_name)
+            or getattr(_told, "forced", None) is not None):
+        return
+    refused = sys.exc_info()[0] is not None
+    while _on_trial:
+        path = _on_trial.pop()
+        if refused:
+            logger.warning("keep_plan: the compiler refused the step under "
+                           "the plan recorded at %s: deleted", path)
+            _forget(path)
+
+
+@functools.cache
+def _listen_for_compiles():
+    """`_step_compiled` among jax.monitoring's listeners, once a process."""
+    jax.monitoring.register_event_duration_secs_listener(_step_compiled)
+
+
+def _forget(path):
+    with contextlib.suppress(OSError):
+        os.remove(path)
+
+
+def _read_record(key, marked):
+    """-> (names, the static plan's compiled peak, the names' own) as
+    `_write_record` wrote them under ``key``, and the record on trial
+    (`_step_compiled`); None where there is none.  One that cannot be read
+    or names what the stack does not mark is deleted."""
+    path = _record_path(key)
+    if not path or not os.path.exists(path):
+        return None
+    try:
+        with open(path) as f:
+            record = json.load(f)
+        names = tuple(record["names"])
+        peaks = int(record["static_peak_bytes"]), int(record["peak_bytes"])
+        if not set(names) <= set(marked):
+            raise ValueError(f"names {names} of no mark")
+    except (OSError, ValueError, KeyError, TypeError) as e:
+        logger.warning("keep_plan: the record at %s cannot be read (%s): "
+                       "deleted", path, e)
+        _forget(path)
+        return None
+    _listen_for_compiles()
+    _on_trial.add(path)
+    return (names, *peaks)
+
+
+def _write_record(key, names, static_peak, peak):
+    """The measured outcome under ``key``, written whole or not at all."""
+    path = _record_path(key)
+    if not path:
+        return
+    try:
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(f"{path}.{os.getpid()}", "w") as f:
+            json.dump({"names": list(names), "static_peak_bytes": static_peak,
+                       "peak_bytes": peak}, f)
+        os.replace(f.name, path)
+    except OSError as e:
+        logger.warning("keep_plan: no record written at %s (%s)", path, e)
 
 
 def _keep(names):
@@ -817,7 +1178,13 @@ def checkpoint_layer(fn, stack=None, behind=(), shared=(), **kw):
     alive (the head's logits, or a chunk of them); ``shared``: what the
     layers hand on to later layers, as `keep_plan` counts it.  The plan is
     made here, once per traced stack, and counted on the job timeline:
-    `remat.bytes_kept` (one chip, all layers) and `remat.names_declined`.
+    `remat.bytes_kept` (one chip, all layers), `remat.names_declined`, and
+    how the room was found: `remat.room_measured` (1: the compiler's
+    account of the step decided, 0: the static sum), `remat.
+    plan_recorded_hit` (1: a record of an earlier measurement served it and
+    nothing was compiled for it), `remat.measured_peak_bytes` (the step's
+    compiled peak under the names kept) and `remat.names_admitted` (the
+    names the account added to the static plan's).
     A layer that marks nothing is a bare `jax.checkpoint`."""
     names = KEPT_RESIDUALS
     if stack is not None:
@@ -826,6 +1193,10 @@ def checkpoint_layer(fn, stack=None, behind=(), shared=(), **kw):
         names += plan["names"]
         tracing.count("remat.bytes_kept", plan["bytes_kept"])
         tracing.count("remat.names_declined", len(plan["declined"]))
+        tracing.count("remat.room_measured", int(plan["measured"]))
+        tracing.count("remat.plan_recorded_hit", int(plan["recorded"]))
+        tracing.count("remat.measured_peak_bytes", plan["peak"])
+        tracing.count("remat.names_admitted", len(plan["admitted"]))
         if getattr(_told, "plans", None) is not None:
             _told.plans.append(plan)
     return jax.checkpoint(fn, policy=_keep(names), **kw)
@@ -1145,9 +1516,17 @@ def train_step(objective, optimizer, compute_dtype, rule=None,
         def cast_objective(p):
             return objective(cast_weights(p, compute_dtype), batch, *count)
 
-        # what a recomputed stack inside cannot see and its budget needs
-        with _telling(state_bytes=state_bytes(params, opt_state,
-                                              compute_dtype)):
+        # what a recomputed stack inside cannot see and its budget needs:
+        # the state's bytes, and the compiler's account of this very step
+        # (a trace made to be measured is told its plans and measures none)
+        told = {"state_bytes": state_bytes(params, opt_state, compute_dtype),
+                "stacks": itertools.count()}
+        if getattr(_told, "forced", None) is None:
+            told["decided"] = {}
+            if getattr(_told, "account", None) is None:
+                told["account"] = _compiled_account(train_step, params,
+                                                    opt_state, batch)
+        with _telling(**told):
             (_, out), grads = jax.value_and_grad(cast_objective,
                                                  has_aux=True)(params)
         with jax.named_scope("optimizer_update"):

@@ -31,3 +31,15 @@ def ensure_compile_cache() -> str:
 
     jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
     return CHECKOUT_CACHE_DIR
+
+
+def compile_cache_dir():
+    """The directory this process keeps jax's compilation cache in, as
+    `ensure_compile_cache` or the environment placed it; None in a process
+    that keeps none (a test, a tool that compiles for a described chip).
+    What is remembered BESIDE the compiled programs (`models/layers.py`:
+    the plan a recomputed stack was seen to fit under) goes below it and
+    nowhere else: no cache, nothing remembered."""
+    import jax
+
+    return jax.config.jax_compilation_cache_dir or None

@@ -122,11 +122,18 @@ def test_a_files_fault_cases_build_its_sound_side_once_between_them():
     program, which is the forward test's to build, once."""
     import test_phi4flash as file
 
+    # since here: the file's own tests may have run in this process before
+    # (xdist hands a worker whole files in no fixed order)
+    before = dict(kit.COMPUTED)
+    since = lambda made: kit.COMPUTED[made.name] - before.get(made.name, 0)
     for name in sorted(file.FAULTS)[:3]:
         file.test_a_seeded_fault_moves_the_logits_past_the_margin(name)
-    assert kit.COMPUTED[file.make_params.name] == 1
-    assert kit.COMPUTED[file.sound_reference_logits.name] == 1
-    sound = kit.COMPUTED[file.sound_system_logits.name]
+    assert since(file.make_params) <= 1
+    assert since(file.sound_reference_logits) <= 1
+    assert kit.COMPUTED[file.make_params.name] >= 1
+    assert kit.COMPUTED[file.sound_reference_logits.name] >= 1
+    sound = since(file.sound_system_logits)
     file.test_the_forward_pass_matches_the_reference_in_float32(0)
-    assert sound <= 1 and kit.COMPUTED[file.sound_system_logits.name] == 1
-    assert kit.COMPUTED[file.sound_reference_logits.name] == 1
+    assert sound == 0 and since(file.sound_system_logits) <= 1
+    assert kit.COMPUTED[file.sound_system_logits.name] >= 1
+    assert since(file.sound_reference_logits) <= 1

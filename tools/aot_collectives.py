@@ -5,9 +5,13 @@ chip: what each chip holds and which collectives the partitioner put in.
 
 Reads `benchmark/configs/<name>.json`, binds the configuration's family to
 the described devices of `v5e:2x2` (a one-chip configuration: the first of
-them), gives `lower_step` the state's shapes with the shardings `init_state` would
-give the arrays, and compiles.  Prints arguments and scratch space per
-chip, then every collective once per `channel_id` (XLA prints an
+them), gives `lower_step` the state's shapes with the shardings `init_state`
+would give the arrays (`models/layers.py:placed_shapes`), and compiles.
+Prints arguments and scratch space per chip, for each recomputed stack what
+its budget kept and declined (the static sum, then what the compiled step's
+own account made of it, `models/layers.py:keep_plan`: the path a chip run
+takes, two more traces and compiles of the step where a name is declined),
+then every collective once per `channel_id` (XLA prints an
 asynchronous one several times) grouped by kind and result shape, with the
 bytes of one and of all.  `--layers` compiles a shallower model: the
 collectives of one layer are those of every layer, in a tenth of the time
@@ -131,38 +135,6 @@ def conditional_branches(hlo_text: str, largest: int = 4) -> list:
     return found
 
 
-def state_shapes(family):
-    """(params, opt_state) as ShapeDtypeStructs placed as
-    `Family.init_state` places the arrays: parameters by the layout's
-    rules, each optimizer leaf beside the parameter whose path ends its
-    own, whatever else (the step count) everywhere."""
-    import jax
-
-    from ray_tpu.parallel.sharding import param_shardings
-
-    key = jax.random.PRNGKey(0)
-    shapes = jax.eval_shape(family._init, key)
-    placed = param_shardings(shapes, family.layout, family.mesh)
-    by_path = {path: s for path, s in
-               jax.tree_util.tree_flatten_with_path(placed)[0]}
-    everywhere = family.layout.named_sharding(family.mesh)
-
-    def beside(path):
-        for start in range(len(path)):
-            if path[start:] in by_path:
-                return by_path[path[start:]]
-        return everywhere
-
-    def struct(leaf, sharding):
-        return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sharding)
-
-    params = jax.tree.map(struct, shapes, placed)
-    opt = jax.eval_shape(family.optimizer().init, shapes)
-    opt = jax.tree_util.tree_map_with_path(
-        lambda path, leaf: struct(leaf, beside(path)), opt)
-    return params, opt
-
-
 # what a v5e states as `memory_stats()["bytes_limit"]`: 15.75 GiB
 V5E_LIMIT_GIB = 15.75
 
@@ -183,15 +155,21 @@ def compile_step(config: dict, traffic: dict, limit_gib=V5E_LIMIT_GIB):
                                         topology_name="v5e:2x2")
     family = registry.family(config)
     family.bind(topo.devices[:chips])
-    params, opt = state_shapes(family)
-    tokens = jax.ShapeDtypeStruct(
-        (traffic["batch"], traffic["seq"] + 1), jnp.int32,
-        sharding=family.layout.named_sharding(family.mesh, "batch", None))
-    from ray_tpu.models.layers import assume_memory_limit
+    from ray_tpu.models.layers import assume_memory_limit, placed_shapes
+    from ray_tpu.parallel.context import use_mesh
 
+    # the state's and the batch's shapes, placed as `Family.init_state` and
+    # `place_batch` place the arrays: the step's own reckoning of it
+    params = jax.eval_shape(family._init, jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct(
+        (traffic["batch"], traffic["seq"] + 1), jnp.int32)
+    with use_mesh(family.mesh):
+        (params, opt, batch), _ = placed_shapes(
+            params, jax.eval_shape(family.optimizer().init, params),
+            {"tokens": tokens})
     plans = []
     with assume_memory_limit(int(limit_gib * 2 ** 30), plans):
-        lowered = family.lower_step(params, opt, {"tokens": tokens})
+        lowered = family.lower_step(params, opt, batch)
     return lowered.compile(), plans
 
 
@@ -245,7 +223,15 @@ def main():
               f"{plan['room'] / gib:.3f} GiB after {plan['already'] / gib:.3f}"
               f" kept whatever the room, {plan['reserve'] / gib:.3f} of "
               f"reserve and {(plan['state'] or 0) / gib:.3f} of training "
-              f"state")
+              f"state (the static sum)")
+        if plan["measured"]:
+            print(f"    measured: room {plan['measured_room'] / gib:.3f} GiB "
+                  f"under the compiled step; admitted "
+                  f"{', '.join(map(size, plan['admitted'])) or 'none'}; "
+                  f"compiled peak {plan['peak'] / gib:.3f} GiB"
+                  + (" (from a record)" if plan["recorded"] else ""))
+        else:
+            print("    not measured: the static plan stands")
     found = collectives(text)
     for (kind, shape), (count, size) in sorted(
             found.items(), key=lambda kv: -kv[1][0] * kv[1][1]):
